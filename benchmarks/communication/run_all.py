@@ -17,9 +17,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .utils import report_line, time_fn
 
@@ -37,13 +36,9 @@ def build_op(op: str, mesh: Mesh, shape):
     rep = P()
 
     def wrap(body, in_spec, out_spec):
-        try:  # replication of collective outputs isn't statically inferrable
-            sm = shard_map(body, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec,
-                           check_vma=False)
-        except TypeError:  # older jax spelling
-            sm = shard_map(body, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec,
-                           check_rep=False)
-        return jax.jit(sm)
+        # replication of collective outputs isn't statically inferrable
+        return jax.jit(shard_map(body, mesh=mesh, in_specs=(in_spec,),
+                                 out_specs=out_spec, check_vma=False))
 
     if op == "all_reduce":
         return wrap(lambda x: lax.psum(x, "x"), spec, spec)

@@ -441,9 +441,6 @@ def main():
     import sys
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    from deepspeed_tpu.utils.jax_env import apply_platform_env
-
-    apply_platform_env()
     import jax.numpy as jnp
 
     from deepspeed_tpu.inference import InferenceEngine, ServingEngine

@@ -49,7 +49,12 @@ def main():
     ap.add_argument("--block", type=int, default=None)
     args = ap.parse_args()
 
-    on_tpu = jax.default_backend() == "tpu"
+    platform = jax.default_backend()
+    on_tpu = platform == "tpu"
+    print(f"[sparse_attention_bench] platform={platform}" + (
+        "" if on_tpu else " — not a TPU: seq 512, batch 1, block 128, float32 "
+        "in the Pallas interpreter unless overridden; not a device number"),
+        flush=True)
     S = args.seq or (8192 if on_tpu else 512)
     B = args.batch or (4 if on_tpu else 1)
     H, D = args.heads, args.dim
@@ -92,7 +97,7 @@ def main():
         "dense_ms": round(t_dense * 1e3, 2),
         "sparse_ms": round(t_sparse * 1e3, 2),
         "causal_block_density": round(density, 3),
-        "platform": jax.default_backend(),
+        "platform": platform,
     }
     print(json.dumps(out), flush=True)
 

@@ -22,6 +22,7 @@ import subprocess
 import sys
 from typing import Optional
 
+from ..utils.jax_env import require_chip_free
 from ..utils.logging import logger
 
 _OOM_MARKERS = (
@@ -82,6 +83,7 @@ class ExperimentScheduler:
         env = dict(os.environ)
         if self.env:
             env.update(self.env)
+        require_chip_free("ExperimentScheduler", env)
         try:
             proc = subprocess.run(
                 cmd, capture_output=True, text=True, timeout=self.trial_timeout,

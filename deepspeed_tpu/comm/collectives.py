@@ -210,9 +210,7 @@ def ppermute(x, axis, perm):
 
 def ring_shift(x, axis, shift: int = 1):
     """Shift values around the ring formed by a mesh axis (ring attention, PP)."""
-    from ..utils.jax_compat import axis_size
-
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return ppermute(x, axis, perm)
 
@@ -229,6 +227,4 @@ def axis_index(axis):
 
 
 def axis_size_in_jit(axis):
-    from ..utils.jax_compat import axis_size
-
-    return axis_size(axis)
+    return lax.axis_size(axis)

@@ -132,14 +132,13 @@ def _shard_map_per_rank(make_per_device, axis, mesh, n_args, n_outs):
     as [world] per-rank rows except output 0 (the rank-identical average)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.jax_compat import shard_map
     from .mesh import current_mesh
 
     mesh = mesh if mesh is not None else current_mesh()
     assert mesh is not None, "compressed allreduce needs a mesh"
     world = mesh.shape[axis]
     spec = P(axis)
-    fn = shard_map(make_per_device(world), mesh=mesh, in_specs=(spec,) * n_args,
+    fn = jax.shard_map(make_per_device(world), mesh=mesh, in_specs=(spec,) * n_args,
                    out_specs=(P(axis),) + (spec,) * (n_outs - 1))
 
     def call(*args):

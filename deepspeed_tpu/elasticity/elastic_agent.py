@@ -55,6 +55,7 @@ from typing import Callable, Optional, Sequence
 from ..launcher.launch import terminate_process_tree
 from ..resilience.heartbeat import HeartbeatJudge
 from ..resilience.retry import RetryPolicy, backoff_delay
+from ..utils.jax_env import require_chip_free
 from ..utils.logging import logger
 from .elasticity import ElasticityIncompatibleWorldSize, compute_elastic_config
 
@@ -173,6 +174,7 @@ class DSElasticAgent:
                 self.heartbeat_file, self.heartbeat_timeout,
                 self.heartbeat_grace)
             self._hb_judge.reset()
+        require_chip_free("DSElasticAgent", env)
         logger.info(
             "elastic agent: launching generation %d at world=%d "
             "(batch=%d, micro=%d): %s",

@@ -481,7 +481,7 @@ def _boundary_tagger(cfg: TransformerConfig):
 
 def _attention_dispatch(cfg: TransformerConfig):
     if cfg.attn_impl == "flash":
-        from ..ops.pallas.flash_attention import flash_attention
+        from ..ops.pallas.flash_attention import flash_attention_sharded
 
         bq = cfg.flash_block_q or None
         bk = cfg.flash_block_k or None
@@ -491,9 +491,9 @@ def _attention_dispatch(cfg: TransformerConfig):
             if bias is not None:
                 # general dense bias (not expressible as alibi/window)
                 return xla_attention(q, k, v, bias=bias, causal=cfg.causal)
-            return flash_attention(
-                q, k, v, causal=cfg.causal, block_q=bq, block_k=bk,
-                alibi_slopes=slopes, window=window,
+            return flash_attention_sharded(
+                q, k, v, mesh=_ACTIVE_MESH[0], causal=cfg.causal, block_q=bq,
+                block_k=bk, alibi_slopes=slopes, window=window,
             )
 
         # alibi and local windows are fused IN-KERNEL (computed from block

@@ -38,17 +38,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from . import interpret_default
 
 NEG_INF = -1e30
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
@@ -127,11 +121,8 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None, block_k: int = 
         )
     num_kb = Smax // block_k
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
-
-    if pltpu is None:
-        raise RuntimeError("pallas TPU support unavailable; use the XLA decode path")
 
     def clamp(j, p_ref, b):
         return jnp.minimum(j, p_ref[b] // block_k)

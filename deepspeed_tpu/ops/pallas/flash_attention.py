@@ -26,7 +26,7 @@ VMEM budget at 8-16k tokens.
 
 Causal masking skips the compute (not the grid step) of fully-masked blocks
 via ``pl.when`` — the block analogue of the reference's triangular softmax
-kernels. On non-TPU backends the kernels run in Pallas interpreter mode so
+kernels. On the CPU platform the kernels run in Pallas interpreter mode so
 tests exercise the same code.
 
 Layout: public API takes [B, S, H, D] (the model family's layout) and maps
@@ -42,15 +42,9 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is importable on non-TPU backends; kernels then run interpreted
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
+from . import interpret_default
 
 LANES = 128  # TPU lane width; LSE/delta are stored lane-broadcast
 NEG_INF = -1e30
@@ -74,34 +68,19 @@ def _auto_block(s: int, cap: int) -> int:
     return min(b, s)
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _vmem_spec(shape, index_map):
-    if _VMEM is not None:
-        return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
-    return pl.BlockSpec(shape, index_map)
+    return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
 
 def _scratch(shape):
-    if _VMEM is None:  # pragma: no cover - pltpu import failed entirely
-        raise RuntimeError("pallas TPU memory spaces unavailable; use attn_impl='xla'")
-    return _VMEM(shape, jnp.float32)
+    return pltpu.VMEM(shape, jnp.float32)
 
 
 def _compiler_params(grid_len):
     """Mark every grid dim except the innermost (the sequential stream over
     which scratch accumulates) as parallel."""
-    if pltpu is None:
-        return None
-    CP = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams", None)
-    if CP is None:
-        return None
-    try:
-        return CP(dimension_semantics=("parallel",) * (grid_len - 1) + ("arbitrary",))
-    except TypeError:  # pragma: no cover - signature drift
-        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (grid_len - 1) + ("arbitrary",))
 
 
 def _widen(lane_tile, width):
@@ -247,10 +226,6 @@ def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
         in_specs.append(_vmem_spec((1, LANES), lambda bh, qi, kj: (0, 0)))
         operands.append(w_arr)
     kernel = _wrap_extras(base, 3, slopes_bh is not None, w_arr is not None)
-    kwargs = {}
-    cp = _compiler_params(len(grid))
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -268,8 +243,8 @@ def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
             _scratch((block_q, LANES)),   # running row-sum l
             _scratch((block_q, D)),       # output accumulator
         ],
+        compiler_params=_compiler_params(len(grid)),
         interpret=interpret,
-        **kwargs,
     )(*operands)
     return out, lse
 
@@ -384,10 +359,7 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [BH,Sq]
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
 
-    kwargs = {}
     cp = _compiler_params(3)
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
 
     has_slopes = slopes_bh is not None
     has_window = w_arr is not None
@@ -425,7 +397,7 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
         ],
         scratch_shapes=[_scratch((block_k, D)), _scratch((block_k, D))],
         interpret=interpret,
-        **kwargs,
+        compiler_params=cp,
     )(q, k, v, g, lse, delta, *extra_ops)
     dk, dv = dkdv
 
@@ -448,7 +420,7 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
         scratch_shapes=[_scratch((block_q, D))],
         interpret=interpret,
-        **kwargs,
+        compiler_params=cp,
     )(q, k, v, g, lse, delta, *extra_ops)
     dslopes = jnp.zeros_like(slopes_bh) if has_slopes else None
     dw = jnp.zeros_like(w_arr) if has_window else None
@@ -560,7 +532,7 @@ def flash_attention(
             f"sequence lengths ({Sq_p}, {Sk_p}) must be divisible by blocks ({block_q}, {block_k})"
         )
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
 
     def to_bhsd(x):
         return x.transpose(0, 2, 1, 3).reshape(x.shape[0] * x.shape[2], x.shape[1], x.shape[3])
@@ -573,3 +545,31 @@ def flash_attention(
     if pad_q:
         out = out[:, :Sq]
     return out
+
+
+def flash_attention_sharded(q, k, v, *, mesh, alibi_slopes=None, window=None, **kw):
+    """``flash_attention`` for un-shard_mapped (pjit) callers on ``mesh``.
+
+    The SPMD partitioner cannot split a Mosaic kernel ("Mosaic kernels cannot
+    be automatically partitioned"), so on a mesh of several devices the call
+    is wrapped in ``shard_map``: each device runs the kernel on its own batch
+    rows (data/fsdp axes) and heads (model axis) — the slot cache's layout
+    rule, parallel/sharding.batch_and_head_axes. Inside another shard_map
+    (the pipeline's stage program) the caller's manual axes already own the
+    layout and the kernel is called as is."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...parallel.sharding import batch_and_head_axes
+
+    def call(q, k, v, slopes, w):
+        return flash_attention(q, k, v, alibi_slopes=slopes, window=w, **kw)
+
+    if mesh is None or mesh.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
+        return call(q, k, v, alibi_slopes, window)
+    batch_axes, head_axis = batch_and_head_axes(mesh, q.shape[0], q.shape[2])
+    qkv = P(batch_axes, None, head_axis, None)
+    # absent operands ride through as None leaves, which match any spec
+    return jax.shard_map(
+        call, mesh=mesh, in_specs=(qkv, qkv, qkv, P(head_axis), P()),
+        out_specs=qkv, check_vma=False,
+    )(q, k, v, alibi_slopes, window)

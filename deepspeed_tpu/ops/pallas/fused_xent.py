@@ -41,11 +41,11 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
+from . import interpret_default
 from .flash_attention import (
     LANES,
     NEG_INF,
     _compiler_params,
-    _interpret_default,
     _lanes,
     _scratch,
     _vmem_spec,
@@ -116,10 +116,6 @@ def _fused_forward(h, w, y_l, block_rows, block_v, vocab, interpret):
     Vp = w.shape[1]
     num_v = Vp // block_v
     grid = (N // block_rows, num_v)
-    kwargs = {}
-    cp = _compiler_params(len(grid))
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
     lse, gold = pl.pallas_call(
         functools.partial(_fwd_kernel, num_v=num_v, vocab=vocab),
         grid=grid,
@@ -142,7 +138,7 @@ def _fused_forward(h, w, y_l, block_rows, block_v, vocab, interpret):
             _scratch((block_rows, LANES)),  # gold-logit accumulator
         ],
         interpret=interpret,
-        **kwargs,
+        compiler_params=_compiler_params(len(grid)),
     )(h.reshape(N // block_rows, block_rows, D), w, y_l)
     return lse.reshape(N, LANES), gold.reshape(N, LANES)
 
@@ -220,10 +216,6 @@ def _fused_backward(h, w, y_l, lse_l, g_l, block_rows, block_v, vocab,
     num_v = Vp // block_v
     num_r = N // block_rows
     h_b = h.reshape(num_r, block_rows, D)
-    kwargs = {}
-    cp = _compiler_params(2)
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
 
     row_specs = [
         _vmem_spec((1, block_rows, D), lambda ri, vj: (ri, 0, 0)),
@@ -240,13 +232,9 @@ def _fused_backward(h, w, y_l, lse_l, g_l, block_rows, block_v, vocab,
         out_shape=jax.ShapeDtypeStruct((num_r, block_rows, D), h.dtype),
         scratch_shapes=[_scratch((block_rows, D))],
         interpret=interpret,
-        **kwargs,
+        compiler_params=_compiler_params(2),
     )(h_b, w, y_l, lse_l, g_l).reshape(N, D)
 
-    kwargs3 = {}
-    cp3 = _compiler_params(3)
-    if cp3 is not None and not interpret:
-        kwargs3["compiler_params"] = cp3
     dw = pl.pallas_call(
         functools.partial(_bwd_dw_kernel, num_r=num_r, vocab=vocab),
         grid=(1, num_v, num_r),  # rows innermost: dW accumulates over them
@@ -261,7 +249,7 @@ def _fused_backward(h, w, y_l, lse_l, g_l, block_rows, block_v, vocab,
         out_shape=jax.ShapeDtypeStruct((D, Vp), w.dtype),
         scratch_shapes=[_scratch((D, block_v))],
         interpret=interpret,
-        **kwargs3,
+        compiler_params=_compiler_params(3),
     )(h_b, w, y_l, lse_l, g_l)
     return dh, dw
 
@@ -327,7 +315,7 @@ def fused_linear_xent(
     N, D = hidden.shape
     V = head.shape[1]
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
 
     block_rows = block_rows or _auto_block(N, MAX_BLOCK_ROWS)
     if N % block_rows:

@@ -22,18 +22,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from ..pallas import interpret_default
 
 NEG_INF = -1e30
 LANES = 128  # lane-broadcast tiling for row statistics (same as flash kernel)
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def layout_to_lists(layout: np.ndarray, causal: bool) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
@@ -338,7 +332,7 @@ def sparse_flash_attention(q, k, v, layout: np.ndarray, causal: bool = True,
     if sm_scale is None:
         sm_scale = 1.0 / (D ** 0.5)
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     # lists stay NUMPY (static): they ride custom_vjp's nondiff_argnums and
     # feed the kernels' scalar-prefetch inputs at call time
     lists = layout_to_lists(layout, causal)

@@ -48,9 +48,7 @@ def ring_attention(q, k, v, axis_name: str = "context"):
     """Causal ring attention for [B, S_local, H, Dh] inputs inside
     shard_map/jit over a mesh with ``axis_name``. Returns [B, S_local, H, Dh].
     """
-    from ..utils.jax_compat import axis_size
-
-    R = axis_size(axis_name)
+    R = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     B, Sq, H, Dh = q.shape
 
@@ -95,10 +93,8 @@ def ring_attention_sharded(q, k, v, mesh, axis_name: str = "context"):
     [B, S_global, H, Dh] arrays sharded on S over ``axis_name``."""
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.jax_compat import shard_map
-
     spec = P(("data", "fsdp"), axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name=axis_name),
         mesh=mesh,
         in_specs=(spec, spec, spec),

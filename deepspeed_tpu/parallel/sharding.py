@@ -156,6 +156,22 @@ def tree_shardings(mesh: Mesh, specs_tree):
     )
 
 
+def batch_and_head_axes(mesh: Mesh, n_batch: int, num_heads: int):
+    """Mesh axes for a batch-like dim and a heads dim, as PartitionSpec
+    entries: the batch rides the ZeRO/data axes (``data``, ``fsdp``), heads
+    ride the TP axis (``model``). Any axis that does not divide its dim is
+    dropped (the dim stays whole), mirroring ``spec_from_logical``'s
+    non-divisible rule."""
+    batch_axes = tuple(a for a in ("data", "fsdp") if mesh.shape.get(a, 1) > 1)
+    size = int(np.prod([mesh.shape[a] for a in batch_axes])) if batch_axes else 1
+    if size <= 1 or n_batch % size:
+        batch_axes = ()
+    model_size = mesh.shape.get("model", 1)
+    head_ax = "model" if (model_size > 1 and num_heads % model_size == 0) else None
+    batch = batch_axes if len(batch_axes) > 1 else (batch_axes[0] if batch_axes else None)
+    return batch, head_ax
+
+
 def kv_slot_cache_spec(mesh: Mesh, n_slots: int, num_heads: int) -> PartitionSpec:
     """PartitionSpec for the serving engine's persistent slot KV cache
     [L, n_slots, Smax, H, Dh]: slots ride the ZeRO/data axes (each device
@@ -163,12 +179,7 @@ def kv_slot_cache_spec(mesh: Mesh, n_slots: int, num_heads: int) -> PartitionSpe
     keeps decode-attention reads local to the shard that owns the slot. Any
     mesh axis that does not divide its dim is dropped (replicated), mirroring
     ``spec_from_logical``'s non-divisible rule."""
-    batch_axes = tuple(a for a in ("data", "fsdp") if mesh.shape.get(a, 1) > 1)
-    size = int(np.prod([mesh.shape[a] for a in batch_axes])) if batch_axes else 1
-    slot_axes = batch_axes if (batch_axes and size > 1 and n_slots % size == 0) else ()
-    model_size = mesh.shape.get("model", 1)
-    head_ax = "model" if (model_size > 1 and num_heads % model_size == 0) else None
-    slot = slot_axes if len(slot_axes) > 1 else (slot_axes[0] if slot_axes else None)
+    slot, head_ax = batch_and_head_axes(mesh, n_slots, num_heads)
     return PartitionSpec(None, slot, None, head_ax, None)
 
 

@@ -40,9 +40,7 @@ def _local_attention(q, k, v, causal: bool):
 def ulysses_attention(q, k, v, axis_name: str = "context", causal: bool = True):
     """Per-device function (inside shard_map): q/k/v [B, S_local, H, Dh]
     sharded on S over ``axis_name``; returns the same layout."""
-    from ..utils.jax_compat import axis_size
-
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     H = q.shape[2]
     if H % n:
         raise ValueError(
@@ -68,10 +66,8 @@ def ulysses_attention_sharded(q, k, v, mesh, axis_name: str = "context",
     on S over ``axis_name`` (same contract as ring_attention_sharded)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.jax_compat import shard_map
-
     spec = P(("data", "fsdp"), axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(ulysses_attention, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),

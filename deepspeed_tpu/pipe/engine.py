@@ -156,8 +156,6 @@ def pipeline_train_1f1b(
     trace) where trace = (is_fwd, fwd_mb, is_bwd, bwd_mb) each [S, ticks] for
     execution-order conformance tests against TrainSchedule.
     """
-    from ..utils.jax_compat import shard_map
-
     M = x_mb.shape[0]
     S = num_stages
     P = PartitionSpec
@@ -264,7 +262,7 @@ def pipeline_train_1f1b(
         trace = tuple(tr[None, :] for tr in trace)  # [1, ticks] per stage
         return loss, gstage_out, ghead, gx_all, trace
 
-    sm = shard_map(
+    sm = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(stage_P, head_P, P(None, dp), P(None, dp), P()),

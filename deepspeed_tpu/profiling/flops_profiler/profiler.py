@@ -186,8 +186,7 @@ class FlopsProfiler:
             n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
 
         # XLA cross-check through the SHARED AOT cost path — the same
-        # lower().compile() capture the program ledger uses (and the same
-        # jax-version cost_analysis shim, utils/jax_compat), so the two
+        # lower().compile() capture the program ledger uses, so the two
         # never disagree on how to read XLA's cost model. The compile is
         # served from the compilation cache when the program already ran.
         from ...telemetry.program_ledger import aot_cost

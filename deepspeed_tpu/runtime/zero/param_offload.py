@@ -40,12 +40,9 @@ import jax
 PyTree = Any
 
 
-from ...utils.jax_compat import device_put_host, memory_space
-
-
 @jax.custom_vjp
 def _stream_leaf(x):
-    return jax.device_put(x, memory_space("device"))
+    return jax.device_put(x, jax.memory.Space.Device)
 
 
 def _fwd(x):
@@ -55,7 +52,7 @@ def _fwd(x):
 def _bwd(_, g):
     # gradient goes straight back to host: the [L, ...] cotangent stack the
     # scan transpose assembles must never live in HBM
-    return (jax.device_put(g, memory_space("host")),)
+    return (jax.device_put(g, jax.memory.Space.Host),)
 
 
 _stream_leaf.defvjp(_fwd, _bwd)
@@ -72,4 +69,4 @@ def place_on_host(tree: PyTree) -> PyTree:
     """Host-level helper: commit a pytree to pinned host memory (identity in
     spirit on backends without a separate host space, e.g. the CPU test
     backend, where the host space folds to device memory)."""
-    return device_put_host(tree)
+    return jax.device_put(tree, jax.memory.Space.Host)

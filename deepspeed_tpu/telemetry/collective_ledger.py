@@ -165,21 +165,40 @@ def _balanced_operands(text: str, open_idx: int) -> str:
     return text[open_idx + 1:]
 
 
+def _result_bytes(line: str, eq_end: int) -> int:
+    """Bytes of an instruction's RESULT type — the text right after the
+    ``=`` at ``eq_end``: one shape token, or a balanced tuple of them."""
+    rest = line[eq_end:].lstrip()
+    if rest.startswith("("):
+        rest = _balanced_operands(rest, 0)
+    else:
+        rest = rest.split(None, 1)[0] if rest else ""
+    return sum(_shape_bytes(dt, dims) for dt, dims in _SHAPE_RE.findall(rest))
+
+
 def parse_hlo_collectives(hlo_text: str) -> list[dict]:
     """Every collective instruction in an HLO module, in textual (schedule)
     order: ``{op, async, name, line, payload_bytes, groups, channel_id,
     overlapped}``. ``-done`` halves of async pairs are folded into their
     ``-start`` (one logical op, bytes counted once, ``overlapped`` judged
-    from the instructions scheduled between the two)."""
+    from the instructions scheduled between the two).
+
+    HLO text names an operand with or without its type
+    (``all-reduce(f32[8]{0} %p)`` / ``all-reduce(%p)``): inline shapes are
+    used where present, otherwise each operand identifier is sized from the
+    result type of the instruction that defined it."""
     lines = hlo_text.splitlines()
     ops: list[dict] = []
     starts: dict[str, dict] = {}  # %name of a -start -> its op record
+    defined: dict[str, int] = {}  # %name -> result bytes of its definition
     for ln, line in enumerate(lines):
+        nm = _NAME_RE.match(line)
+        if nm:
+            defined[nm.group("name")] = _result_bytes(line, nm.end())
         m = _OP_LINE_RE.search(line)
         if m is None:
             continue
         kind = m.group("kind") or ""
-        nm = _NAME_RE.match(line)
         name = nm.group("name") if nm else f"line{ln}"
         # m.end() - 1 is exactly the op's own open paren (the regex ends on
         # it) — `line.index("(")` would grab a tuple RESULT shape's paren
@@ -203,6 +222,9 @@ def parse_hlo_collectives(hlo_text: str) -> list[dict]:
         operand = _balanced_operands(line, m.end() - 1)
         payload = sum(_shape_bytes(dt, dims)
                       for dt, dims in _SHAPE_RE.findall(operand))
+        if not payload:
+            payload = sum(defined.get(ident, 0)
+                          for ident in _ID_RE.findall(operand))
         groups: list[list[int]] = []
         g1 = _GROUPS_V1_RE.search(line)
         if g1:

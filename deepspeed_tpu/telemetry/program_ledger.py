@@ -78,12 +78,11 @@ PEAKS: dict[str, dict] = {
                 "peak_ici_gbps": None},
 }
 
-# device_kind substrings -> PEAKS key, most specific first ("v5 lite" must
-# match before a bare "v5", which is the v5p marketing name in device_kind)
+# device_kind substrings -> PEAKS key; a kind that matches none is unrated
 _KIND_PATTERNS = (
     ("v6e", "tpu_v6e"), ("v6 lite", "tpu_v6e"),
     ("v5e", "tpu_v5e"), ("v5 lite", "tpu_v5e"), ("v5litepod", "tpu_v5e"),
-    ("v5p", "tpu_v5p"), ("v5", "tpu_v5p"),
+    ("v5p", "tpu_v5p"),
     ("v4", "tpu_v4"), ("v3", "tpu_v3"), ("v2", "tpu_v2"),
 )
 
@@ -145,18 +144,14 @@ def _arg_spec(leaf):
 
 def aot_cost(fn, args, kwargs=None, hlo: bool = False) -> dict:
     """Cost + memory analysis of ``fn`` lowered at ``args``' signature —
-    ONE shared lower().compile() path for the ledger and the flops profiler
-    (utils/jax_compat normalizes the per-version return shapes). Returns
-    {flops, bytes_accessed, optimal_seconds?, argument_bytes, output_bytes,
-    temp_bytes, alias_bytes, ...} with absent fields omitted; {} when the
-    function can't be lowered or the backend has no cost model.
+    ONE shared lower().compile() path for the ledger and the flops
+    profiler. Returns {flops, bytes_accessed, optimal_seconds?,
+    argument_bytes, output_bytes, temp_bytes, alias_bytes, ...} with absent
+    fields omitted; {} when the function can't be lowered.
     ``hlo=True`` additionally includes ``hlo_text`` (the post-optimization
     HLO of the SAME compiled artifact — the collective ledger's input;
     callers pop it rather than carrying megabytes into snapshots)."""
     import jax
-
-    from ..utils.jax_compat import (compiled_cost_analysis,
-                                    compiled_hlo_text, compiled_memory_stats)
 
     lower = getattr(fn, "lower", None)
     if lower is None:
@@ -164,20 +159,23 @@ def aot_cost(fn, args, kwargs=None, hlo: bool = False) -> dict:
     specs, kw_specs = jax.tree.map(_arg_spec, (tuple(args), kwargs or {}))
     compiled = lower(*specs, **kw_specs).compile()
     out: dict = {}
-    ca = compiled_cost_analysis(compiled)
-    if ca:
-        flops = float(ca.get("flops", 0.0))
-        by = float(ca.get("bytes accessed", 0.0))
-        if flops > 0:
-            out["flops"] = flops
-        if by > 0:
-            out["bytes_accessed"] = by
-        opt = float(ca.get("optimal_seconds", 0.0))
-        if opt > 0:
-            out["optimal_seconds"] = opt
-    out.update(compiled_memory_stats(compiled))
+    ca = compiled.cost_analysis() or {}
+    flops = float(ca.get("flops", 0.0))
+    by = float(ca.get("bytes accessed", 0.0))
+    if flops > 0:
+        out["flops"] = flops
+    if by > 0:
+        out["bytes_accessed"] = by
+    opt = float(ca.get("optimal_seconds", 0.0))
+    if opt > 0:
+        out["optimal_seconds"] = opt
+    ma = compiled.memory_analysis()
+    for f in ("argument", "output", "temp", "alias", "generated_code"):
+        v = getattr(ma, f"{f}_size_in_bytes", None)
+        if v is not None:
+            out[f"{f}_bytes"] = int(v)
     if hlo:
-        out["hlo_text"] = compiled_hlo_text(compiled)
+        out["hlo_text"] = compiled.as_text() or ""
     return out
 
 

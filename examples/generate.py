@@ -29,7 +29,10 @@ def main():
     ap.add_argument("--top-p", type=float, default=0.95)
     args = ap.parse_args()
 
-    on_tpu = jax.default_backend() == "tpu"
+    platform = jax.default_backend()
+    on_tpu = platform == "tpu"
+    print(f"platform={platform}" + ("" if on_tpu else
+          " — not a TPU: float32 weights instead of bf16"), flush=True)
     if args.hf:
         from transformers import AutoModelForCausalLM, AutoTokenizer
 
@@ -52,7 +55,7 @@ def main():
     out = engine.generate(
         prompt, max_new_tokens=args.tokens, temperature=args.temperature,
         top_p=args.top_p, rng=jax.random.PRNGKey(0))
-    print("generated token ids:", out[0].tolist())
+    print(f"generated token ids (platform={platform}):", out[0].tolist())
     if tok is not None:
         print("text:", tok.decode(np.concatenate([prompt[0], out[0]])))
 

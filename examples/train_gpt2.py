@@ -42,7 +42,11 @@ def main():
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
 
-    on_tpu = jax.default_backend() == "tpu"
+    platform = jax.default_backend()
+    on_tpu = platform == "tpu"
+    print(f"platform={platform}" + ("" if on_tpu else
+          " — not a TPU: float32 + XLA attention instead of bf16 + flash; "
+          "a functional run, not a speed reading"), flush=True)
     is_onebit = args.optimizer in ("OneBitAdam", "OneBitLamb", "ZeroOneAdam")
     zero_stage = args.zero_stage
     if is_onebit and zero_stage > 1:
@@ -99,7 +103,7 @@ def main():
         if (step + 1) % 10 == 0:
             m = jax.device_get(metrics)
             print(f"step {engine.global_steps}: loss={float(m['loss']):.4f} "
-                  f"lr={float(m['lr']):.2e}")
+                  f"lr={float(m['lr']):.2e} platform={platform}")
 
     engine.save_checkpoint(args.ckpt_dir)
     print(f"saved checkpoint to {args.ckpt_dir} "

@@ -1,0 +1,226 @@
+"""The main path compiled for a TPU v5e that is described, not attached.
+
+The TPU compiler is installed in the sandbox: ``jax.experimental.topologies``
+describes a ``v5e:2x2`` host and ``lower(...).compile()`` then raises what the
+chip's compiler would raise — a misaligned kernel slice, too much VMEM, a
+program over HBM — which interpret mode cannot show. Nothing runs, so nothing
+here is a chip run or a time.
+
+Tier 1 compiles the main path's kernels at real widths with
+``interpret=False`` (about two seconds each). The slow tier compiles whole
+programs at GPT-2 125M: the train step as ``chip_smoke.py`` / ``bench.py``
+configure it, the serving programs of ``chip_smoke.py``'s serve phase, and the
+ZeRO-3 fsdp=4 step over the four described chips (run them with ``-m slow``
+before spending chip time on ``chip_smoke.py``).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+import chip_smoke
+
+HBM_BYTES = 16e9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    # dstpu: allow[broad-except] -- no TPU compiler in this installation: whatever it raises, the answer is skip
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return topo.devices
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip: the next run would warn and compile
+    again, so the cache is off around these."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Code that asks ``jax.default_backend()`` still sees the CPU here;
+    steer it onto its TPU branch (compiled kernels, donation) for the
+    compile, in the test and not through an option of the program."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _footprint(compiled) -> float:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+# ---------------------------------------------------------------------------
+# kernels at real widths (tier 1)
+# ---------------------------------------------------------------------------
+
+def _flash_fwd_bwd(sds):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    x = sds((16, 1024, 12, 64), jnp.bfloat16)  # bench.py / chip_smoke micro-batch
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, block_q=1024,
+                                       block_k=1024, interpret=False).astype(jnp.float32))
+
+    return jax.grad(loss, argnums=(0, 1, 2)), (x, x, x)
+
+
+def _decode_kernel(sds):
+    from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
+
+    sz = chip_smoke.REAL  # the serve phase's slot cache: [n_slots, Smax, H, Dh]
+    n, Dh = sz["n_slots"], sz["D"] // sz["H"]
+    cache = sds((n, sz["S"], sz["H"], Dh), jnp.bfloat16)
+    return (lambda q, k, v, p: decode_attention(q, k, v, p, interpret=False),
+            (sds((n, sz["H"], Dh), jnp.bfloat16), cache, cache, sds((n,), jnp.int32)))
+
+
+def _fused_xent_fwd_bwd(sds):
+    from deepspeed_tpu.ops.pallas.fused_xent import fused_linear_xent
+
+    N, D, V = 16384, 768, 50304
+
+    def loss(h, w, y):
+        return jnp.sum(fused_linear_xent(h, w, y, interpret=False))
+
+    return jax.grad(loss, argnums=(0, 1)), (
+        sds((N, D), jnp.bfloat16), sds((D, V), jnp.bfloat16), sds((N,), jnp.int32))
+
+
+@pytest.mark.parametrize("build", [_flash_fwd_bwd, _decode_kernel, _fused_xent_fwd_bwd],
+                         ids=["flash_fwd_bwd", "decode_attention", "fused_linear_xent"])
+def test_kernel_compiles_for_v5e(build, v5e, no_persistent_cache):
+    one_chip = SingleDeviceSharding(v5e[0])
+    fn, args = build(lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _footprint(compiled) < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# whole programs at GPT-2 125M (slow tier)
+# ---------------------------------------------------------------------------
+
+def _retarget(engine, devices) -> None:
+    """Point a CPU-built engine's mesh and state shardings at described
+    devices, so that the step it builds next lowers for them."""
+    mesh = Mesh(np.asarray(devices).reshape(engine.mesh.devices.shape),
+                engine.mesh.axis_names)
+    engine.mesh = mesh
+    engine.model.set_mesh(mesh)
+    engine._state_shardings = jax.tree.map(
+        lambda s: NamedSharding(mesh, s.spec, memory_kind=s.memory_kind),
+        engine._state_shardings, is_leaf=lambda x: isinstance(x, NamedSharding))
+
+
+def _compile_train_step(engine, sz):
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        engine.state, engine._state_shardings)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (sz["B"], sz["S"] + 1), jnp.int32,
+        sharding=NamedSharding(engine.mesh, engine.batch_spec))}
+    return engine._build_train_step().lower(state, batch).compile()
+
+
+@pytest.mark.slow
+def test_train_step_125m_compiles_for_one_v5e(v5e, no_persistent_cache, as_tpu):
+    import deepspeed_tpu
+    from deepspeed_tpu.comm.mesh import MeshConfig, build_mesh
+
+    sz = chip_smoke.REAL
+    cfg = chip_smoke._ds_config(sz, zero_stage=1, micro=sz["micro"],
+                                gas=sz["B"] // sz["micro"], mesh={"data": 1})
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=chip_smoke._train_model(sz), config=cfg,
+        mesh=build_mesh(MeshConfig(data=1), devices=jax.devices()[:1]))
+    _retarget(engine, v5e[:1])
+    compiled = _compile_train_step(engine, sz)
+    assert "tpu_custom_call" in compiled.as_text()  # the flash kernel is in it
+    print("train step 125M on one v5e:", compiled.memory_analysis())
+    assert _footprint(compiled) < HBM_BYTES
+
+
+@pytest.mark.slow
+def test_fsdp4_train_step_125m_compiles_for_v5e_2x2(v5e, no_persistent_cache, as_tpu):
+    import deepspeed_tpu
+    from deepspeed_tpu.comm.mesh import MeshConfig, build_mesh
+
+    sz = chip_smoke.REAL
+    cfg = chip_smoke._ds_config(sz, zero_stage=3, micro=sz["micro"], gas=1,
+                                mesh={"data": 1, "fsdp": 4})
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=chip_smoke._train_model(sz), config=cfg,
+        mesh=build_mesh(MeshConfig(data=1, fsdp=4), devices=jax.devices()[:4]))
+    _retarget(engine, v5e)
+    compiled = _compile_train_step(engine, sz)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" in text or "all-reduce" in text  # really partitioned
+    print("fsdp=4 train step 125M, per device:", compiled.memory_analysis())
+    assert _footprint(compiled) < HBM_BYTES
+
+
+@pytest.mark.slow
+def test_serving_programs_125m_compile_for_one_v5e(v5e, no_persistent_cache, as_tpu):
+    """The programs ``SlotWorker`` builds for chip_smoke's serve phase: the
+    one decode step, and the smallest and largest prefill bucket its prompts
+    fall into (each program takes ~25 s here: the vocab-wide sampler sort)."""
+    from deepspeed_tpu.inference.serving import SlotWorker, _next_pow2
+    from deepspeed_tpu.models.transformer import Model, TransformerConfig
+
+    sz = chip_smoke.REAL
+    one_chip = SingleDeviceSharding(v5e[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    cfg = TransformerConfig(
+        vocab_size=sz["V"], max_seq_len=sz["S"], num_layers=sz["L"],
+        num_heads=sz["H"], hidden_size=sz["D"], pos_emb="learned", dtype=jnp.bfloat16)
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(Model(cfg).init, jax.random.PRNGKey(0)))
+    n, Smax = sz["n_slots"], sz["S"]
+    kv = sds((sz["L"], n, Smax, sz["H"], sz["D"] // sz["H"]), jnp.bfloat16)
+    cache = {"k": kv, "v": kv}
+    key = sds((2,), jnp.uint32)
+
+    # the program builders read only these three attributes
+    worker = SlotWorker.__new__(SlotWorker)
+    worker.cfg, worker.Smax = cfg, Smax
+    worker._cache_shardings = {"k": one_chip, "v": one_chip}
+
+    vec = lambda dtype: sds((n,), dtype)
+    decode = worker._build_decode().lower(
+        params, cache, vec(jnp.int32), vec(jnp.int32), vec(jnp.int32),
+        vec(jnp.bool_), key, vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)).compile()
+    assert "tpu_custom_call" in decode.as_text()  # the Pallas decode kernel
+    worst = _footprint(decode)
+    one = lambda dtype: sds((1,), dtype)
+    buckets = sorted({max(16, _next_pow2(p)) for p in sz["prompt_lens"]})
+    for bucket in (buckets[0], buckets[-1]):
+        prefill = worker._build_prefill(bucket).lower(
+            params, cache, sds((1, bucket), jnp.int32), sds((), jnp.int32),
+            sds((), jnp.int32), key, one(jnp.float32), one(jnp.int32),
+            one(jnp.float32)).compile()
+        worst = max(worst, _footprint(prefill))
+    print(f"serving programs 125M on one v5e: worst footprint {worst / 1e9:.2f} GB")
+    assert worst < HBM_BYTES

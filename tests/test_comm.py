@@ -9,7 +9,7 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu import comm
 from deepspeed_tpu.comm.mesh import MeshConfig, build_mesh
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def test_mesh_shapes():
@@ -30,10 +30,7 @@ def test_mesh_invalid():
 
 
 def _shmap(mesh, f, in_spec, out_spec):
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_spec, out_specs=out_spec, check_vma=False)
-    except TypeError:  # older jax spelling
-        return shard_map(f, mesh=mesh, in_specs=in_spec, out_specs=out_spec, check_rep=False)
+    return shard_map(f, mesh=mesh, in_specs=in_spec, out_specs=out_spec, check_vma=False)
 
 
 def test_all_reduce(mesh8):

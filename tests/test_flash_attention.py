@@ -93,3 +93,30 @@ def test_model_with_flash_attention_matches_xla():
     lx = mx.loss(params, {"tokens": toks})
     lf = mf.loss(params, {"tokens": toks})
     np.testing.assert_allclose(np.asarray(lx), np.asarray(lf), rtol=1e-5)
+
+
+@pytest.mark.parametrize("mesh_cfg", [{"data": 2, "fsdp": 2, "model": 2}, {"data": 8}],
+                         ids=["dp2-fsdp2-tp2", "dp8-batch-indivisible"])
+def test_sharded_wrapper_matches_unsharded(mesh_cfg):
+    """flash_attention_sharded (what the model calls on a mesh of several
+    devices, because a Mosaic kernel cannot be partitioned by GSPMD) gives
+    the unsharded kernel's output and gradients: batch over data x fsdp,
+    heads over model, and an axis that does not divide its dim left out."""
+    from deepspeed_tpu.comm.mesh import MeshConfig, build_mesh
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_sharded
+
+    mesh = build_mesh(MeshConfig(**mesh_cfg))
+    q, k, v = _qkv(B=4, S=128)
+    slopes = jnp.asarray([0.5, 0.25, 0.125, 0.0625], jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(jnp.square(fn(q, k, v)))
+
+    plain = lambda q, k, v: flash_attention(q, k, v, alibi_slopes=slopes)
+    sharded = lambda q, k, v: flash_attention_sharded(
+        q, k, v, mesh=mesh, alibi_slopes=slopes)
+    ref, ref_g = jax.value_and_grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    out, out_g = jax.jit(jax.value_and_grad(loss(sharded), argnums=(0, 1, 2)))(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5)
+    for a, b in zip(out_g, ref_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)

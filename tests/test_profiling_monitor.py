@@ -114,7 +114,7 @@ def test_comms_logger_records_trace_time():
 
     comms_logger.configure(enabled=True, verbose=False)
     try:
-        from deepspeed_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from deepspeed_tpu.comm.mesh import MeshConfig, build_mesh
 

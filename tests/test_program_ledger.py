@@ -284,7 +284,8 @@ def test_first_matching_program_owns_the_gauge():
 
 
 def test_platform_peak_table_resolution():
-    """device_kind strings map to the right generation; v5e before v5p."""
+    """device_kind strings map to the right generation; a kind the table
+    does not know (a bare "v5" included) is unrated, never a guessed peak."""
 
     class _Dev:
         def __init__(self, platform, kind):
@@ -294,6 +295,7 @@ def test_platform_peak_table_resolution():
     assert platform_peaks(_Dev("tpu", "TPU v5 lite"))["peak_tflops"] == 197.0
     assert platform_peaks(_Dev("tpu", "TPU v5p"))["peak_tflops"] == 459.0
     assert platform_peaks(_Dev("tpu", "TPU v7x"))["peak_tflops"] is None
+    assert platform_peaks(_Dev("tpu", "TPU v5"))["peak_tflops"] is None
     assert platform_peaks(_Dev("cpu", "cpu"))["label"] == "cpu (unrated)"
 
 

@@ -212,7 +212,7 @@ def test_sparse_tensor_dense_roundtrip():
 
 
 def test_sparse_all_reduce_over_mesh(mesh8):
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.runtime.sparse_tensor import SparseTensor, sparse_all_reduce
