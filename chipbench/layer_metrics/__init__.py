@@ -1,0 +1,1 @@
+"""Found by name: one file per entry (see chipbench/README.md)."""
