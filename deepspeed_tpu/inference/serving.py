@@ -115,6 +115,7 @@ from ..runtime.config import (ChunkedPrefillConfig, FaultInjectionConfig,
 from ..telemetry import (IncidentRecorder, RequestTracer, Telemetry,
                          TimeSeriesStore, classify_terminal, hbm_snapshot,
                          tree_bytes)
+from ..telemetry.tracing import spans as ended_spans
 from ..utils.donation import donated_jit
 from ..utils.logging import log_dist
 from .engine import InferenceEngine
@@ -136,6 +137,9 @@ def _next_pow2(n: int) -> int:
 # 2^_SPEC_PROBE_WAIT_MAX_LOG2 decode steps
 _SPEC_SUPPRESS_AFTER = 3
 _SPEC_PROBE_WAIT_MAX_LOG2 = 6
+# ended spans a telemetry_snapshot() carries (about fifty steps): snapshots
+# are scraped and travel over the fleet's rpc, the ring's 65,536 do not
+_SNAPSHOT_SPANS = 512
 
 
 @dataclass
@@ -555,35 +559,51 @@ class SlotWorker:
 
     # -- dispatches ------------------------------------------------------
 
+    def _compiled(self, sp, prog) -> bool:
+        """Whether the call just made into ``prog`` compiled, noted on its
+        span and on the scheduler's heartbeat flag. A call that compiled is
+        left out of the latency histograms (it is ``compile/wall_s``'s datum,
+        and would otherwise be the p99)."""
+        compiled = bool(prog.last_call_compiled)
+        self.step_compiled |= compiled
+        sp.annotate(compiled=compiled)
+        return compiled
+
+    # Every dispatch below is ONE span with two children: ``dispatch`` (from
+    # entry until the jitted call has returned: key split, operand conversion
+    # and upload, enqueue) and ``fetch`` (``jax.device_get`` of the tokens:
+    # the wait for the device plus the copy back). The fetch syncs, so the
+    # span's own duration is device-true, and it is what the latency
+    # histograms are fed from.
+
     def decode(self, last_tok, pos, wpos, active, temp, top_k, top_p):
         """Advance EVERY slot one token — THE compile-stable path: a second
         compilation means an operand's shape/dtype/sharding drifted and
         every admission would pay a retrace (the watchdog warns or raises
         per config). Returns host ``(next_token, bad_sentinel)`` [n_slots]
-        arrays; the fetch syncs, so the recorded latency is device-true."""
+        arrays."""
         tm = self.telemetry
-        if self._decode is None:
-            wd = tm.watchdog
-            self._decode = wd.watch(
-                self._build_decode(), wd.unique_name("serving/decode"),
-                stable=True)
-        self._rng, k = jax.random.split(self._rng)
-        t0 = time.perf_counter()
-        # host arrays straight into the jitted call (pjit batches the
-        # uploads); dtypes are pinned by the engine's per-slot state arrays
-        self._cache, nxt, bad = self._decode(
-            self.params, self._cache, last_tok, pos,
-            np.asarray(wpos, np.int32), active, k, temp, top_k, top_p,
-        )
-        self._decode_steps += 1
-        self.step_compiled |= bool(self._decode.last_call_compiled)
-        nxt, bad = (np.asarray(x) for x in jax.device_get((nxt, bad)))
-        # nxt is fetched: the decode program has fully executed on device.
-        # The compiling call is excluded from the latency histogram (it is
-        # compile/wall_s's datum, and would otherwise be the p99)
-        if not self._decode.last_call_compiled:
-            tm.histogram("serving/decode_step_sec").observe(
-                time.perf_counter() - t0)
+        with tm.span("decode", n_active=int(np.count_nonzero(active))) as sp:
+            with tm.span("dispatch"):
+                if self._decode is None:
+                    wd = tm.watchdog
+                    self._decode = wd.watch(
+                        self._build_decode(), wd.unique_name("serving/decode"),
+                        stable=True)
+                self._rng, k = jax.random.split(self._rng)
+                # host arrays straight into the jitted call (pjit batches the
+                # uploads); dtypes are pinned by the engine's per-slot state
+                # arrays
+                self._cache, nxt, bad = self._decode(
+                    self.params, self._cache, last_tok, pos,
+                    np.asarray(wpos, np.int32), active, k, temp, top_k, top_p,
+                )
+            self._decode_steps += 1
+            compiled = self._compiled(sp, self._decode)
+            with tm.span("fetch"):
+                nxt, bad = (np.asarray(x) for x in jax.device_get((nxt, bad)))
+        if not compiled:
+            tm.histogram("serving/decode_step_sec").observe(sp.dur_s)
         tm.counter("serving/decode_steps").inc()
         return nxt, bad
 
@@ -594,112 +614,117 @@ class SlotWorker:
         bucket (at most two: the all-greedy fast path and the mixed-
         sampling one), the chunked-prefill discipline applied to decode.
         Returns host ``(accept, resample, clean, bad)`` arrays
-        ([n, depth] / [n, depth+1] / [n, depth+1] / [n]); the fetch syncs,
-        so the recorded latency is device-true."""
+        ([n, depth] / [n, depth+1] / [n, depth+1] / [n])."""
         tm = self.telemetry
-        key = (depth, greedy_only)
-        if key not in self._verifies:
-            wd = tm.watchdog
-            name = f"serving/verify[{depth}{':greedy' if greedy_only else ''}]"
-            self._verifies[key] = wd.watch(
-                self._build_verify(depth, greedy_only),
-                wd.unique_name(name), stable=True)
-        prog = self._verifies[key]
-        # host arrays go straight into the jitted call: pjit's C++ argument
-        # path uploads them in one batch, and the greedy family's trimmed
-        # signature (no rng/temp/top_k/top_p — dead operands there) skips
-        # both the uploads and the per-step key split
-        t0 = time.perf_counter()
-        wpos = np.asarray(wpos, np.int32)
-        if greedy_only:
-            self._cache, packed = prog(
-                self.params, self._cache, toks, pos, wpos, active)
-            self.step_compiled |= bool(prog.last_call_compiled)
-            p = np.asarray(packed)  # the ONE fetch; syncs the program
-            tokens = p[:, depth:2 * depth + 1]
-            out = (p[:, :depth].astype(bool), tokens, tokens,
-                   p[:, -1].astype(bool))
-        else:
-            self._rng, k = jax.random.split(self._rng)
-            self._cache, accept, resample, clean, bad = prog(
-                self.params, self._cache, toks, pos, wpos, active, k,
-                temp, top_k, top_p)
-            self.step_compiled |= bool(prog.last_call_compiled)
-            out = tuple(np.asarray(x) for x in
-                        jax.device_get((accept, resample, clean, bad)))
+        with tm.span("verify", n_active=int(np.count_nonzero(active)),
+                     depth=depth) as sp:
+            with tm.span("dispatch"):
+                key = (depth, greedy_only)
+                if key not in self._verifies:
+                    wd = tm.watchdog
+                    name = f"serving/verify[{depth}{':greedy' if greedy_only else ''}]"
+                    self._verifies[key] = wd.watch(
+                        self._build_verify(depth, greedy_only),
+                        wd.unique_name(name), stable=True)
+                prog = self._verifies[key]
+                # host arrays go straight into the jitted call: pjit's C++
+                # argument path uploads them in one batch, and the greedy
+                # family's trimmed signature (no rng/temp/top_k/top_p — dead
+                # operands there) skips both the uploads and the per-step
+                # key split
+                wpos = np.asarray(wpos, np.int32)
+                if greedy_only:
+                    self._cache, *out = prog(
+                        self.params, self._cache, toks, pos, wpos, active)
+                else:
+                    self._rng, k = jax.random.split(self._rng)
+                    self._cache, *out = prog(
+                        self.params, self._cache, toks, pos, wpos, active, k,
+                        temp, top_k, top_p)
+            compiled = self._compiled(sp, prog)
+            with tm.span("fetch"):
+                out = tuple(np.asarray(x) for x in jax.device_get(out))
+            if greedy_only:
+                p, = out  # one packed array: the ONE fetch
+                tokens = p[:, depth:2 * depth + 1]
+                out = (p[:, :depth].astype(bool), tokens, tokens,
+                       p[:, -1].astype(bool))
+            if warm:
+                sp.annotate(warm=True)
         if warm:
             # pre-warm dispatch (all slots inactive, writes dropped): it
             # exists to COMPILE, so it is neither a latency datum nor a
             # verify step the acceptance accounting should see
             return out
-        # device-true (the fetch synced); the compiling call is excluded —
-        # same rule as decode: compile/wall_s records it, and folding it in
-        # would make the latency tail pure compile time
-        if not prog.last_call_compiled:
-            tm.histogram("serving/verify_step_sec").observe(
-                time.perf_counter() - t0)
+        if not compiled:
+            tm.histogram("serving/verify_step_sec").observe(sp.dur_s)
         tm.counter("serving/verify_steps").inc()
         tm.counter(f"serving/verify_bucket[{depth}]").inc()
         return out
 
     def prefill(self, bucket: int, padded, slot: int, true_len: int,
-                temperature: float, top_k: int, top_p: float):
+                temperature: float, top_k: int, top_p: float, *, uid=None):
         """One-shot bucketed prompt prefill into ``slot``. Returns the host
-        ``(first_token, bad)`` pair; the fetch syncs."""
+        ``(first_token, bad)`` pair. ``uid`` only labels the span."""
         tm = self.telemetry
-        if bucket not in self._prefills:
-            # each bucket length is its own compile-stable program: one
-            # compile at first use, never again
-            wd = tm.watchdog
-            self._prefills[bucket] = wd.watch(
-                self._build_prefill(bucket),
-                wd.unique_name(f"serving/prefill[{bucket}]"), stable=True)
-        self._rng, k = jax.random.split(self._rng)
-        t0 = time.perf_counter()
-        self._cache, tok, bad = self._prefills[bucket](
-            self.params, self._cache, jnp.asarray(padded),
-            jnp.int32(slot), jnp.int32(true_len), k,
-            jnp.asarray([temperature], jnp.float32),
-            jnp.asarray([top_k], jnp.int32),
-            jnp.asarray([top_p], jnp.float32),
-        )
-        self.step_compiled |= bool(self._prefills[bucket].last_call_compiled)
-        tok_h, bad_h = jax.device_get((tok, bad))
-        # the token fetch above synced, so this wall time is device-true;
-        # the compiling call is excluded — compile/wall_s records it, and
-        # folding it in would make the latency tail pure compile time
-        if not self._prefills[bucket].last_call_compiled:
-            tm.histogram("serving/prefill_sec").observe(time.perf_counter() - t0)
+        with tm.span("prefill", uid=uid, slot=slot, bucket=bucket,
+                     true_len=true_len) as sp:
+            with tm.span("dispatch"):
+                if bucket not in self._prefills:
+                    # each bucket length is its own compile-stable program:
+                    # one compile at first use, never again
+                    wd = tm.watchdog
+                    self._prefills[bucket] = wd.watch(
+                        self._build_prefill(bucket),
+                        wd.unique_name(f"serving/prefill[{bucket}]"), stable=True)
+                prog = self._prefills[bucket]
+                self._rng, k = jax.random.split(self._rng)
+                self._cache, tok, bad = prog(
+                    self.params, self._cache, jnp.asarray(padded),
+                    jnp.int32(slot), jnp.int32(true_len), k,
+                    jnp.asarray([temperature], jnp.float32),
+                    jnp.asarray([top_k], jnp.int32),
+                    jnp.asarray([top_p], jnp.float32),
+                )
+            compiled = self._compiled(sp, prog)
+            with tm.span("fetch"):
+                tok_h, bad_h = jax.device_get((tok, bad))
+        if not compiled:
+            tm.histogram("serving/prefill_sec").observe(sp.dur_s)
         tm.counter(f"serving/prefill_bucket[{bucket}]").inc()
         return int(np.asarray(tok_h)[0]), bool(np.asarray(bad_h).reshape(-1)[0])
 
     def chunk(self, width: int, toks, slot: int, start: int, live: int,
-              temperature: float, top_k: int, top_p: float, *, fetch: bool):
+              temperature: float, top_k: int, top_p: float, *, fetch: bool,
+              uid=None):
         """One prompt chunk through the ``width`` program. ``fetch=False``
         (intermediate chunk) returns None and leaves the dispatch async —
         the sampled token is garbage mid-prompt logits, and the next decode
-        step overlaps with the chunk; the FINAL chunk fetches and returns
-        ``(first_token, bad)``."""
-        prog = self._chunk_prog(width)
+        step overlaps with the chunk (its span has no ``fetch`` child and is
+        no latency datum); the FINAL chunk fetches and returns
+        ``(first_token, bad)``. ``uid`` only labels the span."""
         tm = self.telemetry
-        self._rng, k = jax.random.split(self._rng)
-        t0 = time.perf_counter()
-        self._cache, tok, bad = prog(
-            self.params, self._cache, jnp.asarray(toks),
-            jnp.int32(slot), jnp.int32(start), jnp.int32(live), k,
-            jnp.asarray([temperature], jnp.float32),
-            jnp.asarray([top_k], jnp.int32),
-            jnp.asarray([top_p], jnp.float32),
-        )
+        with tm.span("chunk", uid=uid, slot=slot, width=width, live=live,
+                     fetch=fetch) as sp:
+            with tm.span("dispatch"):
+                prog = self._chunk_prog(width)
+                self._rng, k = jax.random.split(self._rng)
+                self._cache, tok, bad = prog(
+                    self.params, self._cache, jnp.asarray(toks),
+                    jnp.int32(slot), jnp.int32(start), jnp.int32(live), k,
+                    jnp.asarray([temperature], jnp.float32),
+                    jnp.asarray([top_k], jnp.int32),
+                    jnp.asarray([top_p], jnp.float32),
+                )
+            compiled = self._compiled(sp, prog)
+            if fetch:
+                with tm.span("fetch"):
+                    tok_h, bad_h = jax.device_get((tok, bad))
         tm.counter(f"serving/chunk_bucket[{width}]").inc()
-        self.step_compiled |= bool(prog.last_call_compiled)
         if not fetch:
             return None
-        tok_h, bad_h = jax.device_get((tok, bad))
-        # device-true (the fetch synced); the compiling call is excluded
-        if not prog.last_call_compiled:
-            tm.histogram("serving/chunk_prefill_sec").observe(
-                time.perf_counter() - t0)
+        if not compiled:
+            tm.histogram("serving/chunk_prefill_sec").observe(sp.dur_s)
         return int(np.asarray(tok_h)[0]), bool(np.asarray(bad_h).reshape(-1)[0])
 
     def prefix_fetch(self, pool_slot: int, slot: int) -> None:
@@ -1112,6 +1137,8 @@ class ServingEngine:
         # step; skip it entirely until some live request can actually expire
         self._deadlines_armed = self.default_deadline_s > 0
         self._epoch = time.perf_counter()
+        self._t_built = self._epoch
+        self._steps = 0  # scheduler iterations: the serve/step span's index
         # per-request lifecycle tracing (telemetry/request_trace.py): a
         # bounded ring of host-side timeline events on the engine's clock,
         # stamped with this replica's id for fleet-wide merges
@@ -1782,11 +1809,19 @@ class ServingEngine:
         otherwise it fetches the cached prefix and leaves the request in the
         ``prefilling`` state for step() to advance chunk by chunk."""
         tm = self.telemetry
+        with tm.span("admit") as sp:
+            admitted = self._admit_arrived(now)
+            sp.annotate(admitted=admitted)
+
+    def _admit_arrived(self, now: float) -> int:
+        tm = self.telemetry
+        admitted = 0
         while self._free and self._queue:
             req = self._pop_tenant_fair(now)
             if req is None:
                 break
             slot = self._free.popleft()
+            admitted += 1
             prompt = np.asarray(req.prompt, np.int32).reshape(-1)
             S = prompt.shape[0]
             t_adm = time.perf_counter() - self._epoch
@@ -1833,6 +1868,7 @@ class ServingEngine:
                 # all segments run back-to-back — legacy blocking semantics
                 while slot in self._prefilling:
                     self._advance_prefill(slot)
+        return admitted
 
     def _prefill_one_shot(self, req: Request, slot: int, prompt: np.ndarray,
                           t_adm: float, entry):
@@ -1841,7 +1877,8 @@ class ServingEngine:
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :S] = prompt
         first, bad = self.worker.prefill(
-            bucket, padded, slot, S, req.temperature, req.top_k, req.top_p)
+            bucket, padded, slot, S, req.temperature, req.top_k, req.top_p,
+            uid=req.uid)
         t_first = time.perf_counter() - self._epoch
         self._activate(slot, req, prompt, first, t_adm, t_first, entry, bad=bad)
 
@@ -1858,7 +1895,8 @@ class ServingEngine:
         pf.idx += 1
         out = self.worker.chunk(
             width, toks, slot, start, live, pf.req.temperature,
-            pf.req.top_k, pf.req.top_p, fetch=pf.idx >= len(pf.segments))
+            pf.req.top_k, pf.req.top_p, fetch=pf.idx >= len(pf.segments),
+            uid=pf.req.uid)
         if out is None:
             # intermediate chunk: the sampled token is garbage (mid-prompt
             # logits) and deliberately NOT fetched — the chunk stays an
@@ -2250,10 +2288,22 @@ class ServingEngine:
     def _step_decode(self, wpos):
         """Advance every active slot ONE token through the decode program —
         the legacy (and speculation-off) device step."""
-        tm = self.telemetry
         nxt, bad = self.worker.decode(
             self._last_tok, self._pos, wpos, self._active,
             self._temp, self._top_k, self._top_p)
+        self._emit(self._emit_decoded, nxt, bad)
+
+    def _emit(self, bookkeeping, *fetched) -> None:
+        """The host's work after a device step's fetch, under one span:
+        token append, EOS/limit, ``_finish``, quarantine."""
+        with self.telemetry.span("emit") as sp:
+            done0 = len(self._terminal_uids)
+            sp.annotate(tokens=bookkeeping(*fetched),
+                        finished=len(self._terminal_uids) - done0)
+
+    def _emit_decoded(self, nxt, bad) -> int:
+        tm = self.telemetry
+        emitted = 0
         for slot in range(self.n_slots):
             if not self._active[slot]:
                 continue
@@ -2273,8 +2323,10 @@ class ServingEngine:
             st.remaining -= 1
             self._pos[slot] += 1
             self._last_tok[slot] = tok
+            emitted += 1
             if tok == st.eos or st.remaining <= 0:
                 self._finish(slot)  # records the uid in _terminal_uids
+        return emitted
 
     def _step_verify(self, drafts: dict[int, np.ndarray], wpos):
         """Advance every active slot up to ``bucket + 1`` tokens through ONE
@@ -2284,7 +2336,6 @@ class ServingEngine:
         share the step. Rejection "rollback" is positional: ``pos`` simply
         never advances past the accepted prefix + bonus token, and the
         rejected tail's stale KV is masked (causally) until overwritten."""
-        tm = self.telemetry
         bucket = _next_pow2(max(len(d) for d in drafts.values()))
         toks = np.zeros((self.n_slots, bucket + 1), np.int32)
         toks[:, 0] = self._last_tok
@@ -2298,6 +2349,11 @@ class ServingEngine:
             bucket, toks, self._pos, wpos, self._active,
             self._temp, self._top_k, self._top_p, greedy_only=greedy_only)
         self._spec_steps += 1
+        self._emit(self._emit_verified, drafts, accept, resample, clean, bad)
+
+    def _emit_verified(self, drafts, accept, resample, clean, bad) -> int:
+        tm = self.telemetry
+        total = 0
         for slot in range(self.n_slots):
             if not self._active[slot]:
                 continue
@@ -2371,8 +2427,10 @@ class ServingEngine:
                     finished = True
                     break
             tm.histogram("serving/spec_burst_tokens").observe(emitted)
+            total += emitted
             if finished:
                 self._finish(slot)
+        return total
 
     def step(self, now: float | None = None, *,
              enforce_deadlines: bool = True) -> list[int]:
@@ -2388,29 +2446,39 @@ class ServingEngine:
         drain's ``now=inf`` would otherwise expire everything."""
         if now is None:
             now = time.perf_counter() - self._epoch
+        self._steps += 1
+        with self.telemetry.span(
+                "serve/step", replica_id=self.replica_id, step=self._steps,
+                n_active=int(self._active.sum()), queue_len=len(self._queue)):
+            return self._step(now, enforce_deadlines)
+
+    def _step(self, now: float, enforce_deadlines: bool) -> list[int]:
         tm = self.telemetry
         self.worker.step_compiled = False  # fresh heartbeat window
-        self._maybe_sample_rings(now)
-        if self._incidents is not None and self._incidents.pending \
-                and math.isfinite(now):
-            self._incidents.tick(now, self._incident_context)
-        if enforce_deadlines:
-            if self._deadlines_armed:
-                self._sweep_deadlines(now)
-            # drain-mode (now=inf) exemption applies here too: it would
-            # treat every future-dated request as simultaneously arrived
-            # and shed a backlog that real-time stepping would have
-            # admitted one slot at a time
-            self._shed_overflow(now)
+        with tm.span("sweep"):
+            self._maybe_sample_rings(now)
+            if self._incidents is not None and self._incidents.pending \
+                    and math.isfinite(now):
+                self._incidents.tick(now, self._incident_context)
+            if enforce_deadlines:
+                if self._deadlines_armed:
+                    self._sweep_deadlines(now)
+                # drain-mode (now=inf) exemption applies here too: it would
+                # treat every future-dated request as simultaneously arrived
+                # and shed a backlog that real-time stepping would have
+                # admitted one slot at a time
+                self._shed_overflow(now)
         self._admit(now)
         tm.gauge("serving/queue_depth").set(len(self._queue))
         tm.gauge("serving/prefilling_slots").set(len(self._prefilling))
-        for _ in range(self.chunk_cfg.chunks_per_step):
-            if not self._prefilling:
-                break
-            slots = sorted(self._prefilling)
-            self._advance_prefill(slots[self._rr % len(slots)])
-            self._rr += 1
+        if self._prefilling:
+            with tm.span("chunks"):
+                for _ in range(self.chunk_cfg.chunks_per_step):
+                    if not self._prefilling:
+                        break
+                    slots = sorted(self._prefilling)
+                    self._advance_prefill(slots[self._rr % len(slots)])
+                    self._rr += 1
         if not self._active.any():
             # the occupancy gauge must read 0 once the engine idles — the
             # bench's slot-leak check watches exactly this
@@ -2442,40 +2510,9 @@ class ServingEngine:
         wpos = np.where(self._active, self._pos, np.int32(self.Smax))
         drafts: dict[int, np.ndarray] = {}
         if self._drafter is not None:
-            for slot in range(self.n_slots):
-                if not self._active[slot]:
-                    continue
-                st = self._slots[slot]
-                # a draft longer than ``remaining`` could never be fully
-                # emitted AND would write KV past the admission budget —
-                # the cap keeps every verify write inside the slot window.
-                # The adaptive per-slot cap (AIMD, see _spec_len) further
-                # clamps it so mispredicting slots draft shallow/cheap
-                cap = min(self.spec_cfg.depth, st.remaining,
-                          int(self._spec_len[slot]))
-                if cap < 1:
-                    if self._spec_len[slot] == 0 and st.remaining > 0:
-                        # suppressed slot: this decode step pays ZERO
-                        # drafting/verify overhead. Tick down the decaying
-                        # probe timer; when it expires, re-arm a depth-1
-                        # probe so a workload that BECOMES predictable can
-                        # climb back onto the AIMD ramp
-                        self._spec_suppressed_steps += 1
-                        self.telemetry.counter(
-                            "serving/spec_suppressed_steps").inc()
-                        self._spec_probe_wait[slot] -= 1
-                        if self._spec_probe_wait[slot] <= 0:
-                            self._spec_len[slot] = 1
-                            self._spec_probes += 1
-                            self.telemetry.counter(
-                                "serving/spec_probes").inc()
-                    continue
-                d = self._drafter.propose(
-                    np.concatenate([
-                        np.asarray(st.request.prompt, np.int32).reshape(-1),
-                        np.asarray(st.tokens, np.int32)]), cap)
-                if d.size:
-                    drafts[slot] = d
+            with tm.span("draft") as sp:
+                drafts = self._draft()
+                sp.annotate(slots=len(drafts))
         if drafts:
             self._step_verify(drafts, wpos)
         else:
@@ -2489,6 +2526,45 @@ class ServingEngine:
         finished = self._terminal_uids
         self._terminal_uids = []
         return finished
+
+    def _draft(self) -> dict[int, np.ndarray]:
+        """Each active slot's proposal for this step, where it has one."""
+        drafts: dict[int, np.ndarray] = {}
+        for slot in range(self.n_slots):
+            if not self._active[slot]:
+                continue
+            st = self._slots[slot]
+            # a draft longer than ``remaining`` could never be fully
+            # emitted AND would write KV past the admission budget —
+            # the cap keeps every verify write inside the slot window.
+            # The adaptive per-slot cap (AIMD, see _spec_len) further
+            # clamps it so mispredicting slots draft shallow/cheap
+            cap = min(self.spec_cfg.depth, st.remaining,
+                      int(self._spec_len[slot]))
+            if cap < 1:
+                if self._spec_len[slot] == 0 and st.remaining > 0:
+                    # suppressed slot: this decode step pays ZERO
+                    # drafting/verify overhead. Tick down the decaying
+                    # probe timer; when it expires, re-arm a depth-1
+                    # probe so a workload that BECOMES predictable can
+                    # climb back onto the AIMD ramp
+                    self._spec_suppressed_steps += 1
+                    self.telemetry.counter(
+                        "serving/spec_suppressed_steps").inc()
+                    self._spec_probe_wait[slot] -= 1
+                    if self._spec_probe_wait[slot] <= 0:
+                        self._spec_len[slot] = 1
+                        self._spec_probes += 1
+                        self.telemetry.counter(
+                            "serving/spec_probes").inc()
+                continue
+            d = self._drafter.propose(
+                np.concatenate([
+                    np.asarray(st.request.prompt, np.int32).reshape(-1),
+                    np.asarray(st.tokens, np.int32)]), cap)
+            if d.size:
+                drafts[slot] = d
+        return drafts
 
     def drain(self) -> dict[int, RequestResult]:
         """Run steps until queue and slots are empty (ignoring arrival
@@ -2639,12 +2715,19 @@ class ServingEngine:
             extra["fault_injection"] = self._inj.stats()
         if self.tracer is not None:
             extra["request_trace"] = self.tracer.events()
+        # the newest of this replica's ended spans, on perf_counter's clock;
+        # request_trace's times are seconds since ``epoch``, so ``epoch + t``
+        # puts an event beside them. (The whole ring: telemetry.tracing.spans)
+        mine = [sp for sp in ended_spans(self._t_built)
+                if sp.replica_id == self.replica_id]
+        extra["spans"] = [sp.as_dict() for sp in mine[-_SNAPSHOT_SPANS:]]
         if self._rings is not None:
             extra["rings"] = self._rings.snapshot()
         if self._incidents is not None:
             extra["incidents"] = self._incidents.index()
         snap = self.telemetry.snapshot(
             replica_id=self.replica_id,
+            epoch=self._epoch,
             compiles=self.compile_counts(),
             comm=comms_logger.summary(),
             hbm=hbm_snapshot(self.worker.hbm_pools(),

@@ -163,5 +163,6 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None, block_k: int = 
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(pos, *operands)
     return out

@@ -245,6 +245,7 @@ def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
         ],
         compiler_params=_compiler_params(len(grid)),
         interpret=interpret,
+        name="flash_fwd",
     )(*operands)
     return out, lse
 
@@ -398,6 +399,7 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
         scratch_shapes=[_scratch((block_k, D)), _scratch((block_k, D))],
         interpret=interpret,
         compiler_params=cp,
+        name="flash_bwd_dkdv",
     )(q, k, v, g, lse, delta, *extra_ops)
     dk, dv = dkdv
 
@@ -421,6 +423,7 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
         scratch_shapes=[_scratch((block_q, D))],
         interpret=interpret,
         compiler_params=cp,
+        name="flash_bwd_dq",
     )(q, k, v, g, lse, delta, *extra_ops)
     dslopes = jnp.zeros_like(slopes_bh) if has_slopes else None
     dw = jnp.zeros_like(w_arr) if has_window else None

@@ -1244,7 +1244,9 @@ class DeepSpeedEngine:
         grad_specs = self.opt_specs_for_params if self.zero_stage >= 2 else self.param_specs
         batch_spec = self.batch_spec
         apply_update = self._make_apply_update()
-        micro_grad = self._make_micro_grad(compute_dtype)
+        # stable names for a trace reader (jax.named_scope lands in each
+        # operation's name-scope stat): fwd_bwd / grad_clip / optimizer
+        micro_grad = jax.named_call(self._make_micro_grad(compute_dtype), name="fwd_bwd")
 
         dropout = self._dropout_enabled
         rng_seed = self._stochastics_seed
@@ -1356,13 +1358,14 @@ class DeepSpeedEngine:
                 finite = jax.device_put(finite, jax.memory.Space.Device)
                 gnorm = jax.device_put(gnorm, jax.memory.Space.Device)
             else:
-                grads = _tree_scale(grads, 1.0 / (loss_scale * gas))
-                flat = jax.tree.leaves(grads)
-                finite = jnp.all(jnp.stack([jnp.all(jnp.isfinite(g)) for g in flat]))
-                gnorm = _global_norm(grads)
-                if clip > 0:
-                    scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-                    grads = _tree_scale(grads, scale)
+                with jax.named_scope("grad_clip"):
+                    grads = _tree_scale(grads, 1.0 / (loss_scale * gas))
+                    flat = jax.tree.leaves(grads)
+                    finite = jnp.all(jnp.stack([jnp.all(jnp.isfinite(g)) for g in flat]))
+                    gnorm = _global_norm(grads)
+                    if clip > 0:
+                        scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                        grads = _tree_scale(grads, scale)
 
             step1 = state["step"] + 1
             lr = lr_schedule(step1)
@@ -1378,7 +1381,8 @@ class DeepSpeedEngine:
                     "overflow": ~finite,
                 }
                 return grads, metrics
-            new_params, new_opt, extras = apply_update(state, grads, finite, step1, lr)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt, extras = apply_update(state, grads, finite, step1, lr)
 
             # fp16 dynamic loss scaling (reference: runtime/fp16/loss_scaler.py
             # DynamicLossScaler): skip + hysteresis-gated halve on overflow,
@@ -1507,16 +1511,31 @@ class DeepSpeedEngine:
         boundary / monitor enabled). A synchronous per-step device_get stalls
         the host until the device drains — steps chain asynchronously
         instead, and overflow accounting catches up lazily.
+
+        The whole call is the ``train/train_batch`` span: the host's share of
+        a step (the compiled step is asynchronous, so dispatch-time by
+        default; device-accurate, blocking on the step's loss, when
+        ``telemetry.device_sync_spans`` is set), and ``train/step_time_sec``
+        is that span's own duration.
         """
-        self._resilience_pre_step()
+        tm = self.telemetry
+        with tm.span("train/train_batch", step=self.global_steps + 1) as sp:
+            metrics = self._train_batch(batch, sp)
+        tm.histogram("train/step_time_sec").observe(sp.dur_s)
+        return metrics
+
+    def _train_batch(self, batch: dict, step_span) -> dict:
+        tm = self.telemetry
+        with tm.span("pre"):
+            self._resilience_pre_step()
+            if self.curriculum_scheduler is not None and not self._nvme_offload:
+                batch = self._apply_curriculum(batch)  # the NVMe path applies its own
         if self._nvme_offload:
             return self._train_batch_nvme(batch)
         if self._onebit_cfg is not None:
             self._train_step = self._onebit_step_fn()
         elif self._train_step is None:
             self._train_step = self._build_train_step()
-        if self.curriculum_scheduler is not None:
-            batch = self._apply_curriculum(batch)
         wcb = self.config.wall_clock_breakdown
         self.tput_timer.start()
         if wcb:
@@ -1542,11 +1561,9 @@ class DeepSpeedEngine:
                 if sub in self.state
                 for path, leaf in jax.tree_util.tree_flatten_with_path(self.state[sub])[0]
             ]
-        with self.telemetry.span("train/train_batch") as _sp:
+        with tm.span("dispatch"):  # batch placement + enqueue
             self.state, metrics = self._train_step(self.state, batch)
-            # dispatch-time span by default; device-accurate (blocks on the
-            # step's loss) when telemetry.device_sync_spans is set
-            _sp.set_sync(metrics["loss"])
+        step_span.set_sync(metrics["loss"])
         if donation_probe is not None:
             self._donation_checked = True
             if self.config.debug.nan_check:
@@ -1600,9 +1617,10 @@ class DeepSpeedEngine:
                     ("Train/Samples/lr", float(metrics["lr"]), self.global_samples),
                 ]
             )
-        self._train_telemetry(batch, metrics if need_host else None, _sp.dur_s)
-        self._resilience_post_step(metrics)
-        self._snapshot_dl_cursor()
+        with tm.span("post"):
+            self._train_telemetry(batch, metrics if need_host else None)
+            self._resilience_post_step(metrics)
+            self._snapshot_dl_cursor()
         return metrics
 
     # ------------------------------------------------------------------
@@ -1715,7 +1733,7 @@ class DeepSpeedEngine:
                 "and no rewind target (save a checkpoint, or disable "
                 "resilience.rewind to keep skipping)")
 
-    def _train_telemetry(self, batch, metrics_host, step_dur: float) -> None:
+    def _train_telemetry(self, batch, metrics_host) -> None:
         """Per-step registry updates. Scalar gauges (loss/lr/grad-norm/scale)
         and device-memory watermarks update only on host boundaries
         (print/monitor steps) — between boundaries the step chain stays
@@ -1723,7 +1741,6 @@ class DeepSpeedEngine:
         flips are therefore boundary-sampled: flips between two boundaries
         collapse into one observed change."""
         tm = self.telemetry
-        tm.histogram("train/step_time_sec").observe(step_dur)
         tm.counter("train/steps").inc()
         tm.counter("train/samples").inc(self.train_batch_size)
         toks = batch.get("tokens") if isinstance(batch, dict) else None
@@ -1816,7 +1833,6 @@ class DeepSpeedEngine:
         if self.curriculum_scheduler is not None:
             batch = self._apply_curriculum(batch)
         self.tput_timer.start()
-        t_step = time.perf_counter()
         grads, metrics = self._train_step(self.state, batch)
         metrics = jax.device_get(metrics)
         overflow = bool(np.asarray(metrics["overflow"]))
@@ -1853,7 +1869,7 @@ class DeepSpeedEngine:
         )
         # the NVMe path is synchronous (per-step host Adam): metrics are
         # already on host, so the gauges update every step
-        self._train_telemetry(batch, metrics, time.perf_counter() - t_step)
+        self._train_telemetry(batch, metrics)
         self._resilience_post_step(metrics, overflow=overflow)
         self._snapshot_dl_cursor()
         return metrics

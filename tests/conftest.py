@@ -11,6 +11,15 @@ flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = flags + " --xla_force_host_platform_device_count=8"
 os.environ["JAX_PLATFORMS"] = "cpu"
+# XLA's CPU client sizes its one thread pool as max(NPROC or the core count,
+# device count), and an in-process collective holds a pool thread until all
+# eight devices have joined it. On a box with <= 8 cores, two independent
+# collectives that the devices enter in different orders (ZeRO-3's all-gather
+# and all-reduce do, under load) then hold every thread: a deadlock that XLA
+# ends by aborting the process after 40 s. Measured on 8 cores beside a busy
+# suite: test_offload_zero3_composes aborted in 2 of 12 runs without this and
+# in 0 of 24 with it.
+os.environ.setdefault("NPROC", "32")
 # NOTE: cache loads emit benign E-level "machine feature" lines (same-machine
 # AOT bookkeeping); pytest captures stderr per test, so they surface only on
 # failures — deliberately not suppressed (TF_CPP_MIN_LOG_LEVEL=3 would also

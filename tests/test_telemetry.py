@@ -19,6 +19,7 @@ compiled programs are already in tests/.xla_cache.
 """
 
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -101,11 +102,13 @@ def test_span_nesting_registry_and_jsonl_schema(tmp_path):
     reg = MetricsRegistry()
     sink = JsonlExporter(path)
     tr = SpanTracer(reg, sink)
+    before = time.perf_counter()
     with tr.span("serve"):
         with tr.span("step") as sp:
             sp.annotate(kind="decode")
         with tr.span("step"):
             pass
+    after = time.perf_counter()
     sink.close()
     events = [json.loads(line) for line in open(path)]
     assert [e["path"] for e in events] == ["serve/step", "serve/step", "serve"]
@@ -114,6 +117,13 @@ def test_span_nesting_registry_and_jsonl_schema(tmp_path):
     assert inner["depth"] == 1 and inner["kind"] == "decode"
     assert {"t", "start_s", "dur_s"} <= set(inner)
     assert events[2]["depth"] == 0
+    # start_s is on time.perf_counter()'s clock (the harness's, RequestResult's
+    # and set_epoch's), not relative to a tracer-private epoch; ids link the tree
+    assert all(before - 1e-6 <= e["start_s"] <= e["start_s"] + e["dur_s"] <= after + 1e-6
+               for e in events)
+    assert events[2]["parent"] is None
+    assert events[0]["parent"] == events[1]["parent"] == events[2]["id"]
+    assert not hasattr(tr, "_epoch")
     # nesting feeds slash-joined registry histograms; parent covers children
     snap = reg.snapshot()["histograms"]
     assert snap["span/serve/step"]["count"] == 2

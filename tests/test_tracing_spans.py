@@ -1,0 +1,491 @@
+"""The program's one span system (telemetry/tracing.py) and what reads it.
+
+Contracts under test:
+  * every ended span is one record of a bounded process-wide ring: id,
+    parent, path, attributes, ``t0 <= t1`` on ``time.perf_counter()``'s clock;
+    a span that raises is still recorded and leaves the nesting intact;
+  * ``ServingEngine.step`` leaves one ``serve/step`` per iteration whose
+    children nest as PERF.md's span table says, one ``dispatch`` + ``fetch``
+    under each worker call, request-labelled prefill/chunk spans, and the
+    latency histograms are fed from exactly the spans that did not compile;
+  * ``train_batch`` leaves ``train/train_batch`` with ``pre`` / ``dispatch``
+    / ``post``;
+  * each per-layer reader under chipbench/layer_metrics/ that reads the ring
+    returns the hand-computed value on a hand-made ring and None on an empty
+    one, and ``python3 -m chipbench.selftest`` holds.
+
+Models stay tiny and reuse the session's serving config, so the compiled
+programs are already in tests/.xla_cache.
+"""
+
+import importlib
+import json
+import os
+import subprocess
+import sys
+import threading
+import time
+from types import SimpleNamespace
+
+import numpy as np
+import pytest
+
+from chipbench.layer_metrics.span_ring import WORKER_CALLS
+from deepspeed_tpu.telemetry import MetricsRegistry, SpanTracer, tracing
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ---------------------------------------------------------------------------
+# the ring
+# ---------------------------------------------------------------------------
+
+def _ring_fields():
+    tr = SpanTracer(MetricsRegistry())
+    before = time.perf_counter()
+    with tr.span("outer", replica_id=7, kind="x") as outer:
+        with tr.span("inner") as inner:
+            inner.annotate(n=3)
+    after = time.perf_counter()
+    got = tracing.spans(before)
+    assert [sp.path for sp in got] == ["outer/inner", "outer"]  # the order they ended
+    rec_in, rec_out = got
+    assert rec_in is inner and rec_out is outer  # the ring holds the span itself
+    assert rec_out.parent is None and rec_in.parent == rec_out.id != rec_in.id
+    assert rec_out.attrs == {"kind": "x"} and rec_in.attrs == {"n": 3}
+    assert rec_in.replica_id == 7  # inherited from the enclosing span
+    assert before <= rec_out.t0 <= rec_in.t0 <= rec_in.t1 <= rec_out.t1 <= after
+    assert rec_in.dur_s == rec_in.t1 - rec_in.t0
+    assert rec_in._sync is None and rec_in._ann is None and rec_in._tracer is None
+    assert rec_in.as_dict() == {"id": rec_in.id, "parent": rec_out.id, "path": "outer/inner",
+                                "t0": rec_in.t0, "t1": rec_in.t1, "replica_id": 7, "n": 3}
+
+
+def _ring_bounded():
+    tr = SpanTracer(MetricsRegistry())
+    tracing.clear_spans()
+    assert tracing.spans() == []
+    for i in range(tracing.RING_CAPACITY + 5):
+        with tr.span("s", i=i):
+            pass
+    got = tracing.spans()
+    assert len(got) == tracing.RING_CAPACITY == 65_536
+    assert got[0].attrs["i"] == 5 and got[-1].attrs["i"] == tracing.RING_CAPACITY + 4
+    mid = got[1000].t0
+    assert len(tracing.spans(mid)) == tracing.RING_CAPACITY - 1000  # t0 >= since
+    tracing.clear_spans()
+    assert tracing.spans() == []
+
+
+def _ring_survives_raise():
+    reg = MetricsRegistry()
+    tr = SpanTracer(reg)
+    since = time.perf_counter()
+    with pytest.raises(ValueError):
+        with tr.span("outer"):
+            with tr.span("boom"):
+                raise ValueError("x")
+    with tr.span("after"):
+        pass
+    got = {sp.path: sp for sp in tracing.spans(since)}
+    assert set(got) == {"outer/boom", "outer", "after"}  # the stack unwound
+    assert got["outer/boom"].attrs["error"] == "ValueError"
+    assert got["after"].parent is None and "error" not in got["after"].attrs
+    # a span that raised is in the ring but is no latency datum
+    assert set(reg.snapshot()["histograms"]) == {"span/after"}
+
+
+def _ring_histogram_handle_once():
+    reg = MetricsRegistry()
+    tr = SpanTracer(reg)
+    for _ in range(50):
+        with tr.span("a"):
+            with tr.span("b"):
+                pass
+    assert set(tr._hists) == {"a", "a/b"}
+    assert tr._hists["a/b"] is reg.histogram("span/a/b")
+    assert reg.snapshot()["histograms"]["span/a/b"]["count"] == 50
+
+
+def _ring_parent_is_per_thread():
+    tr = SpanTracer(MetricsRegistry())
+    since = time.perf_counter()
+    seen = {}
+
+    def other():
+        with tr.span("other") as sp:
+            seen["other"] = sp
+
+    with tr.span("main"):
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+    assert seen["other"].parent is None and seen["other"].path == "other"
+    assert {sp.path for sp in tracing.spans(since)} == {"main", "other"}
+
+
+def _ring_threads_lose_nothing():
+    """More writers than cores, one reader, a short switch interval: every
+    span is recorded once, under an id of its own."""
+    tr = SpanTracer(MetricsRegistry())
+    workers, each = 2 * (os.cpu_count() or 4), 300
+    since = time.perf_counter()
+    stop = threading.Event()
+
+    def write(k):
+        for i in range(each):
+            with tr.span("w", k=k, i=i):
+                with tr.span("x"):
+                    pass
+
+    def read():
+        while not stop.is_set():
+            tracing.spans(since)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        reader = threading.Thread(target=read)
+        threads = [threading.Thread(target=write, args=(k,)) for k in range(workers)]
+        reader.start()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        stop.set()
+        reader.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not reader.is_alive() and not any(t.is_alive() for t in threads)
+    got = tracing.spans(since)
+    outer = [sp for sp in got if sp.path == "w"]
+    assert len(got) == 2 * workers * each and len({sp.id for sp in got}) == len(got)
+    assert sorted((sp.attrs["k"], sp.attrs["i"]) for sp in outer) == [
+        (k, i) for k in range(workers) for i in range(each)]
+    by_id = {sp.id: sp for sp in got}
+    assert all(by_id[sp.parent].path == "w" for sp in got if sp.path == "w/x")
+
+
+@pytest.mark.parametrize("case", [_ring_fields, _ring_bounded, _ring_survives_raise,
+                                  _ring_histogram_handle_once, _ring_parent_is_per_thread,
+                                  _ring_threads_lose_nothing],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_ring(case):
+    case()
+
+
+# ---------------------------------------------------------------------------
+# spans where the work happens: serving
+# ---------------------------------------------------------------------------
+
+SERVING = {
+    "plain": {},
+    "chunked": {"chunked_prefill": {"enabled": True, "chunk_size": 16}},
+    "prefix": {"prefix_cache": {"enabled": True, "n_slots": 8, "block": 8}},
+    "speculation": {"speculation": {"enabled": True, "depth": 4}},
+}
+
+# the name of the span each span may sit under (None: the root)
+PARENTS = {
+    "serve/step": {None},
+    "sweep": {"serve/step"}, "admit": {"serve/step"}, "chunks": {"serve/step"},
+    "draft": {"serve/step"}, "emit": {"serve/step"},
+    "decode": {"serve/step"}, "verify": {"serve/step"},
+    "prefill": {"admit"}, "chunk": {"chunks", "admit"},
+    "dispatch": set(WORKER_CALLS), "fetch": set(WORKER_CALLS),
+}
+
+
+def _serve(engine, mode):
+    from deepspeed_tpu.inference import ServingEngine
+    from deepspeed_tpu.inference.serving import Request
+
+    srv = ServingEngine(engine, {"request_trace": {"enabled": True}}, n_slots=2,
+                        max_seq_len=128, replica_id=f"t-{mode}", **SERVING[mode])
+    rng = np.random.default_rng(3)
+    lens = (5, 40, 9, 23, 40)
+    prompts = [rng.integers(0, 97, size=n).astype(np.int32) for n in lens]
+    if mode == "prefix":
+        prompts[4] = prompts[1].copy()  # a whole-prompt repeat: a prefix hit
+    if mode == "speculation":
+        prompts = [np.tile(p[:4], 10)[:len(p)] for p in prompts]  # n-gram drafts match
+    reqs = [Request(uid=100 + i, prompt=p, max_new_tokens=3 + i) for i, p in enumerate(prompts)]
+    since = time.perf_counter()
+    results = srv.serve(reqs)
+    assert all(r.ok for r in results.values())
+    mine = [sp for sp in tracing.spans(since) if sp.replica_id == srv.replica_id]
+    return srv, reqs, mine
+
+
+@pytest.mark.parametrize("mode", list(SERVING))
+def test_serving_spans(tiny_serving_engine, mode):
+    srv, reqs, spans = _serve(tiny_serving_engine, mode)
+    by_id = {sp.id: sp for sp in spans}
+    kids = {}
+    for sp in spans:
+        kids.setdefault(sp.parent, []).append(sp)
+    hist = srv.telemetry.registry.snapshot()["histograms"]
+    counters = srv.telemetry.registry.snapshot()["counters"]
+
+    # every scheduler iteration is one serve/step, numbered, and everything nests
+    # as the table says, on one clock, inside its parent
+    steps = [sp for sp in spans if sp.path == "serve/step"]
+    assert [sp.attrs["step"] for sp in steps] == list(range(1, len(steps) + 1))
+    assert all({"n_active", "queue_len"} <= set(sp.attrs) for sp in steps)
+    for sp in spans:
+        up = by_id.get(sp.parent)
+        assert (up.name if up else None) in PARENTS[sp.name], sp.path
+        assert sp.t0 <= sp.t1
+        if up is not None:
+            assert sp.path == f"{up.path}/{sp.name}" and up.t0 <= sp.t0 and sp.t1 <= up.t1
+    for st in steps:
+        names = [c.name for c in kids.get(st.id, [])]
+        assert names[:2] == ["sweep", "admit"] and names.count("sweep") == 1
+        assert names.count("decode") + names.count("verify") <= 1
+        # host bookkeeping follows every device step, and only a device step
+        assert names.count("emit") == names.count("decode") + names.count("verify")
+
+    # one dispatch + one fetch under each worker call (a chunk left asynchronous
+    # has no fetch), in that order
+    calls = [sp for sp in spans if sp.name in WORKER_CALLS]
+    for sp in calls:
+        under = [c.name for c in kids.get(sp.id, [])]
+        want = ["dispatch"] if sp.attrs.get("fetch") is False else ["dispatch", "fetch"]
+        assert under == want, (sp.path, under)
+        assert isinstance(sp.attrs["compiled"], bool)
+
+    # the latency histograms hold exactly the calls that did not compile
+    def timed(kind):
+        return [sp for sp in calls if sp.name == kind and not sp.attrs["compiled"]
+                and sp.attrs.get("fetch") is not False]
+
+    for kind, name in (("decode", "serving/decode_step_sec"), ("prefill", "serving/prefill_sec"),
+                       ("verify", "serving/verify_step_sec"),
+                       ("chunk", "serving/chunk_prefill_sec")):
+        assert hist.get(name, {"count": 0})["count"] == len(timed(kind)), name
+        if timed(kind):
+            np.testing.assert_allclose(hist[name]["sum"], sum(sp.dur_s for sp in timed(kind)))
+    decodes = [sp for sp in calls if sp.name == "decode"]
+    assert len(decodes) == counters.get("serving/decode_steps", 0)
+    assert all(1 <= sp.attrs["n_active"] <= 2 for sp in decodes)
+    emitted = sum(sp.attrs["tokens"] for sp in spans if sp.name == "emit")
+    finished = sum(sp.attrs["finished"] for sp in spans if sp.name == "emit")
+    # the first token of a request comes from its prefill, the rest from emit
+    assert emitted == sum(r.max_new_tokens - 1 for r in reqs) and finished == len(reqs)
+    assert sum(sp.attrs["admitted"] for sp in spans if sp.name == "admit") == len(reqs)
+
+    # a request's spans carry its uid and meet the request trace on one clock
+    prompt_len = {r.uid: len(r.prompt) for r in reqs}
+    prefills = [sp for sp in calls if sp.name == "prefill"]
+    chunks = [sp for sp in calls if sp.name == "chunk"]
+    for sp in prefills:
+        n = prompt_len[sp.attrs["uid"]]
+        assert sp.attrs["true_len"] == n and sp.attrs["bucket"] == srv._bucket_len(n)
+        assert sp.attrs["slot"] in (0, 1)
+    for sp in chunks:
+        assert sp.attrs["uid"] in prompt_len and 1 <= sp.attrs["live"] <= sp.attrs["width"]
+    snap = srv.telemetry_snapshot()
+    assert snap["epoch"] == srv._epoch and snap["replica_id"] == srv.replica_id
+    assert [d["id"] for d in snap["spans"]] == [sp.id for sp in spans][-len(snap["spans"]):]
+    json.dumps(snap["spans"])  # plain data
+    events = {(e["uid"], e["event"]): snap["epoch"] + e["t"] for e in snap["request_trace"]}
+    last_call = {}
+    for sp in prefills + chunks:
+        last_call[sp.attrs["uid"]] = sp
+    assert set(last_call) == set(prompt_len)
+    for uid, sp in last_call.items():
+        first_call = min((c for c in prefills + chunks if c.attrs["uid"] == uid),
+                         key=lambda c: c.t0)
+        assert events[uid, "admitted"] <= first_call.t0
+        assert sp.t1 <= events[uid, "first_token"]
+
+    if mode == "plain":
+        assert {sp.name for sp in spans} == {"serve/step", "sweep", "admit", "prefill", "decode",
+                                             "dispatch", "fetch", "emit"}
+        assert len(prefills) == len(reqs) and not chunks
+    if mode == "chunked":
+        assert not prefills and {by_id[sp.parent].name for sp in chunks} == {"chunks"}
+        assert any(sp.attrs["fetch"] is False for sp in chunks)
+        assert sum(sp.attrs["live"] for sp in chunks) == sum(prompt_len.values())
+    if mode == "prefix":
+        # the repeated prompt's suffix runs through the chunk path inside admit
+        assert {by_id[sp.parent].name for sp in chunks} == {"admit"}
+        assert {sp.attrs["uid"] for sp in chunks} == {104}
+    if mode == "speculation":
+        verifies = [sp for sp in calls if sp.name == "verify"]
+        drafts = [sp for sp in spans if sp.name == "draft"]
+        assert verifies and all(sp.attrs["depth"] in (1, 2, 4) for sp in verifies)
+        assert len(drafts) == len(decodes) + len(verifies)
+        assert sum(sp.attrs["slots"] > 0 for sp in drafts) == len(verifies)
+
+
+def test_worker_call_outside_step_has_a_short_path(tiny_serving_engine):
+    from deepspeed_tpu.inference import ServingEngine
+
+    srv = ServingEngine(tiny_serving_engine, n_slots=2, max_seq_len=128,
+                        speculation={"enabled": True, "depth": 2})
+    since = time.perf_counter()
+    srv.warm_verify()
+    got = tracing.spans(since)
+    assert {sp.path for sp in got} == {"verify", "verify/dispatch", "verify/fetch"}
+    assert all(sp.attrs["warm"] for sp in got if sp.path == "verify")
+    hist = srv.telemetry.registry.snapshot()["histograms"]
+    assert "serving/verify_step_sec" not in hist  # a warm call is no latency datum
+
+
+# ---------------------------------------------------------------------------
+# spans where the work happens: training
+# ---------------------------------------------------------------------------
+
+def test_train_batch_spans():
+    import deepspeed_tpu
+    from simple_model import base_config, random_tokens, tiny_transformer
+
+    cfg = base_config()
+    cfg["mesh"] = {"data": -1}
+    engine, _, _, _ = deepspeed_tpu.initialize(model=tiny_transformer(), config=cfg)
+    batch = random_tokens(16)
+    since = time.perf_counter()
+    for _ in range(3):
+        engine.train_batch(batch)
+    spans = tracing.spans(since)
+    steps = [sp for sp in spans if sp.path == "train/train_batch"]
+    assert [sp.attrs["step"] for sp in steps] == [1, 2, 3] and all(s.parent is None for s in steps)
+    for st in steps:
+        kids = sorted((sp for sp in spans if sp.parent == st.id), key=lambda sp: sp.t0)
+        assert [k.path for k in kids] == [f"train/train_batch/{n}"
+                                          for n in ("pre", "dispatch", "post")]
+        assert st.t0 <= kids[0].t0 and kids[-1].t1 <= st.t1
+        assert all(a.t1 <= b.t0 for a, b in zip(kids, kids[1:]))
+    hist = engine.telemetry.registry.snapshot()["histograms"]
+    assert hist["train/step_time_sec"]["count"] == 3
+    np.testing.assert_allclose(hist["train/step_time_sec"]["sum"], sum(s.dur_s for s in steps))
+    assert hist["span/train/train_batch/dispatch"]["count"] == 3
+
+
+# ---------------------------------------------------------------------------
+# the per-layer readers of the ring (chipbench/layer_metrics/)
+# ---------------------------------------------------------------------------
+
+def _sp(id, parent, path, t0, t1, **attrs):
+    return SimpleNamespace(id=id, parent=parent, name=path.rsplit("/", 1)[-1] if path not in (
+        "serve/step", "train/train_batch") else path, path=path, t0=t0, t1=t1, attrs=attrs,
+        replica_id=0)
+
+
+def _call(id, parent, path, t0, t_disp, t1, **attrs):
+    """A worker call with its dispatch [t0, t_disp] and fetch [t_disp, t1]."""
+    return [_sp(id, parent, path, t0, t1, compiled=False, **attrs),
+            _sp(id + 1, id, path + "/dispatch", t0, t_disp),
+            _sp(id + 2, id, path + "/fetch", t_disp, t1)]
+
+
+E = 1000.0  # the serving loop's epoch; its window is [10, 20) on the loop's clock
+S = "serve/step"
+HAND_SERVE = [
+    # a warm-up decode outside step(), before the window: its fetch ends 5 ms
+    # before the window opens, so 4 ms of the gap to the next call lie inside
+    *_call(90, None, "decode", E + 9.940, E + 9.942, E + 9.995, n_active=8),
+    # step A, 60 ms: decode 54 ms (dispatch 2); self 6
+    _sp(1, None, S, E + 10.000, E + 10.060, step=1),
+    _sp(2, 1, S + "/sweep", E + 10.000, E + 10.001),
+    _sp(3, 1, S + "/admit", E + 10.001, E + 10.002, admitted=0),
+    *_call(4, 1, S + "/decode", E + 10.002, E + 10.004, E + 10.056, n_active=8),
+    _sp(7, 1, S + "/emit", E + 10.056, E + 10.058, tokens=8, finished=1),
+    # step B, 168 ms: prefill 102 ms (dispatch 3) under admit, decode 52 ms
+    # (dispatch 3); self 14
+    _sp(8, None, S, E + 10.062, E + 10.230, step=2),
+    _sp(9, 8, S + "/sweep", E + 10.062, E + 10.063),
+    _sp(10, 8, S + "/admit", E + 10.063, E + 10.167, admitted=1),
+    *_call(11, 10, S + "/admit/prefill", E + 10.064, E + 10.067, E + 10.166,
+           uid=5, slot=0, bucket=2048, true_len=1024),
+    *_call(14, 8, S + "/decode", E + 10.168, E + 10.171, E + 10.220, n_active=8),
+    _sp(17, 8, S + "/emit", E + 10.220, E + 10.224, tokens=8, finished=0),
+    # step D, 110 ms: prefill 102 ms (dispatch 2), no decode; self 8
+    _sp(22, None, S, E + 10.232, E + 10.342, step=3),
+    _sp(23, 22, S + "/admit", E + 10.232, E + 10.337, admitted=1),
+    *_call(24, 23, S + "/admit/prefill", E + 10.233, E + 10.235, E + 10.335,
+           uid=6, slot=1, bucket=2048, true_len=1536),
+    # step C: its decode compiled (498 ms): no median sees it, nor its step
+    _sp(18, None, S, E + 10.350, E + 10.850, step=4),
+    _sp(19, 18, S + "/decode", E + 10.351, E + 10.849, n_active=8, compiled=True),
+    _sp(20, 19, S + "/decode/dispatch", E + 10.351, E + 10.353),
+    _sp(21, 19, S + "/decode/fetch", E + 10.353, E + 10.849),
+    # after the window: not counted
+    *_call(40, None, "decode", E + 20.500, E + 20.501, E + 20.510, n_active=1),
+]
+T = "train/train_batch"
+HAND_TRAIN = [  # t_start 500 + t_setup 100: the counted steps are [600.0, 602.8]
+    _sp(1, None, T, 598.0, 598.9, step=1),  # warm-up (it compiled): before the window
+    _sp(2, None, T, 600.001, 600.004, step=3),
+    _sp(3, 2, T + "/dispatch", 600.002, 600.003),
+    _sp(4, None, T, 601.401, 601.406, step=4),
+    _sp(5, None, T, 602.801, 602.803, step=5),  # begun after the last counted step
+]
+# gaps (ms): 4 (the lead-in call's, clipped) + 11 (A->prefill) + 5 (prefill->decode B)
+# + 15 (decode B->prefill D) + 18 (prefill D->compiled decode); window 10 s
+READERS = {
+    "decode_prog_ms_p50": 53.0, "prefill_prog_ms_p50": 102.0, "decode_dispatch_ms_p50": 2.5,
+    "prefill_padding_pct": 37.5, "sched_host_ms_p50": 8.0,
+    "serve_host_gap_pct": 100.0 * 0.053 / 10.0, "train_host_ms_p50": 4.0,
+}
+
+
+def _ctx(notes):
+    run = SimpleNamespace(t_start=500.0, note=lambda **kw: notes.append(kw))
+    return {"serve": {"window": (10.0, 20.0), "epoch": E, "traced": (10.0, 10.1)},
+            "train": {"steps": [(0.0, 1.4), (1.4, 2.8)]}, "t_setup": 100.0, "trace": None,
+            "run": run}
+
+
+def _patch_ring(monkeypatch, records):
+    monkeypatch.setattr(tracing, "spans",
+                        lambda since=float("-inf"): [sp for sp in records if sp.t0 >= since])
+
+
+@pytest.mark.parametrize("name", list(READERS))
+def test_reader_on_a_hand_made_ring(monkeypatch, name):
+    reader = importlib.import_module(f"chipbench.layer_metrics.{name}")
+    _patch_ring(monkeypatch, HAND_SERVE + HAND_TRAIN)
+    notes = []
+    np.testing.assert_allclose(reader.read(_ctx(notes)), READERS[name], rtol=1e-9)
+    if name == "serve_host_gap_pct":
+        (note,) = notes
+        assert note["event"] == "host_gaps"
+        by = note["window"]["by_span"]
+        np.testing.assert_allclose(sum(by.values()), note["window"]["gap_s"])
+        np.testing.assert_allclose(note["window"]["gap_s"], 0.053)
+        # A's emit 2 + B's 4 (the gap to D begins at B's fetch end); between steps 2 + 2 + 8
+        np.testing.assert_allclose([by["emit"], by["outside"], by["sweep"]],
+                                   [0.006, 0.012, 0.002], atol=1e-9)
+        np.testing.assert_allclose(by["dispatch"], 0.002 + 0.003 + 0.003 + 0.002 + 0.002)
+        # the traced sub-window [10.0, 10.1): the lead-in's 4 ms + the 11 ms gap
+        np.testing.assert_allclose(note["traced"]["pct"], 15.0)
+        assert note["traced"]["device_idle_pct"] is None  # no trace in this ctx
+
+
+@pytest.mark.parametrize("name", list(READERS))
+def test_reader_on_an_empty_ring(monkeypatch, name):
+    reader = importlib.import_module(f"chipbench.layer_metrics.{name}")
+    notes = []
+    _patch_ring(monkeypatch, [])
+    assert reader.read(_ctx(notes)) is None and not notes
+    # a program without the ring (the parent of PR 24): nothing to read either
+    monkeypatch.delattr(tracing, "spans")
+    assert reader.read(_ctx(notes)) is None
+    # and the other kind of cell has no such block
+    monkeypatch.undo()
+    _patch_ring(monkeypatch, HAND_SERVE + HAND_TRAIN)
+    ctx = _ctx(notes)
+    ctx["serve" if name != "train_host_ms_p50" else "train"] = None
+    assert reader.read(ctx) is None
+
+
+def test_chipbench_selftest():
+    """The yardstick's own checks: every per_layer entry of BENCHMARK.json
+    against its reader file, the trace reduction, flops, traffic."""
+    proc = subprocess.run([sys.executable, "-m", "chipbench.selftest"], cwd=ROOT,
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
+                          text=True, timeout=300)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "FAIL" not in proc.stdout and proc.stdout.count("ok ") == 6
