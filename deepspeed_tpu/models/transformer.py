@@ -942,9 +942,11 @@ def _moe_layer(cfg, lp, moe_p, x, attn_fn, bias, positions, local_bias=None):
 # The reference's decode path is the fused `softmax_context` CUDA kernel with
 # an incremental KV cache (csrc/transformer/inference/csrc/pt_binding.cpp:
 # softmax_context_* :1237, attention-with-cache). TPU-native: the cache is a
-# static-shape [L, B, Smax, H, Dh] pair threaded through the layer scan; one
-# `apply_with_cache` function serves both prefill (T = prompt len, pos = 0)
-# and decode (T = 1) so XLA compiles exactly two programs per sequence budget.
+# static-shape [L, B, Smax, H, Dh] pair that stays ONE buffer through the
+# layer scan (its carry: layer l writes its new rows at [l, row, pos] and
+# attends to layer l of the stack where it lies); one `apply_with_cache`
+# function serves both prefill (T = prompt len, pos = 0) and decode (T = 1)
+# so XLA compiles exactly two programs per sequence budget.
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int, dtype=None):
     """Allocate an empty KV cache for ``batch`` sequences of up to ``max_len``."""
@@ -1057,11 +1059,11 @@ def apply_with_cache(
         positions = pos + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
     x, _ = embed(cfg, params, tokens, positions)
 
+    Smax = cache["k"].shape[2]
     bias = None
     if cfg.pos_emb == "alibi":
         # alibi distances vs absolute key positions, rows = new tokens
         slopes = alibi_slopes(cfg.num_heads)
-        Smax = cache["k"].shape[2]
         if vector_pos:
             dist = jnp.arange(Smax)[None, None, :] - positions[:, :, None]  # [B,T,Smax]
             bias = (slopes[None, :, None, None] * dist[:, None]).astype(jnp.float32)
@@ -1085,31 +1087,49 @@ def apply_with_cache(
             write_positions = (jnp.asarray(write_pos, jnp.int32)[:, None]
                                + jnp.arange(T)[None, :])
 
-        def _write_cache(c, new):
-            # per-row scatter: row b's block lands at [write_pos[b], +T).
-            # mode="drop" is load-bearing: the serving engine passes
-            # write_pos=Smax for inactive/prefilling slots so their garbage
-            # write is DISCARDED here — a mid-admission slot already holds
-            # prefix KV at the low positions, so no in-range parking spot
-            # is safe
-            return c.at[_rows, write_positions].set(new.astype(c.dtype), mode="drop")
+        def _write_cache(c, l, new):
+            # per-row scatter into layer l of the stack: row b's block lands
+            # at [write_pos[b], +T). mode="drop" is load-bearing: the serving
+            # engine passes write_pos=Smax for inactive/prefilling slots so
+            # their garbage write is DISCARDED here — a mid-admission slot
+            # already holds prefix KV at the low positions, so no in-range
+            # parking spot is safe
+            return c.at[l, _rows, write_positions].set(new.astype(c.dtype), mode="drop")
     else:
         if write_pos is not None:
             raise ValueError("write_pos requires a per-row pos vector")
 
-        def _write_cache(c, new):
-            return lax.dynamic_update_slice(c, new.astype(c.dtype), (0, pos, 0, 0))
+        def _write_cache(c, l, new):
+            return lax.dynamic_update_slice(c, new[None].astype(c.dtype), (l, 0, pos, 0, 0))
 
-    def layer_core(x, lp, k_cache, v_cache, ffn_fn):
+    # A lock-step block as long as the cache (serving prefill: a local cache
+    # of the bucket's length) IS the layer's cache once written, so attention
+    # reads the block itself and XLA keeps QK^T and the softmax in one fusion;
+    # read back through the stack, Pythia's 2048-token prefill took 103 ms on
+    # the chip instead of 73 (PERF.md §6, PR 25).
+    fills_cache = not vector_pos and T == Smax
+
+    # The stacked [L, B, Smax, H, Dh] cache is the layer loop's CARRY: layer l
+    # writes only its new rows into it and attends to a view of it, so with
+    # the cache donated the loop's input and output are one buffer. As the
+    # scan's xs/ys it would be sliced out and restacked layer by layer and
+    # copied whole to reconcile the two (tests/test_chip_compile.py guards it).
+    def layer_core(carry, lp, l, ffn_fn):
+        x, k_stack, v_stack = carry
         lp = _dequant_layer(cfg, lp)
         h = layer_norm(x, lp["ln1_scale"], lp["ln1_bias"], cfg.layernorm_epsilon)
         q, k, v = _qkv_proj(cfg, lp, h, positions)
-        k_cache = _write_cache(k_cache, k)
-        v_cache = _write_cache(v_cache, v)
+        k_stack = _write_cache(k_stack, l, k)
+        v_stack = _write_cache(v_stack, l, v)
         if use_decode_kernel:
-            attn = decode_attention(q[:, 0], k_cache, v_cache, pos)[:, None]
+            attn = decode_attention(q[:, 0], k_stack, v_stack, pos, layer=l)[:, None]
         else:
-            attn = cached_attention(q, k_cache, v_cache, pos, bias=bias)
+            if fills_cache:
+                k_l, v_l = k.astype(k_stack.dtype), v.astype(v_stack.dtype)
+            else:
+                k_l, v_l = (lax.dynamic_index_in_dim(c, l, keepdims=False)
+                            for c in (k_stack, v_stack))
+            attn = cached_attention(q, k_l, v_l, pos, bias=bias)
         attn_out = _attn_out_proj(cfg, lp, attn)
         if cfg.parallel_residual:
             h2 = layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.layernorm_epsilon)
@@ -1118,24 +1138,20 @@ def apply_with_cache(
             x = x + attn_out
             h2 = layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.layernorm_epsilon)
             x = x + ffn_fn(lp, h2)
-        return x, k_cache, v_cache
+        return x, k_stack, v_stack
 
     def layer(carry, inputs):
-        x = carry
-        lp, k_cache, v_cache = inputs
-        x, k_cache, v_cache = layer_core(
-            x, load_layer(lp), k_cache, v_cache, lambda lp, h2: _ffn(cfg, lp, h2)
-        )
-        return x, (k_cache, v_cache)
+        lp, l = inputs
+        return layer_core(carry, load_layer(lp), l, lambda lp, h2: _ffn(cfg, lp, h2)), None
 
+    carry = (x, cache["k"], cache["v"])
+    layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     if cfg.moe_every > 0:
         from ..moe.layer import moe_ffn_apply, moe_ffn_dense
 
         E = cfg.moe_every
         G = cfg.num_layers // E
         regroup = lambda a: a.reshape((G, E) + a.shape[1:])
-        layers_g = jax.tree.map(regroup, layers_xs)
-        kc_g, vc_g = regroup(cache["k"]), regroup(cache["v"])
         # decode (T=1): capacity-free routing — the capacity heuristic
         # degenerates to ~1 slot at single-token steps and drops colliding
         # tokens; prefill keeps training's GShard capacity semantics
@@ -1145,30 +1161,20 @@ def apply_with_cache(
             moe_fn = lambda moe_p, h2: moe_ffn_apply(cfg, moe_p, h2, mesh=_ACTIVE_MESH[0])[0]
 
         def group_layer(carry, xs):
-            x = carry
-            lg, moe_p, kc, vc = xs
+            # E-1 dense layers, then the group's MoE layer, all on the carry
+            lg, moe_p, ids = xs
             if E > 1:
                 firsts = jax.tree.map(lambda a: a[: E - 1], lg)
-                x, (kc_head, vc_head) = lax.scan(layer, x, (firsts, kc[: E - 1], vc[: E - 1]))
+                carry, _ = lax.scan(layer, carry, (firsts, ids[: E - 1]))
             lp_last = load_layer(jax.tree.map(lambda a: a[E - 1], lg))
-            x, kc_last, vc_last = layer_core(
-                x, lp_last, kc[E - 1], vc[E - 1],
-                lambda lp, h2: moe_fn(load_moe(moe_p), h2),
-            )
-            if E > 1:
-                kc_new = jnp.concatenate([kc_head, kc_last[None]], axis=0)
-                vc_new = jnp.concatenate([vc_head, vc_last[None]], axis=0)
-            else:
-                kc_new, vc_new = kc_last[None], vc_last[None]
-            return x, (kc_new, vc_new)
+            return layer_core(carry, lp_last, ids[E - 1],
+                              lambda lp, h2: moe_fn(load_moe(moe_p), h2)), None
 
-        x, (new_k_g, new_v_g) = lax.scan(
-            group_layer, x, (layers_g, moe_xs, kc_g, vc_g)
-        )
-        new_k = new_k_g.reshape((cfg.num_layers,) + new_k_g.shape[2:])
-        new_v = new_v_g.reshape((cfg.num_layers,) + new_v_g.shape[2:])
+        (x, new_k, new_v), _ = lax.scan(
+            group_layer, carry,
+            (jax.tree.map(regroup, layers_xs), moe_xs, regroup(layer_ids)))
     else:
-        x, (new_k, new_v) = lax.scan(layer, x, (layers_xs, cache["k"], cache["v"]))
+        (x, new_k, new_v), _ = lax.scan(layer, carry, (layers_xs, layer_ids))
     if last_index is not None:
         # bucketed prefill: the live last token sits at ``last_index``
         # (prompt_len - 1), not at T-1 — project only that position

@@ -22,6 +22,10 @@ This kernel:
 
 Layout: q [B, H, D] (the new token, post-rotary), k/v cache [B, Smax, H, D],
 pos [B] int32 = index of the newest valid entry (keys [0, pos] attended).
+With ``layer`` the caches are a model's whole [L, B, Smax, H, D] stacks and the
+layer index is a second scalar-prefetch operand of the K/V index maps: the
+kernel streams layer ``layer`` where it lies, and the caller slices nothing
+out (XLA cannot fuse a slice into a ``pallas_call`` operand).
 
 The per-row ``pos`` vector is what makes the kernel continuous-batching
 ready: the serving engine's single compiled decode step
@@ -45,8 +49,9 @@ from . import interpret_default
 NEG_INF = -1e30
 
 
-def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+def _decode_kernel(pos_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
                    *, sm_scale, block_k, num_kb, slope_ref=None):
+    # layer_ref is read by the K/V index maps only
     # All-elementwise formulation: decode attention at T=1 is a matvec per
     # head — pure HBM streaming, so the MXU buys nothing and the VPU does the
     # whole block in consistent (kk, H, D)-shaped broadcasts/reductions.
@@ -96,10 +101,12 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         o_ref[...] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
-def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None, block_k: int = 512,
-                     interpret: bool | None = None, alibi_slopes=None):
+def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
+                     block_k: int = 512, interpret: bool | None = None, alibi_slopes=None):
     """q [B, H, D], k/v_cache [B, Smax, H, D], pos [B] or scalar int32 (index
-    of the newest valid cache entry) -> attention output [B, H, D].
+    of the newest valid cache entry) -> attention output [B, H, D]. With
+    ``layer`` (int32 scalar, may be traced) k/v_cache are the stacked
+    [L, B, Smax, H, D] caches and layer ``layer`` of them is read in place.
 
     Equivalent to ``xla_attention(q[:, None], k_cache, v_cache,
     causal_offset=pos)[:, 0]`` but reads only the valid cache prefix.
@@ -107,7 +114,9 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None, block_k: int = 
     cache positions, nothing streamed).
     """
     B, H, D = q.shape
-    Smax = k_cache.shape[1]
+    if layer is None:  # one layer's cache is a stack of one: a free reshape
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
+    Smax = k_cache.shape[2]
     if sm_scale is None:
         sm_scale = 1.0 / (D ** 0.5)
     block_k = min(block_k, Smax)
@@ -123,14 +132,15 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None, block_k: int = 
     if interpret is None:
         interpret = interpret_default()
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
-    def clamp(j, p_ref, b):
-        return jnp.minimum(j, p_ref[b] // block_k)
+    def kv_block(b, j, p_ref, l_ref):
+        return (l_ref[0], b, jnp.minimum(j, p_ref[b] // block_k), 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, H, D), lambda b, j, p: (b, 0, 0)),
-        pl.BlockSpec((1, block_k, H, D), lambda b, j, p: (b, clamp(j, p, b), 0, 0)),
-        pl.BlockSpec((1, block_k, H, D), lambda b, j, p: (b, clamp(j, p, b), 0, 0)),
+        pl.BlockSpec((1, H, D), lambda b, j, p, l: (b, 0, 0)),
+        pl.BlockSpec((None, 1, block_k, H, D), kv_block),
+        pl.BlockSpec((None, 1, block_k, H, D), kv_block),
     ]
     operands = [q, k_cache, v_cache]
     base = functools.partial(
@@ -140,18 +150,18 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None, block_k: int = 
         kernel = base
     else:
         slopes_arr = jnp.asarray(alibi_slopes, jnp.float32).reshape(1, H, 1)
-        in_specs.append(pl.BlockSpec((1, H, 1), lambda b, j, p: (0, 0, 0)))
+        in_specs.append(pl.BlockSpec((1, H, 1), lambda b, j, p, l: (0, 0, 0)))
         operands.append(slopes_arr)
 
-        def kernel(pos_ref, q_ref, k_ref, v_ref, s_ref, o_ref, m_scr, l_scr, acc_scr):
-            return base(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+        def kernel(pos_ref, layer_ref, q_ref, k_ref, v_ref, s_ref, o_ref, m_scr, l_scr, acc_scr):
+            return base(pos_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                         acc_scr, slope_ref=s_ref)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, num_kb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, D), lambda b, j, p: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, D), lambda b, j, p, l: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, H, 1), jnp.float32),
             pltpu.VMEM((1, H, 1), jnp.float32),
@@ -164,5 +174,5 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None, block_k: int = 
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
         name="decode_attention",
-    )(pos, *operands)
+    )(pos, layer, *operands)
     return out

@@ -7,7 +7,10 @@ program over HBM — which interpret mode cannot show. Nothing runs, so nothing
 here is a chip run or a time.
 
 Tier 1 compiles the main path's kernels at real widths with
-``interpret=False`` (about two seconds each). The slow tier compiles whole
+``interpret=False`` (about two seconds each) and the serving decode program
+at BLOOM's and Pythia's widths, cut to four layers and a small vocabulary: the
+guard that the slot KV cache stays one buffer through the layer loop. The
+slow tier compiles whole
 programs at GPT-2 125M: the train step as ``chip_smoke.py`` / ``bench.py``
 configure it, the serving programs of ``chip_smoke.py``'s serve phase, and the
 ZeRO-3 fsdp=4 step over the four described chips (run them with ``-m slow``
@@ -15,6 +18,7 @@ before spending chip time on ``chip_smoke.py``).
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -118,6 +122,68 @@ def test_kernel_compiles_for_v5e(build, v5e, no_persistent_cache):
     assert _footprint(compiled) < HBM_BYTES
 
 
+def _bare_slot_worker(cfg, n, Smax, one_chip, param_dtype=None):
+    """A ``SlotWorker`` with just what its program builders read, and the
+    shapes of its operands on the described chip: (worker, params, cache, sds)."""
+    from deepspeed_tpu.inference.serving import SlotWorker
+    from deepspeed_tpu.models.transformer import Model
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree.map(lambda x: sds(x.shape, param_dtype or x.dtype),
+                          jax.eval_shape(Model(cfg).init, jax.random.PRNGKey(0)))
+    kv = sds((cfg.num_layers, n, Smax, cfg.num_heads, cfg.head_dim), jnp.bfloat16)
+    worker = SlotWorker.__new__(SlotWorker)
+    worker.cfg, worker.Smax = cfg, Smax
+    worker._cache_shardings = {"k": one_chip, "v": one_chip}
+    return worker, params, {"k": kv, "v": kv}, sds
+
+
+def _compile_decode(worker, params, cache, n, sds):
+    vec = lambda dtype: sds((n,), dtype)
+    return worker._build_decode().lower(
+        params, cache, vec(jnp.int32), vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+        sds((2,), jnp.uint32), vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)).compile()
+
+
+# ---------------------------------------------------------------------------
+# the decode program keeps the slot cache in place (tier 1)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("family", ["bloom_dense_alibi", "pythia_pallas_kernel"])
+def test_decode_program_keeps_the_slot_cache_in_place(family, v5e, no_persistent_cache, as_tpu):
+    """``SlotWorker``'s decode step at the widths of the benchmark's two
+    configurations (4 layers, 8 slots x 512, vocabulary 1024, bf16 weights so
+    that the temporaries are not weight casts): the stacked cache is the layer
+    loop's carry, donated in and aliased out. Put it back into the scan's
+    xs/ys and the compiler slices a layer out and restacks it in every
+    iteration and copies the whole cache twice to reconcile the buffers
+    (both assertions then fail, as they do on the code before PR 25)."""
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    fields = {"bloom_dense_alibi": dict(pos_emb="alibi", embed_ln=True, activation="gelu"),
+              "pythia_pallas_kernel": dict(pos_emb="rotary", rotary_pct=0.25, parallel_residual=True,
+                                           tie_embeddings=False, activation="gelu_exact")}[family]
+    L, n, Smax, H, Dh = 4, 8, 512, 16, 128
+    cfg = TransformerConfig(vocab_size=1024, max_seq_len=Smax, num_layers=L, num_heads=H,
+                            hidden_size=H * Dh, intermediate_size=4 * H * Dh, use_bias=True,
+                            dtype=jnp.bfloat16, **fields)
+    worker, params, cache, sds = _bare_slot_worker(
+        cfg, n, Smax, SingleDeviceSharding(v5e[0]), param_dtype=jnp.bfloat16)
+    compiled = _compile_decode(worker, params, cache, n, sds)
+
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (family == "pythia_pallas_kernel")
+    whole = re.escape(f"bf16[{L},{n},{Smax},{H},{Dh}]")
+    copies = re.findall(rf"^\s*%?[\w.-]+ = {whole}\S* copy\(", text, re.M)
+    assert not copies, f"the decode step copies the whole slot cache: {copies}"
+    ma = compiled.memory_analysis()
+    layer_bytes = n * Smax * H * Dh * 2  # one layer of K (or of V)
+    assert ma.alias_size_in_bytes >= 2 * L * layer_bytes  # K and V: donated in, aliased out
+    # nothing cache-sized beside the cache: not a copy of the stack, not one
+    # layer sliced out of it (for the Pallas kernel, which takes the stack)
+    assert ma.temp_size_in_bytes < layer_bytes, (ma.temp_size_in_bytes, layer_bytes)
+
+
 # ---------------------------------------------------------------------------
 # whole programs at GPT-2 125M (slow tier)
 # ---------------------------------------------------------------------------
@@ -187,31 +253,17 @@ def test_serving_programs_125m_compile_for_one_v5e(v5e, no_persistent_cache, as_
     """The programs ``SlotWorker`` builds for chip_smoke's serve phase: the
     one decode step, and the smallest and largest prefill bucket its prompts
     fall into (each program takes ~25 s here: the vocab-wide sampler sort)."""
-    from deepspeed_tpu.inference.serving import SlotWorker, _next_pow2
-    from deepspeed_tpu.models.transformer import Model, TransformerConfig
+    from deepspeed_tpu.inference.serving import _next_pow2
+    from deepspeed_tpu.models.transformer import TransformerConfig
 
     sz = chip_smoke.REAL
-    one_chip = SingleDeviceSharding(v5e[0])
-    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     cfg = TransformerConfig(
         vocab_size=sz["V"], max_seq_len=sz["S"], num_layers=sz["L"],
         num_heads=sz["H"], hidden_size=sz["D"], pos_emb="learned", dtype=jnp.bfloat16)
-    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
-                          jax.eval_shape(Model(cfg).init, jax.random.PRNGKey(0)))
     n, Smax = sz["n_slots"], sz["S"]
-    kv = sds((sz["L"], n, Smax, sz["H"], sz["D"] // sz["H"]), jnp.bfloat16)
-    cache = {"k": kv, "v": kv}
+    worker, params, cache, sds = _bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0]))
     key = sds((2,), jnp.uint32)
-
-    # the program builders read only these three attributes
-    worker = SlotWorker.__new__(SlotWorker)
-    worker.cfg, worker.Smax = cfg, Smax
-    worker._cache_shardings = {"k": one_chip, "v": one_chip}
-
-    vec = lambda dtype: sds((n,), dtype)
-    decode = worker._build_decode().lower(
-        params, cache, vec(jnp.int32), vec(jnp.int32), vec(jnp.int32),
-        vec(jnp.bool_), key, vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)).compile()
+    decode = _compile_decode(worker, params, cache, n, sds)
     assert "tpu_custom_call" in decode.as_text()  # the Pallas decode kernel
     worst = _footprint(decode)
     one = lambda dtype: sds((1,), dtype)

@@ -53,6 +53,20 @@ def test_decode_attention_per_row_pos():
         np.testing.assert_allclose(np.asarray(out[b]), np.asarray(ref[0]), rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("layer", [0, 2])
+def test_decode_attention_reads_a_layer_of_the_stacked_cache(layer):
+    """``layer=`` reads layer l of the [L, B, Smax, H, D] stacks in place,
+    bit-identical to the kernel on that layer sliced out (traced index: one
+    program for every layer, as the model's layer scan calls it)."""
+    q, kc, vc = _qkv(B=3)
+    pos = jnp.asarray([0, 100, 255], jnp.int32)
+    stack = lambda c: jnp.stack([c, c[::-1], c * 0.5])
+    ks, vs = stack(kc), stack(vc)
+    out = jax.jit(lambda l: decode_attention(q, ks, vs, pos, layer=l, block_k=64))(layer)
+    ref = decode_attention(q, ks[layer], vs[layer], pos, block_k=64)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
 def test_decode_in_model_matches_xla_path():
     cfg_k = TransformerConfig(
         vocab_size=97, max_seq_len=128, num_layers=2, num_heads=4, hidden_size=32,

@@ -217,3 +217,154 @@ def test_serving_with_decode_kernel(engine):
     res = srv.drain()
     np.testing.assert_array_equal(res[0].tokens, engine.generate(pa[None], 5)[0])
     np.testing.assert_array_equal(res[1].tokens, engine.generate(pb[None], 5)[0])
+
+
+# ---------------------------------------------------------------------------
+# apply_with_cache keeps the stacked cache in place through the layer loop
+# (PR 25): bit-identical to the per-layer-cache semantics it replaced
+# ---------------------------------------------------------------------------
+
+def _restacking_apply_with_cache(cfg, params, tokens, cache, pos, write_pos=None):
+    """``apply_with_cache`` as it was before the cache became the layer loop's
+    carry: every layer gets its own [B, Smax, H, Dh] cache (the scan's xs),
+    updates it, and the updated caches are restacked (the scan's ys). Full
+    logits, dense or MoE-grouped, built from the model's own layer pieces."""
+    from jax import lax
+
+    from deepspeed_tpu.models import transformer as tfm
+    from deepspeed_tpu.moe.layer import moe_ffn_apply, moe_ffn_dense
+    from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
+
+    B, T = tokens.shape
+    pos = jnp.asarray(pos, jnp.int32)
+    Smax = cache["k"].shape[2]
+    steps = jnp.arange(T)
+    if pos.ndim:
+        positions = pos[:, None] + steps[None, :]
+        wp = positions if write_pos is None else jnp.asarray(write_pos, jnp.int32)[:, None] + steps[None, :]
+        rows = jnp.arange(B)[:, None]
+        write = lambda c, new: c.at[rows, wp].set(new.astype(c.dtype), mode="drop")
+    else:
+        positions = pos + jnp.broadcast_to(steps[None, :], (B, T))
+        write = lambda c, new: lax.dynamic_update_slice(c, new.astype(c.dtype), (0, pos, 0, 0))
+    x, _ = tfm.embed(cfg, params, tokens, positions)
+    bias = None
+    if cfg.pos_emb == "alibi":
+        slopes = tfm.alibi_slopes(cfg.num_heads)
+        if pos.ndim:
+            dist = jnp.arange(Smax)[None, None, :] - positions[:, :, None]
+            bias = (slopes[None, :, None, None] * dist[:, None]).astype(jnp.float32)
+        else:
+            dist = jnp.arange(Smax)[None, :] - (pos + steps[:, None])
+            bias = (slopes[:, None, None] * dist[None]).astype(jnp.float32)[None]
+    kernel = T == 1 and cfg.decode_attn == "kernel" and cfg.pos_emb != "alibi"
+
+    def one_layer(x, lp, kc, vc, ffn):
+        h = tfm.layer_norm(x, lp["ln1_scale"], lp["ln1_bias"], cfg.layernorm_epsilon)
+        q, k, v = tfm._qkv_proj(cfg, lp, h, positions)
+        kc, vc = write(kc, k), write(vc, v)
+        if kernel:
+            attn = decode_attention(q[:, 0], kc, vc, pos)[:, None]
+        else:
+            attn = tfm.cached_attention(q, kc, vc, pos, bias=bias)
+        out = tfm._attn_out_proj(cfg, lp, attn)
+        if cfg.parallel_residual:
+            h2 = tfm.layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.layernorm_epsilon)
+            return x + out + ffn(lp, h2), kc, vc
+        x = x + out
+        h2 = tfm.layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.layernorm_epsilon)
+        return x + ffn(lp, h2), kc, vc
+
+    def dense(x, xs):
+        x, kc, vc = one_layer(x, *xs, lambda lp, h2: tfm._ffn(cfg, lp, h2))
+        return x, (kc, vc)
+
+    if cfg.moe_every:
+        E, G = cfg.moe_every, cfg.num_layers // cfg.moe_every
+        group = lambda a: a.reshape((G, E) + a.shape[1:])
+        if T == 1:
+            moe = lambda p, h2: moe_ffn_dense(cfg, p, h2)
+        else:
+            moe = lambda p, h2: moe_ffn_apply(cfg, p, h2, mesh=None)[0]
+
+        def grouped(x, xs):
+            lg, moe_p, kc, vc = xs
+            head = jax.tree.map(lambda a: a[:E - 1], (lg, kc, vc))
+            x, (kc_head, vc_head) = lax.scan(dense, x, head)
+            x, kc_last, vc_last = one_layer(x, jax.tree.map(lambda a: a[E - 1], lg), kc[E - 1],
+                                            vc[E - 1], lambda lp, h2: moe(moe_p, h2))
+            return x, (jnp.concatenate([kc_head, kc_last[None]]),
+                       jnp.concatenate([vc_head, vc_last[None]]))
+
+        x, (new_k, new_v) = lax.scan(grouped, x, (jax.tree.map(group, params["layers"]),
+                                                  params["moe"], group(cache["k"]), group(cache["v"])))
+        new_k, new_v = (a.reshape(cache["k"].shape) for a in (new_k, new_v))
+    else:
+        x, (new_k, new_v) = lax.scan(dense, x, (params["layers"], cache["k"], cache["v"]))
+    if cfg.final_ln:
+        x = tfm.layer_norm(x, params["lnf_scale"], params["lnf_bias"], cfg.layernorm_epsilon)
+    head = params["lm_head"] if "lm_head" in params else params["wte"].T
+    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype)).astype(jnp.float32)
+    if "lm_head_bias" in params:
+        logits = logits + params["lm_head_bias"].astype(jnp.float32)
+    return logits, {"k": new_k, "v": new_v}
+
+
+_SMAX = 32
+# (model fields, T, pos, write_pos): scalar pos = lock-step, list = one position a slot
+_IN_PLACE_CASES = {
+    "prefill_scalar_pos_T16": (dict(), 16, 3, None),
+    "prefill_block_fills_the_cache": (dict(), _SMAX, 0, None),
+    "alibi_prefill_block_fills_the_cache": (dict(pos_emb="alibi", embed_ln=True), _SMAX, 0, None),
+    "decode_T1_one_row_parked_at_Smax": (dict(), 1, [5, 0, 17], [5, _SMAX, 17]),
+    "verify_T4": (dict(), 4, [5, 0, 17], [5, _SMAX, 17]),
+    "moe_every_2_prefill": (dict(moe_every=2, num_experts=4, moe_top_k=1, moe_capacity_factor=8.0,
+                                 num_layers=4), 8, 0, None),
+    "moe_every_2_decode": (dict(moe_every=2, num_experts=4, moe_top_k=1, num_layers=4),
+                           1, [5, 0, 17], [5, _SMAX, 17]),
+    "alibi_decode": (dict(pos_emb="alibi", embed_ln=True), 1, [5, 0, 17], [5, _SMAX, 17]),
+    "alibi_prefill": (dict(pos_emb="alibi", embed_ln=True), 16, 0, None),
+    "partial_rotary_parallel_residual": (dict(pos_emb="rotary", rotary_pct=0.25, parallel_residual=True,
+                                              tie_embeddings=False), 1, [5, 0, 17], [5, _SMAX, 17]),
+    "pallas_decode_kernel": (dict(pos_emb="rotary", decode_attn="kernel"), 1, [5, 0, 17], [5, _SMAX, 17]),
+    "bf16_decode": (dict(dtype=jnp.bfloat16), 1, [5, 0, 17], [5, _SMAX, 17]),
+}
+
+
+@pytest.mark.parametrize("case", list(_IN_PLACE_CASES))
+def test_apply_with_cache_in_place_is_bit_identical_to_restacking(case):
+    from deepspeed_tpu.models import transformer as tfm
+
+    fields, T, pos, write_pos = _IN_PLACE_CASES[case]
+    cfg = TransformerConfig(**{**dict(
+        vocab_size=97, max_seq_len=_SMAX, num_layers=3, num_heads=4, hidden_size=32,
+        dtype=jnp.float32, loss_chunk_size=0, decode_attn="xla"), **fields})
+    tfm._ACTIVE_MESH[0] = None  # the MoE sharding hook of an earlier test's engine
+    params = tfm.init(cfg, jax.random.PRNGKey(0))
+    B = 3
+    shape = (cfg.num_layers, B, _SMAX, cfg.num_heads, cfg.head_dim)
+    kk, kv, kt = jax.random.split(jax.random.PRNGKey(1), 3)
+    # a cache that already holds something everywhere: an untouched element
+    # must come back as it went in, not as zero
+    cache = {"k": jax.random.normal(kk, shape).astype(cfg.dtype),
+             "v": jax.random.normal(kv, shape).astype(cfg.dtype)}
+    tokens = jax.random.randint(kt, (B, T), 0, cfg.vocab_size)
+    kw = {} if write_pos is None else {"write_pos": jnp.asarray(write_pos, jnp.int32)}
+    pos = jnp.asarray(pos, jnp.int32)
+
+    got_logits, got = jax.jit(
+        lambda p, t, c: tfm.apply_with_cache(cfg, p, t, c, pos, **kw))(params, tokens, cache)
+    ref_logits, ref = jax.jit(
+        lambda p, t, c: _restacking_apply_with_cache(cfg, p, t, c, pos, **kw))(params, tokens, cache)
+
+    np.testing.assert_array_equal(np.asarray(got_logits), np.asarray(ref_logits))
+    for side in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(got[side], np.float32), np.asarray(ref[side], np.float32))
+        assert got[side].shape == shape and got[side].dtype == cache[side].dtype
+    if write_pos is not None:
+        # the row parked at write_pos = Smax wrote nothing: mode="drop"
+        for side in ("k", "v"):
+            np.testing.assert_array_equal(np.asarray(got[side][:, 1], np.float32),
+                                          np.asarray(cache[side][:, 1], np.float32))
+        assert not np.array_equal(np.asarray(got["k"][:, 0], np.float32),
+                                  np.asarray(cache["k"][:, 0], np.float32))
