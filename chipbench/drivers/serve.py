@@ -16,10 +16,13 @@ through the engine itself (greedy, 9 tokens). Then (a) a probe program — the
 engine's own ``apply_with_cache`` on the engine's own weights, prefilling each
 prompt into a slot cache as ``SlotWorker`` does and taking 8 decode steps
 through that cache, fed the tokens the engine produced — returns the logits
-the serving programs sample from, and they agree with the plain float32
-reference's full forward pass within ``LOGIT_TOL``; (b) every token the engine
-produced lies within ``LOGIT_TOL`` of the reference's top logit at its step
-(bf16 streams may part from the reference only at a near tie).
+the serving programs sample from, and they agree with the full forward pass
+of the plain float32 reference the configuration names (``references/``,
+handed the engine's whole parameter tree) within ``LOGIT_TOL``; (b) every
+token the engine produced lies within ``LOGIT_TOL`` of the reference's top
+logit at its step (bf16 streams may part from the reference only at a near
+tie). ``LOGIT_TOL``, the prompts and the probe are the same for every
+configuration.
 """
 
 from __future__ import annotations
@@ -27,6 +30,8 @@ from __future__ import annotations
 import time
 
 import numpy as np
+
+from ..references import load_reference
 
 # max |logit| difference between the bf16 serving path and the float32
 # reference on the same weights, logits of standard deviation about 1.
@@ -66,22 +71,22 @@ def _request(Request, r: dict, arrival=None):
                    arrival_time=r["arrival_time"] if arrival is None else arrival)
 
 
-def _probe_logits(srv, prompts, forced):
+def probe_logits(cfg, params, prompts, buckets, forced):
     """Logits [2, 1 + DECODE_STEPS, V] of the serving path's own computation:
-    bucket-padded prefill of each prompt into a slot cache (what
-    ``SlotWorker._build_prefill`` does), then decode steps through that cache
-    at per-row positions (``_build_decode``), fed ``forced`` [2, DECODE_STEPS]."""
+    prefill of each of the two prompts, padded to its bucket, into a slot
+    cache (what ``SlotWorker._build_prefill`` does), then decode steps through
+    that cache at per-row positions (``_build_decode``), fed ``forced``
+    [2, DECODE_STEPS]."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     from deepspeed_tpu.models import transformer as tfm
 
-    cfg, params = srv.engine.cfg, srv.engine.params
     lens = np.asarray([len(p) for p in prompts], np.int32)
     padded = []
-    for p in prompts:
-        row = np.zeros((1, _bucket(srv, len(p))), np.int32)
+    for p, b in zip(prompts, buckets):
+        row = np.zeros((1, b), np.int32)
         row[0, :len(p)] = p
         padded.append(row)
     smax = -(-(int(max(r.shape[1] for r in padded)) + DECODE_STEPS) // 128) * 128
@@ -112,8 +117,7 @@ def _probe_logits(srv, prompts, forced):
 
 def _check(run, srv, Request) -> dict:
     """Warm the engine's own path on the two check prompts and compare."""
-    from .. import reference
-
+    reference = load_reference(run.program)
     rng = np.random.default_rng([run.seed, 0xC4EC])
     vocab = run.program["vocab_size"]
     budget = run.sized("deployment")["max_seq_len"]
@@ -126,15 +130,14 @@ def _check(run, srv, Request) -> dict:
     if any(results[r.uid].status != "ok" or len(g) != DECODE_STEPS + 1
            for r, g in zip(reqs, got)):
         return {"ok": False, "why": "a check request did not complete"}
-    probe = _probe_logits(srv, prompts, np.stack([g[:DECODE_STEPS] for g in got]))
     params = srv.engine.params
-    top = {k: v for k, v in params.items() if k != "layers"}
-    get_layer = reference.stacked_layer_getter(params["layers"])
+    probe = probe_logits(srv.engine.cfg, params, prompts, [_bucket(srv, len(p)) for p in prompts],
+                         np.stack([g[:DECODE_STEPS] for g in got]))
     err, tie_gap, spread = 0.0, 0.0, 0.0
     for j, (p, g) in enumerate(zip(prompts, got)):
         rows = np.arange(len(p) - 1, len(p) + DECODE_STEPS)
-        ref = reference.logits_at(run.program, top, get_layer,
-                                  np.concatenate([p, g[:DECODE_STEPS]]), rows)
+        ref = reference.logits_at(run.program, params, np.concatenate([p, g[:DECODE_STEPS]]),
+                                  rows, fetch=lambda leaves: leaves)  # all on the one chip
         err = max(err, float(np.max(np.abs(probe[j] - ref))))
         tie_gap = max(tie_gap, float(np.max(ref.max(axis=-1) - ref[np.arange(len(g)), g])))
         spread = float(np.std(ref))

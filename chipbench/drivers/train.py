@@ -10,9 +10,10 @@ Correctness goes through the measured program. The first ``train_batch``
 call (a warm-up step, in set-up) is given the first ``reference_sequences``
 sequences of the stream repeated to a full batch, so the loss it returns, on
 the engine's initial parameters, is the mean over exactly those sequences; it
-agrees with the plain float32 reference on the same sequences and parameters
-within ``LOSS_TOL``. And in the window the loss falls, nothing is non-finite
-and no overflow flag is set.
+agrees with the plain float32 reference the configuration names
+(``references/``) on the same sequences and parameters within ``LOSS_TOL``,
+the same for every configuration. And in the window the loss falls, nothing
+is non-finite and no overflow flag is set.
 """
 
 from __future__ import annotations
@@ -20,6 +21,8 @@ from __future__ import annotations
 import time
 
 import numpy as np
+
+from ..references import load_reference
 
 # |first train_batch loss - float32 reference loss| on the same 4 x 2048 tokens
 # and parameters, bf16 compute against float32. The engine's forward alone
@@ -71,18 +74,16 @@ def _build(run):
 
 
 def _reference_loss(run, engine, sequences) -> float:
-    """Float32 reference loss on the engine's initial parameters, each leaf
-    (and each layer of a stacked leaf) brought to one device as it is used."""
+    """Float32 reference loss on the engine's initial parameters: the
+    reference is handed the sharded tree whole, and each slice it takes (the
+    top-level leaves, then one layer at a time) is brought to one device as
+    it is used, so the state is never gathered at once."""
     import jax
 
-    from .. import reference
-
     dev = jax.devices()[0]
-    params = engine.state["params"]
-    top = {k: jax.device_put(v, dev) for k, v in params.items() if k != "layers"}
-    layers = params["layers"]
-    get_layer = lambda i: {k: jax.device_put(v[i], dev) for k, v in layers.items()}
-    return reference.lm_loss(run.program, top, get_layer, sequences)
+    return load_reference(run.program).lm_loss(
+        run.program, engine.state["params"], sequences,
+        fetch=lambda leaves: jax.device_put(leaves, dev))
 
 
 def _memory_analysis(run, engine, batch):

@@ -1,11 +1,14 @@
 """Operations and bytes computed from shapes — the benchmark's conventions.
 
 Training FLOPs per token (``train_flops_per_token``): 6 x the parameters that
-sit in a matrix multiplication on a token's path (the four attention
-projections and the two feed-forward matrices of every layer, and the output
-head), plus attention itself at its causal half: forward 2*S*d per layer per
-token (QK^T and PV over the S/2 keys a token sees on average), times 3 for
-forward + backward. The embedding LOOKUP is a gather and counts nothing; a
+sit in a matrix multiplication on ONE token's path, plus attention itself at
+its causal half: forward 2*S*d per layer per token (QK^T and PV over the S/2
+keys a token sees on average), times 3 for forward + backward. Which
+parameters those are is the architecture's to say: the count comes from the
+configuration's reference (``references/<name>.py::param_counts``,
+``matmul_on_token_path``: the projections and feed-forward matrices of every
+layer the token visits and the output head; a sparse model counts the experts
+a token is routed to). The embedding LOOKUP is a gather and counts nothing; a
 tied head still counts once, as the head. Recomputed operations (remat, the
 flash backward's second pass over the scores) do not count: this is the work
 the model requires, so tokens/s times it over the chips' peak is an
@@ -18,27 +21,15 @@ device time: ``roofline`` takes the larger of ops/peak and bytes/bandwidth.
 
 from __future__ import annotations
 
-
-def param_counts(program: dict) -> dict:
-    """Parameter counts of this decoder family from the program's sizes."""
-    d, L, V = program["hidden_size"], program["num_layers"], program["vocab_size"]
-    f = program.get("intermediate_size") or 4 * d
-    bias = program.get("use_bias", True)
-    matmul_layer = 4 * d * d + 2 * d * f
-    other_layer = 4 * d + ((3 * d + d + f + d) if bias else 0)  # two LayerNorms, biases
-    embedding = V * d
-    head = 0 if program.get("tie_embeddings", True) else d * V
-    other = 2 * d + (2 * d if program.get("embed_ln") else 0)  # final LN, embedding LN
-    if program.get("pos_emb") == "learned":
-        other += program["max_seq_len"] * d
-    return {
-        "matmul_per_layer": matmul_layer,
-        "matmul_on_token_path": L * matmul_layer + d * V,  # the head counts tied or not
-        "total": L * (matmul_layer + other_layer) + embedding + head + other,
-    }
+from .references import Program, load_reference
 
 
-def train_flops_per_token(program: dict, seq_len: int) -> float:
+def param_counts(program: Program) -> dict:
+    """Parameter counts from the program's sizes, as its reference counts them."""
+    return load_reference(program).param_counts(program)
+
+
+def train_flops_per_token(program: Program, seq_len: int) -> float:
     d, L = program["hidden_size"], program["num_layers"]
     attention = 3 * L * 2 * seq_len * d  # causal half, fwd + bwd
     return 6.0 * param_counts(program)["matmul_on_token_path"] + attention

@@ -1,17 +1,20 @@
-"""The plain reference: this decoder family's forward pass (and its language-
-model loss) in straightforward float32 ``jax.numpy`` — no kernels, no cache,
-no scan, one layer at a time (several sequences go through a layer together
-only by ``jax.vmap`` of the one-sequence function, so that each layer's weights
-are fetched once).
+"""The plain reference of the 2022 GPT family (GPT-NeoX / Pythia, BLOOM):
+its forward pass and language-model loss in straightforward float32
+``jax.numpy`` — no kernels, no cache, no scan, one layer at a time (several
+sequences go through a layer together only by ``jax.vmap`` of the one-sequence
+function, so that each layer's weights are fetched once) — and its parameter
+counts. The protocol is stated in ``references/__init__.py``.
 
-It covers what the benchmark's configurations use: pre-LayerNorm decoder
-blocks with biases; rotary position embedding on the first ``rotary_pct`` of
-each head (NeoX half-split, base 10000) or alibi; parallel (GPT-NeoX) or
-sequential (BLOOM) residual; optional LayerNorm after the embedding; tied or
-untied output head; exact or tanh GELU. It shares no code with
-``deepspeed_tpu.models.transformer``; it reads that module's parameter LAYOUT
-(``wq`` [d, H, Dh], ``wo`` [H, Dh, d], ...), because it is run on the system's
-own weights.
+It covers (``COVERS``) pre-LayerNorm decoder blocks with biases, full
+multi-head attention and a two-matrix GELU feed-forward; rotary position
+embedding on the first ``rotary_pct`` of each head (NeoX half-split, base
+10000) or alibi; parallel (GPT-NeoX) or sequential (BLOOM) residual; optional
+LayerNorm after the embedding; tied or untied output head; exact or tanh
+GELU. Anything else in a ``program`` is refused by its key's name. It shares
+no code with ``deepspeed_tpu.models.transformer``; it reads that module's
+parameter LAYOUT (one stack ``params["layers"]`` of ``wq`` [L, d, H, Dh],
+``wo`` [L, H, Dh, d], ...; everything else a top-level leaf), because it is
+run on the system's own weights.
 
 On a TPU a float32 matmul runs in lower precision unless asked otherwise, so
 everything here runs under ``jax.default_matmul_precision("highest")``.
@@ -25,6 +28,20 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+ANY = None
+COVERS = {
+    "vocab_size": ANY, "max_seq_len": ANY, "num_layers": ANY, "num_heads": ANY,
+    "hidden_size": ANY, "intermediate_size": ANY, "layernorm_epsilon": ANY, "rotary_pct": ANY,
+    "pos_emb": ("rotary", "alibi"),
+    "parallel_residual": (False, True), "embed_ln": (False, True),
+    "tie_embeddings": (False, True), "activation": ("gelu", "gelu_exact"),
+    # switches of the program's model that this block does not implement,
+    # accepted only at the value that leaves the block as it is written here
+    "use_bias": (True,), "norm_style": ("pre",), "final_ln": (True,), "causal": (True,),
+    "rotary_interleaved": (False,), "local_attn_window": (0,), "local_attn_layers": (None,),
+    "moe_every": (0,),
+}
 
 
 def _layer_norm(x, scale, bias, eps):
@@ -83,13 +100,16 @@ def _f32(x):
     return jnp.asarray(x, jnp.float32)
 
 
-def hidden_states(program: dict, top: dict, get_layer, tokens) -> jax.Array:
+def _fetch_top(params: dict, fetch) -> dict:
+    """The leaves outside the layer stack: embedding, LayerNorms, head."""
+    return fetch({k: v for k, v in params.items() if k != "layers"})
+
+
+def _hidden_states(program: dict, top: dict, layers: dict, tokens, fetch) -> jax.Array:
     """Final-LayerNormed hidden states [S, d] of one token sequence, or
-    [N, S, d] of N sequences of one length. ``top`` holds the non-layer
-    leaves; ``get_layer(i)`` returns layer i's leaves — so the caller decides
-    where each layer's weights come from and how many are resident at once."""
-    if program["pos_emb"] not in ("rotary", "alibi"):
-        raise NotImplementedError(f"reference has no pos_emb {program['pos_emb']!r}")
+    [N, S, d] of N sequences of one length. ``layers`` is the system's stack
+    of [L, ...] leaves; layer i's slices are fetched as it is reached (a
+    sharded leaf is gathered by the slice), so one layer is resident at once."""
     eps = float(program.get("layernorm_epsilon", 1e-5))
     head_dim = program["hidden_size"] // program["num_heads"]
     with jax.default_matmul_precision("highest"):
@@ -103,7 +123,8 @@ def hidden_states(program: dict, top: dict, get_layer, tokens) -> jax.Array:
         if x.ndim == 3:
             layer = jax.vmap(layer, in_axes=(0, None))
         for i in range(program["num_layers"]):
-            x = layer(x, {k: _f32(v) for k, v in get_layer(i).items()})
+            lp = fetch({k: v[i] for k, v in layers.items()})
+            x = layer(x, {k: _f32(v) for k, v in lp.items()})
         return _layer_norm(x, _f32(top["lnf_scale"]), _f32(top["lnf_bias"]), eps)
 
 
@@ -111,17 +132,19 @@ def _head(program: dict, top: dict):
     return _f32(top["wte"]).T if program.get("tie_embeddings", True) else _f32(top["lm_head"])
 
 
-def logits_at(program: dict, top: dict, get_layer, tokens, rows) -> np.ndarray:
+def logits_at(program: dict, params: dict, tokens, rows, *, fetch) -> np.ndarray:
     """Float32 logits [len(rows), vocab] at the given positions."""
-    x = hidden_states(program, top, get_layer, tokens)
+    top = _fetch_top(params, fetch)
+    x = _hidden_states(program, top, params["layers"], tokens, fetch)
     with jax.default_matmul_precision("highest"):
         return np.asarray(x[jnp.asarray(rows)] @ _head(program, top))
 
 
-def lm_loss(program: dict, top: dict, get_layer, tokens) -> float:
+def lm_loss(program: dict, params: dict, tokens, *, fetch) -> float:
     """Mean next-token cross-entropy of ``tokens`` [S + 1] or [N, S + 1]."""
+    top = _fetch_top(params, fetch)
     tokens = np.asarray(tokens)
-    x = hidden_states(program, top, get_layer, tokens[..., :-1])
+    x = _hidden_states(program, top, params["layers"], tokens[..., :-1], fetch)
     head = _head(program, top)
     with jax.default_matmul_precision("highest"):
         losses = []
@@ -133,7 +156,18 @@ def lm_loss(program: dict, top: dict, get_layer, tokens) -> float:
         return float(jnp.mean(jnp.stack(losses)))
 
 
-def stacked_layer_getter(layers: dict):
-    """``get_layer`` for the system's stacked [L, ...] layer leaves: slices
-    one layer at a time (a sharded leaf is gathered by the slice)."""
-    return lambda i: {k: v[i] for k, v in layers.items()}
+def param_counts(program: dict) -> dict:
+    """Four attention projections and two feed-forward matrices a layer, all on
+    every token's path; two LayerNorms and six biases a layer beside them."""
+    d, L, V = program["hidden_size"], program["num_layers"], program["vocab_size"]
+    f = program.get("intermediate_size") or 4 * d
+    matmul_layer = 4 * d * d + 2 * d * f
+    other_layer = 4 * d + (3 * d + d + f + d)  # two LayerNorms; biases of q, k, v, o, ffn
+    embedding = V * d
+    head = 0 if program.get("tie_embeddings", True) else d * V
+    other = 2 * d + (2 * d if program.get("embed_ln") else 0)  # final LN, embedding LN
+    return {
+        "matmul_per_layer": matmul_layer,
+        "matmul_on_token_path": L * matmul_layer + d * V,  # the head counts tied or not
+        "total": L * (matmul_layer + other_layer) + embedding + head + other,
+    }

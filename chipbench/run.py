@@ -4,8 +4,9 @@
     python3 -m chipbench.run --workload W --seed N --seconds S --trace 0|1
 
 Everything a cell is made of is a file found by name: the cell itself
-(``workloads/W.json``), its configuration (``configs/``), the driver that
-knows how to build and drive the system under test (``drivers/``), the traffic
+(``workloads/W.json``), its configuration (``configs/``) and the plain
+reference that configuration names (``references/``), the driver that knows
+how to build and drive the system under test (``drivers/``), the traffic
 generator (``traffic/``), and one reader per metric (``end_to_end/`` and
 ``layer_metrics/``). Which metrics a cell reports, with their units, is read
 from ``BENCHMARK.json``. This file holds no cell, configuration or metric
@@ -36,6 +37,8 @@ import os
 import shutil
 import sys
 import tempfile
+
+from .references import load_reference, named_reference, program_of  # imports no jax
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -71,7 +74,9 @@ class Run:
         self.seed, self.trace = int(args.seed), bool(args.trace)
         self.seconds = float(cell["rehearse"]["seconds"] if self.rehearse else args.seconds)
         self.chips = int(entry["chips"])
-        self.program = dict(config["rehearse_program" if self.rehearse else "program"])
+        # the model's keywords as run; remembers the reference the configuration names
+        self.program = program_of(
+            config, "rehearse_program" if self.rehearse else "program")
         self.cache = {"hits": 0, "writes": 0}
         self.compile_times: list[float] = []  # perf_counter at the end of each compile
         self.trace_dir = None
@@ -208,6 +213,7 @@ def main(argv=None) -> int:
     if not entries:
         raise SystemExit(f"BENCHMARK.json has no workload {args.workload!r}")
     config = _named("configs", cell["config"])
+    named_reference(config)  # none, or one with no file: ends here, with the list of those there
     chips = int(entries[0]["chips"])
 
     if args.rehearse:  # before jax is imported
@@ -233,6 +239,7 @@ def main(argv=None) -> int:
             return 3
     cache_dir = use_compile_cache()
     run = Run(args, cell, entries[0], config, peak)
+    load_reference(run.program)  # a key the reference does not cover: refused here, by its name
     run.listen()
     note(event="start", workload=args.workload, seed=run.seed, seconds=run.seconds,
          trace=run.trace, rehearse=run.rehearse, cache_dir=cache_dir,

@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Checks of the benchmark's own yardstick; runs on the CPU in seconds.
+"""Checks of the benchmark's own yardstick; runs on the CPU in under a minute.
 
     python3 -m chipbench.selftest            # all checks, exit 0 if they hold
     python3 -m chipbench.selftest --record D # (on a machine with >= 2 TPU chips) record
@@ -9,11 +9,18 @@ What is checked: the interval arithmetic and the reductions of ``reduce.py``
 on a hand-made trace whose numbers are worked out by hand, and on a small
 trace recorded on the chip (``recorded/``: a few steps of a tiny sharded
 program on two devices' planes) against the reduction pinned when it was
-recorded; ``flops.py`` against hand counts for both configurations; that each
-traffic generator gives identical requests for one seed and different ones
-for another; and that ``BENCHMARK.json``, the directories and the readers
-agree (every metric has its reader, every cell its files, and ``run.py`` names
-none of them).
+recorded; ``flops.py`` and the references' counts against hand counts for
+both configurations; that each traffic generator gives identical requests for
+one seed and different ones for another; that ``BENCHMARK.json``, the
+directories and the readers agree (every metric has its reader, every cell its
+files, and ``run.py`` names none of them); and the references
+(``check_references``, run as part of ``check_files``): every configuration
+names one that is there, exports the protocol and covers its program, a
+configuration that names none or a missing one is refused, a program with a
+key the reference does not implement is refused by that key's name, and the
+system's float32 model agrees with each configuration's reference at the
+rehearsal size (``parity.py``), within a tolerance that bfloat16 fails. The
+last part compiles small programs on the CPU: about 20 s, most of the whole.
 """
 
 from __future__ import annotations
@@ -106,10 +113,11 @@ def check_recorded_trace() -> None:
 
 def check_flops() -> None:
     from . import flops
+    from .references import program_of
 
     def program(name):
         with open(os.path.join(HERE, "configs", f"{name}.json")) as f:
-            return json.load(f)["program"]
+            return program_of(json.load(f))
 
     # pythia-1.4b by hand: a layer has 4*2048^2 + 2*2048*8192 = 50,331,648 matmul
     # parameters; 24 layers + the 2048 x 50304 head = 1,310,982,144 on a token's path;
@@ -180,6 +188,8 @@ def check_traffic() -> None:
 
 
 def check_files() -> None:
+    from .references import available
+
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     e2e = {m["name"]: m for m in bench["end_to_end"]}
@@ -204,8 +214,52 @@ def check_files() -> None:
         text = f.read()
     names = ([w["name"] for w in bench["workloads"]] + [c["name"] for c in bench["configs"]]
              + [m["name"].split(".")[0] for g in ("end_to_end", "per_layer") for m in bench[g]])
-    named = [n for n in names if n in text]
+    named = [n for n in names + available() if n in text]
     assert not named, f"run.py names {named}"
+    # one more kind of file found by name; not a line of its own in main()'s list because
+    # tests/test_tracing_spans.py pins the number of "ok" lines and no benchmark PR may edit it
+    check_references(bench)
+
+
+def _refused(call, *words) -> None:
+    """``call`` must end in a refusal whose message has every one of ``words``."""
+    from .references import NotCovered
+
+    try:
+        call()
+    except (SystemExit, NotCovered) as e:
+        missing = [w for w in words if w not in str(e)]
+        assert not missing, f"the refusal {str(e)!r} does not say {missing}"
+    else:
+        raise AssertionError(f"not refused (expected a refusal naming {words})")
+
+
+def check_references(bench: dict) -> None:
+    from . import parity
+    from .references import Program, available, load_reference, named_reference, program_of
+
+    for c in bench["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            config = json.load(f)
+        for block in ("program", "rehearse_program"):
+            program = program_of(config, block)
+            load_reference(program).param_counts(program)  # there, whole, and covers the block
+    there = available()
+    _refused(lambda: named_reference({"name": "x"}), "names no reference", *there)
+    _refused(lambda: named_reference({"name": "x", "reference": "no_such"}),
+             "no references/no_such.py", *there)
+    with open(os.path.join(HERE, "configs", "pythia-1.4b.json")) as f:
+        covered = json.load(f)["rehearse_program"]
+    for key, value in (("moe_every", 2), ("use_bias", False), ("rotary_interleaved", True),
+                       ("activation", "relu"), ("norm_style", "post"), ("num_kv_heads", 4)):
+        odd = Program({**covered, key: value}, "gpt_family")
+        _refused(lambda: load_reference(odd), repr(key))
+    # the system against each configuration's reference, and the tolerance against bfloat16
+    for name, which in parity.cases():
+        parity.check(name, which)
+        err = parity.error(name, which, bf16=True)
+        assert err > parity.TOL[which], (
+            f"{name} {which}: bfloat16 compute passes the tolerance ({err:.3g})")
 
 
 def record(out_dir: str) -> int:
