@@ -105,6 +105,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from ..models import transformer as tfm
+from ..moe.dropless import expert_load, load_summary
 from ..parallel.sharding import kv_prefix_pool_spec, kv_slot_cache_spec
 from ..resilience import FaultInjector, RequestRejected
 from ..runtime.config import (ChunkedPrefillConfig, FaultInjectionConfig,
@@ -250,6 +251,20 @@ class _Handoff:
     entry: object = None  # acquired PrefixEntry, released on handoff_release
 
 
+def _forward(cfg, params, toks, cache, pos, live, **kw):
+    """``apply_with_cache`` for a serving program -> (logits, cache, extra
+    outputs). A dense model has no extra output and its program is what it
+    was. A model with dropless routing adds ONE: int32 [routed layers, E],
+    how many of the ``live`` rows (toks-shaped bool: not bucket padding, not
+    an idle slot) each layer sent to each expert; it comes back in the fetch
+    that brings the tokens and ``SlotWorker._note_load`` puts it on the span."""
+    if cfg.moe_routing != "dropless":
+        return (*tfm.apply_with_cache(cfg, params, toks, cache, pos, **kw), ())
+    logits, cache, chosen = tfm.apply_with_cache(
+        cfg, params, toks, cache, pos, return_routing=True, **kw)
+    return logits, cache, (expert_load(chosen, live, cfg.num_experts),)
+
+
 class SlotWorker:
     """The compiled-program driver half of the serving engine.
 
@@ -353,8 +368,8 @@ class SlotWorker:
             # position: inactive/prefilling rows write at Smax (dropped by
             # the scatter) but ATTEND at pos 0, so the length-aware decode
             # kernel streams one block for an idle row, not the whole cache
-            logits, cache = tfm.apply_with_cache(
-                cfg, params, toks[:, None], cache, pos, write_pos=wpos)
+            logits, cache, load = _forward(
+                cfg, params, toks[:, None], cache, pos, active[:, None], write_pos=wpos)
             # per-slot NaN sentinel: a non-finite logit row means the slot's
             # state is poisoned (bad KV, numeric fault) — the host
             # quarantines the request; the sampled token for such a row is
@@ -362,13 +377,12 @@ class SlotWorker:
             # one-compiled-decode-step contract holds.
             bad = jnp.any(~jnp.isfinite(logits[:, 0]), axis=-1)
             nxt = sample_logits_vector(logits[:, 0], rng, temp, top_k, top_p)
-            return cache, jnp.where(active, nxt, 0), bad
+            return (cache, jnp.where(active, nxt, 0), bad, *load)
 
         # all serving programs donate the slot KV cache / prefix pool —
         # XLA-created device buffers, never CPU zero-copy host memory, so
         # donation stays on every backend (utils/donation.py is the gate)
-        return donated_jit(decode, donate_argnums=(1,),
-                           out_shardings=(self._cache_shardings, None, None))
+        return donated_jit(decode, donate_argnums=(1,), out_shardings=self._outs(2))
 
     def _build_verify(self, depth: int, greedy_only: bool = False):
         cfg = self.cfg
@@ -380,8 +394,9 @@ class SlotWorker:
             # uploads per verify step on a path whose whole point is
             # shaving per-step cost
             def verify_greedy(params, cache, toks, pos, wpos, active):
-                logits, cache = tfm.apply_with_cache(
-                    cfg, params, toks, cache, pos, write_pos=wpos)
+                logits, cache, load = _forward(
+                    cfg, params, toks, cache, pos,
+                    jnp.broadcast_to(active[:, None], toks.shape), write_pos=wpos)
                 bad = jnp.any(~jnp.isfinite(logits), axis=(1, 2))
                 # acceptance is draft == argmax and every emitted token IS
                 # the argmax — no top-k/top-p sort, no categorical draws,
@@ -400,10 +415,10 @@ class SlotWorker:
                 packed = jnp.concatenate(
                     [(accept & on).astype(jnp.int32), out,
                      bad.astype(jnp.int32)[:, None]], axis=1)
-                return cache, packed
+                return (cache, packed, *load)
 
             return donated_jit(verify_greedy, donate_argnums=(1,),
-                               out_shardings=(self._cache_shardings, None))
+                               out_shardings=self._outs(1))
 
         def verify(params, cache, toks, pos, wpos, active, rng, temp, top_k, top_p):
             # toks [n_slots, depth+1]: column 0 is each slot's last sampled
@@ -419,8 +434,9 @@ class SlotWorker:
             # Inactive slots write at Smax.. and beyond: every position of
             # their block lands out of range and the scatter's mode="drop"
             # discards it, the same contract decode relies on.
-            logits, cache = tfm.apply_with_cache(
-                cfg, params, toks, cache, pos, write_pos=wpos)
+            logits, cache, load = _forward(
+                cfg, params, toks, cache, pos,
+                jnp.broadcast_to(active[:, None], toks.shape), write_pos=wpos)
             # the sentinel spans ALL depth+1 positions: a NaN anywhere in
             # the block poisons the accept/bonus math for that slot
             bad = jnp.any(~jnp.isfinite(logits), axis=(1, 2))
@@ -428,11 +444,9 @@ class SlotWorker:
                 logits, toks[:, 1:], rng, temp, top_k, top_p)
             on = active[:, None]
             return (cache, accept & on, jnp.where(on, resample, 0),
-                    jnp.where(on, clean, 0), bad)
+                    jnp.where(on, clean, 0), bad, *load)
 
-        return donated_jit(verify, donate_argnums=(1,),
-                           out_shardings=(self._cache_shardings,
-                                          None, None, None, None))
+        return donated_jit(verify, donate_argnums=(1,), out_shardings=self._outs(4))
 
     def _build_prefill(self, bucket: int):
         cfg = self.cfg
@@ -442,8 +456,9 @@ class SlotWorker:
             # live tokens never attend to it, and its KV is overwritten by
             # decode steps as the sequence grows into those positions)
             local = tfm.init_cache(cfg, 1, bucket, dtype=cache["k"].dtype)
-            logits, local = tfm.apply_with_cache(
-                cfg, params, prompt, local, 0, last_index=true_len - 1)
+            logits, local, load = _forward(
+                cfg, params, prompt, local, 0, jnp.arange(bucket)[None, :] < true_len,
+                last_index=true_len - 1)
             bad = jnp.any(~jnp.isfinite(logits[:, 0]), axis=-1)
             tok = sample_logits_vector(logits[:, 0], rng, temp, top_k, top_p)
             cache = {
@@ -451,10 +466,9 @@ class SlotWorker:
                     cache[kv], local[kv], (0, slot, 0, 0, 0))
                 for kv in ("k", "v")
             }
-            return cache, tok, bad
+            return (cache, tok, bad, *load)
 
-        return donated_jit(prefill, donate_argnums=(1,),
-                           out_shardings=(self._cache_shardings, None, None))
+        return donated_jit(prefill, donate_argnums=(1,), out_shardings=self._outs(2))
 
     def _build_chunk(self, width: int):
         cfg = self.cfg
@@ -475,9 +489,9 @@ class SlotWorker:
             # before any query position can attend to it, and ``last_index``
             # projects only the live last token's logits.
             local = tfm.slice_cache_slot(cache, slot, Smax)
-            logits, local = tfm.apply_with_cache(
+            logits, local, load = _forward(
                 cfg, params, toks, local, jnp.reshape(start, (1,)),
-                last_index=true_len - 1)
+                jnp.arange(width)[None, :] < true_len, last_index=true_len - 1)
             # NaN mid-prompt propagates through attention to every later
             # chunk, so the final chunk's sentinel covers the whole prefill
             bad = jnp.any(~jnp.isfinite(logits[:, 0]), axis=-1)
@@ -487,10 +501,9 @@ class SlotWorker:
             # positions per chunk would multiply the cache-write bandwidth
             # by Smax/width on exactly the prompt-side hot path
             new_kv = tfm.slice_cache_slot(local, 0, width, start=start)
-            return tfm.update_cache_slot(cache, new_kv, slot, start=start), tok, bad
+            return (tfm.update_cache_slot(cache, new_kv, slot, start=start), tok, bad, *load)
 
-        return donated_jit(chunk, donate_argnums=(1,),
-                           out_shardings=(self._cache_shardings, None, None))
+        return donated_jit(chunk, donate_argnums=(1,), out_shardings=self._outs(2))
 
     def _build_fetch(self):
         pmax = self.pmax
@@ -557,7 +570,25 @@ class SlotWorker:
                 wd.unique_name(f"serving/kv_import[{width}]"), stable=True)
         return self._kv_imports[width]
 
+    def _outs(self, n: int) -> tuple:
+        """``out_shardings`` of a program that returns the slot cache, ``n``
+        host-bound values and, for a routed model, the expert load."""
+        routed = self.cfg.moe_routing == "dropless"
+        return (self._cache_shardings,) + (None,) * (n + routed)
+
     # -- dispatches ------------------------------------------------------
+
+    def _note_load(self, sp, load) -> None:
+        """A routed model's call: how uneven the routing of its live rows was,
+        on the call's span and the gauges of the same names (``load`` is the
+        fetched tail of the program's outputs: empty for a dense model)."""
+        if not load:
+            return
+        summary = load_summary(load[0])
+        sp.annotate(**summary)
+        self.telemetry.gauge("serving/expert_load_max_over_mean").set(
+            summary["expert_load_max_over_mean"])
+        self.telemetry.gauge("serving/experts_touched").set(summary["experts_touched"])
 
     def _compiled(self, sp, prog) -> bool:
         """Whether the call just made into ``prog`` compiled, noted on its
@@ -594,14 +625,15 @@ class SlotWorker:
                 # host arrays straight into the jitted call (pjit batches the
                 # uploads); dtypes are pinned by the engine's per-slot state
                 # arrays
-                self._cache, nxt, bad = self._decode(
+                self._cache, *out = self._decode(
                     self.params, self._cache, last_tok, pos,
                     np.asarray(wpos, np.int32), active, k, temp, top_k, top_p,
                 )
             self._decode_steps += 1
             compiled = self._compiled(sp, self._decode)
             with tm.span("fetch"):
-                nxt, bad = (np.asarray(x) for x in jax.device_get((nxt, bad)))
+                nxt, bad, *load = (np.asarray(x) for x in jax.device_get(out))
+            self._note_load(sp, load)
         if not compiled:
             tm.histogram("serving/decode_step_sec").observe(sp.dur_s)
         tm.counter("serving/decode_steps").inc()
@@ -644,6 +676,9 @@ class SlotWorker:
             compiled = self._compiled(sp, prog)
             with tm.span("fetch"):
                 out = tuple(np.asarray(x) for x in jax.device_get(out))
+            n_out = 1 if greedy_only else 4  # what follows is a routed model's expert load
+            self._note_load(sp, out[n_out:])
+            out = out[:n_out]
             if greedy_only:
                 p, = out  # one packed array: the ONE fetch
                 tokens = p[:, depth:2 * depth + 1]
@@ -679,7 +714,7 @@ class SlotWorker:
                         wd.unique_name(f"serving/prefill[{bucket}]"), stable=True)
                 prog = self._prefills[bucket]
                 self._rng, k = jax.random.split(self._rng)
-                self._cache, tok, bad = prog(
+                self._cache, *out = prog(
                     self.params, self._cache, jnp.asarray(padded),
                     jnp.int32(slot), jnp.int32(true_len), k,
                     jnp.asarray([temperature], jnp.float32),
@@ -688,7 +723,8 @@ class SlotWorker:
                 )
             compiled = self._compiled(sp, prog)
             with tm.span("fetch"):
-                tok_h, bad_h = jax.device_get((tok, bad))
+                tok_h, bad_h, *load = jax.device_get(out)
+            self._note_load(sp, load)
         if not compiled:
             tm.histogram("serving/prefill_sec").observe(sp.dur_s)
         tm.counter(f"serving/prefill_bucket[{bucket}]").inc()
@@ -709,7 +745,7 @@ class SlotWorker:
             with tm.span("dispatch"):
                 prog = self._chunk_prog(width)
                 self._rng, k = jax.random.split(self._rng)
-                self._cache, tok, bad = prog(
+                self._cache, *out = prog(
                     self.params, self._cache, jnp.asarray(toks),
                     jnp.int32(slot), jnp.int32(start), jnp.int32(live), k,
                     jnp.asarray([temperature], jnp.float32),
@@ -719,7 +755,8 @@ class SlotWorker:
             compiled = self._compiled(sp, prog)
             if fetch:
                 with tm.span("fetch"):
-                    tok_h, bad_h = jax.device_get((tok, bad))
+                    tok_h, bad_h, *load = jax.device_get(out)
+                self._note_load(sp, load)
         tm.counter(f"serving/chunk_bucket[{width}]").inc()
         if not fetch:
             return None

@@ -50,6 +50,10 @@ class TransformerConfig:
     parallel_residual: bool = False  # GPT-NeoX style
     causal: bool = True  # False = bidirectional (BERT-style encoders)
     norm_style: str = "pre"  # pre (GPT) | post (BERT) layernorm placement
+    norm_kind: str = "layer"  # layer (scale + bias) | rms (RMSNorm: scale only)
+    # RMSNorm with a learned scale over the whole query and the whole key
+    # projection, before the head split and the rotary (OLMoE's q_norm / k_norm)
+    qk_norm: bool = False
     # GPT-Neo alternating local attention: window size + per-layer 0/1 flags
     # (1 = local); None = all-global
     local_attn_window: int = 0
@@ -58,7 +62,9 @@ class TransformerConfig:
     tie_embeddings: bool = True
     use_bias: bool = True
     final_ln: bool = True  # False: no final LayerNorm (BERT encoders)
-    activation: str = "gelu"  # gelu | gelu_exact | relu
+    # gelu | gelu_exact | relu | swiglu (down(silu(gate(x)) * up(x)): the gated
+    # expert of moe_routing="dropless"; there is no gated dense feed-forward yet)
+    activation: str = "gelu"
     embed_ln: bool = False  # LayerNorm after embedding (BLOOM)
     attn_impl: str = "xla"  # xla | flash | ring | sparse
     flash_block_q: int = 0  # 0 = auto (ops/pallas/flash_attention._auto_block)
@@ -110,7 +116,14 @@ class TransformerConfig:
     moe_every: int = 0  # >0: every Nth layer is an MoE FFN (see moe/)
     num_experts: int = 1
     moe_top_k: int = 1
-    moe_capacity_factor: float = 1.25
+    # "gshard": top-1 / top-2, capacity, tokens over it dropped (moe/sharded_moe.py).
+    # "dropless": any top-k, no capacity and no dropped token, gated experts,
+    # every layer routed (moe/dropless.py).
+    moe_routing: str = "gshard"
+    # dropless only: the k weights renormalised to sum to 1 (a model's
+    # norm_topk_prob), or left as the raw softmax probabilities
+    moe_norm_topk_prob: bool = False
+    moe_capacity_factor: float = 1.25  # gshard only
     moe_aux_coeff: float = 0.01  # load-balancing loss weight
     loss_chunk_size: int = 512  # chunk the vocab projection in the loss; 0 = off
     # "chunked": lax.scan over sequence chunks (logits chunk materialized,
@@ -162,10 +175,24 @@ def _dense_init(key, shape, fan_in):
     return (jax.random.normal(key, shape) * (1.0 / math.sqrt(fan_in))).astype(jnp.float32)
 
 
+def _dropless(cfg: TransformerConfig) -> bool:
+    """Whether the feed-forward of every layer is the dropless routed block
+    (moe/dropless.py); refuses the combinations that block does not have."""
+    dropless, gated = cfg.moe_routing == "dropless", cfg.activation == "swiglu"
+    if dropless != gated or (dropless and cfg.moe_every != 1):
+        raise NotImplementedError(
+            "moe_routing='dropless', activation='swiglu' and moe_every=1 come together: the "
+            "dropless block has gated experts in every layer, and there is no gated dense "
+            f"feed-forward (got moe_routing={cfg.moe_routing!r}, activation="
+            f"{cfg.activation!r}, moe_every={cfg.moe_every})")
+    return dropless
+
+
 def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
     keys = jax.random.split(rng, 16)
     d, f, L = cfg.hidden_size, cfg.ffn_size, cfg.num_layers
     H, Dh = cfg.num_heads, cfg.head_dim
+    dropless = _dropless(cfg)
 
     def stack(key, shape, fan_in):
         ks = jax.random.split(key, L)
@@ -173,16 +200,18 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
 
     layers = {
         "ln1_scale": jnp.ones((L, d)),
-        "ln1_bias": jnp.zeros((L, d)),
         "ln2_scale": jnp.ones((L, d)),
-        "ln2_bias": jnp.zeros((L, d)),
         "wq": stack(keys[0], (d, H, Dh), d),
         "wk": stack(keys[1], (d, H, Dh), d),
         "wv": stack(keys[2], (d, H, Dh), d),
         "wo": stack(keys[3], (H, Dh, d), d),
-        "wi": stack(keys[4], (d, f), d),
-        "wo_mlp": stack(keys[5], (f, d), f),
     }
+    if cfg.norm_kind != "rms":
+        layers.update({"ln1_bias": jnp.zeros((L, d)), "ln2_bias": jnp.zeros((L, d))})
+    if cfg.qk_norm:
+        layers.update({"q_norm_scale": jnp.ones((L, H, Dh)), "k_norm_scale": jnp.ones((L, H, Dh))})
+    if not dropless:  # no layer has a dense feed-forward there
+        layers.update({"wi": stack(keys[4], (d, f), d), "wo_mlp": stack(keys[5], (f, d), f)})
     if cfg.use_bias:
         layers.update(
             {
@@ -190,24 +219,30 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
                 "bk": jnp.zeros((L, H, Dh)),
                 "bv": jnp.zeros((L, H, Dh)),
                 "bo": jnp.zeros((L, d)),
-                "bi": jnp.zeros((L, f)),
-                "bo_mlp": jnp.zeros((L, d)),
             }
         )
+        if not dropless:
+            layers.update({"bi": jnp.zeros((L, f)), "bo_mlp": jnp.zeros((L, d))})
     params = {
         "wte": jax.random.normal(keys[6], (cfg.vocab_size, d)) * 0.02,
         "layers": layers,
         "lnf_scale": jnp.ones((d,)),
-        "lnf_bias": jnp.zeros((d,)),
     }
+    if cfg.norm_kind != "rms":
+        params["lnf_bias"] = jnp.zeros((d,))
     if cfg.pos_emb == "learned":
         params["wpe"] = jax.random.normal(keys[7], (cfg.max_seq_len, d)) * 0.01
     if cfg.embed_ln:
         params["emb_ln_scale"] = jnp.ones((d,))
-        params["emb_ln_bias"] = jnp.zeros((d,))
+        if cfg.norm_kind != "rms":
+            params["emb_ln_bias"] = jnp.zeros((d,))
     if not cfg.tie_embeddings:
         params["lm_head"] = _dense_init(keys[8], (d, cfg.vocab_size), d)
-    if cfg.moe_every > 0:
+    if dropless:
+        from ..moe.dropless import init_dropless
+
+        params["moe"] = init_dropless(keys[9], L, cfg.num_experts, d, f)
+    elif cfg.moe_every > 0:
         from ..moe.layer import init_moe_params
 
         n_moe = cfg.num_layers // cfg.moe_every
@@ -218,18 +253,22 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
 def logical_axes(cfg: TransformerConfig) -> Params:
     """Pytree of logical-axis tuples matching ``init``'s output; consumed by
     parallel/sharding.spec_from_logical."""
+    dropless = _dropless(cfg)
     layers = {
         "ln1_scale": ("layers", "embed"),
-        "ln1_bias": ("layers", "embed"),
         "ln2_scale": ("layers", "embed"),
-        "ln2_bias": ("layers", "embed"),
         "wq": ("layers", "embed", "heads", "kv"),
         "wk": ("layers", "embed", "heads", "kv"),
         "wv": ("layers", "embed", "heads", "kv"),
         "wo": ("layers", "heads", "kv", "embed"),
-        "wi": ("layers", "embed", "mlp"),
-        "wo_mlp": ("layers", "mlp", "embed"),
     }
+    if cfg.norm_kind != "rms":
+        layers.update({"ln1_bias": ("layers", "embed"), "ln2_bias": ("layers", "embed")})
+    if cfg.qk_norm:
+        layers.update({"q_norm_scale": ("layers", "heads", "kv"),
+                       "k_norm_scale": ("layers", "heads", "kv")})
+    if not dropless:
+        layers.update({"wi": ("layers", "embed", "mlp"), "wo_mlp": ("layers", "mlp", "embed")})
     if cfg.use_bias:
         layers.update(
             {
@@ -237,24 +276,30 @@ def logical_axes(cfg: TransformerConfig) -> Params:
                 "bk": ("layers", "heads", "kv"),
                 "bv": ("layers", "heads", "kv"),
                 "bo": ("layers", "embed"),
-                "bi": ("layers", "mlp"),
-                "bo_mlp": ("layers", "embed"),
             }
         )
+        if not dropless:
+            layers.update({"bi": ("layers", "mlp"), "bo_mlp": ("layers", "embed")})
     axes = {
         "wte": ("vocab", "embed"),
         "layers": layers,
         "lnf_scale": ("embed",),
-        "lnf_bias": ("embed",),
     }
+    if cfg.norm_kind != "rms":
+        axes["lnf_bias"] = ("embed",)
     if cfg.pos_emb == "learned":
         axes["wpe"] = (None, "embed")
     if cfg.embed_ln:
         axes["emb_ln_scale"] = ("embed",)
-        axes["emb_ln_bias"] = ("embed",)
+        if cfg.norm_kind != "rms":
+            axes["emb_ln_bias"] = ("embed",)
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
-    if cfg.moe_every > 0:
+    if dropless:
+        from ..moe.dropless import dropless_logical_axes
+
+        axes["moe"] = dropless_logical_axes()
+    elif cfg.moe_every > 0:
         from ..moe.layer import moe_logical_axes
 
         axes["moe"] = moe_logical_axes()
@@ -271,6 +316,21 @@ def layer_norm(x, scale, bias, eps):
     var = jnp.mean(jnp.square(x32 - mu), axis=-1, keepdims=True)
     out = (x32 - mu) * lax.rsqrt(var + eps) * scale + bias
     return out.astype(x.dtype)
+
+
+def rms_norm(x, scale, eps, axes=-1):
+    """RMSNorm over ``axes`` (scale only, no mean taken off), in float32."""
+    x32 = x.astype(jnp.float32)
+    out = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=axes, keepdims=True) + eps) * scale
+    return out.astype(x.dtype)
+
+
+def norm(cfg: "TransformerConfig", x, p, name: str):
+    """The normalisation called ``name`` (ln1, ln2, lnf, emb_ln) of the
+    parameter dict ``p``, of the kind the model has."""
+    if cfg.norm_kind == "rms":
+        return rms_norm(x, p[name + "_scale"], cfg.layernorm_epsilon)
+    return layer_norm(x, p[name + "_scale"], p[name + "_bias"], cfg.layernorm_epsilon)
 
 
 def rotary_embed(x, positions, rotary_dims, interleaved: bool = False):
@@ -576,6 +636,9 @@ def _qkv_proj(cfg: TransformerConfig, lp, h, positions):
             q = q + lp["bq"].astype(h.dtype)
             k = k + lp["bk"].astype(h.dtype)
             v = v + lp["bv"].astype(h.dtype)
+        if cfg.qk_norm:  # over the whole projection: heads and head dimension together
+            q = rms_norm(q, lp["q_norm_scale"], cfg.layernorm_epsilon, axes=(-2, -1))
+            k = rms_norm(k, lp["k_norm_scale"], cfg.layernorm_epsilon, axes=(-2, -1))
         if cfg.pos_emb == "rotary":
             rd = int(cfg.head_dim * cfg.rotary_pct)
             q = rotary_embed(q, positions, rd, interleaved=cfg.rotary_interleaved)
@@ -716,22 +779,22 @@ def _layer_body(cfg: TransformerConfig, attn_fn, carry, lp, alibi_bias, position
         q, k, v = _qkv_proj(cfg, lp, x, positions)
         attn_out = _attn_out_proj(cfg, lp, attn(q, k, v))
         attn_out = gate * _dropout(attn_out, cfg.attn_dropout, k_attn)
-        x = layer_norm(x + attn_out, lp["ln1_scale"], lp["ln1_bias"], cfg.layernorm_epsilon)
+        x = norm(cfg, x + attn_out, lp, "ln1")
         f = gate * _dropout(_ffn(cfg, lp, x), cfg.hidden_dropout, k_hidden)
-        x = layer_norm(x + f, lp["ln2_scale"], lp["ln2_bias"], cfg.layernorm_epsilon)
+        x = norm(cfg, x + f, lp, "ln2")
         return x, None
 
-    h = layer_norm(x, lp["ln1_scale"], lp["ln1_bias"], cfg.layernorm_epsilon)
+    h = norm(cfg, x, lp, "ln1")
     q, k, v = _qkv_proj(cfg, lp, h, positions)
     attn_out = _attn_out_proj(cfg, lp, attn(q, k, v))
     attn_out = gate * _dropout(attn_out, cfg.attn_dropout, k_attn)
 
     if cfg.parallel_residual:
-        h2 = layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.layernorm_epsilon)
+        h2 = norm(cfg, x, lp, "ln2")
         x = x + attn_out + gate * _dropout(_ffn(cfg, lp, h2), cfg.hidden_dropout, k_hidden)
     else:
         x = x + attn_out
-        h2 = layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.layernorm_epsilon)
+        h2 = norm(cfg, x, lp, "ln2")
         x = x + gate * _dropout(_ffn(cfg, lp, h2), cfg.hidden_dropout, k_hidden)
     return x, None
 
@@ -746,7 +809,7 @@ def embed(cfg: TransformerConfig, params: Params, tokens, positions=None):
         if cfg.pos_emb == "learned":
             x = x + params["wpe"][positions].astype(cfg.dtype)
         if cfg.embed_ln:
-            x = layer_norm(x, params["emb_ln_scale"], params["emb_ln_bias"], cfg.layernorm_epsilon)
+            x = norm(cfg, x, params, "emb_ln")
         return x, positions
 
 
@@ -769,10 +832,13 @@ def apply(
     rng: Optional[jax.Array] = None,
     step=None,
     _top_streamed: bool = False,
+    return_routing: bool = False,
 ) -> jnp.ndarray:
     """tokens [B, S] int32 -> logits [B, S, vocab] (fp32), or the final hidden
     states [B, S, d] when ``return_hidden`` (used by the chunked LM loss).
     With ``with_aux`` returns (out, aux_loss) — MoE load-balancing loss.
+    With ``return_routing`` (dropless routing only) the experts chosen for
+    every token in every routed layer come last: int32 [layers, B, S, k].
     ``rng`` enables dropout / progressive layer drop (training); ``step``
     drives the PLD theta schedule. ``_top_streamed``: the caller already
     streamed the top-level leaves (param_offload) — a shared leaf (tied wte)
@@ -781,6 +847,7 @@ def apply(
     rejects."""
     B, S = tokens.shape
     L = cfg.num_layers
+    _routing_asked(cfg, return_routing)
     if not _top_streamed:
         params = _stream_top_level(cfg, params)
     x, positions = embed(cfg, params, tokens, positions)
@@ -832,6 +899,7 @@ def apply(
     unroll = max(1, cfg.scan_unroll)
 
     aux_total = jnp.zeros((), jnp.float32)
+    chosen = None
     E = cfg.moe_every
     if E > 0 and "moe" in params and L % E == 0:
         # Grouped scan: (E-1 dense layers + 1 MoE layer) per group — one
@@ -848,12 +916,11 @@ def apply(
                 x, _ = lax.scan(scan_body, x, dense_part,
                                 unroll=unroll)
             lp_last = load_layer(jax.tree.map(lambda a: a[E - 1], lg))
-            x, aux = _moe_layer(
-                cfg, lp_last, load_moe(moe_p), x, attn_fn, bias, positions, local_bias)
-            return x, aux
+            return _moe_layer(cfg, lp_last, load_moe(moe_p), x, attn_fn, bias, positions,
+                              local_bias, with_experts=True)
 
-        x, auxs = lax.scan(maybe_remat(group_body), x, (layers_g, moe_xs),
-                           unroll=unroll)
+        x, (auxs, chosen) = lax.scan(maybe_remat(group_body), x, (layers_g, moe_xs),
+                                     unroll=unroll)
         aux_total = jnp.sum(auxs)
     elif E > 0:
         # non-uniform depth: python loop fallback
@@ -892,23 +959,47 @@ def apply(
                             unroll=unroll)
 
     if cfg.final_ln:
-        x = layer_norm(x, params["lnf_scale"], params["lnf_bias"], cfg.layernorm_epsilon)
-    if return_hidden:
-        return (x, aux_total) if with_aux else x
-    with jax.named_scope("lm_head"):
-        head = params.get("lm_head", None)
-        if head is None:
-            head = params["wte"].T
-        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-        logits = logits.astype(jnp.float32)
-        if "lm_head_bias" in params:
-            logits = logits + params["lm_head_bias"].astype(jnp.float32)
-    return (logits, aux_total) if with_aux else logits
+        x = norm(cfg, x, params, "lnf")
+    if not return_hidden:
+        with jax.named_scope("lm_head"):
+            head = params.get("lm_head", None)
+            if head is None:
+                head = params["wte"].T
+            x = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype)).astype(jnp.float32)
+            if "lm_head_bias" in params:
+                x = x + params["lm_head_bias"].astype(jnp.float32)
+    out = (x,) + ((aux_total,) if with_aux else ()) + ((chosen,) if return_routing else ())
+    return out if len(out) > 1 else x
 
 
-def _moe_layer(cfg, lp, moe_p, x, attn_fn, bias, positions, local_bias=None):
-    from ..moe.layer import moe_ffn_apply
+def _routing_asked(cfg, return_routing: bool) -> None:
+    if return_routing and cfg.moe_routing != "dropless":
+        raise NotImplementedError(
+            "return_routing needs moe_routing='dropless': the GShard path keeps its "
+            "choices as [T, E, C] one-hot tensors, and a dense model has none")
 
+
+def _moe_ffn(cfg, moe_p, h, decode: bool = False):
+    """The routed feed-forward of one layer on h [B, T, d] -> (out, load-
+    balancing loss, experts chosen [B, T, k] or None). GShard routing keeps
+    its capacity semantics except at a single-token ``decode`` step, where
+    the capacity heuristic degenerates to ~1 slot and drops colliding tokens;
+    dropless routing is one function everywhere."""
+    with jax.named_scope("moe"):
+        if cfg.moe_routing == "dropless":
+            from ..moe.dropless import moe_ffn_dropless
+
+            return moe_ffn_dropless(cfg, moe_p, h)
+        from ..moe.layer import moe_ffn_apply, moe_ffn_dense
+
+        if decode:
+            return moe_ffn_dense(cfg, moe_p, h), jnp.zeros((), jnp.float32), None
+        return moe_ffn_apply(cfg, moe_p, h, mesh=_ACTIVE_MESH[0]) + (None,)
+
+
+def _moe_layer(cfg, lp, moe_p, x, attn_fn, bias, positions, local_bias=None,
+               with_experts: bool = False):
+    """One routed layer -> (x, aux_loss), or (x, (aux_loss, experts chosen))."""
     lp = dict(lp)
     rng = lp.pop("_rng", None)
     pld_keep = lp.pop("_pld_keep", None)
@@ -924,15 +1015,16 @@ def _moe_layer(cfg, lp, moe_p, x, attn_fn, bias, positions, local_bias=None):
     if is_local is not None and local_bias is not None:
         lb = jnp.where(is_local.astype(bool), local_bias, 0.0)[None, None]
         bias = lb if bias is None else bias + lb
-    h = layer_norm(x, lp["ln1_scale"], lp["ln1_bias"], cfg.layernorm_epsilon)
+    h = norm(cfg, x, lp, "ln1")
     q, k, v = _qkv_proj(cfg, lp, h, positions)
     attn_out = gate * _dropout(
         _attn_out_proj(cfg, lp, _attn_call(cfg, attn_fn, q, k, v, bias, is_local)),
         cfg.attn_dropout, k_attn)
     x = x + attn_out
-    h2 = layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.layernorm_epsilon)
-    moe_out, aux_loss = moe_ffn_apply(cfg, moe_p, h2, mesh=_ACTIVE_MESH[0])
-    return x + gate * _dropout(moe_out, cfg.hidden_dropout, k_hidden), aux_loss
+    h2 = norm(cfg, x, lp, "ln2")
+    moe_out, aux_loss, experts = _moe_ffn(cfg, moe_p, h2)
+    x = x + gate * _dropout(moe_out, cfg.hidden_dropout, k_hidden)
+    return x, ((aux_loss, experts) if with_experts else aux_loss)
 
 
 # ---------------------------------------------------------------------------
@@ -1005,6 +1097,7 @@ def cached_attention(q, k_cache, v_cache, pos, *, bias=None):
 def apply_with_cache(
     cfg: TransformerConfig, params: Params, tokens, cache, pos,
     last_only: bool = False, last_index=None, write_pos=None,
+    return_routing: bool = False,
 ):
     """tokens [B, T] entering at absolute position ``pos`` -> (logits, updated
     cache). Serves prefill (T=prompt) and decode (T=1). With ``last_only``
@@ -1028,7 +1121,12 @@ def apply_with_cache(
     caller).
 
     MoE models decode through the same grouped scan as training (every
-    ``moe_every``-th layer routes its FFN through the experts)."""
+    ``moe_every``-th layer routes its FFN through the experts). With
+    ``return_routing`` (dropless routing only) a third value comes back: the
+    experts chosen for each of the tokens given, in every routed layer, int32
+    [layers, B, T, k] — padded and idle rows are routed like any other, so a
+    caller that counts load masks them itself."""
+    _routing_asked(cfg, return_routing)
     if cfg.moe_every > 0 and ("moe" not in params or cfg.num_layers % cfg.moe_every):
         raise NotImplementedError(
             "apply_with_cache with MoE needs num_layers divisible by moe_every "
@@ -1115,9 +1213,10 @@ def apply_with_cache(
     # scan's xs/ys it would be sliced out and restacked layer by layer and
     # copied whole to reconcile the two (tests/test_chip_compile.py guards it).
     def layer_core(carry, lp, l, ffn_fn):
+        # ffn_fn -> (output, the experts a routed feed-forward chose or None)
         x, k_stack, v_stack = carry
         lp = _dequant_layer(cfg, lp)
-        h = layer_norm(x, lp["ln1_scale"], lp["ln1_bias"], cfg.layernorm_epsilon)
+        h = norm(cfg, x, lp, "ln1")
         q, k, v = _qkv_proj(cfg, lp, h, positions)
         k_stack = _write_cache(k_stack, l, k)
         v_stack = _write_cache(v_stack, l, v)
@@ -1132,33 +1231,31 @@ def apply_with_cache(
             attn = cached_attention(q, k_l, v_l, pos, bias=bias)
         attn_out = _attn_out_proj(cfg, lp, attn)
         if cfg.parallel_residual:
-            h2 = layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.layernorm_epsilon)
-            x = x + attn_out + ffn_fn(lp, h2)
+            h2 = norm(cfg, x, lp, "ln2")
+            f, experts = ffn_fn(lp, h2)
+            x = x + attn_out + f
         else:
             x = x + attn_out
-            h2 = layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.layernorm_epsilon)
-            x = x + ffn_fn(lp, h2)
-        return x, k_stack, v_stack
+            h2 = norm(cfg, x, lp, "ln2")
+            f, experts = ffn_fn(lp, h2)
+            x = x + f
+        return (x, k_stack, v_stack), experts
 
     def layer(carry, inputs):
         lp, l = inputs
-        return layer_core(carry, load_layer(lp), l, lambda lp, h2: _ffn(cfg, lp, h2)), None
+        return layer_core(carry, load_layer(lp), l, lambda lp, h2: (_ffn(cfg, lp, h2), None))
 
     carry = (x, cache["k"], cache["v"])
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    chosen = None
     if cfg.moe_every > 0:
-        from ..moe.layer import moe_ffn_apply, moe_ffn_dense
-
         E = cfg.moe_every
         G = cfg.num_layers // E
         regroup = lambda a: a.reshape((G, E) + a.shape[1:])
-        # decode (T=1): capacity-free routing — the capacity heuristic
-        # degenerates to ~1 slot at single-token steps and drops colliding
-        # tokens; prefill keeps training's GShard capacity semantics
-        if T == 1:
-            moe_fn = lambda moe_p, h2: moe_ffn_dense(cfg, moe_p, h2)
-        else:
-            moe_fn = lambda moe_p, h2: moe_ffn_apply(cfg, moe_p, h2, mesh=_ACTIVE_MESH[0])[0]
+
+        def moe_fn(moe_p, h2):
+            out, _, experts = _moe_ffn(cfg, moe_p, h2, decode=T == 1)
+            return out, experts
 
         def group_layer(carry, xs):
             # E-1 dense layers, then the group's MoE layer, all on the carry
@@ -1168,9 +1265,9 @@ def apply_with_cache(
                 carry, _ = lax.scan(layer, carry, (firsts, ids[: E - 1]))
             lp_last = load_layer(jax.tree.map(lambda a: a[E - 1], lg))
             return layer_core(carry, lp_last, ids[E - 1],
-                              lambda lp, h2: moe_fn(load_moe(moe_p), h2)), None
+                              lambda lp, h2: moe_fn(load_moe(moe_p), h2))
 
-        (x, new_k, new_v), _ = lax.scan(
+        (x, new_k, new_v), chosen = lax.scan(
             group_layer, carry,
             (jax.tree.map(regroup, layers_xs), moe_xs, regroup(layer_ids)))
     else:
@@ -1182,14 +1279,15 @@ def apply_with_cache(
     elif last_only:
         x = x[:, -1:]
     if cfg.final_ln:
-        x = layer_norm(x, params["lnf_scale"], params["lnf_bias"], cfg.layernorm_epsilon)
+        x = norm(cfg, x, params, "lnf")
     head = params.get("lm_head", None)
     if head is None:
         head = params["wte"].T
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype)).astype(jnp.float32)
     if "lm_head_bias" in params:
         logits = logits + params["lm_head_bias"].astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v}
+    cache = {"k": new_k, "v": new_v}
+    return (logits, cache, chosen) if return_routing else (logits, cache)
 
 
 # ---------------------------------------------------------------------------

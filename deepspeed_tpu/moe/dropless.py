@@ -1,0 +1,153 @@
+"""Dropless top-k routing over a bank of gated experts (``moe_routing:
+"dropless"``): the mixture-of-experts block of OLMoE / Mixtral-style models,
+which are trained without capacity and so have no token to drop.
+
+Beside the GShard path (``sharded_moe.py``: top-1 / top-2, a capacity, one-hot
+``[T, E, C]`` dispatch and combine einsums, tokens over capacity dropped) this
+one has no capacity and no ``[T, E, C]`` tensor at any skew:
+
+* ``route``: router logits and a softmax over every expert in float32, top-k
+  for any k, the k weights renormalised or left as the raw probabilities
+  (``moe_norm_topk_prob``, a model's ``norm_topk_prob``); the one router of
+  training, prefill and decode.
+* ``experts_sorted``: the token-expert pairs sorted by expert, one grouped
+  matmul per projection over the contiguous groups (``jax.lax.ragged_dot``:
+  on a TPU a grouped-GEMM kernel that visits only the rows there are),
+  unsorted, and summed with the weights.
+* ``experts_dense``: every expert on every row, each expert's weights read
+  once; for the few rows of a decode step, where the sorted form reads the
+  same bytes and pays a sort, two gathers and near-empty tiles on top
+  (PERF.md §6, PR 27 has the chip's timings of both).
+
+Experts are ``down(silu(gate(x)) * up(x))``, stacked ``[layers, E, ...]`` under
+the ``expert`` logical axis so a mesh shards them like the GShard bank.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from .experts import experts_logical_axes
+
+# Rows (tokens of one call) up to which every expert is computed on every row
+# instead of sorting the pairs: a decode or verify step, a short chunk. From
+# the chip's timings of one layer's block at OLMoE's widths (PERF.md §6, PR 27;
+# ms, dense / sorted): 3.0 / 5.7 at 16 rows, 3.0 / 7.3 at 256, 3.7 / 7.5 at 512,
+# 6.3 / 8.1 at 1024, 16.0 / 8.9 at 2048 with the weights held in float32 as
+# today, and 1.9 / 2.0, 2.0 / 3.6, 3.4 / 3.7, 6.1 / 4.3, 13.2 / 5.2 held in
+# bfloat16: up to 512 the dense form wins either way.
+DENSE_ROWS = 512
+
+
+def init_dropless(rng, n_layers: int, num_experts: int, d_model: int, d_ff: int):
+    """Router and gated expert bank of ``n_layers`` routed layers; every stack
+    is drawn whole, in one call (no per-layer list to restack)."""
+    keys = jax.random.split(rng, 4)
+
+    def draw(key, shape, fan_in):
+        return jax.random.normal(key, shape, jnp.float32) * (1.0 / math.sqrt(fan_in))
+
+    bank = (n_layers, num_experts)
+    return {
+        "gate": draw(keys[0], (n_layers, d_model, num_experts), d_model),
+        "experts": {
+            "wg": draw(keys[1], bank + (d_model, d_ff), d_model),
+            "wi": draw(keys[2], bank + (d_model, d_ff), d_model),
+            "wo": draw(keys[3], bank + (d_ff, d_model), d_ff),
+        },
+    }
+
+
+def dropless_logical_axes():
+    ex = experts_logical_axes()
+    ex["wg"] = ex["wi"]
+    return {"gate": (None, "embed", None), "experts": {k: (None,) + v for k, v in ex.items()}}
+
+
+def route(x, gate_w, top_k: int, renormalize: bool):
+    """x [T, M] -> (weights [T, k] float32, experts [T, k] int32, probs [T, E]
+    float32). The logits are a float32 product at full precision whatever the
+    compute dtype: the gap between the k-th and the (k+1)-th expert is a few
+    hundredths of the logits' spread, and a bf16 product would move it."""
+    logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = lax.top_k(probs, top_k)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts.astype(jnp.int32), probs
+
+
+def load_balance_loss(probs, experts):
+    """E x sum_e(share of the token-expert pairs sent to e x mean router
+    probability of e): 1 when both are uniform (the GShard / Switch term, with
+    the share taken over all k choices)."""
+    E = probs.shape[-1]
+    share = jnp.mean(jax.nn.one_hot(experts, E, dtype=jnp.float32), axis=(0, 1))
+    return E * jnp.sum(share * jnp.mean(probs, axis=0))
+
+
+def _gated(gate, up):
+    return jax.nn.silu(gate) * up
+
+
+def experts_sorted(bank, x, weights, experts):
+    """x [T, M] through the chosen experts: pairs sorted by expert, grouped
+    matmuls over the groups as they are (no capacity, no padding to one)."""
+    T, M = x.shape
+    k = experts.shape[1]
+    E = bank["wi"].shape[0]
+    flat = experts.reshape(T * k)
+    order = jnp.argsort(flat)  # pairs by expert; pair p is token p // k
+    sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+    xs = x[order // k]
+    w = {name: leaf.astype(x.dtype) for name, leaf in bank.items()}
+    h = _gated(lax.ragged_dot(xs, w["wg"], sizes), lax.ragged_dot(xs, w["wi"], sizes))
+    ys = lax.ragged_dot(h, w["wo"], sizes)  # [T * k, M], still sorted
+    ys = ys[jnp.argsort(order)].reshape(T, k, M)
+    return jnp.einsum("tkm,tk->tm", ys.astype(jnp.float32), weights).astype(x.dtype)
+
+
+def experts_dense(bank, x, weights, experts):
+    """Every expert on every row of x [T, M]; the weights of those not chosen
+    are zero."""
+    T = x.shape[0]
+    E = bank["wi"].shape[0]
+    w = {name: leaf.astype(x.dtype) for name, leaf in bank.items()}
+    h = _gated(jnp.einsum("tm,emf->etf", x, w["wg"]), jnp.einsum("tm,emf->etf", x, w["wi"]))
+    ys = jnp.einsum("etf,efm->etm", h, w["wo"])
+    mix = jnp.zeros((T, E), jnp.float32).at[jnp.arange(T)[:, None], experts].set(weights)
+    return jnp.einsum("etm,te->tm", ys.astype(jnp.float32), mix).astype(x.dtype)
+
+
+def moe_ffn_dropless(cfg, moe_p, h):
+    """h [B, S, M] -> (out [B, S, M], load-balancing loss, experts [B, S, k])."""
+    B, S, M = h.shape
+    x = h.reshape(B * S, M)
+    weights, experts, probs = route(x, moe_p["gate"], cfg.moe_top_k, cfg.moe_norm_topk_prob)
+    form = experts_dense if B * S <= DENSE_ROWS else experts_sorted
+    out = form(moe_p["experts"], x, weights, experts)
+    return (out.reshape(B, S, M), load_balance_loss(probs, experts),
+            experts.reshape(B, S, cfg.moe_top_k))
+
+
+def expert_load(experts, live, num_experts: int):
+    """experts [layers, B, T, k], live [B, T] bool -> int32 [layers, E]: the
+    rows that count (not bucket padding, not an idle slot) sent to each expert."""
+    hot = jax.nn.one_hot(experts, num_experts, dtype=jnp.int32)  # [layers, B, T, k, E]
+    return jnp.sum(hot * live[None, :, :, None, None].astype(jnp.int32), axis=(1, 2, 3))
+
+
+def load_summary(load) -> dict:
+    """Host side, load [layers, E] as fetched: how uneven the routing of one
+    call was (the worst layer's busiest expert over its mean expert) and how
+    many experts a layer touched on average."""
+    load = np.asarray(load, np.float64)
+    mean = np.maximum(load.mean(axis=1), 1e-9)
+    return {"expert_load_max_over_mean": float(np.max(load.max(axis=1) / mean)),
+            "experts_touched": float(np.mean(np.count_nonzero(load, axis=1)))}

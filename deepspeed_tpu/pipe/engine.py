@@ -413,9 +413,7 @@ class PipelineEngine(DeepSpeedEngine):
                 return h
 
             def loss_head(hp, y, labels_b):
-                h = tfm.layer_norm(
-                    y, hp["lnf_scale"], hp["lnf_bias"], cfg.layernorm_epsilon
-                )
+                h = tfm.norm(cfg, y, hp, "lnf")
                 return tfm.lm_loss_from_hidden(cfg, hp, h, labels_b)
 
             loss, g_stage, g_head, gx, _trace = pipeline_train_1f1b(

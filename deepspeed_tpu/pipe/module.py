@@ -332,9 +332,7 @@ class PipelinedTransformer(Model):
                 stage_fn, params["layers"], x_mb, self.num_stages, self.mesh)
             aux = jnp.zeros((), jnp.float32)
         hidden = out_mb.reshape((B,) + out_mb.shape[2:])
-        hidden = tfm.layer_norm(
-            hidden, params["lnf_scale"], params["lnf_bias"], cfg.layernorm_epsilon
-        )
+        hidden = tfm.norm(cfg, hidden, params, "lnf")
         nll = tfm.lm_loss_from_hidden(cfg, params, hidden, labels)
         # aux accumulated once per microbatch per group: average over M to
         # match the base model's per-batch group sum

@@ -184,6 +184,33 @@ def test_decode_program_keeps_the_slot_cache_in_place(family, v5e, no_persistent
     assert ma.temp_size_in_bytes < layer_bytes, (ma.temp_size_in_bytes, layer_bytes)
 
 
+@pytest.mark.parametrize("rows,grouped", [(16, False), (2048, True)], ids=["decode", "prefill"])
+def test_dropless_expert_block_at_olmoe_widths(rows, grouped, v5e, no_persistent_cache):
+    """One routed layer of OLMoE (64 gated experts of 2048 x 1024, top-8, bf16 compute,
+    float32 weights as held): a 2048-row prefill goes through the compiler's grouped-GEMM
+    kernel (``ragged_dot``: three calls and their group metadata) and holds nothing of the
+    size of a GShard ``[T, E, C]`` dispatch tensor (2048 x 64 x 320 floats = 168 MB); a
+    16-row decode step computes every expert densely, with no kernel call."""
+    from deepspeed_tpu.models.transformer import TransformerConfig
+    from deepspeed_tpu.moe.dropless import moe_ffn_dropless
+
+    cfg = TransformerConfig(hidden_size=2048, intermediate_size=1024, num_experts=64, moe_top_k=8,
+                            moe_routing="dropless", activation="swiglu", moe_every=1,
+                            dtype=jnp.bfloat16)
+    one_chip = SingleDeviceSharding(v5e[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    bank = {"wg": (64, 2048, 1024), "wi": (64, 2048, 1024), "wo": (64, 1024, 2048)}
+    moe_p = {"gate": sds((2048, 64), jnp.float32),
+             "experts": {k: sds(shape, jnp.float32) for k, shape in bank.items()}}
+    compiled = jax.jit(lambda p, h: moe_ffn_dropless(cfg, p, h)).lower(
+        moe_p, sds((1, rows, 2048), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert (text.count('custom_call_target="tpu_custom_call"') >= 3) == grouped
+    weights_bf16 = 3 * 64 * 2048 * 1024 * 2  # the cast of this layer's experts
+    pairs = rows * 8 * 2048 * 2  # the gathered rows of every token-expert pair, bf16
+    assert compiled.memory_analysis().temp_size_in_bytes < weights_bf16 + 6 * pairs + 2 ** 26
+
+
 # ---------------------------------------------------------------------------
 # whole programs at GPT-2 125M (slow tier)
 # ---------------------------------------------------------------------------
