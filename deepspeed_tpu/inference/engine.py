@@ -17,6 +17,17 @@ TPU-native design:
                            whole generation is a single device call).
   * KV cache            -> static [L, B, Smax, H, Dh] arrays, donated between
                            steps (models/transformer.apply_with_cache).
+  * module.half()       -> the engine holds every leaf in the dtype the
+                           forward pass reads it in, cast once when it is
+                           built (models/transformer.hold_for_compute): with
+                           dtype bf16 the matrices, embeddings and
+                           activation-dtype biases are bf16; norm scales and
+                           biases, lm_head_bias and a router's gate stay
+                           float32. The same rule whether the weights are
+                           drawn here, passed as ``params`` or converted from
+                           an HF checkpoint; ``engine.params`` (and so
+                           ``SlotWorker.hbm_pools()["params"]``) is that tree,
+                           and no program casts a weight.
 """
 
 from __future__ import annotations
@@ -96,20 +107,16 @@ class InferenceEngine:
             axes_tree, shape_tree, shd.DEFAULT_TP_RULES, self.mesh
         )
         shardings = shd.tree_shardings(self.mesh, self.param_specs)
+        # One rule for what the engine holds, however the weights arrive
+        # (tfm.hold_for_compute: the reference's module.half(), but for the
+        # leaves the forward pass reads in float32). No program casts a weight.
         if params is None:
-            params = jax.jit(model.init, out_shardings=shardings)(jax.random.PRNGKey(0))
+            # drawn and rounded in one program: the float32 tree is never resident
+            params = jax.jit(lambda r: tfm.hold_for_compute(self.cfg, model.init(r)),
+                             out_shardings=shardings)(jax.random.PRNGKey(0))
         else:
-            # weights live in the engine dtype (bf16 halves HBM vs fp32, like
-            # the reference's module.half() conversion); ints (e.g. rotary
-            # position tables) keep their dtype
-            np_dtype = np.dtype(jnp.dtype(dtype).name)
-
-            def _cast(x):
-                x = np.asarray(x)
-                return x.astype(np_dtype) if np.issubdtype(x.dtype, np.floating) else x
-
-            params = jax.tree.map(_cast, params)
-            params = jax.device_put(params, shardings)
+            params = jax.device_put(
+                tfm.hold_for_compute(self.cfg, jax.tree.map(np.asarray, params)), shardings)
         self.params = params
 
         # --- weight-only int8/int4 quantization (reference: MoQ injection +
@@ -134,10 +141,14 @@ class InferenceEngine:
 
         self._fwd = None
         self._generate = {}
-        n_params = sum(int(np.prod(s)) for s in jax.tree.leaves(shape_tree))
+        n_params = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
+        held: dict[str, int] = {}
+        for leaf in jax.tree.leaves(self.params):
+            held[leaf.dtype.name] = held.get(leaf.dtype.name, 0) + int(leaf.nbytes)
         log_dist(
             f"inference engine: {n_params/1e6:.1f}M params, tp={tp_size}, "
-            f"mesh={dict(self.mesh.shape)}, dtype={jnp.dtype(dtype).name}",
+            f"mesh={dict(self.mesh.shape)}, dtype={jnp.dtype(dtype).name}, held "
+            + ", ".join(f"{n / 1e9:.3f} GB {d}" for d, n in sorted(held.items())),
             ranks=[0],
         )
 

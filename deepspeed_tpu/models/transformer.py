@@ -112,7 +112,9 @@ class TransformerConfig:
     # plays this role, runtime/zero/parameter_offload.py). Costs one extra
     # layer's params resident per unroll step; no effect on math.
     scan_unroll: int = 1
-    dtype: Any = jnp.float32  # compute dtype (params always stored fp32)
+    # compute dtype. init() draws float32 leaves and training keeps them as its
+    # masters; hold_for_compute() gives the tree an inference engine holds
+    dtype: Any = jnp.float32
     moe_every: int = 0  # >0: every Nth layer is an MoE FFN (see moe/)
     num_experts: int = 1
     moe_top_k: int = 1
@@ -309,6 +311,45 @@ def logical_axes(cfg: TransformerConfig) -> Params:
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
+
+# The leaves the forward pass below casts to ``cfg.dtype`` before it uses them
+# (``.astype(h.dtype)`` and its like): the embeddings (``embed``), the
+# vocabulary projection, the attention and feed-forward matrices and the biases
+# added in the activations' dtype (``_qkv_proj``, ``_attn_out_proj``, ``_ffn``),
+# and the expert banks (``wg`` / ``wi`` / ``wo`` of moe/dropless.py and
+# moe/experts.py). Every other floating leaf is read in float32: the norm scales
+# and biases (``layer_norm`` / ``rms_norm`` multiply in float32), the q/k norm
+# scales, ``lm_head_bias`` (added to float32 logits) and the router's ``gate``
+# (``dropless.route`` is a float32 product at full precision, and the GShard
+# path's ``moe_dispatch_combine`` likewise).
+_READ_IN_COMPUTE_DTYPE = frozenset({
+    "wte", "wpe", "lm_head",
+    "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo",
+    "wi", "wo_mlp", "bi", "bo_mlp", "wg",
+})
+
+
+def hold_for_compute(cfg: TransformerConfig, params: Params) -> Params:
+    """``params`` with every floating leaf in the dtype the forward pass reads
+    it in: ``cfg.dtype`` for the leaves of ``_READ_IN_COMPUTE_DTYPE``, float32
+    for the rest. The forward pass's own casts are then no-ops, and its outputs
+    are those it computes from the float32 tree, bit for bit: a leaf rounded
+    once is the same number as that leaf rounded in every call. What an engine
+    that only runs the forward pass should hold (``InferenceEngine``); training
+    keeps float32 masters and calls the same forward pass on them. Integer
+    leaves and quantised storage (``{"q", "s"}`` under a matrix's name) pass
+    through; with ``cfg.dtype`` float32 nothing changes. Takes numpy leaves on
+    the host and traced ones inside ``jit`` alike."""
+    held = jnp.dtype(cfg.dtype)
+
+    def hold(path, leaf):
+        if not jnp.issubdtype(leaf.dtype, jnp.floating):
+            return leaf
+        name = getattr(path[-1], "key", None)
+        return leaf.astype(held if name in _READ_IN_COMPUTE_DTYPE else jnp.float32)
+
+    return jax.tree_util.tree_map_with_path(hold, params)
+
 
 def layer_norm(x, scale, bias, eps):
     x32 = x.astype(jnp.float32)

@@ -122,15 +122,17 @@ def test_kernel_compiles_for_v5e(build, v5e, no_persistent_cache):
     assert _footprint(compiled) < HBM_BYTES
 
 
-def _bare_slot_worker(cfg, n, Smax, one_chip, param_dtype=None):
+def _bare_slot_worker(cfg, n, Smax, one_chip):
     """A ``SlotWorker`` with just what its program builders read, and the
-    shapes of its operands on the described chip: (worker, params, cache, sds)."""
+    shapes of its operands on the described chip, the weights typed as
+    ``InferenceEngine`` holds them: (worker, params, cache, sds)."""
     from deepspeed_tpu.inference.serving import SlotWorker
-    from deepspeed_tpu.models.transformer import Model
+    from deepspeed_tpu.models.transformer import Model, hold_for_compute
 
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    params = jax.tree.map(lambda x: sds(x.shape, param_dtype or x.dtype),
-                          jax.eval_shape(Model(cfg).init, jax.random.PRNGKey(0)))
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda r: hold_for_compute(cfg, Model(cfg).init(r)), jax.random.PRNGKey(0)))
     kv = sds((cfg.num_layers, n, Smax, cfg.num_heads, cfg.head_dim), jnp.bfloat16)
     worker = SlotWorker.__new__(SlotWorker)
     worker.cfg, worker.Smax = cfg, Smax
@@ -145,30 +147,49 @@ def _compile_decode(worker, params, cache, n, sds):
         sds((2,), jnp.uint32), vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)).compile()
 
 
+def _compile_prefill(worker, params, cache, bucket, sds):
+    one = lambda dtype: sds((1,), dtype)
+    return worker._build_prefill(bucket).lower(
+        params, cache, sds((1, bucket), jnp.int32), sds((), jnp.int32), sds((), jnp.int32),
+        sds((2,), jnp.uint32), one(jnp.float32), one(jnp.int32), one(jnp.float32)).compile()
+
+
+_FAMILIES = {
+    "bloom_dense_alibi": dict(pos_emb="alibi", embed_ln=True, activation="gelu"),
+    "pythia_pallas_kernel": dict(pos_emb="rotary", rotary_pct=0.25, parallel_residual=True,
+                                 tie_embeddings=False, activation="gelu_exact"),
+    "olmoe_dropless": dict(pos_emb="rotary", norm_kind="rms", qk_norm=True, use_bias=False,
+                           tie_embeddings=False, activation="swiglu", moe_routing="dropless",
+                           moe_every=1, num_experts=64, moe_top_k=8, intermediate_size=1024),
+}
+
+
+def _family_cfg(family, L, Smax, H=16, Dh=128):
+    """A benchmark configuration's block at its published widths, ``L`` layers
+    and a vocabulary of 1024."""
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    fields = dict(intermediate_size=4 * H * Dh, use_bias=True)
+    fields.update(_FAMILIES[family])
+    return TransformerConfig(vocab_size=1024, max_seq_len=Smax, num_layers=L, num_heads=H,
+                             hidden_size=H * Dh, dtype=jnp.bfloat16, **fields)
+
+
 # ---------------------------------------------------------------------------
 # the decode program keeps the slot cache in place (tier 1)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("family", ["bloom_dense_alibi", "pythia_pallas_kernel"])
 def test_decode_program_keeps_the_slot_cache_in_place(family, v5e, no_persistent_cache, as_tpu):
-    """``SlotWorker``'s decode step at the widths of the benchmark's two
-    configurations (4 layers, 8 slots x 512, vocabulary 1024, bf16 weights so
-    that the temporaries are not weight casts): the stacked cache is the layer
-    loop's carry, donated in and aliased out. Put it back into the scan's
-    xs/ys and the compiler slices a layer out and restacks it in every
-    iteration and copies the whole cache twice to reconcile the buffers
-    (both assertions then fail, as they do on the code before PR 25)."""
-    from deepspeed_tpu.models.transformer import TransformerConfig
-
-    fields = {"bloom_dense_alibi": dict(pos_emb="alibi", embed_ln=True, activation="gelu"),
-              "pythia_pallas_kernel": dict(pos_emb="rotary", rotary_pct=0.25, parallel_residual=True,
-                                           tie_embeddings=False, activation="gelu_exact")}[family]
+    """``SlotWorker``'s decode step at the widths of the benchmark's two dense
+    configurations (4 layers, 8 slots x 512, vocabulary 1024): the stacked
+    cache is the layer loop's carry, donated in and aliased out. Put it back
+    into the scan's xs/ys and the compiler slices a layer out and restacks it
+    in every iteration and copies the whole cache twice to reconcile the
+    buffers (both assertions then fail, as they do on the code before PR 25)."""
     L, n, Smax, H, Dh = 4, 8, 512, 16, 128
-    cfg = TransformerConfig(vocab_size=1024, max_seq_len=Smax, num_layers=L, num_heads=H,
-                            hidden_size=H * Dh, intermediate_size=4 * H * Dh, use_bias=True,
-                            dtype=jnp.bfloat16, **fields)
-    worker, params, cache, sds = _bare_slot_worker(
-        cfg, n, Smax, SingleDeviceSharding(v5e[0]), param_dtype=jnp.bfloat16)
+    cfg = _family_cfg(family, L, Smax, H, Dh)
+    worker, params, cache, sds = _bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0]))
     compiled = _compile_decode(worker, params, cache, n, sds)
 
     text = compiled.as_text()
@@ -184,13 +205,48 @@ def test_decode_program_keeps_the_slot_cache_in_place(family, v5e, no_persistent
     assert ma.temp_size_in_bytes < layer_bytes, (ma.temp_size_in_bytes, layer_bytes)
 
 
+@pytest.mark.parametrize("family,L", [("bloom_dense_alibi", 24), ("pythia_pallas_kernel", 24),
+                                      ("olmoe_dropless", 4)])
+def test_serving_programs_cast_no_stacked_weight(family, L, v5e, no_persistent_cache, as_tpu):
+    """``SlotWorker``'s decode step and its 2048-row prefill at the widths and
+    depths of the benchmark's three configurations (8 slots x 2048, vocabulary
+    1024), on operands typed as ``InferenceEngine`` holds them
+    (``hold_for_compute``): no ``convert`` yields a whole stacked weight; the
+    decode step's temporaries are under the bf16 bytes of the largest stacked
+    leaf, and the prefill's (its local K/V, the dense score matrix, for OLMoE
+    layer l of each expert bank sliced out for the grouped-GEMM kernel) under
+    those of all the stacks together. On ``model.init``'s float32 operands,
+    which the engine held before PR 28, all of it fails: every program casts
+    every stack (twelve converts for BLOOM, the three expert banks for OLMoE)
+    and carries the bf16 copies as temporaries on top of the rest (2.42 GB of
+    the decode step's 2.42 and of the prefill's 3.79 for BLOOM, 3.26 GB of
+    3.26 and of 3.85 for OLMoE-L4)."""
+    n, Smax = 8, 2048
+    cfg = _family_cfg(family, L, Smax)
+    worker, params, cache, sds = _bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0]))
+    stacks = [x.shape for x in jax.tree.leaves(params)  # the matrices, stacked [L, ...]
+              if x.ndim >= 3 and x.shape[0] == L and x.dtype == jnp.bfloat16]
+    nbytes = [int(np.prod(shape)) * 2 for shape in stacks]
+    shapes = "|".join(re.escape(",".join(map(str, shape))) for shape in sorted(set(stacks)))
+    for name, compiled, bound in (
+            ("decode", _compile_decode(worker, params, cache, n, sds), max(nbytes)),
+            ("prefill", _compile_prefill(worker, params, cache, Smax, sds), sum(nbytes))):
+        casts = re.findall(rf"^\s*%?[\w.-]+ = \w+\[(?:{shapes})\]\S* convert\(",
+                           compiled.as_text(), re.M)
+        assert not casts, f"the {name} program casts a whole stacked weight: {casts}"
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < bound, (name, temp, bound)
+
+
 @pytest.mark.parametrize("rows,grouped", [(16, False), (2048, True)], ids=["decode", "prefill"])
 def test_dropless_expert_block_at_olmoe_widths(rows, grouped, v5e, no_persistent_cache):
-    """One routed layer of OLMoE (64 gated experts of 2048 x 1024, top-8, bf16 compute,
-    float32 weights as held): a 2048-row prefill goes through the compiler's grouped-GEMM
-    kernel (``ragged_dot``: three calls and their group metadata) and holds nothing of the
-    size of a GShard ``[T, E, C]`` dispatch tensor (2048 x 64 x 320 floats = 168 MB); a
-    16-row decode step computes every expert densely, with no kernel call."""
+    """One routed layer of OLMoE (64 gated experts of 2048 x 1024, top-8, bf16 compute, the
+    bank in bf16 and the router in float32 as ``InferenceEngine`` holds them): a 2048-row
+    prefill goes through the compiler's grouped-GEMM kernel (``ragged_dot``: three calls and
+    their group metadata) and holds nothing of the size of a GShard ``[T, E, C]`` dispatch
+    tensor (2048 x 64 x 320 floats = 168 MB) nor a copy of the bank (805 MB, what the cast of
+    a float32 bank took before PR 28); a 16-row decode step computes every expert densely,
+    with no kernel call."""
     from deepspeed_tpu.models.transformer import TransformerConfig
     from deepspeed_tpu.moe.dropless import moe_ffn_dropless
 
@@ -201,14 +257,14 @@ def test_dropless_expert_block_at_olmoe_widths(rows, grouped, v5e, no_persistent
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     bank = {"wg": (64, 2048, 1024), "wi": (64, 2048, 1024), "wo": (64, 1024, 2048)}
     moe_p = {"gate": sds((2048, 64), jnp.float32),
-             "experts": {k: sds(shape, jnp.float32) for k, shape in bank.items()}}
+             "experts": {k: sds(shape, jnp.bfloat16) for k, shape in bank.items()}}
     compiled = jax.jit(lambda p, h: moe_ffn_dropless(cfg, p, h)).lower(
         moe_p, sds((1, rows, 2048), jnp.bfloat16)).compile()
     text = compiled.as_text()
     assert (text.count('custom_call_target="tpu_custom_call"') >= 3) == grouped
-    weights_bf16 = 3 * 64 * 2048 * 1024 * 2  # the cast of this layer's experts
+    assert not re.findall(r"= \w+\[64,(?:2048,1024|1024,2048)\]\S* convert\(", text)
     pairs = rows * 8 * 2048 * 2  # the gathered rows of every token-expert pair, bf16
-    assert compiled.memory_analysis().temp_size_in_bytes < weights_bf16 + 6 * pairs + 2 ** 26
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * pairs + 2 ** 26
 
 
 # ---------------------------------------------------------------------------
@@ -289,17 +345,11 @@ def test_serving_programs_125m_compile_for_one_v5e(v5e, no_persistent_cache, as_
         num_heads=sz["H"], hidden_size=sz["D"], pos_emb="learned", dtype=jnp.bfloat16)
     n, Smax = sz["n_slots"], sz["S"]
     worker, params, cache, sds = _bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0]))
-    key = sds((2,), jnp.uint32)
     decode = _compile_decode(worker, params, cache, n, sds)
     assert "tpu_custom_call" in decode.as_text()  # the Pallas decode kernel
     worst = _footprint(decode)
-    one = lambda dtype: sds((1,), dtype)
     buckets = sorted({max(16, _next_pow2(p)) for p in sz["prompt_lens"]})
     for bucket in (buckets[0], buckets[-1]):
-        prefill = worker._build_prefill(bucket).lower(
-            params, cache, sds((1, bucket), jnp.int32), sds((), jnp.int32),
-            sds((), jnp.int32), key, one(jnp.float32), one(jnp.int32),
-            one(jnp.float32)).compile()
-        worst = max(worst, _footprint(prefill))
+        worst = max(worst, _footprint(_compile_prefill(worker, params, cache, bucket, sds)))
     print(f"serving programs 125M on one v5e: worst footprint {worst / 1e9:.2f} GB")
     assert worst < HBM_BYTES
