@@ -12,13 +12,13 @@ from .experts import apply_experts, experts_logical_axes, init_experts
 from .sharded_moe import moe_dispatch_combine
 
 
-def init_moe_params(rng, num_moe_layers: int, num_experts: int, d_model: int, d_ff: int):
-    """Stacked MoE params with leading [n_moe_layers] dim."""
-    keys = jax.random.split(rng, num_moe_layers + 1)
+def init_moe_params(rng, num_routed: int, num_experts: int, d_model: int, d_ff: int):
+    """Stacked MoE params with leading [num_routed] dim."""
+    keys = jax.random.split(rng, num_routed + 1)
     gates = jnp.stack(
-        [jax.random.normal(k, (d_model, num_experts)) * (1.0 / math.sqrt(d_model)) for k in keys[:num_moe_layers]]
+        [jax.random.normal(k, (d_model, num_experts)) * (1.0 / math.sqrt(d_model)) for k in keys[:num_routed]]
     )
-    banks = [init_experts(jax.random.fold_in(keys[-1], i), num_experts, d_model, d_ff) for i in range(num_moe_layers)]
+    banks = [init_experts(jax.random.fold_in(keys[-1], i), num_experts, d_model, d_ff) for i in range(num_routed)]
     all_experts = jax.tree.map(lambda *xs: jnp.stack(xs), *banks)
     return {"gate": gates, "experts": all_experts}
 

@@ -17,14 +17,15 @@ when enabled. The *stochastic* variant maps to per-call dropout keys derived
 from a step counter (the reference trades exact replay for speed; here replay
 is controlled by whether the caller fixes the rng).
 
-The implementation reuses the model family's layer body
-(models/transformer.py:_layer_body) so numerics, dropout semantics, and remat
+Both layers ARE the model family's block
+(models/transformer.py:_block) so numerics, dropout semantics, and remat
 behavior are identical to what the training engine compiles.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Optional
 
 import jax
@@ -103,21 +104,20 @@ class DeepSpeedTransformerLayer:
 
     def apply(self, params: dict, hidden_states, attention_mask=None, rng=None):
         cfg = self._cfg
-        lp = dict(params)
         if rng is None and self.config.stochastic_mode and self.config.training:
             # stochastic mode: fresh dropout mask per call, no replay contract
             rng = jax.random.fold_in(jax.random.PRNGKey(self.config.seed), self._counter)
             self._counter += 1
-        if rng is not None and (cfg.hidden_dropout > 0 or cfg.attn_dropout > 0):
-            lp["_rng"] = rng
-        x = hidden_states.astype(cfg.dtype)
         bias = None
         if attention_mask is not None:
             bias = jnp.asarray(attention_mask, jnp.float32)
             while bias.ndim < 4:
                 bias = bias[:, None]
-        attn_fn = lambda q, k, v, b: mt.xla_attention(q, k, v, bias=b, causal=False)
-        out, _ = mt._layer_body(cfg, attn_fn, x, lp, alibi_bias=bias, positions=None)
+        (out, _), _ = mt._block(
+            cfg, (hidden_states.astype(cfg.dtype), None), params, 0, positions=None, rng=rng,
+            attend=lambda q, k, v, state, l: (
+                mt.xla_attention(q, k, v, bias=bias, causal=False), state),
+            ffn=partial(mt._dense_ffn, cfg))
         return (out,) if self.config.return_tuple else out
 
     __call__ = apply
@@ -162,6 +162,7 @@ class DeepSpeedInferenceConfig:
             norm_style="pre" if self.pre_layer_norm else "post",
             layernorm_epsilon=self.layer_norm_eps,
             dtype=jnp.bfloat16 if self.fp16 else jnp.float32,
+            decode_attn="xla",
         )
 
 
@@ -190,29 +191,13 @@ class DeepSpeedTransformerInference:
 
     def apply(self, params: dict, hidden_states, cache: dict, pos):
         cfg = self._cfg
-        eps = cfg.layernorm_epsilon
         x = hidden_states.astype(cfg.dtype)
-        B, T = x.shape[0], x.shape[1]
-        positions = pos + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-        pre_ln = cfg.norm_style == "pre"
-        h = (mt.layer_norm(x, params["ln1_scale"], params["ln1_bias"], eps)
-             if pre_ln else x)
-        q, k, v = mt._qkv_proj(cfg, params, h, positions)
-        k_cache = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
-        attn = mt.cached_attention(q, k_cache, v_cache, pos)
-        attn_out = mt._attn_out_proj(cfg, params, attn)
-        if pre_ln:
-            x = x + attn_out
-            h2 = mt.layer_norm(x, params["ln2_scale"], params["ln2_bias"], eps)
-            x = x + mt._ffn(cfg, params, h2)
-        else:
-            # post-LN (BERT layout): sublayer -> residual -> LayerNorm
-            x = mt.layer_norm(x + attn_out, params["ln1_scale"], params["ln1_bias"], eps)
-            x = mt.layer_norm(x + mt._ffn(cfg, params, x),
-                              params["ln2_scale"], params["ln2_bias"], eps)
-        return x, {"k": k_cache, "v": v_cache}
+        B, T = x.shape[:2]
+        # the model's cache attention over a one-layer stack
+        positions, attend = mt._cache_attention(cfg, B, T, cache["k"].shape[1], pos)
+        (x, (k_stack, v_stack)), _ = mt._block(
+            cfg, (x, (cache["k"][None], cache["v"][None])), params, 0, positions=positions,
+            attend=attend, ffn=partial(mt._dense_ffn, cfg))
+        return x, {"k": k_stack[0], "v": v_stack[0]}
 
     __call__ = apply

@@ -362,8 +362,6 @@ class PipelineEngine(DeepSpeedEngine):
         if self._pipe_schedule != "1f1b":
             return super()._make_micro_grad(compute_dtype)
 
-        from functools import partial
-
         from ..models import transformer as tfm
 
         model = self.model
@@ -391,8 +389,10 @@ class PipelineEngine(DeepSpeedEngine):
             # stage_fn runs INSIDE the executor's shard_map, where the batch
             # dim is the per-dp-shard slice (all rows share the same arange)
             positions = jnp.broadcast_to(jnp.arange(Sq)[None, :], (mb // n_dp, Sq))
-            bias = tfm.attn_bias(cfg, Sq)
-            attn_fn = tfm._attention_dispatch(cfg)
+            attend = tfm._stateless_attention(cfg, Sq)
+            # every mesh axis is manual inside the executor's shard_map: the saved
+            # boundary cannot be given a sharding there (partition_activations)
+            wrap = tfm._remat_wrapper(cfg.replace(remat_partition_axis=""))
 
             def embed_fn(p_rest):
                 x, _ = tfm.embed(cfg, p_rest, inputs)
@@ -401,20 +401,12 @@ class PipelineEngine(DeepSpeedEngine):
             x_mb, pull_embed = jax.vjp(embed_fn, p_rest)
             labels_mb = labels.reshape((M, mb, Sq))
 
-            def stage_fn(sp, h):
-                body = partial(
-                    tfm._layer_body, cfg, attn_fn, alibi_bias=bias, positions=positions
-                )
-                if cfg.remat:
-                    body = jax.checkpoint(
-                        body, policy=tfm._remat_policy(cfg.remat_policy), prevent_cse=False
-                    )
-                h, _ = lax.scan(lambda c, lp: body(c, lp), h, sp)
-                return h
+            def stage_fn(sp, h):  # MoE is refused for this schedule at construction
+                return tfm._layer_loop(
+                    cfg, sp, None, h, None, positions=positions, attend=attend, wrap=wrap)[0]
 
             def loss_head(hp, y, labels_b):
-                h = tfm.norm(cfg, y, hp, "lnf")
-                return tfm.lm_loss_from_hidden(cfg, hp, h, labels_b)
+                return tfm.lm_loss_from_hidden(cfg, hp, tfm._final_norm(cfg, hp, y), labels_b)
 
             loss, g_stage, g_head, gx, _trace = pipeline_train_1f1b(
                 stage_fn, loss_head, p_stages, p_rest, x_mb, labels_mb,

@@ -22,12 +22,10 @@ contributions automatically.
 from __future__ import annotations
 
 import re
-from functools import partial
 from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ..models import transformer as tfm
 from ..models.transformer import Model, TransformerConfig
@@ -227,6 +225,10 @@ class PipelinedTransformer(Model):
                 "dropout/progressive-layer-drop under pipeline parallelism is "
                 "not wired up (per-stage rng routing); disable them"
             )
+        if cfg.local_attn_layers is not None:
+            raise NotImplementedError(
+                "local-attention layers under pipeline parallelism are not wired up: a stage "
+                "sees its own layer indices, not the model's")
         super().__init__(cfg, loss_fn=None)
         self.num_stages = num_stages
         self.num_micro_batches = num_micro_batches
@@ -279,61 +281,23 @@ class PipelinedTransformer(Model):
         assert B % M == 0, f"batch {B} not divisible by {M} microbatches"
         x, full_positions = tfm.embed(cfg, params, inputs)
         positions = full_positions[: B // M]  # identical rows; per-microbatch view
-        bias = tfm.attn_bias(cfg, Sq)
-        attn_fn = tfm._attention_dispatch(cfg)
-        E = cfg.moe_every
-        has_moe = E > 0 and "moe" in params
-        K = self.layers_per_stage
+        attend = tfm._stateless_attention(cfg, Sq)
+        wrap = tfm._remat_wrapper(cfg)
 
-        body = partial(
-            tfm._layer_body, cfg, attn_fn, alibi_bias=bias, positions=positions
-        )
-        if cfg.remat:
-            body = jax.checkpoint(
-                body, policy=tfm._remat_policy(cfg.remat_policy), prevent_cse=False
-            )
+        def stage_fn(stage_params, h):
+            # the aux (load-balancing) losses of a stage's routed layers (zero for a dense
+            # model) stream back through pipeline_apply's validity-gated side channel
+            layers, moe = stage_params
+            h, _, aux, _ = tfm._layer_loop(
+                cfg, layers, moe, h, None, positions=positions, attend=attend, wrap=wrap)
+            return h, aux
 
-        if has_moe:
-            # PP x EP: each stage scans its (E-1 dense + 1 MoE)-layer groups;
-            # the MoE aux (load-balancing) losses stream back through
-            # pipeline_apply's validity-gated side channel.
-            G = K // E
-
-            def stage_fn(stage_params, h):
-                lg_full, moe_p = stage_params
-                lg_g = jax.tree.map(
-                    lambda a: a.reshape((G, E) + a.shape[1:]), lg_full)
-
-                def group_body(c, xs):
-                    lgg, mp = xs
-                    if E > 1:
-                        dense = jax.tree.map(lambda a: a[: E - 1], lgg)
-                        c, _ = lax.scan(lambda cc, lp: body(cc, lp), c, dense)
-                    lp_last = jax.tree.map(lambda a: a[E - 1], lgg)
-                    c, aux = tfm._moe_layer(
-                        cfg, lp_last, mp, c, attn_fn, bias, positions)
-                    return c, aux
-
-                h, auxs = lax.scan(group_body, h, (lg_g, moe_p))
-                return h, jnp.sum(auxs)
-
-            stage_tree = (params["layers"], params["moe"])
-            out_mb, aux = pipeline_apply(
-                stage_fn, stage_tree, x_mb := x.reshape((M, B // M) + x.shape[1:]),
-                self.num_stages, self.mesh, collect_aux=True)
-        else:
-
-            def stage_fn(stage_params, h):
-                h, _ = lax.scan(lambda c, lp: body(c, lp), h, stage_params)
-                return h
-
-            x_mb = x.reshape((M, B // M) + x.shape[1:])  # [M, mb, Sq, d]
-            out_mb = pipeline_apply(
-                stage_fn, params["layers"], x_mb, self.num_stages, self.mesh)
-            aux = jnp.zeros((), jnp.float32)
+        x_mb = x.reshape((M, B // M) + x.shape[1:])  # [M, mb, Sq, d]
+        out_mb, aux = pipeline_apply(
+            stage_fn, (params["layers"], params.get("moe")), x_mb, self.num_stages, self.mesh,
+            collect_aux=True)
         hidden = out_mb.reshape((B,) + out_mb.shape[2:])
-        hidden = tfm.norm(cfg, hidden, params, "lnf")
-        nll = tfm.lm_loss_from_hidden(cfg, params, hidden, labels)
+        nll = tfm.lm_loss_from_hidden(cfg, params, tfm._final_norm(cfg, params, hidden), labels)
         # aux accumulated once per microbatch per group: average over M to
         # match the base model's per-batch group sum
         return nll + cfg.moe_aux_coeff * aux / M

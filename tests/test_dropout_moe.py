@@ -94,25 +94,24 @@ def _reset_active_mesh():
 
 @pytest.mark.slow  # ~7s warm; MoE grouped-scan parity — MoE training stays
 # warm in test_moe / test_moe_training_with_remat
-def test_moe_grouped_scan_matches_python_loop():
-    cfg = _moe_cfg()
+@pytest.mark.parametrize("num_layers", [4, 5])  # 5: two whole periods, then one trailing dense layer
+def test_moe_grouped_scan_matches_python_loop(num_layers):
+    from functools import partial
+
+    cfg = _moe_cfg(num_layers=num_layers)
     params = tfm.init(cfg, jax.random.PRNGKey(0))
     toks = jnp.asarray(np.random.default_rng(0).integers(0, 128, size=(2, 17)), jnp.int32)
     out_scan = tfm.apply(cfg, params, toks)
-    # force the python-loop fallback by pretending depth is non-uniform:
-    # moe_every=3 with L=4 -> loop path, but we need SAME placement; instead
-    # reimplement the loop manually for the reference
+    # the reference: the same block, layer by layer in a python loop
     x, positions = tfm.embed(cfg, params, toks)
-    bias = tfm.attn_bias(cfg, 17)
-    attn_fn = tfm._attention_dispatch(cfg)
-    aux = 0.0
+    block = partial(tfm._block, cfg, positions=positions, attend=tfm._stateless_attention(cfg, 17))
     for i in range(cfg.num_layers):
         lp = jax.tree.map(lambda a: a[i], params["layers"])
+        ffn = partial(tfm._dense_ffn, cfg)
         if (i + 1) % cfg.moe_every == 0:
             moe_p = jax.tree.map(lambda a: a[(i + 1) // cfg.moe_every - 1], params["moe"])
-            x, a = tfm._moe_layer(cfg, lp, moe_p, x, attn_fn, bias, positions)
-        else:
-            x, _ = tfm._layer_body(cfg, attn_fn, x, lp, bias, positions)
+            ffn = lambda lp, h: tfm._moe_ffn(cfg, moe_p, h)
+        (x, _), _ = block((x, None), lp, i, ffn=ffn)
     x = tfm.layer_norm(x, params["lnf_scale"], params["lnf_bias"], cfg.layernorm_epsilon)
     head = params["wte"].T
     ref = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype)).astype(jnp.float32)

@@ -368,3 +368,75 @@ def test_apply_with_cache_in_place_is_bit_identical_to_restacking(case):
                                           np.asarray(cache[side][:, 1], np.float32))
         assert not np.array_equal(np.asarray(got["k"][:, 0], np.float32),
                                   np.asarray(cache["k"][:, 0], np.float32))
+
+
+# ---------------------------------------------------------------------------
+# One block: training (`apply`), serving (`apply_with_cache`) and the pipeline
+# compute the same function of the same `cfg`, whatever its residual form, norm
+# and feed-forward (a routed layer honours `parallel_residual` and `norm_style`
+# like a dense one; serving honours `norm_style`).
+# ---------------------------------------------------------------------------
+
+_AGREE_FFN = {
+    "dense": dict(),
+    "gshard_every_2nd": dict(moe_every=2, num_experts=4, moe_top_k=1, moe_capacity_factor=8.0),
+    "dropless_top2": dict(moe_every=1, num_experts=4, moe_top_k=2, moe_routing="dropless",
+                          activation="swiglu"),
+}
+_AGREE_CASES = {
+    f"{res}-{ffn}-{kind}": dict(_AGREE_FFN[ffn], parallel_residual=res == "parallel", norm_kind=kind)
+    for res in ("sequential", "parallel") for ffn in _AGREE_FFN for kind in ("layer", "rms")
+}
+_AGREE_CASES["post_norm-dense-layer"] = dict(norm_style="post")
+# two whole periods, then one trailing dense layer (L % moe_every != 0)
+_AGREE_CASES["sequential-gshard_every_2nd_of_5-layer"] = dict(_AGREE_FFN["gshard_every_2nd"], num_layers=5)
+
+
+def _agree_model(fields):
+    from deepspeed_tpu.models import transformer as tfm
+
+    cfg = TransformerConfig(**{**dict(
+        vocab_size=97, max_seq_len=_SMAX, num_layers=4, num_heads=4, hidden_size=32,
+        pos_emb="rotary", dtype=jnp.float32, loss_chunk_size=0, decode_attn="xla"), **fields})
+    tfm._ACTIVE_MESH[0] = None
+    params = tfm.init(cfg, jax.random.PRNGKey(0))
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    # noise on every leaf: a norm's scale and bias must count
+    noisy = [a + 0.1 * jax.random.normal(k, a.shape, a.dtype) for a, k in zip(leaves, keys)]
+    return tfm, cfg, jax.tree.unflatten(tree, noisy)
+
+
+@pytest.mark.parametrize("case", list(_AGREE_CASES))
+def test_training_and_serving_compute_the_same_layers(case):
+    tfm, cfg, params = _agree_model(_AGREE_CASES[case])
+    prompt = jnp.asarray(np.random.default_rng(1).integers(0, 97, size=(2, 9)), jnp.int32)
+    full = tfm.apply(cfg, params, prompt)[:, -1]
+    logits, cache = tfm.apply_with_cache(cfg, params, prompt, tfm.init_cache(cfg, 2, _SMAX), 0,
+                                         last_only=True)
+    np.testing.assert_allclose(np.asarray(logits[:, -1]), np.asarray(full), rtol=0, atol=2e-3)
+    nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+    dec, _ = tfm.apply_with_cache(cfg, params, nxt, cache, jnp.full((2,), 9, jnp.int32))
+    ext = tfm.apply(cfg, params, jnp.concatenate([prompt, nxt], 1))[:, -1]
+    np.testing.assert_allclose(np.asarray(dec[:, -1]), np.asarray(ext), rtol=0, atol=2e-3)
+
+
+@pytest.mark.parametrize("case", ["parallel-gshard_every_2nd-layer", "parallel-gshard_every_2nd-rms"])
+def test_pipeline_computes_the_same_layers(case):
+    from deepspeed_tpu.comm.mesh import MeshConfig, build_mesh
+    from deepspeed_tpu.pipe import PipelinedTransformer
+
+    tfm, cfg, params = _agree_model(_AGREE_CASES[case])
+    mesh = build_mesh(MeshConfig(pipe=2, data=-1))
+    plain = Model(cfg)
+    plain.set_mesh(mesh)
+    batch = {"tokens": np.random.default_rng(2).integers(0, 97, size=(4, 17)).astype(np.int32)}
+    want = plain.loss(params, batch)
+    piped = PipelinedTransformer(cfg, num_stages=2, num_micro_batches=1)
+    piped.set_mesh(mesh)
+    staged = dict(params, **{name: jax.tree.map(lambda a: a.reshape((2, a.shape[0] // 2) + a.shape[1:]),
+                                                params[name]) for name in ("layers", "moe")})
+    try:
+        np.testing.assert_allclose(np.asarray(piped.loss(staged, batch)), np.asarray(want), rtol=2e-5)
+    finally:
+        tfm._ACTIVE_MESH[0] = None
