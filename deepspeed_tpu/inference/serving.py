@@ -702,8 +702,10 @@ class SlotWorker:
         """One-shot bucketed prompt prefill into ``slot``. Returns the host
         ``(first_token, bad)`` pair. ``uid`` only labels the span."""
         tm = self.telemetry
-        with tm.span("prefill", uid=uid, slot=slot, bucket=bucket,
-                     true_len=true_len) as sp:
+        # ``attn``: the form the bucket's program was traced with (its local
+        # cache is the bucket long: ``_build_prefill``)
+        with tm.span("prefill", uid=uid, slot=slot, bucket=bucket, true_len=true_len,
+                     attn=tfm.cache_attention_form(self.cfg.num_heads, 1, bucket, bucket)) as sp:
             with tm.span("dispatch"):
                 if bucket not in self._prefills:
                     # each bucket length is its own compile-stable program:

@@ -1129,6 +1129,31 @@ def cached_attention(q, k_cache, v_cache, pos, *, bias=None):
     return xla_attention(q, k_cache, v_cache, causal_offset=pos, bias=bias)
 
 
+# The float32 score matrix [B, H, T, Smax] of ONE layer, in bytes, up to which
+# a block that fills its cache attends densely; above it the block goes
+# through the flash forward kernel. Measured, not configured (PERF.md §6,
+# PR 30: ``SlotWorker.prefill`` timed both ways on one v5e at BLOOM-1b7's
+# widths, 16 heads): while XLA keeps the scores in VMEM the dense form is 3-7%
+# ahead (128 to 1280 rows, 100 MiB: 33.3 ms against 35.7), and once they spill
+# to HBM it is behind by up to half (1536 rows, 144 MiB: 44.5 against 37.8;
+# 2048 rows, 256 MiB: 89.5 against 46.3). Compiled for other head counts the
+# spill follows the bytes, not the rows (98 MiB stays, 121 MiB does not).
+DENSE_SCORE_BYTES = 100 * 2 ** 20
+
+
+def cache_attention_form(num_heads: int, B: int, T: int, Smax: int, lock_step: bool = True) -> str:
+    """``"flash"`` or ``"dense"``: how ``T`` new tokens a row attend in a cache
+    ``Smax`` long, read from the shapes alone. A lock-step block as long as its
+    cache (it can only enter at position 0) is plain causal self-attention, and
+    the kernel is the cheaper form of it once the dense form's score matrix is
+    over ``DENSE_SCORE_BYTES``: serving's long prefill buckets. Decode steps,
+    per-row positions (verify, chunk) and a prompt shorter than its cache are
+    dense. ``_cache_attention`` traces by this and ``SlotWorker.prefill``
+    labels its span by it."""
+    fills_cache = lock_step and T == Smax
+    return "flash" if fills_cache and 4 * B * num_heads * T * Smax > DENSE_SCORE_BYTES else "dense"
+
+
 def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, write_pos=None):
     """-> (positions [B, T], the block's ``attend``) for T new tokens entering a
     stacked [L, B, Smax, H, Dh] cache pair at ``pos`` (scalar, or [B] with
@@ -1147,10 +1172,23 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
     else:
         positions = pos + jnp.broadcast_to(steps[None, :], (B, T))
 
+    # A lock-step block as long as the cache (serving prefill: a local cache
+    # of the bucket's length) IS the layer's cache once written, so attention
+    # reads the block itself: through the flash kernel where the dense scores
+    # would spill (no score matrix, alibi from block positions), otherwise
+    # densely with QK^T and the softmax in one fusion; read back through the
+    # stack, Pythia's 2048-token prefill took 103 ms on the chip instead of 73
+    # (PERF.md §6, PR 25).
+    lock_step = not vector_pos
+    fills_cache = lock_step and T == Smax
+    use_flash = cache_attention_form(cfg.num_heads, B, T, Smax, lock_step) == "flash"
+    if use_flash:
+        from ..ops.pallas.flash_attention import flash_attention_sharded
+
+    slopes = alibi_slopes(cfg.num_heads) if cfg.pos_emb == "alibi" else None
     bias = None
-    if cfg.pos_emb == "alibi":
+    if slopes is not None and not use_flash:
         # alibi distances vs absolute key positions, rows = new tokens
-        slopes = alibi_slopes(cfg.num_heads)
         if vector_pos:
             dist = jnp.arange(Smax)[None, None, :] - positions[:, :, None]  # [B,T,Smax]
             bias = (slopes[None, :, None, None] * dist[:, None]).astype(jnp.float32)
@@ -1187,23 +1225,20 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
         def write(c, l, new):
             return lax.dynamic_update_slice(c, new[None].astype(c.dtype), (l, 0, pos, 0, 0))
 
-    # A lock-step block as long as the cache (serving prefill: a local cache
-    # of the bucket's length) IS the layer's cache once written, so attention
-    # reads the block itself and XLA keeps QK^T and the softmax in one fusion;
-    # read back through the stack, Pythia's 2048-token prefill took 103 ms on
-    # the chip instead of 73 (PERF.md §6, PR 25).
-    fills_cache = not vector_pos and T == Smax
-
     def attend(q, k, v, stacks, l):
         k_stack, v_stack = write(stacks[0], l, k), write(stacks[1], l, v)
         if use_decode_kernel:
             attn = decode_attention(q[:, 0], k_stack, v_stack, pos, layer=l)[:, None]
+            return attn, (k_stack, v_stack)
+        if fills_cache:
+            k_l, v_l = k.astype(k_stack.dtype), v.astype(v_stack.dtype)
         else:
-            if fills_cache:
-                k_l, v_l = k.astype(k_stack.dtype), v.astype(v_stack.dtype)
-            else:
-                k_l, v_l = (lax.dynamic_index_in_dim(c, l, keepdims=False)
-                            for c in (k_stack, v_stack))
+            k_l, v_l = (lax.dynamic_index_in_dim(c, l, keepdims=False)
+                        for c in (k_stack, v_stack))
+        if use_flash:
+            attn = flash_attention_sharded(q, k_l, v_l, mesh=_ACTIVE_MESH[0], causal=True,
+                                           alibi_slopes=slopes)
+        else:
             attn = cached_attention(q, k_l, v_l, pos, bias=bias)
         return attn, (k_stack, v_stack)
 

@@ -104,8 +104,10 @@ def _lanes(col, lanes=LANES):
 def _block_scores(q, k_blk, qi, kj, *, sm_scale, causal, slope_ref, w_ref):
     """[Bq, Bk] fp32 scores with alibi / local-window / causal fused.
 
-    ``slope_ref`` (or None): [1, LANES] block of the per-program alibi slope
-    (one row per fused batch×head program) — the bias is COMPUTED from block
+    ``slope_ref`` (or None): [1, 1, LANES] block of the per-program alibi slope
+    (one lane-broadcast row per fused batch×head program, read as a vector:
+    a [1, LANES] block of a [BH, LANES] array is no legal TPU tile, and a
+    scalar is not read from VMEM) — the bias is COMPUTED from block
     positions, never streamed from HBM (the reference threads alibi through
     softmax_context_* the same way, pt_binding.cpp:1231-1283). ``w_ref`` (or
     None): [1, LANES] runtime local-attention window; w <= 0 means global
@@ -124,7 +126,7 @@ def _block_scores(q, k_blk, qi, kj, *, sm_scale, causal, slope_ref, w_ref):
             jnp.int32, (block_q, block_k), 1
         )
     if slope_ref is not None:
-        s = s + slope_ref[0, 0] * (k_pos - q_pos).astype(jnp.float32)
+        s = s + _widen(slope_ref[0], block_k) * (k_pos - q_pos).astype(jnp.float32)
     if w_ref is not None:
         w = w_ref[0, 0]  # fp32 runtime window; w <= 0 means global
         s = jnp.where((w <= 0) | ((q_pos - k_pos).astype(jnp.float32) < w), s, NEG_INF)
@@ -220,7 +222,7 @@ def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
     ]
     operands = [q, k, v]
     if slopes_bh is not None:
-        in_specs.append(_vmem_spec((1, LANES), lambda bh, qi, kj: (bh, 0)))
+        in_specs.append(_vmem_spec((1, 1, LANES), lambda bh, qi, kj: (bh, 0, 0)))
         operands.append(slopes_bh)
     if w_arr is not None:
         in_specs.append(_vmem_spec((1, LANES), lambda bh, qi, kj: (0, 0)))
@@ -367,7 +369,7 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
     extra_specs = []
     extra_ops = []
     if has_slopes:
-        extra_specs.append(_vmem_spec((1, LANES), lambda bh, a, b: (bh, 0)))
+        extra_specs.append(_vmem_spec((1, 1, LANES), lambda bh, a, b: (bh, 0, 0)))
         extra_ops.append(slopes_bh)
     if has_window:
         extra_specs.append(_vmem_spec((1, LANES), lambda bh, a, b: (0, 0)))
@@ -504,9 +506,9 @@ def flash_attention(
     if alibi_slopes is not None:
         sl = jnp.asarray(alibi_slopes, jnp.float32)
         assert sl.shape == (H,), (sl.shape, H)
-        # one [LANES] row per fused batch×head program
+        # one [1, LANES] row per fused batch×head program
         slopes_bh = jnp.broadcast_to(
-            jnp.tile(sl, B)[:, None], (B * H, LANES))
+            jnp.tile(sl, B)[:, None, None], (B * H, 1, LANES))
     w_arr = None
     if window is not None:
         w_arr = jnp.full((1, LANES), 0.0, jnp.float32) + jnp.asarray(

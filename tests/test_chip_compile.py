@@ -9,8 +9,9 @@ here is a chip run or a time.
 Tier 1 compiles the main path's kernels at real widths with
 ``interpret=False`` (about two seconds each) and the serving decode program
 at BLOOM's and Pythia's widths, cut to four layers and a small vocabulary: the
-guard that the slot KV cache stays one buffer through the layer loop. The
-slow tier compiles whole
+guard that the slot KV cache stays one buffer through the layer loop; and the
+2048-row prefill of the three benchmark configurations, which attends through
+the flash kernel and holds no score matrix. The slow tier compiles whole
 programs at GPT-2 125M: the train step as ``chip_smoke.py`` / ``bench.py``
 configure it, the serving programs of ``chip_smoke.py``'s serve phase, and the
 ZeRO-3 fsdp=4 step over the four described chips (run them with ``-m slow``
@@ -90,6 +91,23 @@ def _flash_fwd_bwd(sds):
     return jax.grad(loss, argnums=(0, 1, 2)), (x, x, x)
 
 
+def _flash_fwd_bwd_alibi(sds):
+    """The kernel with BLOOM's alibi computed from block positions, at the
+    serving models' heads (16 of 128, 2048 rows): the slopes ride in as a
+    [BH, 1, 128] array, a [BH, 128] one in (1, 128) blocks is refused by the
+    chip's lowering and passed every interpret-mode test (PR 30)."""
+    from deepspeed_tpu.models.transformer import alibi_slopes
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    x = sds((1, 2048, 16, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, alibi_slopes=alibi_slopes(16),
+                                       interpret=False).astype(jnp.float32))
+
+    return jax.grad(loss, argnums=(0, 1, 2)), (x, x, x)
+
+
 def _decode_kernel(sds):
     from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
 
@@ -112,8 +130,10 @@ def _fused_xent_fwd_bwd(sds):
         sds((N, D), jnp.bfloat16), sds((D, V), jnp.bfloat16), sds((N,), jnp.int32))
 
 
-@pytest.mark.parametrize("build", [_flash_fwd_bwd, _decode_kernel, _fused_xent_fwd_bwd],
-                         ids=["flash_fwd_bwd", "decode_attention", "fused_linear_xent"])
+@pytest.mark.parametrize("build", [_flash_fwd_bwd, _flash_fwd_bwd_alibi, _decode_kernel,
+                                   _fused_xent_fwd_bwd],
+                         ids=["flash_fwd_bwd", "flash_fwd_bwd_alibi", "decode_attention",
+                              "fused_linear_xent"])
 def test_kernel_compiles_for_v5e(build, v5e, no_persistent_cache):
     one_chip = SingleDeviceSharding(v5e[0])
     fn, args = build(lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip))
@@ -127,6 +147,7 @@ def _bare_slot_worker(cfg, n, Smax, one_chip):
     shapes of its operands on the described chip, the weights typed as
     ``InferenceEngine`` holds them: (worker, params, cache, sds)."""
     from deepspeed_tpu.inference.serving import SlotWorker
+    from deepspeed_tpu.models import transformer as tfm
     from deepspeed_tpu.models.transformer import Model, hold_for_compute
 
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -136,6 +157,7 @@ def _bare_slot_worker(cfg, n, Smax, one_chip):
     kv = sds((cfg.num_layers, n, Smax, cfg.num_heads, cfg.head_dim), jnp.bfloat16)
     worker = SlotWorker.__new__(SlotWorker)
     worker.cfg, worker.Smax = cfg, Smax
+    tfm._ACTIVE_MESH[0] = None  # the engine's own (one chip) in a process; an earlier test's here
     worker._cache_shardings = {"k": one_chip, "v": one_chip}
     return worker, params, {"k": kv, "v": kv}, sds
 
@@ -213,10 +235,11 @@ def test_serving_programs_cast_no_stacked_weight(family, L, v5e, no_persistent_c
     1024), on operands typed as ``InferenceEngine`` holds them
     (``hold_for_compute``): no ``convert`` yields a whole stacked weight; the
     decode step's temporaries are under the bf16 bytes of the largest stacked
-    leaf, and the prefill's (its local K/V, the dense score matrix, for OLMoE
-    layer l of each expert bank sliced out for the grouped-GEMM kernel) under
-    those of all the stacks together. On ``model.init``'s float32 operands,
-    which the engine held before PR 28, all of it fails: every program casts
+    leaf, and the prefill's (its local K/V and, for OLMoE, layer l of each
+    expert bank sliced out for the grouped-GEMM kernel; no score matrix since
+    PR 30) under a third of those of all the stacks together. On
+    ``model.init``'s float32 operands, which the engine held before PR 28, all
+    of it fails: every program casts
     every stack (twelve converts for BLOOM, the three expert banks for OLMoE)
     and carries the bf16 copies as temporaries on top of the rest (2.42 GB of
     the decode step's 2.42 and of the prefill's 3.79 for BLOOM, 3.26 GB of
@@ -230,12 +253,44 @@ def test_serving_programs_cast_no_stacked_weight(family, L, v5e, no_persistent_c
     shapes = "|".join(re.escape(",".join(map(str, shape))) for shape in sorted(set(stacks)))
     for name, compiled, bound in (
             ("decode", _compile_decode(worker, params, cache, n, sds), max(nbytes)),
-            ("prefill", _compile_prefill(worker, params, cache, Smax, sds), sum(nbytes))):
+            ("prefill", _compile_prefill(worker, params, cache, Smax, sds), sum(nbytes) // 3)):
         casts = re.findall(rf"^\s*%?[\w.-]+ = \w+\[(?:{shapes})\]\S* convert\(",
                            compiled.as_text(), re.M)
         assert not casts, f"the {name} program casts a whole stacked weight: {casts}"
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < bound, (name, temp, bound)
+
+
+@pytest.mark.parametrize("family,L", [("bloom_dense_alibi", 24), ("pythia_pallas_kernel", 24),
+                                      ("olmoe_dropless", 4)])
+def test_long_prefill_attends_through_the_flash_kernel(family, L, v5e, no_persistent_cache, as_tpu,
+                                                       monkeypatch):
+    """The 2048-row prefill of each benchmark configuration (16 heads of 128,
+    8 slots x 2048, vocabulary 1024): its block fills its local cache and its
+    dense scores would be 256 MiB, so attention is ONE ``flash_fwd`` kernel
+    call in the layer loop, no ``[16, 2048, 2048]`` float32 value exists, and
+    the temporaries are under the dense form's (the same program with the
+    constant steered out of reach) by the score matrix where that was their
+    peak (OLMoE's is the sliced-out expert banks, before and after). The
+    1024-row bucket, whose 64 MiB of scores XLA keeps in VMEM, has no kernel."""
+    from deepspeed_tpu.models import transformer as tfm
+
+    n, Smax = 8, 2048
+    cfg = _family_cfg(family, L, Smax)
+    worker, params, cache, sds = _bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0]))
+    scores = rf"f32\[(?:1,)?{cfg.num_heads},{Smax},{Smax}\]"
+    flash = _compile_prefill(worker, params, cache, Smax, sds)
+    text = flash.as_text()
+    assert len(re.findall(r'^\s*%?flash_fwd[\w.]* = .*custom_call_target="tpu_custom_call"',
+                          text, re.M)) == 1
+    assert not re.search(scores, text)
+    assert "flash_fwd" not in _compile_prefill(worker, params, cache, Smax // 2, sds).as_text()
+
+    monkeypatch.setattr(tfm, "DENSE_SCORE_BYTES", 2 ** 40)
+    dense = _compile_prefill(worker, params, cache, Smax, sds)
+    assert "flash_fwd" not in dense.as_text() and re.search(scores, dense.as_text())
+    saved = dense.memory_analysis().temp_size_in_bytes - flash.memory_analysis().temp_size_in_bytes
+    assert saved > (0 if family == "olmoe_dropless" else 4 * cfg.num_heads * Smax * Smax), saved
 
 
 @pytest.mark.parametrize("rows,grouped", [(16, False), (2048, True)], ids=["decode", "prefill"])

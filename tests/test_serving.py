@@ -440,3 +440,113 @@ def test_pipeline_computes_the_same_layers(case):
         np.testing.assert_allclose(np.asarray(piped.loss(staged, batch)), np.asarray(want), rtol=2e-5)
     finally:
         tfm._ACTIVE_MESH[0] = None
+
+
+# ---------------------------------------------------------------------------
+# A lock-step block as long as its cache attends through the flash forward
+# kernel once the dense form's score matrix is over DENSE_SCORE_BYTES (PR 30):
+# the same function as the dense form, chosen by the shapes alone.
+# ---------------------------------------------------------------------------
+
+_FLASH_POS = {"alibi": dict(pos_emb="alibi", embed_ln=True), "rotary": dict(pos_emb="rotary")}
+_FLASH_PREFILL_CASES = [(pos, dtype, live) for pos in _FLASH_POS for dtype in ("float32", "bfloat16")
+                        for live in ("full_bucket", "padded_bucket")]
+_FLASH_ROWS, _FLASH_HEADS = 256, 4
+
+
+@pytest.fixture
+def kernel_from_256_rows(monkeypatch):
+    """The measured constant is 100 MiB of scores (16 heads x 1280 rows): the
+    tiny model reaches the kernel on the CPU with the constant steered, in the
+    test, to one byte under its own 256-row block's scores."""
+    from deepspeed_tpu.models import transformer as tfm
+
+    monkeypatch.setattr(tfm, "DENSE_SCORE_BYTES", 4 * _FLASH_HEADS * _FLASH_ROWS ** 2 - 1)
+    return tfm
+
+
+def test_cache_attention_form_reads_the_shapes():
+    from deepspeed_tpu.models import transformer as tfm
+
+    form = tfm.cache_attention_form
+    # 16 heads (the benchmark's three models): dense up to 1280 rows, the kernel from 1536
+    assert [form(16, 1, T, T) for T in (1, 128, 1024, 1280, 1536, 2048)] == \
+        ["dense"] * 4 + ["flash"] * 2
+    assert form(32, 1, 1024, 1024) == "flash" and form(4, 1, 2048, 2048) == "dense"  # by bytes
+    assert form(16, 2, 1024, 1024) == "flash"  # a lock-step batch holds B score matrices
+    # a prompt shorter than its cache, a decode step, per-row positions: dense whatever the size
+    assert form(16, 1, 2048, 4096) == form(16, 8, 1, 2048) == "dense"
+    assert form(16, 1, 2048, 2048, lock_step=False) == "dense"
+
+
+@pytest.mark.parametrize("pos_emb,dtype,live", _FLASH_PREFILL_CASES,
+                         ids=["-".join(c) for c in _FLASH_PREFILL_CASES])
+def test_prefill_block_that_fills_its_cache_attends_through_the_flash_kernel(
+        pos_emb, dtype, live, kernel_from_256_rows):
+    """``apply_with_cache`` with ``T == Smax`` at the smallest block over the
+    constant (the kernel in interpreter mode) against the hand-written layer
+    above, which goes down ``cached_attention`` whatever the shapes: the
+    logits of the live rows to round-off of the compute dtype, the sampled
+    token equal, the cache written bit for bit in layer 0 (its rows have
+    passed through no attention) and to round-off below it. Half as long, or
+    shorter than its cache, the same call traces no kernel."""
+    tfm = kernel_from_256_rows
+    T = _FLASH_ROWS
+    true_len = T if live == "full_bucket" else T - 37
+    cfg = TransformerConfig(**{**dict(
+        vocab_size=97, max_seq_len=T, num_layers=3, num_heads=_FLASH_HEADS, hidden_size=32,
+        dtype=jnp.dtype(dtype), loss_chunk_size=0, decode_attn="xla"), **_FLASH_POS[pos_emb]})
+    tfm._ACTIVE_MESH[0] = None  # an earlier test's engine
+    params = tfm.hold_for_compute(cfg, tfm.init(cfg, jax.random.PRNGKey(0)))
+    tokens = np.zeros((1, T), np.int32)  # a bucket: the prompt, then padding
+    tokens[0, :true_len] = np.random.default_rng(5).integers(0, cfg.vocab_size, size=true_len)
+    cache = tfm.init_cache(cfg, 1, T, dtype=cfg.dtype)
+
+    flash = lambda p, t, c: tfm.apply_with_cache(cfg, p, t, c, 0)
+    assert "flash_fwd" in str(jax.make_jaxpr(flash)(params, tokens, cache))
+    got_logits, got = jax.jit(flash)(params, tokens, cache)
+    ref_logits, ref = jax.jit(
+        lambda p, t, c: _restacking_apply_with_cache(cfg, p, t, c, 0))(params, tokens, cache)
+
+    got_logits, ref_logits = (np.asarray(x[0, :true_len], np.float32) for x in (got_logits, ref_logits))
+    step = float(jnp.finfo(cfg.dtype).eps)  # float32: 1.2e-7, bf16: 7.8e-3, of values of order 1
+    np.testing.assert_allclose(got_logits, ref_logits, rtol=0, atol=4 * step * np.abs(ref_logits).max())
+    assert np.argmax(got_logits[-1]) == np.argmax(ref_logits[-1])  # greedy at last_index
+    for side in ("k", "v"):
+        g, r = (np.asarray(x[side][:, 0, :true_len], np.float32) for x in (got, ref))
+        np.testing.assert_array_equal(g[0], r[0])
+        np.testing.assert_allclose(g[1:], r[1:], rtol=0, atol=4 * step * np.abs(r).max())
+        assert got[side].shape == cache[side].shape and got[side].dtype == cache[side].dtype
+
+    short = T // 2
+    for t_new, s_max in ((short, short), (short, T)):
+        jaxpr = jax.make_jaxpr(lambda p, t, c: tfm.apply_with_cache(cfg, p, t, c, 0))(
+            params, tokens[:, :t_new], tfm.init_cache(cfg, 1, s_max, dtype=cfg.dtype))
+        assert "pallas_call" not in str(jaxpr)
+
+
+def test_prefill_span_says_which_attention_its_program_runs(kernel_from_256_rows):
+    """The ``…/prefill`` span's ``attn`` comes from the rule the bucket's
+    program was traced with: a long prompt's bucket reaches the kernel, a
+    short one's does not, and both serve the model's own greedy tokens."""
+    import time
+
+    from deepspeed_tpu.telemetry import tracing
+
+    tfm, rows = kernel_from_256_rows, _FLASH_ROWS
+    cfg = TransformerConfig(vocab_size=97, max_seq_len=2 * rows, num_layers=2, num_heads=_FLASH_HEADS,
+                            hidden_size=32, dtype=jnp.float32, loss_chunk_size=0,
+                            decode_attn="xla", pos_emb="alibi", embed_ln=True)
+    eng = InferenceEngine(model=Model(cfg), config={"dtype": "fp32"})
+    srv = ServingEngine(eng, n_slots=2, max_seq_len=2 * rows)
+    long_p, short_p = _prompts([rows - 30, 9], seed=3)
+    t0 = time.perf_counter()
+    res = srv.serve([Request(uid=0, prompt=long_p, max_new_tokens=3),
+                     Request(uid=1, prompt=short_p, max_new_tokens=3)])
+    spans = {sp.attrs["uid"]: sp.attrs for sp in tracing.spans(t0) if sp.name == "prefill"}
+    assert (spans[0]["bucket"], spans[0]["attn"]) == (rows, "flash")
+    assert spans[1]["bucket"] < rows and spans[1]["attn"] == "dense"
+    for uid, prompt in ((0, long_p), (1, short_p)):
+        assert res[uid].status == "ok"
+        logits = tfm.apply(cfg, eng.params, prompt[None])  # training's forward: xla attention
+        assert int(jnp.argmax(logits[0, -1])) == int(res[uid].tokens[0])
