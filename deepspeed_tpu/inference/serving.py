@@ -254,15 +254,18 @@ class _Handoff:
 def _forward(cfg, params, toks, cache, pos, live, **kw):
     """``apply_with_cache`` for a serving program -> (logits, cache, extra
     outputs). A dense model has no extra output and its program is what it
-    was. A model with dropless routing adds ONE: int32 [routed layers, E],
-    how many of the ``live`` rows (toks-shaped bool: not bucket padding, not
-    an idle slot) each layer sent to each expert; it comes back in the fetch
-    that brings the tokens and ``SlotWorker._note_load`` puts it on the span."""
+    was. A model with dropless routing adds TWO. int32 [routed layers, E]: how
+    many of the ``live`` rows (toks-shaped bool: not bucket padding, not an
+    idle slot) each layer sent to each expert; it comes back in the fetch
+    that brings the tokens and ``SlotWorker._note_load`` puts it on the span.
+    Then the experts chosen themselves, int32 [routed layers, B, T, k], which
+    the load was counted from: they stay on the device unless
+    ``SlotWorker.routing_log`` asks for them."""
     if cfg.moe_routing != "dropless":
         return (*tfm.apply_with_cache(cfg, params, toks, cache, pos, **kw), ())
     logits, cache, chosen = tfm.apply_with_cache(
         cfg, params, toks, cache, pos, return_routing=True, **kw)
-    return logits, cache, (expert_load(chosen, live, cfg.num_experts),)
+    return logits, cache, (expert_load(chosen, live, cfg.num_experts), chosen)
 
 
 class SlotWorker:
@@ -299,12 +302,19 @@ class SlotWorker:
         self.Smax = -(-int(budget) // 128) * 128
         self._rng = jax.random.PRNGKey(seed)
 
-        self.spec = kv_slot_cache_spec(self.mesh, self.n_slots, self.cfg.num_heads)
+        # the cache is the tree the model's attention says (per-head K/V, or
+        # latent attention's shared rotary key + latent: ``tfm.cache_layout``);
+        # everything below works on that tree. Its head axis shards over the
+        # TP axis where every leaf's heads divide (a latent is every head's:
+        # it has one, and replicates)
+        layout = tfm.cache_layout(self.cfg)
+        cache_heads = min(heads for heads, _ in layout.values())
+        self.spec = kv_slot_cache_spec(self.mesh, self.n_slots, cache_heads)
         self._cache_sharding = NamedSharding(self.mesh, self.spec)
         # every program pins the cache OUTPUT to this sharding too — an
         # inferred output sharding that differs from the input's would give
         # the next call a differently-sharded operand and silently recompile
-        self._cache_shardings = {"k": self._cache_sharding, "v": self._cache_sharding}
+        self._cache_shardings = {name: self._cache_sharding for name in layout}
         self._cache = jax.jit(
             partial(tfm.init_cache, self.cfg, self.n_slots, self.Smax,
                     dtype=self.cfg.dtype),
@@ -321,10 +331,9 @@ class SlotWorker:
                 raise ValueError(
                     f"prefix_cache.max_prefix_len ({self.pmax}) exceeds the "
                     f"slot cache length {self.Smax}")
-            pool_spec = kv_prefix_pool_spec(self.mesh, prefix_cfg.n_slots,
-                                            self.cfg.num_heads)
+            pool_spec = kv_prefix_pool_spec(self.mesh, prefix_cfg.n_slots, cache_heads)
             self._pool_sharding = NamedSharding(self.mesh, pool_spec)
-            self._pool_shardings = {"k": self._pool_sharding, "v": self._pool_sharding}
+            self._pool_shardings = {name: self._pool_sharding for name in layout}
             self._pool = jax.jit(
                 partial(tfm.init_cache, self.cfg, prefix_cfg.n_slots, self.pmax,
                         dtype=self.cfg.dtype),
@@ -355,6 +364,12 @@ class SlotWorker:
         # on real hardware; that is not a hang), the same rule the latency
         # histograms already apply via last_call_compiled
         self.step_compiled = False
+        # a routed model's programs return the experts they chose beside the
+        # load counted from them; set this to a list and every fetched call
+        # appends {"span", its rows (decode / verify: pos, active; prefill:
+        # uid, slot, true_len; chunk: uid, slot, start, live), "chosen"}. The
+        # programs are the same either way: observation, not a path
+        self.routing_log: list | None = None
 
     # -- compiled programs ----------------------------------------------
 
@@ -455,18 +470,13 @@ class SlotWorker:
             # prompt [1, bucket] (padded tail masked out by causality: the
             # live tokens never attend to it, and its KV is overwritten by
             # decode steps as the sequence grows into those positions)
-            local = tfm.init_cache(cfg, 1, bucket, dtype=cache["k"].dtype)
+            local = tfm.init_cache(cfg, 1, bucket, dtype=jax.tree.leaves(cache)[0].dtype)
             logits, local, load = _forward(
                 cfg, params, prompt, local, 0, jnp.arange(bucket)[None, :] < true_len,
                 last_index=true_len - 1)
             bad = jnp.any(~jnp.isfinite(logits[:, 0]), axis=-1)
             tok = sample_logits_vector(logits[:, 0], rng, temp, top_k, top_p)
-            cache = {
-                kv: jax.lax.dynamic_update_slice(
-                    cache[kv], local[kv], (0, slot, 0, 0, 0))
-                for kv in ("k", "v")
-            }
-            return (cache, tok, bad, *load)
+            return (tfm.update_cache_slot(cache, local, slot), tok, bad, *load)
 
         return donated_jit(prefill, donate_argnums=(1,), out_shardings=self._outs(2))
 
@@ -572,18 +582,31 @@ class SlotWorker:
 
     def _outs(self, n: int) -> tuple:
         """``out_shardings`` of a program that returns the slot cache, ``n``
-        host-bound values and, for a routed model, the expert load."""
+        host-bound values and, for a routed model, the expert load and the
+        experts chosen (``_forward``)."""
         routed = self.cfg.moe_routing == "dropless"
-        return (self._cache_shardings,) + (None,) * (n + routed)
+        return (self._cache_shardings,) + (None,) * (n + 2 * routed)
 
     # -- dispatches ------------------------------------------------------
 
-    def _note_load(self, sp, load) -> None:
+    def _host_bound(self, out: list) -> tuple:
+        """A program's outputs behind the cache -> (those the host fetches, the
+        experts a routed model chose: a device array that no fetch waits for)."""
+        if self.cfg.moe_routing != "dropless":
+            return out, None
+        return out[:-1], out[-1]
+
+    def _note_load(self, sp, load, chosen=None, **rows) -> None:
         """A routed model's call: how uneven the routing of its live rows was,
         on the call's span and the gauges of the same names (``load`` is the
-        fetched tail of the program's outputs: empty for a dense model)."""
+        fetched tail of the program's outputs: empty for a dense model). While
+        ``routing_log`` is a list, the call's ``chosen`` experts are fetched
+        too and appended with what says whose ``rows`` they are."""
         if not load:
             return
+        if self.routing_log is not None:
+            self.routing_log.append({"span": sp.name, **rows,
+                                     "chosen": np.asarray(chosen)})
         summary = load_summary(load[0])
         sp.annotate(**summary)
         self.telemetry.gauge("serving/expert_load_max_over_mean").set(
@@ -614,7 +637,12 @@ class SlotWorker:
         per config). Returns host ``(next_token, bad_sentinel)`` [n_slots]
         arrays."""
         tm = self.telemetry
-        with tm.span("decode", n_active=int(np.count_nonzero(active))) as sp:
+        # ``cached_tokens``: the cache positions the step attends to, summed
+        # over its live rows (row at ``pos`` reads [0, pos]); ``attn``: the form
+        # the program was traced with (``prefill`` has flash / dense)
+        with tm.span("decode", n_active=int(np.count_nonzero(active)),
+                     cached_tokens=int(np.sum((np.asarray(pos) + 1)[np.asarray(active, bool)])),
+                     attn=tfm.cache_step_form(self.cfg)) as sp:
             with tm.span("dispatch"):
                 if self._decode is None:
                     wd = tm.watchdog
@@ -631,9 +659,10 @@ class SlotWorker:
                 )
             self._decode_steps += 1
             compiled = self._compiled(sp, self._decode)
+            out, chosen = self._host_bound(out)
             with tm.span("fetch"):
                 nxt, bad, *load = (np.asarray(x) for x in jax.device_get(out))
-            self._note_load(sp, load)
+            self._note_load(sp, load, chosen, pos=np.array(pos), active=np.array(active, bool))
         if not compiled:
             tm.histogram("serving/decode_step_sec").observe(sp.dur_s)
         tm.counter("serving/decode_steps").inc()
@@ -648,8 +677,10 @@ class SlotWorker:
         Returns host ``(accept, resample, clean, bad)`` arrays
         ([n, depth] / [n, depth+1] / [n, depth+1] / [n])."""
         tm = self.telemetry
-        with tm.span("verify", n_active=int(np.count_nonzero(active)),
-                     depth=depth) as sp:
+        with tm.span("verify", n_active=int(np.count_nonzero(active)), depth=depth,
+                     cached_tokens=int(np.sum(
+                         (np.asarray(pos) + depth + 1)[np.asarray(active, bool)])),
+                     attn=tfm.cache_step_form(self.cfg)) as sp:
             with tm.span("dispatch"):
                 key = (depth, greedy_only)
                 if key not in self._verifies:
@@ -674,10 +705,12 @@ class SlotWorker:
                         self.params, self._cache, toks, pos, wpos, active, k,
                         temp, top_k, top_p)
             compiled = self._compiled(sp, prog)
+            out, chosen = self._host_bound(out)
             with tm.span("fetch"):
                 out = tuple(np.asarray(x) for x in jax.device_get(out))
             n_out = 1 if greedy_only else 4  # what follows is a routed model's expert load
-            self._note_load(sp, out[n_out:])
+            self._note_load(sp, out[n_out:], chosen, pos=np.array(pos),
+                            active=np.array(active, bool))
             out = out[:n_out]
             if greedy_only:
                 p, = out  # one packed array: the ONE fetch
@@ -724,9 +757,10 @@ class SlotWorker:
                     jnp.asarray([top_p], jnp.float32),
                 )
             compiled = self._compiled(sp, prog)
+            out, chosen = self._host_bound(out)
             with tm.span("fetch"):
                 tok_h, bad_h, *load = jax.device_get(out)
-            self._note_load(sp, load)
+            self._note_load(sp, load, chosen, uid=uid, slot=slot, true_len=true_len)
         if not compiled:
             tm.histogram("serving/prefill_sec").observe(sp.dur_s)
         tm.counter(f"serving/prefill_bucket[{bucket}]").inc()
@@ -742,8 +776,9 @@ class SlotWorker:
         no latency datum); the FINAL chunk fetches and returns
         ``(first_token, bad)``. ``uid`` only labels the span."""
         tm = self.telemetry
-        with tm.span("chunk", uid=uid, slot=slot, width=width, live=live,
-                     fetch=fetch) as sp:
+        with tm.span("chunk", uid=uid, slot=slot, width=width, live=live, fetch=fetch,
+                     cached_tokens=int(start) + int(live),
+                     attn=tfm.cache_step_form(self.cfg)) as sp:
             with tm.span("dispatch"):
                 prog = self._chunk_prog(width)
                 self._rng, k = jax.random.split(self._rng)
@@ -756,9 +791,10 @@ class SlotWorker:
                 )
             compiled = self._compiled(sp, prog)
             if fetch:
+                out, chosen = self._host_bound(out)
                 with tm.span("fetch"):
                     tok_h, bad_h, *load = jax.device_get(out)
-                self._note_load(sp, load)
+                self._note_load(sp, load, chosen, uid=uid, slot=slot, start=start, live=live)
         tm.counter(f"serving/chunk_bucket[{width}]").inc()
         if not fetch:
             return None
@@ -833,10 +869,7 @@ class SlotWorker:
             self.step_compiled = True  # first fill call compiles the program
 
             def fill(cache, slot, val):
-                return {
-                    kv: cache[kv].at[:, slot].set(val)
-                    for kv in ("k", "v")
-                }
+                return jax.tree.map(lambda c: c.at[:, slot].set(val), cache)
 
             wd = self.telemetry.watchdog
             self._poison = wd.watch(
@@ -845,7 +878,7 @@ class SlotWorker:
                 wd.unique_name("serving/fill_slot"), stable=True)
         self._cache = self._poison(
             self._cache, jnp.int32(slot),
-            jnp.asarray(value, self._cache["k"].dtype))
+            jnp.asarray(value, jax.tree.leaves(self._cache)[0].dtype))
 
     def hbm_pools(self) -> dict:
         """Named device-memory pools this worker holds — the HBM ledger's
@@ -975,6 +1008,11 @@ class ServingEngine:
         if self.role not in ("both", "prefill", "decode"):
             raise ValueError(
                 f"serving role must be both|prefill|decode, got {self.role!r}")
+        if self.role != "both" and engine.cfg.kv_lora_rank:
+            raise NotImplementedError(
+                f"serving role {self.role!r} with latent attention (kv_lora_rank > 0): the "
+                "prefill -> decode handoff's wire form (inference/rpc.py: raw or int8 "
+                "windows of per-head K/V) has not carried the latent cache tree yet")
         n_slots = n_slots if n_slots is not None else config.get("n_slots", 8)
         max_seq_len = max_seq_len if max_seq_len is not None else config.get(
             "max_seq_len", 0)
@@ -1217,8 +1255,9 @@ class ServingEngine:
             feat.append(f"speculation[depth {sp.depth}, {sp.draft_source}]")
         log_dist(
             f"serving engine: {n} slots x {self.Smax} tokens, cache "
-            f"{2 * self.cfg.num_layers * n * self.Smax * self.cfg.hidden_size * jnp.dtype(self.cfg.dtype).itemsize / 1e6:.1f} MB, "
-            f"spec={self.worker.spec}" + (", " + ", ".join(feat) if feat else ""),
+            f"{self.worker.hbm_pools()['slot_kv_cache'] / 1e6:.1f} MB at "
+            f"{tfm.cache_bytes_per_token(self.cfg)} B a token a layer "
+            f"({tfm.cache_step_form(self.cfg)}), spec={self.worker.spec}" + (", " + ", ".join(feat) if feat else ""),
             ranks=[0],
         )
 

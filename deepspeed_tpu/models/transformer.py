@@ -54,6 +54,18 @@ class TransformerConfig:
     # RMSNorm with a learned scale over the whole query and the whole key
     # projection, before the head split and the rotary (OLMoE's q_norm / k_norm)
     qk_norm: bool = False
+    # Explicit head sizes: the width of a query / key head and of a value head
+    # (0: hidden_size // num_heads for both). Only latent attention has them yet.
+    qk_head_dim: int = 0
+    v_head_dim: int = 0
+    # Latent attention (MLA, DeepSeek-V2 / V3; no query compression): the keys
+    # and values of all heads are expanded from ONE normed latent of
+    # ``kv_lora_rank`` values a token, and the last ``qk_rope_head_dim`` of each
+    # q/k head are a rotary part whose key is shared by the heads. The cache
+    # holds the latent and that key (``cache_layout``), never per-head K/V.
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    rotary_base: float = 10000.0
     # GPT-Neo alternating local attention: window size + per-layer 0/1 flags
     # (1 = local); None = all-global
     local_attn_window: int = 0
@@ -63,7 +75,8 @@ class TransformerConfig:
     use_bias: bool = True
     final_ln: bool = True  # False: no final LayerNorm (BERT encoders)
     # gelu | gelu_exact | relu | swiglu (down(silu(gate(x)) * up(x)): the gated
-    # expert of moe_routing="dropless"; there is no gated dense feed-forward yet)
+    # experts of moe_routing="dropless", its shared expert and its leading dense
+    # layers; a model with no routed layer has no gated feed-forward yet)
     activation: str = "gelu"
     embed_ln: bool = False  # LayerNorm after embedding (BLOOM)
     attn_impl: str = "xla"  # xla | flash | ring | sparse
@@ -125,6 +138,21 @@ class TransformerConfig:
     # dropless only: the k weights renormalised to sum to 1 (a model's
     # norm_topk_prob), or left as the raw softmax probabilities
     moe_norm_topk_prob: bool = False
+    # dropless only, the router's form (moe/dropless.py::route): the score of an
+    # expert is the softmax over all of them or a sigmoid of its own logit; a
+    # held (not trained by the loss) per-expert bias may be added to the scores
+    # for the CHOICE alone; the k weights are multiplied by a constant
+    moe_score_fn: str = "softmax"  # softmax | sigmoid
+    moe_select_bias: bool = False
+    moe_routed_scale: float = 1.0
+    # dropless only: one gated expert of this width that every token goes
+    # through beside its routed ones (0: none)
+    moe_shared_size: int = 0
+    # dropless only: this many leading layers have a dense gated feed-forward
+    # of width ``dense_intermediate_size`` instead of the routed block
+    # (``intermediate_size`` stays the width of one expert)
+    moe_first_dense: int = 0
+    dense_intermediate_size: Optional[int] = None
     moe_capacity_factor: float = 1.25  # gshard only
     moe_aux_coeff: float = 0.01  # load-balancing loss weight
     loss_chunk_size: int = 512  # chunk the vocab projection in the loss; 0 = off
@@ -157,9 +185,21 @@ class TransformerConfig:
     # partition_parameters.py:537 remote_device='cpu').
     param_offload: bool = False
 
+    def __post_init__(self):
+        _refuse_uncoded(self)
+
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        """The width of a query / key head."""
+        return self.qk_head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def value_head_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def dense_ffn_size(self) -> int:
+        return self.dense_intermediate_size or self.ffn_size
 
     @property
     def ffn_size(self) -> int:
@@ -177,15 +217,65 @@ def _dense_init(key, shape, fan_in):
     return (jax.random.normal(key, shape) * (1.0 / math.sqrt(fan_in))).astype(jnp.float32)
 
 
+def _refuse_uncoded(cfg: "TransformerConfig") -> None:
+    """Called when a configuration is built: the combinations of the latent
+    attention and of the dropless router's forms that have no code are refused
+    by name, never computed as something else."""
+    if cfg.kv_lora_rank > 0:
+        no_code = {
+            f"pos_emb={cfg.pos_emb!r} (its positions are the rotary part of q and k)":
+                cfg.pos_emb != "rotary",
+            "qk_norm": cfg.qk_norm, "use_bias": cfg.use_bias, "causal=False": not cfg.causal,
+            "local_attn_layers": cfg.local_attn_layers is not None,
+            "weight_bits": cfg.weight_bits != 0,
+            f"attn_impl={cfg.attn_impl!r} (training attends through the XLA form: the flash "
+            "backward kernels take one head size)": cfg.attn_impl != "xla",
+            "decode_attn='kernel' (the Pallas decode kernel reads per-head K/V; state "
+            "decode_attn='xla')": cfg.decode_attn == "kernel",
+        }
+        for what, refused in no_code.items():
+            if refused:
+                raise NotImplementedError(f"latent attention (kv_lora_rank > 0) with {what} "
+                                          "has no code")
+        rope = cfg.qk_rope_head_dim
+        if not (0 < rope < cfg.qk_head_dim and rope % 2 == 0 and cfg.v_head_dim > 0):
+            raise ValueError(
+                "latent attention states its head sizes: qk_head_dim > qk_rope_head_dim > 0 "
+                f"(even) and v_head_dim > 0 (got {cfg.qk_head_dim}, {rope}, {cfg.v_head_dim})")
+    elif cfg.qk_head_dim or cfg.v_head_dim or cfg.qk_rope_head_dim:
+        raise NotImplementedError(
+            "qk_head_dim / v_head_dim / qk_rope_head_dim without latent attention "
+            "(kv_lora_rank > 0) have no code: a plain head is hidden_size // num_heads wide")
+    if cfg.moe_score_fn not in ("softmax", "sigmoid"):
+        raise ValueError(f"moe_score_fn is 'softmax' or 'sigmoid', not {cfg.moe_score_fn!r}")
+    forms = {"moe_score_fn": cfg.moe_score_fn != "softmax", "moe_select_bias": cfg.moe_select_bias,
+             "moe_routed_scale": cfg.moe_routed_scale != 1.0,
+             "moe_shared_size": cfg.moe_shared_size != 0,
+             "moe_first_dense": cfg.moe_first_dense != 0,
+             "dense_intermediate_size": cfg.dense_intermediate_size is not None}
+    stated = [name for name, is_stated in forms.items() if is_stated]
+    if stated and cfg.moe_routing != "dropless":
+        raise NotImplementedError(
+            f"{', '.join(stated)}: only moe_routing='dropless' has these forms "
+            f"(got moe_routing={cfg.moe_routing!r})")
+    if cfg.moe_first_dense and (cfg.use_bias or not 0 < cfg.moe_first_dense < cfg.num_layers):
+        raise NotImplementedError(
+            "moe_first_dense: the leading dense gated layers have no biases (use_bias=False) "
+            f"and leave a routed layer behind them (got {cfg.moe_first_dense} of "
+            f"{cfg.num_layers} layers, use_bias={cfg.use_bias})")
+
+
 def _dropless(cfg: TransformerConfig) -> bool:
-    """Whether the feed-forward of every layer is the dropless routed block
-    (moe/dropless.py); refuses the combinations that block does not have."""
+    """Whether the model's feed-forward is the dropless routed block
+    (moe/dropless.py) in every layer after the ``moe_first_dense`` leading ones;
+    refuses the combinations that block does not have."""
     dropless, gated = cfg.moe_routing == "dropless", cfg.activation == "swiglu"
     if dropless != gated or (dropless and cfg.moe_every != 1):
         raise NotImplementedError(
             "moe_routing='dropless', activation='swiglu' and moe_every=1 come together: the "
-            "dropless block has gated experts in every layer, and there is no gated dense "
-            f"feed-forward (got moe_routing={cfg.moe_routing!r}, activation="
+            "dropless block has gated experts in every layer (after moe_first_dense leading "
+            "dense gated ones), and a model with no routed layer has no gated feed-forward "
+            f"(got moe_routing={cfg.moe_routing!r}, activation="
             f"{cfg.activation!r}, moe_every={cfg.moe_every})")
     return dropless
 
@@ -193,21 +283,26 @@ def _dropless(cfg: TransformerConfig) -> bool:
 def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
     keys = jax.random.split(rng, 16)
     d, f, L = cfg.hidden_size, cfg.ffn_size, cfg.num_layers
-    H, Dh = cfg.num_heads, cfg.head_dim
+    H, Dh, Dv = cfg.num_heads, cfg.head_dim, cfg.value_head_dim
     dropless = _dropless(cfg)
 
-    def stack(key, shape, fan_in):
-        ks = jax.random.split(key, L)
+    def stack(key, shape, fan_in, n=L):
+        ks = jax.random.split(key, n)
         return jnp.stack([_dense_init(k, shape, fan_in) for k in ks])
 
     layers = {
         "ln1_scale": jnp.ones((L, d)),
         "ln2_scale": jnp.ones((L, d)),
         "wq": stack(keys[0], (d, H, Dh), d),
-        "wk": stack(keys[1], (d, H, Dh), d),
-        "wv": stack(keys[2], (d, H, Dh), d),
-        "wo": stack(keys[3], (H, Dh, d), d),
+        "wo": stack(keys[3], (H, Dv, d), d),
     }
+    if cfg.kv_lora_rank:  # the latent and the shared rotary key; then every head's k_nope | v
+        R, Dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        layers.update({"wkv_a": stack(keys[1], (d, R + Dr), d),
+                       "kv_norm_scale": jnp.ones((L, R)),
+                       "wkv_b": stack(keys[2], (R, H, Dh - Dr + Dv), R)})
+    else:
+        layers.update({"wk": stack(keys[1], (d, H, Dh), d), "wv": stack(keys[2], (d, H, Dh), d)})
     if cfg.norm_kind != "rms":
         layers.update({"ln1_bias": jnp.zeros((L, d)), "ln2_bias": jnp.zeros((L, d))})
     if cfg.qk_norm:
@@ -243,7 +338,13 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
     if dropless:
         from ..moe.dropless import init_dropless
 
-        params["moe"] = init_dropless(keys[9], L, cfg.num_experts, d, f)
+        lead, fd = cfg.moe_first_dense, cfg.dense_ffn_size
+        params["moe"] = init_dropless(keys[9], L - lead, cfg.num_experts, d, f,
+                                      shared=cfg.moe_shared_size, select_bias=cfg.moe_select_bias)
+        if lead:
+            params["dense_ffn"] = {"wg": stack(keys[10], (d, fd), d, lead),
+                                   "wi": stack(keys[11], (d, fd), d, lead),
+                                   "wo_mlp": stack(keys[12], (fd, d), fd, lead)}
     elif cfg.moe_every > 0:
         from ..moe.layer import init_moe_params
 
@@ -260,10 +361,14 @@ def logical_axes(cfg: TransformerConfig) -> Params:
         "ln1_scale": ("layers", "embed"),
         "ln2_scale": ("layers", "embed"),
         "wq": ("layers", "embed", "heads", "kv"),
-        "wk": ("layers", "embed", "heads", "kv"),
-        "wv": ("layers", "embed", "heads", "kv"),
         "wo": ("layers", "heads", "kv", "embed"),
     }
+    if cfg.kv_lora_rank:  # the latent is every head's: not split over them
+        layers.update({"wkv_a": ("layers", "embed", None), "kv_norm_scale": ("layers", None),
+                       "wkv_b": ("layers", None, "heads", "kv")})
+    else:
+        layers.update({"wk": ("layers", "embed", "heads", "kv"),
+                       "wv": ("layers", "embed", "heads", "kv")})
     if cfg.norm_kind != "rms":
         layers.update({"ln1_bias": ("layers", "embed"), "ln2_bias": ("layers", "embed")})
     if cfg.qk_norm:
@@ -300,7 +405,11 @@ def logical_axes(cfg: TransformerConfig) -> Params:
     if dropless:
         from ..moe.dropless import dropless_logical_axes
 
-        axes["moe"] = dropless_logical_axes()
+        axes["moe"] = dropless_logical_axes(shared=bool(cfg.moe_shared_size),
+                                            select_bias=cfg.moe_select_bias)
+        if cfg.moe_first_dense:
+            axes["dense_ffn"] = {"wg": ("layers", "embed", "mlp"), "wi": ("layers", "embed", "mlp"),
+                                 "wo_mlp": ("layers", "mlp", "embed")}
     elif cfg.moe_every > 0:
         from ..moe.layer import moe_logical_axes
 
@@ -317,14 +426,16 @@ def logical_axes(cfg: TransformerConfig) -> Params:
 # vocabulary projection, the attention and feed-forward matrices and the biases
 # added in the activations' dtype (``_qkv_proj``, ``_attn_out_proj``, ``_ffn``),
 # and the expert banks (``wg`` / ``wi`` / ``wo`` of moe/dropless.py and
-# moe/experts.py). Every other floating leaf is read in float32: the norm scales
-# and biases (``layer_norm`` / ``rms_norm`` multiply in float32), the q/k norm
-# scales, ``lm_head_bias`` (added to float32 logits) and the router's ``gate``
-# (``dropless.route`` is a float32 product at full precision, and the GShard
-# path's ``moe_dispatch_combine`` likewise).
+# moe/experts.py; the shared expert and the leading dense layers have the same
+# names), and latent attention's ``wkv_a`` / ``wkv_b``. Every other floating leaf
+# is read in float32: the norm scales and biases (``layer_norm`` / ``rms_norm``
+# multiply in float32), the q/k norm scales and the latent's ``kv_norm_scale``,
+# ``lm_head_bias`` (added to float32 logits) and the router's ``gate`` and its
+# selection ``bias`` (``dropless.route`` is a float32 product at full precision,
+# and the GShard path's ``moe_dispatch_combine`` likewise).
 _READ_IN_COMPUTE_DTYPE = frozenset({
     "wte", "wpe", "lm_head",
-    "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo",
+    "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo", "wkv_a", "wkv_b",
     "wi", "wo_mlp", "bi", "bo_mlp", "wg",
 })
 
@@ -374,7 +485,7 @@ def norm(cfg: "TransformerConfig", x, p, name: str):
     return layer_norm(x, p[name + "_scale"], p[name + "_bias"], cfg.layernorm_epsilon)
 
 
-def rotary_embed(x, positions, rotary_dims, interleaved: bool = False):
+def rotary_embed(x, positions, rotary_dims, interleaved: bool = False, base: float = 10000.0):
     """Apply rotary position embedding to the first ``rotary_dims`` of x
     [B, S, H, Dh] (reference inference kernel: apply_rotary_pos_emb,
     csrc/transformer/inference/csrc/pt_binding.cpp:1268). ``interleaved``
@@ -383,7 +494,7 @@ def rotary_embed(x, positions, rotary_dims, interleaved: bool = False):
     rd = rotary_dims
     x_rot, x_pass = x[..., :rd], x[..., rd:]
     half = rd // 2
-    freqs = jnp.exp(-math.log(10000.0) * jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs = jnp.exp(-math.log(base) * jnp.arange(0, half, dtype=jnp.float32) / half)
     angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]  # [B,S,half]
     cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
     sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
@@ -416,10 +527,17 @@ def xla_attention(q, k, v, *, causal_offset=0, bias=None, causal=True, dtype=jnp
     reference's test_cuda_forward.py strategy). ``causal=False`` gives the
     bidirectional encoder form (BERT). ``causal_offset`` may be a scalar or a
     per-row [B] vector — continuous batching decodes every cache slot at its
-    own absolute position."""
+    own absolute position. v's heads may be another width than q's and k's."""
     B, Sq, H, Dh = q.shape
-    Sk = k.shape[1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / math.sqrt(Dh)
+    probs = _masked_softmax(scores, causal_offset, bias, causal).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def _masked_softmax(scores, causal_offset=0, bias=None, causal=True):
+    """float32 scores [B, H, Sq, Sk] -> probabilities, under ``xla_attention``'s
+    additive bias and causal mask (``causal_offset``: scalar or [B])."""
+    Sq, Sk = scores.shape[-2:]
     if bias is not None:
         scores = scores + bias
     if causal:
@@ -434,8 +552,37 @@ def xla_attention(q, k, v, *, causal_offset=0, bias=None, causal=True, dtype=jnp
             k_pos = jnp.arange(Sk)[None, None, :]
             mask = q_pos >= k_pos  # [B, Sq, Sk]
             scores = jnp.where(mask[:, None], scores, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    return jax.nn.softmax(scores, axis=-1)
+
+
+def _latent_expand(cfg: "TransformerConfig", lp, k_pe, c):
+    """Latent attention's EXPANDED form: the rotary key k_pe [B, S, 1, Dr] and
+    the normed latent c [B, S, 1, R] of a block -> its per-head keys
+    [B, S, H, Dn + Dr] (``k_nope`` from the latent, then the key every head
+    shares) and values [B, S, H, Dv]; plain attention follows."""
+    Dn = cfg.head_dim - cfg.qk_rope_head_dim
+    kv = jnp.einsum("bsr,rhk->bshk", c[:, :, 0], lp["wkv_b"].astype(c.dtype))
+    k_pe = jnp.broadcast_to(k_pe, kv.shape[:3] + k_pe.shape[3:])
+    return jnp.concatenate([kv[..., :Dn], k_pe], axis=-1), kv[..., Dn:]
+
+
+def _latent_attention(cfg: "TransformerConfig", lp, q, k_pe, c, pos):
+    """Latent attention's ABSORBED form, for a step that reads the cache:
+    q [B, T, H, Dn + Dr] against the cached rotary keys k_pe [B, Smax, 1, Dr]
+    and latents c [B, Smax, 1, R] whose valid rows are [0, pos + T) (``pos``
+    scalar or [B]). With ``wkv_b`` split a head into W_uk, W_uv [R, D]:
+    score_h(s) = ((q_nope_h W_uk_h^T) c_s + q_pe_h k_pe_s) / sqrt(Dn + Dr) and
+    out_h = (sum_s p_s c_s) W_uv_h, equal to the expanded form in exact
+    arithmetic; the cache is read as it lies and never expanded to heads."""
+    Dn = cfg.head_dim - cfg.qk_rope_head_dim
+    w = lp["wkv_b"].astype(q.dtype)
+    k_pe, c = k_pe[:, :, 0], c[:, :, 0]
+    q_lat = jnp.einsum("bthn,rhn->bthr", q[..., :Dn], w[..., :Dn])
+    scores = (jnp.einsum("bthr,bsr->bhts", q_lat, c).astype(jnp.float32)
+              + jnp.einsum("bthp,bsp->bhts", q[..., Dn:], k_pe).astype(jnp.float32))
+    probs = _masked_softmax(scores / math.sqrt(cfg.head_dim), pos).astype(q.dtype)
+    out_lat = jnp.einsum("bhts,bsr->bthr", probs, c)
+    return jnp.einsum("bthr,rhv->bthv", out_lat, w[..., Dn:])
 
 
 def _param_streamer(cfg: TransformerConfig):
@@ -647,7 +794,9 @@ def _ffn(cfg, lp, h):
         u = jnp.einsum("bsd,df->bsf", h, lp["wi"].astype(h.dtype))
         if cfg.use_bias:
             u = u + lp["bi"].astype(h.dtype)
-        if cfg.activation == "relu":
+        if cfg.activation == "swiglu":  # a leading dense layer of a dropless model: no biases
+            u = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["wg"].astype(h.dtype))) * u
+        elif cfg.activation == "relu":
             u = jax.nn.relu(u)
         elif cfg.activation == "gelu_exact":
             u = jax.nn.gelu(u, approximate=False)
@@ -666,10 +815,22 @@ def _dense_ffn(cfg, lp, h):
 
 
 def _qkv_proj(cfg: TransformerConfig, lp, h, positions):
-    """LN'd hidden states -> rotary-embedded q, k, v [B, T, H, Dh]."""
+    """LN'd hidden states -> rotary-embedded q, k, v [B, T, H, Dh]. Latent
+    attention gives what it caches in the place of k and v: the rotary key
+    every head shares [B, T, 1, Dr] and the normed latent [B, T, 1, R]; the
+    block's ``attend`` expands or absorbs them (``_latent_expand`` /
+    ``_latent_attention``)."""
     with jax.named_scope("attn"):
         h = _act_q(cfg, h)
         q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(h.dtype))
+        if cfg.kv_lora_rank:
+            R, Dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+            rope = partial(rotary_embed, positions=positions, rotary_dims=Dr,
+                           interleaved=cfg.rotary_interleaved, base=cfg.rotary_base)
+            kv = jnp.einsum("bsd,dr->bsr", h, lp["wkv_a"].astype(h.dtype))
+            c = rms_norm(kv[..., :R], lp["kv_norm_scale"], cfg.layernorm_epsilon)
+            q = jnp.concatenate([q[..., :-Dr], rope(q[..., -Dr:])], axis=-1)
+            return q, rope(kv[:, :, None, R:]), c[:, :, None]
         k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(h.dtype))
         v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(h.dtype))
         if cfg.use_bias:
@@ -681,8 +842,8 @@ def _qkv_proj(cfg: TransformerConfig, lp, h, positions):
             k = rms_norm(k, lp["k_norm_scale"], cfg.layernorm_epsilon, axes=(-2, -1))
         if cfg.pos_emb == "rotary":
             rd = int(cfg.head_dim * cfg.rotary_pct)
-            q = rotary_embed(q, positions, rd, interleaved=cfg.rotary_interleaved)
-            k = rotary_embed(k, positions, rd, interleaved=cfg.rotary_interleaved)
+            q = rotary_embed(q, positions, rd, cfg.rotary_interleaved, cfg.rotary_base)
+            k = rotary_embed(k, positions, rd, cfg.rotary_interleaved, cfg.rotary_base)
         return q, k, v
 
 
@@ -789,10 +950,11 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
     ``l`` its index in the stacks it came from. What differs between callers
     comes in as arguments and nothing else does:
 
-    - ``attend(q, k, v, state, l) -> (attention output, state)``: how attention
-      is computed from the projected q, k, v and what state it carries
+    - ``attend(q, k, v, state, l, lp) -> (attention output, state)``: how
+      attention is computed from the projected q, k, v and what state it carries
       (training: ``_stateless_attention``, no state; serving:
-      ``_cache_attention``, state = the two stacked caches);
+      ``_cache_attention``, state = the stacked cache tree); ``lp`` is there
+      for latent attention, whose form (expanded or absorbed) is ``attend``'s;
     - ``ffn(lp, h) -> (out, aux_loss, experts chosen or None)``: this layer's
       feed-forward, dense or routed (``_layer_loop`` picks);
     - ``rng`` / ``pld_keep``: the training-only stochastic gates (dropout;
@@ -816,7 +978,7 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
 
     pre = cfg.norm_style != "post"
     q, k, v = _qkv_proj(cfg, lp, norm(cfg, x, lp, "ln1") if pre else x, positions)
-    attn, state = attend(q, k, v, state, l)
+    attn, state = attend(q, k, v, state, l, lp)
     attn_out = branch(_attn_out_proj(cfg, lp, attn), cfg.attn_dropout, k_attn)
     if pre and cfg.parallel_residual:
         f, aux, experts = ffn(lp, norm(cfg, x, lp, "ln2"))
@@ -840,12 +1002,15 @@ def _stateless_attention(cfg: TransformerConfig, S: int):
     attn_fn = _attention_dispatch(cfg)
     fused = getattr(attn_fn, "handles_fused_bias", False)
     bias = None if fused else attn_bias(cfg, S)
+    if cfg.kv_lora_rank:  # a whole sequence fills its own cache: the expanded form
+        return lambda q, k, v, state, l, lp: (
+            attn_fn(q, *_latent_expand(cfg, lp, k, v), bias), state)
     if cfg.local_attn_window <= 0 or cfg.local_attn_layers is None:
-        return lambda q, k, v, state, l: (attn_fn(q, k, v, bias), state)
+        return lambda q, k, v, state, l, lp: (attn_fn(q, k, v, bias), state)
     is_local = jnp.asarray(cfg.local_attn_layers, bool)  # per layer
     local_bias = None if fused else _local_attn_bias(cfg, S)
 
-    def attend(q, k, v, state, l):
+    def attend(q, k, v, state, l, lp):
         if fused:  # the raw window (0 = global) instead of a dense bias
             w = jnp.where(is_local[l], jnp.float32(cfg.local_attn_window), jnp.float32(0))
             return attn_fn(q, k, v, bias, window=w), state
@@ -875,7 +1040,7 @@ def _remat_wrapper(cfg: TransformerConfig):
 
 
 def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, attend,
-                per_layer=None, wrap=None, decode: bool = False):
+                per_layer=None, wrap=None, decode: bool = False, lead=None):
     """THE layer loop: ``_block`` over the stacked ``layers`` [L, ...] (the
     whole model's, or one pipeline stage's slice), ``moe`` the routed layers'
     stacks or None -> (x, state, summed aux loss, experts chosen or None).
@@ -887,7 +1052,10 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
     under ``wrap`` has ``remat_group`` layers a period instead. ``wrap`` (the
     caller's ``_remat_wrapper`` or None) goes round each scanned body;
     ``per_layer`` holds [L]-leading ``rng`` / ``pld_keep`` for the block;
-    ``decode`` marks a single-token step for the routed feed-forward."""
+    ``decode`` marks a single-token step for the routed feed-forward. ``lead``
+    (``params["dense_ffn"]`` or None): the gated feed-forward stacks of the
+    ``moe_first_dense`` leading layers, which run in a scan of their own before
+    the periods; ``moe`` then holds the stacks of the layers after them."""
     layers_xs, load_layer = _make_stack_loader(cfg, layers)
     routed = cfg.moe_every > 0 and moe is not None
     moe_xs, load_moe = _make_stack_loader(cfg, moe) if routed else (None, None)
@@ -895,10 +1063,26 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
     xs = (layers_xs, jnp.arange(L, dtype=jnp.int32), per_layer or {})
     unroll = max(1, cfg.scan_unroll)
     block = partial(_block, cfg, positions=positions, attend=attend)
+    wrap = wrap or (lambda body: body)
+    carry = (x, state)
 
     def dense(carry, xs):
         lp, l, gates = xs
         return block(carry, load_layer(lp), l, **gates, ffn=partial(_dense_ffn, cfg))[0], None
+
+    if lead is not None:
+        lead_xs, load_lead = _make_stack_loader(cfg, lead)
+        n_lead = jax.tree.leaves(lead_xs)[0].shape[0]
+
+        def leading(carry, xs):
+            (lp, l, gates), ffn_p = xs
+            return block(carry, {**load_layer(lp), **load_lead(ffn_p)}, l, **gates,
+                         ffn=partial(_dense_ffn, cfg))[0], None
+
+        carry, _ = lax.scan(wrap(leading), carry,
+                            (jax.tree.map(lambda a: a[:n_lead], xs), lead_xs), unroll=unroll)
+        xs = jax.tree.map(lambda a: a[n_lead:], xs)
+        L -= n_lead
 
     period = 1
     if routed:
@@ -927,8 +1111,6 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
         return block(carry, load_layer(lp), l, **gates,
                      ffn=lambda lp, h: _moe_ffn(cfg, load_moe(moe_p), h, decode))
 
-    wrap = wrap or (lambda body: body)
-    carry = (x, state)
     G, tail = divmod(L, period)
     if period == 1 and not routed:  # a period of one dense layer is the layer
         carry, ys = lax.scan(wrap(dense), carry, xs, unroll=unroll)
@@ -1031,7 +1213,8 @@ def apply(
             per_layer["pld_keep"] = 1.0 - depth_frac * (1.0 - theta_t)  # [L]
     x, _, aux_total, chosen = _layer_loop(
         cfg, params["layers"], params.get("moe"), x, None, positions=positions,
-        attend=_stateless_attention(cfg, S), per_layer=per_layer, wrap=_remat_wrapper(cfg))
+        attend=_stateless_attention(cfg, S), per_layer=per_layer, wrap=_remat_wrapper(cfg),
+        lead=params.get("dense_ffn"))
     x = _final_norm(cfg, params, x) if return_hidden else _lm_head(cfg, params, x)
     out = (x,) + ((aux_total,) if with_aux else ()) + ((chosen,) if return_routing else ())
     return out if len(out) > 1 else x
@@ -1069,22 +1252,46 @@ def _moe_ffn(cfg, moe_p, h, decode: bool = False):
 # The reference's decode path is the fused `softmax_context` CUDA kernel with
 # an incremental KV cache (csrc/transformer/inference/csrc/pt_binding.cpp:
 # softmax_context_* :1237, attention-with-cache). TPU-native: the cache is a
-# static-shape [L, B, Smax, H, Dh] pair that stays ONE buffer through the
-# layer scan (its carry: layer l writes its new rows at [l, row, pos] and
-# attends to layer l of the stack where it lies); one `apply_with_cache`
-# function serves both prefill (T = prompt len, pos = 0) and decode (T = 1)
-# so XLA compiles exactly two programs per sequence budget.
+# tree of static-shape [L, B, Smax, heads, width] leaves (``cache_layout``)
+# that stays ONE set of buffers through the layer scan (its carry: layer l
+# writes its new rows at [l, row, pos] and attends to layer l of the stack
+# where it lies); one `apply_with_cache` function serves both prefill
+# (T = prompt len, pos = 0) and decode (T = 1) so XLA compiles exactly two
+# programs per sequence budget.
+
+def cache_layout(cfg: TransformerConfig) -> dict:
+    """{leaf: (heads, width)} of what the model's attention caches a token a
+    layer; every helper below, and the serving engine, work on the tree this
+    gives and on no leaf's name or trailing shape. Plain attention: the keys
+    and the values of every head. Latent attention: ``k`` is the rotary key the
+    heads share, ``v`` the normed latent, which is the absorbed form's value
+    and the rest of its key; the 'one head' is every head's."""
+    if cfg.kv_lora_rank:
+        return {"k": (1, cfg.qk_rope_head_dim), "v": (1, cfg.kv_lora_rank)}
+    return {"k": (cfg.num_heads, cfg.head_dim), "v": (cfg.num_heads, cfg.value_head_dim)}
+
+
+def cache_bytes_per_token(cfg: TransformerConfig, dtype=None) -> int:
+    """Bytes one token takes in ONE layer of the cache."""
+    values = sum(heads * width for heads, width in cache_layout(cfg).values())
+    return values * jnp.dtype(dtype or cfg.dtype).itemsize
+
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int, dtype=None):
-    """Allocate an empty KV cache for ``batch`` sequences of up to ``max_len``."""
+    """Allocate an empty cache for ``batch`` sequences of up to ``max_len``."""
     dtype = dtype or cfg.dtype
-    shape = (cfg.num_layers, batch, max_len, cfg.num_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return {name: jnp.zeros((cfg.num_layers, batch, max_len) + tail, dtype)
+            for name, tail in cache_layout(cfg).items()}
+
+
+def cache_len(cache) -> int:
+    """Smax of a [L, B, Smax, ...] cache tree."""
+    return jax.tree.leaves(cache)[0].shape[2]
 
 
 def slice_cache_slot(cache, slot, length: int, start=0):
-    """Read one sequence's KV window out of a slot cache:
-    {k,v} [L, B, Smax, H, Dh] -> [L, 1, length, H, Dh] at row ``slot``,
+    """Read one sequence's window out of a slot cache: every leaf
+    [L, B, Smax, H, Dh] -> [L, 1, length, H, Dh] at row ``slot``,
     positions [start, start+length). ``slot`` and ``start`` may be traced
     int32 scalars — the caller's program stays compile-stable across
     slots/offsets; ``length`` is static: it picks the compiled program.
@@ -1094,30 +1301,26 @@ def slice_cache_slot(cache, slot, length: int, start=0):
     ``apply_with_cache`` at the chunk's offset, and write back only the
     chunk's region; prefix fetch/store move windows between the slot cache
     and the prefix pool."""
-    L, _, Smax, H, Dh = cache["k"].shape
-    if length > Smax:
-        raise ValueError(f"cache window ({length}) exceeds cache length {Smax}")
+    if length > cache_len(cache):
+        raise ValueError(f"cache window ({length}) exceeds cache length {cache_len(cache)}")
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
-    return {
-        kv: lax.dynamic_slice(cache[kv], (0, slot, start, 0, 0), (L, 1, length, H, Dh))
-        for kv in ("k", "v")
-    }
+    return jax.tree.map(
+        lambda c: lax.dynamic_slice(c, (0, slot, start, 0, 0),
+                                    (c.shape[0], 1, length) + c.shape[3:]), cache)
 
 
 def update_cache_slot(cache, window, slot, start=0):
-    """Write a [L, 1, W, H, Dh] KV window into row ``slot`` of a slot cache
-    at positions [start, start+W) (one ``dynamic_update_slice`` per k/v —
-    the inverse of ``slice_cache_slot``). ``slot``/``start`` are traced
+    """Write a window (every leaf [L, 1, W, H, Dh]) into row ``slot`` of a
+    slot cache at positions [start, start+W) (one ``dynamic_update_slice`` per
+    leaf — the inverse of ``slice_cache_slot``). ``slot``/``start`` are traced
     scalars: one compiled program regardless of which slot/offset is
     written."""
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
-    return {
-        kv: lax.dynamic_update_slice(
-            cache[kv], window[kv].astype(cache[kv].dtype), (0, slot, start, 0, 0))
-        for kv in ("k", "v")
-    }
+    return jax.tree.map(
+        lambda c, w: lax.dynamic_update_slice(c, w.astype(c.dtype), (0, slot, start, 0, 0)),
+        cache, window)
 
 
 def cached_attention(q, k_cache, v_cache, pos, *, bias=None):
@@ -1154,13 +1357,26 @@ def cache_attention_form(num_heads: int, B: int, T: int, Smax: int, lock_step: b
     return "flash" if fills_cache and 4 * B * num_heads * T * Smax > DENSE_SCORE_BYTES else "dense"
 
 
+def cache_step_form(cfg: TransformerConfig) -> str:
+    """``"latent"`` or ``"dense"``: how a step that READS the slot cache
+    (decode, verify, chunk) attends, for the spans' ``attn``: latent attention
+    in its absorbed form over the cached latent, plain attention densely (or
+    through the Pallas decode kernel) over per-head K/V."""
+    return "latent" if cfg.kv_lora_rank else "dense"
+
+
 def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, write_pos=None):
     """-> (positions [B, T], the block's ``attend``) for T new tokens entering a
-    stacked [L, B, Smax, H, Dh] cache pair at ``pos`` (scalar, or [B] with
+    stacked [L, B, Smax, H, Dh] cache tree at ``pos`` (scalar, or [B] with
     ``write_pos``: see ``apply_with_cache``).
 
     ``attend`` writes the new rows into layer ``l`` of the stacks and attends to
-    that layer where it lies. The stacks are its state, so they stay the layer
+    that layer where it lies. Latent attention has its two forms here, chosen
+    from the same shapes: a block that fills its cache expands its own latent
+    and attends as plain causal attention (``_latent_expand``; the flash kernel
+    by ``cache_attention_form``'s rule, at q/k and v heads of two widths);
+    every other block attends in the absorbed form over the cached latent
+    (``_latent_attention``) and nothing per-head is ever made of the cache. The stacks are its state, so they stay the layer
     loop's CARRY: with the cache donated the loop's input and output are one
     buffer; as the scan's xs/ys they would be sliced out and restacked layer by
     layer and copied whole (tests/test_chip_compile.py guards it)."""
@@ -1225,22 +1441,27 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
         def write(c, l, new):
             return lax.dynamic_update_slice(c, new[None].astype(c.dtype), (l, 0, pos, 0, 0))
 
-    def attend(q, k, v, stacks, l):
-        k_stack, v_stack = write(stacks[0], l, k), write(stacks[1], l, v)
+    def attend(q, k, v, stacks, l, lp):
+        k_stack, v_stack = write(stacks["k"], l, k), write(stacks["v"], l, v)
+        stacks = {"k": k_stack, "v": v_stack}
         if use_decode_kernel:
             attn = decode_attention(q[:, 0], k_stack, v_stack, pos, layer=l)[:, None]
-            return attn, (k_stack, v_stack)
+            return attn, stacks
         if fills_cache:
             k_l, v_l = k.astype(k_stack.dtype), v.astype(v_stack.dtype)
+            if cfg.kv_lora_rank:
+                k_l, v_l = _latent_expand(cfg, lp, k_l, v_l)
         else:
             k_l, v_l = (lax.dynamic_index_in_dim(c, l, keepdims=False)
                         for c in (k_stack, v_stack))
+            if cfg.kv_lora_rank:
+                return _latent_attention(cfg, lp, q, k_l, v_l, pos), stacks
         if use_flash:
             attn = flash_attention_sharded(q, k_l, v_l, mesh=_ACTIVE_MESH[0], causal=True,
                                            alibi_slopes=slopes)
         else:
             attn = cached_attention(q, k_l, v_l, pos, bias=bias)
-        return attn, (k_stack, v_stack)
+        return attn, stacks
 
     return positions, attend
 
@@ -1290,18 +1511,17 @@ def apply_with_cache(
         )
     B, T = tokens.shape
     params = _stream_top_level(cfg, params)
-    positions, attend = _cache_attention(cfg, B, T, cache["k"].shape[2], pos, write_pos)
+    positions, attend = _cache_attention(cfg, B, T, cache_len(cache), pos, write_pos)
     x, _ = embed(cfg, params, tokens, positions)
-    x, (new_k, new_v), _, chosen = _layer_loop(
-        cfg, params["layers"], params.get("moe"), x, (cache["k"], cache["v"]),
-        positions=positions, attend=attend, decode=T == 1)
+    x, cache, _, chosen = _layer_loop(
+        cfg, params["layers"], params.get("moe"), x, dict(cache),
+        positions=positions, attend=attend, decode=T == 1, lead=params.get("dense_ffn"))
     if last_index is not None:
         # bucketed prefill: the live last token sits at ``last_index``
         # (prompt_len - 1), not at T-1 — project only that position
         x = lax.dynamic_slice_in_dim(x, jnp.asarray(last_index, jnp.int32), 1, axis=1)
     elif last_only:
         x = x[:, -1:]
-    cache = {"k": new_k, "v": new_v}
     logits = _lm_head(cfg, params, x)
     return (logits, cache, chosen) if return_routing else (logits, cache)
 

@@ -6,10 +6,14 @@ Beside the GShard path (``sharded_moe.py``: top-1 / top-2, a capacity, one-hot
 ``[T, E, C]`` dispatch and combine einsums, tokens over capacity dropped) this
 one has no capacity and no ``[T, E, C]`` tensor at any skew:
 
-* ``route``: router logits and a softmax over every expert in float32, top-k
-  for any k, the k weights renormalised or left as the raw probabilities
-  (``moe_norm_topk_prob``, a model's ``norm_topk_prob``); the one router of
-  training, prefill and decode.
+* ``route``: router logits in float32; an expert's score the softmax over every
+  expert or the sigmoid of its own logit (``moe_score_fn``); top-k for any k,
+  taken of the scores or of the scores plus a held per-expert bias that selects
+  and does not weigh (``moe_select_bias``: DeepSeek-V3's
+  ``e_score_correction_bias``); the k weights renormalised or left as the raw
+  scores (``moe_norm_topk_prob``, a model's ``norm_topk_prob``) and multiplied by
+  ``moe_routed_scale``; the one router of training, prefill and decode, its
+  forms data of the configuration.
 * ``experts_sorted``: the token-expert pairs sorted by expert, one grouped
   matmul per projection over the contiguous groups (``jax.lax.ragged_dot``:
   on a TPU a grouped-GEMM kernel that visits only the rows there are),
@@ -18,6 +22,10 @@ one has no capacity and no ``[T, E, C]`` tensor at any skew:
   once; for the few rows of a decode step, where the sorted form reads the
   same bytes and pays a sort, two gathers and near-empty tiles on top
   (PERF.md §6, PR 27 has the chip's timings of both).
+
+* the shared expert (``moe_shared_size``): one more gated MLP that every row
+  goes through, a plain matmul beside the routed sum and not a group of the
+  grouped GEMM (its rows are all of them: nothing to sort).
 
 Experts are ``down(silu(gate(x)) * up(x))``, stacked ``[layers, E, ...]`` under
 the ``expert`` logical axis so a mesh shards them like the GShard bank.
@@ -43,17 +51,30 @@ from .experts import experts_logical_axes
 # bfloat16: up to 512 the dense form wins either way.
 DENSE_ROWS = 512
 
+# The standard deviation the selection bias is DRAWN with. The published model
+# starts it at zero and moves it by the experts' load, never by the loss; at
+# zero, choosing by ``score + bias`` is choosing by ``score`` and the mechanism
+# is tested by nothing. Sigmoid scores of unit-variance logits lie about 0.011
+# apart round the 6th of 128 (0.03 round the 2nd of 8), so a bias of this spread
+# changes the chosen set for most tokens and leaves most of each set in place
+# (tests/test_kanana.py counts both).
+SELECT_BIAS_STD = 0.02
 
-def init_dropless(rng, n_layers: int, num_experts: int, d_model: int, d_ff: int):
+
+def init_dropless(rng, n_layers: int, num_experts: int, d_model: int, d_ff: int,
+                  shared: int = 0, select_bias: bool = False):
     """Router and gated expert bank of ``n_layers`` routed layers; every stack
-    is drawn whole, in one call (no per-layer list to restack)."""
-    keys = jax.random.split(rng, 4)
+    is drawn whole, in one call (no per-layer list to restack). ``shared``: the
+    width of the shared expert (0: none); ``select_bias``: the router's held
+    selection bias, drawn non-zero (``SELECT_BIAS_STD``)."""
+    keys = jax.random.split(rng, 4)  # the gate and the bank draw as they always have
+    keys = list(keys) + list(jax.random.split(jax.random.fold_in(rng, 1), 4))
 
     def draw(key, shape, fan_in):
         return jax.random.normal(key, shape, jnp.float32) * (1.0 / math.sqrt(fan_in))
 
     bank = (n_layers, num_experts)
-    return {
+    out = {
         "gate": draw(keys[0], (n_layers, d_model, num_experts), d_model),
         "experts": {
             "wg": draw(keys[1], bank + (d_model, d_ff), d_model),
@@ -61,26 +82,49 @@ def init_dropless(rng, n_layers: int, num_experts: int, d_model: int, d_ff: int)
             "wo": draw(keys[3], bank + (d_ff, d_model), d_ff),
         },
     }
+    if select_bias:
+        out["bias"] = SELECT_BIAS_STD * jax.random.normal(keys[4], bank, jnp.float32)
+    if shared:
+        out["shared"] = {"wg": draw(keys[5], (n_layers, d_model, shared), d_model),
+                         "wi": draw(keys[6], (n_layers, d_model, shared), d_model),
+                         "wo": draw(keys[7], (n_layers, shared, d_model), shared)}
+    return out
 
 
-def dropless_logical_axes():
+def dropless_logical_axes(shared: bool = False, select_bias: bool = False):
     ex = experts_logical_axes()
     ex["wg"] = ex["wi"]
-    return {"gate": (None, "embed", None), "experts": {k: (None,) + v for k, v in ex.items()}}
+    axes = {"gate": (None, "embed", None), "experts": {k: (None,) + v for k, v in ex.items()}}
+    if select_bias:
+        axes["bias"] = (None, None)
+    if shared:
+        axes["shared"] = {"wg": (None, "embed", "mlp"), "wi": (None, "embed", "mlp"),
+                          "wo": (None, "mlp", "embed")}
+    return axes
 
 
-def route(x, gate_w, top_k: int, renormalize: bool):
-    """x [T, M] -> (weights [T, k] float32, experts [T, k] int32, probs [T, E]
+def route(x, gate_w, top_k: int, renormalize: bool, *, score_fn: str = "softmax",
+          select_bias=None, scale: float = 1.0):
+    """x [T, M] -> (weights [T, k] float32, experts [T, k] int32, scores [T, E]
     float32). The logits are a float32 product at full precision whatever the
     compute dtype: the gap between the k-th and the (k+1)-th expert is a few
-    hundredths of the logits' spread, and a bf16 product would move it."""
+    hundredths of the logits' spread, and a bf16 product would move it.
+    ``select_bias`` [E] is added to the scores for the choice alone: the weights
+    are the chosen experts' own scores, renormalised (over their sum + 1e-20, as
+    the published code has it) and scaled as asked."""
     logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = lax.top_k(probs, top_k)
+    scores = jax.nn.sigmoid(logits) if score_fn == "sigmoid" else jax.nn.softmax(logits, axis=-1)
+    if select_bias is None:
+        weights, experts = lax.top_k(scores, top_k)
+    else:
+        _, experts = lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if renormalize:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return weights, experts.astype(jnp.int32), probs
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        weights = weights * scale
+    return weights, experts.astype(jnp.int32), scores
 
 
 def load_balance_loss(probs, experts):
@@ -125,13 +169,23 @@ def experts_dense(bank, x, weights, experts):
     return jnp.einsum("etm,te->tm", ys.astype(jnp.float32), mix).astype(x.dtype)
 
 
+def shared_expert(w, x):
+    """The gated MLP every row of x [T, M] goes through."""
+    w = {name: leaf.astype(x.dtype) for name, leaf in w.items()}
+    return _gated(x @ w["wg"], x @ w["wi"]) @ w["wo"]
+
+
 def moe_ffn_dropless(cfg, moe_p, h):
     """h [B, S, M] -> (out [B, S, M], load-balancing loss, experts [B, S, k])."""
     B, S, M = h.shape
     x = h.reshape(B * S, M)
-    weights, experts, probs = route(x, moe_p["gate"], cfg.moe_top_k, cfg.moe_norm_topk_prob)
+    weights, experts, probs = route(
+        x, moe_p["gate"], cfg.moe_top_k, cfg.moe_norm_topk_prob, score_fn=cfg.moe_score_fn,
+        select_bias=moe_p.get("bias"), scale=cfg.moe_routed_scale)
     form = experts_dense if B * S <= DENSE_ROWS else experts_sorted
     out = form(moe_p["experts"], x, weights, experts)
+    if "shared" in moe_p:
+        out = out + shared_expert(moe_p["shared"], x)
     return (out.reshape(B, S, M), load_balance_loss(probs, experts),
             experts.reshape(B, S, cfg.moe_top_k))
 

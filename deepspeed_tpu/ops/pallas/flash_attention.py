@@ -209,6 +209,7 @@ def _fwd_kernel(
 def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
                    block_k, interpret):
     BH, Sq, D = q.shape
+    Dv = v.shape[2]  # a value head may be narrower than a q/k head (latent attention)
     Sk = k.shape[1]
     num_k = Sk // block_k
     grid = (BH, Sq // block_q, num_k)
@@ -218,7 +219,7 @@ def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
     in_specs = [
         _vmem_spec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
         _vmem_spec((1, block_k, D), lambda bh, qi, kj: (bh, kj, 0)),
-        _vmem_spec((1, block_k, D), lambda bh, qi, kj: (bh, kj, 0)),
+        _vmem_spec((1, block_k, Dv), lambda bh, qi, kj: (bh, kj, 0)),
     ]
     operands = [q, k, v]
     if slopes_bh is not None:
@@ -233,17 +234,17 @@ def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            _vmem_spec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
+            _vmem_spec((1, block_q, Dv), lambda bh, qi, kj: (bh, qi, 0)),
             _vmem_spec((1, block_q, LANES), lambda bh, qi, kj: (bh, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, Sq, LANES), jnp.float32),
         ],
         scratch_shapes=[
             _scratch((block_q, LANES)),   # running row-max m
             _scratch((block_q, LANES)),   # running row-sum l
-            _scratch((block_q, D)),       # output accumulator
+            _scratch((block_q, Dv)),      # output accumulator
         ],
         compiler_params=_compiler_params(len(grid)),
         interpret=interpret,
@@ -354,6 +355,11 @@ def _bwd_dq_kernel(
 
 def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
     q, k, v, slopes_bh, w_arr, out, lse = res
+    if v.shape[2] != q.shape[2]:
+        raise NotImplementedError(
+            f"flash_attention backward: value heads of {v.shape[2]} beside q/k heads of "
+            f"{q.shape[2]} — the backward kernels take one head size; differentiate through "
+            "attn_impl='xla'")
     lse = jnp.broadcast_to(lse[..., None], lse.shape + (LANES,))  # re-tile lanes
     BH, Sq, D = q.shape
     Sk = k.shape[1]
@@ -478,7 +484,11 @@ def flash_attention(
     alibi_slopes=None,
     window=None,
 ):
-    """Fused blockwise attention. q/k/v: [B, S, H, D] -> [B, S, H, D].
+    """Fused blockwise attention. q/k/v: [B, S, H, D] -> [B, S, H, D]. The
+    forward kernel takes value heads of a width of their own (v [B, S, H, Dv]
+    -> [B, S, H, Dv]: latent attention's 192-wide q/k beside 128-wide v); the
+    softmax scale defaults to 1/sqrt(D) of the q/k heads as given. The backward
+    kernels refuse unequal widths by name.
 
     Structured biases are FUSED (computed from block positions in-kernel, no
     HBM bias tensor — the reference threads alibi through its inference
@@ -546,7 +556,7 @@ def flash_attention(
         to_bhsd(q), to_bhsd(k), to_bhsd(v), slopes_bh, w_arr, sm_scale, causal,
         block_q, block_k, interpret
     )
-    out = out.reshape(B, H, Sq_p, D).transpose(0, 2, 1, 3)
+    out = out.reshape(B, H, Sq_p, v.shape[3]).transpose(0, 2, 1, 3)
     if pad_q:
         out = out[:, :Sq]
     return out

@@ -115,7 +115,7 @@ class DeepSpeedTransformerLayer:
                 bias = bias[:, None]
         (out, _), _ = mt._block(
             cfg, (hidden_states.astype(cfg.dtype), None), params, 0, positions=None, rng=rng,
-            attend=lambda q, k, v, state, l: (
+            attend=lambda q, k, v, state, l, lp: (
                 mt.xla_attention(q, k, v, bias=bias, causal=False), state),
             ffn=partial(mt._dense_ffn, cfg))
         return (out,) if self.config.return_tuple else out
@@ -195,9 +195,9 @@ class DeepSpeedTransformerInference:
         B, T = x.shape[:2]
         # the model's cache attention over a one-layer stack
         positions, attend = mt._cache_attention(cfg, B, T, cache["k"].shape[1], pos)
-        (x, (k_stack, v_stack)), _ = mt._block(
-            cfg, (x, (cache["k"][None], cache["v"][None])), params, 0, positions=positions,
-            attend=attend, ffn=partial(mt._dense_ffn, cfg))
-        return x, {"k": k_stack[0], "v": v_stack[0]}
+        (x, stacks), _ = mt._block(
+            cfg, (x, {"k": cache["k"][None], "v": cache["v"][None]}), params, 0,
+            positions=positions, attend=attend, ffn=partial(mt._dense_ffn, cfg))
+        return x, {"k": stacks["k"][0], "v": stacks["v"][0]}
 
     __call__ = apply

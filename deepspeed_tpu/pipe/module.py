@@ -229,6 +229,11 @@ class PipelinedTransformer(Model):
             raise NotImplementedError(
                 "local-attention layers under pipeline parallelism are not wired up: a stage "
                 "sees its own layer indices, not the model's")
+        if cfg.moe_first_dense:
+            raise NotImplementedError(
+                "moe_first_dense under pipeline parallelism is not wired up: the leading dense "
+                "layers' stacks (params['dense_ffn']) belong to the first stage alone, and "
+                "every stage is handed an equal slice of 'layers' and 'moe'")
         super().__init__(cfg, loss_fn=None)
         self.num_stages = num_stages
         self.num_micro_batches = num_micro_batches

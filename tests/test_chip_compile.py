@@ -154,12 +154,14 @@ def _bare_slot_worker(cfg, n, Smax, one_chip):
     params = jax.tree.map(
         lambda x: sds(x.shape, x.dtype),
         jax.eval_shape(lambda r: hold_for_compute(cfg, Model(cfg).init(r)), jax.random.PRNGKey(0)))
-    kv = sds((cfg.num_layers, n, Smax, cfg.num_heads, cfg.head_dim), jnp.bfloat16)
+    cache = jax.tree.map(  # the tree the model's attention caches: K/V per head, or a latent
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: tfm.init_cache(cfg, n, Smax, dtype=jnp.bfloat16)))
     worker = SlotWorker.__new__(SlotWorker)
     worker.cfg, worker.Smax = cfg, Smax
     tfm._ACTIVE_MESH[0] = None  # the engine's own (one chip) in a process; an earlier test's here
-    worker._cache_shardings = {"k": one_chip, "v": one_chip}
-    return worker, params, {"k": kv, "v": kv}, sds
+    worker._cache_shardings = {name: one_chip for name in cache}
+    return worker, params, cache, sds
 
 
 def _compile_decode(worker, params, cache, n, sds):
@@ -291,6 +293,69 @@ def test_long_prefill_attends_through_the_flash_kernel(family, L, v5e, no_persis
     assert "flash_fwd" not in dense.as_text() and re.search(scores, dense.as_text())
     saved = dense.memory_analysis().temp_size_in_bytes - flash.memory_analysis().temp_size_in_bytes
     assert saved > (0 if family == "olmoe_dropless" else 4 * cfg.num_heads * Smax * Smax), saved
+
+
+def _kanana_worker(L, n, Smax, v5e):
+    """kanana-2-30b-a3b's block at its published widths (``chipbench/configs/
+    kanana-2-30b-a3b-L7.json``'s ``program``), ``L`` layers (the leading dense one
+    and ``L - 1`` routed), 16 of its 128 experts and a vocabulary of 1024."""
+    import json
+    from deepspeed_tpu.models import transformer as tfm
+
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__), "chipbench", "configs",
+                           "kanana-2-30b-a3b-L7.json")) as f:
+        program = json.load(f)["program"]
+    cfg = tfm.TransformerConfig(dtype=jnp.bfloat16, **{
+        **program, "num_layers": L, "num_experts": 16, "vocab_size": 1024, "max_seq_len": Smax})
+    return (cfg, *_bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0])))
+
+
+def test_latent_decode_program_keeps_the_latent_cache_in_place(v5e, no_persistent_cache, as_tpu):
+    """``SlotWorker``'s decode step of the latent-attention block (the cell's 7 layers,
+    the cell's 24 slots x 8192): the cache tree is the 512-wide latent and the 64-wide
+    rotary key, the layer loop's carry, donated in and aliased out, never copied
+    whole; the step attends in the absorbed form, so nothing per-head is made
+    of the cache: no value has the cache's rows beside the 32 heads' 128 / 192 /
+    256 widths (a step that expanded ``c W_kv_b`` over the cached tokens would
+    hold ``[24, 8192, 32, 256]``), and the temporaries are the step's float32
+    scores, about one layer's latent in all."""
+    L, n, Smax = 7, 24, 8192
+    cfg, worker, params, cache, sds = _kanana_worker(L, n, Smax, v5e)
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (L, n, Smax, 1, 64), "v": (L, n, Smax, 1, 512)}
+    compiled = _compile_decode(worker, params, cache, n, sds)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    for width in (64, 512):
+        whole = rf"bf16\[{L},{n},{Smax},(?:1,)?{width}\]"
+        copies = re.findall(rf"^\s*%?[\w.-]+ = {whole}\S* copy\(", text, re.M)
+        assert not copies, f"the decode step copies the whole latent cache: {copies}"
+    expanded = re.findall(rf"\w+\[(?:1,)?{n},{Smax},{cfg.num_heads},(?:128|192|256|320)\]", text)
+    assert not expanded, f"the decode step expands the cached latent to heads: {expanded[:3]}"
+    ma = compiled.memory_analysis()
+    layer_bytes = n * Smax * (512 + 64) * 2
+    assert ma.alias_size_in_bytes >= L * layer_bytes
+    # score-sized float32 temporaries (24 x 32 x 8192), not a copy of the stack nor of a layer
+    assert not re.findall(rf"= bf16\[(?:1,)?{n},{Smax},(?:1,)?512\]\S* (?:copy|transpose)\(", text)
+    assert ma.temp_size_in_bytes < 1.25 * layer_bytes, (ma.temp_size_in_bytes, layer_bytes)
+
+
+def test_latent_prefill_attends_through_the_flash_kernel(v5e, no_persistent_cache, as_tpu):
+    """The 1024-row prefill of the same block at 32 heads: its dense scores would
+    be 128 MiB, so the expanded form goes through the flash forward kernel at q/k
+    heads of 192 and value heads of 128: ONE ``flash_fwd`` call in each of the two
+    layer loops (the leading dense layer's and the routed layers'), and no
+    ``f32[32, 1024, 1024]`` value. The 512-row bucket attends densely."""
+    L, n, Smax = 3, 8, 2048
+    cfg, worker, params, cache, sds = _kanana_worker(L, n, Smax, v5e)
+    text = _compile_prefill(worker, params, cache, 1024, sds).as_text()
+    calls = re.findall(r'^\s*%?flash_fwd[\w.]* = .*custom_call_target="tpu_custom_call"', text, re.M)
+    assert len(calls) == 2
+    # the kernel's output is as wide as a value head, its q and k operands as a q/k head
+    assert all(f"bf16[{cfg.num_heads},1024,128]" in c for c in calls), calls
+    assert f"bf16[{cfg.num_heads},1024,192]" in text and f"[{cfg.num_heads},1024,256]" not in text
+    assert not re.search(rf"f32\[(?:1,)?{cfg.num_heads},1024,1024\]", text)
+    assert "flash_fwd" not in _compile_prefill(worker, params, cache, 512, sds).as_text()
 
 
 @pytest.mark.parametrize("rows,grouped", [(16, False), (2048, True)], ids=["decode", "prefill"])

@@ -19,11 +19,13 @@ from deepspeed_tpu.models import transformer as tfm
 
 CONFIGS = sorted(os.path.basename(p)[:-5]
                  for p in glob.glob(os.path.join(parity.HERE, "configs", "*.json")))
-ROUTED = [c for c in CONFIGS if c.startswith("olmoe")]
+ROUTED = [c for c in CONFIGS if c.startswith(("olmoe", "kanana"))]
 # read in float32 by the forward pass; everything else floating is cast to cfg.dtype
 FLOAT32_LEAVES = {"ln1_scale", "ln1_bias", "ln2_scale", "ln2_bias", "lnf_scale", "lnf_bias",
                   "emb_ln_scale", "emb_ln_bias", "q_norm_scale", "k_norm_scale",
-                  "lm_head_bias", "gate"}
+                  "lm_head_bias", "gate",
+                  # latent attention's norm over the latent; a router's selection bias (PR 31)
+                  "kv_norm_scale", "bias"}
 PROMPT, STEPS = 24, 3
 
 

@@ -212,17 +212,21 @@ def test_live_row_counters_ignore_padding_and_idle_slots(cfg, params):
     for pad in (0, 5):
         padded = np.full((1, 64), pad, np.int32)
         padded[0, :45] = _tokens(cfg, (45,), 1)
-        _, _, (load,) = serving._forward(cfg, params, padded, tfm.init_cache(cfg, 1, 64), 0,
-                                         jnp.arange(64)[None, :] < 45, last_index=44)
+        _, _, (load, chosen) = serving._forward(cfg, params, padded, tfm.init_cache(cfg, 1, 64),
+                                                0, jnp.arange(64)[None, :] < 45, last_index=44)
         loads.append(np.asarray(load))
         assert load.shape == (L, E) and np.all(load.sum(axis=1) == 45 * k)
+        # beside the load, the choices it was counted from: every row's, padding included
+        assert chosen.shape == (L, 1, 64, k)
+        assert np.array_equal(load, [np.bincount(np.asarray(c)[0, :45].ravel(), minlength=E)
+                                     for c in chosen])
     assert np.array_equal(*loads)
     # decode: 2 active slots of 4
     active = jnp.asarray([True, False, True, False])
     pos = jnp.asarray([3, 0, 9, 0], jnp.int32)
-    _, _, (load,) = serving._forward(cfg, params, _tokens(cfg, (4, 1), 4),
-                                     tfm.init_cache(cfg, 4, 128), pos, active[:, None],
-                                     write_pos=pos)
+    _, _, (load, _) = serving._forward(cfg, params, _tokens(cfg, (4, 1), 4),
+                                       tfm.init_cache(cfg, 4, 128), pos, active[:, None],
+                                       write_pos=pos)
     assert np.all(np.asarray(load).sum(axis=1) == 2 * k)
     # a dense model's programs gain nothing
     dense = tfm.TransformerConfig(vocab_size=64, num_layers=1, num_heads=2, hidden_size=16)

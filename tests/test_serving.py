@@ -390,6 +390,15 @@ _AGREE_CASES = {
 _AGREE_CASES["post_norm-dense-layer"] = dict(norm_style="post")
 # two whole periods, then one trailing dense layer (L % moe_every != 0)
 _AGREE_CASES["sequential-gshard_every_2nd_of_5-layer"] = dict(_AGREE_FFN["gshard_every_2nd"], num_layers=5)
+# latent attention (cache: a 16-wide latent + an 8-wide rotary key, narrower than ONE head) over a
+# leading dense gated layer and three routed ones with a sigmoid router, a selection bias, a
+# scale and a shared expert: the prefill below is shorter than its cache, so it and the decode
+# step attend in the absorbed form, and ``apply`` in the expanded one
+_AGREE_CASES["sequential-latent_lead1_sigmoid_shared-rms"] = dict(
+    _AGREE_FFN["dropless_top2"], norm_kind="rms", use_bias=False, rotary_interleaved=True,
+    qk_head_dim=24, v_head_dim=12, kv_lora_rank=16, qk_rope_head_dim=8, moe_first_dense=1,
+    dense_intermediate_size=48, moe_score_fn="sigmoid", moe_select_bias=True,
+    moe_norm_topk_prob=True, moe_routed_scale=2.5, moe_shared_size=24)
 
 
 def _agree_model(fields):
