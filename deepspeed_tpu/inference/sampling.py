@@ -126,21 +126,52 @@ def _filter_logits_vector(logits, t, k, p):
     return jnp.where((p < 1.0)[:, None] & (scaled < pth), NEG_INF, scaled)
 
 
+SAMPLER_FORMS = ("argmax", "draw", "filter")
+
+
+def sampler_form(temperature, top_k, top_p, vocab: int):
+    """Which of ``SAMPLER_FORMS`` the rows ``temperature`` / ``top_k`` /
+    ``top_p`` [B] ask ``sample_logits_vector`` for, as its index: 0 no row
+    samples, 1 some row samples and none of those filters, 2 a sampling row
+    sets a top-k or a nucleus. Written once in what numpy arrays and traced
+    ones both do, so the compiled programs branch on it and ``SlotWorker``
+    names it on their spans from the arrays it hands them (a greedy row's
+    filters count for nothing: its token is the arg-max either way)."""
+    samples = temperature > 0.0
+    filters = samples & (((top_k > 0) & (top_k < vocab)) | (top_p < 1.0))
+    return samples.any().astype("int32") + filters.any().astype("int32")
+
+
 def sample_logits_vector(logits, rng, temperature, top_k, top_p):
     """Per-slot sampling: logits [B, V] with PER-ROW sampler state as arrays
     (temperature/top_k/top_p all [B]) -> token ids [B] int32.
 
     Rows with temperature <= 0 take the greedy argmax. Every sampler knob is
     an array operand, so admitting a request with new sampling params reuses
-    the already-compiled decode step (the ServingEngine contract)."""
+    the already-compiled decode step (the ServingEngine contract).
+
+    The program does the work its operands ask for (``sampler_form``, one
+    conditional): the sort over the vocabulary runs only when a sampling row
+    filters, the divide and the draw only when a row samples. The three forms
+    are one function with its dead work left out: ``_filter_logits_vector``
+    hands back ``scaled`` untouched for a row with no filter, and a row's
+    draw reads its own logits and noise alone, so the tokens are the same
+    bits whichever form runs."""
     logits = logits.astype(jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     t = jnp.asarray(temperature, jnp.float32)
     k = jnp.asarray(top_k, jnp.int32)
     p = jnp.asarray(top_p, jnp.float32)
-    scaled = _filter_logits_vector(logits, t, k, p)
-    drawn = jax.random.categorical(rng, scaled, axis=-1)
-    return jnp.where(t <= 0.0, greedy, drawn).astype(jnp.int32)
+
+    def drawn_from(scaled):
+        drawn = jax.random.categorical(rng, scaled, axis=-1)
+        return jnp.where(t <= 0.0, greedy, drawn).astype(jnp.int32)
+
+    return jax.lax.switch(
+        sampler_form(t, k, p, logits.shape[-1]),
+        (lambda: greedy,
+         lambda: drawn_from(logits / jnp.maximum(t, 1e-6)[:, None]),
+         lambda: drawn_from(_filter_logits_vector(logits, t, k, p))))
 
 
 def verify_logits_vector(logits, draft, rng, temperature, top_k, top_p):

@@ -121,7 +121,8 @@ from ..utils.donation import donated_jit
 from ..utils.logging import log_dist
 from .engine import InferenceEngine
 from .prefix_cache import PrefixIndex
-from .sampling import sample_logits_vector, verify_logits_vector
+from .sampling import (SAMPLER_FORMS, sample_logits_vector, sampler_form,
+                       verify_logits_vector)
 from .speculation import make_drafter
 
 
@@ -623,6 +624,17 @@ class SlotWorker:
         sp.annotate(compiled=compiled)
         return compiled
 
+    def _sampler_rows(self, sp, temperature, top_k, top_p):
+        """The sampler operands of a call as its program gets them ([rows]
+        float32 / int32 / float32 host arrays), with the form the program's
+        sampler takes on them noted on the call's span as ``sampler``:
+        ``sampler_form``, the rule the program itself branches on."""
+        rows = (np.atleast_1d(np.asarray(temperature, np.float32)),
+                np.atleast_1d(np.asarray(top_k, np.int32)),
+                np.atleast_1d(np.asarray(top_p, np.float32)))
+        sp.annotate(sampler=SAMPLER_FORMS[int(sampler_form(*rows, self.cfg.vocab_size))])
+        return rows
+
     # Every dispatch below is ONE span with two children: ``dispatch`` (from
     # entry until the jitted call has returned: key split, operand conversion
     # and upload, enqueue) and ``fetch`` (``jax.device_get`` of the tokens:
@@ -639,11 +651,13 @@ class SlotWorker:
         tm = self.telemetry
         # ``cached_tokens``: the cache positions the step attends to, summed
         # over its live rows (row at ``pos`` reads [0, pos]); ``attn``: the form
-        # the program was traced with (``prefill`` has flash / dense)
+        # the program was traced with (``prefill`` has flash / dense);
+        # ``sampler`` (below): the form its sampler takes on these rows
         with tm.span("decode", n_active=int(np.count_nonzero(active)),
                      cached_tokens=int(np.sum((np.asarray(pos) + 1)[np.asarray(active, bool)])),
                      attn=tfm.cache_step_form(self.cfg)) as sp:
             with tm.span("dispatch"):
+                rows = self._sampler_rows(sp, temp, top_k, top_p)
                 if self._decode is None:
                     wd = tm.watchdog
                     self._decode = wd.watch(
@@ -655,8 +669,7 @@ class SlotWorker:
                 # arrays
                 self._cache, *out = self._decode(
                     self.params, self._cache, last_tok, pos,
-                    np.asarray(wpos, np.int32), active, k, temp, top_k, top_p,
-                )
+                    np.asarray(wpos, np.int32), active, k, *rows)
             self._decode_steps += 1
             compiled = self._compiled(sp, self._decode)
             out, chosen = self._host_bound(out)
@@ -740,6 +753,7 @@ class SlotWorker:
         with tm.span("prefill", uid=uid, slot=slot, bucket=bucket, true_len=true_len,
                      attn=tfm.cache_attention_form(self.cfg.num_heads, 1, bucket, bucket)) as sp:
             with tm.span("dispatch"):
+                rows = self._sampler_rows(sp, temperature, top_k, top_p)
                 if bucket not in self._prefills:
                     # each bucket length is its own compile-stable program:
                     # one compile at first use, never again
@@ -751,11 +765,7 @@ class SlotWorker:
                 self._rng, k = jax.random.split(self._rng)
                 self._cache, *out = prog(
                     self.params, self._cache, jnp.asarray(padded),
-                    jnp.int32(slot), jnp.int32(true_len), k,
-                    jnp.asarray([temperature], jnp.float32),
-                    jnp.asarray([top_k], jnp.int32),
-                    jnp.asarray([top_p], jnp.float32),
-                )
+                    jnp.int32(slot), jnp.int32(true_len), k, *rows)
             compiled = self._compiled(sp, prog)
             out, chosen = self._host_bound(out)
             with tm.span("fetch"):
@@ -780,15 +790,12 @@ class SlotWorker:
                      cached_tokens=int(start) + int(live),
                      attn=tfm.cache_step_form(self.cfg)) as sp:
             with tm.span("dispatch"):
+                rows = self._sampler_rows(sp, temperature, top_k, top_p)
                 prog = self._chunk_prog(width)
                 self._rng, k = jax.random.split(self._rng)
                 self._cache, *out = prog(
                     self.params, self._cache, jnp.asarray(toks),
-                    jnp.int32(slot), jnp.int32(start), jnp.int32(live), k,
-                    jnp.asarray([temperature], jnp.float32),
-                    jnp.asarray([top_k], jnp.int32),
-                    jnp.asarray([top_p], jnp.float32),
-                )
+                    jnp.int32(slot), jnp.int32(start), jnp.int32(live), k, *rows)
             compiled = self._compiled(sp, prog)
             if fetch:
                 out, chosen = self._host_bound(out)

@@ -295,6 +295,64 @@ def test_long_prefill_attends_through_the_flash_kernel(family, L, v5e, no_persis
     assert saved > (0 if family == "olmoe_dropless" else 4 * cfg.num_heads * Smax * Smax), saved
 
 
+def _computations(text):
+    """An optimised HLO module's text as {computation: (its lines, the
+    computations it calls outside a conditional's branches, those it calls as
+    a conditional's branches)}, and the entry computation's name."""
+    comps, entry, name = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(2)
+            comps[name] = ([], set(), set())
+            entry = name if head.group(1) else entry
+        elif name is not None and line != "}":
+            body, calls, branches = comps[name]
+            body.append(line)
+            calls.update(re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.-]+)", line))
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                branches.update(c.strip().lstrip("%") for c in group.split(","))
+            branches.update(re.findall(r"(?:true|false)_computation=%?([\w.-]+)", line))
+    return comps, entry
+
+
+def _reach(comps, roots, through_branches):
+    seen, todo = set(), list(roots)
+    while todo:
+        c = todo.pop()
+        if c not in seen and c in comps:
+            seen.add(c)
+            todo += comps[c][1] | (comps[c][2] if through_branches else set())
+    return seen
+
+
+@pytest.mark.parametrize("family", ["bloom_dense_alibi", "pythia_pallas_kernel"])
+def test_sampler_sort_stays_behind_the_conditional(family, v5e, no_persistent_cache, as_tpu):
+    """``SlotWorker``'s decode step and a prefill at the widths of the two
+    ``gpt_family`` configurations (4 layers, 8 slots x 512, vocabulary 1024),
+    compiled for the chip: the sampler is ONE three-branch ``conditional`` of
+    the entry computation, the ``[rows, vocab]`` sort is reached only through
+    its branches, and what runs whatever the operands say (the entry
+    computation and all it calls outside those branches) holds no such sort.
+    A compiler that flattened the conditional into a select would put the sort
+    back into every all-greedy step, and this would say so before a chip call."""
+    L, n, Smax, V = 4, 8, 512, 1024
+    cfg = _family_cfg(family, L, Smax)
+    worker, params, cache, sds = _bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0]))
+    for name, compiled, rows in (("decode", _compile_decode(worker, params, cache, n, sds), n),
+                                 ("prefill", _compile_prefill(worker, params, cache, Smax, sds), 1)):
+        comps, entry = _computations(compiled.as_text())
+        conds = [line for line in comps[entry][0] if " conditional(" in line]
+        assert len(conds) == 1 and len(comps[entry][2]) == 3, (name, conds)
+        sort = re.compile(rf"= \(?f32\[{rows},{V}\][^=]* sort\(")
+        sorts_in = lambda cs: [line.split(" = ")[0].strip() for c in cs for line in comps[c][0]
+                               if sort.search(line)]
+        always = _reach(comps, [entry], through_branches=False)
+        assert not sorts_in(always), (name, sorts_in(always))
+        behind = _reach(comps, comps[entry][2], through_branches=True)
+        assert sorts_in(behind), name
+
+
 def _kanana_worker(L, n, Smax, v5e):
     """kanana-2-30b-a3b's block at its published widths (``chipbench/configs/
     kanana-2-30b-a3b-L7.json``'s ``program``), ``L`` layers (the leading dense one

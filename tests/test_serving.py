@@ -188,6 +188,105 @@ def test_sampler_fused_filters_match_sequential():
             assert allowed[b, toks[b]], (b, toks[b])
 
 
+def _sampler_rows(mix, B):
+    """One operand mix of ``sample_logits_vector`` at ``B`` rows: (temperature,
+    top_k, top_p) and the form the mix asks for. At one row the mixes that
+    need a second row fall back to what their first row asks for."""
+    t, k, p = np.zeros(B, np.float32), np.zeros(B, np.int32), np.ones(B, np.float32)
+    ramp = np.linspace(0.6, 1.4, B).astype(np.float32)
+    if mix == "all_greedy":
+        form = "argmax"
+    elif mix == "one_sampled_row_no_filter":
+        t[-1], form = 0.8, "draw"
+    elif mix == "temperature_only":
+        t[:], form = ramp, "draw"
+        k[0], p[-1] = 2 ** 20, 1.5  # a top-k of the whole row and a nucleus over 1 filter nothing
+    elif mix == "top_k_only":
+        t[:], form = ramp, "filter"
+        k[::2] = np.arange(B)[::2] * 7 + 3
+    elif mix == "top_p_only":
+        t[:], form = ramp, "filter"
+        p[::2] = np.linspace(0.0, 0.95, B)[::2]  # 0: the nucleus of one token
+    elif mix == "top_k_and_top_p":
+        t[:], form = ramp, "filter"
+        k[:] = np.arange(B) % 3 * 20
+        p[:] = np.linspace(0.3, 1.0, B)
+    elif mix == "greedy_row_filters_beside_sampled_rows":
+        t[1:], form = ramp[1:], "draw" if B > 1 else "argmax"
+        k[0], p[0] = 5, 0.5  # asked of a row whose token is the arg-max either way
+    return (t, k, p), form
+
+
+@pytest.mark.parametrize("B,V", [(8, 1000), (1, 50_304)])
+@pytest.mark.parametrize("seed", [0, 11])
+@pytest.mark.parametrize("mix", [
+    "all_greedy", "one_sampled_row_no_filter", "temperature_only", "top_k_only", "top_p_only",
+    "top_k_and_top_p", "greedy_row_filters_beside_sampled_rows"])
+def test_sampler_forms_are_the_parents_bits(mix, seed, B, V):
+    """``sample_logits_vector`` leaves out the work no row asks for (the sort
+    unless a sampling row filters, the divide and the draw unless a row
+    samples) and returns, bit for bit, what the one formula it had before
+    returns: arg-max, filter, draw, select."""
+    from deepspeed_tpu.inference.sampling import (SAMPLER_FORMS, _filter_logits_vector,
+                                                  sample_logits_vector, sampler_form)
+
+    def parent(logits, rng, t, k, p):
+        logits = logits.astype(jnp.float32)
+        greedy = jnp.argmax(logits, axis=-1)
+        scaled = _filter_logits_vector(logits, t, k, p)
+        drawn = jax.random.categorical(rng, scaled, axis=-1)
+        return jnp.where(t <= 0.0, greedy, drawn).astype(jnp.int32)
+
+    rows, form = _sampler_rows(mix, B)
+    assert SAMPLER_FORMS[int(sampler_form(*rows, V))] == form
+    logits = 4.0 * jax.random.normal(jax.random.PRNGKey(seed), (B, V), jnp.float32)
+    key = jax.random.PRNGKey(1000 + seed)
+    got = jax.jit(sample_logits_vector)(logits, key, *rows)
+    want = jax.jit(parent)(logits, key, *rows)
+    assert got.dtype == want.dtype == jnp.int32 and got.shape == (B,)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if form != "argmax" and B > 1:  # the mix does draw: not the arg-max in every row
+        assert np.any(np.asarray(got) != np.asarray(jnp.argmax(logits, axis=-1)))
+
+
+def _sorts_and_argmaxes(jaxpr, under_cond=False):
+    """(the primitive's name, whether it sits in a branch of a ``cond``) for
+    every sort and arg-max of ``jaxpr`` and of the jaxprs nested in it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("sort", "argmax"):
+            found.append((eqn.primitive.name, under_cond))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _sorts_and_argmaxes(sub, under_cond or eqn.primitive.name == "cond")
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "chunk"])
+def test_serving_programs_sort_only_under_the_conditional(engine, program):
+    """The three programs that sample: the vocabulary-wide sort is in a branch
+    of a ``cond``, never beside it; the arg-max every form starts from is
+    outside."""
+    srv = ServingEngine(engine, n_slots=2, max_seq_len=128)
+    w, n = srv.worker, 2
+    rng = jax.random.PRNGKey(0)
+    vec = lambda dtype, rows: np.zeros((rows,), dtype)
+    sampler = lambda rows: (rng, vec(np.float32, rows), vec(np.int32, rows), vec(np.float32, rows))
+    i32 = np.int32(0)
+    if program == "decode":
+        fn, args = w._build_decode(), (vec(np.int32, n), vec(np.int32, n), vec(np.int32, n),
+                                       vec(np.bool_, n), *sampler(n))
+    elif program == "prefill":
+        fn, args = w._build_prefill(16), (np.zeros((1, 16), np.int32), i32, i32, *sampler(1))
+    else:
+        fn, args = w._build_chunk(16), (np.zeros((1, 16), np.int32), i32, i32, i32, *sampler(1))
+    found = _sorts_and_argmaxes(jax.make_jaxpr(fn)(w.params, w._cache, *args).jaxpr)
+    assert ("sort", True) in found and ("sort", False) not in found, found
+    assert ("argmax", False) in found
+
+
 def test_vector_samplers_match_scalar():
     """The per-row array samplers agree with the scalar-config ones row by
     row (the decode step's no-recompile path must not change semantics)."""

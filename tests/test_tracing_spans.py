@@ -319,6 +319,51 @@ def test_serving_spans(tiny_serving_engine, mode):
         assert sum(sp.attrs["slots"] > 0 for sp in drafts) == len(verifies)
 
 
+@pytest.mark.parametrize("mode", ["plain", "chunked"])
+def test_sampler_attribute_is_the_rule_the_program_branches_on(tiny_serving_engine, mode):
+    """``sampler`` on a decode / prefill / chunk span names the form the
+    call's program took: the arrays each call handed its program, given to
+    ``sampler_form`` TRACED (as ``sample_logits_vector`` evaluates it), name
+    the same form. The traffic makes all three occur: greedy requests, one
+    that draws, one that filters, one whose filters sit on a greedy row."""
+    import jax
+
+    from deepspeed_tpu.inference import ServingEngine
+    from deepspeed_tpu.inference.sampling import SAMPLER_FORMS, sampler_form
+    from deepspeed_tpu.inference.serving import Request
+
+    srv = ServingEngine(tiny_serving_engine, n_slots=2, max_seq_len=128,
+                        replica_id=f"sampler-{mode}", **SERVING[mode])
+    traced = jax.jit(sampler_form, static_argnums=3)
+    handed = {"decode": [], "prefill": [], "chunk": []}
+    for kind in handed:
+        call = getattr(srv.worker, kind)
+
+        def recording(*args, _call=call, _kind=kind, **kw):
+            # temperature, top_k, top_p close every signature (copied: decode's are live state)
+            handed[_kind].append(tuple(np.array(a, dt, ndmin=1) for a, dt in
+                                       zip(args[-3:], (np.float32, np.int32, np.float32))))
+            return _call(*args, **kw)
+
+        setattr(srv.worker, kind, recording)
+    rng = np.random.default_rng(5)
+    asks = [dict(), dict(temperature=0.9), dict(temperature=0.7, top_k=5, top_p=0.9),
+            dict(top_k=3, top_p=0.5), dict(temperature=1.1, top_p=0.8), dict()]
+    reqs = [Request(uid=i, prompt=rng.integers(0, 97, size=5 + 6 * i).astype(np.int32),
+                    max_new_tokens=3 + 2 * (i % 3), **ask) for i, ask in enumerate(asks)]
+    since = time.perf_counter()
+    assert all(r.ok for r in srv.serve(reqs).values())
+    spans = [sp for sp in tracing.spans(since) if sp.replica_id == srv.replica_id]
+    seen = {}
+    for kind, rows in handed.items():
+        got = [sp.attrs["sampler"] for sp in spans if sp.name == kind]
+        want = [SAMPLER_FORMS[int(traced(*row, 97))] for row in rows]
+        assert got == want, kind
+        seen[kind] = set(got)
+    assert seen["decode"] == set(SAMPLER_FORMS)
+    assert seen["chunk" if mode == "chunked" else "prefill"] == set(SAMPLER_FORMS)
+
+
 def test_worker_call_outside_step_has_a_short_path(tiny_serving_engine):
     from deepspeed_tpu.inference import ServingEngine
 
