@@ -185,9 +185,12 @@ class InferenceEngine:
                 repetition_penalty=rep_penalty,
             )
             cache = tfm.init_cache(cfg, B, Smax, dtype=cfg.dtype)
+            state = cache.pop(tfm.STATE, None)  # per-sequence leaves have no head axis to pin
             cache = jax.tree.map(
                 lambda x: jax.lax.with_sharding_constraint(x, cache_sharding), cache
             )
+            if state is not None:
+                cache[tfm.STATE] = state
             seen0 = (
                 update_seen(jnp.zeros((B, cfg.vocab_size), jnp.bool_), prompt)
                 if use_seen

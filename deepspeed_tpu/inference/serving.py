@@ -102,7 +102,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..models import transformer as tfm
 from ..moe.dropless import expert_load, load_summary
@@ -261,11 +261,12 @@ def _forward(cfg, params, toks, cache, pos, live, **kw):
     that brings the tokens and ``SlotWorker._note_load`` puts it on the span.
     Then the experts chosen themselves, int32 [routed layers, B, T, k], which
     the load was counted from: they stay on the device unless
-    ``SlotWorker.routing_log`` asks for them."""
+    ``SlotWorker.routing_log`` asks for them. The model is handed ``live`` too:
+    a state-space mixer's state may move on those rows alone."""
     if cfg.moe_routing != "dropless":
-        return (*tfm.apply_with_cache(cfg, params, toks, cache, pos, **kw), ())
+        return (*tfm.apply_with_cache(cfg, params, toks, cache, pos, live=live, **kw), ())
     logits, cache, chosen = tfm.apply_with_cache(
-        cfg, params, toks, cache, pos, return_routing=True, **kw)
+        cfg, params, toks, cache, pos, return_routing=True, live=live, **kw)
     return logits, cache, (expert_load(chosen, live, cfg.num_experts), chosen)
 
 
@@ -309,18 +310,33 @@ class SlotWorker:
         # TP axis where every leaf's heads divide (a latent is every head's:
         # it has one, and replicates)
         layout = tfm.cache_layout(self.cfg)
-        cache_heads = min(heads for heads, _ in layout.values())
+        per_token = {name: tail for name, tail in layout.items() if name != tfm.STATE}
+        cache_heads = min(heads for heads, _ in per_token.values())
         self.spec = kv_slot_cache_spec(self.mesh, self.n_slots, cache_heads)
         self._cache_sharding = NamedSharding(self.mesh, self.spec)
+
+        def shardings(token_sharding):
+            """The sharding tree of a cache: its per-token leaves as given; its
+            per-sequence leaves [L, slots, ...] (a state-space mixer's state) over
+            the slots' axis alone."""
+            tree = {name: token_sharding for name in per_token}
+            if tfm.STATE in layout:
+                per_seq = NamedSharding(self.mesh, PartitionSpec(*token_sharding.spec[:2]))
+                tree[tfm.STATE] = {name: per_seq for name in layout[tfm.STATE]}
+            return tree
+
         # every program pins the cache OUTPUT to this sharding too — an
         # inferred output sharding that differs from the input's would give
         # the next call a differently-sharded operand and silently recompile
-        self._cache_shardings = {name: self._cache_sharding for name in layout}
+        self._cache_shardings = shardings(self._cache_sharding)
         self._cache = jax.jit(
             partial(tfm.init_cache, self.cfg, self.n_slots, self.Smax,
                     dtype=self.cfg.dtype),
-            out_shardings=self._cache_sharding,
+            out_shardings=self._cache_shardings,
         )()
+        # what ONE decode step must read and write of per-sequence state, a live
+        # row: every layer's leaves, once each way (0 for a model without)
+        self.state_bytes_per_slot = self.cfg.num_layers * tfm.cache_state_bytes(self.cfg)
 
         # prefix pool: the slot cache's sibling — same [L, slots, len, H, Dh]
         # layout, holding cached prompt prefixes instead of live sequences
@@ -334,11 +350,11 @@ class SlotWorker:
                     f"slot cache length {self.Smax}")
             pool_spec = kv_prefix_pool_spec(self.mesh, prefix_cfg.n_slots, cache_heads)
             self._pool_sharding = NamedSharding(self.mesh, pool_spec)
-            self._pool_shardings = {name: self._pool_sharding for name in layout}
+            self._pool_shardings = shardings(self._pool_sharding)
             self._pool = jax.jit(
                 partial(tfm.init_cache, self.cfg, prefix_cfg.n_slots, self.pmax,
                         dtype=self.cfg.dtype),
-                out_shardings=self._pool_sharding,
+                out_shardings=self._pool_shardings,
             )()
 
         self._decode = None  # jitted lazily (params pytree shapes needed)
@@ -468,10 +484,16 @@ class SlotWorker:
         cfg = self.cfg
 
         def prefill(params, cache, prompt, slot, true_len, rng, temp, top_k, top_p):
-            # prompt [1, bucket] (padded tail masked out by causality: the
-            # live tokens never attend to it, and its KV is overwritten by
-            # decode steps as the sequence grows into those positions)
-            local = tfm.init_cache(cfg, 1, bucket, dtype=jax.tree.leaves(cache)[0].dtype)
+            # prompt [1, bucket]. ATTENTION needs no mask for the padded tail:
+            # causality hides it (the live tokens never attend to it, and its KV
+            # is overwritten by decode steps as the sequence grows into those
+            # positions). A RECURRENCE has no causality to hide behind: every
+            # padded row would advance the state decode then starts from, so a
+            # state-space mixer is handed the live-row mask (``_forward``) and
+            # the state written to the slot is the one after row true_len - 1.
+            # ``update_cache_slot`` overwrites the slot's per-sequence leaves
+            # whole: its previous request leaves nothing behind
+            local = tfm.init_cache(cfg, 1, bucket, dtype=tfm.cache_dtype(cache))
             logits, local, load = _forward(
                 cfg, params, prompt, local, 0, jnp.arange(bucket)[None, :] < true_len,
                 last_index=true_len - 1)
@@ -499,7 +521,11 @@ class SlotWorker:
             # positions >= the prompt length is overwritten by decode steps
             # before any query position can attend to it, and ``last_index``
             # projects only the live last token's logits.
-            local = tfm.slice_cache_slot(cache, slot, Smax)
+            # A state-space mixer's state comes out with the window, whole, and
+            # goes back whole: the chunk starts from what the last chunk left
+            # (from nothing at ``start`` 0, whatever the slot held) and moves
+            # on its live rows only.
+            local = tfm.fresh_cache_state(tfm.slice_cache_slot(cache, slot, Smax), start == 0)
             logits, local, load = _forward(
                 cfg, params, toks, local, jnp.reshape(start, (1,)),
                 jnp.arange(width)[None, :] < true_len, last_index=true_len - 1)
@@ -624,6 +650,26 @@ class SlotWorker:
         sp.annotate(compiled=compiled)
         return compiled
 
+    def _state_attrs(self, n_active: int) -> dict:
+        """What a decode span says of a state-space mixer's state (nothing for a
+        model without): ``state_rows``, the active rows whose state the step
+        advanced, and ``state_bytes``, the per-sequence bytes it had to read and
+        write for them (2 x rows x layers x (state + convolution tail))."""
+        if not self.state_bytes_per_slot:
+            return {}
+        return {"state_rows": n_active,
+                "state_bytes": 2 * n_active * self.state_bytes_per_slot}
+
+    def _scan_attrs(self, rows: int, live: int) -> dict:
+        """What a prefill or chunk span says of a state-space mixer's scan
+        (nothing for a model without): the chunks it ran (those of the bucket's
+        padding among them) and ``state_rows``, the live rows that moved the
+        state."""
+        if not self.state_bytes_per_slot:
+            return {}
+        return {"scan_chunks": -(-int(rows) // self.cfg.ssm_chunk_size),
+                "state_rows": int(live)}
+
     def _sampler_rows(self, sp, temperature, top_k, top_p):
         """The sampler operands of a call as its program gets them ([rows]
         float32 / int32 / float32 host arrays), with the form the program's
@@ -653,9 +699,13 @@ class SlotWorker:
         # over its live rows (row at ``pos`` reads [0, pos]); ``attn``: the form
         # the program was traced with (``prefill`` has flash / dense);
         # ``sampler`` (below): the form its sampler takes on these rows
-        with tm.span("decode", n_active=int(np.count_nonzero(active)),
+        # ``state_rows`` / ``state_bytes`` (a state-space mixer only): the active
+        # rows whose per-sequence state the step advanced, and the bytes of it
+        # the step had to read AND write
+        n_active = int(np.count_nonzero(active))
+        with tm.span("decode", n_active=n_active,
                      cached_tokens=int(np.sum((np.asarray(pos) + 1)[np.asarray(active, bool)])),
-                     attn=tfm.cache_step_form(self.cfg)) as sp:
+                     attn=tfm.cache_step_form(self.cfg), **self._state_attrs(n_active)) as sp:
             with tm.span("dispatch"):
                 rows = self._sampler_rows(sp, temp, top_k, top_p)
                 if self._decode is None:
@@ -751,7 +801,8 @@ class SlotWorker:
         # ``attn``: the form the bucket's program was traced with (its local
         # cache is the bucket long: ``_build_prefill``)
         with tm.span("prefill", uid=uid, slot=slot, bucket=bucket, true_len=true_len,
-                     attn=tfm.cache_attention_form(self.cfg.num_heads, 1, bucket, bucket)) as sp:
+                     attn=tfm.cache_attention_form(self.cfg.num_heads, 1, bucket, bucket),
+                     **self._scan_attrs(bucket, true_len)) as sp:
             with tm.span("dispatch"):
                 rows = self._sampler_rows(sp, temperature, top_k, top_p)
                 if bucket not in self._prefills:
@@ -788,7 +839,7 @@ class SlotWorker:
         tm = self.telemetry
         with tm.span("chunk", uid=uid, slot=slot, width=width, live=live, fetch=fetch,
                      cached_tokens=int(start) + int(live),
-                     attn=tfm.cache_step_form(self.cfg)) as sp:
+                     attn=tfm.cache_step_form(self.cfg), **self._scan_attrs(width, live)) as sp:
             with tm.span("dispatch"):
                 rows = self._sampler_rows(sp, temperature, top_k, top_p)
                 prog = self._chunk_prog(width)
@@ -832,11 +883,18 @@ class SlotWorker:
             self._pool, self._cache, jnp.int32(slot), jnp.int32(pool_slot))
         self.step_compiled |= bool(self._store.last_call_compiled)
 
+    def _refuse_state(self, what: str) -> None:
+        if self.state_bytes_per_slot:
+            raise NotImplementedError(
+                f"{what} with a state-space mixer's recurrent state: the wire form carries "
+                "windows of per-token K/V and no per-sequence state")
+
     def kv_export(self, width: int, slot: int, start: int):
         """Fetch one [start, start+width) KV window of ``slot`` to the host
         — the disaggregated handoff's wire unit. Pow2 ``width`` keeps the
         program family bounded (one program per width, slot/start traced).
         Returns host ``(k, v)`` arrays [L, 1, width, H, Dh]."""
+        self._refuse_state("kv_export")
         prog = self._kv_export_prog(width)
         kv = prog(self._cache, jnp.int32(slot), jnp.int32(start))
         self.step_compiled |= bool(prog.last_call_compiled)
@@ -850,6 +908,7 @@ class SlotWorker:
         window writes the same bytes), donation + pinned output sharding
         exactly like the chunk path, so the decode program's cache operand
         never drifts."""
+        self._refuse_state("kv_import")
         prog = self._kv_import_prog(width)
         self._cache = prog(
             self._cache,
@@ -884,16 +943,18 @@ class SlotWorker:
                             out_shardings=self._cache_shardings),
                 wd.unique_name("serving/fill_slot"), stable=True)
         self._cache = self._poison(
-            self._cache, jnp.int32(slot),
-            jnp.asarray(value, jax.tree.leaves(self._cache)[0].dtype))
+            self._cache, jnp.int32(slot), jnp.asarray(value, tfm.cache_dtype(self._cache)))
 
     def hbm_pools(self) -> dict:
         """Named device-memory pools this worker holds — the HBM ledger's
         rows (bytes from array metadata, no device sync)."""
+        state = self._cache.get(tfm.STATE, {})
         pools = {
             "params": tree_bytes(self.params),
-            "slot_kv_cache": tree_bytes(self._cache),
+            "slot_kv_cache": tree_bytes(self._cache) - tree_bytes(state),
         }
+        if state:  # a state-space mixer's per-sequence leaves: constant in the sequence
+            pools["slot_state"] = tree_bytes(state)
         if self._pool is not None:
             pools["prefix_pool"] = tree_bytes(self._pool)
         return pools
@@ -1020,6 +1081,12 @@ class ServingEngine:
                 f"serving role {self.role!r} with latent attention (kv_lora_rank > 0): the "
                 "prefill -> decode handoff's wire form (inference/rpc.py: raw or int8 "
                 "windows of per-head K/V) has not carried the latent cache tree yet")
+        recurrent = engine.cfg.ssm_state_size > 0
+        if self.role != "both" and recurrent:
+            raise NotImplementedError(
+                f"serving role {self.role!r} with a state-space mixer (ssm_state_size > 0): the "
+                "prefill -> decode handoff (kv_export / kv_import) carries windows of per-token "
+                "K/V and no per-sequence recurrent state")
         n_slots = n_slots if n_slots is not None else config.get("n_slots", 8)
         max_seq_len = max_seq_len if max_seq_len is not None else config.get(
             "max_seq_len", 0)
@@ -1088,6 +1155,16 @@ class ServingEngine:
         if isinstance(sp, dict):
             sp = SpeculationConfig(**sp)
         self.spec_cfg: SpeculationConfig = sp
+        if recurrent:
+            # each moves the cache by POSITION, and a recurrent state has none: a
+            # prefix's K/V can be copied but not the state after it (that takes a
+            # snapshot a prefix), a rejected draft cannot be rolled back out of a
+            # state already advanced (that takes a snapshot a verify step)
+            for what, on in (("prefix_cache", pc.enabled), ("speculation", sp.enabled)):
+                if on:
+                    raise NotImplementedError(
+                        f"{what} with a state-space mixer (ssm_state_size > 0) has no code: it "
+                        "would run on a stale recurrent state; serve with it off")
         # the drafter is constructed eagerly so a bad draft_source fails at
         # engine build, not on the first decode step (draft_model needs the
         # model's vocab size to build its host-resident scorer)
@@ -1168,6 +1245,9 @@ class ServingEngine:
         self.worker = SlotWorker(engine, self.telemetry, self.n_slots,
                                  self.budget, seed, pc)
         self.Smax = self.worker.Smax
+        if recurrent:  # beside the HBM ledger's ``slot_state`` row (``hbm_pools``)
+            self.telemetry.gauge("serving/slot_state_bytes").set(
+                self.worker.hbm_pools()["slot_state"])
 
         # host-side prefix index: the radix trie mapping prompt prefixes to
         # the worker's pool slots (scheduler state — the pool is device)
@@ -1264,7 +1344,10 @@ class ServingEngine:
             f"serving engine: {n} slots x {self.Smax} tokens, cache "
             f"{self.worker.hbm_pools()['slot_kv_cache'] / 1e6:.1f} MB at "
             f"{tfm.cache_bytes_per_token(self.cfg)} B a token a layer "
-            f"({tfm.cache_step_form(self.cfg)}), spec={self.worker.spec}" + (", " + ", ".join(feat) if feat else ""),
+            f"({tfm.cache_step_form(self.cfg)})"
+            + (f", recurrent state {self.worker.hbm_pools()['slot_state'] / 1e6:.1f} MB at "
+               f"{self.worker.state_bytes_per_slot} B a slot" if recurrent else "")
+            + f", spec={self.worker.spec}" + (", " + ", ".join(feat) if feat else ""),
             ranks=[0],
         )
 

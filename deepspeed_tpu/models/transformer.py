@@ -27,6 +27,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 Params = Any
@@ -34,6 +35,15 @@ Params = Any
 # Mesh handle for MoE sharding constraints inside traced code (set by
 # Model.set_mesh via the engine; [None] = no constraint, single-mesh apps only).
 _ACTIVE_MESH: list = [None]
+
+
+class _Stated(dict):
+    """A mapping a configuration states (``multipliers``), hashable by its items
+    so that the configuration stays what it was: a jit's static argument."""
+
+    def __hash__(self):
+        return hash(tuple(sorted((k, tuple(v) if isinstance(v, (list, tuple)) else v)
+                                 for k, v in self.items())))
 
 
 @dataclass(frozen=True)
@@ -54,8 +64,14 @@ class TransformerConfig:
     # RMSNorm with a learned scale over the whole query and the whole key
     # projection, before the head split and the rotary (OLMoE's q_norm / k_norm)
     qk_norm: bool = False
+    # Grouped-query attention: this many key / value heads (0: num_heads), query
+    # head i attending key / value head i // (num_heads // num_kv_heads); the
+    # cache holds the key / value heads, never the repeated ones
+    num_kv_heads: int = 0
     # Explicit head sizes: the width of a query / key head and of a value head
-    # (0: hidden_size // num_heads for both). Only latent attention has them yet.
+    # (0: hidden_size // num_heads for both). Plain attention takes ``qk_head_dim``
+    # for both (num_heads x qk_head_dim need not be hidden_size); two different
+    # widths and a rotary part are latent attention's.
     qk_head_dim: int = 0
     v_head_dim: int = 0
     # Latent attention (MLA, DeepSeek-V2 / V3; no query compression): the keys
@@ -66,6 +82,25 @@ class TransformerConfig:
     kv_lora_rank: int = 0
     qk_rope_head_dim: int = 0
     rotary_base: float = 10000.0
+    # A Mamba-2 state-space mixer beside the attention in EVERY layer, on the same
+    # normed input, both added to the residual before a sequential feed-forward
+    # (Falcon-H1; ``_ssm_mixer`` has the equations). ``ssm_state_size`` > 0 turns
+    # it on: ``ssm_heads`` heads of ``ssm_head_dim`` channels, each with a state of
+    # ``ssm_state_size``; B and C in ``ssm_groups`` groups of heads; a depthwise
+    # causal convolution of ``ssm_conv_kernel`` taps; many rows go through the
+    # chunked scan in chunks of ``ssm_chunk_size``. The slot cache holds, beside
+    # K/V, a float32 state and the convolution's tail per SEQUENCE (``cache_layout``).
+    ssm_state_size: int = 0
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_conv_kernel: int = 4
+    ssm_chunk_size: int = 128
+    # Maximal-update multipliers, as configuration data: constants the forward
+    # pass multiplies by at the places ``MULTIPLIERS`` lists (a place left out
+    # is 1). ``init`` draws each matrix whose output one scales at 1 / (sqrt(fan
+    # in) x the multiplier), so that a multiplier dropped from the program shows.
+    multipliers: Optional[dict] = None
     # GPT-Neo alternating local attention: window size + per-layer 0/1 flags
     # (1 = local); None = all-global
     local_attn_window: int = 0
@@ -74,9 +109,9 @@ class TransformerConfig:
     tie_embeddings: bool = True
     use_bias: bool = True
     final_ln: bool = True  # False: no final LayerNorm (BERT encoders)
-    # gelu | gelu_exact | relu | swiglu (down(silu(gate(x)) * up(x)): the gated
-    # experts of moe_routing="dropless", its shared expert and its leading dense
-    # layers; a model with no routed layer has no gated feed-forward yet)
+    # gelu | gelu_exact | relu | swiglu (down(silu(gate(x)) * up(x)), no biases:
+    # the feed-forward of a model with no routed layer, and the gated experts of
+    # moe_routing="dropless", its shared expert and its leading dense layers)
     activation: str = "gelu"
     embed_ln: bool = False  # LayerNorm after embedding (BLOOM)
     attn_impl: str = "xla"  # xla | flash | ring | sparse
@@ -186,6 +221,8 @@ class TransformerConfig:
     param_offload: bool = False
 
     def __post_init__(self):
+        if self.multipliers is not None:
+            object.__setattr__(self, "multipliers", _Stated(self.multipliers))
         _refuse_uncoded(self)
 
     @property
@@ -196,6 +233,28 @@ class TransformerConfig:
     @property
     def value_head_dim(self) -> int:
         return self.v_head_dim or self.head_dim
+
+    @property
+    def kv_heads(self) -> int:
+        """The key / value heads plain attention projects and caches."""
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def ssm_inner(self) -> int:
+        """The mixer's channels: heads x head width."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """The channels the mixer convolves: x, then B and C of every group."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
+
+    def multiplier(self, name: str):
+        """The constant the forward pass multiplies by at the place ``name``
+        (``MULTIPLIERS``): a float, or a tuple of floats; 1 where not stated."""
+        width = MULTIPLIERS[name]
+        value = (self.multipliers or {}).get(name, 1.0 if width == 1 else (1.0,) * width)
+        return float(value) if width == 1 else tuple(float(v) for v in value)
 
     @property
     def dense_ffn_size(self) -> int:
@@ -242,10 +301,12 @@ def _refuse_uncoded(cfg: "TransformerConfig") -> None:
             raise ValueError(
                 "latent attention states its head sizes: qk_head_dim > qk_rope_head_dim > 0 "
                 f"(even) and v_head_dim > 0 (got {cfg.qk_head_dim}, {rope}, {cfg.v_head_dim})")
-    elif cfg.qk_head_dim or cfg.v_head_dim or cfg.qk_rope_head_dim:
+    elif cfg.qk_rope_head_dim or cfg.v_head_dim not in (0, cfg.qk_head_dim):
         raise NotImplementedError(
-            "qk_head_dim / v_head_dim / qk_rope_head_dim without latent attention "
-            "(kv_lora_rank > 0) have no code: a plain head is hidden_size // num_heads wide")
+            "v_head_dim other than qk_head_dim, or qk_rope_head_dim, without latent attention "
+            "(kv_lora_rank > 0) have no code: a plain head has ONE width, qk_head_dim or "
+            "hidden_size // num_heads")
+    _refuse_uncoded_heads_and_mixer(cfg)
     if cfg.moe_score_fn not in ("softmax", "sigmoid"):
         raise ValueError(f"moe_score_fn is 'softmax' or 'sigmoid', not {cfg.moe_score_fn!r}")
     forms = {"moe_score_fn": cfg.moe_score_fn != "softmax", "moe_select_bias": cfg.moe_select_bias,
@@ -265,36 +326,132 @@ def _refuse_uncoded(cfg: "TransformerConfig") -> None:
             f"{cfg.num_layers} layers, use_bias={cfg.use_bias})")
 
 
+# The places the forward pass multiplies by a stated constant
+# (``TransformerConfig.multipliers``), with how many constants each takes:
+# ``ssm_multipliers`` scale the mixer's projection segment by segment (z, x, B,
+# C, dt), ``mlp_multipliers`` the gate's pre-activation and the feed-forward's
+# output. Falcon-H1's names.
+MULTIPLIERS = {"embedding_multiplier": 1, "attention_in_multiplier": 1, "key_multiplier": 1,
+               "attention_out_multiplier": 1, "ssm_in_multiplier": 1, "ssm_multipliers": 5,
+               "ssm_out_multiplier": 1, "mlp_multipliers": 2, "lm_head_multiplier": 1}
+
+
+def _refuse_uncoded_heads_and_mixer(cfg: "TransformerConfig") -> None:
+    """``_refuse_uncoded`` for grouped-query heads, the state-space mixer and the
+    multipliers: what has no code is refused by name."""
+    grouped = cfg.num_kv_heads not in (0, cfg.num_heads)
+    if grouped and cfg.num_heads % cfg.num_kv_heads:
+        raise ValueError(f"num_kv_heads ({cfg.num_kv_heads}) does not divide num_heads "
+                         f"({cfg.num_heads})")
+    for name, value in (cfg.multipliers or {}).items():
+        if name not in MULTIPLIERS:
+            raise ValueError(f"multipliers: no place is called {name!r}; there are: "
+                             f"{', '.join(MULTIPLIERS)}")
+        if MULTIPLIERS[name] > 1 and len(value) != MULTIPLIERS[name]:
+            raise ValueError(f"multipliers[{name!r}] takes {MULTIPLIERS[name]} values")
+    mixer = cfg.ssm_state_size > 0
+    for feature, on, no_code in (
+        ("grouped-query heads (num_kv_heads < num_heads)", grouped, {
+            "latent attention (kv_lora_rank > 0: its heads share ONE latent)":
+                cfg.kv_lora_rank > 0,
+            f"attn_impl={cfg.attn_impl!r} (training attends through the XLA form: the flash, "
+            "ring and sparse kernels take as many K/V heads as query heads)":
+                cfg.attn_impl != "xla",
+            "decode_attn='kernel' (the Pallas decode kernel reads one K/V head per query "
+            "head; state decode_attn='xla')": cfg.decode_attn == "kernel",
+        }),
+        ("the state-space mixer (ssm_state_size > 0)", mixer, {
+            "causal=False (a recurrence runs one way)": not cfg.causal,
+            "norm_style='post'": cfg.norm_style == "post",
+            "parallel_residual (its feed-forward is sequential)": cfg.parallel_residual,
+            "local_attn_layers": cfg.local_attn_layers is not None,
+            "weight_bits": cfg.weight_bits != 0, "act_quant_bits": cfg.act_quant_bits != 0,
+            "use_bias (only its convolution has a bias)": cfg.use_bias,
+            "latent attention (kv_lora_rank > 0)": cfg.kv_lora_rank > 0,
+            "a routed feed-forward (moe_every > 0)": cfg.moe_every > 0,
+            f"attn_impl={cfg.attn_impl!r}": cfg.attn_impl != "xla",
+            "dropout or progressive layer drop":
+                bool(cfg.hidden_dropout or cfg.attn_dropout or cfg.pld_enabled),
+            "param_offload": cfg.param_offload,
+        }),
+    ):
+        for what, refused in no_code.items():
+            if on and refused:
+                raise NotImplementedError(f"{feature} with {what} has no code")
+    if mixer:
+        sizes = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_conv_kernel,
+                 cfg.ssm_chunk_size)
+        if min(sizes) < 1 or cfg.ssm_conv_kernel < 2 or cfg.ssm_heads % cfg.ssm_groups:
+            raise ValueError(
+                "the state-space mixer states its sizes: ssm_heads, ssm_head_dim, ssm_groups "
+                "(dividing ssm_heads), ssm_conv_kernel >= 2 and ssm_chunk_size, all > 0 "
+                f"(got {sizes})")
+    elif cfg.ssm_heads or cfg.ssm_head_dim:
+        raise ValueError("ssm_heads / ssm_head_dim without ssm_state_size > 0: no mixer to size")
+    stated = cfg.multipliers or {}
+    if not mixer and any(name.startswith("ssm_") for name in stated):
+        raise ValueError("multipliers of the state-space mixer without one (ssm_state_size = 0)")
+    if "mlp_multipliers" in stated and (cfg.activation != "swiglu" or cfg.moe_every > 0):
+        raise NotImplementedError(
+            "mlp_multipliers scale a dense gated feed-forward's gate and output: "
+            "activation='swiglu' with no routed layer")
+    if "key_multiplier" in stated and cfg.kv_lora_rank:
+        raise NotImplementedError("key_multiplier with latent attention has no code")
+
+
+def refuse_in_pipeline(cfg: "TransformerConfig") -> None:
+    """What the pipeline schedules (pipe/) have not carried yet, by name."""
+    if cfg.ssm_state_size > 0:
+        raise NotImplementedError(
+            "the state-space mixer (ssm_state_size > 0) under a pipeline schedule has no "
+            "code: the stages' stacks and logical axes do not name its leaves")
+
+
 def _dropless(cfg: TransformerConfig) -> bool:
     """Whether the model's feed-forward is the dropless routed block
     (moe/dropless.py) in every layer after the ``moe_first_dense`` leading ones;
-    refuses the combinations that block does not have."""
+    refuses the combinations that block, and a gated dense feed-forward, do not
+    have."""
     dropless, gated = cfg.moe_routing == "dropless", cfg.activation == "swiglu"
-    if dropless != gated or (dropless and cfg.moe_every != 1):
+    if dropless and (not gated or cfg.moe_every != 1):
         raise NotImplementedError(
             "moe_routing='dropless', activation='swiglu' and moe_every=1 come together: the "
             "dropless block has gated experts in every layer (after moe_first_dense leading "
-            "dense gated ones), and a model with no routed layer has no gated feed-forward "
-            f"(got moe_routing={cfg.moe_routing!r}, activation="
-            f"{cfg.activation!r}, moe_every={cfg.moe_every})")
+            f"dense gated ones) (got activation={cfg.activation!r}, moe_every={cfg.moe_every})")
+    if gated and not dropless and (cfg.moe_every > 0 or cfg.use_bias):
+        raise NotImplementedError(
+            "activation='swiglu' without moe_routing='dropless' is a dense gated feed-forward "
+            "in every layer, with no biases: GShard's experts are not gated (got moe_every="
+            f"{cfg.moe_every}, use_bias={cfg.use_bias})")
     return dropless
 
 
 def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
+    """The seeded draw. Every matrix at 1 / sqrt(fan in); one whose OUTPUT a
+    stated multiplier scales (``MULTIPLIERS``) at 1 / (sqrt(fan in) x that
+    multiplier), the mixer's input projection segment by segment, so that the
+    multiplied activations have the size they have in a model without
+    multipliers and a multiplier dropped from the forward pass changes the
+    logits by its factor, not by nothing."""
     keys = jax.random.split(rng, 16)
+    more = jax.random.split(jax.random.fold_in(rng, 16), 8)  # the leaves newer than the 16
     d, f, L = cfg.hidden_size, cfg.ffn_size, cfg.num_layers
-    H, Dh, Dv = cfg.num_heads, cfg.head_dim, cfg.value_head_dim
+    H, Hkv, Dh, Dv = cfg.num_heads, cfg.kv_heads, cfg.head_dim, cfg.value_head_dim
     dropless = _dropless(cfg)
+    mult = cfg.multiplier
+    attn_in = mult("attention_in_multiplier")
 
-    def stack(key, shape, fan_in, n=L):
+    def stack(key, shape, fan_in, n=L, scale=1.0):
         ks = jax.random.split(key, n)
-        return jnp.stack([_dense_init(k, shape, fan_in) for k in ks])
+        return jnp.stack([_dense_init(k, shape, fan_in) for k in ks]) / scale
 
     layers = {
         "ln1_scale": jnp.ones((L, d)),
         "ln2_scale": jnp.ones((L, d)),
-        "wq": stack(keys[0], (d, H, Dh), d),
-        "wo": stack(keys[3], (H, Dv, d), d),
+        "wq": stack(keys[0], (d, H, Dh), d, scale=attn_in),
+        # a head width that is not hidden_size // num_heads: the fan-in is the heads'
+        "wo": stack(keys[3], (H, Dv, d), d if cfg.kv_lora_rank else H * Dv,
+                    scale=mult("attention_out_multiplier")),
     }
     if cfg.kv_lora_rank:  # the latent and the shared rotary key; then every head's k_nope | v
         R, Dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
@@ -302,26 +459,39 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
                        "kv_norm_scale": jnp.ones((L, R)),
                        "wkv_b": stack(keys[2], (R, H, Dh - Dr + Dv), R)})
     else:
-        layers.update({"wk": stack(keys[1], (d, H, Dh), d), "wv": stack(keys[2], (d, H, Dh), d)})
+        layers.update({
+            "wk": stack(keys[1], (d, Hkv, Dh), d, scale=attn_in * mult("key_multiplier")),
+            "wv": stack(keys[2], (d, Hkv, Dh), d, scale=attn_in)})
     if cfg.norm_kind != "rms":
         layers.update({"ln1_bias": jnp.zeros((L, d)), "ln2_bias": jnp.zeros((L, d))})
     if cfg.qk_norm:
-        layers.update({"q_norm_scale": jnp.ones((L, H, Dh)), "k_norm_scale": jnp.ones((L, H, Dh))})
+        layers.update({"q_norm_scale": jnp.ones((L, H, Dh)),
+                       "k_norm_scale": jnp.ones((L, Hkv, Dh))})
     if not dropless:  # no layer has a dense feed-forward there
-        layers.update({"wi": stack(keys[4], (d, f), d), "wo_mlp": stack(keys[5], (f, d), f)})
+        gate_m, down_m = mult("mlp_multipliers")
+        layers.update({"wi": stack(keys[4], (d, f), d),
+                       "wo_mlp": stack(keys[5], (f, d), f, scale=down_m)})
+        if cfg.activation == "swiglu":
+            layers["wg"] = stack(more[0], (d, f), d, scale=gate_m)
+    if cfg.ssm_state_size:
+        layers.update(_init_mixer(cfg, more[1:6], stack))
     if cfg.use_bias:
         layers.update(
             {
                 "bq": jnp.zeros((L, H, Dh)),
-                "bk": jnp.zeros((L, H, Dh)),
-                "bv": jnp.zeros((L, H, Dh)),
+                "bk": jnp.zeros((L, Hkv, Dh)),
+                "bv": jnp.zeros((L, Hkv, Dh)),
                 "bo": jnp.zeros((L, d)),
             }
         )
         if not dropless:
             layers.update({"bi": jnp.zeros((L, f)), "bo_mlp": jnp.zeros((L, d))})
+    stated = cfg.multipliers or {}
     params = {
-        "wte": jax.random.normal(keys[6], (cfg.vocab_size, d)) * 0.02,
+        # with a stated embedding multiplier the residual stream enters at the size of
+        # a layer's output (1), so that the multiplier left out would show in the logits
+        "wte": jax.random.normal(keys[6], (cfg.vocab_size, d)) * (
+            1.0 / mult("embedding_multiplier") if "embedding_multiplier" in stated else 0.02),
         "layers": layers,
         "lnf_scale": jnp.ones((d,)),
     }
@@ -334,7 +504,11 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
         if cfg.norm_kind != "rms":
             params["emb_ln_bias"] = jnp.zeros((d,))
     if not cfg.tie_embeddings:
-        params["lm_head"] = _dense_init(keys[8], (d, cfg.vocab_size), d)
+        params["lm_head"] = (_dense_init(keys[8], (d, cfg.vocab_size), d)
+                             / mult("lm_head_multiplier"))
+    elif "lm_head_multiplier" in stated:
+        raise NotImplementedError("lm_head_multiplier with tie_embeddings has no seeded draw: "
+                                  "the embedding cannot be drawn for both multipliers")
     if dropless:
         from ..moe.dropless import init_dropless
 
@@ -351,6 +525,33 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
         n_moe = cfg.num_layers // cfg.moe_every
         params["moe"] = init_moe_params(keys[9], n_moe, cfg.num_experts, d, f)
     return params
+
+
+def _init_mixer(cfg: TransformerConfig, keys, stack) -> dict:
+    """The mixer's leaves, [L]-stacked. ``ssm_in`` maps the hidden state to
+    z | x | B | C | dt (``ssm_inner``, ``ssm_inner``, G x N, G x N, heads), each
+    segment drawn for its own multiplier; the rest as Mamba-2 initialises them:
+    ``A_log`` = log(1 ... heads) (heads that forget in a few tokens and heads
+    that remember hundreds), ``dt_bias`` the inverse softplus of a log-uniform
+    step in [0.001, 0.1], ``D`` = 1, the convolution uniform within 1 / sqrt(taps)."""
+    d, L, H, K = cfg.hidden_size, cfg.num_layers, cfg.ssm_heads, cfg.ssm_conv_kernel
+    inner, gn, conv_dim = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state_size, cfg.ssm_conv_dim
+    in_m = cfg.multiplier("ssm_in_multiplier")
+    segments = zip(jax.random.split(keys[0], 5), (inner, inner, gn, gn, H),
+                   cfg.multiplier("ssm_multipliers"))
+    dt = jnp.exp(jax.random.uniform(keys[1], (L, H), minval=math.log(1e-3), maxval=math.log(1e-1)))
+    bound = 1.0 / math.sqrt(K)
+    return {
+        "ssm_in": jnp.concatenate([stack(k, (d, width), d, scale=in_m * m)
+                                   for k, width, m in segments], axis=-1),
+        "ssm_conv": jax.random.uniform(keys[2], (L, K, conv_dim), minval=-bound, maxval=bound),
+        "ssm_conv_bias": jax.random.uniform(keys[3], (L, conv_dim), minval=-bound, maxval=bound),
+        "ssm_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
+        "ssm_a_log": jnp.broadcast_to(jnp.log(jnp.arange(1, H + 1, dtype=jnp.float32)), (L, H)),
+        "ssm_d": jnp.ones((L, H)),
+        "ssm_norm_scale": jnp.ones((L, inner)),
+        "ssm_out": stack(keys[4], (inner, d), inner, scale=cfg.multiplier("ssm_out_multiplier")),
+    }
 
 
 def logical_axes(cfg: TransformerConfig) -> Params:
@@ -376,6 +577,13 @@ def logical_axes(cfg: TransformerConfig) -> Params:
                        "k_norm_scale": ("layers", "heads", "kv")})
     if not dropless:
         layers.update({"wi": ("layers", "embed", "mlp"), "wo_mlp": ("layers", "mlp", "embed")})
+        if cfg.activation == "swiglu":
+            layers["wg"] = ("layers", "embed", "mlp")
+    if cfg.ssm_state_size:  # z | x | B | C | dt lie side by side in one axis: not split
+        layers.update({"ssm_in": ("layers", "embed", None), "ssm_conv": ("layers", None, None),
+                       "ssm_conv_bias": ("layers", None), "ssm_dt_bias": ("layers", None),
+                       "ssm_a_log": ("layers", None), "ssm_d": ("layers", None),
+                       "ssm_norm_scale": ("layers", None), "ssm_out": ("layers", None, "embed")})
     if cfg.use_bias:
         layers.update(
             {
@@ -432,11 +640,15 @@ def logical_axes(cfg: TransformerConfig) -> Params:
 # multiply in float32), the q/k norm scales and the latent's ``kv_norm_scale``,
 # ``lm_head_bias`` (added to float32 logits) and the router's ``gate`` and its
 # selection ``bias`` (``dropless.route`` is a float32 product at full precision,
-# and the GShard path's ``moe_dispatch_combine`` likewise).
+# and the GShard path's ``moe_dispatch_combine`` likewise). Of the state-space
+# mixer the two projections and the convolution (its tail is cached in the
+# compute dtype) are cast; ``ssm_dt_bias``, ``ssm_a_log``, ``ssm_d`` and the gated
+# norm's scale enter float32 arithmetic and are read in float32.
 _READ_IN_COMPUTE_DTYPE = frozenset({
     "wte", "wpe", "lm_head",
     "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo", "wkv_a", "wkv_b",
     "wi", "wo_mlp", "bi", "bo_mlp", "wg",
+    "ssm_in", "ssm_out", "ssm_conv", "ssm_conv_bias",
 })
 
 
@@ -527,11 +739,22 @@ def xla_attention(q, k, v, *, causal_offset=0, bias=None, causal=True, dtype=jnp
     reference's test_cuda_forward.py strategy). ``causal=False`` gives the
     bidirectional encoder form (BERT). ``causal_offset`` may be a scalar or a
     per-row [B] vector — continuous batching decodes every cache slot at its
-    own absolute position. v's heads may be another width than q's and k's."""
+    own absolute position. v's heads may be another width than q's and k's.
+    k and v may have FEWER heads than q (grouped-query attention): query head i
+    attends key / value head i // (H // Hkv), the group contracted against its
+    one K/V head where it lies, never against repeated copies."""
     B, Sq, H, Dh = q.shape
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / math.sqrt(Dh)
+    Hkv = k.shape[2]
+    if Hkv == H:
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / math.sqrt(Dh)
+        probs = _masked_softmax(scores, causal_offset, bias, causal).astype(q.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    qg = q.reshape(B, Sq, Hkv, H // Hkv, Dh)
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32) / math.sqrt(Dh)
+    scores = scores.reshape(B, H, Sq, k.shape[1])  # the mask and a bias are per query head
     probs = _masked_softmax(scores, causal_offset, bias, causal).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.reshape(B, Hkv, H // Hkv, Sq, k.shape[1]), v)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 def _masked_softmax(scores, causal_offset=0, bias=None, causal=True):
@@ -776,6 +999,11 @@ def _attention_dispatch(cfg: TransformerConfig):
     return lambda q, k, v, bias: xla_attention(q, k, v, bias=bias, causal=cfg.causal)
 
 
+def _times(x, m: float):
+    """x scaled by a stated multiplier; x itself where it is 1 (no operation traced)."""
+    return x if m == 1.0 else x * m
+
+
 def _act_q(cfg, x):
     """Activation fake-quant at linear-projection inputs (compression's
     activation_quantization group; reference QuantAct basic_layer.py:12)."""
@@ -794,8 +1022,10 @@ def _ffn(cfg, lp, h):
         u = jnp.einsum("bsd,df->bsf", h, lp["wi"].astype(h.dtype))
         if cfg.use_bias:
             u = u + lp["bi"].astype(h.dtype)
-        if cfg.activation == "swiglu":  # a leading dense layer of a dropless model: no biases
-            u = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["wg"].astype(h.dtype))) * u
+        gate_m, down_m = cfg.multiplier("mlp_multipliers")
+        if cfg.activation == "swiglu":  # a gated feed-forward has no biases
+            gate = jnp.einsum("bsd,df->bsf", h, lp["wg"].astype(h.dtype))
+            u = jax.nn.silu(_times(gate, gate_m)) * u
         elif cfg.activation == "relu":
             u = jax.nn.relu(u)
         elif cfg.activation == "gelu_exact":
@@ -806,7 +1036,7 @@ def _ffn(cfg, lp, h):
         out = jnp.einsum("bsf,fd->bsd", u, lp["wo_mlp"].astype(h.dtype))
         if cfg.use_bias:
             out = out + lp["bo_mlp"].astype(h.dtype)
-        return out
+        return _times(out, down_m)
 
 
 def _dense_ffn(cfg, lp, h):
@@ -822,6 +1052,7 @@ def _qkv_proj(cfg: TransformerConfig, lp, h, positions):
     ``_latent_attention``)."""
     with jax.named_scope("attn"):
         h = _act_q(cfg, h)
+        h = _times(h, cfg.multiplier("attention_in_multiplier"))
         q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(h.dtype))
         if cfg.kv_lora_rank:
             R, Dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
@@ -831,8 +1062,9 @@ def _qkv_proj(cfg: TransformerConfig, lp, h, positions):
             c = rms_norm(kv[..., :R], lp["kv_norm_scale"], cfg.layernorm_epsilon)
             q = jnp.concatenate([q[..., :-Dr], rope(q[..., -Dr:])], axis=-1)
             return q, rope(kv[:, :, None, R:]), c[:, :, None]
-        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(h.dtype))
+        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(h.dtype))  # kv_heads of them
         v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(h.dtype))
+        k = _times(k, cfg.multiplier("key_multiplier"))  # before the rotary
         if cfg.use_bias:
             q = q + lp["bq"].astype(h.dtype)
             k = k + lp["bk"].astype(h.dtype)
@@ -853,7 +1085,152 @@ def _attn_out_proj(cfg: TransformerConfig, lp, attn_out):
         out = jnp.einsum("bshk,hkd->bsd", attn_out, lp["wo"].astype(attn_out.dtype))
         if cfg.use_bias:
             out = out + lp["bo"].astype(attn_out.dtype)
-        return out
+        return _times(out, cfg.multiplier("attention_out_multiplier"))
+
+
+# The cache tree's subtree of per-SEQUENCE leaves ([L, B, ...], no position
+# axis: overwritten whole by every step); every other leaf of the tree is
+# per-TOKEN ([L, B, Smax, heads, width]). ``cache_layout`` builds both kinds and
+# the helpers below it tell them apart by this key alone.
+STATE = "state"
+
+
+def _ssm_scan(x, dt, A, Bm, Cm, S0, chunk: int):
+    """The selective scan S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t, y_t = S_t C_t
+    over many rows, in its chunked form: x [B, T, H, P], dt [B, T, H] float32
+    (0 on a row that must not move the state), A [H] float32 (< 0), Bm / Cm
+    [B, T, G, N] (head h reads group h // (H // G); never repeated to heads), S0
+    [B, H, P, N] float32 -> (y [B, T, H, P] float32, S_T). T is padded to whole
+    chunks with dt = 0. Within a chunk of Q rows the pairs are a [Q, Q] matrix a
+    head (decay x C.B, as attention's scores are); between chunks one state a
+    chunk is carried. Nothing of size rows x rows x heads x N is formed: the
+    largest temporaries are [chunks, heads, Q, Q] and [chunks, heads, P, N].
+    The decays, their cumulative sums and the states are float32; the four
+    contractions take their operands in x's dtype and accumulate in float32."""
+    f32 = jnp.float32
+    B_, T, H, P = x.shape
+    G, N = Bm.shape[2:]
+    Hg, Q = H // G, chunk
+    pad = (-T) % Q
+    if pad:
+        x, dt, Bm, Cm = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                         for a in (x, dt, Bm, Cm))
+    nc = (T + pad) // Q
+    xc = x.reshape(B_, nc, Q, G, Hg, P)
+    dtc = dt.reshape(B_, nc, Q, G, Hg)
+    Bc, Cc = Bm.reshape(B_, nc, Q, G, N), Cm.reshape(B_, nc, Q, G, N)
+    cum = jnp.cumsum(dtc * A.reshape(G, Hg), axis=2)  # [B, nc, Q, G, Hg], falling from 0
+    # within a chunk: y_i += sum_{j <= i} exp(cum_i - cum_j) dt_j (C_i . B_j) x_j
+    cum_t = jnp.moveaxis(cum, 2, -1)  # [B, nc, G, Hg, Q]
+    causal = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+    decay = jnp.exp(jnp.where(causal, cum_t[..., :, None] - cum_t[..., None, :], -jnp.inf))
+    cb = jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc, preferred_element_type=f32)
+    pairs = cb[:, :, :, None] * decay * jnp.moveaxis(dtc, 2, -1)[..., None, :]
+    y = jnp.einsum("bcghij,bcjghp->bcighp", pairs.astype(x.dtype), xc,
+                   preferred_element_type=f32)
+    # a chunk's own contribution to the state at its end, and its whole decay
+    to_end = jnp.exp(cum[:, :, -1:] - cum) * dtc  # [B, nc, Q, G, Hg]
+    local = jnp.einsum("bcjghp,bcjgn->cbghpn", (xc * to_end[..., None]).astype(x.dtype), Bc,
+                       preferred_element_type=f32)
+    whole = jnp.moveaxis(jnp.exp(cum[:, :, -1]), 1, 0)  # [nc, B, G, Hg]
+
+    def carry_state(S, chunk_):
+        own, dec = chunk_
+        return dec[..., None, None] * S + own, S  # the state ENTERING the chunk is what it reads
+
+    S_T, entering = lax.scan(carry_state, S0.reshape(B_, G, Hg, P, N), (local, whole))
+    y = y + jnp.einsum("bcign,cbghpn->bcighp", Cc, entering.astype(x.dtype),
+                       preferred_element_type=f32) * jnp.exp(cum)[..., None]
+    return y.reshape(B_, nc * Q, H, P)[:, :T], S_T.reshape(B_, H, P, N)
+
+
+def _ssm_step(x, dt, A, Bm, Cm, S0):
+    """``_ssm_scan`` for ONE row a sequence (a decode step): the recurrence as it
+    is written, one update of the state and one read, elementwise in float32 (the
+    state is read once and written once; no contraction rounds it)."""
+    f32 = jnp.float32
+    B_, _, H, P = x.shape
+    G, N = Bm.shape[2:]
+    Hg = H // G
+    dt = dt[:, 0].reshape(B_, G, Hg, 1, 1)
+    Bg, Cg = (m[:, 0].astype(f32)[:, :, None, None, :] for m in (Bm, Cm))  # [B, G, 1, 1, N]
+    S = (jnp.exp(dt * A.reshape(G, Hg, 1, 1)) * S0.reshape(B_, G, Hg, P, N)
+         + dt * x[:, 0].astype(f32).reshape(B_, G, Hg, P, 1) * Bg)
+    return jnp.sum(S * Cg, axis=-1).reshape(B_, 1, H, P), S.reshape(B_, H, P, N)
+
+
+def _ssm_mixer(cfg: TransformerConfig, lp, h, state, l, live):
+    """The Mamba-2 mixer of one layer on the normed h [B, T, d] -> (its output
+    [B, T, d], state). As published (Falcon-H1; every multiplier is
+    ``cfg.multiplier``'s, z | xBC | dt are ``ssm_in``'s segments):
+
+        [z | xBC | dt] = (W_in (h in_mult)) * ssm_multipliers, segment by segment
+        xBC_t = silu(sum_j w_conv[j] * xBC_{t-K+1+j} + b_conv)     depthwise, causal
+        [x | B | C] = xBC;  dt_t = softplus(dt_t + dt_bias);  A = -exp(A_log)
+        S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t;   y_t = S_t C_t + D x_t
+        out = W_out(grouped_rmsnorm(y * silu(z))) out_mult         gate BEFORE the norm
+
+    ``state`` is None (training, ``apply``: the sequence starts from nothing and
+    keeps nothing) or the cache tree the block's ``attend`` carries, whose
+    ``STATE`` leaves hold per layer and row the state S [H, P, N] in float32 and
+    the convolution's tail, the last K - 1 rows of xBC BEFORE the convolution:
+    layer ``l`` of both is read, and written back in place. ``live`` [B, T] bool
+    or None (all): the rows that are a sequence's own, leading each row of the
+    batch. On any other row (a prefill bucket's padding, a slot that is idle or
+    still prefilling in a decode step) dt is 0, so exp(dt A) = 1 and dt x (x) B =
+    0 and the state passes through exactly, and the tail kept is that of the
+    last K - 1 LIVE rows. T = 1 takes the recurrence itself, more rows the
+    chunked scan from the state given."""
+    f32 = jnp.float32
+    H, P, G, N, K = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state_size,
+                     cfg.ssm_conv_kernel)
+    inner, gn, conv_dim = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state_size, cfg.ssm_conv_dim
+    B_, T, _ = h.shape
+    with jax.named_scope("ssm"):
+        segments = np.repeat(np.asarray(cfg.multiplier("ssm_multipliers"), np.float32),
+                             (inner, inner, gn, gn, H))
+        proj = jnp.einsum("bsd,dz->bsz", _times(h, cfg.multiplier("ssm_in_multiplier")),
+                          lp["ssm_in"].astype(h.dtype), preferred_element_type=f32) * segments
+        z, xBC, dt = (proj[..., :inner], proj[..., inner:inner + conv_dim].astype(h.dtype),
+                      proj[..., inner + conv_dim:])
+        if state is None:
+            tail, S0 = jnp.zeros((B_, K - 1, conv_dim), h.dtype), jnp.zeros((B_, H, P, N), f32)
+        else:
+            tail, S0 = (lax.dynamic_index_in_dim(state[STATE][name], l, keepdims=False)
+                        for name in ("conv", "ssm"))
+        rows = jnp.concatenate([tail.astype(h.dtype), xBC], axis=1)  # [B, K - 1 + T, C]
+        taps = lp["ssm_conv"].astype(h.dtype).astype(f32)
+        conv = sum(rows[:, j:j + T].astype(f32) * taps[j] for j in range(K))
+        xBC = jax.nn.silu(conv + lp["ssm_conv_bias"].astype(h.dtype).astype(f32)).astype(h.dtype)
+        xs = xBC[..., :inner].reshape(B_, T, H, P)
+        Bm = xBC[..., inner:inner + gn].reshape(B_, T, G, N)
+        Cm = xBC[..., inner + gn:].reshape(B_, T, G, N)
+        dt = jax.nn.softplus(dt + lp["ssm_dt_bias"].astype(f32))
+        if live is not None:
+            dt = jnp.where(live[..., None], dt, 0.0)
+        A = -jnp.exp(lp["ssm_a_log"].astype(f32))
+        if T == 1:
+            y, S = _ssm_step(xs, dt, A, Bm, Cm, S0.astype(f32))
+        else:
+            y, S = _ssm_scan(xs, dt, A, Bm, Cm, S0.astype(f32), cfg.ssm_chunk_size)
+        y = y + lp["ssm_d"].astype(f32)[:, None] * xs.astype(f32)
+        gated = (y.reshape(B_, T, inner) * jax.nn.silu(z)).reshape(B_, T, G, inner // G)
+        gated = gated * lax.rsqrt(jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
+                                  + cfg.layernorm_epsilon)
+        gated = (gated.reshape(B_, T, inner) * lp["ssm_norm_scale"].astype(f32)).astype(h.dtype)
+        out = _times(jnp.einsum("bsi,id->bsd", gated, lp["ssm_out"].astype(h.dtype)),
+                     cfg.multiplier("ssm_out_multiplier"))
+        if state is None:
+            return out, state
+        n_live = jnp.full((B_,), T, jnp.int32) if live is None else jnp.sum(live, axis=1)
+        kept = n_live.astype(jnp.int32)[:, None, None] + jnp.arange(K - 1)[None, :, None]
+        tail = jnp.take_along_axis(rows, kept, axis=1)  # rows n_live ... n_live + K - 2
+        held = state[STATE]
+        held = {"conv": lax.dynamic_update_slice(held["conv"], tail[None].astype(
+                    held["conv"].dtype), (l, 0, 0, 0)),
+                "ssm": lax.dynamic_update_slice(held["ssm"], S[None].astype(held["ssm"].dtype),
+                                                (l, 0, 0, 0, 0))}
+        return out, {**state, STATE: held}
 
 
 def quantizable_layer_leaves(layers: dict, group_size: int) -> dict[str, int]:
@@ -943,7 +1320,7 @@ NEG_BIAS = -1e30
 
 
 def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
-           rng=None, pld_keep=None):
+           rng=None, pld_keep=None, live=None):
     """THE transformer layer: every caller's layer is this function.
 
     carry = (x [B, T, d] in the compute dtype, state); ``lp`` one layer's leaves;
@@ -958,11 +1335,17 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
     - ``ffn(lp, h) -> (out, aux_loss, experts chosen or None)``: this layer's
       feed-forward, dense or routed (``_layer_loop`` picks);
     - ``rng`` / ``pld_keep``: the training-only stochastic gates (dropout;
-      progressive layer drop, one coin for BOTH residual branches).
+      progressive layer drop, one coin for BOTH residual branches);
+    - ``live`` [B, T] bool or None: the rows that are a sequence's own, for a
+      model whose state-space mixer must not move on the others (``_ssm_mixer``).
 
     Norm kind and placement and the residual form are what ``cfg`` says, for a
     dense layer and a routed one alike (``norm_style="post"`` is the BERT
-    layout, sublayer -> residual add -> norm, and is sequential).
+    layout, sublayer -> residual add -> norm, and is sequential). Three residual
+    forms: sequential (x + attn, then + ffn of the new x), parallel (x + attn +
+    ffn, both of the same x: GPT-NeoX), and, with a state-space mixer, the
+    attention and the mixer in parallel on the SAME normed input followed by a
+    sequential feed-forward (Falcon-H1); the mixer's state rides in ``state``.
     Returns (carry, (aux_loss, experts)): a ``lax.scan`` body."""
     x, state = carry
     lp = _dequant_layer(cfg, lp)
@@ -977,9 +1360,13 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
         return out if gate is None else gate * out
 
     pre = cfg.norm_style != "post"
-    q, k, v = _qkv_proj(cfg, lp, norm(cfg, x, lp, "ln1") if pre else x, positions)
+    h = norm(cfg, x, lp, "ln1") if pre else x
+    q, k, v = _qkv_proj(cfg, lp, h, positions)
     attn, state = attend(q, k, v, state, l, lp)
     attn_out = branch(_attn_out_proj(cfg, lp, attn), cfg.attn_dropout, k_attn)
+    if cfg.ssm_state_size:
+        mixed, state = _ssm_mixer(cfg, lp, h, state, l, live)
+        attn_out = attn_out + mixed
     if pre and cfg.parallel_residual:
         f, aux, experts = ffn(lp, norm(cfg, x, lp, "ln2"))
         x = x + attn_out + branch(f, cfg.hidden_dropout, k_hidden)
@@ -1040,7 +1427,7 @@ def _remat_wrapper(cfg: TransformerConfig):
 
 
 def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, attend,
-                per_layer=None, wrap=None, decode: bool = False, lead=None):
+                per_layer=None, wrap=None, decode: bool = False, lead=None, live=None):
     """THE layer loop: ``_block`` over the stacked ``layers`` [L, ...] (the
     whole model's, or one pipeline stage's slice), ``moe`` the routed layers'
     stacks or None -> (x, state, summed aux loss, experts chosen or None).
@@ -1055,14 +1442,15 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
     ``decode`` marks a single-token step for the routed feed-forward. ``lead``
     (``params["dense_ffn"]`` or None): the gated feed-forward stacks of the
     ``moe_first_dense`` leading layers, which run in a scan of their own before
-    the periods; ``moe`` then holds the stacks of the layers after them."""
+    the periods; ``moe`` then holds the stacks of the layers after them.
+    ``live``: the block's (the rows a state-space mixer may move on)."""
     layers_xs, load_layer = _make_stack_loader(cfg, layers)
     routed = cfg.moe_every > 0 and moe is not None
     moe_xs, load_moe = _make_stack_loader(cfg, moe) if routed else (None, None)
     L = jax.tree.leaves(layers_xs)[0].shape[0]
     xs = (layers_xs, jnp.arange(L, dtype=jnp.int32), per_layer or {})
     unroll = max(1, cfg.scan_unroll)
-    block = partial(_block, cfg, positions=positions, attend=attend)
+    block = partial(_block, cfg, positions=positions, attend=attend, live=live)
     wrap = wrap or (lambda body: body)
     carry = (x, state)
 
@@ -1143,6 +1531,7 @@ def _lm_head(cfg: TransformerConfig, params: Params, x):
     with jax.named_scope("lm_head"):
         logits = jnp.einsum("bsd,dv->bsv", x, _head_matrix(params).astype(x.dtype))
         logits = logits.astype(jnp.float32)
+        logits = _times(logits, cfg.multiplier("lm_head_multiplier"))
         if "lm_head_bias" in params:
             logits = logits + params["lm_head_bias"].astype(jnp.float32)
         return logits
@@ -1155,6 +1544,7 @@ def embed(cfg: TransformerConfig, params: Params, tokens, positions=None):
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
         x = params["wte"][tokens].astype(cfg.dtype)
+        x = _times(x, cfg.multiplier("embedding_multiplier"))
         if cfg.pos_emb == "learned":
             x = x + params["wpe"][positions].astype(cfg.dtype)
         if cfg.embed_ln:
@@ -1252,7 +1642,8 @@ def _moe_ffn(cfg, moe_p, h, decode: bool = False):
 # The reference's decode path is the fused `softmax_context` CUDA kernel with
 # an incremental KV cache (csrc/transformer/inference/csrc/pt_binding.cpp:
 # softmax_context_* :1237, attention-with-cache). TPU-native: the cache is a
-# tree of static-shape [L, B, Smax, heads, width] leaves (``cache_layout``)
+# tree of static-shape leaves (``cache_layout``: [L, B, Smax, heads, width] a
+# token, [L, B, ...] a sequence for a state-space mixer's state)
 # that stays ONE set of buffers through the layer scan (its carry: layer l
 # writes its new rows at [l, row, pos] and attends to layer l of the stack
 # where it lies); one `apply_with_cache` function serves both prefill
@@ -1260,41 +1651,87 @@ def _moe_ffn(cfg, moe_p, h, decode: bool = False):
 # programs per sequence budget.
 
 def cache_layout(cfg: TransformerConfig) -> dict:
-    """{leaf: (heads, width)} of what the model's attention caches a token a
-    layer; every helper below, and the serving engine, work on the tree this
-    gives and on no leaf's name or trailing shape. Plain attention: the keys
-    and the values of every head. Latent attention: ``k`` is the rotary key the
-    heads share, ``v`` the normed latent, which is the absorbed form's value
-    and the rest of its key; the 'one head' is every head's."""
+    """What the model keeps of a sequence between steps, as the tree every helper
+    below, and the serving engine, work on (on no leaf's name or trailing shape).
+
+    Per-TOKEN leaves, ``{leaf: (heads, width)}``: what attention caches a token a
+    layer, [L, B, Smax, heads, width] in the cache. Plain attention: the keys and
+    the values of every K/V head (``kv_heads``: grouped-query attention caches
+    its few, not the query heads' many). Latent attention: ``k`` is the rotary
+    key the heads share, ``v`` the normed latent, which is the absorbed form's
+    value and the rest of its key; the 'one head' is every head's.
+
+    Per-SEQUENCE leaves, under ``STATE``, ``{leaf: (shape, dtype or None for the
+    cache's)}``: state with no position axis, [L, B, *shape] in the cache, that
+    every step overwrites whole and nothing can slice by position. A
+    state-space mixer's float32 state [heads, head width, state size] (it
+    accumulates over thousands of steps) and the convolution's tail, the last
+    ``ssm_conv_kernel - 1`` rows of its input. Absent for a model without."""
     if cfg.kv_lora_rank:
         return {"k": (1, cfg.qk_rope_head_dim), "v": (1, cfg.kv_lora_rank)}
-    return {"k": (cfg.num_heads, cfg.head_dim), "v": (cfg.num_heads, cfg.value_head_dim)}
+    layout = {"k": (cfg.kv_heads, cfg.head_dim), "v": (cfg.kv_heads, cfg.value_head_dim)}
+    if cfg.ssm_state_size:
+        layout[STATE] = {
+            "ssm": ((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size), jnp.float32),
+            "conv": ((cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim), None)}
+    return layout
+
+
+def _token_leaves(tree: dict) -> dict:
+    return {name: leaf for name, leaf in tree.items() if name != STATE}
 
 
 def cache_bytes_per_token(cfg: TransformerConfig, dtype=None) -> int:
-    """Bytes one token takes in ONE layer of the cache."""
-    values = sum(heads * width for heads, width in cache_layout(cfg).values())
+    """Bytes one token takes in ONE layer of the cache (its per-token leaves)."""
+    values = sum(heads * width for heads, width in _token_leaves(cache_layout(cfg)).values())
     return values * jnp.dtype(dtype or cfg.dtype).itemsize
+
+
+def cache_state_bytes(cfg: TransformerConfig, dtype=None) -> int:
+    """Bytes one SEQUENCE's per-sequence leaves take in ONE layer of the cache
+    (0 for a model that keeps none)."""
+    return sum(math.prod(shape) * jnp.dtype(own or dtype or cfg.dtype).itemsize
+               for shape, own in cache_layout(cfg).get(STATE, {}).values())
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int, dtype=None):
     """Allocate an empty cache for ``batch`` sequences of up to ``max_len``."""
     dtype = dtype or cfg.dtype
-    return {name: jnp.zeros((cfg.num_layers, batch, max_len) + tail, dtype)
-            for name, tail in cache_layout(cfg).items()}
+    layout = cache_layout(cfg)
+    cache = {name: jnp.zeros((cfg.num_layers, batch, max_len) + tail, dtype)
+             for name, tail in _token_leaves(layout).items()}
+    if STATE in layout:
+        cache[STATE] = {name: jnp.zeros((cfg.num_layers, batch) + shape, own or dtype)
+                        for name, (shape, own) in layout[STATE].items()}
+    return cache
 
 
 def cache_len(cache) -> int:
-    """Smax of a [L, B, Smax, ...] cache tree."""
-    return jax.tree.leaves(cache)[0].shape[2]
+    """Smax of a cache tree: the position axis of its per-token leaves
+    [L, B, Smax, heads, width]."""
+    return jax.tree.leaves(_token_leaves(cache))[0].shape[2]
+
+
+def cache_dtype(cache):
+    """The dtype a cache tree's per-token leaves are held in."""
+    return jax.tree.leaves(_token_leaves(cache))[0].dtype
+
+
+def _slot_state(state: dict, slot):
+    """Row ``slot`` of per-sequence leaves [L, B, ...] -> [L, 1, ...], whole."""
+    return jax.tree.map(lambda c: lax.dynamic_slice(
+        c, (0, slot) + (0,) * (c.ndim - 2), (c.shape[0], 1) + c.shape[2:]), state)
 
 
 def slice_cache_slot(cache, slot, length: int, start=0):
-    """Read one sequence's window out of a slot cache: every leaf
-    [L, B, Smax, H, Dh] -> [L, 1, length, H, Dh] at row ``slot``,
-    positions [start, start+length). ``slot`` and ``start`` may be traced
-    int32 scalars — the caller's program stays compile-stable across
-    slots/offsets; ``length`` is static: it picks the compiled program.
+    """Read one sequence's window out of a slot cache: every per-token leaf
+    [L, B, Smax, heads, width] -> [L, 1, length, heads, width] at row ``slot``,
+    positions [start, start+length); every per-sequence leaf [L, B, ...] ->
+    [L, 1, ...], whole (it has no positions: the sequence's state is the state
+    after the last step that wrote it, whatever window is asked for). ``slot``
+    and ``start`` may be traced int32 scalars — the caller's program stays
+    compile-stable across slots/offsets; ``length`` is static: it picks the
+    compiled program.
 
     The serving engine's chunked prefill and prefix-cache copies both run on
     these windows: chunk programs slice a slot out, extend it through
@@ -1305,22 +1742,42 @@ def slice_cache_slot(cache, slot, length: int, start=0):
         raise ValueError(f"cache window ({length}) exceeds cache length {cache_len(cache)}")
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
-    return jax.tree.map(
+    out = jax.tree.map(
         lambda c: lax.dynamic_slice(c, (0, slot, start, 0, 0),
-                                    (c.shape[0], 1, length) + c.shape[3:]), cache)
+                                    (c.shape[0], 1, length) + c.shape[3:]), _token_leaves(cache))
+    if STATE in cache:
+        out[STATE] = _slot_state(cache[STATE], slot)
+    return out
 
 
 def update_cache_slot(cache, window, slot, start=0):
-    """Write a window (every leaf [L, 1, W, H, Dh]) into row ``slot`` of a
-    slot cache at positions [start, start+W) (one ``dynamic_update_slice`` per
-    leaf — the inverse of ``slice_cache_slot``). ``slot``/``start`` are traced
-    scalars: one compiled program regardless of which slot/offset is
-    written."""
+    """Write a window into row ``slot`` of a slot cache — the inverse of
+    ``slice_cache_slot``: every per-token leaf [L, 1, W, heads, width] at
+    positions [start, start+W) (one ``dynamic_update_slice`` per leaf), every
+    per-sequence leaf [L, 1, ...] over the row's WHOLE (a prefill leaves nothing
+    of the slot's previous request behind). ``slot``/``start`` are traced
+    scalars: one compiled program regardless of which slot/offset is written."""
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
-    return jax.tree.map(
+    out = jax.tree.map(
         lambda c, w: lax.dynamic_update_slice(c, w.astype(c.dtype), (0, slot, start, 0, 0)),
-        cache, window)
+        _token_leaves(cache), _token_leaves(window))
+    if STATE in cache:
+        out[STATE] = jax.tree.map(
+            lambda c, w: lax.dynamic_update_slice(c, w.astype(c.dtype),
+                                                  (0, slot) + (0,) * (c.ndim - 2)),
+            cache[STATE], window[STATE])
+    return out
+
+
+def fresh_cache_state(cache, fresh):
+    """``cache`` with its per-sequence leaves zeroed where ``fresh`` (a traced
+    bool scalar): a sequence's first chunk starts from nothing, whatever the
+    slot's last request left. A tree without such leaves comes back as it is."""
+    if STATE not in cache:
+        return cache
+    return {**cache, STATE: jax.tree.map(
+        lambda c: jnp.where(fresh, jnp.zeros((), c.dtype), c), cache[STATE])}
 
 
 def cached_attention(q, k_cache, v_cache, pos, *, bias=None):
@@ -1443,7 +1900,7 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
 
     def attend(q, k, v, stacks, l, lp):
         k_stack, v_stack = write(stacks["k"], l, k), write(stacks["v"], l, v)
-        stacks = {"k": k_stack, "v": v_stack}
+        stacks = {**stacks, "k": k_stack, "v": v_stack}  # a mixer's state rides along
         if use_decode_kernel:
             attn = decode_attention(q[:, 0], k_stack, v_stack, pos, layer=l)[:, None]
             return attn, stacks
@@ -1457,6 +1914,9 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
             if cfg.kv_lora_rank:
                 return _latent_attention(cfg, lp, q, k_l, v_l, pos), stacks
         if use_flash:
+            if k_l.shape[2] != q.shape[2]:  # grouped heads: the kernel takes one K/V head a
+                group = q.shape[2] // k_l.shape[2]  # query head; the BLOCK's own, not the cache's
+                k_l, v_l = jnp.repeat(k_l, group, axis=2), jnp.repeat(v_l, group, axis=2)
             attn = flash_attention_sharded(q, k_l, v_l, mesh=_ACTIVE_MESH[0], causal=True,
                                            alibi_slopes=slopes)
         else:
@@ -1469,7 +1929,7 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
 def apply_with_cache(
     cfg: TransformerConfig, params: Params, tokens, cache, pos,
     last_only: bool = False, last_index=None, write_pos=None,
-    return_routing: bool = False,
+    return_routing: bool = False, live=None,
 ):
     """tokens [B, T] entering at absolute position ``pos`` -> (logits, updated
     cache). Serves prefill (T=prompt) and decode (T=1). With ``last_only``
@@ -1496,7 +1956,15 @@ def apply_with_cache(
     as the loop's carry. With ``return_routing`` (dropless routing only) a third
     value comes back: the experts chosen for each of the tokens given, in every
     routed layer, int32 [layers, B, T, k] — padded and idle rows are routed like
-    any other, so a caller that counts load masks them itself."""
+    any other, so a caller that counts load masks them itself.
+
+    ``live`` [B, T] bool (None: every row): the rows that are a sequence's own,
+    leading each row of the batch — not a bucket's padding, not an idle slot's
+    ride-along token. Attention needs no such mask (causality hides the padded
+    tail and ``write_pos`` drops an idle row's write); a state-space mixer does:
+    a recurrence has no causality to hide behind, so its state moves on live
+    rows only (``_ssm_mixer``), and a padded block (``last_index``) without the
+    mask is refused for it."""
     _routing_asked(cfg, return_routing)
     if not cfg.causal:
         raise NotImplementedError("KV-cache decoding is causal-only (encoders use apply())")
@@ -1510,12 +1978,18 @@ def apply_with_cache(
             "silently change the attention pattern the model trained with"
         )
     B, T = tokens.shape
+    if live is None and last_index is not None and cfg.ssm_state_size:
+        raise ValueError(
+            "apply_with_cache(last_index=...) pads the block past its live last token: a "
+            "state-space mixer needs `live` (the rows that are the sequence's own), or its "
+            "state runs on over the padding")
     params = _stream_top_level(cfg, params)
     positions, attend = _cache_attention(cfg, B, T, cache_len(cache), pos, write_pos)
     x, _ = embed(cfg, params, tokens, positions)
     x, cache, _, chosen = _layer_loop(
         cfg, params["layers"], params.get("moe"), x, dict(cache),
-        positions=positions, attend=attend, decode=T == 1, lead=params.get("dense_ffn"))
+        positions=positions, attend=attend, decode=T == 1, lead=params.get("dense_ffn"),
+        live=live)
     if last_index is not None:
         # bucketed prefill: the live last token sits at ``last_index``
         # (prompt_len - 1), not at T-1 — project only that position
@@ -1571,6 +2045,8 @@ def lm_loss_from_hidden(cfg: TransformerConfig, params: Params, hidden, labels,
     never materialized (see ``causal_lm_loss``). Shared by the plain and
     pipelined model families."""
     head = _head_matrix(params, (lambda t: t) if _top_streamed else _param_streamer(cfg))
+    # the logits' multiplier, on the narrow side
+    hidden = _times(hidden, cfg.multiplier("lm_head_multiplier"))
 
     _n_rows = hidden.shape[0] * hidden.shape[1]
     _impl, _reason = effective_loss_impl(cfg, n_rows=_n_rows)

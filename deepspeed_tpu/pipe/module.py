@@ -234,6 +234,7 @@ class PipelinedTransformer(Model):
                 "moe_first_dense under pipeline parallelism is not wired up: the leading dense "
                 "layers' stacks (params['dense_ffn']) belong to the first stage alone, and "
                 "every stage is handed an equal slice of 'layers' and 'moe'")
+        tfm.refuse_in_pipeline(cfg)  # both schedules run this model's stages
         super().__init__(cfg, loss_fn=None)
         self.num_stages = num_stages
         self.num_micro_batches = num_micro_batches

@@ -229,6 +229,61 @@ def test_decode_program_keeps_the_slot_cache_in_place(family, v5e, no_persistent
     assert ma.temp_size_in_bytes < layer_bytes, (ma.temp_size_in_bytes, layer_bytes)
 
 
+def _falcon_h1_cfg(L, Smax):
+    """Falcon-H1-34B's block at its published widths (a Mamba-2 mixer of 32 heads x
+    128 with a state of 256 in 2 groups beside 20 / 4 grouped heads of 128, a gated
+    MLP of 21504), ``L`` layers and a vocabulary of 1024."""
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=1024, max_seq_len=Smax, num_layers=L, num_heads=20, num_kv_heads=4,
+        qk_head_dim=128, hidden_size=5120, intermediate_size=21504, pos_emb="rotary",
+        rotary_base=1e11, tie_embeddings=False, use_bias=False, norm_kind="rms",
+        activation="swiglu", decode_attn="xla", ssm_state_size=256, ssm_heads=32,
+        ssm_head_dim=128, ssm_groups=2, ssm_conv_kernel=4, ssm_chunk_size=128,
+        multipliers={"key_multiplier": 0.011, "ssm_multipliers": [0.35, 0.25, 0.18, 0.5, 0.35],
+                     "mlp_multipliers": [0.18, 0.011]}, dtype=jnp.bfloat16)
+
+
+def test_decode_program_keeps_the_recurrent_state_in_place(v5e, no_persistent_cache, as_tpu):
+    """``SlotWorker``'s decode step at Falcon-H1-34B's widths (4 layers, 16 slots x
+    512): the float32 state stack [L, slots, 32, 128, 256] (268 MB here, 1.07 GB
+    in the cell) rides in the layer loop's carry with K/V, donated in and aliased
+    out; layer l of it is read, advanced by the one-step recurrence and written
+    back where it lies. The compiled program holds no second copy of the stack
+    and nothing of one layer's size beside it."""
+    L, n, Smax = 4, 16, 512
+    cfg = _falcon_h1_cfg(L, Smax)
+    worker, params, cache, sds = _bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0]))
+    assert cache["k"].shape == (L, n, Smax, 4, 128)  # the K/V heads, not the 20 query heads
+    compiled = _compile_decode(worker, params, cache, n, sds)
+    text = compiled.as_text()
+    whole = re.escape(f"f32[{L},{n},32,128,256]")
+    copies = re.findall(rf"^\s*%?[\w.-]+ = {whole}\S* copy\(", text, re.M)
+    assert not copies, f"the decode step copies the whole state stack: {copies}"
+    ma = compiled.memory_analysis()
+    layer_state = n * 32 * 128 * 256 * 4
+    kv = 2 * L * n * Smax * 4 * 128 * 2
+    assert ma.alias_size_in_bytes >= L * layer_state + kv  # donated in, aliased out
+    assert ma.temp_size_in_bytes < layer_state, (ma.temp_size_in_bytes, layer_state)
+
+
+def test_prefill_scan_forms_no_pairs_by_state_temporary(v5e, no_persistent_cache, as_tpu):
+    """The 1024-row prefill at Falcon-H1-34B's widths (4 layers): the chunked scan
+    keeps its pairs as [chunks, heads, 128, 128] and its states as [chunks, heads,
+    128, 256]; the form that multiplies them out (``transformers``' fallback:
+    [chunks, 128, 128, heads, 256] float32 = 4.3 GB at 1024 rows) would not fit
+    beside the model, and a tenth of it would fail here. B and C stay at their 2
+    groups: nothing [rows, 32 heads, 256] is made of them."""
+    L, n, Smax, rows = 4, 8, 2048, 1024
+    cfg = _falcon_h1_cfg(L, Smax)
+    worker, params, cache, sds = _bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0]))
+    compiled = _compile_prefill(worker, params, cache, rows, sds)
+    multiplied_out = (rows // 128) * 128 * 128 * 32 * 256 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < multiplied_out // 10
+    assert not re.findall(rf"(?:f32|bf16)\[1,{rows},32,256\]", compiled.as_text())
+
+
 @pytest.mark.parametrize("family,L", [("bloom_dense_alibi", 24), ("pythia_pallas_kernel", 24),
                                       ("olmoe_dropless", 4)])
 def test_serving_programs_cast_no_stacked_weight(family, L, v5e, no_persistent_cache, as_tpu):

@@ -499,6 +499,19 @@ _AGREE_CASES["sequential-latent_lead1_sigmoid_shared-rms"] = dict(
     dense_intermediate_size=48, moe_score_fn="sigmoid", moe_select_bias=True,
     moe_norm_topk_prob=True, moe_routed_scale=2.5, moe_shared_size=24)
 
+# the third residual form: a state-space mixer beside grouped-query attention (4 query / 2 K/V
+# heads of 12, not hidden / heads) on the same normed input, then a sequential gated
+# feed-forward, every multiplier other than 1; the mixer's state rides in the cache tree
+_AGREE_CASES["mixer_beside_grouped_attention-gated-rms"] = dict(
+    norm_kind="rms", use_bias=False, activation="swiglu", intermediate_size=48, num_kv_heads=2,
+    qk_head_dim=12, tie_embeddings=False, ssm_state_size=8, ssm_heads=4, ssm_head_dim=8,
+    ssm_groups=2, ssm_chunk_size=4,
+    multipliers={"embedding_multiplier": 2.0, "attention_in_multiplier": 0.5,
+                 "key_multiplier": 0.25, "attention_out_multiplier": 0.3,
+                 "ssm_in_multiplier": 0.5, "ssm_multipliers": [0.35, 0.25, 0.18, 0.5, 0.35],
+                 "ssm_out_multiplier": 0.2, "mlp_multipliers": [0.4, 0.1],
+                 "lm_head_multiplier": 0.125})
+
 
 def _agree_model(fields):
     from deepspeed_tpu.models import transformer as tfm
