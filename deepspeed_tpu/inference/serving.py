@@ -337,6 +337,9 @@ class SlotWorker:
         # what ONE decode step must read and write of per-sequence state, a live
         # row: every layer's leaves, once each way (0 for a model without)
         self.state_bytes_per_slot = self.cfg.num_layers * tfm.cache_state_bytes(self.cfg)
+        # where the programs read a routed layer's expert banks from ("in_place" /
+        # "sliced"; None for a model without dropless routing): the rule they trace by
+        self.expert_bank = tfm.expert_bank_form(self.cfg, self.params.get("moe"), self.mesh)
 
         # prefix pool: the slot cache's sibling — same [L, slots, len, H, Dh]
         # layout, holding cached prompt prefixes instead of live sequences
@@ -660,15 +663,18 @@ class SlotWorker:
         return {"state_rows": n_active,
                 "state_bytes": 2 * n_active * self.state_bytes_per_slot}
 
-    def _scan_attrs(self, rows: int, live: int) -> dict:
-        """What a prefill or chunk span says of a state-space mixer's scan
-        (nothing for a model without): the chunks it ran (those of the bucket's
-        padding among them) and ``state_rows``, the live rows that moved the
-        state."""
-        if not self.state_bytes_per_slot:
-            return {}
-        return {"scan_chunks": -(-int(rows) // self.cfg.ssm_chunk_size),
-                "state_rows": int(live)}
+    def _block_attrs(self, rows: int, live: int) -> dict:
+        """What a prefill or chunk span says of the block its program was traced
+        with. A state-space mixer's scan: the chunks it ran (those of the
+        bucket's padding among them) and ``state_rows``, the live rows that moved
+        the state. A routed model's ``expert_bank``: where the program reads
+        layer l of the three banks from (``expert_bank_form``). Nothing for a
+        model with neither."""
+        attrs = {"expert_bank": self.expert_bank} if self.expert_bank else {}
+        if self.state_bytes_per_slot:
+            attrs.update(scan_chunks=-(-int(rows) // self.cfg.ssm_chunk_size),
+                         state_rows=int(live))
+        return attrs
 
     def _sampler_rows(self, sp, temperature, top_k, top_p):
         """The sampler operands of a call as its program gets them ([rows]
@@ -802,7 +808,7 @@ class SlotWorker:
         # cache is the bucket long: ``_build_prefill``)
         with tm.span("prefill", uid=uid, slot=slot, bucket=bucket, true_len=true_len,
                      attn=tfm.cache_attention_form(self.cfg.num_heads, 1, bucket, bucket),
-                     **self._scan_attrs(bucket, true_len)) as sp:
+                     **self._block_attrs(bucket, true_len)) as sp:
             with tm.span("dispatch"):
                 rows = self._sampler_rows(sp, temperature, top_k, top_p)
                 if bucket not in self._prefills:
@@ -839,7 +845,7 @@ class SlotWorker:
         tm = self.telemetry
         with tm.span("chunk", uid=uid, slot=slot, width=width, live=live, fetch=fetch,
                      cached_tokens=int(start) + int(live),
-                     attn=tfm.cache_step_form(self.cfg), **self._scan_attrs(width, live)) as sp:
+                     attn=tfm.cache_step_form(self.cfg), **self._block_attrs(width, live)) as sp:
             with tm.span("dispatch"):
                 rows = self._sampler_rows(sp, temperature, top_k, top_p)
                 prog = self._chunk_prog(width)

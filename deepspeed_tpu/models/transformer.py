@@ -1427,7 +1427,8 @@ def _remat_wrapper(cfg: TransformerConfig):
 
 
 def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, attend,
-                per_layer=None, wrap=None, decode: bool = False, lead=None, live=None):
+                per_layer=None, wrap=None, decode: bool = False, lead=None, live=None,
+                banks_in_place: bool = False):
     """THE layer loop: ``_block`` over the stacked ``layers`` [L, ...] (the
     whole model's, or one pipeline stage's slice), ``moe`` the routed layers'
     stacks or None -> (x, state, summed aux loss, experts chosen or None).
@@ -1443,10 +1444,22 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
     (``params["dense_ffn"]`` or None): the gated feed-forward stacks of the
     ``moe_first_dense`` leading layers, which run in a scan of their own before
     the periods; ``moe`` then holds the stacks of the layers after them.
-    ``live``: the block's (the rows a state-space mixer may move on)."""
+    ``live``: the block's (the rows a state-space mixer may move on).
+    ``banks_in_place`` (a forward-only caller's, by ``expert_bank_form``): the
+    three expert banks of dropless routing are not scanned; the bodies close
+    over the held stacks and the scan carries, in their place, the routed
+    layer's position in the ``moe`` stacks (NOT the model's layer number: a
+    leading dense layer or a period of several shifts it), which the routed
+    feed-forward reads the banks through (``moe/dropless.py``). A backward
+    pass wants the scanned slice (its cotangent is one layer's), so training
+    and the pipeline stages leave it off."""
     layers_xs, load_layer = _make_stack_loader(cfg, layers)
     routed = cfg.moe_every > 0 and moe is not None
     moe_xs, load_moe = _make_stack_loader(cfg, moe) if routed else (None, None)
+    if routed and banks_in_place:
+        banks = moe["experts"]
+        n_routed = jax.tree.leaves(banks)[0].shape[0]
+        moe_xs = {**moe_xs, "experts": jnp.arange(n_routed, dtype=jnp.int32)}
     L = jax.tree.leaves(layers_xs)[0].shape[0]
     xs = (layers_xs, jnp.arange(L, dtype=jnp.int32), per_layer or {})
     unroll = max(1, cfg.scan_unroll)
@@ -1496,8 +1509,11 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
         if not routed:
             return carry, None
         lp, l, gates = jax.tree.map(lambda a: a[n_dense], lxs)
+        moe_l, bank_layer = load_moe(moe_p), None
+        if banks_in_place:  # the scan carried this layer's position where its banks would be
+            moe_l, bank_layer = {**moe_l, "experts": banks}, moe_l["experts"]
         return block(carry, load_layer(lp), l, **gates,
-                     ffn=lambda lp, h: _moe_ffn(cfg, load_moe(moe_p), h, decode))
+                     ffn=lambda lp, h: _moe_ffn(cfg, moe_l, h, decode, bank_layer))
 
     G, tail = divmod(L, period)
     if period == 1 and not routed:  # a period of one dense layer is the layer
@@ -1617,17 +1633,19 @@ def _routing_asked(cfg, return_routing: bool) -> None:
             "choices as [T, E, C] one-hot tensors, and a dense model has none")
 
 
-def _moe_ffn(cfg, moe_p, h, decode: bool = False):
+def _moe_ffn(cfg, moe_p, h, decode: bool = False, bank_layer=None):
     """The routed feed-forward of one layer on h [B, T, d] -> (out, load-
     balancing loss, experts chosen [B, T, k] or None). GShard routing keeps
     its capacity semantics except at a single-token ``decode`` step, where
     the capacity heuristic degenerates to ~1 slot and drops colliding tokens;
-    dropless routing is one function everywhere."""
+    dropless routing is one function everywhere. ``bank_layer`` (dropless
+    routing, ``_layer_loop(banks_in_place=True)``): ``moe_p["experts"]`` are the
+    held stacks and this the layer's position in them."""
     with jax.named_scope("moe"):
         if cfg.moe_routing == "dropless":
             from ..moe.dropless import moe_ffn_dropless
 
-            return moe_ffn_dropless(cfg, moe_p, h)
+            return moe_ffn_dropless(cfg, moe_p, h, bank_layer)
         from ..moe.layer import moe_ffn_apply, moe_ffn_dense
 
         if decode:
@@ -1822,6 +1840,41 @@ def cache_step_form(cfg: TransformerConfig) -> str:
     return "latent" if cfg.kv_lora_rank else "dense"
 
 
+def expert_bank_form(cfg: TransformerConfig, moe, mesh=None):
+    """``"in_place"`` or ``"sliced"`` (None for a model without dropless
+    routing): where a program that only runs the forward pass through the cache
+    reads a routed layer's three expert banks from, decided from what it is
+    handed. In place (``_layer_loop(banks_in_place=True)``: layer ``l`` read
+    through the grouped GEMM's own group index out of the held ``[L, E, K, N]``
+    stacks, no per-layer copy) unless that would cost more than the copy it
+    saves: a bank leaf that is not a plain array of the compute dtype (a
+    float32-held or quantised ``{"q" | "q4", "s"}`` leaf would be cast or
+    dequantised WHOLE, L times one layer's), stacks that ``param_offload``
+    streams a slice at a time, or stacks sharded over a mesh axis by the rules
+    ``InferenceEngine`` places them by (``[L, E]`` viewed as ``[L * E]`` keeps no
+    ``expert`` sharding). ``apply_with_cache`` traces by this on the process's
+    active mesh (``Model.set_mesh``, as the sharded attention paths read it) and
+    ``SlotWorker`` labels its ``prefill`` and ``chunk`` spans by it on its
+    engine's (``expert_bank``)."""
+    if cfg.moe_routing != "dropless" or moe is None:
+        return None
+    bank = moe["experts"]
+    plain = all(not isinstance(leaf, dict) and leaf.dtype == jnp.dtype(cfg.dtype)
+                for leaf in bank.values())
+    if cfg.param_offload or not plain:
+        return "sliced"
+    mesh = mesh if mesh is not None else _ACTIVE_MESH[0]
+    if mesh is not None and mesh.size > 1:
+        from ..moe.dropless import dropless_logical_axes
+        from ..parallel.sharding import DEFAULT_TP_RULES, spec_from_logical
+
+        axes = dropless_logical_axes()["experts"]
+        if any(tuple(spec_from_logical(axes[name], leaf.shape, DEFAULT_TP_RULES, mesh))
+               for name, leaf in bank.items()):
+            return "sliced"
+    return "in_place"
+
+
 def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, write_pos=None):
     """-> (positions [B, T], the block's ``attend``) for T new tokens entering a
     stacked [L, B, Smax, H, Dh] cache tree at ``pos`` (scalar, or [B] with
@@ -1953,7 +2006,9 @@ def apply_with_cache(
     caller).
 
     The layers are training's (``_layer_loop`` over ``_block``), with the cache
-    as the loop's carry. With ``return_routing`` (dropless routing only) a third
+    as the loop's carry; a routed model's expert banks are read in place out of
+    the held stacks where ``expert_bank_form`` allows (no backward pass here
+    wants a layer's slice). With ``return_routing`` (dropless routing only) a third
     value comes back: the experts chosen for each of the tokens given, in every
     routed layer, int32 [layers, B, T, k] — padded and idle rows are routed like
     any other, so a caller that counts load masks them itself.
@@ -1989,7 +2044,7 @@ def apply_with_cache(
     x, cache, _, chosen = _layer_loop(
         cfg, params["layers"], params.get("moe"), x, dict(cache),
         positions=positions, attend=attend, decode=T == 1, lead=params.get("dense_ffn"),
-        live=live)
+        live=live, banks_in_place=expert_bank_form(cfg, params.get("moe")) == "in_place")
     if last_index is not None:
         # bucketed prefill: the live last token sits at ``last_index``
         # (prompt_len - 1), not at T-1 — project only that position

@@ -29,6 +29,20 @@ one has no capacity and no ``[T, E, C]`` tensor at any skew:
 
 Experts are ``down(silu(gate(x)) * up(x))``, stacked ``[layers, E, ...]`` under
 the ``expert`` logical axis so a mesh shards them like the GShard bank.
+
+Where the bank is read from (PR 34). Both forms take the three banks either as
+ONE layer's ``[E, K, N]`` leaves (training, evaluation, the pipeline stages: the
+layer loop scans the stacks and hands over the slice, whose cotangent a backward
+pass needs and which ``param_offload`` streams) or as the HELD stacks
+``[L, E, K, N]`` with ``layer``, the routed layer's traced position in them (the
+serving programs: ``transformer.expert_bank_form`` says when). In the second case
+``experts_sorted`` views each stack as ``[L * E, K, N]`` (a reshape of a contiguous
+array: nothing moves) and counts the pairs into groups ``layer * E + expert``, so
+the grouped-GEMM kernel reads layer ``layer`` through its own group index and
+every other layer's groups are empty (an empty group gets no tile): no
+``[E, K, N]`` copy of the layer is made for the kernel's operand, which was a
+fifth of a routed prefill (PERF.md §6, PR 34). ``experts_dense`` takes the layer
+inside its einsums' operands, where XLA fuses the slice as it does a scanned one.
 """
 
 from __future__ import annotations
@@ -140,15 +154,21 @@ def _gated(gate, up):
     return jax.nn.silu(gate) * up
 
 
-def experts_sorted(bank, x, weights, experts):
+def experts_sorted(bank, x, weights, experts, layer=None):
     """x [T, M] through the chosen experts: pairs sorted by expert, grouped
-    matmuls over the groups as they are (no capacity, no padding to one)."""
+    matmuls over the groups as they are (no capacity, no padding to one).
+    ``bank``: one layer's ``[E, K, N]`` leaves or, with ``layer`` (a traced
+    index), the held stacks ``[L, E, K, N]``, read in place as ``L * E`` groups
+    of which only layer ``layer``'s hold a pair."""
     T, M = x.shape
     k = experts.shape[1]
-    E = bank["wi"].shape[0]
     flat = experts.reshape(T * k)
     order = jnp.argsort(flat)  # pairs by expert; pair p is token p // k
-    sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+    if layer is not None:  # [L, E, K, N] as L * E groups: layer ``layer``'s alone are filled
+        L, E = bank["wi"].shape[:2]
+        flat = layer * E + flat
+        bank = {name: leaf.reshape((L * E,) + leaf.shape[2:]) for name, leaf in bank.items()}
+    sizes = jnp.zeros((bank["wi"].shape[0],), jnp.int32).at[flat].add(1)
     xs = x[order // k]
     w = {name: leaf.astype(x.dtype) for name, leaf in bank.items()}
     h = _gated(lax.ragged_dot(xs, w["wg"], sizes), lax.ragged_dot(xs, w["wi"], sizes))
@@ -157,10 +177,13 @@ def experts_sorted(bank, x, weights, experts):
     return jnp.einsum("tkm,tk->tm", ys.astype(jnp.float32), weights).astype(x.dtype)
 
 
-def experts_dense(bank, x, weights, experts):
+def experts_dense(bank, x, weights, experts, layer=None):
     """Every expert on every row of x [T, M]; the weights of those not chosen
-    are zero."""
+    are zero. ``bank`` and ``layer`` as ``experts_sorted`` takes them."""
     T = x.shape[0]
+    if layer is not None:
+        bank = {name: lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False)
+                for name, leaf in bank.items()}
     E = bank["wi"].shape[0]
     w = {name: leaf.astype(x.dtype) for name, leaf in bank.items()}
     h = _gated(jnp.einsum("tm,emf->etf", x, w["wg"]), jnp.einsum("tm,emf->etf", x, w["wi"]))
@@ -175,15 +198,17 @@ def shared_expert(w, x):
     return _gated(x @ w["wg"], x @ w["wi"]) @ w["wo"]
 
 
-def moe_ffn_dropless(cfg, moe_p, h):
-    """h [B, S, M] -> (out [B, S, M], load-balancing loss, experts [B, S, k])."""
+def moe_ffn_dropless(cfg, moe_p, h, layer=None):
+    """h [B, S, M] -> (out [B, S, M], load-balancing loss, experts [B, S, k]).
+    ``moe_p``: one routed layer's leaves; with ``layer`` its ``experts`` are the
+    held stacks of every routed layer and ``layer`` this one's position in them."""
     B, S, M = h.shape
     x = h.reshape(B * S, M)
     weights, experts, probs = route(
         x, moe_p["gate"], cfg.moe_top_k, cfg.moe_norm_topk_prob, score_fn=cfg.moe_score_fn,
         select_bias=moe_p.get("bias"), scale=cfg.moe_routed_scale)
     form = experts_dense if B * S <= DENSE_ROWS else experts_sorted
-    out = form(moe_p["experts"], x, weights, experts)
+    out = form(moe_p["experts"], x, weights, experts, layer)
     if "shared" in moe_p:
         out = out + shared_expert(moe_p["shared"], x)
     return (out.reshape(B, S, M), load_balance_loss(probs, experts),

@@ -292,15 +292,21 @@ def test_serving_programs_cast_no_stacked_weight(family, L, v5e, no_persistent_c
     1024), on operands typed as ``InferenceEngine`` holds them
     (``hold_for_compute``): no ``convert`` yields a whole stacked weight; the
     decode step's temporaries are under the bf16 bytes of the largest stacked
-    leaf, and the prefill's (its local K/V and, for OLMoE, layer l of each
-    expert bank sliced out for the grouped-GEMM kernel; no score matrix since
-    PR 30) under a third of those of all the stacks together. On
-    ``model.init``'s float32 operands, which the engine held before PR 28, all
-    of it fails: every program casts
-    every stack (twelve converts for BLOOM, the three expert banks for OLMoE)
-    and carries the bf16 copies as temporaries on top of the rest (2.42 GB of
-    the decode step's 2.42 and of the prefill's 3.79 for BLOOM, 3.26 GB of
-    3.26 and of 3.85 for OLMoE-L4)."""
+    leaf, and the prefill's (its local K/V; no score matrix since PR 30) under
+    a third of those of all the stacks together. OLMoE's prefill reads layer l
+    of each expert bank IN PLACE, out of the held ``[L, 64, ...]`` stack through
+    the grouped-GEMM kernel's group index (PR 34: ``expert_bank_form``,
+    ``moe/dropless.py``): it defines no value of one layer's bank, and its
+    temporaries are its activations alone (315 MB: the local K/V, 67 MB, and the
+    16,384 pairs' gathered rows, gate, up and down products at 67 MB each),
+    held under 1.25 x ONE layer of ONE bank (268 MB), where the slice copied out
+    for the kernel's operand made them 633 MB; its decode step takes the layer
+    inside the dense form's GEMM fusions, as the scanned slice was taken. On ``model.init``'s
+    float32 operands, which the engine held before PR 28, all of it fails: every
+    program casts every stack (twelve converts for BLOOM, the three expert
+    banks for OLMoE) and carries the bf16 copies as temporaries on top of the
+    rest (2.42 GB of the decode step's 2.42 and of the prefill's 3.79 for
+    BLOOM, 3.26 GB of 3.26 and of 3.85 for OLMoE-L4)."""
     n, Smax = 8, 2048
     cfg = _family_cfg(family, L, Smax)
     worker, params, cache, sds = _bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0]))
@@ -308,14 +314,21 @@ def test_serving_programs_cast_no_stacked_weight(family, L, v5e, no_persistent_c
               if x.ndim >= 3 and x.shape[0] == L and x.dtype == jnp.bfloat16]
     nbytes = [int(np.prod(shape)) * 2 for shape in stacks]
     shapes = "|".join(re.escape(",".join(map(str, shape))) for shape in sorted(set(stacks)))
+    routed = family == "olmoe_dropless"
+    bank_layer = 64 * 2048 * 1024 * 2  # one layer of one expert bank, bf16
     for name, compiled, bound in (
             ("decode", _compile_decode(worker, params, cache, n, sds), max(nbytes)),
-            ("prefill", _compile_prefill(worker, params, cache, Smax, sds), sum(nbytes) // 3)):
-        casts = re.findall(rf"^\s*%?[\w.-]+ = \w+\[(?:{shapes})\]\S* convert\(",
-                           compiled.as_text(), re.M)
+            ("prefill", _compile_prefill(worker, params, cache, Smax, sds),
+             bank_layer * 5 // 4 if routed else sum(nbytes) // 3)):
+        text = compiled.as_text()
+        casts = re.findall(rf"^\s*%?[\w.-]+ = \w+\[(?:{shapes})\]\S* convert\(", text, re.M)
         assert not casts, f"the {name} program casts a whole stacked weight: {casts}"
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < bound, (name, temp, bound)
+        if routed and name == "prefill":
+            sliced = re.findall(r"^\s*%?[\w.-]+ = bf16\[64,(?:2048,1024|1024,2048)\]", text, re.M)
+            assert not sliced, f"the prefill copies a layer of an expert bank out: {sliced}"
+            assert len(re.findall(r"^\s*%?ragged-dot[\w.-]* = bf16\[16384,", text, re.M)) == 3
 
 
 @pytest.mark.parametrize("family,L", [("bloom_dense_alibi", 24), ("pythia_pallas_kernel", 24),
@@ -498,6 +511,54 @@ def test_dropless_expert_block_at_olmoe_widths(rows, grouped, v5e, no_persistent
     assert not re.findall(r"= \w+\[64,(?:2048,1024|1024,2048)\]\S* convert\(", text)
     pairs = rows * 8 * 2048 * 2  # the gathered rows of every token-expert pair, bf16
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * pairs + 2 ** 26
+
+
+def test_dropless_expert_block_reads_the_held_stacks_in_place(v5e, no_persistent_cache):
+    """The routed block of four OLMoE layers in one scan, 2048 rows, the three banks
+    handed over as the held ``[4, 64, ...]`` stacks with the layer's index
+    (``moe_ffn_dropless(..., layer)``): the grouped-GEMM kernel is still called three times
+    an iteration, on the stack itself viewed as 256 groups; the loop body defines no value
+    of one layer's bank (the scanned slice makes three: the slice copied out for the
+    kernel's operand, ``dynamic-slice_bitcast_fusion`` ``bf16[64,2048,1024]``, 3.27 ms each
+    on the chip) and its temporaries are the block's activations (the gathered rows of
+    the 16,384 pairs, 67 MB), a third of one layer's bank at most and a bank (268 MB)
+    less than the scanned form's."""
+    from deepspeed_tpu.models.transformer import TransformerConfig
+    from deepspeed_tpu.moe.dropless import moe_ffn_dropless
+
+    L, rows = 4, 2048
+    cfg = TransformerConfig(hidden_size=2048, intermediate_size=1024, num_experts=64, moe_top_k=8,
+                            moe_routing="dropless", activation="swiglu", moe_every=1,
+                            dtype=jnp.bfloat16)
+    one_chip = SingleDeviceSharding(v5e[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    bank = {"wg": (L, 64, 2048, 1024), "wi": (L, 64, 2048, 1024), "wo": (L, 64, 1024, 2048)}
+    moe = {"gate": sds((L, 2048, 64), jnp.float32),
+           "experts": {k: sds(shape, jnp.bfloat16) for k, shape in bank.items()}}
+
+    def in_place(moe, h):
+        def body(h, xs):
+            gate, l = xs
+            moe_l = {"gate": gate, "experts": moe["experts"]}
+            return h + moe_ffn_dropless(cfg, moe_l, h, l)[0], None
+        return jax.lax.scan(body, h, (moe["gate"], jnp.arange(L, dtype=jnp.int32)))[0]
+
+    def scanned(moe, h):
+        return jax.lax.scan(lambda h, moe_l: (h + moe_ffn_dropless(cfg, moe_l, h)[0], None),
+                            h, moe)[0]
+
+    h = sds((1, rows, 2048), jnp.bfloat16)
+    bank_layer = 64 * 2048 * 1024 * 2
+    layer_of_a_bank = r"^\s*%?[\w.-]+ = bf16\[64,(?:2048,1024|1024,2048)\]"
+    temps = {}
+    for fn, slices in ((in_place, 0), (scanned, 3)):
+        compiled = jax.jit(fn).lower(moe, h).compile()
+        text = compiled.as_text()
+        assert len(re.findall(r"^\s*%?ragged-dot[\w.-]* = bf16\[16384,", text, re.M)) == 3
+        assert len(re.findall(layer_of_a_bank, text, re.M)) == slices, fn.__name__
+        temps[fn.__name__] = compiled.memory_analysis().temp_size_in_bytes
+    assert temps["in_place"] < bank_layer // 3, temps
+    assert temps["scanned"] - temps["in_place"] > 0.9 * bank_layer, temps
 
 
 # ---------------------------------------------------------------------------

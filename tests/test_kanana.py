@@ -288,6 +288,50 @@ def test_the_drawn_selection_bias_changes_a_visible_share_of_the_choices(cfg):
     assert kept > 0.6 * cfg.moe_top_k, kept
 
 
+# -- the expert banks read in place (PR 34): tests/test_olmoe.py's cases at this twin, whose
+# routed layers are the model's layers 1 and 2 and the ``moe`` stacks' 0 and 1 ---------------
+
+import test_olmoe as olmoe_cases  # noqa: E402
+
+
+@pytest.mark.parametrize("pairing", olmoe_cases._PAIRINGS)
+@pytest.mark.parametrize("form", [dropless.experts_sorted, dropless.experts_dense],
+                         ids=["sorted", "dense"])
+def test_the_held_stacks_read_in_place_are_the_layers_slice(cfg, params, form, pairing):
+    olmoe_cases.bank_in_place_is_the_slice(cfg, params["moe"], form, pairing)
+
+
+@pytest.mark.parametrize("rows", [64, 520], ids=["dense_form", "sorted_form"])
+def test_the_stack_index_is_the_routed_layers_not_the_models(cfg, params, rows, monkeypatch):
+    """Behind ONE leading dense layer the model's layer 1 is routed stack 0: the in-place
+    programs give the sliced programs' logits (``same_as``), and with the model's layer
+    number in the index's place they do not (the last routed layer would read past the
+    stacks, the first the second's banks)."""
+    assert cfg.moe_first_dense == 1 and params["moe"]["gate"].shape[0] == cfg.num_layers - 1
+    run = olmoe_cases.cache_pass(cfg, params, rows, monkeypatch)
+    real = dropless.moe_ffn_dropless
+    with monkeypatch.context() as m:
+        m.setattr(dropless, "moe_ffn_dropless",
+                  lambda c, p, h, layer=None: real(c, p, h, None if layer is None else layer + 1))
+        off_by_the_lead = run()[0]
+    logits_s = olmoe_cases.in_place_is_the_sliced_pass(run, rows, monkeypatch)
+    assert float(jnp.max(jnp.abs(off_by_the_lead - logits_s))) > 1e-2
+
+
+def test_training_scans_the_slice_and_the_cache_path_reads_the_stacks(cfg, params, monkeypatch):
+    E, M, F = cfg.num_experts, cfg.hidden_size, cfg.ffn_size
+    routed = cfg.num_layers - cfg.moe_first_dense
+    train, serve = olmoe_cases.banks_by_caller(cfg, params, monkeypatch)
+    assert sorted(train) == [(E, F, M), (E, M, F), (E, M, F)]
+    assert sorted(serve) == [(routed * E, F, M), (routed * E, M, F), (routed * E, M, F)]
+
+
+def test_one_chip_engine_reads_the_banks_in_place_and_serves_the_models_tokens(program,
+                                                                              monkeypatch):
+    olmoe_cases.serves_a_long_prompt_in_place(olmoe_cases.one_chip_engine(program, monkeypatch),
+                                              program["vocab_size"])
+
+
 def _engine(program, dtype, seed=0, n_slots=4, **serving_block):
     return build_serving_engine({
         "model": {**program, "dtype": dtype},

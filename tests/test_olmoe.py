@@ -5,6 +5,7 @@ the CPU, seeded weights, float32 unless a test says bfloat16."""
 
 import json
 import os
+import re
 import sys
 import time
 
@@ -205,6 +206,179 @@ def test_the_two_forms_of_the_expert_block_agree(cfg, params, renormalize):
         assert float(aux) > 0
 
 
+_PAIRINGS = ("as routed", "an expert no pair chose", "all pairs on one expert")
+
+
+def _paired(cfg, moe_l, x, pairing):
+    """(weights, experts) of ``x`` under layer ``moe_l``'s router, bent to the pairing."""
+    weights, experts, _ = dropless.route(x, moe_l["gate"], cfg.moe_top_k, cfg.moe_norm_topk_prob,
+                                         score_fn=cfg.moe_score_fn, select_bias=moe_l.get("bias"),
+                                         scale=cfg.moe_routed_scale)
+    if pairing == "an expert no pair chose":
+        spare = (experts + 1) % cfg.num_experts  # expert 3's pairs go to 4 (a row may hold 4 twice)
+        experts = jnp.where(experts == 3, spare, experts)
+    elif pairing == "all pairs on one expert":
+        experts = jnp.full_like(experts, 5)
+    return weights, experts
+
+
+def same_as(to_the_bit, got, want, what=None):
+    """The dense form indexes the layer inside its einsums: the same bits anywhere. The
+    sorted form's grouped matmul is the same kernel on the same tiles ON THE CHIP (bit for
+    bit there at OLMoE's and kanana's shapes: PERF.md section 6, PR 34); XLA's CPU lowering
+    of ``ragged_dot`` sums over all the groups it is handed, L * E or E, in another order,
+    so here the two agree to float32 rounding (values are O(1); a wrong layer is O(1) off)."""
+    got, want = np.asarray(got), np.asarray(want)
+    if to_the_bit:
+        assert np.array_equal(got, want), what
+    else:
+        assert np.max(np.abs(got - want)) <= 2e-6 * max(1.0, float(np.max(np.abs(want)))), what
+
+
+def bank_in_place_is_the_slice(cfg, moe, form, pairing):
+    """``form`` on the held stacks with the routed layer's index (traced) against ``form``
+    on that layer's slice, for every layer (``same_as``). Shared with tests/test_kanana.py."""
+    x = jax.random.normal(jax.random.PRNGKey(7), (40, cfg.hidden_size))
+    stacks = moe["experts"]
+    n_routed = stacks["wi"].shape[0]
+    assert n_routed >= 2
+    in_place = jax.jit(lambda l, w, e: form(stacks, x, w, e, l))
+    of_slice = jax.jit(lambda bank, w, e: form(bank, x, w, e))  # both compiled: one summation order
+    outs = []
+    for l in range(n_routed):
+        moe_l = jax.tree.map(lambda a: a[l], moe)
+        weights, experts = _paired(cfg, moe_l, x, pairing)
+        if pairing != "as routed":
+            counts = np.bincount(np.asarray(experts).ravel(), minlength=cfg.num_experts)
+            assert (counts[3] == 0) if pairing == _PAIRINGS[1] else (counts[5] == experts.size)
+        sliced = of_slice(moe_l["experts"], weights, experts)
+        got = in_place(jnp.int32(l), weights, experts)
+        same_as(form is dropless.experts_dense, got, sliced, (l, pairing))
+        outs.append(np.asarray(sliced))
+    assert not np.array_equal(outs[0], outs[1])  # the layers differ: an index off by one would show
+
+
+@pytest.mark.parametrize("pairing", _PAIRINGS)
+@pytest.mark.parametrize("form", [dropless.experts_sorted, dropless.experts_dense],
+                         ids=["sorted", "dense"])
+def test_the_held_stacks_read_in_place_are_the_layers_slice(cfg, params, form, pairing):
+    bank_in_place_is_the_slice(cfg, params["moe"], form, pairing)
+
+
+def _ragged_rhs_shapes(jaxpr, out=None):
+    """The shapes of the bank operand of every ``ragged_dot`` of a jaxpr, nested ones too."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("ragged_dot"):
+            out.append(tuple(eqn.invars[1].aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _ragged_rhs_shapes(sub, out)
+    return out
+
+
+def on_one_chip(monkeypatch):
+    """No active mesh for the test's own traces, as on the chip's one device: an engine built
+    earlier in the process over the eight virtual devices leaves its mesh active
+    (``Model.set_mesh``), and it shards the bank, which ``expert_bank_form`` answers with
+    the slice."""
+    monkeypatch.setattr(tfm, "_ACTIVE_MESH", [None])
+
+
+def banks_by_caller(cfg, params, monkeypatch, rows=520):
+    """(bank operands of ``apply``'s grouped GEMMs, of ``apply_with_cache``'s) over
+    ``rows`` > DENSE_ROWS tokens. Shared with tests/test_kanana.py."""
+    on_one_chip(monkeypatch)
+    tokens = _tokens(cfg, (1, rows), 3)
+    long = cfg.replace(max_seq_len=1024)
+    train = jax.make_jaxpr(lambda p: tfm.apply(long, p, tokens))(params)
+    serve = jax.make_jaxpr(lambda p: tfm.apply_with_cache(
+        long, p, tokens, tfm.init_cache(long, 1, rows), 0)[0])(params)
+    return _ragged_rhs_shapes(train.jaxpr), _ragged_rhs_shapes(serve.jaxpr)
+
+
+def test_training_scans_the_slice_and_the_cache_path_reads_the_stacks(cfg, params, monkeypatch):
+    """``apply`` (training, evaluation: a backward pass wants one layer's cotangent) hands the
+    grouped GEMMs layer l's ``[E, K, N]`` slice, as it always has; ``apply_with_cache`` hands
+    them the held stacks as ``[L * E, K, N]``. Bit for bit the same logits either way."""
+    E, M, F, L = cfg.num_experts, cfg.hidden_size, cfg.ffn_size, cfg.num_layers
+    train, serve = banks_by_caller(cfg, params, monkeypatch)
+    assert sorted(train) == [(E, F, M), (E, M, F), (E, M, F)]  # one scanned body
+    assert sorted(serve) == [(L * E, F, M), (L * E, M, F), (L * E, M, F)]
+
+
+def cache_pass(cfg, params, rows, monkeypatch):
+    """-> a call that runs ``rows`` tokens a row through ``apply_with_cache`` from an empty
+    cache (520 rows: the sorted form; 64: the dense) and returns (logits, cache, chosen)."""
+    on_one_chip(monkeypatch)
+    long = cfg.replace(max_seq_len=1024)
+    tokens = _tokens(cfg, (2, rows), 9)
+    assert tfm.expert_bank_form(long, params["moe"]) == "in_place"
+    return lambda: tfm.apply_with_cache(long, params, tokens, tfm.init_cache(long, 2, rows), 0,
+                                        return_routing=True)
+
+
+def in_place_is_the_sliced_pass(run, rows, monkeypatch):
+    """``run`` as it is (the banks in place) against ``run`` with the rule saying ``sliced``:
+    the same logits and cache (``same_as``), the same experts chosen. -> the sliced logits.
+    Both shared with tests/test_kanana.py."""
+    logits, cache, chosen = run()
+    monkeypatch.setattr(tfm, "expert_bank_form", lambda *a, **k: "sliced")
+    logits_s, cache_s, chosen_s = run()
+    to_the_bit = rows <= dropless.DENSE_ROWS
+    same_as(to_the_bit, logits, logits_s)
+    assert np.array_equal(np.asarray(chosen), np.asarray(chosen_s))
+    for name in cache:
+        same_as(to_the_bit, cache[name], cache_s[name], name)
+    return logits_s
+
+
+@pytest.mark.parametrize("rows", [64, 520], ids=["dense_form", "sorted_form"])
+def test_in_place_banks_give_the_sliced_programs_logits(cfg, params, rows, monkeypatch):
+    in_place_is_the_sliced_pass(cache_pass(cfg, params, rows, monkeypatch), rows, monkeypatch)
+
+
+def _mesh(n_devices):
+    """The first ``n_devices`` along ``data`` (``build_mesh`` would also make it the process's
+    current mesh, for the tests that follow)."""
+    from deepspeed_tpu.comm.mesh import AXIS_ORDER
+
+    shape = tuple(n_devices if axis == "data" else 1 for axis in AXIS_ORDER)
+    return jax.sharding.Mesh(np.asarray(jax.devices()[:n_devices]).reshape(shape), AXIS_ORDER)
+
+
+_NOT_IN_PLACE = {
+    "a float32-held bank under bfloat16 compute": lambda cfg, moe: (
+        cfg.replace(dtype=jnp.bfloat16), moe, None),
+    "a quantised bank leaf": lambda cfg, moe: (
+        cfg, {**moe, "experts": {**moe["experts"], "wi": {
+            "q": moe["experts"]["wi"].astype(jnp.int8), "s": jnp.ones((2, 16, 1, 1))}}}, None),
+    "stacks that param_offload streams": lambda cfg, moe: (
+        cfg.replace(param_offload=True), moe, None),
+    "stacks sharded over the mesh": lambda cfg, moe: (cfg, moe, "eight devices"),
+}
+
+
+@pytest.mark.parametrize("case", list(_NOT_IN_PLACE))
+def test_the_bank_is_sliced_where_in_place_would_cost_more(cfg, params, case, monkeypatch):
+    """``expert_bank_form`` on what the program is handed; a float32-held bank is then cast a
+    LAYER at a time inside the scan, never the whole stack."""
+    assert tfm.expert_bank_form(cfg, params["moe"], _mesh(1)) == "in_place"
+    assert tfm.expert_bank_form(cfg.replace(moe_every=0, moe_routing="top1"), None) is None
+    cfg2, moe, mesh = _NOT_IN_PLACE[case](cfg, params["moe"])
+    assert tfm.expert_bank_form(cfg2, moe, _mesh(8 if mesh else 1)) == "sliced"
+    if cfg2.dtype == jnp.bfloat16:
+        on_one_chip(monkeypatch)
+        long = cfg2.replace(max_seq_len=1024)
+        tokens = _tokens(cfg, (1, 520), 3)
+        jaxpr = jax.make_jaxpr(lambda p: tfm.apply_with_cache(
+            long, p, tokens, tfm.init_cache(long, 1, 520, dtype=jnp.bfloat16), 0)[0])(params)
+        E, M, F = cfg.num_experts, cfg.hidden_size, cfg.ffn_size
+        assert sorted(_ragged_rhs_shapes(jaxpr.jaxpr)) == [(E, F, M), (E, M, F), (E, M, F)]
+        whole = re.findall(r"bf16\[%d,%d,\d+,\d+\] = convert_element_type" % (cfg.num_layers, E),
+                           str(jaxpr))
+        assert not whole, whole
+
+
 def test_live_row_counters_ignore_padding_and_idle_slots(cfg, params):
     k, E, L = cfg.moe_top_k, cfg.num_experts, cfg.num_layers
     # prefill: 45 live rows of a 64-row bucket, whatever the padding holds
@@ -263,6 +437,57 @@ def test_serving_engine_serves_and_its_spans_carry_the_load(cfg, program):
     assert int(jnp.argmax(logits[0, -1])) == int(results[0].tokens[0])
 
 
+def one_chip_engine(program, monkeypatch, dtype=jnp.float32, n_slots=2, max_seq_len=1024):
+    """A serving engine on a ONE-device mesh, as the chip's is (``_engine``'s spans the eight
+    virtual devices, which shard the bank over ``data``); the mesh it makes active is put
+    back when the test ends. Shared with tests/test_kanana.py."""
+    from deepspeed_tpu.inference import InferenceEngine
+
+    monkeypatch.setattr(tfm, "_ACTIVE_MESH", [None])  # ``set_mesh`` writes into this one
+    cfg = tfm.TransformerConfig(dtype=dtype, **{**program, "max_seq_len": max_seq_len})
+    engine = InferenceEngine(model=tfm.Model(cfg), mesh=_mesh(1), config={
+        "dtype": {jnp.float32: "fp32", jnp.bfloat16: "bf16"}[dtype]})
+    return serving.ServingEngine(engine, config={"n_slots": n_slots, "max_seq_len": max_seq_len,
+                                                 "seed": 0})
+
+
+def serves_a_long_prompt_in_place(srv, vocab):
+    """A 600-token prompt (the 1024-row bucket: the sorted form) then four decode steps (the
+    dense form) through ``srv``: every prefill span says ``expert_bank: in_place``, no decode
+    span carries the attribute, and the greedy tokens are ``apply``'s own, which scans the
+    slice. Shared with tests/test_kanana.py."""
+    cfg, held = srv.engine.cfg, srv.engine.params
+    assert srv.worker.expert_bank == "in_place"
+    prompt = np.random.default_rng(11).integers(0, vocab, size=600).astype(np.int32)
+    t0 = time.perf_counter()
+    tokens = srv.serve([serving.Request(uid=0, prompt=prompt, max_new_tokens=5)])[0].tokens
+    spans = [sp for sp in tracing.spans(t0) if sp.name in ("prefill", "decode")]
+    prefills = [sp for sp in spans if sp.name == "prefill"]
+    assert [sp.attrs["bucket"] for sp in prefills] == [1024]
+    assert all(sp.attrs["expert_bank"] == "in_place" for sp in prefills)
+    assert len(spans) > 1 and not any("expert_bank" in sp.attrs for sp in spans
+                                      if sp.name == "decode")
+    seen = list(prompt)
+    for tok in tokens:
+        logits = tfm.apply(cfg, held, np.asarray(seen, np.int32)[None])
+        assert int(jnp.argmax(logits[0, -1])) == int(tok)
+        seen.append(int(tok))
+
+
+def test_one_chip_engine_reads_the_banks_in_place_and_serves_the_models_tokens(program,
+                                                                              monkeypatch):
+    serves_a_long_prompt_in_place(one_chip_engine(program, monkeypatch), program["vocab_size"])
+
+
+def test_an_engine_over_a_mesh_that_shards_the_bank_keeps_the_slice(cfg, program):
+    srv = _engine(program, "float32")  # the eight virtual devices: 16 experts over ``data``
+    assert srv.worker.expert_bank == "sliced"
+    t0 = time.perf_counter()
+    srv.serve([serving.Request(uid=0, prompt=_tokens(cfg, (40,), 2), max_new_tokens=2)])
+    prefills = [sp for sp in tracing.spans(t0) if sp.name == "prefill"]
+    assert prefills and all(sp.attrs["expert_bank"] == "sliced" for sp in prefills)
+
+
 def test_train_batch_takes_two_steps_under_zero1(program):
     model = tfm.Model(tfm.TransformerConfig(dtype=jnp.float32, **program))
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, config={
@@ -273,6 +498,9 @@ def test_train_batch_takes_two_steps_under_zero1(program):
     losses = [float(engine.train_batch(batch)["loss"]) for _ in range(2)]
     assert np.isfinite(losses).all() and losses[1] < losses[0], losses
     assert engine._train_step._cache_size() == 1
+    # training names no bank form: its one form is the scanned slice
+    assert not any("expert_bank" in sp.attrs for sp in tracing.spans(0.0)
+                   if sp.path.startswith("train/"))
 
 
 class _Run:
