@@ -619,12 +619,31 @@ class SlotWorker:
 
     # -- dispatches ------------------------------------------------------
 
-    def _host_bound(self, out: list) -> tuple:
-        """A program's outputs behind the cache -> (those the host fetches, the
-        experts a routed model chose: a device array that no fetch waits for)."""
-        if self.cfg.moe_routing != "dropless":
-            return out, None
-        return out[:-1], out[-1]
+    def _decode_prog(self):
+        if self._decode is None:
+            wd = self.telemetry.watchdog
+            self._decode = wd.watch(
+                self._build_decode(), wd.unique_name("serving/decode"), stable=True)
+        return self._decode
+
+    def _verify_prog(self, depth: int, greedy_only: bool):
+        key = (depth, greedy_only)
+        if key not in self._verifies:
+            wd = self.telemetry.watchdog
+            name = f"serving/verify[{depth}{':greedy' if greedy_only else ''}]"
+            self._verifies[key] = wd.watch(
+                self._build_verify(depth, greedy_only), wd.unique_name(name), stable=True)
+        return self._verifies[key]
+
+    def _prefill_prog(self, bucket: int):
+        if bucket not in self._prefills:
+            # each bucket length is its own compile-stable program: one
+            # compile at first use, never again
+            wd = self.telemetry.watchdog
+            self._prefills[bucket] = wd.watch(
+                self._build_prefill(bucket),
+                wd.unique_name(f"serving/prefill[{bucket}]"), stable=True)
+        return self._prefills[bucket]
 
     def _note_load(self, sp, load, chosen=None, **rows) -> None:
         """A routed model's call: how uneven the routing of its live rows was,
@@ -642,16 +661,6 @@ class SlotWorker:
         self.telemetry.gauge("serving/expert_load_max_over_mean").set(
             summary["expert_load_max_over_mean"])
         self.telemetry.gauge("serving/experts_touched").set(summary["experts_touched"])
-
-    def _compiled(self, sp, prog) -> bool:
-        """Whether the call just made into ``prog`` compiled, noted on its
-        span and on the scheduler's heartbeat flag. A call that compiled is
-        left out of the latency histograms (it is ``compile/wall_s``'s datum,
-        and would otherwise be the p99)."""
-        compiled = bool(prog.last_call_compiled)
-        self.step_compiled |= compiled
-        sp.annotate(compiled=compiled)
-        return compiled
 
     def _state_attrs(self, n_active: int) -> dict:
         """What a decode span says of a state-space mixer's state (nothing for a
@@ -687,12 +696,81 @@ class SlotWorker:
         sp.annotate(sampler=SAMPLER_FORMS[int(sampler_form(*rows, self.cfg.vocab_size))])
         return rows
 
-    # Every dispatch below is ONE span with two children: ``dispatch`` (from
-    # entry until the jitted call has returned: key split, operand conversion
-    # and upload, enqueue) and ``fetch`` (``jax.device_get`` of the tokens:
-    # the wait for the device plus the copy back). The fetch syncs, so the
-    # span's own duration is device-true, and it is what the latency
-    # histograms are fed from.
+    def _run(self, name: str, attrs: dict, program, operands, n_out: int, *,
+             key: bool = True, fetch: bool = True, **rows):
+        """The scaffold of the four worker calls (``decode``, ``verify``,
+        ``prefill``, ``chunk``), and the ONE place their spans are opened. A
+        call is one span ``name`` with two children and, under those, the five
+        parts a call's cost beyond the device's work is made of:
+
+          dispatch            entry until the program's call has returned: before
+                              that the device cannot start. Its self time is
+                              ``program()``: the look-up, or the first build
+            dispatch/operands ``operands(sp)`` -> (the program's operands behind
+                              params and cache, its sampler rows): every host
+                              conversion and every eager upload of one
+            dispatch/key      ``jax.random.split``: a device program of its own
+                              (none with ``key=False``: greedy ``verify``)
+            dispatch/enqueue  the watched program's call: the proxy's bookkeeping,
+                              pjit's argument path over the parameter tree, the
+                              batched upload of the host operands, the enqueue
+          fetch               until the host holds the results (none with
+                              ``fetch=False``: the call stays asynchronous)
+            fetch/wait        the copies asked for (``copy_to_host_async``), then
+                              ``block_until_ready`` on the host-bound outputs: the
+                              runtime's launch latency and the device's run
+            fetch/copy        ``device_get`` of the same outputs, as numpy: what
+                              is left of the copy back once they are ready
+
+        All outputs of one program become ready together, and ``wait`` asks for
+        the copies before it waits, as ``device_get`` itself does first: the
+        transfers follow the program on the device with no host round trip
+        between. So ``wait`` then ``copy`` moves the same bytes in the same
+        order, and at the same times, as one ``device_get`` of them would: the
+        split changes what is timed, not what is fetched (waiting FIRST and
+        asking afterwards cost 0.25 ms a call on the chip: PERF.md section 6, PR 35).
+        The fetch syncs, so the call span's own duration is device-true, and it
+        is what the callers feed the latency histograms from.
+
+        On the call's span: ``compiled`` (a call that compiled is no latency
+        datum, and the scheduler's heartbeat exempts its step), ``h2d``, the
+        separate host->device operands of the call (every operand behind params
+        and cache is a host array handed to the program or an upload made for
+        it; the key split's program counts as one) and ``d2h``, the separate
+        arrays fetched (the experts a ``routing_log`` asks for are not among
+        them). Returns ``(span, the n_out fetched arrays or None)``; what
+        follows them in the fetch is a routed model's load, noted with ``rows``."""
+        tm = self.telemetry
+        with tm.span(name, **attrs) as sp:
+            with tm.span("dispatch"):
+                with tm.span("operands"):
+                    head, sampler = operands(sp)
+                prog = program()
+                keys = ()
+                if key:
+                    with tm.span("key"):
+                        self._rng, k = jax.random.split(self._rng)
+                    keys = (k,)
+                with tm.span("enqueue"):
+                    self._cache, *out = prog(self.params, self._cache, *head, *keys, *sampler)
+            compiled = bool(prog.last_call_compiled)
+            self.step_compiled |= compiled
+            chosen = None  # a routed model's choices: a device array no fetch waits for
+            if self.cfg.moe_routing == "dropless":
+                *out, chosen = out
+            sp.annotate(compiled=compiled, h2d=len(head) + len(keys) + len(sampler),
+                        d2h=len(out) if fetch else 0)
+            if not fetch:
+                return sp, None
+            with tm.span("fetch"):
+                with tm.span("wait"):
+                    for x in out:
+                        x.copy_to_host_async()
+                    jax.block_until_ready(out)
+                with tm.span("copy"):
+                    out = tuple(np.asarray(x) for x in jax.device_get(out))
+            self._note_load(sp, out[n_out:], chosen, **rows)
+        return sp, out[:n_out]
 
     def decode(self, last_tok, pos, wpos, active, temp, top_k, top_p):
         """Advance EVERY slot one token — THE compile-stable path: a second
@@ -704,35 +782,23 @@ class SlotWorker:
         # ``cached_tokens``: the cache positions the step attends to, summed
         # over its live rows (row at ``pos`` reads [0, pos]); ``attn``: the form
         # the program was traced with (``prefill`` has flash / dense);
-        # ``sampler`` (below): the form its sampler takes on these rows
+        # ``sampler``: the form its sampler takes on these rows;
         # ``state_rows`` / ``state_bytes`` (a state-space mixer only): the active
         # rows whose per-sequence state the step advanced, and the bytes of it
         # the step had to read AND write
         n_active = int(np.count_nonzero(active))
-        with tm.span("decode", n_active=n_active,
+        attrs = dict(n_active=n_active,
                      cached_tokens=int(np.sum((np.asarray(pos) + 1)[np.asarray(active, bool)])),
-                     attn=tfm.cache_step_form(self.cfg), **self._state_attrs(n_active)) as sp:
-            with tm.span("dispatch"):
-                rows = self._sampler_rows(sp, temp, top_k, top_p)
-                if self._decode is None:
-                    wd = tm.watchdog
-                    self._decode = wd.watch(
-                        self._build_decode(), wd.unique_name("serving/decode"),
-                        stable=True)
-                self._rng, k = jax.random.split(self._rng)
-                # host arrays straight into the jitted call (pjit batches the
-                # uploads); dtypes are pinned by the engine's per-slot state
-                # arrays
-                self._cache, *out = self._decode(
-                    self.params, self._cache, last_tok, pos,
-                    np.asarray(wpos, np.int32), active, k, *rows)
-            self._decode_steps += 1
-            compiled = self._compiled(sp, self._decode)
-            out, chosen = self._host_bound(out)
-            with tm.span("fetch"):
-                nxt, bad, *load = (np.asarray(x) for x in jax.device_get(out))
-            self._note_load(sp, load, chosen, pos=np.array(pos), active=np.array(active, bool))
-        if not compiled:
+                     attn=tfm.cache_step_form(self.cfg), **self._state_attrs(n_active))
+        # host arrays straight into the jitted call (pjit batches the uploads);
+        # dtypes are pinned by the engine's per-slot state arrays
+        sp, (nxt, bad) = self._run(
+            "decode", attrs, self._decode_prog,
+            lambda sp: ((last_tok, pos, np.asarray(wpos, np.int32), active),
+                        self._sampler_rows(sp, temp, top_k, top_p)),
+            2, pos=np.array(pos), active=np.array(active, bool))
+        self._decode_steps += 1
+        if not sp.attrs["compiled"]:
             tm.histogram("serving/decode_step_sec").observe(sp.dur_s)
         tm.counter("serving/decode_steps").inc()
         return nxt, bad
@@ -746,54 +812,30 @@ class SlotWorker:
         Returns host ``(accept, resample, clean, bad)`` arrays
         ([n, depth] / [n, depth+1] / [n, depth+1] / [n])."""
         tm = self.telemetry
-        with tm.span("verify", n_active=int(np.count_nonzero(active)), depth=depth,
+        attrs = dict(n_active=int(np.count_nonzero(active)), depth=depth,
                      cached_tokens=int(np.sum(
                          (np.asarray(pos) + depth + 1)[np.asarray(active, bool)])),
-                     attn=tfm.cache_step_form(self.cfg)) as sp:
-            with tm.span("dispatch"):
-                key = (depth, greedy_only)
-                if key not in self._verifies:
-                    wd = tm.watchdog
-                    name = f"serving/verify[{depth}{':greedy' if greedy_only else ''}]"
-                    self._verifies[key] = wd.watch(
-                        self._build_verify(depth, greedy_only),
-                        wd.unique_name(name), stable=True)
-                prog = self._verifies[key]
-                # host arrays go straight into the jitted call: pjit's C++
-                # argument path uploads them in one batch, and the greedy
-                # family's trimmed signature (no rng/temp/top_k/top_p — dead
-                # operands there) skips both the uploads and the per-step
-                # key split
-                wpos = np.asarray(wpos, np.int32)
-                if greedy_only:
-                    self._cache, *out = prog(
-                        self.params, self._cache, toks, pos, wpos, active)
-                else:
-                    self._rng, k = jax.random.split(self._rng)
-                    self._cache, *out = prog(
-                        self.params, self._cache, toks, pos, wpos, active, k,
-                        temp, top_k, top_p)
-            compiled = self._compiled(sp, prog)
-            out, chosen = self._host_bound(out)
-            with tm.span("fetch"):
-                out = tuple(np.asarray(x) for x in jax.device_get(out))
-            n_out = 1 if greedy_only else 4  # what follows is a routed model's expert load
-            self._note_load(sp, out[n_out:], chosen, pos=np.array(pos),
-                            active=np.array(active, bool))
-            out = out[:n_out]
-            if greedy_only:
-                p, = out  # one packed array: the ONE fetch
-                tokens = p[:, depth:2 * depth + 1]
-                out = (p[:, :depth].astype(bool), tokens, tokens,
-                       p[:, -1].astype(bool))
-            if warm:
-                sp.annotate(warm=True)
+                     attn=tfm.cache_step_form(self.cfg), **({"warm": True} if warm else {}))
+        # host arrays go straight into the jitted call: pjit's C++ argument
+        # path uploads them in one batch, and the greedy family's trimmed
+        # signature (no rng/temp/top_k/top_p — dead operands there) skips both
+        # the uploads and the per-step key split
+        sp, out = self._run(
+            "verify", attrs, lambda: self._verify_prog(depth, greedy_only),
+            lambda sp: ((toks, pos, np.asarray(wpos, np.int32), active),
+                        () if greedy_only else (temp, top_k, top_p)),
+            1 if greedy_only else 4, key=not greedy_only,
+            pos=np.array(pos), active=np.array(active, bool))
+        if greedy_only:
+            p, = out  # one packed array: the ONE fetch
+            tokens = p[:, depth:2 * depth + 1]
+            out = (p[:, :depth].astype(bool), tokens, tokens, p[:, -1].astype(bool))
         if warm:
             # pre-warm dispatch (all slots inactive, writes dropped): it
             # exists to COMPILE, so it is neither a latency datum nor a
             # verify step the acceptance accounting should see
             return out
-        if not compiled:
+        if not sp.attrs["compiled"]:
             tm.histogram("serving/verify_step_sec").observe(sp.dur_s)
         tm.counter("serving/verify_steps").inc()
         tm.counter(f"serving/verify_bucket[{depth}]").inc()
@@ -806,32 +848,18 @@ class SlotWorker:
         tm = self.telemetry
         # ``attn``: the form the bucket's program was traced with (its local
         # cache is the bucket long: ``_build_prefill``)
-        with tm.span("prefill", uid=uid, slot=slot, bucket=bucket, true_len=true_len,
+        attrs = dict(uid=uid, slot=slot, bucket=bucket, true_len=true_len,
                      attn=tfm.cache_attention_form(self.cfg.num_heads, 1, bucket, bucket),
-                     **self._block_attrs(bucket, true_len)) as sp:
-            with tm.span("dispatch"):
-                rows = self._sampler_rows(sp, temperature, top_k, top_p)
-                if bucket not in self._prefills:
-                    # each bucket length is its own compile-stable program:
-                    # one compile at first use, never again
-                    wd = tm.watchdog
-                    self._prefills[bucket] = wd.watch(
-                        self._build_prefill(bucket),
-                        wd.unique_name(f"serving/prefill[{bucket}]"), stable=True)
-                prog = self._prefills[bucket]
-                self._rng, k = jax.random.split(self._rng)
-                self._cache, *out = prog(
-                    self.params, self._cache, jnp.asarray(padded),
-                    jnp.int32(slot), jnp.int32(true_len), k, *rows)
-            compiled = self._compiled(sp, prog)
-            out, chosen = self._host_bound(out)
-            with tm.span("fetch"):
-                tok_h, bad_h, *load = jax.device_get(out)
-            self._note_load(sp, load, chosen, uid=uid, slot=slot, true_len=true_len)
-        if not compiled:
+                     **self._block_attrs(bucket, true_len))
+        sp, (tok, bad) = self._run(
+            "prefill", attrs, lambda: self._prefill_prog(bucket),
+            lambda sp: ((jnp.asarray(padded), jnp.int32(slot), jnp.int32(true_len)),
+                        self._sampler_rows(sp, temperature, top_k, top_p)),
+            2, uid=uid, slot=slot, true_len=true_len)
+        if not sp.attrs["compiled"]:
             tm.histogram("serving/prefill_sec").observe(sp.dur_s)
         tm.counter(f"serving/prefill_bucket[{bucket}]").inc()
-        return int(np.asarray(tok_h)[0]), bool(np.asarray(bad_h).reshape(-1)[0])
+        return int(tok[0]), bool(bad.reshape(-1)[0])
 
     def chunk(self, width: int, toks, slot: int, start: int, live: int,
               temperature: float, top_k: int, top_p: float, *, fetch: bool,
@@ -843,28 +871,22 @@ class SlotWorker:
         no latency datum); the FINAL chunk fetches and returns
         ``(first_token, bad)``. ``uid`` only labels the span."""
         tm = self.telemetry
-        with tm.span("chunk", uid=uid, slot=slot, width=width, live=live, fetch=fetch,
+        attrs = dict(uid=uid, slot=slot, width=width, live=live, fetch=fetch,
                      cached_tokens=int(start) + int(live),
-                     attn=tfm.cache_step_form(self.cfg), **self._block_attrs(width, live)) as sp:
-            with tm.span("dispatch"):
-                rows = self._sampler_rows(sp, temperature, top_k, top_p)
-                prog = self._chunk_prog(width)
-                self._rng, k = jax.random.split(self._rng)
-                self._cache, *out = prog(
-                    self.params, self._cache, jnp.asarray(toks),
-                    jnp.int32(slot), jnp.int32(start), jnp.int32(live), k, *rows)
-            compiled = self._compiled(sp, prog)
-            if fetch:
-                out, chosen = self._host_bound(out)
-                with tm.span("fetch"):
-                    tok_h, bad_h, *load = jax.device_get(out)
-                self._note_load(sp, load, chosen, uid=uid, slot=slot, start=start, live=live)
+                     attn=tfm.cache_step_form(self.cfg), **self._block_attrs(width, live))
+        sp, out = self._run(
+            "chunk", attrs, lambda: self._chunk_prog(width),
+            lambda sp: ((jnp.asarray(toks), jnp.int32(slot), jnp.int32(start),
+                         jnp.int32(live)),
+                        self._sampler_rows(sp, temperature, top_k, top_p)),
+            2, fetch=fetch, uid=uid, slot=slot, start=start, live=live)
         tm.counter(f"serving/chunk_bucket[{width}]").inc()
         if not fetch:
             return None
-        if not compiled:
+        if not sp.attrs["compiled"]:
             tm.histogram("serving/chunk_prefill_sec").observe(sp.dur_s)
-        return int(np.asarray(tok_h)[0]), bool(np.asarray(bad_h).reshape(-1)[0])
+        tok, bad = out
+        return int(tok[0]), bool(bad.reshape(-1)[0])
 
     def prefix_fetch(self, pool_slot: int, slot: int) -> None:
         """Copy a prefix-pool window into ``slot`` (ONE compiled program;

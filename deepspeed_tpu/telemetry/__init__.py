@@ -70,8 +70,7 @@ class Telemetry:
         self.registry = registry if registry is not None else MetricsRegistry()
         self.sink = JsonlExporter(jsonl_path, max_bytes=jsonl_max_bytes,
                                   keep=jsonl_keep) if jsonl_path else None
-        self.tracer = SpanTracer(self.registry, self.sink,
-                                 device_sync=device_sync_spans)
+        self.tracer = SpanTracer(self.sink, device_sync=device_sync_spans)
         self.ledger = ProgramLedger(self.registry, enabled=ledger,
                                     collectives=ledger_collectives,
                                     ici_gbps=ici_gbps)

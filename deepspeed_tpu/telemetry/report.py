@@ -634,7 +634,7 @@ def summarize(events: list[dict], top: int = 10) -> str:
             lines.append(f"  {name:<44} {v:g}")
         for name, h in metrics.get("histograms", {}).items():
             # only time-suffixed metrics render with time units
-            timed = name.endswith(("_sec", "_s")) or name.startswith("span/")
+            timed = name.endswith(("_sec", "_s"))
             fmt = _fmt_s if timed else (lambda v: f"{v:g}")
             lines.append(
                 f"  {name:<44} n={h['count']} p50={fmt(h['p50'])} "

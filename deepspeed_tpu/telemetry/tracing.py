@@ -17,7 +17,6 @@ A span is a named wall-clock region of host code. Each span:
     replicas can share a process). ``spans(since)`` / ``clear_spans()`` are
     the read surface that ``telemetry_snapshot()``, the benchmark's readers
     and the tests share;
-  * feeds its duration into the registry histogram ``span/<path>``;
   * optionally emits a JSONL event ``{"type": "span", "name", "path",
     "depth", "id", "parent", "start_s", "dur_s"}`` (``start_s`` on the same
     ``perf_counter`` clock, ``t`` absolute wall time added by the exporter).
@@ -43,11 +42,8 @@ import collections
 import itertools
 import threading
 import time
-from typing import Optional
 
 import jax
-
-from .registry import MetricsRegistry, get_registry
 
 RING_CAPACITY = 65_536  # ended spans kept, process-wide; the oldest fall out
 
@@ -144,13 +140,10 @@ class Span:
 
 
 class SpanTracer:
-    def __init__(self, registry: Optional[MetricsRegistry] = None, sink=None,
-                 device_sync: bool = False):
-        self.registry = registry if registry is not None else get_registry()
+    def __init__(self, sink=None, device_sync: bool = False):
         self.sink = sink
         self.device_sync = device_sync
         self._tls = threading.local()
-        self._hists: dict = {}  # path -> its span/<path> histogram
 
     def _stack(self) -> list:
         st = getattr(self._tls, "stack", None)
@@ -170,10 +163,6 @@ class SpanTracer:
         return Span(self, name, sync, replica_id, attrs)
 
     def _emit(self, span: Span) -> None:
-        hist = self._hists.get(span.path)
-        if hist is None:
-            hist = self._hists[span.path] = self.registry.histogram(f"span/{span.path}")
-        hist.observe(span.t1 - span.t0)
         if self.sink is not None:
             ev = {
                 "type": "span",

@@ -35,6 +35,7 @@ from deepspeed_tpu.telemetry import (
     SpanTracer,
     Telemetry,
     prometheus_text,
+    tracing,
 )
 
 
@@ -99,9 +100,8 @@ def test_registry_snapshot_and_prometheus_and_type_guard():
 
 def test_span_nesting_registry_and_jsonl_schema(tmp_path):
     path = str(tmp_path / "spans.jsonl")
-    reg = MetricsRegistry()
     sink = JsonlExporter(path)
-    tr = SpanTracer(reg, sink)
+    tr = SpanTracer(sink)
     before = time.perf_counter()
     with tr.span("serve"):
         with tr.span("step") as sp:
@@ -124,15 +124,16 @@ def test_span_nesting_registry_and_jsonl_schema(tmp_path):
     assert events[2]["parent"] is None
     assert events[0]["parent"] == events[1]["parent"] == events[2]["id"]
     assert not hasattr(tr, "_epoch")
-    # nesting feeds slash-joined registry histograms; parent covers children
-    snap = reg.snapshot()["histograms"]
-    assert snap["span/serve/step"]["count"] == 2
-    assert snap["span/serve"]["count"] == 1
-    assert snap["span/serve"]["sum"] >= snap["span/serve/step"]["sum"]
+    # the ring holds the same spans under slash-joined paths; parent covers children
+    ring = tracing.spans(before)
+    steps = [sp for sp in ring if sp.path == "serve/step"]
+    (serve,) = [sp for sp in ring if sp.path == "serve"]
+    assert len(steps) == 2
+    assert serve.dur_s >= sum(sp.dur_s for sp in steps)
 
 
 def test_span_device_sync_mode_blocks_on_output():
-    tr = SpanTracer(MetricsRegistry(), device_sync=True)
+    tr = SpanTracer(device_sync=True)
     with tr.span("jit") as sp:
         out = jax.jit(lambda x: x * 2)(jnp.ones((16,)))
         sp.set_sync(out)  # block_until_ready at span exit must not raise

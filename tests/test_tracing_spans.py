@@ -6,8 +6,11 @@ Contracts under test:
     a span that raises is still recorded and leaves the nesting intact;
   * ``ServingEngine.step`` leaves one ``serve/step`` per iteration whose
     children nest as PERF.md's span table says, one ``dispatch`` + ``fetch``
-    under each worker call, request-labelled prefill/chunk spans, and the
-    latency histograms are fed from exactly the spans that did not compile;
+    under each worker call with the five parts of a call under those
+    (``operands`` / ``key`` / ``enqueue``, ``wait`` / ``copy``) and ``h2d`` /
+    ``d2h`` on the call, request-labelled prefill/chunk spans, and the latency
+    histograms are fed from exactly the spans that did not compile; no span
+    feeds a ``span/<path>`` histogram;
   * ``train_batch`` leaves ``train/train_batch`` with ``pre`` / ``dispatch``
     / ``post``;
   * each per-layer reader under chipbench/layer_metrics/ that reads the ring
@@ -31,7 +34,7 @@ import numpy as np
 import pytest
 
 from chipbench.layer_metrics.span_ring import WORKER_CALLS
-from deepspeed_tpu.telemetry import MetricsRegistry, SpanTracer, tracing
+from deepspeed_tpu.telemetry import SpanTracer, tracing
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -41,7 +44,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # ---------------------------------------------------------------------------
 
 def _ring_fields():
-    tr = SpanTracer(MetricsRegistry())
+    tr = SpanTracer()
     before = time.perf_counter()
     with tr.span("outer", replica_id=7, kind="x") as outer:
         with tr.span("inner") as inner:
@@ -62,7 +65,7 @@ def _ring_fields():
 
 
 def _ring_bounded():
-    tr = SpanTracer(MetricsRegistry())
+    tr = SpanTracer()
     tracing.clear_spans()
     assert tracing.spans() == []
     for i in range(tracing.RING_CAPACITY + 5):
@@ -78,8 +81,7 @@ def _ring_bounded():
 
 
 def _ring_survives_raise():
-    reg = MetricsRegistry()
-    tr = SpanTracer(reg)
+    tr = SpanTracer()
     since = time.perf_counter()
     with pytest.raises(ValueError):
         with tr.span("outer"):
@@ -91,24 +93,12 @@ def _ring_survives_raise():
     assert set(got) == {"outer/boom", "outer", "after"}  # the stack unwound
     assert got["outer/boom"].attrs["error"] == "ValueError"
     assert got["after"].parent is None and "error" not in got["after"].attrs
-    # a span that raised is in the ring but is no latency datum
-    assert set(reg.snapshot()["histograms"]) == {"span/after"}
-
-
-def _ring_histogram_handle_once():
-    reg = MetricsRegistry()
-    tr = SpanTracer(reg)
-    for _ in range(50):
-        with tr.span("a"):
-            with tr.span("b"):
-                pass
-    assert set(tr._hists) == {"a", "a/b"}
-    assert tr._hists["a/b"] is reg.histogram("span/a/b")
-    assert reg.snapshot()["histograms"]["span/a/b"]["count"] == 50
+    # a span that raised says so in the ring, and so does what it unwound through
+    assert [sp.path for sp in tracing.spans(since) if "error" not in sp.attrs] == ["after"]
 
 
 def _ring_parent_is_per_thread():
-    tr = SpanTracer(MetricsRegistry())
+    tr = SpanTracer()
     since = time.perf_counter()
     seen = {}
 
@@ -127,7 +117,7 @@ def _ring_parent_is_per_thread():
 def _ring_threads_lose_nothing():
     """More writers than cores, one reader, a short switch interval: every
     span is recorded once, under an id of its own."""
-    tr = SpanTracer(MetricsRegistry())
+    tr = SpanTracer()
     workers, each = 2 * (os.cpu_count() or 4), 300
     since = time.perf_counter()
     stop = threading.Event()
@@ -167,7 +157,7 @@ def _ring_threads_lose_nothing():
 
 
 @pytest.mark.parametrize("case", [_ring_fields, _ring_bounded, _ring_survives_raise,
-                                  _ring_histogram_handle_once, _ring_parent_is_per_thread,
+                                  _ring_parent_is_per_thread,
                                   _ring_threads_lose_nothing],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_ring(case):
@@ -193,10 +183,12 @@ PARENTS = {
     "decode": {"serve/step"}, "verify": {"serve/step"},
     "prefill": {"admit"}, "chunk": {"chunks", "admit"},
     "dispatch": set(WORKER_CALLS), "fetch": set(WORKER_CALLS),
+    "operands": {"dispatch"}, "key": {"dispatch"}, "enqueue": {"dispatch"},
+    "wait": {"fetch"}, "copy": {"fetch"},
 }
 
 
-def _serve(engine, mode):
+def _serve(engine, mode, **ask):
     from deepspeed_tpu.inference import ServingEngine
     from deepspeed_tpu.inference.serving import Request
 
@@ -209,7 +201,8 @@ def _serve(engine, mode):
         prompts[4] = prompts[1].copy()  # a whole-prompt repeat: a prefix hit
     if mode == "speculation":
         prompts = [np.tile(p[:4], 10)[:len(p)] for p in prompts]  # n-gram drafts match
-    reqs = [Request(uid=100 + i, prompt=p, max_new_tokens=3 + i) for i, p in enumerate(prompts)]
+    reqs = [Request(uid=100 + i, prompt=p, max_new_tokens=3 + i, **ask)
+            for i, p in enumerate(prompts)]
     since = time.perf_counter()
     results = srv.serve(reqs)
     assert all(r.ok for r in results.values())
@@ -226,6 +219,7 @@ def test_serving_spans(tiny_serving_engine, mode):
         kids.setdefault(sp.parent, []).append(sp)
     hist = srv.telemetry.registry.snapshot()["histograms"]
     counters = srv.telemetry.registry.snapshot()["counters"]
+    assert hist and not any(name.startswith("span/") for name in hist)  # the ring is the record
 
     # every scheduler iteration is one serve/step, numbered, and everything nests
     # as the table says, on one clock, inside its parent
@@ -301,7 +295,8 @@ def test_serving_spans(tiny_serving_engine, mode):
 
     if mode == "plain":
         assert {sp.name for sp in spans} == {"serve/step", "sweep", "admit", "prefill", "decode",
-                                             "dispatch", "fetch", "emit"}
+                                             "dispatch", "operands", "key", "enqueue",
+                                             "fetch", "wait", "copy", "emit"}
         assert len(prefills) == len(reqs) and not chunks
     if mode == "chunked":
         assert not prefills and {by_id[sp.parent].name for sp in chunks} == {"chunks"}
@@ -372,10 +367,187 @@ def test_worker_call_outside_step_has_a_short_path(tiny_serving_engine):
     since = time.perf_counter()
     srv.warm_verify()
     got = tracing.spans(since)
-    assert {sp.path for sp in got} == {"verify", "verify/dispatch", "verify/fetch"}
+    # the greedy family draws nothing: no key split, so no ``key`` span
+    assert {sp.path for sp in got} == {
+        "verify", "verify/dispatch", "verify/dispatch/operands", "verify/dispatch/enqueue",
+        "verify/fetch", "verify/fetch/wait", "verify/fetch/copy"}
     assert all(sp.attrs["warm"] for sp in got if sp.path == "verify")
     hist = srv.telemetry.registry.snapshot()["histograms"]
     assert "serving/verify_step_sec" not in hist  # a warm call is no latency datum
+
+
+# ---------------------------------------------------------------------------
+# the anatomy of a worker call (SlotWorker._run): five parts, two counters
+# ---------------------------------------------------------------------------
+
+# call kind -> (serving mode that makes it, request keywords, host operands, device operands,
+# arrays fetched): what the call hands its program behind params and cache, by where it lives
+# when handed over (a device operand is an upload made for the call, or the split key)
+ANATOMY = {
+    "decode": ("plain", {}, 7, 1, 2),
+    "prefill": ("plain", {}, 3, 4, 2),  # prompt, slot, true_len uploaded eagerly; the key
+    "chunk": ("chunked", {}, 3, 5, 2),
+    "verify": ("speculation", {}, 4, 0, 1),  # greedy: no key, no sampler rows, ONE packed array
+    "verify-sampled": ("speculation", {"temperature": 0.8, "top_k": 1}, 7, 1, 4),  # the argmax, drawn
+}
+
+
+class _Recorder:
+    """A watched program that notes the operands of each call behind params and
+    cache, and is otherwise the program."""
+
+    def __init__(self, prog, seen):
+        self._prog, self._seen = prog, seen
+
+    def __call__(self, params, cache, *operands):
+        self._seen.append(operands)
+        return self._prog(params, cache, *operands)
+
+    def __getattr__(self, name):
+        return getattr(self._prog, name)
+
+
+def _by_parent(spans):
+    kids = {}
+    for sp in spans:
+        kids.setdefault(sp.parent, []).append(sp)
+    return {up: sorted(below, key=lambda sp: sp.t0) for up, below in kids.items()}
+
+
+@pytest.mark.parametrize("kind", list(ANATOMY))
+def test_worker_call_has_five_parts_in_order(tiny_serving_engine, kind):
+    """``operands`` / ``key`` / ``enqueue`` under ``dispatch`` and ``wait`` /
+    ``copy`` under ``fetch``: in that order, disjoint, inside their parent, and
+    covering it but for the program look-up between ``operands`` and what
+    follows. A greedy ``verify`` has no ``key``; a chunk left asynchronous has no
+    ``fetch`` and so neither of its parts."""
+    mode, ask, *_ = ANATOMY[kind]
+    _, _, spans = _serve(tiny_serving_engine, mode, **ask)
+    kids = _by_parent(spans)
+    name = kind.split("-")[0]
+    calls = [sp for sp in spans if sp.name == name]
+    assert calls
+    uncovered = []
+    for call in calls:
+        dispatch, *rest = kids[call.id]
+        want = ["operands", "enqueue"] if kind == "verify" else ["operands", "key", "enqueue"]
+        parts = kids[dispatch.id]
+        assert [sp.name for sp in parts] == want, call.path
+        groups = [(dispatch, parts)]
+        if call.attrs.get("fetch") is False:
+            assert not rest and call.attrs["d2h"] == 0
+        else:
+            (fetch,) = rest
+            assert fetch.name == "fetch" and dispatch.t1 <= fetch.t0
+            assert [sp.name for sp in kids[fetch.id]] == ["wait", "copy"]
+            groups.append((fetch, kids[fetch.id]))
+        for up, below in groups:
+            assert up.t0 <= below[0].t0 and below[-1].t1 <= up.t1
+            assert all(a.t1 <= b.t0 for a, b in zip(below, below[1:]))
+            assert all(sp.path == f"{up.path}/{sp.name}" for sp in below)
+            lookup = below[1].t0 - below[0].t1 if up is dispatch else 0.0
+            uncovered.append(up.dur_s - sum(sp.dur_s for sp in below) - lookup)
+    # what no part covers is a few Python statements: microseconds, on any machine
+    assert np.median(uncovered) < 2e-4, np.median(uncovered)
+    if kind == "chunk":
+        assert any(c.attrs["fetch"] is False for c in calls)
+
+
+@pytest.mark.parametrize("kind", list(ANATOMY))
+def test_h2d_and_d2h_are_what_the_call_hands_over_and_fetches(tiny_serving_engine, monkeypatch,
+                                                              kind):
+    """``h2d`` = the host arrays given to the program + the uploads made for it
+    + one for the key split's program (its result is the one device operand
+    that is no upload); ``d2h`` = the arrays ``device_get`` is handed: counted
+    here from outside, at the program's call and at ``jax.device_get``."""
+    import jax
+
+    from deepspeed_tpu.inference import ServingEngine
+    from deepspeed_tpu.inference.serving import Request
+
+    mode, ask, n_host, n_device, n_fetched = ANATOMY[kind]
+    name = kind.split("-")[0]
+    srv = ServingEngine(tiny_serving_engine, n_slots=2, max_seq_len=128,
+                        replica_id=f"count-{kind}", **SERVING[mode])
+    handed, fetched = [], []
+    getter = f"_{name}_prog"
+    get = getattr(srv.worker, getter)
+    monkeypatch.setattr(srv.worker, getter, lambda *a: _Recorder(get(*a), handed))
+    device_get = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda out: (fetched.append(len(out)), device_get(out))[1])
+    rng = np.random.default_rng(11)
+    prompts = [np.tile(rng.integers(0, 97, size=4), 10)[:n].astype(np.int32) for n in (9, 40)]
+    reqs = [Request(uid=i, prompt=p, max_new_tokens=6, **ask) for i, p in enumerate(prompts)]
+    since = time.perf_counter()
+    assert all(r.ok for r in srv.serve(reqs).values())
+    monkeypatch.undo()
+    spans = [sp for sp in tracing.spans(since) if sp.replica_id == srv.replica_id]
+    calls = [sp for sp in spans if sp.name == name
+             and (name != "verify" or (sp.attrs["h2d"] == 4) == (kind == "verify"))]
+    others = [sp for sp in spans if sp.name in WORKER_CALLS and sp.name != name]
+    assert calls and len(handed) == sum(sp.name == name for sp in spans)
+    mine = [ops for ops in handed if len(ops) == n_host + n_device]
+    assert len(mine) == len(calls)
+    for ops in mine:
+        assert sum(isinstance(x, jax.Array) for x in ops) == n_device
+        assert sum(isinstance(x, (np.ndarray, np.generic)) for x in ops) == n_host
+    assert {sp.attrs["h2d"] for sp in calls} == {n_host + n_device}
+    fetching = [sp for sp in calls if sp.attrs.get("fetch") is not False]
+    assert {sp.attrs["d2h"] for sp in fetching} == {n_fetched}
+    # every fetch of the run is some call's: none is made beside the scaffold
+    assert sorted(fetched) == sorted(sp.attrs["d2h"] for sp in calls + others
+                                     if sp.attrs.get("fetch") is not False)
+
+
+def test_a_routed_model_fetches_one_array_more():
+    """The expert load comes back in the fetch that brings the tokens: ``d2h``
+    3 where a dense model's call has 2; ``h2d`` is the dense model's."""
+    from chipbench.references import program_of
+    from deepspeed_tpu.inference.serving import Request
+    from deepspeed_tpu.launcher.serving_worker import build_serving_engine
+
+    with open(os.path.join(ROOT, "chipbench", "configs", "olmoe-1b-7b-L4.json")) as f:
+        program = program_of(json.load(f), "rehearse_program")
+    srv = build_serving_engine({"model": {**program, "dtype": "float32"}, "engine_dtype": "fp32",
+                                "serving": {"n_slots": 4, "max_seq_len": 256, "seed": 0}})
+    rng = np.random.default_rng(2)
+    reqs = [Request(uid=i, prompt=rng.integers(0, program["vocab_size"], size=n).astype(np.int32),
+                    max_new_tokens=4) for i, n in enumerate((40, 77))]
+    since = time.perf_counter()
+    assert all(r.ok for r in srv.serve(reqs).values())
+    calls = {sp.name: sp for sp in tracing.spans(since) if sp.name in ("prefill", "decode")}
+    assert {k: (sp.attrs["h2d"], sp.attrs["d2h"]) for k, sp in calls.items()} == {
+        "prefill": (7, 3), "decode": (8, 3)}
+    assert "expert_load_max_over_mean" in calls["decode"].attrs
+
+
+def test_wait_then_copy_changes_no_token(tiny_serving_engine, monkeypatch):
+    """All outputs of one program become ready together, so waiting for them
+    and then copying them fetches what one ``device_get`` fetched: a sampled
+    stream is the same, token for token, with the wait taken out again (the
+    form the calls had before their ``fetch`` was split)."""
+    import jax
+
+    from deepspeed_tpu.inference import ServingEngine
+    from deepspeed_tpu.inference.serving import Request
+
+    def stream(mode):
+        srv = ServingEngine(tiny_serving_engine, n_slots=2, max_seq_len=128, seed=5,
+                            **SERVING[mode])
+        rng = np.random.default_rng(9)
+        reqs = [Request(uid=i, prompt=np.tile(rng.integers(0, 97, size=4), 8)[:n].astype(np.int32),
+                        max_new_tokens=8, temperature=0.9 if i % 2 else 0.0,
+                        top_p=0.8 if i == 3 else 1.0) for i, n in enumerate((7, 30, 12, 21))]
+        results = srv.serve(reqs)
+        assert all(r.ok for r in results.values())
+        return [list(map(int, results[r.uid].tokens)) for r in reqs]
+
+    for mode in SERVING:
+        with_wait = stream(mode)
+        with monkeypatch.context() as m:
+            m.setattr(jax, "block_until_ready", lambda out: out)
+            assert stream(mode) == with_wait, mode
 
 
 # ---------------------------------------------------------------------------
@@ -405,7 +577,8 @@ def test_train_batch_spans():
     hist = engine.telemetry.registry.snapshot()["histograms"]
     assert hist["train/step_time_sec"]["count"] == 3
     np.testing.assert_allclose(hist["train/step_time_sec"]["sum"], sum(s.dur_s for s in steps))
-    assert hist["span/train/train_batch/dispatch"]["count"] == 3
+    assert sum(sp.path == "train/train_batch/dispatch" for sp in spans) == 3
+    assert not any(name.startswith("span/") for name in hist)
 
 
 # ---------------------------------------------------------------------------
@@ -524,6 +697,131 @@ def test_reader_on_an_empty_ring(monkeypatch, name):
     ctx = _ctx(notes)
     ctx["serve" if name != "train_host_ms_p50" else "train"] = None
     assert reader.read(ctx) is None
+
+
+# -- the anatomy readers (PR 35): the ring's five parts beside the trace's programs ----------
+
+def _anatomy_call(id, parent, path, t0, operands, key, enqueue, wait, copy, **attrs):
+    """A worker call from t0 (s) with its five parts back to back (ms each)."""
+    t = [t0]
+    for ms in (operands, key, enqueue, wait, copy):
+        t.append(t[-1] + ms / 1e3)
+    return [_sp(id, parent, path, t[0], t[5], **{"compiled": False, **attrs}),
+            _sp(id + 1, id, path + "/dispatch", t[0], t[3]),
+            _sp(id + 2, id + 1, path + "/dispatch/operands", t[0], t[1]),
+            _sp(id + 3, id + 1, path + "/dispatch/key", t[1], t[2]),
+            _sp(id + 4, id + 1, path + "/dispatch/enqueue", t[2], t[3]),
+            _sp(id + 5, id, path + "/fetch", t[3], t[5]),
+            _sp(id + 6, id + 5, path + "/fetch/wait", t[3], t[4]),
+            _sp(id + 7, id + 5, path + "/fetch/copy", t[4], t[5])]
+
+
+# the traced window is [10.0, 10.2) of the loop's clock, the host window [10, 20)
+HAND_ANATOMY = [
+    # 20 ms, half of it before the traced window: counts as half a run
+    *_anatomy_call(100, None, S + "/decode", E + 9.990, .5, .5, 1, 17, 1, h2d=8, d2h=2),
+    *_anatomy_call(110, None, S + "/decode", E + 10.020, .5, .5, 2, 16, 1, h2d=8, d2h=2),
+    *_anatomy_call(120, None, S + "/admit/prefill", E + 10.050, 3, 1, 2, 93, 1, h2d=7, d2h=2,
+                   bucket=2048, true_len=1500),
+    # it compiled: no reader sees it
+    *_anatomy_call(130, None, S + "/decode", E + 10.150, 1, 1, 5, 22, 1, h2d=8, d2h=2,
+                   compiled=True),
+    # 40 ms, 10 of them inside the traced window: a quarter of a run
+    *_anatomy_call(140, None, S + "/decode", E + 10.190, 1, 1, 3, 33.5, 1.5, h2d=8, d2h=2),
+    # after the traced window, in the host window: a call that packed its operands and results
+    *_anatomy_call(150, None, S + "/decode", E + 12.000, 1, 1, 4, 22, 2, h2d=6, d2h=1),
+]
+HAND_OPS = {"jit_decode/fusion.1": 0.014, "jit_decode/fusion.2": 0.007,  # 21 ms over 1.75 runs
+            "jit_prefill/ragged-dot": 0.080,
+            "jit_decoder/fusion.9": 0.5, "jit__threefry_split/fusion": 0.001}  # foreign programs
+DECODE_RUNS = 0.5 + 1 + 0.25
+ANATOMY_READERS = {
+    "decode_device_ms_mean": 21.0 / DECODE_RUNS, "prefill_device_ms_mean": 80.0,
+    # the weighted calls: (0.5 x 20 + 20 + 0.25 x 40) ms over 1.75 runs
+    "decode_call_overhead_ms": 40.0 / DECODE_RUNS - 21.0 / DECODE_RUNS,
+    "prefill_call_overhead_ms": 20.0,
+    # the host window's calls that did not compile: those at 10.020, 10.190, 12.000
+    "decode_enqueue_ms_p50": 3.0, "decode_copy_ms_p50": 1.5, "decode_host_transfers": 10.0,
+}
+TRACE_READ = ("decode_device_ms_mean", "prefill_device_ms_mean", "decode_call_overhead_ms",
+              "prefill_call_overhead_ms")
+
+
+def _anatomy_ctx(notes, ops=HAND_OPS):
+    ctx = _ctx(notes)
+    ctx["serve"]["traced"] = (10.0, 10.2)
+    ctx["trace"] = {"op_seconds": dict(ops), "busy_s_worst": sum(ops.values()), "window_s": 0.2}
+    return ctx
+
+
+@pytest.mark.parametrize("name", list(ANATOMY_READERS))
+def test_anatomy_reader_on_a_hand_made_ring_and_trace(monkeypatch, name):
+    reader = importlib.import_module(f"chipbench.layer_metrics.{name}")
+    _patch_ring(monkeypatch, HAND_ANATOMY)
+    notes = []
+    np.testing.assert_allclose(reader.read(_anatomy_ctx(notes)), ANATOMY_READERS[name], rtol=1e-9)
+    if name != "decode_call_overhead_ms":
+        assert not notes
+        return
+    (note,) = notes
+    assert note["event"] == "call_anatomy"
+    traced, host = note["decode"]["traced"], note["decode"]["host"]
+    np.testing.assert_allclose(
+        [traced["runs"], traced["device_ms"], traced["call"]["mean"], traced["call"]["p50"]],
+        [1.75, 12.0, 40.0 / 1.75, 20.0])
+    # enqueue (0.5 x 1 + 2 + 0.25 x 3) / 1.75 + wait (0.5 x 17 + 16 + 0.25 x 33.5) / 1.75 - 12
+    np.testing.assert_allclose(traced["runtime_ms"], (3.25 + 32.875) / 1.75 - 12.0)
+    np.testing.assert_allclose([traced["overhead_ms"], host["overhead_ms"]],
+                               [ANATOMY_READERS[name], 30.0 - 12.0])
+    np.testing.assert_allclose([host["runs"], host["call"]["mean"], host["enqueue"]["p50"],
+                                host["copy"]["mean"], host["h2d"], host["d2h"]],
+                               [3, 30.0, 3.0, 1.5, 8, 2])
+    for table in (traced, host):  # the parts cover their parents: this ring has no look-up
+        for up, parts in (("dispatch", ("operands", "key", "enqueue")), ("fetch", ("wait", "copy"))):
+            np.testing.assert_allclose(sum(table[p]["mean"] for p in parts), table[up]["mean"])
+    np.testing.assert_allclose([note["prefill"]["traced"]["device_ms"],
+                                note["prefill"]["traced"]["operands"]["mean"]], [80.0, 3.0])
+    # runs x device time of the two programs + the others tile the busy seconds
+    programs = note["programs_s"]
+    np.testing.assert_allclose(
+        1e-3 * (traced["runs"] * traced["device_ms"] + 80.0) + programs["other"], note["busy_s"])
+    np.testing.assert_allclose([programs["jit_decode"], programs["other"]], [0.021, 0.501])
+    assert programs["other_top"][0] == ("jit_decoder", 0.5)
+
+
+@pytest.mark.parametrize("name", list(ANATOMY_READERS))
+def test_anatomy_reader_with_nothing_to_read(monkeypatch, name):
+    reader = importlib.import_module(f"chipbench.layer_metrics.{name}")
+    notes = []
+    # a trace that names no program (the CPU rehearsal's): the span-read ones still read
+    _patch_ring(monkeypatch, HAND_ANATOMY)
+    got = reader.read(_anatomy_ctx(notes, {"fusion.1": 0.1, "dot.2": 0.05}))
+    if name in TRACE_READ:
+        assert got is None
+    else:
+        np.testing.assert_allclose(got, ANATOMY_READERS[name])
+    # a ring whose calls have no parts and no counters (the parent of PR 35): the device's
+    # time a run and the overhead read from what it has; step A's decode, 54 ms, is the one
+    # call of _ctx's traced window [10.0, 10.1)
+    _patch_ring(monkeypatch, HAND_SERVE)
+    ctx = _anatomy_ctx(notes, {"jit_decode/fusion.1": 0.040})
+    ctx["serve"]["traced"] = (10.0, 10.1)
+    want = {"decode_device_ms_mean": 40.0, "decode_call_overhead_ms": 14.0}.get(name)
+    if want is None:
+        assert reader.read(ctx) is None
+    else:
+        np.testing.assert_allclose(reader.read(ctx), want)
+    # a window that holds no call, a program without the ring, a cell that does not serve
+    notes.clear()
+    _patch_ring(monkeypatch, [])
+    assert reader.read(_anatomy_ctx(notes)) is None and not notes
+    monkeypatch.delattr(tracing, "spans")
+    assert reader.read(_anatomy_ctx(notes)) is None
+    monkeypatch.undo()
+    _patch_ring(monkeypatch, HAND_ANATOMY)
+    ctx = _anatomy_ctx(notes)
+    ctx["serve"] = None
+    assert reader.read(ctx) is None and not notes
 
 
 def test_chipbench_selftest():
