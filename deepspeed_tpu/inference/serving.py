@@ -102,7 +102,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from ..models import transformer as tfm
 from ..moe.dropless import expert_load, load_summary
@@ -302,7 +302,6 @@ class SlotWorker:
         # stays at the model's limit, so those tail positions are never
         # admitted into
         self.Smax = -(-int(budget) // 128) * 128
-        self._rng = jax.random.PRNGKey(seed)
 
         # the cache is the tree the model's attention says (per-head K/V, or
         # latent attention's shared rotary key + latent: ``tfm.cache_layout``);
@@ -329,6 +328,12 @@ class SlotWorker:
         # inferred output sharding that differs from the input's would give
         # the next call a differently-sharded operand and silently recompile
         self._cache_shardings = shardings(self._cache_sharding)
+        # the key lives on the device from here on: every program that draws
+        # takes it, splits it INSIDE and hands the carried half back (``_run``
+        # stores that and never fetches it). Placed where the programs return
+        # it, so a program's first call and its later ones hand it the same
+        # kind of operand: one compile
+        self._rng = jax.device_put(jax.random.PRNGKey(seed), self._key_sharding())
         self._cache = jax.jit(
             partial(tfm.init_cache, self.cfg, self.n_slots, self.Smax,
                     dtype=self.cfg.dtype),
@@ -411,13 +416,14 @@ class SlotWorker:
             # garbage and discarded. Computed in the SAME program: the
             # one-compiled-decode-step contract holds.
             bad = jnp.any(~jnp.isfinite(logits[:, 0]), axis=-1)
-            nxt = sample_logits_vector(logits[:, 0], rng, temp, top_k, top_p)
-            return (cache, jnp.where(active, nxt, 0), bad, *load)
+            rng, k = jax.random.split(rng)
+            nxt = sample_logits_vector(logits[:, 0], k, temp, top_k, top_p)
+            return (cache, rng, jnp.where(active, nxt, 0), bad, *load)
 
         # all serving programs donate the slot KV cache / prefix pool —
         # XLA-created device buffers, never CPU zero-copy host memory, so
         # donation stays on every backend (utils/donation.py is the gate)
-        return donated_jit(decode, donate_argnums=(1,), out_shardings=self._outs(2))
+        return self._keyed_jit(decode, 4, 2)
 
     def _build_verify(self, depth: int, greedy_only: bool = False):
         cfg = self.cfg
@@ -475,13 +481,14 @@ class SlotWorker:
             # the sentinel spans ALL depth+1 positions: a NaN anywhere in
             # the block poisons the accept/bonus math for that slot
             bad = jnp.any(~jnp.isfinite(logits), axis=(1, 2))
+            rng, k = jax.random.split(rng)
             accept, resample, clean = verify_logits_vector(
-                logits, toks[:, 1:], rng, temp, top_k, top_p)
+                logits, toks[:, 1:], k, temp, top_k, top_p)
             on = active[:, None]
-            return (cache, accept & on, jnp.where(on, resample, 0),
+            return (cache, rng, accept & on, jnp.where(on, resample, 0),
                     jnp.where(on, clean, 0), bad, *load)
 
-        return donated_jit(verify, donate_argnums=(1,), out_shardings=self._outs(4))
+        return self._keyed_jit(verify, 4, 4)
 
     def _build_prefill(self, bucket: int):
         cfg = self.cfg
@@ -501,10 +508,11 @@ class SlotWorker:
                 cfg, params, prompt, local, 0, jnp.arange(bucket)[None, :] < true_len,
                 last_index=true_len - 1)
             bad = jnp.any(~jnp.isfinite(logits[:, 0]), axis=-1)
-            tok = sample_logits_vector(logits[:, 0], rng, temp, top_k, top_p)
-            return (tfm.update_cache_slot(cache, local, slot), tok, bad, *load)
+            rng, k = jax.random.split(rng)
+            tok = sample_logits_vector(logits[:, 0], k, temp, top_k, top_p)
+            return (tfm.update_cache_slot(cache, local, slot), rng, tok, bad, *load)
 
-        return donated_jit(prefill, donate_argnums=(1,), out_shardings=self._outs(2))
+        return self._keyed_jit(prefill, 3, 2)
 
     def _build_chunk(self, width: int):
         cfg = self.cfg
@@ -535,15 +543,17 @@ class SlotWorker:
             # NaN mid-prompt propagates through attention to every later
             # chunk, so the final chunk's sentinel covers the whole prefill
             bad = jnp.any(~jnp.isfinite(logits[:, 0]), axis=-1)
-            tok = sample_logits_vector(logits[:, 0], rng, temp, top_k, top_p)
+            rng, k = jax.random.split(rng)
+            tok = sample_logits_vector(logits[:, 0], k, temp, top_k, top_p)
             # write back ONLY the chunk's region [start, start+width) — the
             # rest of the window is unchanged, and splatting all Smax
             # positions per chunk would multiply the cache-write bandwidth
             # by Smax/width on exactly the prompt-side hot path
             new_kv = tfm.slice_cache_slot(local, 0, width, start=start)
-            return (tfm.update_cache_slot(cache, new_kv, slot, start=start), tok, bad, *load)
+            return (tfm.update_cache_slot(cache, new_kv, slot, start=start), rng, tok, bad,
+                    *load)
 
-        return donated_jit(chunk, donate_argnums=(1,), out_shardings=self._outs(2))
+        return self._keyed_jit(chunk, 4, 2)
 
     def _build_fetch(self):
         pmax = self.pmax
@@ -616,6 +626,30 @@ class SlotWorker:
         experts chosen (``_forward``)."""
         routed = self.cfg.moe_routing == "dropless"
         return (self._cache_shardings,) + (None,) * (n + 2 * routed)
+
+    def _key_sharding(self):
+        """Where the carried key lives: replicated over the devices of the cache.
+        On one device that is said without the mesh, as a key made by hand
+        (``jax.random.PRNGKey``) is typed: jit traces by the operands' types, the
+        mesh among them, so such a key finds the served program's own trace."""
+        on = jax.tree.leaves(self._cache_shardings)[0]
+        if len(on.device_set) == 1:
+            return SingleDeviceSharding(*on.device_set)
+        return NamedSharding(on.mesh, PartitionSpec())
+
+    def _keyed_jit(self, fun, n_head: int, n: int):
+        """The jit of a program that draws: ``fun(params, cache, *n_head operands,
+        rng, temp, top_k, top_p)`` splits the worker's key ``rng`` and hands the
+        carried half back behind the cache, ahead of its ``n`` host-bound values.
+        The key's sharding is pinned coming in and going out, as the cache's is
+        going out: the key one call returns is the next call's operand, whichever
+        program that is, and a key typed by hand (a lowering for the compiler's
+        memory account) lowers to the same module as a served call's."""
+        cache, *rest = self._outs(n)
+        key = self._key_sharding()
+        return donated_jit(fun, donate_argnums=(1,),
+                           in_shardings=(None,) * (2 + n_head) + (key, None, None, None),
+                           out_shardings=(cache, key, *rest))
 
     # -- dispatches ------------------------------------------------------
 
@@ -700,17 +734,17 @@ class SlotWorker:
              key: bool = True, fetch: bool = True, **rows):
         """The scaffold of the four worker calls (``decode``, ``verify``,
         ``prefill``, ``chunk``), and the ONE place their spans are opened. A
-        call is one span ``name`` with two children and, under those, the five
-        parts a call's cost beyond the device's work is made of:
+        call makes ONE trip into the runtime, the call of its own program:
+        nothing eager (no program of its own, no upload of its own) runs before
+        it. A call is one span ``name`` with two children and, under those, the
+        four parts a call's cost beyond the device's work is made of:
 
           dispatch            entry until the program's call has returned: before
                               that the device cannot start. Its self time is
                               ``program()``: the look-up, or the first build
             dispatch/operands ``operands(sp)`` -> (the program's operands behind
-                              params and cache, its sampler rows): every host
-                              conversion and every eager upload of one
-            dispatch/key      ``jax.random.split``: a device program of its own
-                              (none with ``key=False``: greedy ``verify``)
+                              params and cache, its sampler rows): host
+                              conversions only, every one a numpy array or scalar
             dispatch/enqueue  the watched program's call: the proxy's bookkeeping,
                               pjit's argument path over the parameter tree, the
                               batched upload of the host operands, the enqueue
@@ -721,6 +755,16 @@ class SlotWorker:
                               runtime's launch latency and the device's run
             fetch/copy        ``device_get`` of the same outputs, as numpy: what
                               is left of the copy back once they are ready
+
+        The key (``key=True``: every call but a greedy ``verify``, whose program
+        draws nothing) goes in as the device array ``self._rng`` between the two
+        groups of operands; the program splits it and returns the carried half
+        first behind the cache, which is stored and never fetched: like the
+        cache it is a future until something waits for the call (PR 36; the
+        eager split of the key it replaces was a program launch of 0.83 ms
+        a call on the host for 3 us of device time). The operand LISTS of the
+        four programs are pinned: ``chipbench/drivers/serve.py::_memory_analysis``
+        and ``chipbench/rehearse_compile.py`` type them by position.
 
         All outputs of one program become ready together, and ``wait`` asks for
         the copies before it waits, as ``device_get`` itself does first: the
@@ -734,31 +778,30 @@ class SlotWorker:
 
         On the call's span: ``compiled`` (a call that compiled is no latency
         datum, and the scheduler's heartbeat exempts its step), ``h2d``, the
-        separate host->device operands of the call (every operand behind params
-        and cache is a host array handed to the program or an upload made for
-        it; the key split's program counts as one) and ``d2h``, the separate
-        arrays fetched (the experts a ``routing_log`` asks for are not among
-        them). Returns ``(span, the n_out fetched arrays or None)``; what
-        follows them in the fetch is a routed model's load, noted with ``rows``."""
+        host arrays handed to the program (every operand behind params and
+        cache but the carried key, a device operand and no upload) and
+        ``d2h``, the separate arrays fetched (the experts a ``routing_log`` asks
+        for are not among them). Returns ``(span, the n_out fetched arrays or
+        None)``; what follows them in the fetch is a routed model's load, noted
+        with ``rows``."""
         tm = self.telemetry
         with tm.span(name, **attrs) as sp:
             with tm.span("dispatch"):
                 with tm.span("operands"):
                     head, sampler = operands(sp)
                 prog = program()
-                keys = ()
-                if key:
-                    with tm.span("key"):
-                        self._rng, k = jax.random.split(self._rng)
-                    keys = (k,)
                 with tm.span("enqueue"):
-                    self._cache, *out = prog(self.params, self._cache, *head, *keys, *sampler)
+                    if key:
+                        self._cache, self._rng, *out = prog(
+                            self.params, self._cache, *head, self._rng, *sampler)
+                    else:
+                        self._cache, *out = prog(self.params, self._cache, *head, *sampler)
             compiled = bool(prog.last_call_compiled)
             self.step_compiled |= compiled
             chosen = None  # a routed model's choices: a device array no fetch waits for
             if self.cfg.moe_routing == "dropless":
                 *out, chosen = out
-            sp.annotate(compiled=compiled, h2d=len(head) + len(keys) + len(sampler),
+            sp.annotate(compiled=compiled, h2d=len(head) + len(sampler),
                         d2h=len(out) if fetch else 0)
             if not fetch:
                 return sp, None
@@ -790,8 +833,10 @@ class SlotWorker:
         attrs = dict(n_active=n_active,
                      cached_tokens=int(np.sum((np.asarray(pos) + 1)[np.asarray(active, bool)])),
                      attn=tfm.cache_step_form(self.cfg), **self._state_attrs(n_active))
-        # host arrays straight into the jitted call (pjit batches the uploads);
-        # dtypes are pinned by the engine's per-slot state arrays
+        # host arrays straight into the jitted call (pjit batches the uploads;
+        # ``h2d`` counts them: seven here), the carried key between them as the
+        # one device operand; dtypes are pinned by the engine's per-slot state
+        # arrays. The operand list is pinned too (``_run``)
         sp, (nxt, bad) = self._run(
             "decode", attrs, self._decode_prog,
             lambda sp: ((last_tok, pos, np.asarray(wpos, np.int32), active),
@@ -818,8 +863,9 @@ class SlotWorker:
                      attn=tfm.cache_step_form(self.cfg), **({"warm": True} if warm else {}))
         # host arrays go straight into the jitted call: pjit's C++ argument
         # path uploads them in one batch, and the greedy family's trimmed
-        # signature (no rng/temp/top_k/top_p — dead operands there) skips both
-        # the uploads and the per-step key split
+        # signature (no rng/temp/top_k/top_p — dead operands there) skips the
+        # uploads and leaves the worker's key where it is (``key=False``); the
+        # sampled family takes and returns it like ``decode``
         sp, out = self._run(
             "verify", attrs, lambda: self._verify_prog(depth, greedy_only),
             lambda sp: ((toks, pos, np.asarray(wpos, np.int32), active),
@@ -853,7 +899,7 @@ class SlotWorker:
                      **self._block_attrs(bucket, true_len))
         sp, (tok, bad) = self._run(
             "prefill", attrs, lambda: self._prefill_prog(bucket),
-            lambda sp: ((jnp.asarray(padded), jnp.int32(slot), jnp.int32(true_len)),
+            lambda sp: ((np.asarray(padded, np.int32), np.int32(slot), np.int32(true_len)),
                         self._sampler_rows(sp, temperature, top_k, top_p)),
             2, uid=uid, slot=slot, true_len=true_len)
         if not sp.attrs["compiled"]:
@@ -876,8 +922,8 @@ class SlotWorker:
                      attn=tfm.cache_step_form(self.cfg), **self._block_attrs(width, live))
         sp, out = self._run(
             "chunk", attrs, lambda: self._chunk_prog(width),
-            lambda sp: ((jnp.asarray(toks), jnp.int32(slot), jnp.int32(start),
-                         jnp.int32(live)),
+            lambda sp: ((np.asarray(toks, np.int32), np.int32(slot), np.int32(start),
+                         np.int32(live)),
                         self._sampler_rows(sp, temperature, top_k, top_p)),
             2, fetch=fetch, uid=uid, slot=slot, start=start, live=live)
         tm.counter(f"serving/chunk_bucket[{width}]").inc()

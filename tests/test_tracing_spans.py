@@ -6,9 +6,12 @@ Contracts under test:
     a span that raises is still recorded and leaves the nesting intact;
   * ``ServingEngine.step`` leaves one ``serve/step`` per iteration whose
     children nest as PERF.md's span table says, one ``dispatch`` + ``fetch``
-    under each worker call with the five parts of a call under those
-    (``operands`` / ``key`` / ``enqueue``, ``wait`` / ``copy``) and ``h2d`` /
-    ``d2h`` on the call, request-labelled prefill/chunk spans, and the latency
+    under each worker call with the four parts of a call under those
+    (``operands`` / ``enqueue``, ``wait`` / ``copy``; no ``key``: the key is
+    split inside the call's own program since PR 36, and nothing eager runs
+    inside a call) and ``h2d`` / ``d2h`` on the call, a served stream that is
+    the eager split's to the token and to the carried key,
+    request-labelled prefill/chunk spans, and the latency
     histograms are fed from exactly the spans that did not compile; no span
     feeds a ``span/<path>`` histogram;
   * ``train_batch`` leaves ``train/train_batch`` with ``pre`` / ``dispatch``
@@ -183,7 +186,7 @@ PARENTS = {
     "decode": {"serve/step"}, "verify": {"serve/step"},
     "prefill": {"admit"}, "chunk": {"chunks", "admit"},
     "dispatch": set(WORKER_CALLS), "fetch": set(WORKER_CALLS),
-    "operands": {"dispatch"}, "key": {"dispatch"}, "enqueue": {"dispatch"},
+    "operands": {"dispatch"}, "enqueue": {"dispatch"},
     "wait": {"fetch"}, "copy": {"fetch"},
 }
 
@@ -295,7 +298,7 @@ def test_serving_spans(tiny_serving_engine, mode):
 
     if mode == "plain":
         assert {sp.name for sp in spans} == {"serve/step", "sweep", "admit", "prefill", "decode",
-                                             "dispatch", "operands", "key", "enqueue",
+                                             "dispatch", "operands", "enqueue",
                                              "fetch", "wait", "copy", "emit"}
         assert len(prefills) == len(reqs) and not chunks
     if mode == "chunked":
@@ -367,7 +370,7 @@ def test_worker_call_outside_step_has_a_short_path(tiny_serving_engine):
     since = time.perf_counter()
     srv.warm_verify()
     got = tracing.spans(since)
-    # the greedy family draws nothing: no key split, so no ``key`` span
+    # the same tree as every other call: no kind of call has a ``key`` span
     assert {sp.path for sp in got} == {
         "verify", "verify/dispatch", "verify/dispatch/operands", "verify/dispatch/enqueue",
         "verify/fetch", "verify/fetch/wait", "verify/fetch/copy"}
@@ -377,16 +380,16 @@ def test_worker_call_outside_step_has_a_short_path(tiny_serving_engine):
 
 
 # ---------------------------------------------------------------------------
-# the anatomy of a worker call (SlotWorker._run): five parts, two counters
+# the anatomy of a worker call (SlotWorker._run): four parts, two counters
 # ---------------------------------------------------------------------------
 
 # call kind -> (serving mode that makes it, request keywords, host operands, device operands,
 # arrays fetched): what the call hands its program behind params and cache, by where it lives
-# when handed over (a device operand is an upload made for the call, or the split key)
+# when handed over (the one device operand is the carried key: no upload, so not in ``h2d``)
 ANATOMY = {
     "decode": ("plain", {}, 7, 1, 2),
-    "prefill": ("plain", {}, 3, 4, 2),  # prompt, slot, true_len uploaded eagerly; the key
-    "chunk": ("chunked", {}, 3, 5, 2),
+    "prefill": ("plain", {}, 6, 1, 2),  # prompt, slot, true_len ride the program's own upload
+    "chunk": ("chunked", {}, 7, 1, 2),
     "verify": ("speculation", {}, 4, 0, 1),  # greedy: no key, no sampler rows, ONE packed array
     "verify-sampled": ("speculation", {"temperature": 0.8, "top_k": 1}, 7, 1, 4),  # the argmax, drawn
 }
@@ -415,12 +418,12 @@ def _by_parent(spans):
 
 
 @pytest.mark.parametrize("kind", list(ANATOMY))
-def test_worker_call_has_five_parts_in_order(tiny_serving_engine, kind):
-    """``operands`` / ``key`` / ``enqueue`` under ``dispatch`` and ``wait`` /
-    ``copy`` under ``fetch``: in that order, disjoint, inside their parent, and
-    covering it but for the program look-up between ``operands`` and what
-    follows. A greedy ``verify`` has no ``key``; a chunk left asynchronous has no
-    ``fetch`` and so neither of its parts."""
+def test_worker_call_has_four_parts_in_order(tiny_serving_engine, kind):
+    """``operands`` / ``enqueue`` under ``dispatch`` and ``wait`` / ``copy``
+    under ``fetch``: in that order, disjoint, inside their parent, and covering
+    it but for the program look-up between ``operands`` and ``enqueue``. No kind
+    of call has a ``key`` span, and the ring holds none; a chunk left
+    asynchronous has no ``fetch`` and so neither of its parts."""
     mode, ask, *_ = ANATOMY[kind]
     _, _, spans = _serve(tiny_serving_engine, mode, **ask)
     kids = _by_parent(spans)
@@ -430,9 +433,8 @@ def test_worker_call_has_five_parts_in_order(tiny_serving_engine, kind):
     uncovered = []
     for call in calls:
         dispatch, *rest = kids[call.id]
-        want = ["operands", "enqueue"] if kind == "verify" else ["operands", "key", "enqueue"]
         parts = kids[dispatch.id]
-        assert [sp.name for sp in parts] == want, call.path
+        assert [sp.name for sp in parts] == ["operands", "enqueue"], call.path
         groups = [(dispatch, parts)]
         if call.attrs.get("fetch") is False:
             assert not rest and call.attrs["d2h"] == 0
@@ -449,6 +451,7 @@ def test_worker_call_has_five_parts_in_order(tiny_serving_engine, kind):
             uncovered.append(up.dur_s - sum(sp.dur_s for sp in below) - lookup)
     # what no part covers is a few Python statements: microseconds, on any machine
     assert np.median(uncovered) < 2e-4, np.median(uncovered)
+    assert not any(sp.name == "key" for sp in spans)
     if kind == "chunk":
         assert any(c.attrs["fetch"] is False for c in calls)
 
@@ -456,10 +459,10 @@ def test_worker_call_has_five_parts_in_order(tiny_serving_engine, kind):
 @pytest.mark.parametrize("kind", list(ANATOMY))
 def test_h2d_and_d2h_are_what_the_call_hands_over_and_fetches(tiny_serving_engine, monkeypatch,
                                                               kind):
-    """``h2d`` = the host arrays given to the program + the uploads made for it
-    + one for the key split's program (its result is the one device operand
-    that is no upload); ``d2h`` = the arrays ``device_get`` is handed: counted
-    here from outside, at the program's call and at ``jax.device_get``."""
+    """``h2d`` = the host arrays given to the program (the carried key is the
+    one device operand, and no upload); ``d2h`` = the arrays ``device_get`` is
+    handed: counted here from outside, at the program's call and at
+    ``jax.device_get``."""
     import jax
 
     from deepspeed_tpu.inference import ServingEngine
@@ -492,12 +495,72 @@ def test_h2d_and_d2h_are_what_the_call_hands_over_and_fetches(tiny_serving_engin
     for ops in mine:
         assert sum(isinstance(x, jax.Array) for x in ops) == n_device
         assert sum(isinstance(x, (np.ndarray, np.generic)) for x in ops) == n_host
-    assert {sp.attrs["h2d"] for sp in calls} == {n_host + n_device}
+    assert {sp.attrs["h2d"] for sp in calls} == {n_host}
     fetching = [sp for sp in calls if sp.attrs.get("fetch") is not False]
     assert {sp.attrs["d2h"] for sp in fetching} == {n_fetched}
     # every fetch of the run is some call's: none is made beside the scaffold
     assert sorted(fetched) == sorted(sp.attrs["d2h"] for sp in calls + others
                                      if sp.attrs.get("fetch") is not False)
+
+
+@pytest.mark.parametrize("kind", list(ANATOMY))
+def test_worker_call_goes_into_the_runtime_once(tiny_serving_engine, monkeypatch, kind):
+    """Between the edges of a call span nothing eager runs: no key split, no
+    ``jnp`` conversion, no upload of its own. The call's one trip into the
+    runtime is its own program's. Checked on warm programs (a first call
+    traces, and the traced body splits the key), with the eager entry points
+    made to raise while ``_run`` is on the stack."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference import ServingEngine
+    from deepspeed_tpu.inference.serving import Request, SlotWorker
+
+    mode, ask, *_ = ANATOMY[kind]
+    name = kind.split("-")[0]
+    srv = ServingEngine(tiny_serving_engine, n_slots=2, max_seq_len=128,
+                        replica_id=f"once-{kind}", **SERVING[mode])
+    rng = np.random.default_rng(13)
+    prompts = [np.tile(rng.integers(0, 97, size=4), 10)[:n].astype(np.int32) for n in (9, 40)]
+
+    def served(first_uid):
+        reqs = [Request(uid=first_uid + i, prompt=p, max_new_tokens=6, **ask)
+                for i, p in enumerate(prompts)]
+        results = srv.serve(reqs)
+        return [list(map(int, results[r.uid].tokens)) for r in reqs if results[r.uid].ok]
+
+    warm = served(0)  # every program of the run compiles here
+    inside, eager = [], []
+    run = SlotWorker._run
+
+    def watched(self, *args, **kwargs):
+        inside.append(args[0])
+        try:
+            return run(self, *args, **kwargs)
+        finally:
+            inside.pop()
+
+    def refuse(what, fn):
+        def guarded(*args, **kwargs):
+            if inside:
+                eager.append((inside[-1], what))
+                raise AssertionError(f"{what} inside a {inside[-1]} call")
+            return fn(*args, **kwargs)
+        return guarded
+
+    monkeypatch.setattr(SlotWorker, "_run", watched)
+    for owner, attr in ((jax.random, "split"), (jax, "device_put"), (jnp, "asarray"),
+                        (jnp, "array"), (type(jnp.int32), "__call__")):
+        monkeypatch.setattr(owner, attr, refuse(f"{owner.__name__}.{attr}", getattr(owner, attr)))
+    since = time.perf_counter()
+    again = served(10)
+    monkeypatch.undo()
+    assert not eager and len(again) == len(prompts)
+    calls = [sp for sp in tracing.spans(since) if sp.replica_id == srv.replica_id
+             and sp.name == name]
+    assert calls and not any(sp.attrs["compiled"] for sp in calls)
+    if not ask:
+        assert again == warm  # greedy rows: the same prompts give the same tokens
 
 
 def test_a_routed_model_fetches_one_array_more():
@@ -518,8 +581,20 @@ def test_a_routed_model_fetches_one_array_more():
     assert all(r.ok for r in srv.serve(reqs).values())
     calls = {sp.name: sp for sp in tracing.spans(since) if sp.name in ("prefill", "decode")}
     assert {k: (sp.attrs["h2d"], sp.attrs["d2h"]) for k, sp in calls.items()} == {
-        "prefill": (7, 3), "decode": (8, 3)}
+        "prefill": (6, 3), "decode": (7, 3)}
     assert "expert_load_max_over_mean" in calls["decode"].attrs
+
+
+STREAM_SEED = 5
+
+
+def _stream_requests():
+    from deepspeed_tpu.inference.serving import Request
+
+    rng = np.random.default_rng(9)
+    return [Request(uid=i, prompt=np.tile(rng.integers(0, 97, size=4), 8)[:n].astype(np.int32),
+                    max_new_tokens=8, temperature=0.9 if i % 2 else 0.0,
+                    top_p=0.8 if i == 3 else 1.0) for i, n in enumerate((7, 30, 12, 21))]
 
 
 def test_wait_then_copy_changes_no_token(tiny_serving_engine, monkeypatch):
@@ -530,15 +605,11 @@ def test_wait_then_copy_changes_no_token(tiny_serving_engine, monkeypatch):
     import jax
 
     from deepspeed_tpu.inference import ServingEngine
-    from deepspeed_tpu.inference.serving import Request
 
     def stream(mode):
-        srv = ServingEngine(tiny_serving_engine, n_slots=2, max_seq_len=128, seed=5,
+        srv = ServingEngine(tiny_serving_engine, n_slots=2, max_seq_len=128, seed=STREAM_SEED,
                             **SERVING[mode])
-        rng = np.random.default_rng(9)
-        reqs = [Request(uid=i, prompt=np.tile(rng.integers(0, 97, size=4), 8)[:n].astype(np.int32),
-                        max_new_tokens=8, temperature=0.9 if i % 2 else 0.0,
-                        top_p=0.8 if i == 3 else 1.0) for i, n in enumerate((7, 30, 12, 21))]
+        reqs = _stream_requests()
         results = srv.serve(reqs)
         assert all(r.ok for r in results.values())
         return [list(map(int, results[r.uid].tokens)) for r in reqs]
@@ -548,6 +619,106 @@ def test_wait_then_copy_changes_no_token(tiny_serving_engine, monkeypatch):
         with monkeypatch.context() as m:
             m.setattr(jax, "block_until_ready", lambda out: out)
             assert stream(mode) == with_wait, mode
+
+
+# ---------------------------------------------------------------------------
+# the key: split inside the programs, carried on the device (PR 36)
+# ---------------------------------------------------------------------------
+
+def _eager_split_form(worker, monkeypatch):
+    """Make ``worker`` draw as it did before PR 36, written out: the HOST holds
+    the key and splits it eagerly before every call that takes one, and the
+    program draws with the half it is handed. The programs are this tree's, traced
+    with their inner split turned into "carry and draw with the operand"; the
+    chain of keys is kept here, by hand, from the same seed."""
+    import jax
+    import jax.numpy as jnp
+
+    split = jax.random.split
+    chain = [jax.random.PRNGKey(STREAM_SEED)]
+    monkeypatch.setattr(jax.random, "split", lambda key, num=2: (
+        jnp.stack([key, key]) if num == 2 else split(key, num)))
+    run = worker._run
+
+    def eager(*args, key=True, **kwargs):
+        if key:
+            chain[0], k = split(chain[0])
+            worker._rng = jax.device_put(k, worker._key_sharding())
+        return run(*args, key=key, **kwargs)
+
+    monkeypatch.setattr(worker, "_run", eager)
+    return chain
+
+
+@pytest.mark.parametrize("mode", list(SERVING))
+def test_stream_and_carried_key_are_the_eager_splits(tiny_serving_engine, monkeypatch, mode):
+    """Greedy and sampled rows mixed: the tokens are those of a stream whose
+    keys the host draws by hand with the eager split from the same seed; the
+    key the worker carries after *k* keyed calls is *k* eager splits of
+    ``PRNGKey(seed)``; and every program compiled once, warm-up included."""
+    import jax
+
+    from deepspeed_tpu.inference import ServingEngine
+
+    def engine(tag):
+        return ServingEngine(tiny_serving_engine, n_slots=2, max_seq_len=128, seed=STREAM_SEED,
+                             replica_id=f"key-{tag}-{mode}", **SERVING[mode])
+
+    def stream(srv):
+        if mode == "speculation":
+            srv.warm_verify()  # greedy family: takes no key, moves none
+        reqs = _stream_requests()
+        since = time.perf_counter()
+        results = srv.serve(reqs)
+        assert all(r.ok for r in results.values())
+        keyed = [sp for sp in tracing.spans(since) if sp.replica_id == srv.replica_id
+                 and sp.name in WORKER_CALLS and not (sp.name == "verify" and sp.attrs["h2d"] == 4)]
+        return [list(map(int, results[r.uid].tokens)) for r in reqs], len(keyed)
+
+    srv = engine("inside")
+    tokens, keyed = stream(srv)
+    key = jax.random.PRNGKey(STREAM_SEED)
+    for _ in range(keyed):
+        key, _ = jax.random.split(key)
+    assert keyed and np.array_equal(np.asarray(srv.worker._rng), np.asarray(key))
+    counts = srv.compile_counts()
+    assert counts["decode"] == 1
+    for family in ("prefill", "chunk_prefill"):
+        assert set(counts.get(family, {}).values()) <= {1}, (family, counts)
+    # a depth's count folds its two families (all-greedy, mixed): one program each
+    assert set(counts.get("verify", {}).values()) <= {1, 2}, counts
+    assert counts["chunk_prefill" if mode == "chunked" else "prefill"]
+
+    with monkeypatch.context() as m:
+        by_hand = engine("by-hand")
+        chain = _eager_split_form(by_hand.worker, m)
+        assert stream(by_hand) == (tokens, keyed), mode
+        assert np.array_equal(np.asarray(chain[0]), np.asarray(key))
+
+
+def test_a_chunk_left_unfetched_advances_the_key_once(tiny_serving_engine):
+    """``fetch=False`` leaves the call asynchronous and the key a future like the
+    cache: it still moves by exactly one split, and a greedy ``verify`` by none."""
+    import jax
+
+    from deepspeed_tpu.inference import ServingEngine
+
+    srv = ServingEngine(tiny_serving_engine, n_slots=2, max_seq_len=128, seed=STREAM_SEED,
+                        chunked_prefill={"enabled": True, "chunk_size": 16},
+                        speculation={"enabled": True, "depth": 2})
+    w = srv.worker
+    want = jax.random.PRNGKey(STREAM_SEED)
+    assert np.array_equal(np.asarray(w._rng), np.asarray(want))
+    toks = np.arange(16, dtype=np.int32)[None, :]
+    assert w.chunk(16, toks, 0, 0, 16, 0.7, 0, 1.0, fetch=False) is None
+    want, _ = jax.random.split(want)
+    assert np.array_equal(np.asarray(w._rng), np.asarray(want))
+    tok, bad = w.chunk(16, toks, 0, 16, 5, 0.7, 0, 1.0, fetch=True)
+    want, k = jax.random.split(want)
+    assert np.array_equal(np.asarray(w._rng), np.asarray(want)) and not bad
+    srv.warm_verify()  # the greedy family: no key operand, the key stays
+    assert np.array_equal(np.asarray(w._rng), np.asarray(want))
+    assert w._rng.committed and w._rng.sharding == w._key_sharding()
 
 
 # ---------------------------------------------------------------------------
@@ -787,6 +958,25 @@ def test_anatomy_reader_on_a_hand_made_ring_and_trace(monkeypatch, name):
         1e-3 * (traced["runs"] * traced["device_ms"] + 80.0) + programs["other"], note["busy_s"])
     np.testing.assert_allclose([programs["jit_decode"], programs["other"]], [0.021, 0.501])
     assert programs["other_top"][0] == ("jit_decoder", 0.5)
+
+
+def test_anatomy_readers_on_a_ring_without_key_spans(monkeypatch):
+    """The program since PR 36: a call has no ``key`` part. Every reader reads what
+    it read (none takes ``key``), and the note's table says ``key`` None with the
+    other parts as they were."""
+    records = [r for r in HAND_ANATOMY if not r.path.endswith("/dispatch/key")]
+    assert len(records) < len(HAND_ANATOMY)
+    _patch_ring(monkeypatch, records)
+    notes = []
+    for name, want in ANATOMY_READERS.items():
+        reader = importlib.import_module(f"chipbench.layer_metrics.{name}")
+        np.testing.assert_allclose(reader.read(_anatomy_ctx(notes)), want, rtol=1e-9)
+    (note,) = notes
+    for kind, operands in (("decode", (0.5 * .5 + .5 + 0.25 * 1) / DECODE_RUNS), ("prefill", 3.0)):
+        traced = note[kind]["traced"]
+        assert traced["key"] is None and note[kind]["host"]["key"] is None
+        np.testing.assert_allclose(traced["operands"]["mean"], operands)
+        assert traced["enqueue"] and traced["wait"] and traced["copy"] and traced["dispatch"]
 
 
 @pytest.mark.parametrize("name", list(ANATOMY_READERS))
