@@ -257,7 +257,9 @@ def _forward(cfg, params, toks, cache, pos, live, **kw):
     outputs). A dense model has no extra output and its program is what it
     was. A model with dropless routing adds TWO. int32 [routed layers, E]: how
     many of the ``live`` rows (toks-shaped bool: not bucket padding, not an
-    idle slot) each layer sent to each expert; it comes back in the fetch
+    idle slot) each layer sent to each expert (with ``moe_experts_held``: to each
+    HELD expert, [routed layers, count + 1], the last column every row's pairs to
+    a held expert, live or not: what the program dispatched); it comes back in the fetch
     that brings the tokens and ``SlotWorker._note_load`` puts it on the span.
     Then the experts chosen themselves, int32 [routed layers, B, T, k], which
     the load was counted from: they stay on the device unless
@@ -267,7 +269,13 @@ def _forward(cfg, params, toks, cache, pos, live, **kw):
         return (*tfm.apply_with_cache(cfg, params, toks, cache, pos, live=live, **kw), ())
     logits, cache, chosen = tfm.apply_with_cache(
         cfg, params, toks, cache, pos, return_routing=True, live=live, **kw)
-    return logits, cache, (expert_load(chosen, live, cfg.num_experts), chosen)
+    held = cfg.moe_experts_held
+    load = expert_load(chosen, live, cfg.num_experts, held)
+    if held:  # one more column: every row's pairs to a held expert, padding and idle rows too
+        first, count = held
+        rows = jnp.sum((chosen >= first) & (chosen < first + count), axis=(1, 2, 3))
+        load = jnp.concatenate([load, rows[:, None].astype(load.dtype)], axis=-1)
+    return logits, cache, (load, chosen)
 
 
 class SlotWorker:
@@ -309,16 +317,18 @@ class SlotWorker:
         # TP axis where every leaf's heads divide (a latent is every head's:
         # it has one, and replicates)
         layout = tfm.cache_layout(self.cfg)
-        per_token = {name: tail for name, tail in layout.items() if name != tfm.STATE}
+        per_token = tfm.token_leaves(layout)
         cache_heads = min(heads for heads, _ in per_token.values())
         self.spec = kv_slot_cache_spec(self.mesh, self.n_slots, cache_heads)
         self._cache_sharding = NamedSharding(self.mesh, self.spec)
 
         def shardings(token_sharding):
-            """The sharding tree of a cache: its per-token leaves as given; its
-            per-sequence leaves [L, slots, ...] (a state-space mixer's state) over
-            the slots' axis alone."""
+            """The sharding tree of a cache: its per-token leaves, and a window
+            layer's rings, as given; its per-sequence leaves [L, slots, ...] (a
+            state-space mixer's state) over the slots' axis alone."""
             tree = {name: token_sharding for name in per_token}
+            if tfm.RING in layout:  # [L, slots, R, heads, width]: the axes of a per-token leaf
+                tree[tfm.RING] = {name: token_sharding for name in layout[tfm.RING]}
             if tfm.STATE in layout:
                 per_seq = NamedSharding(self.mesh, PartitionSpec(*token_sharding.spec[:2]))
                 tree[tfm.STATE] = {name: per_seq for name in layout[tfm.STATE]}
@@ -342,6 +352,8 @@ class SlotWorker:
         # what ONE decode step must read and write of per-sequence state, a live
         # row: every layer's leaves, once each way (0 for a model without)
         self.state_bytes_per_slot = self.cfg.num_layers * tfm.cache_state_bytes(self.cfg)
+        # how many layers keep a ring of ``local_attn_window`` positions a slot (0: none)
+        self.window_layers = len(self.cfg.window_layers)
         # where the programs read a routed layer's expert banks from ("in_place" /
         # "sliced"; None for a model without dropless routing): the rule they trace by
         self.expert_bank = tfm.expert_bank_form(self.cfg, self.params.get("moe"), self.mesh)
@@ -690,7 +702,11 @@ class SlotWorker:
         if self.routing_log is not None:
             self.routing_log.append({"span": sp.name, **rows,
                                      "chosen": np.asarray(chosen)})
-        summary = load_summary(load[0])
+        load = load[0]
+        if self.cfg.moe_experts_held:  # the share's own: what was held, what was dispatched
+            load, rows = load[:, :-1], load[:, -1]
+            sp.annotate(experts_held=int(load.shape[1]), expert_rows_held=int(rows.sum()))
+        summary = load_summary(load)
         sp.annotate(**summary)
         self.telemetry.gauge("serving/expert_load_max_over_mean").set(
             summary["expert_load_max_over_mean"])
@@ -705,6 +721,18 @@ class SlotWorker:
             return {}
         return {"state_rows": n_active,
                 "state_bytes": 2 * n_active * self.state_bytes_per_slot}
+
+    def _ring_attrs(self, live_positions) -> dict:
+        """What a span says of the window layers' rings (nothing for a model
+        without): ``window_layers``, how many layers keep one, and ``ring_tokens``,
+        the positions ONE of them read, summed over the call's live query rows
+        (a query at position p reads min(p + 1, window) of them);
+        ``cached_tokens`` beside it is ONE whole-context layer's."""
+        if not self.window_layers:
+            return {}
+        p = np.asarray(live_positions, np.int64).reshape(-1)
+        return {"window_layers": self.window_layers,
+                "ring_tokens": int(np.sum(np.minimum(p + 1, self.cfg.local_attn_window)))}
 
     def _block_attrs(self, rows: int, live: int) -> dict:
         """What a prefill or chunk span says of the block its program was traced
@@ -830,9 +858,10 @@ class SlotWorker:
         # rows whose per-sequence state the step advanced, and the bytes of it
         # the step had to read AND write
         n_active = int(np.count_nonzero(active))
-        attrs = dict(n_active=n_active,
-                     cached_tokens=int(np.sum((np.asarray(pos) + 1)[np.asarray(active, bool)])),
-                     attn=tfm.cache_step_form(self.cfg), **self._state_attrs(n_active))
+        live_pos = np.asarray(pos)[np.asarray(active, bool)]
+        attrs = dict(n_active=n_active, cached_tokens=int(np.sum(live_pos + 1)),
+                     attn=tfm.cache_step_form(self.cfg), **self._state_attrs(n_active),
+                     **self._ring_attrs(live_pos))
         # host arrays straight into the jitted call (pjit batches the uploads;
         # ``h2d`` counts them: seven here), the carried key between them as the
         # one device operand; dtypes are pinned by the engine's per-slot state
@@ -895,8 +924,9 @@ class SlotWorker:
         # ``attn``: the form the bucket's program was traced with (its local
         # cache is the bucket long: ``_build_prefill``)
         attrs = dict(uid=uid, slot=slot, bucket=bucket, true_len=true_len,
-                     attn=tfm.cache_attention_form(self.cfg.num_heads, 1, bucket, bucket),
-                     **self._block_attrs(bucket, true_len))
+                     attn=tfm.cache_block_form(self.cfg, bucket),
+                     **self._block_attrs(bucket, true_len),
+                     **self._ring_attrs(np.arange(true_len)))
         sp, (tok, bad) = self._run(
             "prefill", attrs, lambda: self._prefill_prog(bucket),
             lambda sp: ((np.asarray(padded, np.int32), np.int32(slot), np.int32(true_len)),
@@ -962,6 +992,10 @@ class SlotWorker:
             raise NotImplementedError(
                 f"{what} with a state-space mixer's recurrent state: the wire form carries "
                 "windows of per-token K/V and no per-sequence state")
+        if self.window_layers:
+            raise NotImplementedError(
+                f"{what} with window layers (local_attn_layers): the wire form carries windows "
+                "of per-token K/V and no ring of a window layer's last positions")
 
     def kv_export(self, width: int, slot: int, start: int):
         """Fetch one [start, start+width) KV window of ``slot`` to the host
@@ -1022,11 +1056,13 @@ class SlotWorker:
     def hbm_pools(self) -> dict:
         """Named device-memory pools this worker holds — the HBM ledger's
         rows (bytes from array metadata, no device sync)."""
-        state = self._cache.get(tfm.STATE, {})
+        state, ring = self._cache.get(tfm.STATE, {}), self._cache.get(tfm.RING, {})
         pools = {
             "params": tree_bytes(self.params),
-            "slot_kv_cache": tree_bytes(self._cache) - tree_bytes(state),
+            "slot_kv_cache": tree_bytes(self._cache) - tree_bytes(state) - tree_bytes(ring),
         }
+        if ring:  # the window layers' rings: constant in the sequence, like state
+            pools["slot_kv_ring"] = tree_bytes(ring)
         if state:  # a state-space mixer's per-sequence leaves: constant in the sequence
             pools["slot_state"] = tree_bytes(state)
         if self._pool is not None:
@@ -1161,6 +1197,12 @@ class ServingEngine:
                 f"serving role {self.role!r} with a state-space mixer (ssm_state_size > 0): the "
                 "prefill -> decode handoff (kv_export / kv_import) carries windows of per-token "
                 "K/V and no per-sequence recurrent state")
+        windowed = bool(engine.cfg.window_layers)
+        if self.role != "both" and windowed:
+            raise NotImplementedError(
+                f"serving role {self.role!r} with window layers (local_attn_layers): the prefill "
+                "-> decode handoff (kv_export / kv_import) carries windows of per-token K/V and "
+                "no ring of a window layer's last positions")
         n_slots = n_slots if n_slots is not None else config.get("n_slots", 8)
         max_seq_len = max_seq_len if max_seq_len is not None else config.get(
             "max_seq_len", 0)
@@ -1239,6 +1281,18 @@ class ServingEngine:
                     raise NotImplementedError(
                         f"{what} with a state-space mixer (ssm_state_size > 0) has no code: it "
                         "would run on a stale recurrent state; serve with it off")
+        if windowed:
+            # each moves the cache by POSITION, and a ring was overwritten past its
+            # window: a prefix's ring is not the ring after the prefix unless it is
+            # stored with it, a chunk entering past position 0 overwrites entries its
+            # first queries still see, a rejected draft cannot be rolled back out of it
+            for what, on in (("prefix_cache", pc.enabled), ("chunked_prefill", cp.enabled),
+                             ("speculation", sp.enabled)):
+                if on:
+                    raise NotImplementedError(
+                        f"{what} with window layers (local_attn_layers) has no code: a window "
+                        "layer keeps a ring of its last local_attn_window positions, which "
+                        "cannot be cut or rolled back at an old position; serve with it off")
         # the drafter is constructed eagerly so a bad draft_source fails at
         # engine build, not on the first decode step (draft_model needs the
         # model's vocab size to build its host-resident scorer)
@@ -1421,6 +1475,9 @@ class ServingEngine:
             f"({tfm.cache_step_form(self.cfg)})"
             + (f", recurrent state {self.worker.hbm_pools()['slot_state'] / 1e6:.1f} MB at "
                f"{self.worker.state_bytes_per_slot} B a slot" if recurrent else "")
+            + (f", rings {self.worker.hbm_pools()['slot_kv_ring'] / 1e6:.1f} MB at "
+               f"{tfm.cache_ring_bytes(self.cfg)} B a slot over {self.worker.window_layers} "
+               f"window layers of {self.cfg.local_attn_window}" if windowed else "")
             + f", spec={self.worker.spec}" + (", " + ", ".join(feat) if feat else ""),
             ranks=[0],
         )

@@ -61,9 +61,11 @@ class TransformerConfig:
     causal: bool = True  # False = bidirectional (BERT-style encoders)
     norm_style: str = "pre"  # pre (GPT) | post (BERT) layernorm placement
     norm_kind: str = "layer"  # layer (scale + bias) | rms (RMSNorm: scale only)
-    # RMSNorm with a learned scale over the whole query and the whole key
-    # projection, before the head split and the rotary (OLMoE's q_norm / k_norm)
-    qk_norm: bool = False
+    # RMSNorm with a learned scale on the queries and the keys, before the rotary.
+    # True: ONE norm over the whole projection, heads and head width together
+    # (OLMoE's q_norm / k_norm). "head": a norm of width ``head_dim`` on every head
+    # after the head split, one [head_dim] scale shared by the heads (EXAONE 4's)
+    qk_norm: Any = False
     # Grouped-query attention: this many key / value heads (0: num_heads), query
     # head i attending key / value head i // (num_heads // num_kv_heads); the
     # cache holds the key / value heads, never the repeated ones
@@ -101,10 +103,19 @@ class TransformerConfig:
     # is 1). ``init`` draws each matrix whose output one scales at 1 / (sqrt(fan
     # in) x the multiplier), so that a multiplier dropped from the program shows.
     multipliers: Optional[dict] = None
-    # GPT-Neo alternating local attention: window size + per-layer 0/1 flags
-    # (1 = local); None = all-global
+    # A layer's KIND, as data (``layer_kinds``). ``local_attn_layers``: per-layer
+    # 0/1 flags, 1 = the layer attends inside a sliding window of
+    # ``local_attn_window`` positions (key j visible to query i iff j <= i and
+    # i - j < window), 0 = over the whole context; None = every layer whole.
+    # GPT-Neo's alternating local layers and EXAONE's three sliding layers in
+    # four are this one thing. ``rotary_layers`` (``pos_emb="rotary"`` only):
+    # per-layer 0/1 flags, 0 = no rotary on that layer's q and k (EXAONE's full
+    # layers carry no position at all); None = every layer. Through the cache a
+    # window layer keeps a RING of ``local_attn_window`` positions a sequence and
+    # a whole layer ``Smax`` (``cache_layout``)
     local_attn_window: int = 0
     local_attn_layers: Optional[tuple] = None
+    rotary_layers: Optional[tuple] = None
     layernorm_epsilon: float = 1e-5
     tie_embeddings: bool = True
     use_bias: bool = True
@@ -188,6 +199,19 @@ class TransformerConfig:
     # (``intermediate_size`` stays the width of one expert)
     moe_first_dense: int = 0
     dense_intermediate_size: Optional[int] = None
+    # dropless only: (first, count), the experts THIS program holds of the
+    # ``num_experts`` the router chooses among: one chip's share of a layer that
+    # expert parallelism spreads over several. The router keeps its width and its
+    # ``moe_top_k`` a token; only the pairs whose expert is held are dispatched, and
+    # the layer's output is the shared expert plus the held experts' part (what
+    # the other chips would add is theirs). None: every expert
+    moe_experts_held: Optional[tuple] = None
+    # Multi-token-prediction modules behind the last layer (DeepSeek-V3 section
+    # 2.2; 0 or 1): [RMSNorm(Emb(t_{i+1})) ; RMSNorm(x_i before the final norm)]
+    # . W_eh, one block of the whole-context kind with no rotary and the model's
+    # last layer's feed-forward, a final norm of its own, the SHARED embedding
+    # and head -> logits for t_{i+2} (``apply(..., mtp_tokens=...)``)
+    mtp_layers: int = 0
     moe_capacity_factor: float = 1.25  # gshard only
     moe_aux_coeff: float = 0.01  # load-balancing loss weight
     loss_chunk_size: int = 512  # chunk the vocab projection in the loss; 0 = off
@@ -223,7 +247,33 @@ class TransformerConfig:
     def __post_init__(self):
         if self.multipliers is not None:
             object.__setattr__(self, "multipliers", _Stated(self.multipliers))
+        for name in ("local_attn_layers", "rotary_layers", "moe_experts_held"):
+            if isinstance(getattr(self, name), list):  # a JSON file's list: hashable
+                object.__setattr__(self, name, tuple(int(v) for v in getattr(self, name)))
         _refuse_uncoded(self)
+
+    @property
+    def layer_kinds(self):
+        """Per layer (window, rotary): the positions a query sees behind it (0:
+        the whole context) and whether its q and k are rotated. None where every
+        layer is of one kind and the loop need not tell them apart."""
+        if self.local_attn_layers is None and self.rotary_layers is None:
+            return None
+        L = self.num_layers
+        windows = [self.local_attn_window if on else 0
+                   for on in (self.local_attn_layers or (0,) * L)]
+        rotary = [bool(on) for on in (self.rotary_layers or (1,) * L)]
+        return tuple(zip(windows, rotary))
+
+    @property
+    def window_layers(self) -> tuple:
+        """The layers (model indices) that attend inside a sliding window."""
+        return tuple(l for l, on in enumerate(self.local_attn_layers or ()) if on)
+
+    @property
+    def experts_held(self) -> tuple:
+        """(first, count) of the experts this program holds."""
+        return tuple(self.moe_experts_held or (0, self.num_experts))
 
     @property
     def head_dim(self) -> int:
@@ -284,8 +334,10 @@ def _refuse_uncoded(cfg: "TransformerConfig") -> None:
         no_code = {
             f"pos_emb={cfg.pos_emb!r} (its positions are the rotary part of q and k)":
                 cfg.pos_emb != "rotary",
-            "qk_norm": cfg.qk_norm, "use_bias": cfg.use_bias, "causal=False": not cfg.causal,
-            "local_attn_layers": cfg.local_attn_layers is not None,
+            "qk_norm": bool(cfg.qk_norm), "use_bias": cfg.use_bias,
+            "causal=False": not cfg.causal,
+            "layer kinds (local_attn_layers / rotary_layers)": cfg.layer_kinds is not None,
+            "mtp_layers": cfg.mtp_layers != 0,
             "weight_bits": cfg.weight_bits != 0,
             f"attn_impl={cfg.attn_impl!r} (training attends through the XLA form: the flash "
             "backward kernels take one head size)": cfg.attn_impl != "xla",
@@ -307,6 +359,7 @@ def _refuse_uncoded(cfg: "TransformerConfig") -> None:
             "(kv_lora_rank > 0) have no code: a plain head has ONE width, qk_head_dim or "
             "hidden_size // num_heads")
     _refuse_uncoded_heads_and_mixer(cfg)
+    _refuse_uncoded_kinds_and_share(cfg)
     if cfg.moe_score_fn not in ("softmax", "sigmoid"):
         raise ValueError(f"moe_score_fn is 'softmax' or 'sigmoid', not {cfg.moe_score_fn!r}")
     forms = {"moe_score_fn": cfg.moe_score_fn != "softmax", "moe_select_bias": cfg.moe_select_bias,
@@ -364,7 +417,8 @@ def _refuse_uncoded_heads_and_mixer(cfg: "TransformerConfig") -> None:
             "causal=False (a recurrence runs one way)": not cfg.causal,
             "norm_style='post'": cfg.norm_style == "post",
             "parallel_residual (its feed-forward is sequential)": cfg.parallel_residual,
-            "local_attn_layers": cfg.local_attn_layers is not None,
+            "layer kinds (local_attn_layers / rotary_layers)": cfg.layer_kinds is not None,
+            "mtp_layers": cfg.mtp_layers != 0,
             "weight_bits": cfg.weight_bits != 0, "act_quant_bits": cfg.act_quant_bits != 0,
             "use_bias (only its convolution has a bias)": cfg.use_bias,
             "latent attention (kv_lora_rank > 0)": cfg.kv_lora_rank > 0,
@@ -399,8 +453,61 @@ def _refuse_uncoded_heads_and_mixer(cfg: "TransformerConfig") -> None:
         raise NotImplementedError("key_multiplier with latent attention has no code")
 
 
+def _refuse_uncoded_kinds_and_share(cfg: "TransformerConfig") -> None:
+    """``_refuse_uncoded`` for the layer kinds, the per-head q/k norm, the held
+    share of the experts and the multi-token-prediction module. (Kinds with
+    latent attention or the state-space mixer are refused beside those; a window
+    layer through the CACHE with alibi, a block entering a ring past position 0
+    and a padded block without ``live`` are refused where the cache path is
+    traced, ``_cache_attention``: ``apply`` has all of them.)"""
+    if cfg.qk_norm not in (False, True, "head"):
+        raise ValueError(f"qk_norm is False, True (one norm over the whole projection) or "
+                         f"'head' (one of width head_dim on every head), not {cfg.qk_norm!r}")
+    L = cfg.num_layers
+    for name, flags in (("local_attn_layers", cfg.local_attn_layers),
+                        ("rotary_layers", cfg.rotary_layers)):
+        if flags is not None and (len(flags) != L or any(f not in (0, 1) for f in flags)):
+            raise ValueError(f"{name} is one 0/1 flag a layer ({L} layers), got {flags!r}")
+    if cfg.local_attn_layers is not None and any(cfg.local_attn_layers) \
+            and cfg.local_attn_window <= 0:
+        raise ValueError("local_attn_layers without a local_attn_window > 0: no window to slide")
+    if cfg.rotary_layers is not None and cfg.pos_emb != "rotary":
+        raise ValueError(f"rotary_layers with pos_emb={cfg.pos_emb!r}: no rotary to switch off")
+    if cfg.moe_experts_held is not None:
+        first, count = (tuple(cfg.moe_experts_held) + (0, 0))[:2]
+        if cfg.moe_routing != "dropless":
+            raise NotImplementedError(
+                "moe_experts_held: only moe_routing='dropless' dispatches to a held share of "
+                f"the experts (got moe_routing={cfg.moe_routing!r})")
+        if len(cfg.moe_experts_held) != 2 or count < 1 or first < 0 \
+                or first + count > cfg.num_experts:
+            raise ValueError("moe_experts_held is (first, count) inside the num_experts "
+                             f"({cfg.num_experts}) the router chooses among, got "
+                             f"{cfg.moe_experts_held!r}")
+    if cfg.mtp_layers not in (0, 1):
+        raise NotImplementedError(
+            f"mtp_layers={cfg.mtp_layers}: one multi-token-prediction module has code (a chain "
+            "of several, each fed the one before it, has none)")
+    if cfg.mtp_layers:
+        no_code = {"norm_kind other than 'rms' (its two joining norms are RMSNorms)":
+                       cfg.norm_kind != "rms",
+                   "norm_style='post'": cfg.norm_style == "post",
+                   "causal=False": not cfg.causal, "param_offload": cfg.param_offload,
+                   "weight_bits": cfg.weight_bits != 0,
+                   "dropout or progressive layer drop":
+                       bool(cfg.hidden_dropout or cfg.attn_dropout or cfg.pld_enabled)}
+        for what, refused in no_code.items():
+            if refused:
+                raise NotImplementedError(f"mtp_layers with {what} has no code")
+
+
 def refuse_in_pipeline(cfg: "TransformerConfig") -> None:
     """What the pipeline schedules (pipe/) have not carried yet, by name."""
+    if cfg.rotary_layers is not None or cfg.mtp_layers:
+        raise NotImplementedError(
+            "rotary_layers / mtp_layers under a pipeline schedule have no code: a stage sees "
+            "its own layer indices, not the model's, and no stage owns the module behind the "
+            "last layer")
     if cfg.ssm_state_size > 0:
         raise NotImplementedError(
             "the state-space mixer (ssm_state_size > 0) under a pipeline schedule has no "
@@ -464,7 +571,9 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
             "wv": stack(keys[2], (d, Hkv, Dh), d, scale=attn_in)})
     if cfg.norm_kind != "rms":
         layers.update({"ln1_bias": jnp.zeros((L, d)), "ln2_bias": jnp.zeros((L, d))})
-    if cfg.qk_norm:
+    if cfg.qk_norm == "head":  # one [head_dim] scale, every head's
+        layers.update({"q_norm_scale": jnp.ones((L, Dh)), "k_norm_scale": jnp.ones((L, Dh))})
+    elif cfg.qk_norm:
         layers.update({"q_norm_scale": jnp.ones((L, H, Dh)),
                        "k_norm_scale": jnp.ones((L, Hkv, Dh))})
     if not dropless:  # no layer has a dense feed-forward there
@@ -514,7 +623,8 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
 
         lead, fd = cfg.moe_first_dense, cfg.dense_ffn_size
         params["moe"] = init_dropless(keys[9], L - lead, cfg.num_experts, d, f,
-                                      shared=cfg.moe_shared_size, select_bias=cfg.moe_select_bias)
+                                      shared=cfg.moe_shared_size, select_bias=cfg.moe_select_bias,
+                                      held=cfg.experts_held[1])
         if lead:
             params["dense_ffn"] = {"wg": stack(keys[10], (d, fd), d, lead),
                                    "wi": stack(keys[11], (d, fd), d, lead),
@@ -524,7 +634,35 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
 
         n_moe = cfg.num_layers // cfg.moe_every
         params["moe"] = init_moe_params(keys[9], n_moe, cfg.num_experts, d, f)
+    if cfg.mtp_layers:
+        params["mtp"] = _init_mtp(cfg, more[6])
     return params
+
+
+def _mtp_block_cfg(cfg: TransformerConfig) -> TransformerConfig:
+    """The configuration of the multi-token-prediction module's ONE block: the
+    model's own, of the whole-context kind with no rotary, its feed-forward the
+    model's last layer's (routed where the model routes)."""
+    return cfg.replace(num_layers=1, mtp_layers=0, moe_first_dense=0,
+                       dense_intermediate_size=None, local_attn_layers=None,
+                       rotary_layers=(0,) if cfg.pos_emb == "rotary" else None)
+
+
+def _init_mtp(cfg: TransformerConfig, key) -> dict:
+    """The module's leaves: the two joining norms, ``eh_proj`` [2d, d] (the
+    embedding's half first), one block drawn as the model's are, its final norm.
+    The embedding and the head are the model's own."""
+    d = cfg.hidden_size
+    k_proj, k_block = jax.random.split(key)
+    block = init(_mtp_block_cfg(cfg).replace(vocab_size=1, tie_embeddings=True,
+                                             pos_emb="none" if cfg.pos_emb == "learned"
+                                             else cfg.pos_emb), k_block)
+    out = {"enorm_scale": jnp.ones((d,)), "hnorm_scale": jnp.ones((d,)),
+           "eh_proj": _dense_init(k_proj, (2 * d, d), 2 * d),
+           "layers": block["layers"], "lnf_scale": jnp.ones((d,))}
+    if "moe" in block:
+        out["moe"] = block["moe"]
+    return out
 
 
 def _init_mixer(cfg: TransformerConfig, keys, stack) -> dict:
@@ -572,7 +710,9 @@ def logical_axes(cfg: TransformerConfig) -> Params:
                        "wv": ("layers", "embed", "heads", "kv")})
     if cfg.norm_kind != "rms":
         layers.update({"ln1_bias": ("layers", "embed"), "ln2_bias": ("layers", "embed")})
-    if cfg.qk_norm:
+    if cfg.qk_norm == "head":
+        layers.update({"q_norm_scale": ("layers", "kv"), "k_norm_scale": ("layers", "kv")})
+    elif cfg.qk_norm:
         layers.update({"q_norm_scale": ("layers", "heads", "kv"),
                        "k_norm_scale": ("layers", "heads", "kv")})
     if not dropless:
@@ -622,6 +762,11 @@ def logical_axes(cfg: TransformerConfig) -> Params:
         from ..moe.layer import moe_logical_axes
 
         axes["moe"] = moe_logical_axes()
+    if cfg.mtp_layers:
+        block = logical_axes(_mtp_block_cfg(cfg))
+        axes["mtp"] = {"enorm_scale": ("embed",), "hnorm_scale": ("embed",),
+                       "eh_proj": (None, "embed"), "layers": block["layers"],
+                       "lnf_scale": ("embed",), **({"moe": block["moe"]} if "moe" in block else {})}
     return axes
 
 
@@ -645,7 +790,7 @@ def logical_axes(cfg: TransformerConfig) -> Params:
 # compute dtype) are cast; ``ssm_dt_bias``, ``ssm_a_log``, ``ssm_d`` and the gated
 # norm's scale enter float32 arithmetic and are read in float32.
 _READ_IN_COMPUTE_DTYPE = frozenset({
-    "wte", "wpe", "lm_head",
+    "wte", "wpe", "lm_head", "eh_proj",
     "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo", "wkv_a", "wkv_b",
     "wi", "wo_mlp", "bi", "bo_mlp", "wg",
     "ssm_in", "ssm_out", "ssm_conv", "ssm_conv_bias",
@@ -1044,12 +1189,14 @@ def _dense_ffn(cfg, lp, h):
     return _ffn(cfg, lp, h), jnp.zeros((), jnp.float32), None
 
 
-def _qkv_proj(cfg: TransformerConfig, lp, h, positions):
+def _qkv_proj(cfg: TransformerConfig, lp, h, positions, rotary=True):
     """LN'd hidden states -> rotary-embedded q, k, v [B, T, H, Dh]. Latent
     attention gives what it caches in the place of k and v: the rotary key
     every head shares [B, T, 1, Dr] and the normed latent [B, T, 1, R]; the
     block's ``attend`` expands or absorbs them (``_latent_expand`` /
-    ``_latent_attention``)."""
+    ``_latent_attention``). ``rotary`` (``rotary_layers``): whether THIS layer
+    rotates, a Python bool where the loop knows the layer's kind and a traced
+    one where it scans layers of both (the rotated pair is then selected)."""
     with jax.named_scope("attn"):
         h = _act_q(cfg, h)
         h = _times(h, cfg.multiplier("attention_in_multiplier"))
@@ -1069,13 +1216,18 @@ def _qkv_proj(cfg: TransformerConfig, lp, h, positions):
             q = q + lp["bq"].astype(h.dtype)
             k = k + lp["bk"].astype(h.dtype)
             v = v + lp["bv"].astype(h.dtype)
-        if cfg.qk_norm:  # over the whole projection: heads and head dimension together
+        if cfg.qk_norm == "head":  # every head by itself, one [head_dim] scale for all
+            q = rms_norm(q, lp["q_norm_scale"], cfg.layernorm_epsilon)
+            k = rms_norm(k, lp["k_norm_scale"], cfg.layernorm_epsilon)
+        elif cfg.qk_norm:  # over the whole projection: heads and head dimension together
             q = rms_norm(q, lp["q_norm_scale"], cfg.layernorm_epsilon, axes=(-2, -1))
             k = rms_norm(k, lp["k_norm_scale"], cfg.layernorm_epsilon, axes=(-2, -1))
-        if cfg.pos_emb == "rotary":
+        if cfg.pos_emb == "rotary" and rotary is not False:
             rd = int(cfg.head_dim * cfg.rotary_pct)
-            q = rotary_embed(q, positions, rd, cfg.rotary_interleaved, cfg.rotary_base)
-            k = rotary_embed(k, positions, rd, cfg.rotary_interleaved, cfg.rotary_base)
+            turned = [rotary_embed(x, positions, rd, cfg.rotary_interleaved, cfg.rotary_base)
+                      for x in (q, k)]
+            q, k = turned if rotary is True else [jnp.where(rotary, t, x)
+                                                  for t, x in zip(turned, (q, k))]
         return q, k, v
 
 
@@ -1089,10 +1241,13 @@ def _attn_out_proj(cfg: TransformerConfig, lp, attn_out):
 
 
 # The cache tree's subtree of per-SEQUENCE leaves ([L, B, ...], no position
-# axis: overwritten whole by every step); every other leaf of the tree is
-# per-TOKEN ([L, B, Smax, heads, width]). ``cache_layout`` builds both kinds and
-# the helpers below it tell them apart by this key alone.
+# axis: overwritten whole by every step); every leaf of the tree outside it and
+# outside ``RING`` is per-TOKEN ([L, B, Smax, heads, width]). ``cache_layout``
+# builds the kinds and the helpers below it tell them apart by these keys alone.
 STATE = "state"
+# The subtree of RINGS ([L_window, B, R, heads, width]: a window layer's last R
+# positions, position p at index p mod R), moved whole like state.
+RING = "ring"
 
 
 def _ssm_scan(x, dt, A, Bm, Cm, S0, chunk: int):
@@ -1320,7 +1475,7 @@ NEG_BIAS = -1e30
 
 
 def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
-           rng=None, pld_keep=None, live=None):
+           rng=None, pld_keep=None, live=None, kind=None):
     """THE transformer layer: every caller's layer is this function.
 
     carry = (x [B, T, d] in the compute dtype, state); ``lp`` one layer's leaves;
@@ -1337,7 +1492,12 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
     - ``rng`` / ``pld_keep``: the training-only stochastic gates (dropout;
       progressive layer drop, one coin for BOTH residual branches);
     - ``live`` [B, T] bool or None: the rows that are a sequence's own, for a
-      model whose state-space mixer must not move on the others (``_ssm_mixer``).
+      model whose state-space mixer must not move on the others (``_ssm_mixer``);
+    - ``kind`` (window, rotary) or None: this layer's entry of ``cfg.layer_kinds``
+      where the loop knows it statically (``_layer_loop(by_kind=True)``: the cache
+      path, whose window layers keep another cache than its whole ones); it is
+      handed on to ``attend``. None: a loop that scans layers of every kind at
+      once (``apply``) reads the layer's flags at the traced ``l``.
 
     Norm kind and placement and the residual form are what ``cfg`` says, for a
     dense layer and a routed one alike (``norm_style="post"`` is the BERT
@@ -1361,8 +1521,11 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
 
     pre = cfg.norm_style != "post"
     h = norm(cfg, x, lp, "ln1") if pre else x
-    q, k, v = _qkv_proj(cfg, lp, h, positions)
-    attn, state = attend(q, k, v, state, l, lp)
+    rotary = True
+    if cfg.rotary_layers is not None:
+        rotary = kind[1] if kind is not None else jnp.asarray(cfg.rotary_layers, bool)[l]
+    q, k, v = _qkv_proj(cfg, lp, h, positions, rotary)
+    attn, state = attend(q, k, v, state, l, lp, **({} if kind is None else {"kind": kind}))
     attn_out = branch(_attn_out_proj(cfg, lp, attn), cfg.attn_dropout, k_attn)
     if cfg.ssm_state_size:
         mixed, state = _ssm_mixer(cfg, lp, h, state, l, live)
@@ -1428,7 +1591,7 @@ def _remat_wrapper(cfg: TransformerConfig):
 
 def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, attend,
                 per_layer=None, wrap=None, decode: bool = False, lead=None, live=None,
-                banks_in_place: bool = False):
+                banks_in_place: bool = False, by_kind: bool = False):
     """THE layer loop: ``_block`` over the stacked ``layers`` [L, ...] (the
     whole model's, or one pipeline stage's slice), ``moe`` the routed layers'
     stacks or None -> (x, state, summed aux loss, experts chosen or None).
@@ -1452,7 +1615,14 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
     leading dense layer or a period of several shifts it), which the routed
     feed-forward reads the banks through (``moe/dropless.py``). A backward
     pass wants the scanned slice (its cotangent is one layer's), so training
-    and the pipeline stages leave it off."""
+    and the pipeline stages leave it off. ``by_kind`` (the cache path's, for a
+    model whose layers are of several kinds): the loop hands every block its
+    layer's kind STATICALLY, scanning over whole periods of the kinds' pattern
+    (``_layer_loop_by_kind``)."""
+    if by_kind and cfg.layer_kinds is not None and len(set(cfg.layer_kinds)) > 1:
+        return _layer_loop_by_kind(cfg, layers, moe, x, state, positions=positions,
+                                   attend=attend, decode=decode, lead=lead, live=live,
+                                   banks_in_place=banks_in_place)
     layers_xs, load_layer = _make_stack_loader(cfg, layers)
     routed = cfg.moe_every > 0 and moe is not None
     moe_xs, load_moe = _make_stack_loader(cfg, moe) if routed else (None, None)
@@ -1529,6 +1699,100 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
     return (*carry, jnp.sum(aux), chosen)
 
 
+def _layer_loop_by_kind(cfg: TransformerConfig, layers, moe, x, state, *, positions, attend,
+                        decode: bool, lead, live, banks_in_place: bool):
+    """``_layer_loop`` for a forward-only caller whose blocks must know their
+    layer's kind as a Python value (a window layer's cache is another array than
+    a whole layer's). What a layer is — its kind, and whether its feed-forward
+    is a leading dense one, a dense one or the routed block — is periodic in the
+    layers after the ``lead``: the loop scans over the whole periods with the
+    period's layers inline (compile time flat in depth; S S G S is four blocks a
+    body), and runs the leading layers and the ``tail`` that fills no period
+    inline, one by one. Same blocks, same stacks, same order as ``_layer_loop``."""
+    kinds, L = cfg.layer_kinds, cfg.num_layers
+    layers_xs, load_layer = _make_stack_loader(cfg, layers)
+    routed_model = cfg.moe_every > 0 and moe is not None
+    moe_xs, load_moe = _make_stack_loader(cfg, moe) if routed_model else (None, None)
+    lead_xs, load_lead = _make_stack_loader(cfg, lead) if lead is not None else (None, None)
+    n_lead = 0 if lead is None else jax.tree.leaves(lead_xs)[0].shape[0]
+    every = max(cfg.moe_every, 1)
+    if routed_model and banks_in_place:
+        banks = moe["experts"]
+        n_routed = jax.tree.leaves(banks)[0].shape[0]
+        moe_xs = {**moe_xs, "experts": jnp.arange(n_routed, dtype=jnp.int32)}
+
+    def ffn_kind(i):
+        if i < n_lead:
+            return "lead"
+        return "routed" if routed_model and (i - n_lead) % every == every - 1 else "dense"
+
+    what = [(kinds[i], ffn_kind(i)) for i in range(L)]
+    n = L - n_lead
+    period = next((p for p in range(every, n, every)
+                   if all(what[n_lead + j] == what[n_lead + j + p] for j in range(n - p))), n)
+    G, tail = divmod(n, period) if period else (0, 0)
+    block = partial(_block, cfg, positions=positions, attend=attend, live=live)
+
+    def one(carry, i, lp, l, extra):
+        """Layer ``i``'s block (``i`` a Python int: what the layer is; ``l`` its
+        index as the stacks are indexed, traced inside a scan)."""
+        kind, feed = what[i]
+        lp = load_layer(lp)
+        if feed == "routed":
+            moe_l, bank_layer = load_moe(extra), None
+            if banks_in_place:  # ``extra`` carried this layer's position where its banks would be
+                moe_l, bank_layer = {**moe_l, "experts": banks}, moe_l["experts"]
+            return block(carry, lp, l, kind=kind,
+                         ffn=lambda lp, h: _moe_ffn(cfg, moe_l, h, decode, bank_layer))
+        if feed == "lead":
+            lp = {**lp, **load_lead(extra)}
+        return block(carry, lp, l, kind=kind, ffn=partial(_dense_ffn, cfg))
+
+    at = lambda tree, i: jax.tree.map(lambda a: a[i], tree)
+    routed_of = lambda i: (i - n_lead) // every  # a routed layer's position in the moe stacks
+    carry, ys = (x, state), []
+
+    def inline(carry, i):
+        feed = what[i][1]
+        extra = (at(lead_xs, i) if feed == "lead" else
+                 at(moe_xs, routed_of(i)) if feed == "routed" else None)
+        carry, y = one(carry, i, at(layers_xs, i), jnp.int32(i), extra)
+        if feed == "routed":
+            ys.append(jax.tree.map(lambda a: a[None], y))
+        return carry
+
+    for i in range(n_lead):
+        carry = inline(carry, i)
+    if G:
+        lo, hi = n_lead, n_lead + G * period
+        per_group = lambda a, r: a.reshape((G, r) + a.shape[1:])
+        grouped = jax.tree.map(lambda a: per_group(a[lo:hi], period),
+                               (layers_xs, jnp.arange(L, dtype=jnp.int32)))
+        n_r = sum(what[lo + j][1] == "routed" for j in range(period))  # routed layers a period
+        moe_g = (jax.tree.map(lambda a: per_group(a[:G * n_r], n_r), moe_xs) if n_r else None)
+
+        def group(carry, xs):
+            (lxs, ls), moe_p = xs
+            out = []
+            for j in range(period):
+                routed = what[lo + j][1] == "routed"
+                extra = at(moe_p, len(out)) if routed else None
+                carry, y = one(carry, lo + j, at(lxs, j), ls[j], extra)
+                if routed:
+                    out.append(y)
+            return carry, (jax.tree.map(lambda *a: jnp.stack(a), *out) if out else None)
+
+        carry, got = lax.scan(group, carry, (grouped, moe_g), unroll=max(1, cfg.scan_unroll))
+        if n_r:
+            ys.append(jax.tree.map(lambda a: a.reshape((G * n_r,) + a.shape[2:]), got))
+    for i in range(L - tail, L):
+        carry = inline(carry, i)
+    if not ys:
+        return (*carry, jnp.zeros((), jnp.float32), None)
+    aux, chosen = jax.tree.map(lambda *a: jnp.concatenate(a), *ys)
+    return (*carry, jnp.sum(aux), chosen)
+
+
 def _head_matrix(params: Params, load=lambda t: t):
     """[d, vocab]: the untied head, or the embedding table transposed."""
     head = params.get("lm_head", None)
@@ -1588,12 +1852,17 @@ def apply(
     step=None,
     _top_streamed: bool = False,
     return_routing: bool = False,
+    mtp_tokens=None,
 ) -> jnp.ndarray:
     """tokens [B, S] int32 -> logits [B, S, vocab] (fp32), or the final hidden
     states [B, S, d] when ``return_hidden`` (used by the chunked LM loss).
     With ``with_aux`` returns (out, aux_loss) — MoE load-balancing loss.
     With ``return_routing`` (dropless routing only) the experts chosen for
     every token in every routed layer come last: int32 [layers, B, S, k].
+    With ``mtp_tokens`` [B, S] (``mtp_layers`` > 0: row i holds t_{i+1}, the token
+    AFTER the one at i) the multi-token-prediction module's logits for t_{i+2}
+    come last of all, [B, S, vocab] (``mtp_logits``), and the experts its block
+    chose are the last row of the routing.
     ``rng`` enables dropout / progressive layer drop (training); ``step``
     drives the PLD theta schedule. ``_top_streamed``: the caller already
     streamed the top-level leaves (param_offload) — a shared leaf (tied wte)
@@ -1621,9 +1890,39 @@ def apply(
         cfg, params["layers"], params.get("moe"), x, None, positions=positions,
         attend=_stateless_attention(cfg, S), per_layer=per_layer, wrap=_remat_wrapper(cfg),
         lead=params.get("dense_ffn"))
+    mtp = ()
+    if mtp_tokens is not None:
+        mtp, module_chosen = mtp_logits(cfg, params, x, mtp_tokens, positions)
+        mtp = (mtp,)
+        if chosen is not None and module_chosen is not None:  # the module's choices last
+            chosen = jnp.concatenate([chosen, module_chosen])
     x = _final_norm(cfg, params, x) if return_hidden else _lm_head(cfg, params, x)
-    out = (x,) + ((aux_total,) if with_aux else ()) + ((chosen,) if return_routing else ())
+    out = ((x,) + ((aux_total,) if with_aux else ()) + ((chosen,) if return_routing else ())
+           + mtp)
     return out if len(out) > 1 else x
+
+
+def mtp_logits(cfg: TransformerConfig, params: Params, hidden, next_tokens, positions=None):
+    """The multi-token-prediction module (``mtp_layers``; DeepSeek-V3 section 2.2).
+    ``hidden`` [B, S, d]: the residual stream behind the model's last layer,
+    BEFORE its final norm; ``next_tokens`` [B, S]: t_{i+1} at row i. h' =
+    [RMSNorm_e(Emb(t_{i+1})) ; RMSNorm_h(hidden_i)] . W_eh, one block of the
+    whole-context kind (no window, no rotary, the model's last layer's
+    feed-forward), the module's own final norm, the model's head -> (float32
+    logits [B, S, vocab] for t_{i+2}, the experts the module's block chose
+    [1, B, S, k] or None)."""
+    if not cfg.mtp_layers:
+        raise ValueError("mtp_logits: the configuration states no mtp_layers")
+    p, eps = params["mtp"], cfg.layernorm_epsilon
+    emb, positions = embed(cfg, params, next_tokens, positions)
+    joined = jnp.concatenate([rms_norm(emb, p["enorm_scale"], eps),
+                              rms_norm(hidden, p["hnorm_scale"], eps)], axis=-1)
+    x = jnp.einsum("bsk,kd->bsd", joined, p["eh_proj"].astype(joined.dtype))
+    block = _mtp_block_cfg(cfg)
+    x, _, _, chosen = _layer_loop(block, p["layers"], p.get("moe"), x, None,
+                                  positions=positions,
+                                  attend=_stateless_attention(block, x.shape[1]))
+    return _lm_head(cfg, {**params, "lnf_scale": p["lnf_scale"]}, x), chosen
 
 
 def _routing_asked(cfg, return_routing: bool) -> None:
@@ -1677,7 +1976,20 @@ def cache_layout(cfg: TransformerConfig) -> dict:
     the values of every K/V head (``kv_heads``: grouped-query attention caches
     its few, not the query heads' many). Latent attention: ``k`` is the rotary
     key the heads share, ``v`` the normed latent, which is the absorbed form's
-    value and the rest of its key; the 'one head' is every head's.
+    value and the rest of its key; the 'one head' is every head's. With layers of
+    several kinds (``layer_kinds``) L counts the WHOLE-context layers alone.
+
+    RINGS, under ``RING``, ``{leaf: (positions, heads, width)}``: what a WINDOW
+    layer keeps, [L_window, B, R, heads, width]: the last R = ``local_attn_window``
+    positions of a sequence, position p at index p mod R, whatever ``Smax`` is.
+    R is the window itself: a step at position p reads p - R + 1 ... p, exactly
+    the R entries the ring holds once p is written, so no entry is read that a
+    mask must hide for being too old, and R = 128 is one lane tile. (A block of
+    T > 1 tokens entering at a position past 0 would need R >= window + T - 1 to
+    still hold what its first query sees: chunked prefill and verification are
+    refused for it, ``_cache_attention``.) A ring cannot be sliced at an old
+    position nor rolled back: the helpers move it WHOLE, as they do state.
+    Absent for a model without window layers.
 
     Per-SEQUENCE leaves, under ``STATE``, ``{leaf: (shape, dtype or None for the
     cache's)}``: state with no position axis, [L, B, *shape] in the cache, that
@@ -1688,6 +2000,8 @@ def cache_layout(cfg: TransformerConfig) -> dict:
     if cfg.kv_lora_rank:
         return {"k": (1, cfg.qk_rope_head_dim), "v": (1, cfg.kv_lora_rank)}
     layout = {"k": (cfg.kv_heads, cfg.head_dim), "v": (cfg.kv_heads, cfg.value_head_dim)}
+    if cfg.window_layers:
+        layout[RING] = {name: (cfg.local_attn_window,) + tail for name, tail in layout.items()}
     if cfg.ssm_state_size:
         layout[STATE] = {
             "ssm": ((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size), jnp.float32),
@@ -1695,14 +2009,31 @@ def cache_layout(cfg: TransformerConfig) -> dict:
     return layout
 
 
-def _token_leaves(tree: dict) -> dict:
-    return {name: leaf for name, leaf in tree.items() if name != STATE}
+def token_leaves(tree: dict) -> dict:
+    """The per-token leaves of a cache tree or of a layout: those with a position
+    axis ``Smax`` long that a window can be cut out of."""
+    return {name: leaf for name, leaf in tree.items() if name not in (STATE, RING)}
+
+
+def _whole_leaves(tree: dict) -> dict:
+    """The subtrees the helpers move WHOLE, a sequence at a time: the rings and
+    the per-sequence state, each [L, B, ...] with the sequence on axis 1."""
+    return {name: tree[name] for name in (RING, STATE) if name in tree}
 
 
 def cache_bytes_per_token(cfg: TransformerConfig, dtype=None) -> int:
-    """Bytes one token takes in ONE layer of the cache (its per-token leaves)."""
-    values = sum(heads * width for heads, width in _token_leaves(cache_layout(cfg)).values())
+    """Bytes one token takes in ONE layer of the cache that keeps every token (a
+    whole-context layer's per-token leaves; a window layer keeps
+    ``local_attn_window`` positions of as many bytes each, ``cache_ring_bytes``)."""
+    values = sum(heads * width for heads, width in token_leaves(cache_layout(cfg)).values())
     return values * jnp.dtype(dtype or cfg.dtype).itemsize
+
+
+def cache_ring_bytes(cfg: TransformerConfig, dtype=None) -> int:
+    """Bytes one SEQUENCE's rings take over ALL the window layers (0 for a model
+    with none): constant in the sequence's length."""
+    values = sum(math.prod(shape) for shape in cache_layout(cfg).get(RING, {}).values())
+    return len(cfg.window_layers) * values * jnp.dtype(dtype or cfg.dtype).itemsize
 
 
 def cache_state_bytes(cfg: TransformerConfig, dtype=None) -> int:
@@ -1716,8 +2047,12 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int, dtype=None):
     """Allocate an empty cache for ``batch`` sequences of up to ``max_len``."""
     dtype = dtype or cfg.dtype
     layout = cache_layout(cfg)
-    cache = {name: jnp.zeros((cfg.num_layers, batch, max_len) + tail, dtype)
-             for name, tail in _token_leaves(layout).items()}
+    n_window = len(cfg.window_layers)
+    cache = {name: jnp.zeros((cfg.num_layers - n_window, batch, max_len) + tail, dtype)
+             for name, tail in token_leaves(layout).items()}
+    if RING in layout:
+        cache[RING] = {name: jnp.zeros((n_window, batch) + shape, dtype)
+                       for name, shape in layout[RING].items()}
     if STATE in layout:
         cache[STATE] = {name: jnp.zeros((cfg.num_layers, batch) + shape, own or dtype)
                         for name, (shape, own) in layout[STATE].items()}
@@ -1727,29 +2062,29 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int, dtype=None):
 def cache_len(cache) -> int:
     """Smax of a cache tree: the position axis of its per-token leaves
     [L, B, Smax, heads, width]."""
-    return jax.tree.leaves(_token_leaves(cache))[0].shape[2]
+    return jax.tree.leaves(token_leaves(cache))[0].shape[2]
 
 
 def cache_dtype(cache):
     """The dtype a cache tree's per-token leaves are held in."""
-    return jax.tree.leaves(_token_leaves(cache))[0].dtype
+    return jax.tree.leaves(token_leaves(cache))[0].dtype
 
 
-def _slot_state(state: dict, slot):
-    """Row ``slot`` of per-sequence leaves [L, B, ...] -> [L, 1, ...], whole."""
+def _slot_whole(tree: dict, slot):
+    """Row ``slot`` of leaves [L, B, ...] -> [L, 1, ...], whole."""
     return jax.tree.map(lambda c: lax.dynamic_slice(
-        c, (0, slot) + (0,) * (c.ndim - 2), (c.shape[0], 1) + c.shape[2:]), state)
+        c, (0, slot) + (0,) * (c.ndim - 2), (c.shape[0], 1) + c.shape[2:]), tree)
 
 
 def slice_cache_slot(cache, slot, length: int, start=0):
     """Read one sequence's window out of a slot cache: every per-token leaf
     [L, B, Smax, heads, width] -> [L, 1, length, heads, width] at row ``slot``,
-    positions [start, start+length); every per-sequence leaf [L, B, ...] ->
-    [L, 1, ...], whole (it has no positions: the sequence's state is the state
-    after the last step that wrote it, whatever window is asked for). ``slot``
-    and ``start`` may be traced int32 scalars — the caller's program stays
-    compile-stable across slots/offsets; ``length`` is static: it picks the
-    compiled program.
+    positions [start, start+length); every ring and per-sequence leaf [L, B, ...]
+    -> [L, 1, ...], whole (they have no positions to cut at: the sequence's
+    rings and state are those after the last step that wrote them, whatever
+    window is asked for). ``slot`` and ``start`` may be traced int32 scalars —
+    the caller's program stays compile-stable across slots/offsets; ``length`` is
+    static: it picks the compiled program.
 
     The serving engine's chunked prefill and prefix-cache copies both run on
     these windows: chunk programs slice a slot out, extend it through
@@ -1762,9 +2097,8 @@ def slice_cache_slot(cache, slot, length: int, start=0):
     start = jnp.asarray(start, jnp.int32)
     out = jax.tree.map(
         lambda c: lax.dynamic_slice(c, (0, slot, start, 0, 0),
-                                    (c.shape[0], 1, length) + c.shape[3:]), _token_leaves(cache))
-    if STATE in cache:
-        out[STATE] = _slot_state(cache[STATE], slot)
+                                    (c.shape[0], 1, length) + c.shape[3:]), token_leaves(cache))
+    out.update(_slot_whole(_whole_leaves(cache), slot))
     return out
 
 
@@ -1772,19 +2106,18 @@ def update_cache_slot(cache, window, slot, start=0):
     """Write a window into row ``slot`` of a slot cache — the inverse of
     ``slice_cache_slot``: every per-token leaf [L, 1, W, heads, width] at
     positions [start, start+W) (one ``dynamic_update_slice`` per leaf), every
-    per-sequence leaf [L, 1, ...] over the row's WHOLE (a prefill leaves nothing
-    of the slot's previous request behind). ``slot``/``start`` are traced
+    ring and per-sequence leaf [L, 1, ...] over the row's WHOLE (a prefill leaves
+    nothing of the slot's previous request behind). ``slot``/``start`` are traced
     scalars: one compiled program regardless of which slot/offset is written."""
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
     out = jax.tree.map(
         lambda c, w: lax.dynamic_update_slice(c, w.astype(c.dtype), (0, slot, start, 0, 0)),
-        _token_leaves(cache), _token_leaves(window))
-    if STATE in cache:
-        out[STATE] = jax.tree.map(
-            lambda c, w: lax.dynamic_update_slice(c, w.astype(c.dtype),
-                                                  (0, slot) + (0,) * (c.ndim - 2)),
-            cache[STATE], window[STATE])
+        token_leaves(cache), token_leaves(window))
+    out.update(jax.tree.map(
+        lambda c, w: lax.dynamic_update_slice(c, w.astype(c.dtype),
+                                              (0, slot) + (0,) * (c.ndim - 2)),
+        _whole_leaves(cache), _whole_leaves(window)))
     return out
 
 
@@ -1833,11 +2166,26 @@ def cache_attention_form(num_heads: int, B: int, T: int, Smax: int, lock_step: b
 
 
 def cache_step_form(cfg: TransformerConfig) -> str:
-    """``"latent"`` or ``"dense"``: how a step that READS the slot cache
-    (decode, verify, chunk) attends, for the spans' ``attn``: latent attention
-    in its absorbed form over the cached latent, plain attention densely (or
-    through the Pallas decode kernel) over per-head K/V."""
-    return "latent" if cfg.kv_lora_rank else "dense"
+    """``"latent"``, ``"dense"``, ``"dense+ring"`` or ``"ring"``: how a step that
+    READS the slot cache (decode, verify, chunk) attends, for the spans' ``attn``:
+    latent attention in its absorbed form over the cached latent, plain attention
+    densely (or through the Pallas decode kernel) over per-head K/V, a window
+    layer over its ring of ``local_attn_window`` positions (``+``: the model has
+    layers of both kinds)."""
+    if cfg.kv_lora_rank:
+        return "latent"
+    n_window = len(cfg.window_layers)
+    return "dense" if not n_window else "ring" if n_window == cfg.num_layers else "dense+ring"
+
+
+def cache_block_form(cfg: TransformerConfig, bucket: int) -> str:
+    """How a prefill block ``bucket`` long that fills its own cache attends, for
+    the span's ``attn``: ``cache_attention_form``'s ``"flash"`` / ``"dense"``, with
+    ``"+window"`` where the model has window layers (they take the same form by
+    the same rule, under the window's mask: the kernel's runtime window, or a
+    [T, T] bias on the dense scores)."""
+    form = cache_attention_form(cfg.num_heads, 1, bucket, bucket)
+    return form + "+window" if cfg.window_layers else form
 
 
 def expert_bank_form(cfg: TransformerConfig, moe, mesh=None):
@@ -1875,7 +2223,8 @@ def expert_bank_form(cfg: TransformerConfig, moe, mesh=None):
     return "in_place"
 
 
-def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, write_pos=None):
+def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, write_pos=None,
+                     live=None):
     """-> (positions [B, T], the block's ``attend``) for T new tokens entering a
     stacked [L, B, Smax, H, Dh] cache tree at ``pos`` (scalar, or [B] with
     ``write_pos``: see ``apply_with_cache``).
@@ -1889,7 +2238,23 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
     (``_latent_attention``) and nothing per-head is ever made of the cache. The stacks are its state, so they stay the layer
     loop's CARRY: with the cache donated the loop's input and output are one
     buffer; as the scan's xs/ys they would be sliced out and restacked layer by
-    layer and copied whole (tests/test_chip_compile.py guards it)."""
+    layer and copied whole (tests/test_chip_compile.py guards it).
+
+    Layers of several kinds (``cfg.layer_kinds``; the loop hands ``attend`` the
+    layer's ``kind`` as a Python value). A whole-context layer is the above, at
+    its index among the whole-context layers. A WINDOW layer keeps a ring
+    (``cache_layout``) and has two forms: a block that STARTS its sequences
+    (``pos`` the Python int 0: a prefill) attends to itself under the window's
+    mask (flash kernel with its runtime window, or densely with a [T, T] bias, by
+    ``cache_attention_form``'s rule on the block's own score matrix) and writes
+    its last R LIVE rows (``live``: not a bucket's padding) into the ring, row p
+    at p mod R; a one-token step writes at ``pos mod R`` and attends over the
+    ring's R entries, each masked by the absolute position it holds (entry r at a
+    query position p holds p - ((p - r) mod R); negative: never written by this
+    sequence, whatever an earlier one left there). Nothing ``Smax`` long is made
+    for a window layer. A block of several tokens entering past position 0 would
+    overwrite ring entries its own first queries still see: refused by name."""
+    starts = isinstance(pos, (int, np.integer)) and int(pos) == 0
     pos = jnp.asarray(pos, jnp.int32)
     vector_pos = pos.ndim >= 1
     steps = jnp.arange(T)
@@ -1908,7 +2273,9 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
     lock_step = not vector_pos
     fills_cache = lock_step and T == Smax
     use_flash = cache_attention_form(cfg.num_heads, B, T, Smax, lock_step) == "flash"
-    if use_flash:
+    windowed = set(cfg.window_layers)  # a window layer's block attends to itself: [T, T] scores
+    window_flash = bool(windowed) and cache_attention_form(cfg.num_heads, B, T, T) == "flash"
+    if use_flash or window_flash:
         from ..ops.pallas.flash_attention import flash_attention_sharded
 
     slopes = alibi_slopes(cfg.num_heads) if cfg.pos_emb == "alibi" else None
@@ -1921,6 +2288,24 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
         else:
             dist = jnp.arange(Smax)[None, :] - (pos + steps[:, None])
             bias = (slopes[:, None, None] * dist[None]).astype(jnp.float32)[None]
+
+    # a layer's index among the layers of ITS kind: where it lies in that kind's stacks
+    if windowed:
+        if slopes is not None:
+            raise NotImplementedError(
+                "window layers (local_attn_layers) through the cache with pos_emb='alibi' have "
+                "no code: the ring's bias from the positions its entries hold is not written")
+        if T > 1 and not starts:
+            raise NotImplementedError(
+                f"a block of {T} tokens entering a window layer's ring past position 0 (chunked "
+                "prefill, speculative verification) has no code: the ring keeps "
+                f"local_attn_window = {cfg.local_attn_window} positions, and the block would "
+                "overwrite entries its own first queries still see")
+        seen, in_kind = {False: 0, True: 0}, []
+        for l in range(cfg.num_layers):
+            in_kind.append(seen[l in windowed])
+            seen[l in windowed] += 1
+        in_kind = jnp.asarray(in_kind, jnp.int32)
 
     # Single-token decode steps route through the Pallas length-aware kernel
     # (ops/pallas/decode_attention.py — the reference's softmax_context,
@@ -1947,13 +2332,65 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
     else:
         if write_pos is not None:
             raise ValueError("write_pos requires a per-row pos vector")
+        write_positions = positions
 
         def write(c, l, new):
             return lax.dynamic_update_slice(c, new[None].astype(c.dtype), (l, 0, pos, 0, 0))
 
-    def attend(q, k, v, stacks, l, lp):
+    def repeat_groups(q, k_l, v_l):
+        """Grouped heads for the flash kernel, which takes one K/V head a query
+        head: the BLOCK's own keys and values repeated, never the cache's."""
+        group = q.shape[2] // k_l.shape[2]
+        return (k_l, v_l) if group == 1 else (jnp.repeat(k_l, group, axis=2),
+                                              jnp.repeat(v_l, group, axis=2))
+
+    def ring_attend(q, k, v, stacks, l, window):
+        ring = stacks[RING]
+        R = ring["k"].shape[2]
+        slots = jnp.arange(R)
+        # the position ring entry r holds for a sequence whose newest position is p
+        held = lambda p: p[:, None] - ((p[:, None] - slots[None, :]) % R)  # [B, R]
+        if T == 1:  # a step: into the ring at pos mod R (an idle row's write is dropped)
+            at = write_positions[:, 0]
+            at = jnp.where(at < Smax, at % R, R)
+            ring = {name: ring[name].at[l, jnp.arange(B), at].set(
+                new[:, 0].astype(ring[name].dtype), mode="drop")
+                for name, new in (("k", k), ("v", v))}
+            k_l, v_l = (lax.dynamic_index_in_dim(ring[name], l, keepdims=False)
+                        for name in ("k", "v"))
+            q_pos = positions[:, 0]
+            k_pos = held(q_pos)
+            seen = (k_pos >= 0) & (q_pos[:, None] - k_pos < window)
+            mask = jnp.where(seen, 0.0, NEG_BIAS).astype(jnp.float32)[:, None, None, :]
+            attn = xla_attention(q, k_l, v_l, bias=mask, causal=False)
+            return attn, {**stacks, RING: ring}
+        # a block that starts its sequences: attends to itself under the window's mask
+        k_l, v_l = k.astype(ring["k"].dtype), v.astype(ring["v"].dtype)
+        if window_flash:
+            attn = flash_attention_sharded(q, *repeat_groups(q, k_l, v_l), mesh=_ACTIVE_MESH[0],
+                                           causal=True, window=float(window))
+        else:
+            dist = steps[:, None] - steps[None, :]
+            inside = jnp.where(dist < window, 0.0, NEG_BIAS).astype(jnp.float32)
+            attn = xla_attention(q, k_l, v_l, bias=inside[None, None])
+        n = (jnp.full((B,), T, jnp.int32) if live is None
+             else jnp.sum(live.astype(jnp.int32), axis=1))
+        src = jnp.clip(held(n - 1), 0, T - 1)[:, :, None, None]  # the last R LIVE rows
+        ring = {name: lax.dynamic_update_slice(
+            ring[name], jnp.take_along_axis(new, src, axis=1)[None], (l, 0, 0, 0, 0))
+            for name, new in (("k", k_l), ("v", v_l))}
+        return attn, {**stacks, RING: ring}
+
+    def attend(q, k, v, stacks, l, lp, kind=None):
+        if windowed:
+            if kind is None:
+                raise ValueError("a cache with rings needs the layer's kind: _layer_loop("
+                                 "by_kind=True)")
+            l = in_kind[l]
+            if kind[0]:
+                return ring_attend(q, k, v, stacks, l, kind[0])
         k_stack, v_stack = write(stacks["k"], l, k), write(stacks["v"], l, v)
-        stacks = {**stacks, "k": k_stack, "v": v_stack}  # a mixer's state rides along
+        stacks = {**stacks, "k": k_stack, "v": v_stack}  # rings and a mixer's state ride along
         if use_decode_kernel:
             attn = decode_attention(q[:, 0], k_stack, v_stack, pos, layer=l)[:, None]
             return attn, stacks
@@ -1967,11 +2404,8 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
             if cfg.kv_lora_rank:
                 return _latent_attention(cfg, lp, q, k_l, v_l, pos), stacks
         if use_flash:
-            if k_l.shape[2] != q.shape[2]:  # grouped heads: the kernel takes one K/V head a
-                group = q.shape[2] // k_l.shape[2]  # query head; the BLOCK's own, not the cache's
-                k_l, v_l = jnp.repeat(k_l, group, axis=2), jnp.repeat(v_l, group, axis=2)
-            attn = flash_attention_sharded(q, k_l, v_l, mesh=_ACTIVE_MESH[0], causal=True,
-                                           alibi_slopes=slopes)
+            attn = flash_attention_sharded(q, *repeat_groups(q, k_l, v_l), mesh=_ACTIVE_MESH[0],
+                                           causal=True, alibi_slopes=slopes)
         else:
             attn = cached_attention(q, k_l, v_l, pos, bias=bias)
         return attn, stacks
@@ -2019,32 +2453,32 @@ def apply_with_cache(
     tail and ``write_pos`` drops an idle row's write); a state-space mixer does:
     a recurrence has no causality to hide behind, so its state moves on live
     rows only (``_ssm_mixer``), and a padded block (``last_index``) without the
-    mask is refused for it."""
+    mask is refused for it; so does a window layer's ring, which keeps a
+    block's last LIVE rows (``_cache_attention``). ``pos`` the Python int 0 says
+    that the block STARTS its sequences, which a window layer must know."""
     _routing_asked(cfg, return_routing)
     if not cfg.causal:
         raise NotImplementedError("KV-cache decoding is causal-only (encoders use apply())")
-    if cfg.local_attn_layers is not None:
-        raise NotImplementedError(
-            "local-attention decode is not wired up; use apply() for GPT-Neo-style models"
-        )
     if cfg.attn_impl == "sparse":
         raise NotImplementedError(
             "block-sparse decode is not wired up — dense cache attention would "
             "silently change the attention pattern the model trained with"
         )
     B, T = tokens.shape
-    if live is None and last_index is not None and cfg.ssm_state_size:
+    if live is None and last_index is not None and (cfg.ssm_state_size or cfg.window_layers):
         raise ValueError(
             "apply_with_cache(last_index=...) pads the block past its live last token: a "
             "state-space mixer needs `live` (the rows that are the sequence's own), or its "
-            "state runs on over the padding")
+            "state runs on over the padding; so does a window layer's ring, or it keeps the "
+            "padding's rows")
     params = _stream_top_level(cfg, params)
-    positions, attend = _cache_attention(cfg, B, T, cache_len(cache), pos, write_pos)
+    positions, attend = _cache_attention(cfg, B, T, cache_len(cache), pos, write_pos, live)
     x, _ = embed(cfg, params, tokens, positions)
     x, cache, _, chosen = _layer_loop(
         cfg, params["layers"], params.get("moe"), x, dict(cache),
         positions=positions, attend=attend, decode=T == 1, lead=params.get("dense_ffn"),
-        live=live, banks_in_place=expert_bank_form(cfg, params.get("moe")) == "in_place")
+        live=live, banks_in_place=expert_bank_form(cfg, params.get("moe")) == "in_place",
+        by_kind=True)
     if last_index is not None:
         # bucketed prefill: the live last token sits at ``last_index``
         # (prompt_len - 1), not at T-1 — project only that position

@@ -43,6 +43,20 @@ every other layer's groups are empty (an empty group gets no tile): no
 ``[E, K, N]`` copy of the layer is made for the kernel's operand, which was a
 fifth of a routed prefill (PERF.md §6, PR 34). ``experts_dense`` takes the layer
 inside its einsums' operands, where XLA fuses the slice as it does a scanned one.
+
+A held share (``moe_experts_held`` = (first, count); PR 38). The program of one
+chip of a deployment that spreads a layer's experts over several holds ``count``
+of the ``E`` experts: the banks are ``[L, count, ...]``, the router stays ``E``
+wide and chooses its k of E, and only the pairs whose expert is held are
+dispatched; the layer's output is the shared expert plus the held experts' part.
+``experts_dense`` mixes the held experts' outputs alone. ``experts_sorted_held``
+sorts the pairs with the held ones first, by expert, and takes THOSE in chunks
+of ``held_chunk_rows`` under a loop whose trip count the data decides: about
+k x count / E of the pairs are held, so the buffers are that share of what every
+pair would take (at 16,384 rows x 8 choices of 128, 16 held: 20,480 gathered rows
+where all pairs are 131,072, 1.6 GB in bfloat16), and a routing as uneven as it
+likes still drops no pair: it takes more trips. Forward only: a loop whose trip
+count is data has no reverse-mode derivative.
 """
 
 from __future__ import annotations
@@ -76,18 +90,20 @@ SELECT_BIAS_STD = 0.02
 
 
 def init_dropless(rng, n_layers: int, num_experts: int, d_model: int, d_ff: int,
-                  shared: int = 0, select_bias: bool = False):
+                  shared: int = 0, select_bias: bool = False, held: int | None = None):
     """Router and gated expert bank of ``n_layers`` routed layers; every stack
     is drawn whole, in one call (no per-layer list to restack). ``shared``: the
     width of the shared expert (0: none); ``select_bias``: the router's held
-    selection bias, drawn non-zero (``SELECT_BIAS_STD``)."""
+    selection bias, drawn non-zero (``SELECT_BIAS_STD``); ``held``: how many of
+    the ``num_experts`` the router chooses among this program holds a bank of
+    (None: all)."""
     keys = jax.random.split(rng, 4)  # the gate and the bank draw as they always have
     keys = list(keys) + list(jax.random.split(jax.random.fold_in(rng, 1), 4))
 
     def draw(key, shape, fan_in):
         return jax.random.normal(key, shape, jnp.float32) * (1.0 / math.sqrt(fan_in))
 
-    bank = (n_layers, num_experts)
+    routed, bank = (n_layers, num_experts), (n_layers, held or num_experts)
     out = {
         "gate": draw(keys[0], (n_layers, d_model, num_experts), d_model),
         "experts": {
@@ -97,7 +113,7 @@ def init_dropless(rng, n_layers: int, num_experts: int, d_model: int, d_ff: int,
         },
     }
     if select_bias:
-        out["bias"] = SELECT_BIAS_STD * jax.random.normal(keys[4], bank, jnp.float32)
+        out["bias"] = SELECT_BIAS_STD * jax.random.normal(keys[4], routed, jnp.float32)
     if shared:
         out["shared"] = {"wg": draw(keys[5], (n_layers, d_model, shared), d_model),
                          "wi": draw(keys[6], (n_layers, d_model, shared), d_model),
@@ -177,9 +193,68 @@ def experts_sorted(bank, x, weights, experts, layer=None):
     return jnp.einsum("tkm,tk->tm", ys.astype(jnp.float32), weights).astype(x.dtype)
 
 
-def experts_dense(bank, x, weights, experts, layer=None):
-    """Every expert on every row of x [T, M]; the weights of those not chosen
-    are zero. ``bank`` and ``layer`` as ``experts_sorted`` takes them."""
+def held_chunk_rows(pairs: int, count: int, num_experts: int) -> int:
+    """The gathered rows one trip of ``experts_sorted_held`` takes: a quarter over
+    the held share of ``pairs`` (an even router fills one trip), a multiple of
+    256, never more than the pairs there are."""
+    want = -(-5 * pairs * count // (4 * num_experts))
+    return min(-(-max(want, 1) // 256) * 256, pairs)
+
+
+def experts_sorted_held(bank, x, weights, experts, first: int, num_experts: int, layer=None):
+    """``experts_sorted`` for a bank that holds experts [first, first + count) of
+    the ``num_experts`` the router chose among: x [T, M], weights and experts
+    [T, k] over ALL the router's experts -> the held experts' part of the routed
+    sum [T, M]. The pairs are sorted held first, by expert; ceil(held pairs / C)
+    trips each take C sorted rows (``held_chunk_rows``) through the grouped
+    matmuls, and every token gathers its own pairs' rows back out of the trip's
+    output, one choice at a time, weighted. No pair is dropped, and none that is
+    not held is gathered or multiplied. ``layer``: as ``experts_sorted`` takes it."""
+    T, M = x.shape
+    k = experts.shape[1]
+    count = bank["wi"].shape[0 if layer is None else 1]
+    if layer is not None:  # [L, count, K, N] as L * count groups: this layer's alone are filled
+        bank = {name: leaf.reshape((-1,) + leaf.shape[2:]) for name, leaf in bank.items()}
+    w = {name: leaf.astype(x.dtype) for name, leaf in bank.items()}
+    flat = experts.reshape(T * k) - first
+    key = jnp.where((flat >= 0) & (flat < count), flat, count)  # the others behind the held
+    order = jnp.argsort(key)  # pairs by held expert; pair p is token p // k
+    place = jnp.argsort(order).reshape(T, k)  # where each token's pairs lie in that order
+    ends = jnp.cumsum(jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count])
+    begins = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+    n_held = ends[-1]
+    C = held_chunk_rows(T * k, count, num_experts)
+    weights = weights.astype(jnp.float32)
+
+    def trip(carry):
+        lo, out = carry
+        rows = jnp.minimum(lo + jnp.arange(C), T * k - 1)
+        xs = x[order[rows] // k]
+        sizes = jnp.clip(ends, lo, lo + C) - jnp.clip(begins, lo, lo + C)
+        if layer is not None:
+            sizes = lax.dynamic_update_slice(jnp.zeros((w["wi"].shape[0],), jnp.int32), sizes,
+                                             (layer * count,))
+        h = _gated(lax.ragged_dot(xs, w["wg"], sizes), lax.ragged_dot(xs, w["wi"], sizes))
+        ys = lax.ragged_dot(h, w["wo"], sizes)  # [C, M], sorted; rows past the held: no group's
+
+        def choice(j, out):
+            at = place[:, j]
+            mine = (at >= lo) & (at < jnp.minimum(lo + C, n_held))
+            got = ys[jnp.clip(at - lo, 0, C - 1)].astype(jnp.float32)
+            return out + jnp.where(mine[:, None], got * weights[:, j, None], 0.0)
+
+        return lo + C, lax.fori_loop(0, k, choice, out)
+
+    _, out = lax.while_loop(lambda carry: carry[0] < n_held, trip,
+                            (jnp.int32(0), jnp.zeros((T, M), jnp.float32)))
+    return out.astype(x.dtype)
+
+
+def experts_dense(bank, x, weights, experts, layer=None, first: int = 0):
+    """Every expert of the bank on every row of x [T, M]; the weights of those
+    not chosen are zero. ``bank`` and ``layer`` as ``experts_sorted`` takes them;
+    ``first``: the bank holds the experts from this one on (a choice outside it
+    is mixed in by no one here)."""
     T = x.shape[0]
     if layer is not None:
         bank = {name: lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False)
@@ -188,7 +263,9 @@ def experts_dense(bank, x, weights, experts, layer=None):
     w = {name: leaf.astype(x.dtype) for name, leaf in bank.items()}
     h = _gated(jnp.einsum("tm,emf->etf", x, w["wg"]), jnp.einsum("tm,emf->etf", x, w["wi"]))
     ys = jnp.einsum("etf,efm->etm", h, w["wo"])
-    mix = jnp.zeros((T, E), jnp.float32).at[jnp.arange(T)[:, None], experts].set(weights)
+    at = experts - first
+    at = jnp.where((at >= 0) & (at < E), at, E)  # not held: dropped (a negative index would wrap)
+    mix = jnp.zeros((T, E), jnp.float32).at[jnp.arange(T)[:, None], at].set(weights, mode="drop")
     return jnp.einsum("etm,te->tm", ys.astype(jnp.float32), mix).astype(x.dtype)
 
 
@@ -207,18 +284,27 @@ def moe_ffn_dropless(cfg, moe_p, h, layer=None):
     weights, experts, probs = route(
         x, moe_p["gate"], cfg.moe_top_k, cfg.moe_norm_topk_prob, score_fn=cfg.moe_score_fn,
         select_bias=moe_p.get("bias"), scale=cfg.moe_routed_scale)
-    form = experts_dense if B * S <= DENSE_ROWS else experts_sorted
-    out = form(moe_p["experts"], x, weights, experts, layer)
+    first, count = cfg.experts_held
+    if B * S <= DENSE_ROWS:
+        out = experts_dense(moe_p["experts"], x, weights, experts, layer, first)
+    elif count < cfg.num_experts:  # one chip's share of the experts: the held pairs alone
+        out = experts_sorted_held(moe_p["experts"], x, weights, experts, first, cfg.num_experts,
+                                  layer)
+    else:
+        out = experts_sorted(moe_p["experts"], x, weights, experts, layer)
     if "shared" in moe_p:
         out = out + shared_expert(moe_p["shared"], x)
     return (out.reshape(B, S, M), load_balance_loss(probs, experts),
             experts.reshape(B, S, cfg.moe_top_k))
 
 
-def expert_load(experts, live, num_experts: int):
+def expert_load(experts, live, num_experts: int, held=None):
     """experts [layers, B, T, k], live [B, T] bool -> int32 [layers, E]: the
-    rows that count (not bucket padding, not an idle slot) sent to each expert."""
-    hot = jax.nn.one_hot(experts, num_experts, dtype=jnp.int32)  # [layers, B, T, k, E]
+    rows that count (not bucket padding, not an idle slot) sent to each expert.
+    ``held`` (first, count): the load of the experts this program holds alone,
+    [layers, count] (a choice outside them is another chip's row)."""
+    first, count = held or (0, num_experts)
+    hot = jax.nn.one_hot(experts - first, count, dtype=jnp.int32)  # [layers, B, T, k, count]
     return jnp.sum(hot * live[None, :, :, None, None].astype(jnp.int32), axis=(1, 2, 3))
 
 

@@ -466,6 +466,53 @@ def test_latent_decode_program_keeps_the_latent_cache_in_place(v5e, no_persisten
     assert ma.temp_size_in_bytes < 1.25 * layer_bytes, (ma.temp_size_in_bytes, layer_bytes)
 
 
+def _k_exaone_worker(n, Smax, v5e):
+    """K-EXAONE-236B-A23B's five layers S S S G S at their published widths
+    (``chipbench/configs/k-exaone-236b-a23b-L5.json``'s ``program``: 64 / 8 heads of
+    128, a window of 128, 16 of 128 experts held) and a vocabulary of 1024."""
+    import json
+    from deepspeed_tpu.models import transformer as tfm
+
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__), "chipbench", "configs",
+                           "k-exaone-236b-a23b-L5.json")) as f:
+        program = json.load(f)["program"]
+    cfg = tfm.TransformerConfig(dtype=jnp.bfloat16, **{
+        **program, "vocab_size": 1024, "max_seq_len": Smax})
+    return (cfg, *_bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0])))
+
+
+def test_kinds_decode_program_keeps_both_cache_kinds_in_place(v5e, no_persistent_cache, as_tpu):
+    """``SlotWorker``'s decode step of the block with window and whole-context
+    layers (the cell's five layers, 16 slots x 8192): the cache tree is ONE
+    whole-context layer ``Smax`` long and four rings of 128, both the layer
+    loop's carry, donated in and aliased out, neither stack copied whole; a
+    window layer attends over its ring, so exactly ONE matmul of the program
+    yields a value ``Smax`` long (the whole-context layer's QK^T; were a window
+    layer to attend over ``Smax`` under a mask there would be five), and the
+    temporaries are that one layer's K and V gathered for the grouped heads plus
+    its float32 scores, not five layers' of either."""
+    n, Smax = 16, 8192
+    cfg, worker, params, cache, sds = _k_exaone_worker(n, Smax, v5e)
+    assert jax.tree.map(lambda x: x.shape, cache) == {
+        "k": (1, n, Smax, 8, 128), "v": (1, n, Smax, 8, 128),
+        "ring": {"k": (4, n, 128, 8, 128), "v": (4, n, 128, 8, 128)}}
+    compiled = _compile_decode(worker, params, cache, n, sds)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # decode_attn "xla": grouped heads, the ring
+    for whole in (rf"bf16\[1,{n},{Smax},8,128\]", rf"bf16\[4,{n},128,8,128\]"):
+        copies = re.findall(rf"^\s*%?[\w.-]+ = {whole}\S* copy\(", text, re.M)
+        assert not copies, f"the decode step copies a whole cache stack: {copies}"
+    long_matmuls = [line for line in text.splitlines()
+                    if re.search(r" (?:convolution|dot)\(", line)
+                    and re.search(rf"^\s*%?[\w.-]+ = \w+\[[\d,]*\b{Smax}\b", line)]
+    assert len(long_matmuls) == 1, long_matmuls
+    ma = compiled.memory_analysis()
+    layer_bytes = n * Smax * 8 * 128 * 2  # the whole-context layer's K (or V)
+    ring_bytes = 4 * n * 128 * 8 * 128 * 2
+    assert ma.alias_size_in_bytes >= 2 * (layer_bytes + ring_bytes)  # donated in, aliased out
+    assert ma.temp_size_in_bytes < 2.5 * layer_bytes, (ma.temp_size_in_bytes, layer_bytes)
+
+
 def test_latent_prefill_attends_through_the_flash_kernel(v5e, no_persistent_cache, as_tpu):
     """The 1024-row prefill of the same block at 32 heads: its dense scores would
     be 128 MiB, so the expanded form goes through the flash forward kernel at q/k
