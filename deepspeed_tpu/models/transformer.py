@@ -2182,10 +2182,28 @@ def cache_block_form(cfg: TransformerConfig, bucket: int) -> str:
     """How a prefill block ``bucket`` long that fills its own cache attends, for
     the span's ``attn``: ``cache_attention_form``'s ``"flash"`` / ``"dense"``, with
     ``"+window"`` where the model has window layers (they take the same form by
-    the same rule, under the window's mask: the kernel's runtime window, or a
-    [T, T] bias on the dense scores)."""
+    the same rule, under the window's mask: the kernel's banded grid,
+    ``window_grid_form``, or a [T, T] bias on the dense scores)."""
     form = cache_attention_form(cfg.num_heads, 1, bucket, bucket)
     return form + "+window" if cfg.window_layers else form
+
+
+def window_grid_form(cfg: TransformerConfig, bucket: int) -> dict:
+    """What a prefill span says of the flash forward grid its window layers took
+    (nothing where no window layer goes through the kernel): ``window_grid``,
+    ``"band"`` where the grid runs over the key blocks the window reaches (the
+    window is a constant of the trace: ``_cache_attention`` hands the kernel the
+    layer's own) or ``"causal"`` where it ran every block at or under the
+    diagonal, and ``window_blocks_pct``, the key blocks it computes as a share of
+    those, from the bucket's shapes: ``flash_attention.window_grid``, the rule
+    the kernel itself is traced by."""
+    if not cfg.window_layers or cache_attention_form(cfg.num_heads, 1, bucket, bucket) != "flash":
+        return {}
+    from ..ops.pallas.flash_attention import window_grid
+
+    grid, pct = window_grid(bucket, cfg.local_attn_window, cfg.num_heads, cfg.head_dim,
+                            cfg.head_dim, jnp.dtype(cfg.dtype).itemsize)
+    return {"window_grid": grid, "window_blocks_pct": round(pct, 2)}
 
 
 def expert_bank_form(cfg: TransformerConfig, moe, mesh=None):
@@ -2245,7 +2263,8 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
     its index among the whole-context layers. A WINDOW layer keeps a ring
     (``cache_layout``) and has two forms: a block that STARTS its sequences
     (``pos`` the Python int 0: a prefill) attends to itself under the window's
-    mask (flash kernel with its runtime window, or densely with a [T, T] bias, by
+    mask (flash kernel over the band of blocks the window reaches, the window a
+    constant of the trace, or densely with a [T, T] bias, by
     ``cache_attention_form``'s rule on the block's own score matrix) and writes
     its last R LIVE rows (``live``: not a bucket's padding) into the ring, row p
     at p mod R; a one-token step writes at ``pos mod R`` and attends over the

@@ -68,6 +68,122 @@ def _auto_block(s: int, cap: int) -> int:
     return min(b, s)
 
 
+# Under a window that is a constant of the trace the forward runs over a band of
+# key blocks (``_band_forward``), and the triangle's blocks are the wrong size for
+# it: a 1024-wide key block is 8 x a window of 128. The key block is the smallest
+# multiple of 128 that holds the window; the query block was timed on the chip
+# (PERF.md §6, PR 40: the kernel alone at [64 heads, rows, 128], window 128).
+BAND_BLOCK_Q = 256
+# What a banded step may hold of the 16 MiB of VMEM the chip's compiler gives a
+# kernel (``_band_step_bytes``, an upper estimate fitted to what the compiler
+# reported for 150 shapes: tests/test_chip_compile.py compiles a sweep).
+BAND_VMEM_BYTES = 14 * 2 ** 20
+# Heads of a banded step written out inline in one iteration of its loop over
+# them: 1 / 2 / 4 / 8 of 8 read 4.06 / 3.17 / 2.91 / 2.89 ms at 16,384 rows
+# (PERF.md §6, PR 40), and the kernel's trace grows with it.
+BAND_UNROLL = 2
+
+
+def static_window(window, sq: int, sk: int, causal: bool):
+    """-> (window, band): what a caller's ``window`` is to the kernels, read
+    from what the trace can see of it. A traced window (GPT-Neo's scanned layers
+    choose theirs by the layer index) stays the runtime operand it was, band 0.
+    A Python number is a constant of the trace: one that masks nothing (<= 0:
+    global; >= the rows) is no window at all, and for causal self-attention any
+    other gives ``band`` = W > 0, a query row seeing the keys q - W + 1 .. q
+    (distances are whole, so d < w is d < ceil(w))."""
+    if window is None or isinstance(window, jax.Array):
+        return window, 0
+    w = math.ceil(float(window))
+    if w <= 0 or w >= sq:
+        return None, 0
+    return window, w if causal and sq == sk else 0
+
+
+def _band_blocks(s: int, band: int) -> tuple[int, int]:
+    """(block_q, block_k) of the banded forward over ``s`` rows (a multiple of
+    128) where the caller names none."""
+    bk = min(-(-band // 128) * 128, MAX_BLOCK_K)
+    while s % bk:
+        bk -= 128
+    return _auto_block(s, BAND_BLOCK_Q), bk
+
+
+def _band_lo(qi, block_q, block_k, band, maximum=max):
+    """The first key block the band of query block ``qi`` reaches (its first row
+    sees back to position qi * block_q - band + 1); ``maximum=jnp.maximum`` for
+    a traced ``qi``."""
+    return maximum(qi * block_q - band + 1, 0) // block_k
+
+
+def _diag(qi, block_q, block_k):
+    """The last key block at or under the diagonal of query block ``qi``."""
+    return (qi * block_q + block_q - 1) // block_k
+
+
+def _band_steps(num_q, block_q, block_k, band):
+    """Key blocks the band of each query block reaches, a list over query
+    blocks: its max is how many the banded forward hands a step (``block_q /
+    block_k + ceil((band - 1) / block_k)`` for ``block_q`` a multiple of
+    ``block_k``), its sum the blocks it computes."""
+    return [_diag(qi, block_q, block_k) - _band_lo(qi, block_q, block_k, band) + 1
+            for qi in range(num_q)]
+
+
+def _band_step_bytes(heads, block_q, block_k, views, d, dv, itemsize):
+    """VMEM one banded step holds, from above: its blocks twice (they are
+    double-buffered: q, o and a float32 lse row block, ``views`` key and value
+    blocks; a head narrower than the 128 lanes is padded to them) and the float32
+    scores and probabilities of the heads in flight at once (the compiler
+    reported 1 to 3 heads' worth with every head written out inline; the loop
+    now holds ``BAND_UNROLL``)."""
+    wide = lambda w: -(-w // LANES) * LANES
+    blocks = (itemsize * (block_q + views * block_k) * (wide(d) + wide(dv))
+              + 4 * LANES * block_q)
+    scores = views * block_q * wide(block_k) * (4 + 4 + itemsize)
+    return 2 * heads * blocks + min(heads, 3) * scores
+
+
+def band_plan(rows, band, bh, d, dv, itemsize, block_q=None, block_k=None):
+    """-> (block_q, block_k, views, heads) of the banded forward over ``rows`` (a
+    multiple of 128) x ``bh`` fused batch x head programs under the static
+    window ``band``, or None where even one head's step would overflow
+    ``BAND_VMEM_BYTES`` (a window of thousands of keys: the whole grid, which
+    streams one key block a step, takes it under the window as an operand).
+    ``views``: the key blocks a step is handed; ``heads``: the programs that
+    ride one step, the most of 8, 4, 2, 1 that divides ``bh`` and fits (a
+    step's work is small, its fixed cost is not)."""
+    auto_q, auto_k = _band_blocks(rows, band)
+    block_q, block_k = min(block_q or auto_q, rows), min(block_k or auto_k, rows)
+    if rows % block_q or rows % block_k:
+        return None
+    views = max(_band_steps(rows // block_q, block_q, block_k, band))
+    for heads in (8, 4, 2, 1):
+        if bh % heads == 0 and _band_step_bytes(
+                heads, block_q, block_k, views, d, dv, itemsize) <= BAND_VMEM_BYTES:
+            return block_q, block_k, views, heads
+    return None
+
+
+@functools.lru_cache(maxsize=None)  # a serving worker asks on every prefill call
+def window_grid(rows: int, window, bh: int, d: int, dv: int, itemsize: int,
+                block_q: int | None = None, block_k: int | None = None):
+    """-> (``"band"`` | ``"causal"``, the key blocks it computes as a % of those
+    at or under the diagonal): the forward that ``flash_attention(causal=True,
+    window=window)`` takes for self-attention over ``rows`` x ``bh`` batch x head
+    programs of width ``d`` (values ``dv``), from the shapes and the window (a
+    Python number or None) alone (``SlotWorker.prefill`` labels its span by it)."""
+    rows += (-rows) % 128
+    _, band = static_window(window, rows, rows, True)
+    plan = band and band_plan(rows, band, bh, d, dv, itemsize, block_q, block_k)
+    if not plan:
+        return "causal", 100.0
+    bq, bk = plan[:2]
+    num_q = rows // bq
+    under = sum(_diag(qi, bq, bk) + 1 for qi in range(num_q))
+    return "band", 100.0 * sum(_band_steps(num_q, bq, bk, band)) / under
+
+
 def _vmem_spec(shape, index_map):
     return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
@@ -206,8 +322,122 @@ def _fwd_kernel(
         lse_ref[0] = m_scr[...] + jnp.log(l_safe)
 
 
+def _band_kernel(q_ref, *refs, sm_scale, band, views, has_slopes, with_lse, unroll):
+    """One query block of ``heads`` fused batch x head programs against the
+    ``views`` key blocks its band reaches, all in this ONE grid step (refs: k x
+    views, v x views[, slopes], o[, lse]): the band is a few blocks wide
+    whatever the sequence's length, so the softmax is taken over all of it at
+    once: no running max, no rescaled accumulator, no scratch. View ``j`` holds
+    key block ``lo(qi) + j`` (``_band_forward``'s index map holds it at the
+    diagonal's block once past it; masked at the index it stands for, such a
+    view lies above the diagonal and is empty). The mask is one per view for
+    every head of the step; a row's max and sum are taken across the views
+    elementwise first, so each costs one cross-lane reduction a row, not one a
+    view. The heads go ``unroll`` at a time through a ``fori_loop``: written
+    out in Python all eight overlap best on the chip, but the kernel is traced
+    and lowered again in every process for every program that holds it, and
+    eight copies of the body made that 1 s a prefill program (PERF.md §6,
+    PR 40)."""
+    k_refs, v_refs = refs[:views], refs[views:2 * views]
+    slope_ref = refs[2 * views] if has_slopes else None
+    o_ref = refs[2 * views + has_slopes]
+    qi = pl.program_id(1)
+    heads, block_q, _ = q_ref.shape
+    block_k = k_refs[0].shape[1]
+    lo = _band_lo(qi, block_q, block_k, band, jnp.maximum)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    # q_pos - k_pos of view j: a key is seen from 0 (causal) up to band - 1 behind
+    dist = [rows - cols + (qi * block_q - (lo + j) * block_k) for j in range(views)]
+    seen = [(d >= 0) & (d < band) for d in dist]
+
+    def head(h):
+        q = q_ref[h]
+        s = []
+        for j in range(views):
+            sj = sm_scale * jax.lax.dot_general(
+                q, k_refs[j][h], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            if has_slopes:
+                sj = sj - _widen(slope_ref[h], block_k) * dist[j].astype(jnp.float32)
+            s.append(jnp.where(seen[j], sj, NEG_INF))
+        # a row's own position is always in its band: m is a real score's
+        m = jnp.max(functools.reduce(jnp.maximum, s), axis=1, keepdims=True)
+        p = [jnp.exp(sj - m) for sj in s]
+        l = jnp.sum(functools.reduce(jnp.add, p), axis=1, keepdims=True)
+        acc = functools.reduce(jnp.add, [
+            jax.lax.dot_general(pj.astype(v_refs[j].dtype), v_refs[j][h],
+                                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            for j, pj in enumerate(p)])
+        o_ref[h] = (acc * (1.0 / l)).astype(o_ref.dtype)
+        if with_lse:
+            refs[-1][h] = jnp.broadcast_to(m + jnp.log(l), (block_q, LANES))
+
+    unroll = min(unroll, heads)
+
+    def some(i, carry):
+        for u in range(unroll):
+            head(i * unroll + u)
+        return carry
+
+    jax.lax.fori_loop(0, heads // unroll, some, None)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "block_q", "block_k", "band", "interpret",
+                                             "with_lse", "heads", "unroll"))
+def _band_forward(q, k, v, slopes_bh, sm_scale, block_q, block_k, band, interpret,
+                  with_lse=True, heads=None, unroll=BAND_UNROLL):
+    """The forward of causal self-attention under a window known to the trace
+    (``static_window``): grid ``(BH / heads, Sq / block_q)``, and a query block
+    gets the key blocks its band reaches as SEPARATE operands of one step
+    (``_band_kernel``), where the whole grid streams every key block through an
+    innermost dimension. View ``j`` of query block ``qi`` is key block
+    ``min(lo(qi) + j, diag(qi))``. ``heads`` programs ride one step
+    (``band_plan``'s, unless the caller times its own). Without ``with_lse``
+    (nothing will differentiate the call) the logsumexp, a float32 row of 128
+    lanes a query, twice the output's bytes, is not written:
+    -> (out, lse | None). Under ``jit``, so that the layers of a program that
+    call it at one shape share one trace of the kernel."""
+    BH, Sq, D = q.shape
+    Dv = v.shape[2]
+    _, _, views, planned = band_plan(Sq, band, BH, D, Dv, q.dtype.itemsize, block_q, block_k)
+    heads = heads or planned
+
+    def kv_view(j):
+        def index(g, qi):
+            lo = _band_lo(qi, block_q, block_k, band, jnp.maximum)
+            return (g, jnp.minimum(lo + j, _diag(qi, block_q, block_k)), 0)
+        return index
+
+    in_specs = ([_vmem_spec((heads, block_q, D), lambda g, qi: (g, qi, 0))]
+                + [_vmem_spec((heads, block_k, D), kv_view(j)) for j in range(views)]
+                + [_vmem_spec((heads, block_k, Dv), kv_view(j)) for j in range(views)])
+    operands = [q] + [k] * views + [v] * views
+    if slopes_bh is not None:
+        in_specs.append(_vmem_spec((heads, 1, LANES), lambda g, qi: (g, 0, 0)))
+        operands.append(slopes_bh)
+    outs = [(Dv, q.dtype)] + ([(LANES, jnp.float32)] if with_lse else [])
+    out = pl.pallas_call(
+        functools.partial(_band_kernel, sm_scale=sm_scale, band=band, views=views,
+                          has_slopes=slopes_bh is not None, with_lse=with_lse, unroll=unroll),
+        grid=(BH // heads, Sq // block_q),
+        in_specs=in_specs,
+        out_specs=[_vmem_spec((heads, block_q, width), lambda g, qi: (g, qi, 0))
+                   for width, _ in outs],
+        out_shape=[jax.ShapeDtypeStruct((BH, Sq, width), dtype) for width, dtype in outs],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="flash_fwd_band",
+    )(*operands)
+    return out[0], out[1] if with_lse else None
+
+
 def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
-                   block_k, interpret):
+                   block_k, interpret, band=0, with_lse=True):
+    """-> (out, lse). ``band`` (``static_window``): ``_band_forward``, which
+    alone heeds ``with_lse``; ``w_arr`` is then the backward's alone."""
+    if band:
+        return _band_forward(q, k, v, slopes_bh, sm_scale, block_q, block_k, band, interpret,
+                             with_lse)
     BH, Sq, D = q.shape
     Dv = v.shape[2]  # a value head may be narrower than a q/k head (latent attention)
     Sk = k.shape[1]
@@ -442,18 +672,18 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
 # Public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash_bhsd(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q, block_k,
-                interpret):
+                interpret, band):
     out, _ = _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal,
-                            block_q, block_k, interpret)
+                            block_q, block_k, interpret, band, with_lse=False)
     return out
 
 
 def _flash_bhsd_fwd(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
-                    block_k, interpret):
+                    block_k, interpret, band):
     out, lse = _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal,
-                              block_q, block_k, interpret)
+                              block_q, block_k, interpret, band)
     # Under jax.checkpoint, out/lse are the residuals the backward kernels
     # need; naming them lets a remat policy (models/transformer.py
     # _remat_policy 'flash' names) save them so the forward kernel is NOT
@@ -464,7 +694,13 @@ def _flash_bhsd_fwd(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
     return out, (q, k, v, slopes_bh, w_arr, out, lse)
 
 
-def _flash_bhsd_bwd(sm_scale, causal, block_q, block_k, interpret, res, g):
+def _flash_bhsd_bwd(sm_scale, causal, block_q, block_k, interpret, band, res, g):
+    # the backward kernels keep the whole grid: a band's window reaches them as
+    # the array a traced one would be (``w_arr`` among the residuals), and they
+    # take the triangle's blocks, not the band's
+    if band:
+        block_q = _auto_block(g.shape[1], MAX_BLOCK_Q)
+        block_k = _auto_block(g.shape[1], MAX_BLOCK_K)
     return _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret)
 
 
@@ -495,8 +731,13 @@ def flash_attention(
     kernels the same way, pt_binding.cpp:1231-1283):
       * ``alibi_slopes``: per-head slopes [H] (BLOOM). Bias added to the
         scores is slope_h * (k_pos - q_pos).
-      * ``window``: runtime local-attention window (traced scalar; <= 0 means
-        global) — GPT-Neo's alternating local layers run one compiled kernel.
+      * ``window``: local-attention window (<= 0 means global). A traced
+        scalar is a runtime operand — GPT-Neo's alternating local layers run
+        one compiled kernel. A Python number is a constant of the trace
+        (``static_window``): for causal self-attention the FORWARD grid is then
+        a band of the key blocks the window reaches, at blocks sized to it
+        (``band_plan``; a caller's ``block_q`` / ``block_k`` still win), and
+        the backward kernels run their whole grid under the same window.
     A general dense ``bias`` tensor is not fused; those callers use the XLA
     path (models/transformer._attention_dispatch falls back).
 
@@ -519,6 +760,7 @@ def flash_attention(
         # one [1, LANES] row per fused batch×head program
         slopes_bh = jnp.broadcast_to(
             jnp.tile(sl, B)[:, None, None], (B * H, 1, LANES))
+    window, band = static_window(window, Sq, Sk, causal)
     w_arr = None
     if window is not None:
         w_arr = jnp.full((1, LANES), 0.0, jnp.float32) + jnp.asarray(
@@ -540,8 +782,15 @@ def flash_attention(
                 f"cross-attention lengths ({Sq}, {Sk}) must be 128-aligned"
             )
     Sq_p, Sk_p = q.shape[1], k.shape[1]
-    block_q = min(block_q, Sq_p) if block_q else _auto_block(Sq_p, MAX_BLOCK_Q)
-    block_k = min(block_k, Sk_p) if block_k else _auto_block(Sk_p, MAX_BLOCK_K)
+    # a band whose step fits VMEM takes the blocks ``band_plan`` sized to it
+    plan = band and band_plan(Sq_p, band, B * H, D, v.shape[3], q.dtype.itemsize,
+                              block_q, block_k)
+    if plan:
+        block_q, block_k = plan[:2]
+    else:
+        band = 0
+        block_q = min(block_q, Sq_p) if block_q else _auto_block(Sq_p, MAX_BLOCK_Q)
+        block_k = min(block_k, Sk_p) if block_k else _auto_block(Sk_p, MAX_BLOCK_K)
     if Sq_p % block_q or Sk_p % block_k:
         raise ValueError(
             f"sequence lengths ({Sq_p}, {Sk_p}) must be divisible by blocks ({block_q}, {block_k})"
@@ -554,7 +803,7 @@ def flash_attention(
 
     out = _flash_bhsd(
         to_bhsd(q), to_bhsd(k), to_bhsd(v), slopes_bh, w_arr, sm_scale, causal,
-        block_q, block_k, interpret
+        block_q, block_k, interpret, band
     )
     out = out.reshape(B, H, Sq_p, v.shape[3]).transpose(0, 2, 1, 3)
     if pad_q:
@@ -571,13 +820,18 @@ def flash_attention_sharded(q, k, v, *, mesh, alibi_slopes=None, window=None, **
     rows (data/fsdp axes) and heads (model axis) — the slot cache's layout
     rule, parallel/sharding.batch_and_head_axes. Inside another shard_map
     (the pipeline's stage program) the caller's manual axes already own the
-    layout and the kernel is called as is."""
+    layout and the kernel is called as is. A window that is a constant of the
+    trace is closed over, not passed: as an operand of ``shard_map`` it would
+    arrive traced and the kernel would keep the whole grid."""
     from jax.sharding import PartitionSpec as P
 
     from ...parallel.sharding import batch_and_head_axes
 
-    def call(q, k, v, slopes, w):
-        return flash_attention(q, k, v, alibi_slopes=slopes, window=w, **kw)
+    traced_window = isinstance(window, jax.Array)
+
+    def call(q, k, v, slopes, w=None):
+        return flash_attention(q, k, v, alibi_slopes=slopes,
+                               window=w if traced_window else window, **kw)
 
     if mesh is None or mesh.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
         return call(q, k, v, alibi_slopes, window)
@@ -587,4 +841,4 @@ def flash_attention_sharded(q, k, v, *, mesh, alibi_slopes=None, window=None, **
     return jax.shard_map(
         call, mesh=mesh, in_specs=(qkv, qkv, qkv, P(head_axis), P()),
         out_specs=qkv, check_vma=False,
-    )(q, k, v, alibi_slopes, window)
+    )(q, k, v, alibi_slopes, window if traced_window else None)

@@ -142,6 +142,38 @@ def test_kernel_compiles_for_v5e(build, v5e, no_persistent_cache):
     assert _footprint(compiled) < HBM_BYTES
 
 
+@pytest.mark.parametrize("window", [100, 128, 512, 1024, 2048, 4096])
+@pytest.mark.parametrize("widths,dtype", [((64, 64), jnp.bfloat16), ((128, 128), jnp.bfloat16),
+                                          ((192, 128), jnp.bfloat16), ((256, 256), jnp.bfloat16),
+                                          ((128, 128), jnp.float32), ((256, 256), jnp.float32)],
+                         ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else x.__name__)
+def test_a_static_window_compiles_as_a_band_or_keeps_the_whole_grid(widths, dtype, window, v5e,
+                                                                    no_persistent_cache):
+    """``flash_attention(window=<a Python number>)`` at 16,384 rows x 8 heads for
+    the chip, forward with the logsumexp (the most VMEM a step takes): a band
+    whose step ``band_plan`` sized (``flash_fwd_band``: heads and key blocks a
+    step by its estimate, inside the 16 MiB the compiler gives a kernel) or,
+    where one head's step would not fit (a window of thousands of keys at wide
+    heads), the whole grid under the window as an operand. Neither is refused."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    rows, heads, (d, dv) = 16384, 8, widths
+    one_chip = SingleDeviceSharding(v5e[0])
+    qk = jax.ShapeDtypeStruct((heads, rows, d), dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((heads, rows, dv), dtype, sharding=one_chip)
+    plan = fa.band_plan(rows, window, heads, d, dv, jnp.dtype(dtype).itemsize)
+    w_arr = jnp.full((1, fa.LANES), float(window), jnp.float32)
+    blocks = plan[:2] if plan else (fa.MAX_BLOCK_Q, fa.MAX_BLOCK_K)
+    compiled = jax.jit(lambda q, k, v: fa._flash_forward(
+        q, k, v, None, w_arr, d ** -0.5, True, *blocks, False,
+        band=window if plan else 0)).lower(qk, qk, v).compile()
+    assert ("flash_fwd_band" in compiled.as_text()) == bool(plan)
+    # the cell's shape keeps the step that was timed; only a wide band is given up
+    if (widths, dtype, window) == ((128, 128), jnp.bfloat16, 128):
+        assert plan == (256, 128, 3, 8)
+    assert plan or window >= 1024
+
+
 def _bare_slot_worker(cfg, n, Smax, one_chip):
     """A ``SlotWorker`` with just what its program builders read, and the
     shapes of its operands on the described chip, the weights typed as
@@ -511,6 +543,29 @@ def test_kinds_decode_program_keeps_both_cache_kinds_in_place(v5e, no_persistent
     ring_bytes = 4 * n * 128 * 8 * 128 * 2
     assert ma.alias_size_in_bytes >= 2 * (layer_bytes + ring_bytes)  # donated in, aliased out
     assert ma.temp_size_in_bytes < 2.5 * layer_bytes, (ma.temp_size_in_bytes, layer_bytes)
+
+
+def test_kinds_prefill_takes_the_banded_forward_in_its_window_layers(v5e, no_persistent_cache,
+                                                                    as_tpu):
+    """The cell's longest prefill (16,384 rows, the five layers S S S G S at 64
+    heads of 128, window 128) compiled for the chip: the four window layers go
+    through the banded forward (``flash_fwd_band``: a query block of 256 rows
+    gets the 3 key blocks of 128 its band reaches as operands of one step, eight
+    heads a step, in the VMEM the compiler allows), the whole-context layer
+    through the whole causal grid (``flash_fwd``: q, k, v and no window), nothing
+    rows x rows is made, and the program fits beside nothing else of the cell."""
+    n, Smax = 4, 16384
+    cfg, worker, params, cache, sds = _k_exaone_worker(n, Smax, v5e)
+    compiled = _compile_prefill(worker, params, cache, Smax, sds)
+    text = compiled.as_text()
+    calls = re.findall(r'^\s*%?(flash_fwd[a-z_]*)[\d.]* = .*?custom-call\((.*?)\), '
+                       r'custom_call_target="tpu_custom_call"', text, re.M)
+    operands = {}
+    for name, ops in calls:
+        operands.setdefault(name, []).append(len(ops.split(", ")))
+    assert operands == {"flash_fwd_band": [7] * 4, "flash_fwd": [3]}, operands
+    assert not re.search(rf"f32\[(?:1,)?{cfg.num_heads},{Smax},{Smax}\]", text)
+    assert _footprint(compiled) < HBM_BYTES
 
 
 def test_latent_prefill_attends_through_the_flash_kernel(v5e, no_persistent_cache, as_tpu):

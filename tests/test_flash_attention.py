@@ -120,3 +120,211 @@ def test_sharded_wrapper_matches_unsharded(mesh_cfg):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5)
     for a, b in zip(out_g, ref_g):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# A window that is a constant of the trace: the banded forward grid (PR 40)
+# ---------------------------------------------------------------------------
+
+def _dense_window(q, k, v, window):
+    """[BH, S, D] causal attention under ``window`` (a query sees the keys
+    q - window + 1 .. q), with each row's logsumexp."""
+    import math
+
+    S = q.shape[1]
+    s = jnp.einsum("bqd,bkd->bqk", q, k) / math.sqrt(q.shape[-1])
+    i = jnp.arange(S)
+    seen = (i[:, None] >= i[None, :]) & (i[:, None] - i[None, :] < window)
+    s = jnp.where(seen[None], s, -jnp.inf)
+    return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, axis=-1), v), jax.nn.logsumexp(s, axis=-1)
+
+
+def _pallas_calls(fn, *args):
+    """name -> (grid, block shapes, operands) of every ``pallas_call`` ``fn``
+    traces to, nested jaxprs (the custom_vjp's, a shard_map's) among them."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                gm = eqn.params["grid_mapping"]
+                blocks = [tuple(getattr(b, "block_size", b) for b in bm.block_shape)
+                          for bm in gm.block_mappings]
+                found[eqn.params["name"]] = (tuple(gm.grid), blocks, len(eqn.invars))
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (list, tuple)) else [value]:
+                    inner = getattr(sub, "jaxpr", sub)
+                    inner = getattr(inner, "jaxpr", inner)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (512, 128), (128, 256), (256, 256)],
+                         ids=lambda b: f"bq{b[0]}-bk{b[1]}")
+@pytest.mark.parametrize("window", [1, 100, 128, 256])
+@pytest.mark.parametrize("rows", [256, 1024, 2048])
+def test_banded_forward_matches_the_whole_grid_and_a_dense_reference(rows, window, blocks):
+    """The forward over the band of key blocks a static window reaches (one step
+    a query block, the band's blocks its operands) gives the whole grid's outputs
+    and logsumexp under the same window as a runtime operand, and a dense
+    reference's: at a window of one position, one that is no multiple of a
+    block, one block, two blocks; query blocks narrower than, equal to and wider
+    than the key blocks."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    block_q, block_k = (min(b, rows) for b in blocks)
+    ks = jax.random.split(jax.random.PRNGKey(rows + window), 3)
+    q, k, v = (jax.random.normal(kk, (2, rows, 32), jnp.float32) * 0.5 for kk in ks)
+    w_arr = jnp.full((1, fa.LANES), float(window), jnp.float32)
+    args = (q, k, v, None, w_arr, 32 ** -0.5, True, block_q, block_k, True)
+    out_w, lse_w = fa._flash_forward(*args)            # whole grid, runtime window
+    out_b, lse_b = fa._flash_forward(*args, band=window)
+    ref, ref_lse = _dense_window(q, k, v, window)
+    for got, lse in ((out_b, lse_b), (out_w, lse_w)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse[:, :, 0]), np.asarray(ref_lse),
+                                   rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out_b), np.asarray(out_w), rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(lse_b), np.asarray(lse_w), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("window", [1, 100, 128, 256, 512, 5000, 0, -1.0])
+def test_static_window_through_the_public_call(window):
+    """``flash_attention(window=<a Python number>)`` at its own choice of blocks:
+    the dense reference's outputs, whether the window makes a band, covers every
+    row (>= the rows: no window at all) or is global (<= 0)."""
+    S = 512
+    q, k, v = _qkv(B=1, S=S, H=2, D=32, seed=3)
+    to_bhsd = lambda x: x.transpose(0, 2, 1, 3).reshape(2, S, 32)
+    ref, _ = _dense_window(to_bhsd(q), to_bhsd(k), to_bhsd(v), window if window > 0 else S)
+    out = flash_attention(q, k, v, window=window)
+    np.testing.assert_allclose(np.asarray(to_bhsd(out)), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [100, 128])
+def test_static_window_with_alibi_matches_the_traced_window(window):
+    """BLOOM's slopes beside a static window: the banded forward computes the
+    bias from the same distances as the whole grid does under a traced one."""
+    q, k, v = _qkv(B=1, S=1024, H=4, D=32, seed=7)
+    slopes = jnp.asarray([0.5, 0.25, 0.125, 0.0625], jnp.float32)
+    band = flash_attention(q, k, v, alibi_slopes=slopes, window=window)
+    whole = flash_attention(q, k, v, alibi_slopes=slopes, window=jnp.float32(window))
+    np.testing.assert_allclose(np.asarray(band), np.asarray(whole), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("window,rows", [(100, 256), (128, 512), (256, 512), (1, 256)])
+def test_gradients_through_a_static_window_match_the_dense_reference(window, rows):
+    """Forward only: under ``jax.grad`` the banded forward's residuals go to the
+    backward kernels, which run their whole grid under the same window."""
+    q, k, v = _qkv(B=1, S=rows, H=2, D=32, seed=5)
+    to_bhsd = lambda x: x.transpose(0, 2, 1, 3).reshape(2, rows, 32)
+    tgt = jax.random.normal(jax.random.PRNGKey(9), (2, rows, 32))
+
+    def loss_flash(q, k, v):
+        return jnp.sum(to_bhsd(flash_attention(q, k, v, window=window)) * tgt)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(_dense_window(to_bhsd(q), to_bhsd(k), to_bhsd(v), window)[0] * tgt)
+
+    assert _pallas_calls(jax.grad(loss_flash), q, k, v)["flash_bwd_dq"][2] == 7  # + the window
+    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("rows,window,blocks,grid", [
+    (2048, 128, (None, None), "band"), (2048, 128, (256, 128), "band"),
+    (2048, 128, (512, 128), "band"), (2048, 128, (128, 256), "band"),
+    (16384, 128, (None, None), "band"), (2048, 100, (None, None), "band"),
+    (2048, 300, (None, None), "band"),
+    (2048, None, (None, None), "whole"), (2048, "traced", (None, None), "whole"),
+    (2048, 0, (None, None), "whole"), (2048, 2048, (None, None), "whole"),
+    (2048, 128, "not causal", "whole"),
+], ids=lambda x: str(x).replace(" ", ""))
+def test_lowered_forward_grid(rows, window, blocks, grid):
+    """The grid the forward kernel is traced with. A static window: NO dimension
+    over key blocks; a query block's step gets the key blocks its band reaches
+    as operands, ``block_q / block_k + ceil((W - 1) / block_k)`` of K and as
+    many of V, at blocks sized to the window, several heads a step, no window
+    operand. No window, a window that masks nothing, a TRACED window (GPT-Neo's
+    scanned layers) and a call that is not causal: ``Sk / block_k`` steps at the
+    triangle's blocks, as before PR 40, the traced window one more operand.
+    Either kernel's name begins ``flash_fwd``."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    x = jax.ShapeDtypeStruct((1, rows, 8, 32), jnp.float32)
+    causal = blocks != "not causal"
+    bq, bk = blocks if causal else (None, None)
+    if window == "traced":
+        fn = lambda q, k, v, w: flash_attention(q, k, v, window=w)
+        args = (x, x, x, jax.ShapeDtypeStruct((), jnp.float32))
+    else:
+        fn = lambda q, k, v: flash_attention(q, k, v, causal=causal, window=window,
+                                             block_q=bq, block_k=bk)
+        args = (x, x, x)
+    calls = _pallas_calls(fn, *args)
+    (name, (grid_dims, block_shapes, operands)), = calls.items()
+    assert name.startswith("flash_fwd")
+    block_q, block_k = block_shapes[0][1], block_shapes[1][1]
+    shape = (8, 32, 32, 4)  # batch x heads, the head widths and the itemsize of ``x``
+    if grid == "band":
+        auto_q, auto_k = fa._band_blocks(rows, window)
+        assert (block_q, block_k) == (bq or auto_q, bk or auto_k)
+        assert auto_k == min(-(-window // 128) * 128, 256)  # 300 -> 384 does not divide 2048
+        views = max(fa._band_steps(rows // block_q, block_q, block_k, window))
+        if block_q % block_k == 0:
+            assert views == block_q // block_k + -(-(window - 1) // block_k)
+        heads = block_shapes[0][0]
+        assert (block_q, block_k, views, heads) == fa.band_plan(rows, window, *shape, bq, bk)
+        assert heads > 1 and grid_dims == (8 // heads, rows // block_q)
+        assert operands == 1 + 2 * views and views < rows // block_k
+        form, pct = fa.window_grid(rows, window, *shape, bq, bk)
+        assert form == "band" and 0 < pct < 100
+    else:
+        assert name == "flash_fwd"
+        assert (block_q, block_k) == (fa.MAX_BLOCK_Q, fa.MAX_BLOCK_K)
+        assert grid_dims == (8, rows // block_q, rows // block_k)
+        # a traced window, and a static one the band cannot take, ride as an operand
+        assert operands == 3 + (window == "traced" or not causal)
+        if causal and window != "traced":
+            assert fa.window_grid(rows, window, *shape) == ("causal", 100.0)
+
+
+def test_band_visits_the_share_of_the_causal_grid_it_says():
+    """``window_blocks_pct``'s arithmetic: 16,384 rows under a window of 128 at
+    (512, 128) blocks are 32 query blocks of 5 key blocks each but the first
+    (4), against 4 + 8 + ... + 128 at or under the diagonal."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    form, pct = fa.window_grid(16384, 128.0, 64, 128, 128, 2, 512, 128)
+    assert form == "band" and pct == pytest.approx(100 * (32 * 5 - 1) / (4 * 32 * 33 / 2))
+
+
+@pytest.mark.parametrize("window", [128.0, "traced"])
+def test_sharded_wrapper_keeps_a_static_window_static(window):
+    """On a mesh of several devices the call is wrapped in ``shard_map``: a
+    static window is closed over (the banded forward inside, no window operand),
+    a traced one stays the operand it was; outputs equal the unsharded call's."""
+    from deepspeed_tpu.comm.mesh import MeshConfig, build_mesh
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_sharded
+
+    mesh = build_mesh(MeshConfig(data=2, fsdp=2, model=2))
+    q, k, v = _qkv(B=4, S=512)
+    if window == "traced":
+        fn = jax.jit(lambda q, k, v, w: flash_attention_sharded(q, k, v, mesh=mesh, window=w))
+        args = (q, k, v, jnp.float32(128))
+    else:
+        fn = jax.jit(lambda q, k, v: flash_attention_sharded(q, k, v, mesh=mesh, window=window))
+        args = (q, k, v)
+    (name, (grid, _, operands)), = _pallas_calls(fn, *args).items()
+    if window == "traced":  # the whole grid (512 rows: one key block), the window an operand
+        assert (name, grid[2], operands) == ("flash_fwd", 1, 4)
+    else:  # per device 2 batch rows x 2 heads; no dimension over key blocks, no window operand
+        assert name == "flash_fwd_band" and len(grid) == 2 and operands % 2 == 1
+    ref = flash_attention(q, k, v, window=128.0)
+    np.testing.assert_allclose(np.asarray(fn(*args)), np.asarray(ref), rtol=1e-5, atol=1e-5)

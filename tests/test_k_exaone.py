@@ -535,6 +535,84 @@ def test_spans_and_pools_say_what_was_read(served):
         srv.worker.kv_export(16, 0, 0)
 
 
+# -- a prefill through a flash+window bucket (PR 40: the banded forward) --------------------------
+
+LONG = 1024  # a bucket whose band is narrower than its causal grid at the band's blocks (512, 128)
+
+
+@pytest.fixture
+def flash_from_1024_rows(monkeypatch, cfg):
+    """``cache_attention_form``'s rule met at 1,024 rows of the twin's 4 heads (the
+    cell meets it from 1,024 rows at 64): the bucket attends through the flash
+    kernel, its window layers through the band."""
+    monkeypatch.setattr(tfm, "DENSE_SCORE_BYTES", 4 * cfg.num_heads * LONG ** 2 - 1)
+    assert tfm.cache_block_form(cfg, LONG) == "flash+window"
+    assert tfm.cache_block_form(cfg, LONG // 2) == "dense+window"
+
+
+def test_a_flash_window_prefill_is_the_reference_and_writes_the_parents_rings(
+        cfg, params, program, reference, flash_from_1024_rows, monkeypatch):
+    """A 700-token prompt padded to 1,024 rows: the window layers take the banded
+    forward (the window a constant of the trace). Its logits are the reference's,
+    and logits and rings are those of the parent's form (the whole causal grid
+    under the window as a runtime operand: ``static_window`` made to see no
+    constant) and of the dense form under a [T, T] bias."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    prompt = _tokens(cfg, (700,), 40)
+    band_logits, band_cache = _prefill(cfg, params, tfm.init_cache(cfg, 2, LONG), 1, prompt)
+    ref = reference.routed_pass(program, params, prompt, np.arange(699, 700), fetch=WHOLE)
+    assert np.max(np.abs(band_logits - ref["logits"][0])) <= TOL
+
+    def rings(cache):
+        return [np.asarray(cache[tfm.RING][name]) for name in ("k", "v")]
+
+    assert all(r[:, 1].any() and not r[:, 0].any() for r in rings(band_cache))
+    with monkeypatch.context() as parent:
+        parent.setattr(fa, "static_window", lambda window, *shape: (window, 0))
+        whole_logits, whole_cache = _prefill(cfg, params, tfm.init_cache(cfg, 2, LONG), 1, prompt)
+    with monkeypatch.context() as dense:
+        dense.setattr(tfm, "DENSE_SCORE_BYTES", 2 ** 40)
+        dense_logits, dense_cache = _prefill(cfg, params, tfm.init_cache(cfg, 2, LONG), 1, prompt)
+    for logits, cache in ((whole_logits, whole_cache), (dense_logits, dense_cache)):
+        assert np.max(np.abs(band_logits - logits)) <= TOL
+        for got, want in zip(rings(band_cache), rings(cache)):
+            np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
+
+
+def test_the_engine_serves_a_flash_window_bucket_and_says_which_grid(program,
+                                                                     flash_from_1024_rows):
+    """Through ``build_serving_engine`` with a 1,024-long slot cache: a 700-token
+    request's prefill goes through the banded forward and its tokens are the
+    argmax of ``apply``; the prefill span says which grid the window layers took
+    and how much of the causal grid it computes; a short request's bucket attends
+    densely and says nothing of a grid."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    spec = _spec({**program, "max_seq_len": LONG})
+    spec["serving"]["max_seq_len"] = LONG
+    srv = build_serving_engine(spec)
+    cfg = srv.engine.cfg
+    prompts = [_tokens(cfg, (700,), 41), _tokens(cfg, (30,), 42)]
+    mark = tracing.spans(0.0)[-1].t1 if tracing.spans(0.0) else 0.0
+    results = srv.serve([Request(uid=i, prompt=p, max_new_tokens=6) for i, p in enumerate(prompts)])
+    for i, p in enumerate(prompts):
+        res = results[i]
+        got = np.asarray(res.tokens)
+        assert res.status == "ok" and len(got) == 6
+        logits = np.asarray(tfm.apply(cfg, srv.engine.params, np.concatenate([p, got])[None]))[0]
+        want = logits[len(p) - 1:len(p) + 5]
+        assert (want.max(axis=-1) - want[np.arange(6), got]).max() <= 1e-4
+    by_bucket = {sp.attrs["bucket"]: sp.attrs for sp in tracing.spans(0.0)
+                 if sp.name == "prefill" and sp.t0 >= mark}
+    long, short = by_bucket[LONG], by_bucket[32]
+    assert (long["attn"], long["window_grid"]) == ("flash+window", "band")
+    blocks_pct = fa.window_grid(LONG, WINDOW, cfg.num_heads, cfg.head_dim, cfg.head_dim,
+                                jnp.dtype(cfg.dtype).itemsize)[1]
+    assert long["window_blocks_pct"] == round(blocks_pct, 2) < 100
+    assert short["attn"] == "dense+window" and "window_grid" not in short
+
+
 # -- the counts at the published widths ------------------------------------------------------------
 
 
