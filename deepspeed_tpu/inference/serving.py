@@ -350,10 +350,18 @@ class SlotWorker:
             out_shardings=self._cache_shardings,
         )()
         # what ONE decode step must read and write of per-sequence state, a live
-        # row: every layer's leaves, once each way (0 for a model without)
-        self.state_bytes_per_slot = self.cfg.num_layers * tfm.cache_state_bytes(self.cfg)
+        # row: the leaves of every layer that keeps any (the layout's own count:
+        # every layer of a model with a mixer, the conv layers of one with layers
+        # by operator), once each way (0 for a model without)
+        kept = tfm.cache_layers(self.cfg)
+        self.state_layers = kept[tfm.STATE]
+        self.state_bytes_per_slot = self.state_layers * tfm.cache_state_bytes(self.cfg)
         # how many layers keep a ring of ``local_attn_window`` positions a slot (0: none)
-        self.window_layers = len(self.cfg.window_layers)
+        self.window_layers = kept[tfm.RING]
+        # what the programs ran of each operator (a model with ``layer_operators`` only)
+        self.operator_attrs = ({"conv_layers": len(self.cfg.conv_layers),
+                                "attn_layers": self.cfg.num_layers - len(self.cfg.conv_layers)}
+                               if self.cfg.conv_layers else {})
         # where the programs read a routed layer's expert banks from ("in_place" /
         # "sliced"; None for a model without dropless routing): the rule they trace by
         self.expert_bank = tfm.expert_bank_form(self.cfg, self.params.get("moe"), self.mesh)
@@ -713,14 +721,18 @@ class SlotWorker:
         self.telemetry.gauge("serving/experts_touched").set(summary["experts_touched"])
 
     def _state_attrs(self, n_active: int) -> dict:
-        """What a decode span says of a state-space mixer's state (nothing for a
-        model without): ``state_rows``, the active rows whose state the step
+        """What a decode span says of the per-sequence state (nothing
+        for a model without): ``state_rows``, the active rows whose state the step
         advanced, and ``state_bytes``, the per-sequence bytes it had to read and
-        write for them (2 x rows x layers x (state + convolution tail))."""
+        write for them (2 x rows x the layers that keep state x a layer's leaves:
+        a mixer's state + convolution tail, a short convolution's tail); and for a
+        model with layers by operator how many of each the program ran
+        (``conv_layers``, ``attn_layers``)."""
         if not self.state_bytes_per_slot:
             return {}
         return {"state_rows": n_active,
-                "state_bytes": 2 * n_active * self.state_bytes_per_slot}
+                "state_bytes": 2 * n_active * self.state_bytes_per_slot,
+                **self.operator_attrs}
 
     def _ring_attrs(self, live_positions) -> dict:
         """What a span says of the window layers' rings (nothing for a model
@@ -738,13 +750,18 @@ class SlotWorker:
         """What a prefill or chunk span says of the block its program was traced
         with. A state-space mixer's scan: the chunks it ran (those of the
         bucket's padding among them) and ``state_rows``, the live rows that moved
-        the state. A routed model's ``expert_bank``: where the program reads
+        the state. A model with layers by operator: ``conv_layers`` / ``attn_layers``
+        it ran, ``state_rows`` and ``state_bytes``, the tail it wrote. A routed
+        model's ``expert_bank``: where the program reads
         layer l of the three banks from (``expert_bank_form``). Nothing for a
         model with neither."""
         attrs = {"expert_bank": self.expert_bank} if self.expert_bank else {}
         if self.state_bytes_per_slot:
-            attrs.update(scan_chunks=-(-int(rows) // self.cfg.ssm_chunk_size),
-                         state_rows=int(live))
+            attrs.update(state_rows=int(live), **self.operator_attrs)
+            if self.cfg.ssm_state_size:
+                attrs.update(scan_chunks=-(-int(rows) // self.cfg.ssm_chunk_size))
+            else:  # the tail written for the slot: one layer's leaves a layer that keeps them
+                attrs.update(state_bytes=self.state_bytes_per_slot)
         return attrs
 
     def _sampler_rows(self, sp, temperature, top_k, top_p):
@@ -992,8 +1009,9 @@ class SlotWorker:
     def _refuse_state(self, what: str) -> None:
         if self.state_bytes_per_slot:
             raise NotImplementedError(
-                f"{what} with a state-space mixer's recurrent state: the wire form carries "
-                "windows of per-token K/V and no per-sequence state")
+                f"{what} with per-sequence state in the cache (a state-space mixer's recurrent "
+                "state, a short convolution's tail): the wire form carries windows of per-token "
+                "K/V and no per-sequence state")
         if self.window_layers:
             raise NotImplementedError(
                 f"{what} with window layers (local_attn_layers): the wire form carries windows "
@@ -1193,12 +1211,14 @@ class ServingEngine:
                 f"serving role {self.role!r} with latent attention (kv_lora_rank > 0): the "
                 "prefill -> decode handoff's wire form (inference/rpc.py: raw or int8 "
                 "windows of per-head K/V) has not carried the latent cache tree yet")
-        recurrent = engine.cfg.ssm_state_size > 0
+        # the cache layout's own word: a model that keeps per-sequence state (a
+        # state-space mixer's, a short convolution's tail), whatever field says so
+        recurrent = tfm.STATE in tfm.cache_layout(engine.cfg)
         if self.role != "both" and recurrent:
             raise NotImplementedError(
-                f"serving role {self.role!r} with a state-space mixer (ssm_state_size > 0): the "
-                "prefill -> decode handoff (kv_export / kv_import) carries windows of per-token "
-                "K/V and no per-sequence recurrent state")
+                f"serving role {self.role!r} with per-sequence state in the cache (a state-space "
+                "mixer's, a short convolution's tail): the prefill -> decode handoff (kv_export / "
+                "kv_import) carries windows of per-token K/V and no per-sequence state")
         windowed = bool(engine.cfg.window_layers)
         if self.role != "both" and windowed:
             raise NotImplementedError(
@@ -1281,8 +1301,9 @@ class ServingEngine:
             for what, on in (("prefix_cache", pc.enabled), ("speculation", sp.enabled)):
                 if on:
                     raise NotImplementedError(
-                        f"{what} with a state-space mixer (ssm_state_size > 0) has no code: it "
-                        "would run on a stale recurrent state; serve with it off")
+                        f"{what} with per-sequence state in the cache (a state-space mixer's, a "
+                        "short convolution's tail) has no code: it would run on a stale state; "
+                        "serve with it off")
         if windowed:
             # each moves the cache by POSITION, and a ring was overwritten past its
             # window: a prefix's ring is not the ring after the prefix unless it is
@@ -1475,8 +1496,10 @@ class ServingEngine:
             f"{self.worker.hbm_pools()['slot_kv_cache'] / 1e6:.1f} MB at "
             f"{tfm.cache_bytes_per_token(self.cfg)} B a token a layer "
             f"({tfm.cache_step_form(self.cfg)})"
-            + (f", recurrent state {self.worker.hbm_pools()['slot_state'] / 1e6:.1f} MB at "
-               f"{self.worker.state_bytes_per_slot} B a slot" if recurrent else "")
+            + (f" in {tfm.cache_layers(self.cfg)['tokens']} layers, per-sequence state "
+               f"{self.worker.hbm_pools()['slot_state'] / 1e6:.1f} MB at "
+               f"{self.worker.state_bytes_per_slot} B a slot over {self.worker.state_layers} "
+               "layers" if recurrent else "")
             + (f", rings {self.worker.hbm_pools()['slot_kv_ring'] / 1e6:.1f} MB at "
                f"{tfm.cache_ring_bytes(self.cfg)} B a slot over {self.worker.window_layers} "
                f"window layers of {self.cfg.local_attn_window}" if windowed else "")

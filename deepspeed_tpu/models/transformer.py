@@ -116,6 +116,16 @@ class TransformerConfig:
     local_attn_window: int = 0
     local_attn_layers: Optional[tuple] = None
     rotary_layers: Optional[tuple] = None
+    # A layer's OPERATOR, the third part of its kind: per layer "attn" (the
+    # attention sublayer above) or "conv", a gated short convolution in its place
+    # (LFM2's; ``_short_conv`` has the equations): no q / k / v, no rotary, no
+    # attention, a depthwise causal filter of ``conv_kernel`` taps whose last
+    # ``conv_kernel - 1`` inputs are ALL a sequence keeps between steps. None:
+    # every layer attends. The two operators' leaves differ, so the parameters
+    # lie in stacks BY OPERATOR (``init``) and the cache keeps K/V for the
+    # attention layers alone and the filter's tail for the others (``cache_layout``)
+    layer_operators: Optional[tuple] = None
+    conv_kernel: int = 0
     layernorm_epsilon: float = 1e-5
     tie_embeddings: bool = True
     use_bias: bool = True
@@ -250,20 +260,29 @@ class TransformerConfig:
         for name in ("local_attn_layers", "rotary_layers", "moe_experts_held"):
             if isinstance(getattr(self, name), list):  # a JSON file's list: hashable
                 object.__setattr__(self, name, tuple(int(v) for v in getattr(self, name)))
+        if isinstance(self.layer_operators, list):
+            object.__setattr__(self, "layer_operators", tuple(self.layer_operators))
         _refuse_uncoded(self)
 
     @property
     def layer_kinds(self):
-        """Per layer (window, rotary): the positions a query sees behind it (0:
-        the whole context) and whether its q and k are rotated. None where every
-        layer is of one kind and the loop need not tell them apart."""
-        if self.local_attn_layers is None and self.rotary_layers is None:
+        """Per layer (window, rotary, operator): the positions a query sees behind
+        it (0: the whole context), whether its q and k are rotated, and what stands
+        in the attention sublayer's place (``OPERATORS``: "attn" itself, or "conv").
+        None where every layer is of one kind and the loop need not tell them apart."""
+        if (self.local_attn_layers is None and self.rotary_layers is None
+                and self.layer_operators is None):
             return None
         L = self.num_layers
         windows = [self.local_attn_window if on else 0
                    for on in (self.local_attn_layers or (0,) * L)]
         rotary = [bool(on) for on in (self.rotary_layers or (1,) * L)]
-        return tuple(zip(windows, rotary))
+        return tuple(zip(windows, rotary, self.layer_operators or ("attn",) * L))
+
+    @property
+    def conv_layers(self) -> tuple:
+        """The layers (model indices) whose operator is the gated short convolution."""
+        return tuple(l for l, op in enumerate(self.layer_operators or ()) if op == "conv")
 
     @property
     def window_layers(self) -> tuple:
@@ -484,6 +503,7 @@ def _refuse_uncoded_kinds_and_share(cfg: "TransformerConfig") -> None:
             raise ValueError("moe_experts_held is (first, count) inside the num_experts "
                              f"({cfg.num_experts}) the router chooses among, got "
                              f"{cfg.moe_experts_held!r}")
+    _refuse_uncoded_operators(cfg)
     if cfg.mtp_layers not in (0, 1):
         raise NotImplementedError(
             f"mtp_layers={cfg.mtp_layers}: one multi-token-prediction module has code (a chain "
@@ -501,8 +521,59 @@ def _refuse_uncoded_kinds_and_share(cfg: "TransformerConfig") -> None:
                 raise NotImplementedError(f"mtp_layers with {what} has no code")
 
 
+# What may stand in a layer's attention sublayer (``layer_operators``); also the keys
+# of the operators' stacks under ``params["layers"]``.
+OPERATORS = ("attn", "conv")
+
+
+def _refuse_uncoded_operators(cfg: "TransformerConfig") -> None:
+    """``_refuse_uncoded`` for ``layer_operators``: what the stacks by operator,
+    the by-kind loop and the short convolution have no code for, by name."""
+    ops, L = cfg.layer_operators, cfg.num_layers
+    if ops is None:
+        if cfg.conv_kernel:
+            raise ValueError("conv_kernel without layer_operators: no layer to filter")
+        return
+    if len(ops) != L or any(op not in OPERATORS for op in ops):
+        raise ValueError(f"layer_operators is one of {OPERATORS} a layer ({L} layers), got {ops!r}")
+    if len(set(ops)) < 2:
+        raise ValueError("layer_operators states layers of BOTH operators (every layer "
+                         "attending is layer_operators=None; a model with no attention layer "
+                         f"has no per-token cache to size), got {ops!r}")
+    if cfg.conv_kernel < 2:
+        raise ValueError(f"layer_operators with a 'conv' layer states conv_kernel >= 2 taps "
+                         f"(got {cfg.conv_kernel})")
+    no_code = {
+        "latent attention (kv_lora_rank > 0)": cfg.kv_lora_rank > 0,
+        "the state-space mixer (ssm_state_size > 0: a mixer beside every layer's attention)":
+            cfg.ssm_state_size > 0,
+        "pos_emb='alibi' (its bias is every layer's)": cfg.pos_emb == "alibi",
+        "window layers (local_attn_layers)": bool(cfg.window_layers),
+        "use_bias (neither operator's leaves are drawn with one)": cfg.use_bias,
+        "norm_style='post'": cfg.norm_style == "post",
+        "parallel_residual": cfg.parallel_residual,
+        "causal=False (the filter runs one way)": not cfg.causal,
+        "weight_bits": cfg.weight_bits != 0, "act_quant_bits": cfg.act_quant_bits != 0,
+        "param_offload (the operators' stacks are not streamed)": cfg.param_offload,
+        "mtp_layers": cfg.mtp_layers != 0,
+        "remat (the by-kind loop wraps no body)": cfg.remat,
+        "dropout or progressive layer drop (the by-kind loop hands no per-layer gates)":
+            bool(cfg.hidden_dropout or cfg.attn_dropout or cfg.pld_enabled),
+        "GShard routing (moe_every > 0 without moe_routing='dropless')":
+            cfg.moe_every > 0 and cfg.moe_routing != "dropless",
+        f"attn_impl={cfg.attn_impl!r}": cfg.attn_impl != "xla",
+    }
+    for what, refused in no_code.items():
+        if refused:
+            raise NotImplementedError(f"layer_operators (a 'conv' layer) with {what} has no code")
+
+
 def refuse_in_pipeline(cfg: "TransformerConfig") -> None:
     """What the pipeline schedules (pipe/) have not carried yet, by name."""
+    if cfg.layer_operators is not None:
+        raise NotImplementedError(
+            "layer_operators under a pipeline schedule has no code: the stages slice ONE "
+            "stack of identical layers, and these lie in stacks by operator")
     if cfg.rotary_layers is not None or cfg.mtp_layers:
         raise NotImplementedError(
             "rotary_layers / mtp_layers under a pipeline schedule have no code: a stage sees "
@@ -547,6 +618,7 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
     dropless = _dropless(cfg)
     mult = cfg.multiplier
     attn_in = mult("attention_in_multiplier")
+    La = L - len(cfg.conv_layers)  # the layers that attend: as long as the attention stacks are
 
     def stack(key, shape, fan_in, n=L, scale=1.0):
         ks = jax.random.split(key, n)
@@ -555,9 +627,9 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
     layers = {
         "ln1_scale": jnp.ones((L, d)),
         "ln2_scale": jnp.ones((L, d)),
-        "wq": stack(keys[0], (d, H, Dh), d, scale=attn_in),
+        "wq": stack(keys[0], (d, H, Dh), d, La, scale=attn_in),
         # a head width that is not hidden_size // num_heads: the fan-in is the heads'
-        "wo": stack(keys[3], (H, Dv, d), d if cfg.kv_lora_rank else H * Dv,
+        "wo": stack(keys[3], (H, Dv, d), d if cfg.kv_lora_rank else H * Dv, La,
                     scale=mult("attention_out_multiplier")),
     }
     if cfg.kv_lora_rank:  # the latent and the shared rotary key; then every head's k_nope | v
@@ -567,15 +639,15 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
                        "wkv_b": stack(keys[2], (R, H, Dh - Dr + Dv), R)})
     else:
         layers.update({
-            "wk": stack(keys[1], (d, Hkv, Dh), d, scale=attn_in * mult("key_multiplier")),
-            "wv": stack(keys[2], (d, Hkv, Dh), d, scale=attn_in)})
+            "wk": stack(keys[1], (d, Hkv, Dh), d, La, scale=attn_in * mult("key_multiplier")),
+            "wv": stack(keys[2], (d, Hkv, Dh), d, La, scale=attn_in)})
     if cfg.norm_kind != "rms":
         layers.update({"ln1_bias": jnp.zeros((L, d)), "ln2_bias": jnp.zeros((L, d))})
     if cfg.qk_norm == "head":  # one [head_dim] scale, every head's
-        layers.update({"q_norm_scale": jnp.ones((L, Dh)), "k_norm_scale": jnp.ones((L, Dh))})
+        layers.update({"q_norm_scale": jnp.ones((La, Dh)), "k_norm_scale": jnp.ones((La, Dh))})
     elif cfg.qk_norm:
-        layers.update({"q_norm_scale": jnp.ones((L, H, Dh)),
-                       "k_norm_scale": jnp.ones((L, Hkv, Dh))})
+        layers.update({"q_norm_scale": jnp.ones((La, H, Dh)),
+                       "k_norm_scale": jnp.ones((La, Hkv, Dh))})
     if not dropless:  # no layer has a dense feed-forward there
         gate_m, down_m = mult("mlp_multipliers")
         layers.update({"wi": stack(keys[4], (d, f), d),
@@ -595,6 +667,8 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
         )
         if not dropless:
             layers.update({"bi": jnp.zeros((L, f)), "bo_mlp": jnp.zeros((L, d))})
+    if cfg.layer_operators is not None:
+        layers = _stacks_by_operator(layers, _init_conv(cfg, more[7], stack))
     stated = cfg.multipliers or {}
     params = {
         # with a stated embedding multiplier the residual stream enters at the size of
@@ -663,6 +737,36 @@ def _init_mtp(cfg: TransformerConfig, key) -> dict:
     if "moe" in block:
         out["moe"] = block["moe"]
     return out
+
+
+# The leaves of a layer that belong to its attention sublayer (no biases: a model
+# with ``layer_operators`` has none): those that lie in the "attn" stack there.
+_ATTENTION_LEAVES = ("wq", "wk", "wv", "wo", "q_norm_scale", "k_norm_scale")
+
+
+def _stacks_by_operator(layers: dict, conv: dict) -> dict:
+    """``init``'s (or ``logical_axes``') one dict of a layer's leaves -> the same
+    with the STACKS BY OPERATOR: the norms and a dense feed-forward, which every
+    layer has, stay [L, ...] at the top; the attention sublayer's leaves go under
+    ``"attn"`` ([attention layers, ...]) and the short convolution's under
+    ``"conv"`` ([conv layers, ...]), each stack as long as its layers are many."""
+    shared = {k: v for k, v in layers.items() if k not in _ATTENTION_LEAVES}
+    return {**shared, "attn": {k: layers[k] for k in _ATTENTION_LEAVES if k in layers},
+            "conv": conv}
+
+
+def _init_conv(cfg: TransformerConfig, key, stack) -> dict:
+    """The short convolution's leaves, [conv layers]-stacked: ``conv_in`` maps the
+    hidden state to B | C | z (three chunks of ``hidden_size``), ``conv_w`` the
+    filter's taps [taps, channels] (uniform within 1 / sqrt(taps), as a
+    depthwise ``Conv1d`` draws them; tap j multiplies u_{t - taps + 1 + j}),
+    ``conv_out`` the output projection."""
+    d, K, n = cfg.hidden_size, cfg.conv_kernel, len(cfg.conv_layers)
+    k_in, k_w, k_out = jax.random.split(key, 3)
+    bound = 1.0 / math.sqrt(K)
+    return {"conv_in": stack(k_in, (d, 3 * d), d, n),
+            "conv_w": jax.random.uniform(k_w, (n, K, d), minval=-bound, maxval=bound),
+            "conv_out": stack(k_out, (d, d), d, n)}
 
 
 def _init_mixer(cfg: TransformerConfig, keys, stack) -> dict:
@@ -735,6 +839,10 @@ def logical_axes(cfg: TransformerConfig) -> Params:
         )
         if not dropless:
             layers.update({"bi": ("layers", "mlp"), "bo_mlp": ("layers", "embed")})
+    if cfg.layer_operators is not None:  # B | C | z lie side by side in one axis: not split
+        layers = _stacks_by_operator(layers, {
+            "conv_in": ("layers", "embed", None), "conv_w": ("layers", None, None),
+            "conv_out": ("layers", None, "embed")})
     axes = {
         "wte": ("vocab", "embed"),
         "layers": layers,
@@ -788,12 +896,14 @@ def logical_axes(cfg: TransformerConfig) -> Params:
 # and the GShard path's ``moe_dispatch_combine`` likewise). Of the state-space
 # mixer the two projections and the convolution (its tail is cached in the
 # compute dtype) are cast; ``ssm_dt_bias``, ``ssm_a_log``, ``ssm_d`` and the gated
-# norm's scale enter float32 arithmetic and are read in float32.
+# norm's scale enter float32 arithmetic and are read in float32. The gated short
+# convolution's two projections and its taps are cast likewise.
 _READ_IN_COMPUTE_DTYPE = frozenset({
     "wte", "wpe", "lm_head", "eh_proj",
     "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo", "wkv_a", "wkv_b",
     "wi", "wo_mlp", "bi", "bo_mlp", "wg",
     "ssm_in", "ssm_out", "ssm_conv", "ssm_conv_bias",
+    "conv_in", "conv_out", "conv_w",
 })
 
 
@@ -1314,6 +1424,78 @@ def _ssm_step(x, dt, A, Bm, Cm, S0):
     return jnp.sum(S * Cg, axis=-1).reshape(B_, 1, H, P), S.reshape(B_, H, P, N)
 
 
+def _causal_filter(tail, u, taps):
+    """The depthwise causal convolution the state-space mixer and the gated short
+    convolution share: u [B, T, C] behind ``tail`` [B, K - 1, C] (the K - 1 rows
+    that came before it: zeros where the sequence starts), ``taps`` [K, C] (the
+    leaf: rounded to u's dtype, multiplied in float32) -> (rows [B, K - 1 + T, C],
+    the tail and u as they lie, and out [B, T, C] float32, out_t = sum_j taps[j] *
+    rows_{t + j}: tap j multiplies u_{t - K + 1 + j})."""
+    K, T = taps.shape[0], u.shape[1]
+    rows = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+    taps = taps.astype(u.dtype).astype(jnp.float32)
+    return rows, sum(rows[:, j:j + T].astype(jnp.float32) * taps[j] for j in range(K))
+
+
+def _filter_tail(rows, K: int, live):
+    """What a sequence keeps of ``_causal_filter``'s ``rows`` [B, K - 1 + T, C]: the
+    K - 1 rows behind its last LIVE one (``live`` [B, T] bool, leading each row of
+    the batch; None: all T), rows n_live ... n_live + K - 2: with fewer than K - 1
+    live rows, what is left of the old tail in front of them, and with none the old
+    tail itself, so a row that only rides along keeps what it had."""
+    B_, T = rows.shape[0], rows.shape[1] - (K - 1)
+    n_live = jnp.full((B_,), T, jnp.int32) if live is None else jnp.sum(live, axis=1)
+    kept = n_live.astype(jnp.int32)[:, None, None] + jnp.arange(K - 1)[None, :, None]
+    return jnp.take_along_axis(rows, kept, axis=1)  # rows n_live ... n_live + K - 2
+
+
+def _short_conv(cfg: TransformerConfig, lp, h, state, l, live):
+    """The gated short convolution of one layer (LFM2's operator, in the attention
+    sublayer's place) on the normed h [B, T, d] -> (its output [B, T, d], state):
+
+        [B | C | z] = h W_in                 three chunks of d, in that order
+        u = B * z
+        c_t = sum_j w[j] * u_{t-K+1+j}       depthwise, causal, no bias, no activation
+        out = (C * c) W_out
+
+    ``state`` is None (``apply``: the sequence starts from nothing and keeps
+    nothing) or the cache tree, whose ``STATE`` leaf ``conv`` [conv layers, B, K - 1,
+    d] holds per layer and row the last K - 1 rows of u, ALL a sequence keeps of
+    such a layer: layer ``l`` (the model's index; the leaf is indexed among the conv
+    layers) is read and written back in place. ``live``: ``_filter_tail``'s."""
+    d, K = cfg.hidden_size, cfg.conv_kernel
+    with jax.named_scope("conv"):
+        proj = jnp.einsum("bsd,dz->bsz", _act_q(cfg, h), lp["conv_in"].astype(h.dtype))
+        gate_in, gate_out, z = proj[..., :d], proj[..., d:2 * d], proj[..., 2 * d:]
+        u = gate_in * z
+        if state is None:
+            tail = jnp.zeros((h.shape[0], K - 1, d), h.dtype)
+        else:
+            at = jnp.asarray(_index_in_kind(cfg), jnp.int32)[l]
+            tail = lax.dynamic_index_in_dim(state[STATE]["conv"], at, keepdims=False)
+        rows, c = _causal_filter(tail, u, lp["conv_w"])
+        out = jnp.einsum("bsd,de->bse", gate_out * c.astype(h.dtype),
+                         lp["conv_out"].astype(h.dtype))
+        if state is None:
+            return out, state
+        held = state[STATE]["conv"]
+        held = lax.dynamic_update_slice(held, _filter_tail(rows, K, live)[None].astype(held.dtype),
+                                        (at, 0, 0, 0))
+        return out, {**state, STATE: {**state[STATE], "conv": held}}
+
+
+def _index_in_kind(cfg: TransformerConfig) -> tuple:
+    """Per layer, its index among the layers that keep the same kind of cache
+    leaves as it does (whole-context K/V, a window layer's ring, a conv layer's
+    state): where it lies in those leaves' stacks."""
+    seen, out = {}, []
+    for window, _, operator in cfg.layer_kinds:
+        kind = (operator, bool(window))
+        out.append(seen.get(kind, 0))
+        seen[kind] = out[-1] + 1
+    return tuple(out)
+
+
 def _ssm_mixer(cfg: TransformerConfig, lp, h, state, l, live):
     """The Mamba-2 mixer of one layer on the normed h [B, T, d] -> (its output
     [B, T, d], state). As published (Falcon-H1; every multiplier is
@@ -1353,9 +1535,7 @@ def _ssm_mixer(cfg: TransformerConfig, lp, h, state, l, live):
         else:
             tail, S0 = (lax.dynamic_index_in_dim(state[STATE][name], l, keepdims=False)
                         for name in ("conv", "ssm"))
-        rows = jnp.concatenate([tail.astype(h.dtype), xBC], axis=1)  # [B, K - 1 + T, C]
-        taps = lp["ssm_conv"].astype(h.dtype).astype(f32)
-        conv = sum(rows[:, j:j + T].astype(f32) * taps[j] for j in range(K))
+        rows, conv = _causal_filter(tail, xBC, lp["ssm_conv"])
         xBC = jax.nn.silu(conv + lp["ssm_conv_bias"].astype(h.dtype).astype(f32)).astype(h.dtype)
         xs = xBC[..., :inner].reshape(B_, T, H, P)
         Bm = xBC[..., inner:inner + gn].reshape(B_, T, G, N)
@@ -1377,9 +1557,7 @@ def _ssm_mixer(cfg: TransformerConfig, lp, h, state, l, live):
                      cfg.multiplier("ssm_out_multiplier"))
         if state is None:
             return out, state
-        n_live = jnp.full((B_,), T, jnp.int32) if live is None else jnp.sum(live, axis=1)
-        kept = n_live.astype(jnp.int32)[:, None, None] + jnp.arange(K - 1)[None, :, None]
-        tail = jnp.take_along_axis(rows, kept, axis=1)  # rows n_live ... n_live + K - 2
+        tail = _filter_tail(rows, K, live)
         held = state[STATE]
         held = {"conv": lax.dynamic_update_slice(held["conv"], tail[None].astype(
                     held["conv"].dtype), (l, 0, 0, 0)),
@@ -1493,11 +1671,15 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
       progressive layer drop, one coin for BOTH residual branches);
     - ``live`` [B, T] bool or None: the rows that are a sequence's own, for a
       model whose state-space mixer must not move on the others (``_ssm_mixer``);
-    - ``kind`` (window, rotary) or None: this layer's entry of ``cfg.layer_kinds``
-      where the loop knows it statically (``_layer_loop(by_kind=True)``: the cache
-      path, whose window layers keep another cache than its whole ones); it is
-      handed on to ``attend``. None: a loop that scans layers of every kind at
-      once (``apply``) reads the layer's flags at the traced ``l``.
+    - ``kind`` (window, rotary, operator) or None: this layer's entry of
+      ``cfg.layer_kinds`` where the loop knows it statically
+      (``_layer_loop(by_kind=True)``: the cache path, whose window layers keep
+      another cache than its whole ones, and every path of a model with
+      ``layer_operators``, whose conv layers have other leaves); it is handed on to
+      ``attend``. None: a loop that scans layers of every kind at once (``apply``)
+      reads the layer's flags at the traced ``l``. A layer whose operator is
+      "conv" runs ``_short_conv`` in the attention sublayer's place: no q / k / v,
+      no rotary, no ``attend``; its state rides in ``state`` as a mixer's does.
 
     Norm kind and placement and the residual form are what ``cfg`` says, for a
     dense layer and a routed one alike (``norm_style="post"`` is the BERT
@@ -1524,9 +1706,12 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
     rotary = True
     if cfg.rotary_layers is not None:
         rotary = kind[1] if kind is not None else jnp.asarray(cfg.rotary_layers, bool)[l]
-    q, k, v = _qkv_proj(cfg, lp, h, positions, rotary)
-    attn, state = attend(q, k, v, state, l, lp, **({} if kind is None else {"kind": kind}))
-    attn_out = branch(_attn_out_proj(cfg, lp, attn), cfg.attn_dropout, k_attn)
+    if kind is not None and kind[2] == "conv":
+        attn_out, state = _short_conv(cfg, lp, h, state, l, live)
+    else:
+        q, k, v = _qkv_proj(cfg, lp, h, positions, rotary)
+        attn, state = attend(q, k, v, state, l, lp, **({} if kind is None else {"kind": kind}))
+        attn_out = branch(_attn_out_proj(cfg, lp, attn), cfg.attn_dropout, k_attn)
     if cfg.ssm_state_size:
         mixed, state = _ssm_mixer(cfg, lp, h, state, l, live)
         attn_out = attn_out + mixed
@@ -1556,7 +1741,9 @@ def _stateless_attention(cfg: TransformerConfig, S: int):
         return lambda q, k, v, state, l, lp: (
             attn_fn(q, *_latent_expand(cfg, lp, k, v), bias), state)
     if cfg.local_attn_window <= 0 or cfg.local_attn_layers is None:
-        return lambda q, k, v, state, l, lp: (attn_fn(q, k, v, bias), state)
+        # ``kind``: the by-kind loop's (a model with ``layer_operators``); its
+        # attention layers are all of the whole-context kind
+        return lambda q, k, v, state, l, lp, kind=None: (attn_fn(q, k, v, bias), state)
     is_local = jnp.asarray(cfg.local_attn_layers, bool)  # per layer
     local_bias = None if fused else _local_attn_bias(cfg, S)
 
@@ -1591,7 +1778,7 @@ def _remat_wrapper(cfg: TransformerConfig):
 
 def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, attend,
                 per_layer=None, wrap=None, decode: bool = False, lead=None, live=None,
-                banks_in_place: bool = False, by_kind: bool = False):
+                banks_in_place: bool = False, by_kind: bool = False, forward_only: bool = False):
     """THE layer loop: ``_block`` over the stacked ``layers`` [L, ...] (the
     whole model's, or one pipeline stage's slice), ``moe`` the routed layers'
     stacks or None -> (x, state, summed aux loss, experts chosen or None).
@@ -1618,11 +1805,12 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
     and the pipeline stages leave it off. ``by_kind`` (the cache path's, for a
     model whose layers are of several kinds): the loop hands every block its
     layer's kind STATICALLY, scanning over whole periods of the kinds' pattern
-    (``_layer_loop_by_kind``)."""
+    (``_layer_loop_by_kind``). ``forward_only`` (the cache path's word that no
+    backward pass follows): that loop reads the stacks by operator in place."""
     if by_kind and cfg.layer_kinds is not None and len(set(cfg.layer_kinds)) > 1:
         return _layer_loop_by_kind(cfg, layers, moe, x, state, positions=positions,
                                    attend=attend, decode=decode, lead=lead, live=live,
-                                   banks_in_place=banks_in_place)
+                                   banks_in_place=banks_in_place, forward_only=forward_only)
     layers_xs, load_layer = _make_stack_loader(cfg, layers)
     routed = cfg.moe_every > 0 and moe is not None
     moe_xs, load_moe = _make_stack_loader(cfg, moe) if routed else (None, None)
@@ -1700,17 +1888,36 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
 
 
 def _layer_loop_by_kind(cfg: TransformerConfig, layers, moe, x, state, *, positions, attend,
-                        decode: bool, lead, live, banks_in_place: bool):
-    """``_layer_loop`` for a forward-only caller whose blocks must know their
-    layer's kind as a Python value (a window layer's cache is another array than
-    a whole layer's). What a layer is — its kind, and whether its feed-forward
-    is a leading dense one, a dense one or the routed block — is periodic in the
-    layers after the ``lead``: the loop scans over the whole periods with the
-    period's layers inline (compile time flat in depth; S S G S is four blocks a
-    body), and runs the leading layers and the ``tail`` that fills no period
-    inline, one by one. Same blocks, same stacks, same order as ``_layer_loop``."""
+                        decode: bool, lead, live, banks_in_place: bool, forward_only: bool):
+    """``_layer_loop`` for a caller whose blocks must know their layer's kind as a
+    Python value (a window layer's cache is another array than a whole layer's; a
+    conv layer has other LEAVES than an attention layer). What a layer is — its
+    kind, and whether its feed-forward is a leading dense one, a dense one or the
+    routed block — is periodic in the layers after the ``lead``: the loop scans
+    over the whole periods with the period's layers inline (compile time flat in
+    depth; S S G S, or A C C C, is four blocks a body), and runs the leading layers
+    and the ``tail`` that fills no period inline, one by one. Same blocks, same
+    stacks, same order as ``_layer_loop``.
+
+    Stacks by operator (``cfg.layer_operators``): ``layers`` then holds, beside the
+    [L]-stacked leaves every layer has, one sub-dict an operator (``OPERATORS``)
+    whose stacks are as long as that operator's layers are many; a block gets its
+    layer's slice of the first and ITS operator's slice of the second, each
+    indexed as its own stack is (in a scanned period: the period's share of each
+    stack, in order). Forward and backward alike: ``apply`` takes this loop for
+    such a model. Where a backward pass follows, the scan takes the period's share
+    of an operator's stack as its xs (the slice's cotangent is one period's); a
+    ``forward_only`` caller's scan carries the layers' INDICES in the stack
+    instead and the body reads the held stack at them (as ``banks_in_place`` does
+    the expert banks): the periods' layers need not begin the stack (a leading
+    conv layer lies before them), and a slice that does not is a copy of all of
+    them in every call (192 MB a decode step at LFM2's widths)."""
     kinds, L = cfg.layer_kinds, cfg.num_layers
-    layers_xs, load_layer = _make_stack_loader(cfg, layers)
+    by_op = {op: layers[op] for op in OPERATORS if op in layers}  # none: ONE stack of layers
+    layers_xs, load_layer = _make_stack_loader(
+        cfg, {k: v for k, v in layers.items() if k not in by_op})
+    op_of = [kind[2] for kind in kinds]
+    in_op = [op_of[:i].count(op_of[i]) for i in range(L)]  # a layer's index in its operator's stack
     routed_model = cfg.moe_every > 0 and moe is not None
     moe_xs, load_moe = _make_stack_loader(cfg, moe) if routed_model else (None, None)
     lead_xs, load_lead = _make_stack_loader(cfg, lead) if lead is not None else (None, None)
@@ -1756,7 +1963,10 @@ def _layer_loop_by_kind(cfg: TransformerConfig, layers, moe, x, state, *, positi
         feed = what[i][1]
         extra = (at(lead_xs, i) if feed == "lead" else
                  at(moe_xs, routed_of(i)) if feed == "routed" else None)
-        carry, y = one(carry, i, at(layers_xs, i), jnp.int32(i), extra)
+        lp = at(layers_xs, i)
+        if by_op:
+            lp = {**lp, **at(by_op[op_of[i]], in_op[i])}
+        carry, y = one(carry, i, lp, jnp.int32(i), extra)
         if feed == "routed":
             ys.append(jax.tree.map(lambda a: a[None], y))
         return carry
@@ -1770,19 +1980,32 @@ def _layer_loop_by_kind(cfg: TransformerConfig, layers, moe, x, state, *, positi
                                (layers_xs, jnp.arange(L, dtype=jnp.int32)))
         n_r = sum(what[lo + j][1] == "routed" for j in range(period))  # routed layers a period
         moe_g = (jax.tree.map(lambda a: per_group(a[:G * n_r], n_r), moe_xs) if n_r else None)
+        ops_g = {}  # each operator's layers of the periods: from its first there, a period's share
+        for op, stack in by_op.items():
+            n_op, first = op_of[lo:lo + period].count(op), op_of[:lo].count(op)
+            ops_g[op] = (jnp.arange(first, first + G * n_op, dtype=jnp.int32).reshape(G, n_op)
+                         if forward_only else jax.tree.map(
+                             lambda a: per_group(a[first:first + G * n_op], n_op), stack))
 
         def group(carry, xs):
-            (lxs, ls), moe_p = xs
+            (lxs, ls), ops_p, moe_p = xs
             out = []
             for j in range(period):
                 routed = what[lo + j][1] == "routed"
                 extra = at(moe_p, len(out)) if routed else None
-                carry, y = one(carry, lo + j, at(lxs, j), ls[j], extra)
+                lp, op = at(lxs, j), op_of[lo + j]
+                if by_op:  # the period's j-th layer: its operator's next within the period
+                    mine = at(ops_p[op], op_of[lo:lo + j].count(op))
+                    lp = {**lp, **(mine if not forward_only else jax.tree.map(
+                        lambda a: lax.dynamic_index_in_dim(a, mine, 0, keepdims=False),
+                        by_op[op]))}
+                carry, y = one(carry, lo + j, lp, ls[j], extra)
                 if routed:
                     out.append(y)
             return carry, (jax.tree.map(lambda *a: jnp.stack(a), *out) if out else None)
 
-        carry, got = lax.scan(group, carry, (grouped, moe_g), unroll=max(1, cfg.scan_unroll))
+        carry, got = lax.scan(group, carry, (grouped, ops_g, moe_g),
+                              unroll=max(1, cfg.scan_unroll))
         if n_r:
             ys.append(jax.tree.map(lambda a: a.reshape((G * n_r,) + a.shape[2:]), got))
     for i in range(L - tail, L):
@@ -1889,7 +2112,9 @@ def apply(
     x, _, aux_total, chosen = _layer_loop(
         cfg, params["layers"], params.get("moe"), x, None, positions=positions,
         attend=_stateless_attention(cfg, S), per_layer=per_layer, wrap=_remat_wrapper(cfg),
-        lead=params.get("dense_ffn"))
+        lead=params.get("dense_ffn"),
+        # layers of two operators have different leaves: no ONE scan over traced flags
+        by_kind=cfg.layer_operators is not None)
     mtp = ()
     if mtp_tokens is not None:
         mtp, module_chosen = mtp_logits(cfg, params, x, mtp_tokens, positions)
@@ -1977,7 +2202,8 @@ def cache_layout(cfg: TransformerConfig) -> dict:
     its few, not the query heads' many). Latent attention: ``k`` is the rotary
     key the heads share, ``v`` the normed latent, which is the absorbed form's
     value and the rest of its key; the 'one head' is every head's. With layers of
-    several kinds (``layer_kinds``) L counts the WHOLE-context layers alone.
+    several kinds (``layer_kinds``) L counts the WHOLE-context ATTENTION layers
+    alone (``cache_layers``): a window layer keeps a ring, a conv layer no token.
 
     RINGS, under ``RING``, ``{leaf: (positions, heads, width)}``: what a WINDOW
     layer keeps, [L_window, B, R, heads, width]: the last R = ``local_attn_window``
@@ -1996,17 +2222,57 @@ def cache_layout(cfg: TransformerConfig) -> dict:
     every step overwrites whole and nothing can slice by position. A
     state-space mixer's float32 state [heads, head width, state size] (it
     accumulates over thousands of steps) and the convolution's tail, the last
-    ``ssm_conv_kernel - 1`` rows of its input. Absent for a model without."""
+    ``ssm_conv_kernel - 1`` rows of its input, in EVERY layer; or, for a model
+    with ``layer_operators``, the gated short convolution's tail alone, the last
+    ``conv_kernel - 1`` rows of its filter's input [conv_kernel - 1, hidden_size],
+    in the CONV layers alone (``cache_layers`` says how many keep it). Absent for
+    a model without."""
     if cfg.kv_lora_rank:
         return {"k": (1, cfg.qk_rope_head_dim), "v": (1, cfg.kv_lora_rank)}
     layout = {"k": (cfg.kv_heads, cfg.head_dim), "v": (cfg.kv_heads, cfg.value_head_dim)}
+    if cache_heads_merged(cfg):
+        layout = {name: (1, heads * width) for name, (heads, width) in layout.items()}
     if cfg.window_layers:
         layout[RING] = {name: (cfg.local_attn_window,) + tail for name, tail in layout.items()}
     if cfg.ssm_state_size:
         layout[STATE] = {
             "ssm": ((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size), jnp.float32),
             "conv": ((cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim), None)}
+    elif cfg.conv_layers:
+        layout[STATE] = {"conv": ((cfg.conv_kernel - 1, cfg.hidden_size), None)}
     return layout
+
+
+LANES = 128  # the chip's vector lanes: the minor dimension an array is tiled by
+
+
+def cache_heads_merged(cfg: TransformerConfig) -> bool:
+    """Whether the cache keeps a token's K/V heads side by side as ONE row
+    ``kv_heads x head_dim`` wide ([L, B, Smax, 1, heads x width]) and not as
+    [..., heads, width]: where a head is narrower than the chip's 128 lanes and the
+    heads together fill whole lanes. A [..., 8, 64] leaf is tiled by its last two
+    dimensions and half of every tile is padding; the decode program then copies
+    the WHOLE cache into the padded form at entry and back at exit (2 x 1.5 GB of
+    temporaries and some 8 ms a step at 128 slots x 3,072, compiled for the chip at
+    LFM2's widths). Merged, the leaf is as wide as its heads together, lies compact,
+    and is the layer loop's carry as it is held; a layer's K/V are viewed as heads
+    where they are read. Plain XLA attention alone (the Pallas decode kernel takes
+    [L, B, Smax, H, Dh] stacks; a window layer's ring keeps its heads)."""
+    together = cfg.kv_heads * cfg.head_dim
+    return (not cfg.kv_lora_rank and cfg.decode_attn == "xla" and not cfg.window_layers
+            and cfg.head_dim % LANES != 0 and together % LANES == 0
+            and cfg.value_head_dim == cfg.head_dim)
+
+
+def cache_layers(cfg: TransformerConfig) -> dict:
+    """How many layers keep each kind of ``cache_layout``'s leaves, as the leading
+    axis of those leaves has it: ``"tokens"`` the whole-context attention layers,
+    ``RING`` the window layers, ``STATE`` the layers with per-sequence state (every
+    layer of a model with a state-space mixer; the conv layers of one with
+    ``layer_operators``; 0 for any other)."""
+    n_window, n_conv = len(cfg.window_layers), len(cfg.conv_layers)
+    return {"tokens": cfg.num_layers - n_window - n_conv, RING: n_window,
+            STATE: n_conv or (cfg.num_layers if cfg.ssm_state_size else 0)}
 
 
 def token_leaves(tree: dict) -> dict:
@@ -2037,8 +2303,8 @@ def cache_ring_bytes(cfg: TransformerConfig, dtype=None) -> int:
 
 
 def cache_state_bytes(cfg: TransformerConfig, dtype=None) -> int:
-    """Bytes one SEQUENCE's per-sequence leaves take in ONE layer of the cache
-    (0 for a model that keeps none)."""
+    """Bytes one SEQUENCE's per-sequence leaves take in ONE layer of the cache that
+    keeps them (0 for a model that keeps none; ``cache_layers(cfg)[STATE]`` layers do)."""
     return sum(math.prod(shape) * jnp.dtype(own or dtype or cfg.dtype).itemsize
                for shape, own in cache_layout(cfg).get(STATE, {}).values())
 
@@ -2046,15 +2312,14 @@ def cache_state_bytes(cfg: TransformerConfig, dtype=None) -> int:
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int, dtype=None):
     """Allocate an empty cache for ``batch`` sequences of up to ``max_len``."""
     dtype = dtype or cfg.dtype
-    layout = cache_layout(cfg)
-    n_window = len(cfg.window_layers)
-    cache = {name: jnp.zeros((cfg.num_layers - n_window, batch, max_len) + tail, dtype)
+    layout, n = cache_layout(cfg), cache_layers(cfg)
+    cache = {name: jnp.zeros((n["tokens"], batch, max_len) + tail, dtype)
              for name, tail in token_leaves(layout).items()}
     if RING in layout:
-        cache[RING] = {name: jnp.zeros((n_window, batch) + shape, dtype)
+        cache[RING] = {name: jnp.zeros((n[RING], batch) + shape, dtype)
                        for name, shape in layout[RING].items()}
     if STATE in layout:
-        cache[STATE] = {name: jnp.zeros((cfg.num_layers, batch) + shape, own or dtype)
+        cache[STATE] = {name: jnp.zeros((n[STATE], batch) + shape, own or dtype)
                         for name, (shape, own) in layout[STATE].items()}
     return cache
 
@@ -2320,11 +2585,9 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
                 "prefill, speculative verification) has no code: the ring keeps "
                 f"local_attn_window = {cfg.local_attn_window} positions, and the block would "
                 "overwrite entries its own first queries still see")
-        seen, in_kind = {False: 0, True: 0}, []
-        for l in range(cfg.num_layers):
-            in_kind.append(seen[l in windowed])
-            seen[l in windowed] += 1
-        in_kind = jnp.asarray(in_kind, jnp.int32)
+    in_kind = None
+    if windowed or cfg.conv_layers:  # not every layer keeps per-token K/V
+        in_kind = jnp.asarray(_index_in_kind(cfg), jnp.int32)
 
     # Single-token decode steps route through the Pallas length-aware kernel
     # (ops/pallas/decode_attention.py — the reference's softmax_context,
@@ -2400,15 +2663,19 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
             for name, new in (("k", k_l), ("v", v_l))}
         return attn, {**stacks, RING: ring}
 
+    merged = cache_heads_merged(cfg)  # the cache's row is every head's, side by side
+    as_row = lambda x: x.reshape(x.shape[:2] + (1, -1)) if merged else x
+    as_heads = lambda c: c.reshape(c.shape[:2] + (cfg.kv_heads, -1)) if merged else c
+
     def attend(q, k, v, stacks, l, lp, kind=None):
-        if windowed:
+        if in_kind is not None:
             if kind is None:
-                raise ValueError("a cache with rings needs the layer's kind: _layer_loop("
-                                 "by_kind=True)")
+                raise ValueError("a cache with rings, or of the attention layers alone, needs "
+                                 "the layer's kind: _layer_loop(by_kind=True)")
             l = in_kind[l]
             if kind[0]:
                 return ring_attend(q, k, v, stacks, l, kind[0])
-        k_stack, v_stack = write(stacks["k"], l, k), write(stacks["v"], l, v)
+        k_stack, v_stack = write(stacks["k"], l, as_row(k)), write(stacks["v"], l, as_row(v))
         stacks = {**stacks, "k": k_stack, "v": v_stack}  # rings and a mixer's state ride along
         if use_decode_kernel:
             attn = decode_attention(q[:, 0], k_stack, v_stack, pos, layer=l)[:, None]
@@ -2418,7 +2685,7 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
             if cfg.kv_lora_rank:
                 k_l, v_l = _latent_expand(cfg, lp, k_l, v_l)
         else:
-            k_l, v_l = (lax.dynamic_index_in_dim(c, l, keepdims=False)
+            k_l, v_l = (as_heads(lax.dynamic_index_in_dim(c, l, keepdims=False))
                         for c in (k_stack, v_stack))
             if cfg.kv_lora_rank:
                 return _latent_attention(cfg, lp, q, k_l, v_l, pos), stacks
@@ -2484,12 +2751,12 @@ def apply_with_cache(
             "silently change the attention pattern the model trained with"
         )
     B, T = tokens.shape
-    if live is None and last_index is not None and (cfg.ssm_state_size or cfg.window_layers):
+    if live is None and last_index is not None and (STATE in cache or RING in cache):
         raise ValueError(
-            "apply_with_cache(last_index=...) pads the block past its live last token: a "
-            "state-space mixer needs `live` (the rows that are the sequence's own), or its "
-            "state runs on over the padding; so does a window layer's ring, or it keeps the "
-            "padding's rows")
+            "apply_with_cache(last_index=...) pads the block past its live last token: "
+            "per-sequence state (a state-space mixer's, a short convolution's tail) needs "
+            "`live` (the rows that are the sequence's own), or it runs on over the padding; so "
+            "does a window layer's ring, or it keeps the padding's rows")
     params = _stream_top_level(cfg, params)
     positions, attend = _cache_attention(cfg, B, T, cache_len(cache), pos, write_pos, live)
     x, _ = embed(cfg, params, tokens, positions)
@@ -2497,7 +2764,7 @@ def apply_with_cache(
         cfg, params["layers"], params.get("moe"), x, dict(cache),
         positions=positions, attend=attend, decode=T == 1, lead=params.get("dense_ffn"),
         live=live, banks_in_place=expert_bank_form(cfg, params.get("moe")) == "in_place",
-        by_kind=True)
+        by_kind=True, forward_only=True)
     if last_index is not None:
         # bucketed prefill: the live last token sits at ``last_index``
         # (prompt_len - 1), not at T-1 — project only that position
@@ -2687,9 +2954,11 @@ class Model:
         + attention term) — used by the throughput reports (reference:
         ThroughputTimer TFLOPS estimate utils/timer.py:135)."""
         c = self.config
+        # a conv layer's operator (d x 3d in, d x d out) is as many parameters as
+        # four d x d attention projections, and attends to nothing
         n_params = (
             c.num_layers * (4 * c.hidden_size * c.hidden_size + 2 * c.hidden_size * c.ffn_size)
             + c.vocab_size * c.hidden_size
         )
-        attn = c.num_layers * 2 * c.max_seq_len * c.hidden_size  # per-token qk+av
+        attn = (c.num_layers - len(c.conv_layers)) * 2 * c.max_seq_len * c.hidden_size  # qk+av
         return 6.0 * (n_params + attn)

@@ -88,6 +88,20 @@ DENSE_ROWS = 512
 # (tests/test_kanana.py counts both).
 SELECT_BIAS_STD = 0.02
 
+# Rows from which ``experts_dense`` hands its first two matmuls the rows once an
+# EXPERT (a batched product over e) and not once for all of them. ``tm,emf->etf``
+# leaves e a free dimension of the bank; XLA then either runs it as a convolution
+# over e on the bank as it lies (what it does at a decode step's 16 to 32 rows: the
+# timings above) or folds e into f, which wants the bank as [E, F, M]: compiled for
+# the chip at 128 rows inside a serving program (a decode step of 128 slots, a
+# 128-row prefill) it took the second and copied BOTH whole [L, E, M, F] stacks into
+# that layout in every call (2 x 3 GB at LFM2's widths: neither program fit; on the
+# chip, PR 42, and ``tests/test_chip_compile.py``). With e a batch dimension each
+# expert's [M, F] is read where it lies, whatever the rows. Every decode step the
+# benchmark had before (16 to 32 rows) keeps the form it was timed in; of its
+# prefill programs one takes this form (a held share's 512-row bucket).
+BATCHED_ROWS = 64
+
 
 def init_dropless(rng, n_layers: int, num_experts: int, d_model: int, d_ff: int,
                   shared: int = 0, select_bias: bool = False, held: int | None = None):
@@ -254,14 +268,19 @@ def experts_dense(bank, x, weights, experts, layer=None, first: int = 0):
     """Every expert of the bank on every row of x [T, M]; the weights of those
     not chosen are zero. ``bank`` and ``layer`` as ``experts_sorted`` takes them;
     ``first``: the bank holds the experts from this one on (a choice outside it
-    is mixed in by no one here)."""
+    is mixed in by no one here). From ``BATCHED_ROWS`` rows the first two matmuls
+    take the rows once an expert."""
     T = x.shape[0]
     if layer is not None:
         bank = {name: lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False)
                 for name, leaf in bank.items()}
     E = bank["wi"].shape[0]
     w = {name: leaf.astype(x.dtype) for name, leaf in bank.items()}
-    h = _gated(jnp.einsum("tm,emf->etf", x, w["wg"]), jnp.einsum("tm,emf->etf", x, w["wi"]))
+    if T >= BATCHED_ROWS:  # e a batch dimension: no layout of the bank but its own
+        xe = jnp.broadcast_to(x[None], (E,) + x.shape)
+        h = _gated(jnp.einsum("etm,emf->etf", xe, w["wg"]), jnp.einsum("etm,emf->etf", xe, w["wi"]))
+    else:
+        h = _gated(jnp.einsum("tm,emf->etf", x, w["wg"]), jnp.einsum("tm,emf->etf", x, w["wi"]))
     ys = jnp.einsum("etf,efm->etm", h, w["wo"])
     at = experts - first
     at = jnp.where((at >= 0) & (at < E), at, E)  # not held: dropped (a negative index would wrap)

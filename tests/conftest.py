@@ -45,6 +45,45 @@ if not os.environ.get("DSTPU_TEST_NO_XLA_CACHE"):
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
+# Every executable a process has loaded keeps a dozen or more memory mappings
+# (its code and constants), and jit's caches keep every executable. One xdist
+# worker runs several test FILES in one process (--dist loadfile):
+# tests/test_serving.py leaves ~31,000 mappings, tests/test_k_exaone.py adds
+# ~37,000, and at vm.max_map_count (65,530) the next mmap fails inside
+# ``deserialize_executable``: "Fatal Python error: Segmentation fault" (or
+# "Aborted") at whichever test loads the executable that crosses the line, the
+# worker down and the run's exit code with it (PR 42: three whole runs in three,
+# always that pair of files on one worker; either file alone passes). So a file
+# that ends above a quarter of the limit drops the loaded executables before
+# the next one starts; what the next file needs again it reloads from the
+# persistent cache above. Between FILES only: a test that counts its engine's
+# compiles (``compile_counts()``) shares that engine with its own file at most.
+def _mapped_regions() -> int:
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return sum(1 for _ in f)
+    except OSError:  # no procfs: nothing to count, nothing to hit
+        return 0
+
+
+def _map_limit() -> int:
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            return int(f.read())
+    except (OSError, ValueError):
+        return 65530  # the kernel's default
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _drop_loaded_executables_between_files():
+    yield
+    if _mapped_regions() > _map_limit() // 4:
+        import gc
+
+        jax.clear_caches()
+        gc.collect()
+
+
 # ---------------------------------------------------------------------------
 # Per-test duration ledger (bin/check_tier1_budget): the warm tier-1 suite
 # runs ~810-940s of an 870s driver budget with ±15% host drift — every run

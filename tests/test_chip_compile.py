@@ -568,6 +568,64 @@ def test_kinds_prefill_takes_the_banded_forward_in_its_window_layers(v5e, no_per
     assert _footprint(compiled) < HBM_BYTES
 
 
+def _lfm2_worker(n, Smax, v5e):
+    """LFM2-24B-A2B's nine layers C A C C C A C C C at their published widths
+    (``chipbench/configs/lfm2-24b-a2b-L9.json``'s ``program``: a gated short
+    convolution of 3 taps in seven layers, 32 / 8 heads of 64 in two, 64 experts of
+    1536 top-4) and a vocabulary of 1024."""
+    import json
+    from deepspeed_tpu.models import transformer as tfm
+
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__), "chipbench", "configs",
+                           "lfm2-24b-a2b-L9.json")) as f:
+        program = json.load(f)["program"]
+    cfg = tfm.TransformerConfig(dtype=jnp.bfloat16, **{
+        **program, "vocab_size": 1024, "max_seq_len": Smax})
+    return (cfg, *_bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0])))
+
+
+def test_operators_decode_program_keeps_kv_and_conv_state_in_place(v5e, no_persistent_cache,
+                                                                   as_tpu):
+    """``SlotWorker``'s decode step of the block with attention and short-convolution
+    layers at the cell's own size (nine layers, 128 slots x 3,072): the cache tree
+    is K/V of the TWO attention layers (a token's 8 heads of 64 side by side as one
+    row of 512: ``cache_heads_merged``; as [..., 8, 64] the program copied the WHOLE
+    cache into a lane-padded form at entry and back at exit, 2 x 1.5 GB of
+    temporaries) and two rows of state a sequence of the SEVEN conv layers, both the
+    layer loop's carry, donated in and aliased out,
+    neither stack copied whole; a conv layer attends to nothing, so exactly TWO
+    matmuls of the program yield a value ``Smax`` long (the attention layers'
+    QK^T: one in the period's scanned body, which runs twice, would read as one;
+    here the lead is inline and the two periods are one scan, so one line), and
+    the temporaries are one layer's K and V gathered for the grouped heads plus
+    its float32 scores, not nine layers' of either."""
+    n, Smax = 128, 3072
+    cfg, worker, params, cache, sds = _lfm2_worker(n, Smax, v5e)
+    assert jax.tree.map(lambda x: x.shape, cache) == {
+        "k": (2, n, Smax, 1, 512), "v": (2, n, Smax, 1, 512), "state": {"conv": (7, n, 2, 2048)}}
+    assert jax.tree.map(lambda x: x.shape, params["layers"]["conv"]) == {
+        "conv_in": (7, 2048, 6144), "conv_w": (7, 3, 2048), "conv_out": (7, 2048, 2048)}
+    assert params["layers"]["attn"]["wq"].shape == (2, 2048, 32, 64)
+    compiled = _compile_decode(worker, params, cache, n, sds)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # decode_attn "xla": grouped heads
+    # (the 7.3 MB state stack is donated and aliased like K/V; the compiler moves it whole
+    # into its faster memory for the loop and back, 9 us of bandwidth: not held to this)
+    for whole in (rf"bf16\[2,{n},{Smax},(?:1,512|8,64)\]", r"bf16\[[67],2048,6144\]",
+                  r"bf16\[[67],2048,2048\]", r"bf16\[8,64,2048,1536\]"):
+        copies = re.findall(rf"^\s*%?[\w.-]+ = {whole}\S* copy\(", text, re.M)
+        assert not copies, f"the decode step copies a whole stack: {copies}"
+    long_matmuls = [line for line in text.splitlines()
+                    if re.search(r" (?:convolution|dot)\(", line)
+                    and re.search(rf"^\s*%?[\w.-]+ = \w+\[[\d,]*\b{Smax}\b", line)]
+    assert len(long_matmuls) == 1, long_matmuls  # the scanned period's ONE attention layer
+    ma = compiled.memory_analysis()
+    layer_bytes = n * Smax * 8 * 64 * 2  # one attention layer's K (or V)
+    state_bytes = 7 * n * 2 * 2048 * 2
+    assert ma.alias_size_in_bytes >= 4 * layer_bytes + state_bytes  # donated in, aliased out
+    assert ma.temp_size_in_bytes < 2.5 * layer_bytes, (ma.temp_size_in_bytes, layer_bytes)
+
+
 def test_latent_prefill_attends_through_the_flash_kernel(v5e, no_persistent_cache, as_tpu):
     """The 1024-row prefill of the same block at 32 heads: its dense scores would
     be 128 MiB, so the expanded form goes through the flash forward kernel at q/k

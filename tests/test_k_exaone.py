@@ -74,8 +74,8 @@ def _bucket(n: int) -> int:
 
 
 def test_layout_is_kinds_as_data_over_a_held_share(cfg, params):
-    assert cfg.layer_kinds == ((16, True), (16, True), (16, True), (0, False), (16, True),
-                               (16, True))
+    S, G = (16, True, "attn"), (0, False, "attn")  # (window, rotary, operator): every layer attends
+    assert cfg.layer_kinds == (S, S, S, G, S, S)
     assert cfg.window_layers == (0, 1, 2, 4, 5) and cfg.experts_held == (4, 4)
     lay, moe = params["layers"], params["moe"]
     assert lay["q_norm_scale"].shape == lay["k_norm_scale"].shape == (6, 24)  # one [D] a layer
