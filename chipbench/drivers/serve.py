@@ -46,6 +46,13 @@ CHECK_PROMPT_LENS = (200, 97)  # <= 256 tokens, two different buckets
 WARM_UID = 10 ** 9  # warm-up and check requests sit far above the traffic's uids
 
 SPANS = ("serve.step", "serve.decode", "serve.prefill", "stamp", "generator", "idle_wait")
+# the least a traced run's host window may be, as a share of the window: the
+# host-clock per-layer metrics and the run's attempted / failed are read there
+HOST_WINDOW_MIN_SHARE = 0.2
+
+
+class HostWindowTooShort(RuntimeError):
+    """stop_trace ate the window a traced run reads its host-clock metrics from."""
 
 
 def _bucket(srv, n: int) -> int:
@@ -205,16 +212,18 @@ def _loop(run, srv, Request, traffic, samples) -> dict:
     closed = traffic["loop"] == "closed"
     # after the window: no new work, and grace_s for what arrived in it to finish
     grace = float(run.sized("traffic")["grace_s"])
-    # With --trace 1 the profiler is started before the loop's clock (its
-    # start-up takes seconds and would stall the arrivals), the traced window is
-    # the first trace.seconds of the measured window, and the host-clock
-    # per-layer metrics are read from what arrives once stop_trace (seconds
-    # again) and the backlog it caused are over.
+    # With --trace 1 the profiler starts run.TRACE_START_BEFORE_S before the
+    # window, inside the lead-in, which no metric reads (in the loop's first
+    # iteration where the lead-in is no longer than that): stop_trace stalls the
+    # loop in proportion to what was profiled (0.7-2.2 s a profiled second, by
+    # the cell), so it pays for that second and the traced ones, not for the
+    # lead-in. The traced window is the first trace.seconds of the measured
+    # window, and the host-clock per-layer metrics are read from what arrives
+    # once stop_trace and the backlog it caused are over.
     trace = run.sized("trace")
-    tracing = "armed" if run.trace else None
+    tracing = "waiting" if run.trace else None
     resume_at = lo
-    if run.trace:
-        run.trace_start(window=False)
+    profiler = {"start_at": None, "start_s": None, "stop_s": None}  # loop clock, seconds
 
     stamps: dict[int, list] = {}
     steps, first_seen, submitted = [], {}, {}
@@ -239,13 +248,20 @@ def _loop(run, srv, Request, traffic, samples) -> dict:
         now = clock()
         if now >= hi + grace:
             break
+        if tracing == "waiting" and now >= lo - run.TRACE_START_BEFORE_S:
+            run.trace_start(window=False)
+            tracing, profiler["start_at"] = "armed", now
+            now = clock()  # a start-up that runs past lo opens the window late
+            profiler["start_s"] = now - profiler["start_at"]
         if tracing == "armed" and now >= lo:
             run.trace_window_open()
             tracing, traced[0] = "open", now
         if tracing == "open" and now >= lo + trace["seconds"]:
             run.trace_stop()
             tracing, traced[1] = None, now
-            resume_at = clock() + trace["settle_s"]
+            stopped = clock()
+            profiler["stop_s"] = stopped - now
+            resume_at = stopped + trace["settle_s"]
         if not closed and srv.n_active == 0 and srv.n_prefilling == 0 and nxt < total:
             wait = reqs[nxt]["arrival_time"] - now  # serve()'s own sleep rule
             if wait > 0:
@@ -287,6 +303,15 @@ def _loop(run, srv, Request, traffic, samples) -> dict:
                 raise RuntimeError("the closed loop ran out of pre-generated requests: "
                                    "raise the traffic's max_rps")
     t_end = clock()
+    if run.trace:
+        arrivals = sum(resume_at <= t < hi for t in submitted.values())
+        if hi - resume_at < HOST_WINDOW_MIN_SHARE * (hi - lo) or not arrivals:
+            stop_s = profiler["stop_s"]  # None: the loop ended before the traced window did
+            raise HostWindowTooShort(
+                f"stop_trace took trace_stop_s={stop_s and round(stop_s, 1)} s and left the "
+                f"host window [resume_at={resume_at:.1f}, hi={hi:.1f}) with {arrivals} "
+                f"arrival(s): under {HOST_WINDOW_MIN_SHARE:.0%} of the window's "
+                f"{hi - lo:.1f} s, or empty. Shorten the cell's trace.seconds")
 
     records = []
     for r in reqs:
@@ -310,7 +335,8 @@ def _loop(run, srv, Request, traffic, samples) -> dict:
         })
     calls = {m: [(a - epoch, b - epoch) for a, b in s] for m, s in samples.items()}
     return {"records": records, "steps": steps, "calls": calls, "window": (resume_at, hi),
-            "t_end": t_end, "loop": traffic["loop"], "epoch": epoch, "traced": tuple(traced)}
+            "t_end": t_end, "loop": traffic["loop"], "epoch": epoch, "traced": tuple(traced),
+            "profiler": profiler}
 
 
 def run(run) -> dict:
@@ -368,5 +394,6 @@ def run(run) -> dict:
         "notes": {"check": check, "steps": len(measured["steps"]), "stats": stats,
                   "statuses": sorted({r["status"] for r in counted}),
                   "traced": measured["traced"], "host_window": measured["window"],
+                  **{f"trace_{k}": v for k, v in measured["profiler"].items()},
                   "compile_counts_after": str(srv.compile_counts())},
     }

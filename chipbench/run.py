@@ -129,9 +129,17 @@ class Run:
 
         return jax.profiler.TraceAnnotation(name)
 
+    # A serving driver starts the profiler this long before its window, not
+    # before its loop: stop_trace stalls the loop for 0.7-2.2 s a PROFILED second
+    # (PERF.md section 6, PR 44), so a profile of the whole lead-in left a traced
+    # run no window to read its host-clock metrics from. The start-up itself
+    # takes 0.04-0.05 s on the chip: a second leaves some 30 to 100 steady steps
+    # before the window in every cell.
+    TRACE_START_BEFORE_S = 1.0
+
     def trace_start(self, window: bool = True) -> None:
-        """Start the profiler (it takes seconds on the chip) and, unless the
-        driver opens it later itself, the traced window."""
+        """Start the profiler (0.04 s on the chip, PR 44) and, unless the driver
+        opens it later itself, the traced window."""
         import jax
 
         self.trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")

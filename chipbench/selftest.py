@@ -11,7 +11,9 @@ trace recorded on the chip (``recorded/``: a few steps of a tiny sharded
 program on two devices' planes) against the reduction pinned when it was
 recorded; ``flops.py`` and the references' counts against hand counts for
 both configurations; that each traffic generator gives identical requests for
-one seed and different ones for another; that ``BENCHMARK.json``, the
+one seed and different ones for another; where a traced serving run starts and
+stops the profiler and the host window that leaves (``test_trace_window.py``,
+on a virtual clock); that ``BENCHMARK.json``, the
 directories and the readers agree (every metric has its reader, every cell its
 files, and ``run.py`` names none of them); and the references
 (``check_references``, run as part of ``check_files``): every configuration
@@ -152,7 +154,8 @@ def check_flops() -> None:
 
 def check_traffic() -> None:
     """Every generator under ``traffic/``, on the parameters it documents as
-    its ``EXAMPLE``, and every cell's own ``traffic`` block."""
+    its ``EXAMPLE``, and every cell's own ``traffic`` block; then the loop that
+    drives serving traffic (``check_trace_window``)."""
     import numpy as np
 
     blocks = {}
@@ -185,6 +188,9 @@ def check_traffic() -> None:
             x, y, z = next(a["batches"]), next(b["batches"]), next(c["batches"])
             assert np.array_equal(x, y) and not np.array_equal(x, z)
             assert x.min() >= 0 and x.max() < 1000
+    # part of this check and not one of its own: the repository's test of this
+    # file counts its six "ok" lines (tests/test_tracing_spans.py)
+    check_trace_window()
 
 
 def check_files() -> None:
@@ -316,6 +322,18 @@ def record(out_dir: str) -> int:
     print(json.dumps({"recorded": dst, "bytes": os.path.getsize(dst), "reduced": {
         k: v for k, v in got.items() if k != "op_seconds"}, "ops": sorted(got["op_seconds"])}))
     return 0
+
+
+def check_trace_window() -> None:
+    """Where a traced serving run starts and stops the profiler and what host
+    window that leaves: ``test_trace_window.py``'s cases, on a virtual clock."""
+    from . import test_trace_window
+
+    for name, case in test_trace_window.CASES.items():
+        try:
+            case()
+        except AssertionError as e:
+            raise AssertionError(f"{name}: {e}") from e
 
 
 def main(argv=None) -> int:
