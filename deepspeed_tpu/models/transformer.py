@@ -997,7 +997,11 @@ def xla_attention(q, k, v, *, causal_offset=0, bias=None, causal=True, dtype=jnp
     own absolute position. v's heads may be another width than q's and k's.
     k and v may have FEWER heads than q (grouped-query attention): query head i
     attends key / value head i // (H // Hkv), the group contracted against its
-    one K/V head where it lies, never against repeated copies."""
+    one K/V head where it lies, never against repeated copies. That contraction's
+    batch dimensions are (row, K/V head): right for a block's own K/V and for a
+    ring of 128 positions; over layer l of a cache stack ``Smax`` long the
+    compiler slices the layer out first (and re-lays narrow heads head-major),
+    which is why a step that reads a cache of rows takes ``_rows_attention``."""
     B, Sq, H, Dh = q.shape
     Hkv = k.shape[2]
     if Hkv == H:
@@ -1061,6 +1065,36 @@ def _latent_attention(cfg: "TransformerConfig", lp, q, k_pe, c, pos):
     probs = _masked_softmax(scores / math.sqrt(cfg.head_dim), pos).astype(q.dtype)
     out_lat = jnp.einsum("bhts,bsr->bthr", probs, c)
     return jnp.einsum("bthr,rhv->bthv", out_lat, w[..., Dn:])
+
+
+def _rows_attention(q, k_rows, v_rows, pos, bias=None):
+    """Grouped-query attention of a short block q [B, T, H, Dh] over a cache layer
+    that holds a token's K/V heads side by side as ONE row (``cache_heads_merged``):
+    k_rows [B, Smax, Hkv x Dh], v_rows [B, Smax, Hkv x Dv], valid rows
+    [0, pos + T) (``pos`` scalar or [B]). The row is contracted WHOLE, the cache's
+    row index the only batch dimension, as ``_latent_attention`` contracts its
+    latent: query head h is laid into block h // (H // Hkv) of a row of zeros, so
+    ``q_rows . k_row`` is q_h . k_{its head} plus exact zeros in the float32
+    accumulation, the softmax is per query head as in ``xla_attention``, and block
+    h // (H // Hkv) of ``probs . v_row`` is the head's output: the same products,
+    the same sums, the same rounding points. It multiplies Hkv x what the grouped
+    form does (H x T operations a byte of K/V read: ``ROWS_OPS_PER_BYTE``) and the
+    compiler reads layer l of the stack inside the contraction. The grouped form's
+    batch dimensions (row, K/V head) are not the stack's leading ones: for it the
+    compiler slices the layer out of the stack and re-lays it head-major, four
+    passes over a layer's K and V where the step needs one (LFM2, 128 slots x
+    3,072: 9.8 ms of a 26.7 ms step, PERF.md §6 PR 45)."""
+    B, T, H, Dh = q.shape
+    Smax, Hkv = k_rows.shape[1], k_rows.shape[2] // Dh
+    # [H, 1, Hkv, 1]: whether block j of a row is query head h's K/V head
+    own = (jnp.arange(H)[:, None] // (H // Hkv) == jnp.arange(Hkv))[:, None, :, None]
+    q_rows = jnp.where(own, q.transpose(0, 2, 1, 3)[:, :, :, None, :], 0)  # [B, H, T, Hkv, Dh]
+    scores = jnp.einsum("bmr,bsr->bms", q_rows.reshape(B, H * T, Hkv * Dh), k_rows)
+    scores = scores.astype(jnp.float32).reshape(B, H, T, Smax) / math.sqrt(Dh)
+    probs = _masked_softmax(scores, pos, bias).astype(q.dtype)
+    out_rows = jnp.einsum("bms,bsr->bmr", probs.reshape(B, H * T, Smax), v_rows)
+    out = jnp.where(own, out_rows.reshape(B, H, T, Hkv, -1), 0)  # one block is the head's
+    return jnp.sum(out, axis=3).transpose(0, 2, 1, 3)
 
 
 def _param_streamer(cfg: TransformerConfig):
@@ -2199,7 +2233,9 @@ def cache_layout(cfg: TransformerConfig) -> dict:
     Per-TOKEN leaves, ``{leaf: (heads, width)}``: what attention caches a token a
     layer, [L, B, Smax, heads, width] in the cache. Plain attention: the keys and
     the values of every K/V head (``kv_heads``: grouped-query attention caches
-    its few, not the query heads' many). Latent attention: ``k`` is the rotary
+    its few, not the query heads' many), or, where ``cache_heads_merged`` says so
+    (narrow heads; grouped heads a step contracts in place), those heads side by
+    side as ONE 'head' ``kv_heads x head_dim`` wide. Latent attention: ``k`` is the rotary
     key the heads share, ``v`` the normed latent, which is the absorbed form's
     value and the rest of its key; the 'one head' is every head's. With layers of
     several kinds (``layer_kinds``) L counts the WHOLE-context ATTENTION layers
@@ -2245,23 +2281,73 @@ def cache_layout(cfg: TransformerConfig) -> dict:
 
 LANES = 128  # the chip's vector lanes: the minor dimension an array is tiled by
 
+# Operations a byte of K/V read up to which a block contracts a cache of rows where
+# they lie (``_rows_attention``): it does (query heads x block rows) of them, and 240
+# is the v5e's ridge (197 TFLOP/s over 819 GB/s), so up to there the block costs the
+# read of the rows and nothing more. Only a step was timed on the chip (PERF.md §6,
+# PR 45); verify blocks of 4 and 7 rows were compiled for it at LFM2's cell (no slice
+# or copy of a layer; the temporaries are the block's float32 scores, 0.27 and 0.55
+# GB); nothing is taken past the ridge.
+ROWS_OPS_PER_BYTE = 240
+
 
 def cache_heads_merged(cfg: TransformerConfig) -> bool:
     """Whether the cache keeps a token's K/V heads side by side as ONE row
     ``kv_heads x head_dim`` wide ([L, B, Smax, 1, heads x width]) and not as
-    [..., heads, width]: where a head is narrower than the chip's 128 lanes and the
-    heads together fill whole lanes. A [..., 8, 64] leaf is tiled by its last two
-    dimensions and half of every tile is padding; the decode program then copies
-    the WHOLE cache into the padded form at entry and back at exit (2 x 1.5 GB of
-    temporaries and some 8 ms a step at 128 slots x 3,072, compiled for the chip at
-    LFM2's widths). Merged, the leaf is as wide as its heads together, lies compact,
-    and is the layer loop's carry as it is held; a layer's K/V are viewed as heads
-    where they are read. Plain XLA attention alone (the Pallas decode kernel takes
-    [L, B, Smax, H, Dh] stacks; a window layer's ring keeps its heads)."""
+    [..., heads, width]. Two reasons, either is enough, and both need the heads
+    together to fill whole lanes:
+
+    a head NARROWER than the chip's 128 lanes. A [..., 8, 64] leaf is tiled by its
+    last two dimensions and half of every tile is padding; the decode program then
+    copies the WHOLE cache into the padded form at entry and back at exit (2 x 1.5 GB
+    of temporaries and some 8 ms a step at 128 slots x 3,072, compiled for the chip
+    at LFM2's widths). Merged, the leaf is as wide as its heads together and lies
+    compact;
+
+    GROUPED heads (``kv_heads < num_heads``) of any width. A step's grouped
+    contraction over [B, Smax, Hkv, Dh] has the K/V head as a batch dimension, which
+    is not a leading dimension of the stack: the compiler slices the layer out as an
+    operation of its own (Falcon-H1, [1, 64, 2048, 4, 128]: 2 x 134 MB written and
+    read again a layer a step). Over a row the step contracts in place
+    (``_rows_attention``, by ``cache_rows_step``'s rule; a model with more query heads
+    than ``ROWS_OPS_PER_BYTE`` would never take it and keeps its heads), and a
+    [..., Hkv, Dh] leaf VIEWED as rows is a materialised reshape of the layer (the
+    tiles differ), so the cache itself holds the rows.
+
+    Either way the leaf is the layer loop's carry as it is held; a block that fills
+    its cache attends to itself and never reads it, and a block too long for the rows
+    form (a chunk) views the layer as heads where it reads it. Plain XLA attention
+    alone: the Pallas decode kernel takes [L, B, Smax, H, Dh] stacks; a latent is one
+    'head' already; a window layer's ring keeps its heads, and so does the
+    whole-context layer beside it (K-EXAONE: ONE such layer, which the compiler reads
+    in place with the re-layout inside the contraction's own fusion; no K/V slice or
+    copy stands in its program). Multi-head attention at the lanes' width (BLOOM,
+    OLMoE, Pythia) has nothing to gain. A row is one 'head' and replicates over a
+    mesh's tensor axis (``kv_slot_cache_spec``): grouped heads that the process's
+    active mesh (``Model.set_mesh``, as ``expert_bank_form`` reads it) would shard
+    over that axis stay heads, each shard holding its own; narrow heads merge there
+    too, as they have since PR 42 (the padded copy of the whole cache costs more)."""
     together = cfg.kv_heads * cfg.head_dim
+    narrow = cfg.head_dim % LANES != 0
+    grouped = cfg.kv_heads < cfg.num_heads <= ROWS_OPS_PER_BYTE
+    if grouped and _ACTIVE_MESH[0] is not None:
+        from ..parallel.sharding import batch_and_head_axes
+
+        grouped = batch_and_head_axes(_ACTIVE_MESH[0], 1, cfg.kv_heads)[1] is None
     return (not cfg.kv_lora_rank and cfg.decode_attn == "xla" and not cfg.window_layers
-            and cfg.head_dim % LANES != 0 and together % LANES == 0
+            and (narrow or grouped) and together % LANES == 0
             and cfg.value_head_dim == cfg.head_dim)
+
+
+def cache_rows_step(cfg: TransformerConfig, T: int = 1) -> bool:
+    """Whether a block of ``T`` tokens a row that READS the cache contracts its rows
+    where they lie (``_rows_attention``): the cache holds rows of grouped heads and
+    the block is a step, or short enough (a verify block of a few drafts) that its
+    H x T operations a byte stay under the chip's ridge. A longer block (a chunk of
+    a prompt: ONE row of the batch) views the layer as heads and pays the grouped
+    form's slice and copy once for many tokens."""
+    return (cache_heads_merged(cfg) and cfg.kv_heads < cfg.num_heads
+            and T * cfg.num_heads <= ROWS_OPS_PER_BYTE)
 
 
 def cache_layers(cfg: TransformerConfig) -> dict:
@@ -2518,10 +2604,15 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
     and attends as plain causal attention (``_latent_expand``; the flash kernel
     by ``cache_attention_form``'s rule, at q/k and v heads of two widths);
     every other block attends in the absorbed form over the cached latent
-    (``_latent_attention``) and nothing per-head is ever made of the cache. The stacks are its state, so they stay the layer
-    loop's CARRY: with the cache donated the loop's input and output are one
-    buffer; as the scan's xs/ys they would be sliced out and restacked layer by
-    layer and copied whole (tests/test_chip_compile.py guards it).
+    (``_latent_attention``) and nothing per-head is ever made of the cache. Grouped
+    heads over a cache of merged rows (``cache_heads_merged``) have two forms the
+    same way: a step, or a block short enough (``cache_rows_step``), contracts the
+    rows where they lie (``_rows_attention``); a longer block that reads the cache
+    (a chunk) views the layer as heads for ``xla_attention``'s grouped form. The
+    stacks are its state, so they stay the layer loop's CARRY: with the cache
+    donated the loop's input and output are one buffer; as the scan's xs/ys they
+    would be sliced out and restacked layer by layer and copied whole
+    (tests/test_chip_compile.py guards it).
 
     Layers of several kinds (``cfg.layer_kinds``; the loop hands ``attend`` the
     layer's ``kind`` as a Python value). A whole-context layer is the above, at
@@ -2666,6 +2757,7 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
     merged = cache_heads_merged(cfg)  # the cache's row is every head's, side by side
     as_row = lambda x: x.reshape(x.shape[:2] + (1, -1)) if merged else x
     as_heads = lambda c: c.reshape(c.shape[:2] + (cfg.kv_heads, -1)) if merged else c
+    rows_step = cache_rows_step(cfg, T)  # a short block contracts the rows where they lie
 
     def attend(q, k, v, stacks, l, lp, kind=None):
         if in_kind is not None:
@@ -2685,10 +2777,13 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
             if cfg.kv_lora_rank:
                 k_l, v_l = _latent_expand(cfg, lp, k_l, v_l)
         else:
-            k_l, v_l = (as_heads(lax.dynamic_index_in_dim(c, l, keepdims=False))
+            k_l, v_l = (lax.dynamic_index_in_dim(c, l, keepdims=False)
                         for c in (k_stack, v_stack))
             if cfg.kv_lora_rank:
                 return _latent_attention(cfg, lp, q, k_l, v_l, pos), stacks
+            if rows_step:
+                return _rows_attention(q, k_l[:, :, 0], v_l[:, :, 0], pos, bias), stacks
+            k_l, v_l = as_heads(k_l), as_heads(v_l)
         if use_flash:
             attn = flash_attention_sharded(q, *repeat_groups(q, k_l, v_l), mesh=_ACTIVE_MESH[0],
                                            causal=True, alibi_slopes=slopes)

@@ -283,21 +283,30 @@ def test_decode_program_keeps_the_recurrent_state_in_place(v5e, no_persistent_ca
     in the cell) rides in the layer loop's carry with K/V, donated in and aliased
     out; layer l of it is read, advanced by the one-step recurrence and written
     back where it lies. The compiled program holds no second copy of the stack
-    and nothing of one layer's size beside it."""
+    and nothing of one layer's size beside it. K/V: the 4 K/V heads of 128 (not the
+    20 query heads) side by side as one row of 512 (``cache_heads_merged``: grouped
+    heads), which the step contracts in place (``_rows_attention``): NO operation
+    of the program yields one layer's K or V (as [L, slots, Smax, 4, 128] the
+    grouped form had the compiler slice each layer's out as an operation of its
+    own, 2 x 134 MB a layer a step in the cell: PR 45), and the temporaries are
+    under a quarter of one layer's K."""
     L, n, Smax = 4, 16, 512
     cfg = _falcon_h1_cfg(L, Smax)
     worker, params, cache, sds = _bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0]))
-    assert cache["k"].shape == (L, n, Smax, 4, 128)  # the K/V heads, not the 20 query heads
+    assert cache["k"].shape == cache["v"].shape == (L, n, Smax, 1, 4 * 128)
     compiled = _compile_decode(worker, params, cache, n, sds)
     text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # decode_attn "xla": grouped heads
     whole = re.escape(f"f32[{L},{n},32,128,256]")
     copies = re.findall(rf"^\s*%?[\w.-]+ = {whole}\S* copy\(", text, re.M)
     assert not copies, f"the decode step copies the whole state stack: {copies}"
+    layer = _operations_writing(text, n * Smax * 4 * 128)
+    assert not layer, f"the decode step slices or copies one layer's K/V: {layer}"
     ma = compiled.memory_analysis()
     layer_state = n * 32 * 128 * 256 * 4
-    kv = 2 * L * n * Smax * 4 * 128 * 2
-    assert ma.alias_size_in_bytes >= L * layer_state + kv  # donated in, aliased out
-    assert ma.temp_size_in_bytes < layer_state, (ma.temp_size_in_bytes, layer_state)
+    layer_bytes = n * Smax * 4 * 128 * 2  # one layer's K (or V)
+    assert ma.alias_size_in_bytes >= L * layer_state + 2 * L * layer_bytes  # donated, aliased
+    assert ma.temp_size_in_bytes < layer_bytes // 4, (ma.temp_size_in_bytes, layer_bytes)
 
 
 def test_prefill_scan_forms_no_pairs_by_state_temporary(v5e, no_persistent_cache, as_tpu):
@@ -426,6 +435,31 @@ def _reach(comps, roots, through_branches):
     return seen
 
 
+def _operations_writing(text, elements, dtype="bf16"):
+    """The instructions of an optimised module that run as operations of their own
+    (the lines of the entry computation and of every loop body, loop condition and
+    conditional branch under it; not those inside a fusion, which make no array)
+    and yield a ``dtype`` array of exactly ``elements`` elements in any order of
+    dimensions: one layer's K (or V), sliced out of its stack, copied or re-laid.
+    Views (a bitcast, an element of a tuple, a parameter) write nothing."""
+    comps, entry = _computations(text)
+    seen, todo, found = set(), [entry], []
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        todo += comps[name][2]
+        for line in comps[name][0]:
+            todo += re.findall(r"(?:body|condition)=%?([\w.-]+)", line)
+            todo += re.findall(r" call\(.*to_apply=%?([\w.-]+)", line)
+            op = re.match(rf"\s*(?:ROOT )?%?[\w.-]+ = {dtype}\[([\d,]+)\]\S* ([\w-]+)\(", line)
+            if (op and op.group(2) not in ("bitcast", "get-tuple-element", "parameter")
+                    and np.prod([int(d) for d in op.group(1).split(",")]) == elements):
+                found.append(line.strip()[:160])
+    return found
+
+
 @pytest.mark.parametrize("family", ["bloom_dense_alibi", "pythia_pallas_kernel"])
 def test_sampler_sort_stays_behind_the_conditional(family, v5e, no_persistent_cache, as_tpu):
     """``SlotWorker``'s decode step and a prefill at the widths of the two
@@ -520,9 +554,14 @@ def test_kinds_decode_program_keeps_both_cache_kinds_in_place(v5e, no_persistent
     loop's carry, donated in and aliased out, neither stack copied whole; a
     window layer attends over its ring, so exactly ONE matmul of the program
     yields a value ``Smax`` long (the whole-context layer's QK^T; were a window
-    layer to attend over ``Smax`` under a mask there would be five), and the
-    temporaries are that one layer's K and V gathered for the grouped heads plus
-    its float32 scores, not five layers' of either."""
+    layer to attend over ``Smax`` under a mask there would be five). The
+    whole-context layer keeps its heads (a model with rings: ``cache_heads_merged``)
+    and its stack is ONE layer, which the compiler reads in place, the head-major
+    re-layout inside the contraction's own fusion: the only operations that yield
+    an array of a layer's K or V are the two in-place writes of the new row, no
+    slice, copy or transpose. The temporaries (1.9 x one layer's K here, 0.528 GB at
+    the cell's 32 x 16,384 where a layer's K is 1.07 GB) are four per-layer copies
+    of the ``wq`` slices (PERF.md §7) and the float32 scores, nothing of K/V."""
     n, Smax = 16, 8192
     cfg, worker, params, cache, sds = _k_exaone_worker(n, Smax, v5e)
     assert jax.tree.map(lambda x: x.shape, cache) == {
@@ -536,13 +575,17 @@ def test_kinds_decode_program_keeps_both_cache_kinds_in_place(v5e, no_persistent
         assert not copies, f"the decode step copies a whole cache stack: {copies}"
     long_matmuls = [line for line in text.splitlines()
                     if re.search(r" (?:convolution|dot)\(", line)
-                    and re.search(rf"^\s*%?[\w.-]+ = \w+\[[\d,]*\b{Smax}\b", line)]
+                    and re.search(rf"^\s*(?:ROOT )?%?[\w.-]+ = \w+\[[\d,]*\b{Smax}\b", line)]
     assert len(long_matmuls) == 1, long_matmuls
+    layer = _operations_writing(text, n * Smax * 8 * 128)
+    assert len(layer) == 2 and all(" fusion(%bitcast" in op and "kind=kCustom" in op
+                                   for op in layer), layer  # the new row into K, into V
     ma = compiled.memory_analysis()
     layer_bytes = n * Smax * 8 * 128 * 2  # the whole-context layer's K (or V)
     ring_bytes = 4 * n * 128 * 8 * 128 * 2
     assert ma.alias_size_in_bytes >= 2 * (layer_bytes + ring_bytes)  # donated in, aliased out
-    assert ma.temp_size_in_bytes < 2.5 * layer_bytes, (ma.temp_size_in_bytes, layer_bytes)
+    wq_copies = 4 * 6144 * 64 * 128 * 2
+    assert ma.temp_size_in_bytes < wq_copies + layer_bytes // 2, (ma.temp_size_in_bytes, layer_bytes)
 
 
 def test_kinds_prefill_takes_the_banded_forward_in_its_window_layers(v5e, no_persistent_cache,
@@ -596,9 +639,14 @@ def test_operators_decode_program_keeps_kv_and_conv_state_in_place(v5e, no_persi
     neither stack copied whole; a conv layer attends to nothing, so exactly TWO
     matmuls of the program yield a value ``Smax`` long (the attention layers'
     QK^T: one in the period's scanned body, which runs twice, would read as one;
-    here the lead is inline and the two periods are one scan, so one line), and
-    the temporaries are one layer's K and V gathered for the grouped heads plus
-    its float32 scores, not nine layers' of either."""
+    here the lead is inline and the two periods are one scan, so one line). The
+    step contracts each attention layer's rows where they lie
+    (``_rows_attention``): NO operation of the program yields one layer's K or V,
+    as [1, 128, 3072, 512] or viewed as [..., 8, 64] (the grouped form had the
+    compiler slice each out of its stack and copy it head-major: four operations of
+    403 MB, 9.8 ms of the cell's 26.7 ms step, PR 45), and the temporaries are the
+    float32 scores and the step's own activations: 0.02 GB, under a quarter of one
+    layer's K, where they were 0.85 GB."""
     n, Smax = 128, 3072
     cfg, worker, params, cache, sds = _lfm2_worker(n, Smax, v5e)
     assert jax.tree.map(lambda x: x.shape, cache) == {
@@ -617,13 +665,15 @@ def test_operators_decode_program_keeps_kv_and_conv_state_in_place(v5e, no_persi
         assert not copies, f"the decode step copies a whole stack: {copies}"
     long_matmuls = [line for line in text.splitlines()
                     if re.search(r" (?:convolution|dot)\(", line)
-                    and re.search(rf"^\s*%?[\w.-]+ = \w+\[[\d,]*\b{Smax}\b", line)]
+                    and re.search(rf"^\s*(?:ROOT )?%?[\w.-]+ = \w+\[[\d,]*\b{Smax}\b", line)]
     assert len(long_matmuls) == 1, long_matmuls  # the scanned period's ONE attention layer
+    layer = _operations_writing(text, n * Smax * 8 * 64)
+    assert not layer, f"the decode step slices or copies one layer's K/V: {layer}"
     ma = compiled.memory_analysis()
     layer_bytes = n * Smax * 8 * 64 * 2  # one attention layer's K (or V)
     state_bytes = 7 * n * 2 * 2048 * 2
     assert ma.alias_size_in_bytes >= 4 * layer_bytes + state_bytes  # donated in, aliased out
-    assert ma.temp_size_in_bytes < 2.5 * layer_bytes, (ma.temp_size_in_bytes, layer_bytes)
+    assert ma.temp_size_in_bytes < layer_bytes // 4, (ma.temp_size_in_bytes, layer_bytes)
 
 
 def test_latent_prefill_attends_through_the_flash_kernel(v5e, no_persistent_cache, as_tpu):
