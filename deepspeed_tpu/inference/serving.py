@@ -105,7 +105,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from ..models import transformer as tfm
-from ..moe.dropless import expert_load, load_summary
+from ..moe.dropless import expert_gemm_form, expert_load, load_summary
 from ..parallel.sharding import kv_prefix_pool_spec, kv_slot_cache_spec
 from ..resilience import FaultInjector, RequestRejected
 from ..runtime.config import (ChunkedPrefillConfig, FaultInjectionConfig,
@@ -753,9 +753,15 @@ class SlotWorker:
         the state. A model with layers by operator: ``conv_layers`` / ``attn_layers``
         it ran, ``state_rows`` and ``state_bytes``, the tail it wrote. A routed
         model's ``expert_bank``: where the program reads
-        layer l of the three banks from (``expert_bank_form``). Nothing for a
-        model with neither."""
-        attrs = {"expert_bank": self.expert_bank} if self.expert_bank else {}
+        layer l of the three banks from (``expert_bank_form``), and
+        ``expert_gemm``: what multiplies its rows through the experts
+        (``dropless.expert_gemm_form``, the rule the program was traced by).
+        Nothing for a model with neither."""
+        attrs = {}
+        if self.expert_bank:
+            attrs = {"expert_bank": self.expert_bank,
+                     "expert_gemm": expert_gemm_form(self.cfg, self.params["moe"]["experts"],
+                                                     rows, self.expert_bank == "in_place")}
         if self.state_bytes_per_slot:
             attrs.update(state_rows=int(live), **self.operator_attrs)
             if self.cfg.ssm_state_size:

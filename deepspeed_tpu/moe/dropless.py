@@ -15,13 +15,21 @@ one has no capacity and no ``[T, E, C]`` tensor at any skew:
   ``moe_routed_scale``; the one router of training, prefill and decode, its
   forms data of the configuration.
 * ``experts_sorted``: the token-expert pairs sorted by expert, one grouped
-  matmul per projection over the contiguous groups (``jax.lax.ragged_dot``:
-  on a TPU a grouped-GEMM kernel that visits only the rows there are),
-  unsorted, and summed with the weights.
+  matmul per projection over the contiguous groups, unsorted, and summed with
+  the weights. The grouped matmul is ``jax.lax.ragged_dot`` (on a TPU the
+  compiler's grouped-GEMM kernel, whose row tile is 512: a tile a group
+  boundary falls in is multiplied once by each group in it, and the groups
+  here are 64 to 384 rows in the mean) or, in a serving program on the chip,
+  ``ops/pallas/grouped_gemm.py`` at a row tile of 128 with the next group's
+  bank block fetched while this one's rows multiply (``expert_gemm_form``
+  says which; PERF.md §6, PR 46 has the chip's timings of both at every
+  cell's shapes: 1.28 -> 0.6 ms a projection at OLMoE's).
 * ``experts_dense``: every expert on every row, each expert's weights read
   once; for the few rows of a decode step, where the sorted form reads the
   same bytes and pays a sort, two gathers and near-empty tiles on top
-  (PERF.md §6, PR 27 has the chip's timings of both).
+  (PERF.md §6, PR 27 has the chip's timings of both, taken against the
+  compiler's 512-row tile; PR 46's table has the sorted form's grouped matmuls
+  alone at 256 and 512 rows a call under both kernels).
 
 * the shared expert (``moe_shared_size``): one more gated MLP that every row
   goes through, a plain matmul beside the routed sum and not a group of the
@@ -62,6 +70,7 @@ count is data has no reverse-mode derivative.
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -75,8 +84,17 @@ from .experts import experts_logical_axes
 # the chip's timings of one layer's block at OLMoE's widths (PERF.md §6, PR 27;
 # ms, dense / sorted): 3.0 / 5.7 at 16 rows, 3.0 / 7.3 at 256, 3.7 / 7.5 at 512,
 # 6.3 / 8.1 at 1024, 16.0 / 8.9 at 2048 with the weights held in float32 as
-# today, and 1.9 / 2.0, 2.0 / 3.6, 3.4 / 3.7, 6.1 / 4.3, 13.2 / 5.2 held in
-# bfloat16: up to 512 the dense form wins either way.
+# then, and 1.9 / 2.0, 2.0 / 3.6, 3.4 / 3.7, 6.1 / 4.3, 13.2 / 5.2 held in
+# bfloat16: up to 512 the dense form won either way. Those sorted timings were
+# of ``lax.ragged_dot``'s 512-row tile, the bank sliced out a layer at a time.
+# Which form each row count takes today: up to 512 rows the dense form, on any
+# platform; over 512 the sorted form, whose grouped matmuls are the Pallas
+# kernel's in a serving program on the chip and ``ragged_dot``'s elsewhere
+# (``expert_gemm_form``). Under that kernel the three grouped matmuls of a
+# 256- or 512-row call are about half what they were (PERF.md §6, PR 46: the
+# table's last four rows), so the sorted form may now overtake the dense one
+# below 512; the constant stays until the two are timed as whole blocks on
+# held stacks (ROADMAP S2(i)).
 DENSE_ROWS = 512
 
 # The standard deviation the selection bias is DRAWN with. The published model
@@ -184,25 +202,63 @@ def _gated(gate, up):
     return jax.nn.silu(gate) * up
 
 
-def experts_sorted(bank, x, weights, experts, layer=None):
+def expert_gemm_form(cfg, bank, rows: int, in_place: bool) -> str:
+    """What multiplies a call of ``rows`` tokens through the routed experts:
+    ``"dense"`` (``experts_dense``: up to ``DENSE_ROWS``), ``"ragged_dot"`` (the
+    sorted forms over the compiler's grouped GEMM) or ``"gmm<tm>"`` (the sorted
+    forms over ``ops/pallas/grouped_gemm.py`` at a row tile of ``tm``). The
+    kernel is taken where the code can see it may: on the ``tpu`` platform (on
+    the CPU every routed test would pay the Pallas interpreter), by a program
+    that only runs forward (``in_place``: the held stacks, which
+    ``transformer.expert_bank_form`` grants to serving programs alone; the kernel
+    has no backward pass), at shapes it tiles (``gmm_tiling``, from the rows of a
+    call and the bank's widths). ``moe_ffn_dropless`` traces by this and
+    ``SlotWorker`` labels its ``prefill`` and ``chunk`` spans by it
+    (``expert_gemm``)."""
+    if rows <= DENSE_ROWS:
+        return "dense"
+    if in_place and jax.default_backend() == "tpu":
+        from ..ops.pallas.grouped_gemm import gmm_tiling
+
+        count, M, F = bank["wi"].shape[-3:]
+        m = rows * cfg.moe_top_k
+        if count < cfg.num_experts:
+            m = held_chunk_rows(m, count, cfg.num_experts)
+        tiles = gmm_tiling(m, M, F), gmm_tiling(m, F, M)
+        if all(tiles):
+            return f"gmm{tiles[0][0]}"
+    return "ragged_dot"
+
+
+def _grouped_dot(xs, w, sizes, kernel: bool):
+    """xs [m, K], sorted by group, x w [G, K, N] by ``sizes`` [G]."""
+    if not kernel:
+        return lax.ragged_dot(xs, w, sizes)
+    from ..ops.pallas.grouped_gemm import gmm_tiling, grouped_matmul
+
+    return grouped_matmul(xs, w, sizes, gmm_tiling(xs.shape[0], *w.shape[1:]))
+
+
+def experts_sorted(bank, x, weights, experts, layer=None, kernel: bool = False):
     """x [T, M] through the chosen experts: pairs sorted by expert, grouped
     matmuls over the groups as they are (no capacity, no padding to one).
     ``bank``: one layer's ``[E, K, N]`` leaves or, with ``layer`` (a traced
     index), the held stacks ``[L, E, K, N]``, read in place as ``L * E`` groups
-    of which only layer ``layer``'s hold a pair."""
+    of which only layer ``layer``'s hold a pair. ``kernel``: the grouped matmuls
+    through the Pallas kernel (``expert_gemm_form``)."""
     T, M = x.shape
     k = experts.shape[1]
     flat = experts.reshape(T * k)
     order = jnp.argsort(flat)  # pairs by expert; pair p is token p // k
     if layer is not None:  # [L, E, K, N] as L * E groups: layer ``layer``'s alone are filled
-        L, E = bank["wi"].shape[:2]
-        flat = layer * E + flat
-        bank = {name: leaf.reshape((L * E,) + leaf.shape[2:]) for name, leaf in bank.items()}
+        flat = layer * bank["wi"].shape[1] + flat
+        bank = {name: leaf.reshape((-1,) + leaf.shape[2:]) for name, leaf in bank.items()}
     sizes = jnp.zeros((bank["wi"].shape[0],), jnp.int32).at[flat].add(1)
     xs = x[order // k]
     w = {name: leaf.astype(x.dtype) for name, leaf in bank.items()}
-    h = _gated(lax.ragged_dot(xs, w["wg"], sizes), lax.ragged_dot(xs, w["wi"], sizes))
-    ys = lax.ragged_dot(h, w["wo"], sizes)  # [T * k, M], still sorted
+    dot = partial(_grouped_dot, sizes=sizes, kernel=kernel)
+    h = _gated(dot(xs, w["wg"]), dot(xs, w["wi"]))
+    ys = dot(h, w["wo"])  # [T * k, M], still sorted
     ys = ys[jnp.argsort(order)].reshape(T, k, M)
     return jnp.einsum("tkm,tk->tm", ys.astype(jnp.float32), weights).astype(x.dtype)
 
@@ -215,7 +271,8 @@ def held_chunk_rows(pairs: int, count: int, num_experts: int) -> int:
     return min(-(-max(want, 1) // 256) * 256, pairs)
 
 
-def experts_sorted_held(bank, x, weights, experts, first: int, num_experts: int, layer=None):
+def experts_sorted_held(bank, x, weights, experts, first: int, num_experts: int, layer=None,
+                        kernel: bool = False):
     """``experts_sorted`` for a bank that holds experts [first, first + count) of
     the ``num_experts`` the router chose among: x [T, M], weights and experts
     [T, k] over ALL the router's experts -> the held experts' part of the routed
@@ -223,7 +280,9 @@ def experts_sorted_held(bank, x, weights, experts, first: int, num_experts: int,
     trips each take C sorted rows (``held_chunk_rows``) through the grouped
     matmuls, and every token gathers its own pairs' rows back out of the trip's
     output, one choice at a time, weighted. No pair is dropped, and none that is
-    not held is gathered or multiplied. ``layer``: as ``experts_sorted`` takes it."""
+    not held is gathered or multiplied. ``layer`` and ``kernel``: as
+    ``experts_sorted`` takes them (the kernel leaves a trip's rows past the held
+    pairs unwritten: no token gathers them)."""
     T, M = x.shape
     k = experts.shape[1]
     count = bank["wi"].shape[0 if layer is None else 1]
@@ -248,8 +307,9 @@ def experts_sorted_held(bank, x, weights, experts, first: int, num_experts: int,
         if layer is not None:
             sizes = lax.dynamic_update_slice(jnp.zeros((w["wi"].shape[0],), jnp.int32), sizes,
                                              (layer * count,))
-        h = _gated(lax.ragged_dot(xs, w["wg"], sizes), lax.ragged_dot(xs, w["wi"], sizes))
-        ys = lax.ragged_dot(h, w["wo"], sizes)  # [C, M], sorted; rows past the held: no group's
+        dot = partial(_grouped_dot, sizes=sizes, kernel=kernel)
+        h = _gated(dot(xs, w["wg"]), dot(xs, w["wi"]))
+        ys = dot(h, w["wo"])  # [C, M], sorted; rows past the held: no group's
 
         def choice(j, out):
             at = place[:, j]
@@ -304,13 +364,15 @@ def moe_ffn_dropless(cfg, moe_p, h, layer=None):
         x, moe_p["gate"], cfg.moe_top_k, cfg.moe_norm_topk_prob, score_fn=cfg.moe_score_fn,
         select_bias=moe_p.get("bias"), scale=cfg.moe_routed_scale)
     first, count = cfg.experts_held
-    if B * S <= DENSE_ROWS:
+    form = expert_gemm_form(cfg, moe_p["experts"], B * S, layer is not None)
+    kernel = form.startswith("gmm")
+    if form == "dense":
         out = experts_dense(moe_p["experts"], x, weights, experts, layer, first)
     elif count < cfg.num_experts:  # one chip's share of the experts: the held pairs alone
         out = experts_sorted_held(moe_p["experts"], x, weights, experts, first, cfg.num_experts,
-                                  layer)
+                                  layer, kernel)
     else:
-        out = experts_sorted(moe_p["experts"], x, weights, experts, layer)
+        out = experts_sorted(moe_p["experts"], x, weights, experts, layer, kernel)
     if "shared" in moe_p:
         out = out + shared_expert(moe_p["shared"], x)
     return (out.reshape(B, S, M), load_balance_loss(probs, experts),

@@ -69,6 +69,13 @@ def as_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
+# The routed feed-forward's grouped matmul in a compiled program's text: the
+# compiler's own (``lax.ragged_dot``) and the Pallas kernel's, which carries the
+# other's name as the head of its own (``ops/pallas/grouped_gemm.py::KERNEL_NAME``).
+RAGGED_DOT = r"^\s*%?ragged-dot(?!-gmm)[\w.-]* = "
+GMM_CALL = r"^\s*%?ragged-dot-gmm[\w.-]*"
+
+
 def _footprint(compiled) -> float:
     ma = compiled.memory_analysis()
     return (ma.argument_size_in_bytes + ma.output_size_in_bytes
@@ -369,7 +376,9 @@ def test_serving_programs_cast_no_stacked_weight(family, L, v5e, no_persistent_c
         if routed and name == "prefill":
             sliced = re.findall(r"^\s*%?[\w.-]+ = bf16\[64,(?:2048,1024|1024,2048)\]", text, re.M)
             assert not sliced, f"the prefill copies a layer of an expert bank out: {sliced}"
-            assert len(re.findall(r"^\s*%?ragged-dot[\w.-]* = bf16\[16384,", text, re.M)) == 3
+            # under ``as_tpu`` the three grouped matmuls are the Pallas kernel's (PR 46)
+            assert len(re.findall(GMM_CALL + r" = bf16\[16384,", text, re.M)) == 3
+            assert not re.findall(RAGGED_DOT, text, re.M)
 
 
 @pytest.mark.parametrize("family,L", [("bloom_dense_alibi", 24), ("pythia_pallas_kernel", 24),
@@ -764,11 +773,67 @@ def test_dropless_expert_block_reads_the_held_stacks_in_place(v5e, no_persistent
     for fn, slices in ((in_place, 0), (scanned, 3)):
         compiled = jax.jit(fn).lower(moe, h).compile()
         text = compiled.as_text()
-        assert len(re.findall(r"^\s*%?ragged-dot[\w.-]* = bf16\[16384,", text, re.M)) == 3
+        assert len(re.findall(RAGGED_DOT + r"bf16\[16384,", text, re.M)) == 3
         assert len(re.findall(layer_of_a_bank, text, re.M)) == slices, fn.__name__
         temps[fn.__name__] = compiled.memory_analysis().temp_size_in_bytes
     assert temps["in_place"] < bank_layer // 3, temps
     assert temps["scanned"] - temps["in_place"] > 0.9 * bank_layer, temps
+
+
+@pytest.mark.parametrize("cell,widths,rows,tile", [
+    ("olmoe", dict(hidden_size=2048, intermediate_size=1024, num_experts=64, moe_top_k=8), 2048,
+     (16384, "gmm128")),
+    ("kanana", dict(hidden_size=2048, intermediate_size=768, num_experts=128, moe_top_k=6,
+                    moe_score_fn="sigmoid", moe_shared_size=1536), 8192, (49152, "gmm128")),
+    ("k-exaone", dict(hidden_size=6144, intermediate_size=2048, num_experts=128, moe_top_k=8,
+                      moe_score_fn="sigmoid", moe_shared_size=2048, moe_experts_held=(0, 16)),
+     2048, (2560, "gmm128")),
+    ("lfm2", dict(hidden_size=2048, intermediate_size=1536, num_experts=64, moe_top_k=4,
+                  moe_score_fn="sigmoid"), 1024, (4096, "gmm128")),
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_dropless_expert_block_takes_the_grouped_matmul_kernel(cell, widths, rows, tile, v5e,
+                                                               no_persistent_cache, as_tpu):
+    """The routed block of the four routed cells (their widths, experts and
+    choices; two layers of held stacks; a prefill bucket of each) in one scan,
+    under ``as_tpu``: the sorted forms go through ``ops/pallas/grouped_gemm.py`` at
+    the tile the rule picks (PR 46), which the chip's compiler takes (its VMEM,
+    its alignment: what the interpreter cannot show). The loop body holds three
+    kernel calls over the pairs' rows (K-EXAONE's inside its loop of trips, over
+    the held chunk's), no ``ragged-dot`` of the compiler's, and no value of one
+    layer's bank."""
+    from deepspeed_tpu.models.transformer import TransformerConfig
+    from deepspeed_tpu.moe.dropless import expert_gemm_form, moe_ffn_dropless
+
+    L = 2
+    cfg = TransformerConfig(moe_routing="dropless", activation="swiglu", moe_every=1,
+                            dtype=jnp.bfloat16, **widths)
+    M, F, E, count = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts, cfg.experts_held[1]
+    one_chip = SingleDeviceSharding(v5e[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    moe = {"gate": sds((L, M, E), jnp.float32),
+           "experts": {"wg": sds((L, count, M, F), jnp.bfloat16),
+                       "wi": sds((L, count, M, F), jnp.bfloat16),
+                       "wo": sds((L, count, F, M), jnp.bfloat16)}}
+    if cfg.moe_shared_size:
+        S = cfg.moe_shared_size
+        moe["shared"] = {"wg": sds((L, M, S), jnp.bfloat16), "wi": sds((L, M, S), jnp.bfloat16),
+                         "wo": sds((L, S, M), jnp.bfloat16)}
+    pairs, form = tile
+    assert expert_gemm_form(cfg, moe["experts"], rows, True) == form
+    assert expert_gemm_form(cfg, moe["experts"], rows, False) == "ragged_dot"
+
+    def in_place(moe, h):
+        def body(h, xs):
+            scanned, l = xs
+            return h + moe_ffn_dropless(cfg, {**scanned, "experts": moe["experts"]}, h, l)[0], None
+        scanned = {name: leaf for name, leaf in moe.items() if name != "experts"}
+        return jax.lax.scan(body, h, (scanned, jnp.arange(L, dtype=jnp.int32)))[0]
+
+    text = jax.jit(in_place).lower(moe, sds((1, rows, M), jnp.bfloat16)).compile().as_text()
+    calls = re.findall(GMM_CALL + r" = bf16\[(\d+),(\d+)\]", text, re.M)
+    assert sorted(calls) == sorted([(str(pairs), str(F))] * 2 + [(str(pairs), str(M))]), calls
+    assert not re.findall(RAGGED_DOT, text, re.M)
+    assert not re.findall(rf"^\s*%?[\w.-]+ = bf16\[{count},(?:{M},{F}|{F},{M})\]", text, re.M)
 
 
 # ---------------------------------------------------------------------------

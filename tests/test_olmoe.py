@@ -465,6 +465,8 @@ def serves_a_long_prompt_in_place(srv, vocab):
     prefills = [sp for sp in spans if sp.name == "prefill"]
     assert [sp.attrs["bucket"] for sp in prefills] == [1024]
     assert all(sp.attrs["expert_bank"] == "in_place" for sp in prefills)
+    # the sorted form, through the compiler's grouped GEMM: the Pallas kernel is the chip's
+    assert all(sp.attrs["expert_gemm"] == "ragged_dot" for sp in prefills)
     assert len(spans) > 1 and not any("expert_bank" in sp.attrs for sp in spans
                                       if sp.name == "decode")
     seen = list(prompt)
@@ -486,6 +488,7 @@ def test_an_engine_over_a_mesh_that_shards_the_bank_keeps_the_slice(cfg, program
     srv.serve([serving.Request(uid=0, prompt=_tokens(cfg, (40,), 2), max_new_tokens=2)])
     prefills = [sp for sp in tracing.spans(t0) if sp.name == "prefill"]
     assert prefills and all(sp.attrs["expert_bank"] == "sliced" for sp in prefills)
+    assert all(sp.attrs["expert_gemm"] == "dense" for sp in prefills)  # 40 rows: every expert
 
 
 def test_train_batch_takes_two_steps_under_zero1(program):
