@@ -528,7 +528,7 @@ OPERATORS = ("attn", "conv")
 
 def _refuse_uncoded_operators(cfg: "TransformerConfig") -> None:
     """``_refuse_uncoded`` for ``layer_operators``: what the stacks by operator,
-    the by-kind loop and the short convolution have no code for, by name."""
+    the layer loop's static kinds and the short convolution have no code for, by name."""
     ops, L = cfg.layer_operators, cfg.num_layers
     if ops is None:
         if cfg.conv_kernel:
@@ -556,9 +556,6 @@ def _refuse_uncoded_operators(cfg: "TransformerConfig") -> None:
         "weight_bits": cfg.weight_bits != 0, "act_quant_bits": cfg.act_quant_bits != 0,
         "param_offload (the operators' stacks are not streamed)": cfg.param_offload,
         "mtp_layers": cfg.mtp_layers != 0,
-        "remat (the by-kind loop wraps no body)": cfg.remat,
-        "dropout or progressive layer drop (the by-kind loop hands no per-layer gates)":
-            bool(cfg.hidden_dropout or cfg.attn_dropout or cfg.pld_enabled),
         "GShard routing (moe_every > 0 without moe_routing='dropless')":
             cfg.moe_every > 0 and cfg.moe_routing != "dropless",
         f"attn_impl={cfg.attn_impl!r}": cfg.attn_impl != "xla",
@@ -1706,12 +1703,12 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
     - ``live`` [B, T] bool or None: the rows that are a sequence's own, for a
       model whose state-space mixer must not move on the others (``_ssm_mixer``);
     - ``kind`` (window, rotary, operator) or None: this layer's entry of
-      ``cfg.layer_kinds`` where the loop knows it statically
-      (``_layer_loop(by_kind=True)``: the cache path, whose window layers keep
-      another cache than its whole ones, and every path of a model with
-      ``layer_operators``, whose conv layers have other leaves); it is handed on to
-      ``attend``. None: a loop that scans layers of every kind at once (``apply``)
-      reads the layer's flags at the traced ``l``. A layer whose operator is
+      ``cfg.layer_kinds`` where ``_layer_loop`` tells the kinds apart as it is
+      traced (the cache path, whose window layers keep another cache than its
+      whole ones, and every path of a model with ``layer_operators``, whose conv
+      layers have other leaves); it is handed on to ``attend``. None: a model of
+      one kind, or one whose layers of every kind run in ONE scan (``apply`` with
+      window / rotary flags), read here at the traced ``l``. A layer whose operator is
       "conv" runs ``_short_conv`` in the attention sublayer's place: no q / k / v,
       no rotary, no ``attend``; its state rides in ``state`` as a mixer's does.
 
@@ -1741,7 +1738,8 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
     if cfg.rotary_layers is not None:
         rotary = kind[1] if kind is not None else jnp.asarray(cfg.rotary_layers, bool)[l]
     if kind is not None and kind[2] == "conv":
-        attn_out, state = _short_conv(cfg, lp, h, state, l, live)
+        conv_out, state = _short_conv(cfg, lp, h, state, l, live)
+        attn_out = branch(conv_out, cfg.attn_dropout, k_attn)
     else:
         q, k, v = _qkv_proj(cfg, lp, h, positions, rotary)
         attn, state = attend(q, k, v, state, l, lp, **({} if kind is None else {"kind": kind}))
@@ -1775,7 +1773,7 @@ def _stateless_attention(cfg: TransformerConfig, S: int):
         return lambda q, k, v, state, l, lp: (
             attn_fn(q, *_latent_expand(cfg, lp, k, v), bias), state)
     if cfg.local_attn_window <= 0 or cfg.local_attn_layers is None:
-        # ``kind``: the by-kind loop's (a model with ``layer_operators``); its
+        # ``kind``: what the loop hands a model with ``layer_operators``; its
         # attention layers are all of the whole-context kind
         return lambda q, k, v, state, l, lp, kind=None: (attn_fn(q, k, v, bias), state)
     is_local = jnp.asarray(cfg.local_attn_layers, bool)  # per layer
@@ -1811,242 +1809,190 @@ def _remat_wrapper(cfg: TransformerConfig):
 
 
 def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, attend,
-                per_layer=None, wrap=None, decode: bool = False, lead=None, live=None,
-                banks_in_place: bool = False, by_kind: bool = False, forward_only: bool = False):
+                per_layer=None, wrap=None, lead=None, live=None, forward_only: bool = False):
     """THE layer loop: ``_block`` over the stacked ``layers`` [L, ...] (the
     whole model's, or one pipeline stage's slice), ``moe`` the routed layers'
     stacks or None -> (x, state, summed aux loss, experts chosen or None).
 
-    Owns the stack loader, the layer index, ``scan_unroll`` and the period:
-    ``moe_every - 1`` dense layers then one routed, scanned over the
-    ``L // moe_every`` whole periods (compile time flat in depth), then the
-    ``L % moe_every`` trailing dense layers in one more scan. A dense model
-    under ``wrap`` has ``remat_group`` layers a period instead. ``wrap`` (the
-    caller's ``_remat_wrapper`` or None) goes round each scanned body;
-    ``per_layer`` holds [L]-leading ``rng`` / ``pld_keep`` for the block;
-    ``decode`` marks a single-token step for the routed feed-forward. ``lead``
-    (``params["dense_ffn"]`` or None): the gated feed-forward stacks of the
-    ``moe_first_dense`` leading layers, which run in a scan of their own before
-    the periods; ``moe`` then holds the stacks of the layers after them.
-    ``live``: the block's (the rows a state-space mixer may move on).
-    ``banks_in_place`` (a forward-only caller's, by ``expert_bank_form``): the
-    three expert banks of dropless routing are not scanned; the bodies close
-    over the held stacks and the scan carries, in their place, the routed
-    layer's position in the ``moe`` stacks (NOT the model's layer number: a
-    leading dense layer or a period of several shifts it), which the routed
-    feed-forward reads the banks through (``moe/dropless.py``). A backward
-    pass wants the scanned slice (its cotangent is one layer's), so training
-    and the pipeline stages leave it off. ``by_kind`` (the cache path's, for a
-    model whose layers are of several kinds): the loop hands every block its
-    layer's kind STATICALLY, scanning over whole periods of the kinds' pattern
-    (``_layer_loop_by_kind``). ``forward_only`` (the cache path's word that no
-    backward pass follows): that loop reads the stacks by operator in place."""
-    if by_kind and cfg.layer_kinds is not None and len(set(cfg.layer_kinds)) > 1:
-        return _layer_loop_by_kind(cfg, layers, moe, x, state, positions=positions,
-                                   attend=attend, decode=decode, lead=lead, live=live,
-                                   banks_in_place=banks_in_place, forward_only=forward_only)
-    layers_xs, load_layer = _make_stack_loader(cfg, layers)
-    routed = cfg.moe_every > 0 and moe is not None
-    moe_xs, load_moe = _make_stack_loader(cfg, moe) if routed else (None, None)
-    if routed and banks_in_place:
-        banks = moe["experts"]
-        n_routed = jax.tree.leaves(banks)[0].shape[0]
-        moe_xs = {**moe_xs, "experts": jnp.arange(n_routed, dtype=jnp.int32)}
-    L = jax.tree.leaves(layers_xs)[0].shape[0]
-    xs = (layers_xs, jnp.arange(L, dtype=jnp.int32), per_layer or {})
-    unroll = max(1, cfg.scan_unroll)
-    block = partial(_block, cfg, positions=positions, attend=attend, live=live)
-    wrap = wrap or (lambda body: body)
-    carry = (x, state)
+    Owns the stack loaders, the layer index, ``scan_unroll`` and the period. It
+    first says, as Python values, WHAT every layer IS: its kind and its
+    feed-forward. The kind is the layer's entry of ``cfg.layer_kinds`` where the
+    blocks must be told apart as the program is traced (a model with
+    ``layer_operators``, whose conv layers have other leaves; a ``forward_only``
+    caller, whose window layers keep another cache), and None otherwise: a model
+    of one kind, or window / rotary flags that ``_block`` reads at the traced
+    layer index. The feed-forward is "lead" (the ``moe_first_dense`` leading
+    layers, whose gated stacks are ``lead`` = ``params["dense_ffn"]``; ``moe``
+    then holds the stacks of the layers after them), "routed" (the last of every
+    ``moe_every`` layers after the lead, where ``moe`` is given) or "dense".
+    Behind the lead the loop finds the shortest period of that list, scans over
+    the whole periods (compile time flat in depth) and runs the layers that fill
+    no period behind them. A dense model of one kind under ``wrap`` has
+    ``remat_group`` layers a period. ONE rule runs every stretch of layers, the
+    lead, the inside of a period and the tail alike: a run of layers that are the
+    same thing is a ``lax.scan`` of the block over their slices (a scan of one
+    trip is the block itself once XLA has simplified it). So a model of one kind
+    is one scan over its stacks as they are; ``moe_every`` = 4 is a scan of (a
+    scan of three dense layers, one routed block); a leading dense layer is one
+    block before the scan; A C C C under a backward pass is a scan of (an
+    attention block, a scan of three conv layers).
 
-    def dense(carry, xs):
-        lp, l, gates = xs
-        return block(carry, load_layer(lp), l, **gates, ffn=partial(_dense_ffn, cfg))[0], None
+    ``wrap`` (the caller's ``_remat_wrapper`` or None) goes round the body of
+    every run outside the periods and round a whole period's; ``per_layer`` holds
+    [L]-leading ``rng`` / ``pld_keep`` for the block; ``live`` is the block's
+    (the rows a state-space mixer may move on).
 
-    if lead is not None:
-        lead_xs, load_lead = _make_stack_loader(cfg, lead)
-        n_lead = jax.tree.leaves(lead_xs)[0].shape[0]
+    Stacks by operator (``cfg.layer_operators``): ``layers`` then holds, beside
+    the [L]-stacked leaves every layer has, one sub-dict an operator
+    (``OPERATORS``) whose stacks are as long as that operator's layers are many.
+    Every stack is cut the same way: a run's (or a period's) share of a stack
+    begins behind the layers before it that USE the stack, so a block gets its
+    layer's slice of the first and ITS operator's slice of the second, a leading
+    layer its slice of ``lead`` and a routed one its slice of ``moe`` (NOT at the
+    model's layer number: a leading dense layer or a period of several shifts it).
 
-        def leading(carry, xs):
-            (lp, l, gates), ffn_p = xs
-            return block(carry, {**load_layer(lp), **load_lead(ffn_p)}, l, **gates,
-                         ffn=partial(_dense_ffn, cfg))[0], None
-
-        carry, _ = lax.scan(wrap(leading), carry,
-                            (jax.tree.map(lambda a: a[:n_lead], xs), lead_xs), unroll=unroll)
-        xs = jax.tree.map(lambda a: a[n_lead:], xs)
-        L -= n_lead
-
-    period = 1
-    if routed:
-        period = cfg.moe_every
-    elif wrap is not None and cfg.remat_group > 1:
-        # number_checkpoints analogue (reference checkpoint():743): boundaries
-        # saved only every remat_group layers; a whole group recomputes in backward
-        if L % cfg.remat_group:
-            import warnings
-
-            warnings.warn(
-                f"remat_group={cfg.remat_group} does not divide num_layers={L}; "
-                "falling back to per-layer activation checkpointing")
-        else:
-            period = cfg.remat_group
-    n_dense = period - int(routed)  # the dense layers at the head of a period
-
-    def group(carry, xs):
-        lxs, moe_p = xs
-        if n_dense:
-            carry, _ = lax.scan(dense, carry, jax.tree.map(lambda a: a[:n_dense], lxs),
-                                unroll=unroll)
-        if not routed:
-            return carry, None
-        lp, l, gates = jax.tree.map(lambda a: a[n_dense], lxs)
-        moe_l, bank_layer = load_moe(moe_p), None
-        if banks_in_place:  # the scan carried this layer's position where its banks would be
-            moe_l, bank_layer = {**moe_l, "experts": banks}, moe_l["experts"]
-        return block(carry, load_layer(lp), l, **gates,
-                     ffn=lambda lp, h: _moe_ffn(cfg, moe_l, h, decode, bank_layer))
-
-    G, tail = divmod(L, period)
-    if period == 1 and not routed:  # a period of one dense layer is the layer
-        carry, ys = lax.scan(wrap(dense), carry, xs, unroll=unroll)
-    else:
-        whole = jax.tree.map(lambda a: a[: G * period], xs) if tail else xs
-        grouped = jax.tree.map(lambda a: a.reshape((G, period) + a.shape[1:]), whole)
-        carry, ys = lax.scan(wrap(group), carry, (grouped, moe_xs), unroll=unroll)
-    if tail:
-        carry, _ = lax.scan(wrap(dense), carry, jax.tree.map(lambda a: a[G * period:], xs),
-                            unroll=unroll)
-    aux, chosen = ys if routed else (jnp.zeros((), jnp.float32), None)
-    return (*carry, jnp.sum(aux), chosen)
-
-
-def _layer_loop_by_kind(cfg: TransformerConfig, layers, moe, x, state, *, positions, attend,
-                        decode: bool, lead, live, banks_in_place: bool, forward_only: bool):
-    """``_layer_loop`` for a caller whose blocks must know their layer's kind as a
-    Python value (a window layer's cache is another array than a whole layer's; a
-    conv layer has other LEAVES than an attention layer). What a layer is — its
-    kind, and whether its feed-forward is a leading dense one, a dense one or the
-    routed block — is periodic in the layers after the ``lead``: the loop scans
-    over the whole periods with the period's layers inline (compile time flat in
-    depth; S S G S, or A C C C, is four blocks a body), and runs the leading layers
-    and the ``tail`` that fills no period inline, one by one. Same blocks, same
-    stacks, same order as ``_layer_loop``.
-
-    Stacks by operator (``cfg.layer_operators``): ``layers`` then holds, beside the
-    [L]-stacked leaves every layer has, one sub-dict an operator (``OPERATORS``)
-    whose stacks are as long as that operator's layers are many; a block gets its
-    layer's slice of the first and ITS operator's slice of the second, each
-    indexed as its own stack is (in a scanned period: the period's share of each
-    stack, in order). Forward and backward alike: ``apply`` takes this loop for
-    such a model. Where a backward pass follows, the scan takes the period's share
-    of an operator's stack as its xs (the slice's cotangent is one period's); a
-    ``forward_only`` caller's scan carries the layers' INDICES in the stack
-    instead and the body reads the held stack at them (as ``banks_in_place`` does
-    the expert banks): the periods' layers need not begin the stack (a leading
-    conv layer lies before them), and a slice that does not is a copy of all of
-    them in every call (192 MB a decode step at LFM2's widths)."""
-    kinds, L = cfg.layer_kinds, cfg.num_layers
+    ``forward_only`` (the cache path's word that no backward pass follows; a
+    backward pass wants the scanned slice, whose cotangent is one layer's, so
+    training and the pipeline stages leave it off) changes what is scanned, not
+    what is computed, because on the chip a slice that does not begin its stack
+    is a copy of all of it in every call (192 MB a decode step at LFM2's
+    widths). Its runs beside and inside the periods are ONE layer long, so their
+    slices are constants of the trace that the block's matmuls read in place (S S
+    S G is a scan of four blocks). The operators' stacks are not sliced at all:
+    the scans carry the layers' INDICES in them and the block reads the held
+    stack there (a period's layers need not begin the stack). Where
+    ``expert_bank_form`` says "in_place" the three expert banks of dropless
+    routing are read the same way, through the grouped GEMM's own group index
+    (``moe/dropless.py``). And a one-token block is a decode step to the routed
+    feed-forward (``_moe_ffn``)."""
     by_op = {op: layers[op] for op in OPERATORS if op in layers}  # none: ONE stack of layers
     layers_xs, load_layer = _make_stack_loader(
-        cfg, {k: v for k, v in layers.items() if k not in by_op})
-    op_of = [kind[2] for kind in kinds]
-    in_op = [op_of[:i].count(op_of[i]) for i in range(L)]  # a layer's index in its operator's stack
+        cfg, {k: v for k, v in layers.items() if k not in by_op} if by_op else layers)
+    L = jax.tree.leaves(layers_xs)[0].shape[0]
     routed_model = cfg.moe_every > 0 and moe is not None
-    moe_xs, load_moe = _make_stack_loader(cfg, moe) if routed_model else (None, None)
-    lead_xs, load_lead = _make_stack_loader(cfg, lead) if lead is not None else (None, None)
-    n_lead = 0 if lead is None else jax.tree.leaves(lead_xs)[0].shape[0]
     every = max(cfg.moe_every, 1)
-    if routed_model and banks_in_place:
-        banks = moe["experts"]
-        n_routed = jax.tree.leaves(banks)[0].shape[0]
-        moe_xs = {**moe_xs, "experts": jnp.arange(n_routed, dtype=jnp.int32)}
+    n_lead = 0 if lead is None else jax.tree.leaves(lead)[0].shape[0]
+    decode = forward_only and x.shape[1] == 1
+    # every stack the blocks read, by the name of what uses it (``uses``)
+    stacks = {"layers": (layers_xs, jnp.arange(L, dtype=jnp.int32), per_layer or {})}
+    load_lead = load_moe = banks = None
+    if lead is not None:
+        stacks["lead"], load_lead = _make_stack_loader(cfg, lead)
+    if routed_model:
+        stacks["routed"], load_moe = _make_stack_loader(cfg, moe)
+        if forward_only and expert_bank_form(cfg, moe) == "in_place":
+            banks = moe["experts"]  # the scans carry the routed layer's position in their place
+            n_routed = jax.tree.leaves(banks)[0].shape[0]
+            stacks["routed"] = {**stacks["routed"],
+                                "experts": jnp.arange(n_routed, dtype=jnp.int32)}
+    for op, stack in by_op.items():
+        stacks[op] = (jnp.arange(jax.tree.leaves(stack)[0].shape[0], dtype=jnp.int32)
+                      if forward_only else stack)
 
-    def ffn_kind(i):
+    kinds = cfg.layer_kinds if by_op or forward_only else None
+
+    def feed(i):
         if i < n_lead:
             return "lead"
         return "routed" if routed_model and (i - n_lead) % every == every - 1 else "dense"
 
-    what = [(kinds[i], ffn_kind(i)) for i in range(L)]
+    what = [(kinds[i] if kinds else None, feed(i)) for i in range(L)]
+    uses = lambda name, w: name in ("layers", w[1]) or (w[0] is not None and name == w[0][2])
     n = L - n_lead
-    period = next((p for p in range(every, n, every)
-                   if all(what[n_lead + j] == what[n_lead + j + p] for j in range(n - p))), n)
+    period = next((p for p in range(1, n) if what[n_lead:L - p] == what[n_lead + p:]), n)
+    if period == 1 and not routed_model and wrap is not None and cfg.remat_group > 1:
+        # number_checkpoints analogue (reference checkpoint():743): boundaries
+        # saved only every remat_group layers; a whole group recomputes in backward
+        if n % cfg.remat_group:
+            import warnings
+
+            warnings.warn(
+                f"remat_group={cfg.remat_group} does not divide num_layers={n}; "
+                "falling back to per-layer activation checkpointing")
+        else:
+            period = cfg.remat_group
     G, tail = divmod(n, period) if period else (0, 0)
+    unroll = max(1, cfg.scan_unroll)
     block = partial(_block, cfg, positions=positions, attend=attend, live=live)
+    unwrapped = lambda body: body  # noqa: E731
+    wrap = wrap or unwrapped
 
-    def one(carry, i, lp, l, extra):
-        """Layer ``i``'s block (``i`` a Python int: what the layer is; ``l`` its
-        index as the stacks are indexed, traced inside a scan)."""
-        kind, feed = what[i]
+    def one(w, carry, xs):
+        """The block of a layer that is ``w``, on its slices ``xs`` of the stacks it uses."""
+        kind, fed = w
+        lp, l, gates = xs["layers"]
         lp = load_layer(lp)
-        if feed == "routed":
-            moe_l, bank_layer = load_moe(extra), None
-            if banks_in_place:  # ``extra`` carried this layer's position where its banks would be
-                moe_l, bank_layer = {**moe_l, "experts": banks}, moe_l["experts"]
-            return block(carry, lp, l, kind=kind,
-                         ffn=lambda lp, h: _moe_ffn(cfg, moe_l, h, decode, bank_layer))
-        if feed == "lead":
-            lp = {**lp, **load_lead(extra)}
-        return block(carry, lp, l, kind=kind, ffn=partial(_dense_ffn, cfg))
-
-    at = lambda tree, i: jax.tree.map(lambda a: a[i], tree)
-    routed_of = lambda i: (i - n_lead) // every  # a routed layer's position in the moe stacks
-    carry, ys = (x, state), []
-
-    def inline(carry, i):
-        feed = what[i][1]
-        extra = (at(lead_xs, i) if feed == "lead" else
-                 at(moe_xs, routed_of(i)) if feed == "routed" else None)
-        lp = at(layers_xs, i)
         if by_op:
-            lp = {**lp, **at(by_op[op_of[i]], in_op[i])}
-        carry, y = one(carry, i, lp, jnp.int32(i), extra)
-        if feed == "routed":
-            ys.append(jax.tree.map(lambda a: a[None], y))
-        return carry
+            mine = xs[kind[2]]
+            lp = {**lp, **(mine if not forward_only else jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, mine, 0, keepdims=False), by_op[kind[2]]))}
+        if fed == "lead":
+            lp = {**lp, **load_lead(xs["lead"])}
+        ffn = partial(_dense_ffn, cfg)
+        if fed == "routed":
+            moe_l, bank_layer = load_moe(xs["routed"]), None
+            if banks is not None:  # this layer's position came where its banks would be
+                moe_l, bank_layer = {**moe_l, "experts": banks}, moe_l["experts"]
+            ffn = lambda lp, h: _moe_ffn(cfg, moe_l, h, decode, bank_layer)  # noqa: E731
+        carry, y = block(carry, lp, l, **gates, ffn=ffn, kind=kind)
+        return carry, (y if fed == "routed" else None)
 
-    for i in range(n_lead):
-        carry = inline(carry, i)
-    if G:
-        lo, hi = n_lead, n_lead + G * period
-        per_group = lambda a, r: a.reshape((G, r) + a.shape[1:])
-        grouped = jax.tree.map(lambda a: per_group(a[lo:hi], period),
-                               (layers_xs, jnp.arange(L, dtype=jnp.int32)))
-        n_r = sum(what[lo + j][1] == "routed" for j in range(period))  # routed layers a period
-        moe_g = (jax.tree.map(lambda a: per_group(a[:G * n_r], n_r), moe_xs) if n_r else None)
-        ops_g = {}  # each operator's layers of the periods: from its first there, a period's share
-        for op, stack in by_op.items():
-            n_op, first = op_of[lo:lo + period].count(op), op_of[:lo].count(op)
-            ops_g[op] = (jnp.arange(first, first + G * n_op, dtype=jnp.int32).reshape(G, n_op)
-                         if forward_only else jax.tree.map(
-                             lambda a: per_group(a[first:first + G * n_op], n_op), stack))
+    def share(held, first, lo, hi, groups=None):
+        """Of stacks ``held``, whose first layer is the model's ``first``: the slices
+        of layers lo..hi, each stack cut behind the layers before ``lo`` that use it;
+        with ``groups`` as [groups, a period's share, ...] of as many periods."""
+        out = {}
+        for name, tree in held.items():
+            before, count = (sum(uses(name, w) for w in what[a:b])
+                             for a, b in ((first, lo), (lo, hi)))
+            if not count:
+                continue
+            count *= groups or 1
 
-        def group(carry, xs):
-            (lxs, ls), ops_p, moe_p = xs
-            out = []
-            for j in range(period):
-                routed = what[lo + j][1] == "routed"
-                extra = at(moe_p, len(out)) if routed else None
-                lp, op = at(lxs, j), op_of[lo + j]
-                if by_op:  # the period's j-th layer: its operator's next within the period
-                    mine = at(ops_p[op], op_of[lo:lo + j].count(op))
-                    lp = {**lp, **(mine if not forward_only else jax.tree.map(
-                        lambda a: lax.dynamic_index_in_dim(a, mine, 0, keepdims=False),
-                        by_op[op]))}
-                carry, y = one(carry, lo + j, lp, ls[j], extra)
-                if routed:
-                    out.append(y)
-            return carry, (jax.tree.map(lambda *a: jnp.stack(a), *out) if out else None)
+            def cut(a):
+                a = a if (before, count) == (0, a.shape[0]) else a[before:before + count]
+                return a if groups is None else a.reshape((groups, count // groups) + a.shape[1:])
 
-        carry, got = lax.scan(group, carry, (grouped, ops_g, moe_g),
-                              unroll=max(1, cfg.scan_unroll))
-        if n_r:
-            ys.append(jax.tree.map(lambda a: a.reshape((G * n_r,) + a.shape[2:]), got))
-    for i in range(L - tail, L):
-        carry = inline(carry, i)
+            out[name] = jax.tree.map(cut, tree)
+        return out
+
+    def run(carry, held, first, lo, hi, wrap):
+        """Layers lo..hi, all the same thing: ONE scan of the block over their slices
+        -> (carry, [what they chose] where they are routed)."""
+        carry, y = lax.scan(wrap(partial(one, what[lo])), carry, share(held, first, lo, hi),
+                            unroll=unroll)
+        return carry, [y] if what[lo][1] == "routed" else []
+
+    def stretch(carry, held, first, lo, hi, wrap=unwrapped):
+        """Layers lo..hi beside the scan over the periods, or inside one, run by run."""
+        ys = []
+        while lo < hi:
+            end = lo + 1 if forward_only else next(
+                (i for i in range(lo, hi) if what[i] != what[lo]), hi)
+            carry, got = run(carry, held, first, lo, end, wrap)
+            ys, lo = ys + got, end
+        return carry, ys
+
+    join = lambda ys: ys[0] if len(ys) == 1 else jax.tree.map(lambda *a: jnp.concatenate(a), *ys)
+    lo, hi = n_lead, L - tail
+    carry, ys = stretch((x, state), stacks, 0, 0, lo, wrap)
+    if period == 1:  # a period of one layer is the layer: one run over the stacks as they are
+        carry, got = run(carry, stacks, 0, lo, hi, wrap)
+        ys += got
+    elif G:
+        def group(carry, held):
+            carry, ys = stretch(carry, held, lo, lo, lo + period)
+            return carry, (join(ys) if ys else None)
+
+        carry, got = lax.scan(wrap(group), carry, share(stacks, 0, lo, lo + period, G),
+                              unroll=unroll)
+        if got is not None:
+            ys.append(jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), got))
+    carry, got = stretch(carry, stacks, 0, hi, L, wrap)
+    ys += got
     if not ys:
         return (*carry, jnp.zeros((), jnp.float32), None)
-    aux, chosen = jax.tree.map(lambda *a: jnp.concatenate(a), *ys)
+    aux, chosen = join(ys)
     return (*carry, jnp.sum(aux), chosen)
 
 
@@ -2146,9 +2092,7 @@ def apply(
     x, _, aux_total, chosen = _layer_loop(
         cfg, params["layers"], params.get("moe"), x, None, positions=positions,
         attend=_stateless_attention(cfg, S), per_layer=per_layer, wrap=_remat_wrapper(cfg),
-        lead=params.get("dense_ffn"),
-        # layers of two operators have different leaves: no ONE scan over traced flags
-        by_kind=cfg.layer_operators is not None)
+        lead=params.get("dense_ffn"))
     mtp = ()
     if mtp_tokens is not None:
         mtp, module_chosen = mtp_logits(cfg, params, x, mtp_tokens, positions)
@@ -2197,8 +2141,8 @@ def _moe_ffn(cfg, moe_p, h, decode: bool = False, bank_layer=None):
     its capacity semantics except at a single-token ``decode`` step, where
     the capacity heuristic degenerates to ~1 slot and drops colliding tokens;
     dropless routing is one function everywhere. ``bank_layer`` (dropless
-    routing, ``_layer_loop(banks_in_place=True)``): ``moe_p["experts"]`` are the
-    held stacks and this the layer's position in them."""
+    routing with the banks read in place, ``_layer_loop(forward_only=True)``):
+    ``moe_p["experts"]`` are the held stacks and this the layer's position in them."""
     with jax.named_scope("moe"):
         if cfg.moe_routing == "dropless":
             from ..moe.dropless import moe_ffn_dropless
@@ -2561,7 +2505,7 @@ def expert_bank_form(cfg: TransformerConfig, moe, mesh=None):
     """``"in_place"`` or ``"sliced"`` (None for a model without dropless
     routing): where a program that only runs the forward pass through the cache
     reads a routed layer's three expert banks from, decided from what it is
-    handed. In place (``_layer_loop(banks_in_place=True)``: layer ``l`` read
+    handed. In place (``_layer_loop(forward_only=True)`` asks here: layer ``l`` read
     through the grouped GEMM's own group index out of the held ``[L, E, K, N]``
     stacks, no per-layer copy) unless that would cost more than the copy it
     saves: a bank leaf that is not a plain array of the compute dtype (a
@@ -2763,7 +2707,7 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
         if in_kind is not None:
             if kind is None:
                 raise ValueError("a cache with rings, or of the attention layers alone, needs "
-                                 "the layer's kind: _layer_loop(by_kind=True)")
+                                 "the layer's kind: _layer_loop(forward_only=True)")
             l = in_kind[l]
             if kind[0]:
                 return ring_attend(q, k, v, stacks, l, kind[0])
@@ -2857,9 +2801,8 @@ def apply_with_cache(
     x, _ = embed(cfg, params, tokens, positions)
     x, cache, _, chosen = _layer_loop(
         cfg, params["layers"], params.get("moe"), x, dict(cache),
-        positions=positions, attend=attend, decode=T == 1, lead=params.get("dense_ffn"),
-        live=live, banks_in_place=expert_bank_form(cfg, params.get("moe")) == "in_place",
-        by_kind=True, forward_only=True)
+        positions=positions, attend=attend, lead=params.get("dense_ffn"), live=live,
+        forward_only=True)
     if last_index is not None:
         # bucketed prefill: the live last token sits at ``last_index``
         # (prompt_len - 1), not at T-1 — project only that position

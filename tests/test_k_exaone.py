@@ -7,10 +7,6 @@ plain reference ``chipbench/references/exaone_moe.py`` (itself held to
 configuration's rehearsal sizes on the CPU, seeded weights, float32 unless a test
 says bfloat16."""
 
-import json
-import os
-import subprocess
-import sys
 from unittest import mock
 
 import jax
@@ -18,56 +14,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+from k_exaone_cases import (  # noqa: F401,I001 -- first: puts the repo's root on sys.path; fixtures
+    WHOLE, TOL, WINDOW, _config, program, reference, cfg, params, _tokens)
 
-import deepspeed_tpu  # noqa: E402,F401
 from chipbench import kinds_cost, parity  # noqa: E402
 from chipbench.drivers import serve_kinds  # noqa: E402
 from chipbench.references import Program, load_reference, program_of  # noqa: E402
-from deepspeed_tpu.inference.serving import Request  # noqa: E402
-from deepspeed_tpu.launcher.serving_worker import build_serving_engine  # noqa: E402
 from deepspeed_tpu.models import transformer as tfm  # noqa: E402
 from deepspeed_tpu.moe import dropless  # noqa: E402
-from deepspeed_tpu.telemetry import tracing  # noqa: E402
-
-WHOLE = lambda leaves: leaves  # noqa: E731
-TOL = parity.TOL["apply"]  # float32 on both sides, summation order alone
-CONFIG = "k-exaone-236b-a23b-L5"
-WINDOW = 16  # the kinds twin's
-
-
-def _config():
-    with open(os.path.join(ROOT, "chipbench", "configs", f"{CONFIG}.json")) as f:
-        return json.load(f)
-
-
-@pytest.fixture(scope="module")
-def program():
-    return program_of(_config(), "rehearse_kinds_program")
-
-
-@pytest.fixture(scope="module")
-def reference(program):
-    return load_reference(program)
-
-
-@pytest.fixture(scope="module")
-def cfg(program):
-    return tfm.TransformerConfig(dtype=jnp.float32, **program)
-
-
-@pytest.fixture(scope="module")
-def params(cfg):
-    return parity._seeded_params(tfm, cfg)  # noise on every leaf: norm scales count too
-
-
-def _tokens(cfg, shape, seed=0):
-    return np.random.default_rng(seed).integers(0, cfg.vocab_size, size=shape).astype(np.int32)
-
-
-def _bucket(n: int) -> int:
-    return max(16, 1 << (n - 1).bit_length())
 
 
 # -- the layout -----------------------------------------------------------------------------------
@@ -107,66 +61,6 @@ def test_apply_matches_the_reference_and_returns_its_choices(cfg, params, progra
         assert np.std(ref["logits"]) > 0.3
         assert np.max(np.abs(np.asarray(got[row]) - ref["logits"])) <= TOL
         np.testing.assert_array_equal(np.sort(np.asarray(chosen)[:, row]), np.sort(ref["own"]))
-
-
-# shorter than the window, the window exactly, one more, several windows; each padded to a bucket
-@pytest.mark.parametrize("n", [5, 16, 17, 50, 200])
-def test_every_step_through_the_kinds_cache_matches_the_reference(cfg, params, program,
-                                                                   reference, n):
-    """The probe of the chip's check (bucket-padded prefill under the live-row
-    mask into a local cache, ``update_cache_slot``, 8 decode steps at per-row
-    positions): the ring holds the last 16 LIVE rows, not the bucket's last, and
-    the steps wrap it."""
-    prompts = [_tokens(cfg, (n,), n), _tokens(cfg, (max(n - 3, 1),), n + 1)]
-    forced = _tokens(cfg, (2, serve_kinds.DECODE_STEPS), n + 2)
-    got, chosen = serve_kinds.probe_logits(cfg, params, prompts, [_bucket(n)] * 2, forced)
-    for j, (p, f) in enumerate(zip(prompts, forced)):
-        rows = np.arange(len(p) - 1, len(p) + serve_kinds.DECODE_STEPS)
-        ref = reference.routed_pass(program, params, np.concatenate([p, f]), rows, fetch=WHOLE,
-                                    routing=chosen[j])
-        assert np.max(np.abs(got[j] - ref["logits"])) <= TOL and ref["slack"] <= 1e-4
-
-
-def _decode(cfg, params, cache, slot, start, tokens, n_rows=3):
-    """Greedy-free decode of ``tokens`` at row ``slot`` from position ``start``,
-    the other rows idle (position 0, their write dropped) -> logits per step."""
-    out = []
-    for i, t in enumerate(tokens):
-        toks = np.zeros((n_rows,), np.int32)
-        pos = np.zeros((n_rows,), np.int32)
-        wpos = np.full((n_rows,), tfm.cache_len(cache), np.int32)
-        toks[slot], pos[slot], wpos[slot] = t, start + i, start + i
-        logits, cache = tfm.apply_with_cache(cfg, params, toks[:, None], cache,
-                                             jnp.asarray(pos), write_pos=jnp.asarray(wpos))
-        out.append(np.asarray(logits[slot, 0]))
-    return np.stack(out), cache
-
-
-def _prefill(cfg, params, cache, slot, prompt):
-    n, bucket = len(prompt), _bucket(len(prompt))
-    padded = np.zeros((1, bucket), np.int32)
-    padded[0, :n] = prompt
-    local = tfm.init_cache(cfg, 1, bucket)
-    logits, local = tfm.apply_with_cache(cfg, params, padded, local, 0, last_index=n - 1,
-                                         live=jnp.arange(bucket)[None, :] < n)
-    return np.asarray(logits[0, 0]), tfm.update_cache_slot(cache, local, slot)
-
-
-def test_a_slot_reused_by_a_shorter_request_reads_nothing_of_the_last(cfg, params):
-    """A 90-token request, then a 7-token one in the same slot, idle rows riding
-    along: every logit is ``apply``'s of the second sequence alone (the ring's
-    stale entries hold positions the mask counts as never written)."""
-    long, short = _tokens(cfg, (90,), 1), _tokens(cfg, (40,), 2)
-    cache = tfm.init_cache(cfg, 3, 128)
-    _, cache = _prefill(cfg, params, cache, 1, long)
-    _, cache = _decode(cfg, params, cache, 1, 90, _tokens(cfg, (5,), 3))
-    first, cache = _prefill(cfg, params, cache, 1, short[:7])
-    steps, cache = _decode(cfg, params, cache, 1, 7, short[7:])  # past two wraps of the ring
-    want = np.asarray(tfm.apply(cfg, params, short[None]))[0]
-    assert np.max(np.abs(first - want[6])) <= TOL
-    assert np.max(np.abs(steps - want[7:])) <= TOL
-    # the idle rows' writes were dropped: their rings are as they were made
-    assert not np.asarray(cache[tfm.RING]["k"])[:, [0, 2]].any()
 
 
 def test_loss_matches_the_reference(cfg, params, program, reference):
@@ -397,28 +291,6 @@ def test_reference_attention_in_query_blocks_is_the_whole_matrix(program, refere
     np.testing.assert_allclose(many, one, atol=2e-5)
 
 
-# -- GPT-Neo's local layers are the same thing -----------------------------------------------------
-
-
-@pytest.mark.parametrize("decode_attn", ["xla", "kernel"])
-def test_gpt_neo_local_layers_are_served_through_the_same_rings(decode_attn):
-    """GPT-Neo's alternating local attention (learned positions, multi-head, a
-    LayerNorm block) through ``apply_with_cache``, which refused it before this
-    PR: a ring for the local layers, ``Smax`` (and, where asked, the Pallas decode
-    kernel) for the global ones; every logit is ``apply``'s."""
-    cfg = tfm.TransformerConfig(vocab_size=211, max_seq_len=128, num_layers=4, num_heads=4,
-                                hidden_size=64, local_attn_window=8,
-                                local_attn_layers=(0, 1, 0, 1), decode_attn=decode_attn)
-    params = parity._seeded_params(tfm, cfg)
-    tokens = _tokens(cfg, (45,), 8)
-    want = np.asarray(tfm.apply(cfg, params, tokens[None]))[0]
-    cache = tfm.init_cache(cfg, 3, 128)
-    assert cache["k"].shape[0] == 2 and cache[tfm.RING]["k"].shape[:3] == (2, 3, 8)
-    first, cache = _prefill(cfg, params, cache, 1, tokens[:21])
-    steps, _ = _decode(cfg, params, cache, 1, 21, tokens[21:])
-    assert np.max(np.abs(first - want[20])) <= 2e-5 and np.max(np.abs(steps - want[21:])) <= 2e-5
-
-
 # -- what has no code is refused by name -----------------------------------------------------------
 
 _REFUSED = {
@@ -447,170 +319,6 @@ def test_combinations_without_code_are_refused_by_name(program, case):
     extra, word = _REFUSED[case]
     with pytest.raises((NotImplementedError, ValueError), match=word):
         tfm.TransformerConfig(**{**program, **extra})
-
-
-def test_the_cache_path_refuses_what_a_ring_cannot_carry(cfg, params):
-    cache = tfm.init_cache(cfg, 1, 64)
-    block = _tokens(cfg, (1, 8))
-    with pytest.raises(NotImplementedError, match="past position 0"):  # a chunk, a verify block
-        tfm.apply_with_cache(cfg, params, block, cache, jnp.asarray([20]))
-    with pytest.raises(ValueError, match="live"):  # a padded block with no live-row mask
-        tfm.apply_with_cache(cfg, params, block, tfm.init_cache(cfg, 1, 8), 0, last_index=4)
-    alibi = tfm.TransformerConfig(vocab_size=64, max_seq_len=64, num_layers=2, num_heads=2,
-                                  hidden_size=32, pos_emb="alibi", local_attn_window=4,
-                                  local_attn_layers=(1, 0), decode_attn="xla")
-    with pytest.raises(NotImplementedError, match="alibi"):
-        tfm.apply_with_cache(alibi, tfm.init(alibi, jax.random.PRNGKey(0)),
-                             np.zeros((1, 4), np.int32), tfm.init_cache(alibi, 1, 4), 0)
-
-
-def _spec(program, **serving):
-    return {"model": {**program, "dtype": "float32"}, "engine_dtype": "fp32",
-            "serving": {"n_slots": 3, "max_seq_len": 128, "seed": 0, "watchdog_mode": "off",
-                        **serving}}
-
-
-@pytest.mark.parametrize("what,block", [
-    ("prefix_cache", {"prefix_cache": {"enabled": True, "n_slots": 2}}),
-    ("chunked_prefill", {"chunked_prefill": {"enabled": True, "chunk_size": 16}}),
-    ("speculation", {"speculation": {"enabled": True}}),
-    ("role", {"role": "prefill"}),
-])
-def test_the_engine_refuses_at_build_what_moves_the_cache_by_position(program, what, block):
-    with pytest.raises(NotImplementedError, match="window layers"):
-        build_serving_engine(_spec(program, **block))
-
-
-# -- the serving engine ----------------------------------------------------------------------------
-
-
-@pytest.fixture(scope="module")
-def served(program):
-    srv = build_serving_engine(_spec(program))
-    cfg = srv.engine.cfg
-    prompts = [_tokens(cfg, (n,), n) for n in (40, 9, 70)]
-    mark = tracing.spans(0.0)[-1].t1 if tracing.spans(0.0) else 0.0
-    results = srv.serve([Request(uid=i, prompt=p, max_new_tokens=12)
-                         for i, p in enumerate(prompts)])
-    return srv, prompts, results, [sp for sp in tracing.spans(0.0) if sp.t0 >= mark]
-
-
-def test_serving_engine_serves_the_models_tokens(served):
-    """Through ``build_serving_engine`` / ``ServingEngine.step`` / ``SlotWorker``
-    like any other model: three requests of three buckets share the slots; every
-    token is the argmax of ``apply`` on what came before it."""
-    srv, prompts, results, _ = served
-    cfg, params = srv.engine.cfg, srv.engine.params
-    for i, p in enumerate(prompts):
-        got = np.asarray(results[i].tokens)
-        assert results[i].status == "ok" and len(got) == 12
-        logits = np.asarray(tfm.apply(cfg, params, np.concatenate([p, got])[None]))[0]
-        want = logits[len(p) - 1:len(p) + 11]
-        gap = want.max(axis=-1) - want[np.arange(12), got]
-        assert gap.max() <= 1e-4, gap
-    assert srv.compile_counts()["decode"] == 1
-
-
-def test_spans_and_pools_say_what_was_read(served):
-    srv, prompts, _, spans = served
-    pools = srv.worker.hbm_pools()
-    assert pools["slot_kv_cache"] == 1 * 3 * 128 * tfm.cache_bytes_per_token(srv.engine.cfg)
-    assert pools["slot_kv_ring"] == 3 * tfm.cache_ring_bytes(srv.engine.cfg)
-    prefills = [sp for sp in spans if sp.name == "prefill"]
-    decodes = [sp for sp in spans if sp.name == "decode"]
-    assert {sp.attrs["attn"] for sp in prefills} == {"dense+window"}
-    assert {sp.attrs["attn"] for sp in decodes} == {"dense+ring"}
-    for sp in prefills + decodes:
-        assert sp.attrs["window_layers"] == 5 and sp.attrs["experts_held"] == 4
-        assert 0 < sp.attrs["experts_touched"] <= 4 and sp.attrs["expert_rows_held"] > 0
-    by_len = {sp.attrs["true_len"]: sp for sp in prefills}
-    for p in prompts:  # a prefill's queries each read min(position + 1, window) of a ring
-        n = len(p)
-        assert by_len[n].attrs["ring_tokens"] == sum(min(i + 1, WINDOW) for i in range(n))
-        assert by_len[n].attrs["ring_tokens"] == kinds_cost.window_pairs(n, WINDOW)
-    full = [sp for sp in decodes if sp.attrs["n_active"] == 3]
-    assert full and all(sp.attrs["ring_tokens"] <= 3 * WINDOW < sp.attrs["cached_tokens"]
-                        for sp in full[2:])
-    with pytest.raises(NotImplementedError, match="window layers"):
-        srv.worker.kv_export(16, 0, 0)
-
-
-# -- a prefill through a flash+window bucket (PR 40: the banded forward) --------------------------
-
-LONG = 1024  # a bucket whose band is narrower than its causal grid at the band's blocks (512, 128)
-
-
-@pytest.fixture
-def flash_from_1024_rows(monkeypatch, cfg):
-    """``cache_attention_form``'s rule met at 1,024 rows of the twin's 4 heads (the
-    cell meets it from 1,024 rows at 64): the bucket attends through the flash
-    kernel, its window layers through the band."""
-    monkeypatch.setattr(tfm, "DENSE_SCORE_BYTES", 4 * cfg.num_heads * LONG ** 2 - 1)
-    assert tfm.cache_block_form(cfg, LONG) == "flash+window"
-    assert tfm.cache_block_form(cfg, LONG // 2) == "dense+window"
-
-
-def test_a_flash_window_prefill_is_the_reference_and_writes_the_parents_rings(
-        cfg, params, program, reference, flash_from_1024_rows, monkeypatch):
-    """A 700-token prompt padded to 1,024 rows: the window layers take the banded
-    forward (the window a constant of the trace). Its logits are the reference's,
-    and logits and rings are those of the parent's form (the whole causal grid
-    under the window as a runtime operand: ``static_window`` made to see no
-    constant) and of the dense form under a [T, T] bias."""
-    from deepspeed_tpu.ops.pallas import flash_attention as fa
-
-    prompt = _tokens(cfg, (700,), 40)
-    band_logits, band_cache = _prefill(cfg, params, tfm.init_cache(cfg, 2, LONG), 1, prompt)
-    ref = reference.routed_pass(program, params, prompt, np.arange(699, 700), fetch=WHOLE)
-    assert np.max(np.abs(band_logits - ref["logits"][0])) <= TOL
-
-    def rings(cache):
-        return [np.asarray(cache[tfm.RING][name]) for name in ("k", "v")]
-
-    assert all(r[:, 1].any() and not r[:, 0].any() for r in rings(band_cache))
-    with monkeypatch.context() as parent:
-        parent.setattr(fa, "static_window", lambda window, *shape: (window, 0))
-        whole_logits, whole_cache = _prefill(cfg, params, tfm.init_cache(cfg, 2, LONG), 1, prompt)
-    with monkeypatch.context() as dense:
-        dense.setattr(tfm, "DENSE_SCORE_BYTES", 2 ** 40)
-        dense_logits, dense_cache = _prefill(cfg, params, tfm.init_cache(cfg, 2, LONG), 1, prompt)
-    for logits, cache in ((whole_logits, whole_cache), (dense_logits, dense_cache)):
-        assert np.max(np.abs(band_logits - logits)) <= TOL
-        for got, want in zip(rings(band_cache), rings(cache)):
-            np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
-
-
-def test_the_engine_serves_a_flash_window_bucket_and_says_which_grid(program,
-                                                                     flash_from_1024_rows):
-    """Through ``build_serving_engine`` with a 1,024-long slot cache: a 700-token
-    request's prefill goes through the banded forward and its tokens are the
-    argmax of ``apply``; the prefill span says which grid the window layers took
-    and how much of the causal grid it computes; a short request's bucket attends
-    densely and says nothing of a grid."""
-    from deepspeed_tpu.ops.pallas import flash_attention as fa
-
-    spec = _spec({**program, "max_seq_len": LONG})
-    spec["serving"]["max_seq_len"] = LONG
-    srv = build_serving_engine(spec)
-    cfg = srv.engine.cfg
-    prompts = [_tokens(cfg, (700,), 41), _tokens(cfg, (30,), 42)]
-    mark = tracing.spans(0.0)[-1].t1 if tracing.spans(0.0) else 0.0
-    results = srv.serve([Request(uid=i, prompt=p, max_new_tokens=6) for i, p in enumerate(prompts)])
-    for i, p in enumerate(prompts):
-        res = results[i]
-        got = np.asarray(res.tokens)
-        assert res.status == "ok" and len(got) == 6
-        logits = np.asarray(tfm.apply(cfg, srv.engine.params, np.concatenate([p, got])[None]))[0]
-        want = logits[len(p) - 1:len(p) + 5]
-        assert (want.max(axis=-1) - want[np.arange(6), got]).max() <= 1e-4
-    by_bucket = {sp.attrs["bucket"]: sp.attrs for sp in tracing.spans(0.0)
-                 if sp.name == "prefill" and sp.t0 >= mark}
-    long, short = by_bucket[LONG], by_bucket[32]
-    assert (long["attn"], long["window_grid"]) == ("flash+window", "band")
-    blocks_pct = fa.window_grid(LONG, WINDOW, cfg.num_heads, cfg.head_dim, cfg.head_dim,
-                                jnp.dtype(cfg.dtype).itemsize)[1]
-    assert long["window_blocks_pct"] == round(blocks_pct, 2) < 100
-    assert short["attn"] == "dense+window" and "window_grid" not in short
 
 
 # -- the counts at the published widths ------------------------------------------------------------
@@ -654,18 +362,3 @@ def test_counts_at_the_published_widths():
     outside = counts["matmul_outside_experts"]
     assert need == (outside + 4 * 16 * 37_748_736) * 2 + (32 * 4500 + 4 * 32 * 128) * 4096
     assert abs(4 * 16 * 37_748_736 * 2 / need - 0.60) < 0.02  # the held experts: most of a step
-
-
-# -- the cell's rehearsal ---------------------------------------------------------------------------
-
-
-def test_the_cells_rehearsal_passes_and_lists_its_metrics():
-    proc = subprocess.run(
-        [sys.executable, "-m", "chipbench.run", "--workload", f"{CONFIG}.serve-mixedlen",
-         "--rehearse", "--trace", "1"], cwd=ROOT, capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
-    last = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert last["rehearsal"] == "passed" and last["correct"] and last["failed"] == 0
-    for name in ("slot_cache_bytes_per_slot", "kinds_decode_hbm_floor_pct", "kinds_prefill_mfu_pct",
-                 "moe_load_max_over_mean", "compiles_in_window.doc", "decode_host_transfers"):
-        assert name in last["would_report"], last["would_report"]

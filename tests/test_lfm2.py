@@ -8,78 +8,27 @@ plain reference ``chipbench/references/lfm2_moe.py`` (itself held to
 configuration's rehearsal sizes on the CPU, seeded weights, float32 unless a test
 says bfloat16."""
 
-import inspect
-import json
-import os
-import subprocess
-import sys
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+from lfm2_cases import (  # noqa: F401,I001 -- first: puts the repo's root on sys.path; fixtures
+    WHOLE, TOL, _config, program, reference, cfg, params, _tokens, _PLANTED, _plant)
 
-import deepspeed_tpu  # noqa: E402,F401
 from chipbench import conv_cost, parity  # noqa: E402
-from chipbench.drivers import serve_latent, serve_shortconv  # noqa: E402
-from chipbench.layer_metrics import (  # noqa: E402
-    conv_decode_hbm_floor_pct, conv_prefill_mfu_pct, kv_bytes_per_token_model,
-    recurrent_state_bytes_per_slot)
+from chipbench.drivers import serve_shortconv  # noqa: E402
 from chipbench.references import Program, load_reference, program_of  # noqa: E402
-from deepspeed_tpu.inference.serving import Request  # noqa: E402
-from deepspeed_tpu.launcher.serving_worker import build_serving_engine  # noqa: E402
 from deepspeed_tpu.models import transformer as tfm  # noqa: E402
-from deepspeed_tpu.moe import dropless  # noqa: E402
-from deepspeed_tpu.telemetry import tracing  # noqa: E402
 
-WHOLE = lambda leaves: leaves  # noqa: E731
-TOL = parity.TOL["apply"]  # float32 on both sides, summation order alone
-CONFIG = "lfm2-24b-a2b-L9"
+
 OPS = ["conv", "attn", "conv", "conv", "conv", "attn", "conv"]  # the twin's
-STEPS = serve_shortconv.serve_latent.DECODE_STEPS
-
-
-def _config():
-    with open(os.path.join(ROOT, "chipbench", "configs", f"{CONFIG}.json")) as f:
-        return json.load(f)
-
-
-@pytest.fixture(scope="module")
-def program():
-    return program_of(_config(), serve_shortconv.TWIN)
-
-
-@pytest.fixture(scope="module")
-def reference(program):
-    return load_reference(program)
-
-
-@pytest.fixture(scope="module")
-def cfg(program):
-    return tfm.TransformerConfig(dtype=jnp.float32, **program)
-
-
-@pytest.fixture(scope="module")
-def params(cfg):
-    return parity._seeded_params(tfm, cfg)  # noise on every leaf: norm scales count too
-
-
-def _tokens(cfg, shape, seed=0):
-    return np.random.default_rng(seed).integers(0, cfg.vocab_size, size=shape).astype(np.int32)
-
-
-def _bucket(n: int) -> int:
-    return max(16, 1 << (n - 1).bit_length())
 
 
 # -- the layout -----------------------------------------------------------------------------------
 
 
-def test_layout_is_stacks_by_operator_and_a_cache_by_kind(cfg, params, program):
+def test_layout_is_stacks_by_operator_and_a_cache_by_layer_kind(cfg, params, program):
     assert program["layer_operators"] == OPS and cfg.conv_kernel == 3
     C, A = (0, True, "conv"), (0, True, "attn")  # (window, rotary, operator)
     assert cfg.layer_kinds == (C, A, C, C, C, A, C) and cfg.conv_layers == (0, 2, 3, 4, 6)
@@ -145,110 +94,6 @@ def test_apply_matches_the_reference_and_returns_its_choices(cfg, params, progra
         np.testing.assert_array_equal(np.sort(np.asarray(chosen)[:, row]), np.sort(ref["own"]))
 
 
-# prompts of 1, 2 and 3 rows (fewer than, as many as, one more than the state's rows), a
-# bucket's worth, and past one; each padded to its bucket (the second prompt 3 shorter)
-@pytest.mark.parametrize("n", [1, 2, 3, 4, 16, 17, 50, 200])
-def test_every_step_through_the_two_kind_cache_matches_the_reference(cfg, params, program,
-                                                                      reference, n):
-    """The probe of the chip's check (bucket-padded prefill under the live-row mask
-    into a local cache, ``update_cache_slot``, 8 decode steps at per-row
-    positions): the state a conv layer hands the steps is that of the last two
-    LIVE rows, not the bucket's last two."""
-    prompts = [_tokens(cfg, (n,), n), _tokens(cfg, (max(n - 3, 1),), n + 1)]
-    forced = _tokens(cfg, (2, STEPS), n + 2)
-    got, chosen = serve_shortconv.probe_logits(cfg, params, prompts, [_bucket(n)] * 2, forced)
-    for j, (p, f) in enumerate(zip(prompts, forced)):
-        rows = np.arange(len(p) - 1, len(p) + STEPS)
-        ref = reference.routed_pass(program, params, np.concatenate([p, f]), rows, fetch=WHOLE,
-                                    routing=chosen[j])
-        assert np.max(np.abs(got[j] - ref["logits"])) <= TOL and ref["slack"] <= 1e-4
-
-
-def _decode(cfg, params, cache, slot, start, tokens, n_rows=3):
-    """Decode ``tokens`` at row ``slot`` from position ``start``, the other rows idle
-    as ``SlotWorker`` rides them (position 0, their write dropped, not live) ->
-    logits per step."""
-    out = []
-    for i, t in enumerate(tokens):
-        toks = np.zeros((n_rows,), np.int32)
-        pos = np.zeros((n_rows,), np.int32)
-        wpos = np.full((n_rows,), tfm.cache_len(cache), np.int32)
-        toks[slot], pos[slot], wpos[slot] = t, start + i, start + i
-        logits, cache = tfm.apply_with_cache(
-            cfg, params, toks[:, None], cache, jnp.asarray(pos), write_pos=jnp.asarray(wpos),
-            live=jnp.asarray(np.arange(n_rows) == slot)[:, None])
-        out.append(np.asarray(logits[slot, 0]))
-    return np.stack(out), cache
-
-
-def _prefill(cfg, params, cache, slot, prompt, bucket=None):
-    n, bucket = len(prompt), bucket or _bucket(len(prompt))
-    padded = np.zeros((1, bucket), np.int32)
-    padded[0, :n] = prompt
-    local = tfm.init_cache(cfg, 1, bucket)
-    logits, local = tfm.apply_with_cache(cfg, params, padded, local, 0, last_index=n - 1,
-                                         live=jnp.arange(bucket)[None, :] < n)
-    return np.asarray(logits[0, 0]), tfm.update_cache_slot(cache, local, slot), local
-
-
-@pytest.mark.parametrize("n", [1, 2, 3, 9])
-def test_the_state_is_the_last_two_live_rows_of_the_filters_input(cfg, params, n):
-    """A prompt of ``n`` rows padded to 16 leaves each conv layer the state an
-    unpadded block of exactly ``n`` rows leaves (zero rows in front where the
-    prompt is shorter than two), and padding drawn from other tokens changes
-    nothing of it."""
-    prompt = _tokens(cfg, (n,), n)
-    _, _, padded = _prefill(cfg, params, tfm.init_cache(cfg, 1, 32), 0, prompt, bucket=16)
-    exact = tfm.init_cache(cfg, 1, n)
-    _, exact = tfm.apply_with_cache(cfg, params, prompt[None], exact, 0)
-    state = np.asarray(padded[tfm.STATE]["conv"])
-    assert state.shape == (5, 1, 2, 64)
-    np.testing.assert_allclose(state, np.asarray(exact[tfm.STATE]["conv"]), atol=2e-5)
-    assert np.abs(state[:, 0, -1]).min() > 0  # the last live row's input
-    assert (n >= 2) == bool(np.abs(state[:, 0, 0]).max() > 0)  # before the start: zero
-    noisy = np.full((1, 16), 7, np.int32)
-    noisy[0, :n] = prompt
-    local = tfm.init_cache(cfg, 1, 16)
-    _, local = tfm.apply_with_cache(cfg, params, noisy, local, 0, last_index=n - 1,
-                                    live=jnp.arange(16)[None, :] < n)
-    np.testing.assert_array_equal(np.asarray(local[tfm.STATE]["conv"]), state)
-
-
-def test_a_slot_reused_by_a_shorter_request_and_idle_rows_riding_the_steps(cfg, params):
-    """A 90-token request, then a 1-token one in the same slot, while another slot
-    holds a prefilled sequence that only rides along (idle: not live): every logit
-    of the second request is ``apply``'s of it alone, the riding slot's state is
-    untouched by the eight steps, and its own steps afterwards are ``apply``'s."""
-    long, short, other = _tokens(cfg, (90,), 1), _tokens(cfg, (12,), 2), _tokens(cfg, (30,), 3)
-    cache = tfm.init_cache(cfg, 3, 128)
-    _, cache, _ = _prefill(cfg, params, cache, 1, long)
-    _, cache, _ = _prefill(cfg, params, cache, 2, other[:20])
-    parked = np.asarray(cache[tfm.STATE]["conv"])[:, 2].copy()
-    _, cache = _decode(cfg, params, cache, 1, 90, _tokens(cfg, (5,), 4))
-    first, cache, _ = _prefill(cfg, params, cache, 1, short[:1])  # a prompt of ONE row
-    steps, cache = _decode(cfg, params, cache, 1, 1, short[1:9])  # eight steps
-    want = np.asarray(tfm.apply(cfg, params, short[None]))[0]
-    assert np.max(np.abs(first - want[0])) <= TOL
-    assert np.max(np.abs(steps - want[1:9])) <= TOL
-    state = np.asarray(cache[tfm.STATE]["conv"])
-    np.testing.assert_array_equal(state[:, 2], parked)  # rode thirteen steps: moved by none
-    assert not state[:, 0].any() and not np.asarray(cache["k"])[:, 0].any()  # never used
-    rest, cache = _decode(cfg, params, cache, 2, 20, other[20:28])
-    want = np.asarray(tfm.apply(cfg, params, other[None]))[0]
-    assert np.max(np.abs(rest - want[20:28])) <= TOL
-
-
-def test_a_row_not_marked_idle_moves_its_state(cfg, params):
-    """The control of the test above: without ``live`` the riding row's state moves."""
-    cache = tfm.init_cache(cfg, 2, 64)
-    _, cache, _ = _prefill(cfg, params, cache, 1, _tokens(cfg, (20,), 3))
-    parked = np.asarray(cache[tfm.STATE]["conv"])[:, 1].copy()
-    pos = jnp.asarray([0, 0])
-    _, cache = tfm.apply_with_cache(cfg, params, np.asarray([[5], [9]], np.int32), cache, pos,
-                                    write_pos=jnp.asarray([0, 64]))
-    assert np.abs(np.asarray(cache[tfm.STATE]["conv"])[:, 1] - parked).max() > 1e-3
-
-
 def test_loss_matches_the_reference_and_has_a_gradient_in_every_stack(cfg, params, program,
                                                                       reference):
     tokens = _tokens(cfg, (2, 97), 4)
@@ -293,26 +138,6 @@ def test_float32_passes_where_the_controls_fail(cfg, params, program, reference)
     assert all(errs[k] <= parity.TOL[k] for k in errs), errs
 
 
-_PLANTED = {  # one line of ``_short_conv`` / ``_filter_tail`` / the configuration wrong
-    "the input gate dropped": ("_short_conv", "u = gate_in * z", "u = z"),
-    "the output gate dropped": ("_short_conv", "gate_out * c.astype(h.dtype)",
-                                "c.astype(h.dtype)"),
-    "the taps out of order": ("_causal_filter", "* taps[j] for j", "* taps[K - 1 - j] for j"),
-    "the state taken from the padding": ("_filter_tail", "if live is None else",
-                                         "if True else"),
-}
-
-
-def _plant(monkeypatch, fault):
-    """``tfm``'s function with one line replaced, as the module would have it."""
-    name, old, new = _PLANTED[fault]
-    source = inspect.getsource(getattr(tfm, name))
-    assert source.count(old) == 1, (name, old)
-    scope = dict(vars(tfm))
-    exec(source.replace(old, new), scope)  # noqa: S102 -- the module's own source, one line changed
-    monkeypatch.setattr(tfm, name, scope[name])
-
-
 @pytest.mark.parametrize("fault", list(_PLANTED))
 def test_a_planted_fault_fails_the_tolerance(cfg, params, program, reference, monkeypatch, fault):
     """Apply and the cache path both miss by far (a state from the padding: the
@@ -321,52 +146,6 @@ def test_a_planted_fault_fails_the_tolerance(cfg, params, program, reference, mo
     errs = _errors(cfg, params, program, reference)
     assert errs["cache"] > 30 * TOL, errs
     assert (errs["apply"] > 30 * TOL) == (fault != "the state taken from the padding"), errs
-
-
-# -- the chip's check: small for bfloat16 compute, large for what it must catch ------------------
-
-
-class _Run:
-    """What ``serve_latent._check`` reads of the harness's run."""
-
-    cell = {"serving": {}}
-
-    def __init__(self, program, seed):
-        self.program, self.seed = program, seed
-
-    def sized(self, block):
-        return {"deployment": {"max_seq_len": 256, "n_slots": 4}}[block]
-
-
-def _check(program, dtype="bfloat16", seed=7):
-    """The driver's check (``serve_shortconv.run``'s prompts and probe in
-    ``serve_latent._check``) on an engine built as the cell builds it."""
-    srv = build_serving_engine({
-        "model": {**program, "dtype": dtype},
-        "engine_dtype": {"float32": "fp32", "bfloat16": "bf16"}[dtype],
-        "serving": {"n_slots": 4, "max_seq_len": 256, "seed": 1, "watchdog_mode": "off"}})
-    with serve_shortconv.as_this_cell():
-        return serve_latent._check(_Run(program, seed), srv, Request)
-
-
-def test_the_chips_check_passes_float32_compute_by_far(program):
-    out = _check(program, "float32")
-    assert out["ok"] and out["check_buckets"] == [128, 256, 256, 256], out
-    assert out["logit_max_abs_err"] < 1e-4 and out["routing_slack"] < 1e-3
-
-
-@pytest.mark.parametrize("fault", list(_PLANTED))
-def test_a_planted_fault_fails_the_chips_check(program, monkeypatch, fault):
-    """By ``serve_latent``'s two-part rule at the cell's own limits, in float32
-    (and so in any precision): the engine serves the faulty program, the probe
-    runs it too, and the reference keeps the architecture."""
-    _plant(monkeypatch, fault)
-    out = _check(program, "float32")
-    assert not out["ok"], out
-    assert (out["logit_tol"], out["routing_tol"]) == (serve_shortconv.LOGIT_TOL,
-                                                      serve_shortconv.ROUTING_TOL)
-    assert max(out["logit_max_abs_err"], out["token_gap_to_reference_top"]) \
-        > 1.5 * serve_shortconv.LOGIT_TOL or out["routing_slack"] > 1.5 * serve_shortconv.ROUTING_TOL, out
 
 
 # -- the reference against the published code -----------------------------------------------------
@@ -526,80 +305,6 @@ def test_falcon_h1s_twin_still_matches_its_reference():
         assert parity.error("falcon-h1-34b-L4", surface) <= parity.TOL[surface]
 
 
-# -- the lead shifts the routed position (PR 34's fault, planted) ----------------------------------
-
-
-@pytest.mark.parametrize("rows", [48, 520], ids=["dense_form", "sorted_form"])
-def test_the_stack_index_is_the_routed_layers_not_the_models(cfg, params, rows, monkeypatch):
-    """Behind ONE leading dense layer the model's layer 1 is routed stack 0, inline
-    and in the scanned periods alike: the in-place programs (one chip) give the
-    sliced programs' logits, and with the model's layer number in the index's
-    place they do not."""
-    tokens = _tokens(cfg, (1, rows), rows)
-
-    def run():
-        cache = tfm.init_cache(cfg, 1, rows)
-        return tfm.apply_with_cache(cfg, params, tokens, cache, 0, last_only=True)[0]
-
-    sliced = run()
-    seen = []
-    real = dropless.moe_ffn_dropless
-
-    def spy(c, p, h, layer=None):
-        seen.append(layer is not None)
-        return real(c, p, h, layer)
-
-    monkeypatch.setattr(tfm, "_ACTIVE_MESH", [None])  # one chip: the banks read in place
-    monkeypatch.setattr(tfm, "expert_bank_form", lambda *a, **k: "in_place")
-    with monkeypatch.context() as m:
-        m.setattr(dropless, "moe_ffn_dropless", spy)
-        in_place = run()
-    assert seen and all(seen)
-    np.testing.assert_allclose(np.asarray(in_place), np.asarray(sliced), atol=2e-5)
-    with monkeypatch.context() as m:
-        m.setattr(dropless, "moe_ffn_dropless",
-                  lambda c, p, h, layer=None: real(c, p, h, None if layer is None
-                                                   else jnp.minimum(layer + 1, 5)))
-        off_by_the_lead = run()
-    assert float(jnp.max(jnp.abs(off_by_the_lead - sliced))) > 1e-2
-
-
-def test_the_forward_only_loop_reads_the_operator_stacks_where_they_lie(cfg, params):
-    """The cache path (forward only) hands its scanned periods the layers' indices
-    and reads the held stacks at them; ``apply`` (a backward pass may follow) scans
-    the periods' slices. Same logits."""
-    def stacks_scanned(fn, *args):
-        text = str(jax.make_jaxpr(fn)(*args))
-        scans = [line for line in text.splitlines() if " scan[" in line]
-        return text.count("f32[1,3,64,192]"), len(scans)  # a period's share of conv_in: [G, n, ...]
-
-    tokens = _tokens(cfg, (1, 24))
-    fwd_bwd = stacks_scanned(lambda p: tfm.apply(cfg, p, tokens), params)
-    cache = tfm.init_cache(cfg, 1, 24)
-    fwd = stacks_scanned(lambda p: tfm.apply_with_cache(cfg, p, tokens, cache, 0)[0], params)
-    assert fwd_bwd[0] > 0 and fwd[0] == 0
-    got = tfm.apply_with_cache(cfg, params, tokens, cache, 0)[0]
-    np.testing.assert_allclose(np.asarray(got), np.asarray(tfm.apply(cfg, params, tokens)), atol=2e-5)
-
-
-def test_the_dense_form_batches_over_experts_from_64_rows(cfg, params):
-    """``experts_dense`` at 63 rows (the rows handed over once for all experts) and
-    at 64 (once an expert: a batched product, which reads each expert's bank where
-    it lies): the same numbers, and the jaxpr says which form each took."""
-    bank = jax.tree.map(lambda a: a[1], params["moe"]["experts"])
-    n = dropless.BATCHED_ROWS
-    x = jax.random.normal(jax.random.PRNGKey(0), (n, 64))
-    weights = jax.random.uniform(jax.random.PRNGKey(1), (n, 4))
-    experts = jnp.argsort(jax.random.uniform(jax.random.PRNGKey(2), (n, 16)))[:, :4].astype(jnp.int32)
-    whole = dropless.experts_dense(bank, x, weights, experts)
-    fewer = dropless.experts_dense(bank, x[:-1], weights[:-1], experts[:-1])
-    assert float(jnp.std(whole)) > 0.01
-    np.testing.assert_allclose(np.asarray(whole[:-1]), np.asarray(fewer), atol=2e-5)
-    batched = lambda *a: str(jax.make_jaxpr(dropless.experts_dense)(bank, *a)).count(  # noqa: E731
-        "dimension_numbers=(([2], [1]), ([0], [0]))")  # e a batch dimension of both operands
-    assert batched(x, weights, experts) == 3 and batched(x[:-1], weights[:-1], experts[:-1]) == 1
-
-
 # -- refusals, by name ------------------------------------------------------------------------------
 
 _REFUSED = {
@@ -620,8 +325,6 @@ _REFUSED = {
     "activation quantisation": (dict(act_quant_bits=8), "act_quant_bits"),
     "param offload": (dict(param_offload=True), "param_offload"),
     "a prediction module": (dict(mtp_layers=1), "mtp_layers"),
-    "remat": (dict(remat=True), "remat"),
-    "dropout": (dict(hidden_dropout=0.1), "dropout"),
     "flash training": (dict(attn_impl="flash"), "attn_impl='flash'"),
     "one operator for every layer": (dict(layer_operators=["attn"] * 7), "BOTH operators"),
     "an operator of no kind": (dict(layer_operators=["conv", "mamba"] + OPS[2:]), "layer_operators"),
@@ -648,145 +351,46 @@ def test_the_loops_that_know_one_stack_refuse_the_operators(cfg, params):
                              last_index=4)
 
 
-def _spec(program, dtype="float32", **serving):
-    return {"model": {**program, "dtype": dtype},
-            "engine_dtype": {"float32": "fp32", "bfloat16": "bf16"}[dtype],
-            "serving": {"n_slots": 3, "max_seq_len": 128, "seed": 0, "watchdog_mode": "off",
-                        **serving}}
+# -- what the by-kind loop refused and the ONE loop runs (PR 47): wrap and per-layer gates ----------
 
 
-@pytest.mark.parametrize("what,block", [
-    ("prefix_cache", {"prefix_cache": {"enabled": True, "n_slots": 2}}),
-    ("speculation", {"speculation": {"enabled": True}}),
-    ("serving role 'prefill'", {"role": "prefill"}),
-    ("serving role 'decode'", {"role": "decode"}),
-])
-def test_the_engine_refuses_at_build_what_moves_the_cache_by_position(program, what, block):
-    with pytest.raises(NotImplementedError, match=what):
-        build_serving_engine(_spec(program, **block))
-
-
-# -- the serving engine ----------------------------------------------------------------------------
+def _loss_and_grads(cfg, params, rng=None, grads=True):
+    loss = lambda p: tfm.causal_lm_loss(cfg, p, {"tokens": _tokens(cfg, (2, 49), 4)}, rng=rng)  # noqa: E731
+    return jax.jit(jax.value_and_grad(loss) if grads else loss)(params)
 
 
 @pytest.fixture(scope="module")
-def served(program):
-    srv = build_serving_engine(_spec(program))
-    cfg = srv.engine.cfg
-    prompts = [_tokens(cfg, (n,), n) for n in (40, 1, 70, 2, 9)]  # five requests, three slots
-    t0 = time.perf_counter()
-    results = srv.serve([Request(uid=i, prompt=p, max_new_tokens=12)
-                         for i, p in enumerate(prompts)])
-    return srv, prompts, results, tracing.spans(t0)
+def plain(cfg, params):
+    return _loss_and_grads(cfg, params)
 
 
-def test_serving_engine_serves_the_models_tokens(served):
-    """Through ``build_serving_engine`` / ``ServingEngine.step`` / ``SlotWorker`` like
-    any other model: five requests share three slots (two are reused, by a
-    shorter and by a longer request); every token is the argmax of ``apply`` on
-    what came before it."""
-    srv, prompts, results, _ = served
-    cfg, params = srv.engine.cfg, srv.engine.params
-    for i, p in enumerate(prompts):
-        got = np.asarray(results[i].tokens)
-        assert results[i].status == "ok" and len(got) == 12
-        logits = np.asarray(tfm.apply(cfg, params, np.concatenate([p, got])[None]))[0]
-        want = logits[len(p) - 1:len(p) + 11]
-        gap = want.max(axis=-1) - want[np.arange(12), got]
-        assert gap.max() <= 1e-4, (i, gap)
-    assert srv.compile_counts()["decode"] == 1
+def test_remat_changes_neither_the_loss_nor_a_gradient(cfg, params, plain):
+    """Activation checkpointing round every body the loop scans (the lead, a whole
+    period A C C C, the tail): the same loss and the same gradient in every stack."""
+    loss, grads = _loss_and_grads(cfg.replace(remat=True), params)
+    assert abs(float(loss) - float(plain[0])) <= 1e-5
+    worst = jax.tree.map(lambda a, b: float(jnp.max(jnp.abs(a - b))), grads, plain[1])
+    assert max(jax.tree.leaves(worst)) <= 1e-5, worst
 
 
-def test_spans_and_pools_say_what_was_kept_and_read(served):
-    srv, prompts, _, spans = served
-    w, cfg = srv.worker, srv.engine.cfg
-    per_slot = 5 * 2 * 64 * 4  # conv layers x rows x channels x float32
-    pools = w.hbm_pools()
-    assert pools["slot_state"] == 3 * per_slot and w.state_bytes_per_slot == per_slot
-    assert w.state_layers == 5 and "slot_kv_ring" not in pools
-    assert pools["slot_kv_cache"] == 2 * 3 * 128 * tfm.cache_bytes_per_token(cfg)  # TWO layers'
-    ctx = {"worker": w, "program": program_of(_config(), serve_shortconv.TWIN)}
-    assert recurrent_state_bytes_per_slot.read(ctx) == per_slot
-    assert kv_bytes_per_token_model.read(ctx) == 2 * tfm.cache_bytes_per_token(cfg)
-    assert kv_bytes_per_token_model.read({**ctx, "program": {"num_layers": 2}}) is None
-    prefills = [sp for sp in spans if sp.name == "prefill"]
-    decodes = [sp for sp in spans if sp.name == "decode"]
-    assert len(prefills) == 5 and decodes
-    for sp in prefills + decodes:
-        assert sp.attrs["conv_layers"] == 5 and sp.attrs["attn_layers"] == 2
-        assert sp.attrs["attn"] == "dense" and 0 < sp.attrs["experts_touched"] <= 16
-        assert sp.attrs["expert_load_max_over_mean"] >= 1
-    for sp in decodes:
-        assert sp.attrs["state_rows"] == sp.attrs["n_active"]
-        assert sp.attrs["state_bytes"] == 2 * sp.attrs["n_active"] * per_slot
-    by_len = {sp.attrs["true_len"]: sp.attrs for sp in prefills}
-    assert sorted(by_len) == [1, 2, 9, 40, 70]
-    assert all(a["state_rows"] == n and a["state_bytes"] == per_slot and
-               a["expert_bank"] in ("in_place", "sliced") for n, a in by_len.items())
-    full = [sp for sp in decodes if sp.attrs["n_active"] == 3]
-    assert full and all(sp.attrs["cached_tokens"] >= 3 for sp in full)  # ONE attention layer's
-    with pytest.raises(NotImplementedError, match="kv_export"):
-        w.kv_export(16, 0, 0)
-    with pytest.raises(NotImplementedError, match="kv_import"):
-        w.kv_import(16, None, None, 0, 0)
-
-
-def test_chunked_prefill_carries_the_state(program, reference):
-    """Chunks of 16 rows through the ``chunk`` programs: each starts from the state
-    the last one left (from nothing at position 0, whatever the slot held) and
-    moves it on its live rows only; the tokens are the reference's."""
-    srv = build_serving_engine(_spec(program, chunked_prefill={"enabled": True, "chunk_size": 16}))
-    rng = np.random.default_rng(8)
-    reqs = [Request(uid=i, prompt=rng.integers(0, program["vocab_size"], size=n).astype(np.int32),
-                    max_new_tokens=5) for i, n in enumerate([50, 17, 33, 2, 90])]
-    results = srv.serve(reqs)
-    assert srv.compile_counts()["chunk_prefill"]
-    for r in reqs:
-        got = np.asarray(results[r.uid].tokens, np.int32)
-        assert results[r.uid].status == "ok" and len(got) == 5
-        ref = reference.logits_at(program, srv.engine.params, np.concatenate([r.prompt, got[:-1]]),
-                                  np.arange(len(r.prompt) - 1, len(r.prompt) + 4), fetch=WHOLE)
-        assert float(np.max(ref.max(axis=-1) - ref[np.arange(5), got])) < TOL, len(r.prompt)
-
-
-# -- the readers and the counts at the published widths --------------------------------------------
-
-
-def test_the_readers_count_by_operator(monkeypatch):
-    """Two prefills and three decode steps on a hand-made ring at the published
-    widths: the floor counts K/V in the TWO attention layers and the state moved,
-    the MFU attention in two layers and the filter in seven; spans without the
-    operators' attributes give nothing."""
-    from types import SimpleNamespace
-
-    program = program_of(_config())
-
-    def call(i, name, t0, t1, **attrs):
-        sp = lambda j, parent, n, a, b, **kw: SimpleNamespace(  # noqa: E731
-            id=j, parent=parent, name=n, path="serve/step/" + n, t0=a, t1=b, attrs=kw)
-        return [sp(i, None, name, t0, t1, compiled=False, **attrs),
-                sp(i + 1, i, "dispatch", t0, t0 + 1e-4), sp(i + 2, i, "fetch", t0 + 1e-4, t1)]
-
-    ops = dict(conv_layers=7, attn_layers=2)
-    step = dict(cached_tokens=128 * 800, state_bytes=2 * 128 * 57344, experts_touched=64.0, **ops)
-    ring = (call(1, "prefill", 100.0, 100.010, bucket=256, **ops)
-            + call(4, "prefill", 100.02, 100.05, bucket=2048, **ops)
-            + call(7, "prefill", 100.06, 100.07, bucket=512)  # a program without the operator
-            + call(10, "decode", 100.10, 100.13, **step) + call(13, "decode", 100.14, 100.17, **step)
-            + call(16, "decode", 100.18, 100.20, cached_tokens=5))
-    monkeypatch.setattr(tracing, "spans", lambda since=float("-inf"): [
-        sp for sp in ring if sp.t1 >= since])
-    notes = []
-    ctx = {"serve": {"epoch": 0.0, "window": (99.0, 101.0)}, "program": program,
-           "peak": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
-           "run": SimpleNamespace(note=lambda **kw: notes.append(kw))}
-    flops = conv_cost.prefill_flops(program, 256) + conv_cost.prefill_flops(program, 2048)
-    assert conv_prefill_mfu_pct.read(ctx) == pytest.approx(100 * flops / 197e12 / 0.040)
-    need = conv_cost.decode_min_bytes(program, 128 * 800, 2 * 128 * 57344, 64.0)
-    assert conv_decode_hbm_floor_pct.read(ctx) == pytest.approx(100 * need / 819e9 / 0.030)
-    assert {n["program"] for n in notes} == {"prefill", "decode"}
-    plain = {**ctx, "program": program_of(_config(), "rehearse_program")}
-    assert conv_prefill_mfu_pct.read(plain) is None and conv_decode_hbm_floor_pct.read(plain) is None
+@pytest.mark.parametrize("case", ["every layer kept", "dropout"])
+def test_the_per_layer_gates_reach_every_layer_of_both_operators(cfg, params, plain, case):
+    """``rng`` / ``pld_keep`` are cut like every other stack. Layer drop at keep
+    probability 1 draws a gate for every layer and keeps them all: the loss without
+    it. Dropout (on the conv sublayer's output as on the attention's) moves the loss
+    and leaves a gradient in every stack."""
+    rng = jax.random.PRNGKey(3)
+    if case == "every layer kept":
+        kept = cfg.replace(pld_enabled=True, pld_theta=1.0)
+        assert abs(float(_loss_and_grads(kept, params, rng, grads=False)) - float(plain[0])) <= 1e-5
+        return
+    dropped = cfg.replace(hidden_dropout=0.2, attn_dropout=0.2)
+    assert abs(float(_loss_and_grads(dropped, params)[0]) - float(plain[0])) <= 1e-5  # no rng
+    loss, grads = _loss_and_grads(dropped, params, rng)
+    assert np.isfinite(float(loss)) and abs(float(loss) - float(plain[0])) > 1e-3
+    sizes = jax.tree.map(lambda g: float(jnp.abs(g).max()), grads)
+    assert min(jax.tree.leaves(sizes["layers"])) > 0 and sizes["dense_ffn"]["wi"] > 0
+    assert sizes["moe"]["gate"] > 0
 
 
 def test_counts_at_the_published_widths():
@@ -834,19 +438,3 @@ def test_counts_at_the_published_widths():
     assert config["reduced"] == ["num_hidden_layers", "num_dense_layers"]
     ran = [config["layer_types"][i] for i in (0, *range(2, 10))]
     assert ["conv" if t == "conv" else "attn" for t in ran] == program["layer_operators"]
-
-
-# -- the cell's rehearsal ---------------------------------------------------------------------------
-
-
-def test_the_cells_rehearsal_passes_and_lists_its_metrics():
-    proc = subprocess.run(
-        [sys.executable, "-m", "chipbench.run", "--workload", f"{CONFIG}.serve-longgen",
-         "--rehearse", "--trace", "1"], cwd=ROOT, capture_output=True, text=True, timeout=900)
-    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
-    last = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert last["rehearsal"] == "passed" and last["correct"] and last["failed"] == 0
-    for name in ("kv_bytes_per_token_model", "conv_decode_hbm_floor_pct", "conv_prefill_mfu_pct",
-                 "recurrent_state_bytes_per_slot", "moe_load_max_over_mean",
-                 "compiles_in_window.doc", "decode_host_transfers"):
-        assert name in last["would_report"], last["would_report"]

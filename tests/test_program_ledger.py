@@ -583,6 +583,29 @@ def test_check_tier1_budget_exit_codes(tmp_path, capsys):
     assert "FAIL" in out.err and "t::slowest" in out.out
 
 
+@pytest.mark.parametrize("case,rows,code,says", [
+    # six workers, whole files each: ONE long file decides the run, not the total / 6
+    ("one_long_file", [("tests/a.py::x", 900.0), ("tests/a.py::y", 700.0),
+                       ("tests/b.py::x", 200.0)], 1, "longest file 1600s"),
+    # many even files: the total over the workers decides, and it fits
+    ("even_files", [(f"tests/f{i}.py::x", 300.0) for i in range(12)], 0, "= 600s"),
+    # inside, but one slow host away: the band is the deciding number's, not the total's
+    ("drift_edge", [("tests/a.py::x", 1400.0), ("tests/b.py::x", 100.0)], 0, "= 1400s"),
+])
+def test_check_tier1_budget_holds_the_longest_file_under_workers(tmp_path, capsys, case, rows,
+                                                                code, says):
+    """``--workers``: what decides the driver's run is max(longest file, total / workers)."""
+    chk = _load_budget_checker()
+    led = str(tmp_path / "durations.jsonl")
+    _write_durations(led, rows)
+    assert chk.main(["--durations", led, "--budget", "1470", "--workers", "6",
+                     "--min-tests", "0"]) == code
+    out = capsys.readouterr()
+    assert says in out.out and f"the longest files are {rows[0][0].split('::')[0]}" in out.out
+    assert ("FAIL" in out.err) == (code == 1)
+    assert ("WARNING" in out.err) == (case == "drift_edge")
+
+
 def test_conftest_writes_durations_ledger():
     """The hook in THIS session has been recording: the previous suite run's
     ledger (if any) parses, and the in-memory buffer for the current run is

@@ -1014,11 +1014,26 @@ def test_anatomy_reader_with_nothing_to_read(monkeypatch, name):
     assert reader.read(ctx) is None and not notes
 
 
-def test_chipbench_selftest():
-    """The yardstick's own checks: every per_layer entry of BENCHMARK.json
-    against its reader file, the trace reduction, flops, traffic."""
-    proc = subprocess.run([sys.executable, "-m", "chipbench.selftest"], cwd=ROOT,
-                          env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
-                          text=True, timeout=300)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "FAIL" not in proc.stdout and proc.stdout.count("ok ") == 6
+_SELFTEST_PARTS = ["check_intervals", "check_handmade_trace", "check_recorded_trace", "check_flops",
+                   "check_traffic", "check_files", "check_trace_window", "check_references"]
+
+
+@pytest.mark.parametrize("part", _SELFTEST_PARTS)
+def test_chipbench_selftest(part, monkeypatch):
+    """The yardstick's own checks, part by part and in this process: every per_layer
+    entry of BENCHMARK.json against its reader file, the trace reduction, flops,
+    traffic, the trace window, and what the references refuse. ``check_files`` ends
+    in ``check_references``, whose second half is ``tests/test_reference_parity.py``
+    case for case (each configuration against its reference, and bfloat16 failing
+    the tolerance): here the first half alone (every block has a reference that
+    covers it, and ``COVERS`` refuses by name), once. ``python3 -m chipbench.selftest``
+    still runs all of it (by hand, before a chip call: ``chipbench/README.md``)."""
+    from chipbench import parity, selftest
+
+    if part == "check_references":
+        monkeypatch.setattr(parity, "cases", lambda: [])  # that half: test_reference_parity.py
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            selftest.check_references(json.load(f))
+    else:
+        monkeypatch.setattr(selftest, "check_references", lambda bench: None)  # check_files' tail
+        getattr(selftest, part)()
