@@ -19,6 +19,8 @@ TPU-first design choices:
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import math
 from dataclasses import dataclass
@@ -29,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 Params = Any
 
@@ -155,10 +158,15 @@ class TransformerConfig:
     act_quant_symmetric: bool = True
     remat: bool = False  # activation checkpointing over the layer scan
     # Remat policy names: any jax.checkpoint_policies attr, plus
-    #   "save_flash"      — save only the flash kernel's out/lse residuals so
-    #                       the Pallas forward never re-runs in backward
+    #   "save_flash"      — the LEAST that is saved: the flash kernel's out/lse
+    #                       residuals, so the Pallas forward never re-runs in
+    #                       backward. A training engine whose device has room
+    #                       adds a dense feed-forward's pre-activation as it
+    #                       traces its step (remat_candidates; from shapes and
+    #                       the device's memory limit, runtime/remat_plan.py)
     #   "dots_and_flash"  — dots_saveable + the flash residuals: no matmul or
     #                       attention recompute, memory = all matmul outputs
+    # Any value but the default is an explicit choice and is taken as written.
     remat_policy: str = "save_flash"
     # Activation-checkpointing extensions (reference configure() knobs,
     # runtime/activation_checkpointing/checkpointing.py:825):
@@ -1161,16 +1169,97 @@ def _stream_top_level(cfg: TransformerConfig, params: Params) -> Params:
 
 _SAVED_NAMES = {"save_flash": ("flash_out", "flash_lse", "xent_lse"),
                 "nothing_saveable": ()}
+# checkpoint names of a dense feed-forward's pre-activation (``_ffn``): the up
+# projection after its bias, and a gated form's gate product
+FFN_NAMES = ("ffn_up", "ffn_gate")
+# Names that ``save_flash`` saves beside its own while a training engine traces
+# its step (``remat_also_saving``): never a user's to set, and the tracing thread's own
+_ALSO_SAVED: contextvars.ContextVar = contextvars.ContextVar("remat_also_saved", default=())
 
 
-def _remat_policy(name: str, offload: bool = False):
+@contextlib.contextmanager
+def remat_also_saving(names):
+    """While a step is TRACED inside this scope, ``_remat_wrapper`` of a model whose
+    policy is ``save_flash`` saves ``names`` too (names ``remat_candidates`` offers:
+    a matmul's output that the backward pass then reads instead of recomputing).
+    The training engine enters it with what ``runtime/remat_plan.plan_saved``
+    found room for; any other policy is an explicit choice and is left alone."""
+    token = _ALSO_SAVED.set(tuple(names))
+    try:
+        yield
+    finally:
+        _ALSO_SAVED.reset(token)
+
+
+def _named(x, name: str):
+    """``x`` under its checkpoint name while a step that saves it is traced
+    (``remat_also_saving``), and ``x`` itself otherwise: every other program, a
+    serving one among them, is what it was to the symbol."""
+    return checkpoint_name(x, name) if name in _ALSO_SAVED.get() else x
+
+
+def remat_candidates(cfg: TransformerConfig) -> tuple:
+    """What a ``save_flash`` checkpoint of ``cfg``'s layers could keep beside its
+    floor, for ``plan_saved``: (floor, names, values). ``floor`` is the values a
+    token that the floor policy saves over all layers: each layer's input and
+    ``flash_out`` (``flash_lse`` is one float a head), the latter [heads, rows,
+    head width] on the device, whose tiles are 128 lanes wide. ``names`` are the
+    one candidate's checkpoint names and ``values`` what they hold a token over
+    all layers (0: nothing to offer): the dense feed-forwards' pre-activations
+    (``FFN_NAMES``; a gated form has two), which remove 2 x hidden_size
+    operations a saved value from the backward pass. A
+    routed layer's experts are not offered. Nor are q, k and v: kept as the
+    flash kernel's residuals they took the three projections out of the
+    backward pass and left the step as long as it was, because the rotary's and
+    the relayout's backward, fused into the recomputed products before, ran as
+    passes of their own (PERF.md section 6, PR 50)."""
+    L, lead = cfg.num_layers, cfg.moe_first_dense
+    flash = cfg.num_layers - len(cfg.conv_layers) if cfg.attn_impl == "flash" else 0
+    floor = L * cfg.hidden_size + flash * cfg.num_heads * (-(-cfg.value_head_dim // 128) * 128)
+    routed = (L - lead) // cfg.moe_every if cfg.moe_every > 0 else 0
+    gated = cfg.activation == "swiglu"
+    ffn = 2 * lead * cfg.dense_ffn_size + (L - lead - routed) * cfg.ffn_size * (1 + gated)
+    return floor, FFN_NAMES[:1 + gated], ffn
+
+
+def step_working_bytes(cfg: TransformerConfig, sequences: int, tokens: int) -> int:
+    """Bytes of a training step's temporaries on one device, beside the state,
+    the gradients and what the checkpoints save: the room ``plan_saved`` leaves
+    them. From shapes, as the compiled floor program of the ZeRO-3 step showed
+    them at seventeen shapes (``experiments/remat_fit.py``; PERF.md section 6,
+    PR 50: within - 2 and + 9% of ``memory_analysis()`` there, before the
+    planner's headroom):
+
+    - weights gathered for use: two layers' (this one's and the next, fetched
+      ahead) and the vocabulary's matrix;
+    - the larger of the two moments a step's memory peaks at: the loss over one
+      chunk of logits, held in float32 and in the compute dtype (every
+      ``sequences`` row x ``loss_chunk_size`` positions x the vocabulary; all
+      positions where the loss is not chunked), or one layer's backward pass,
+      which holds about two rows as wide as the feed-forward and ten as wide
+      as the residual for each of the device's ``tokens``."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    d, f = cfg.hidden_size, max(cfg.ffn_size, cfg.dense_ffn_size)
+    layer_weights = d * (cfg.num_heads + 2 * cfg.kv_heads) * cfg.head_dim + d * d + (
+        2 + (cfg.activation == "swiglu")) * d * f
+    gathered = (2 * layer_weights + cfg.vocab_size * d) * item
+    chunk, S = cfg.loss_chunk_size, tokens // max(sequences, 1)
+    rows = sequences * chunk if 0 < chunk < S and S % chunk == 0 else tokens
+    loss = rows * cfg.vocab_size * (4 + item)
+    layer = tokens * (2 * f + 10 * d) * item
+    return gathered + max(loss, layer)
+
+
+def _remat_policy(name: str, offload: bool = False, also: tuple = ()):
     """Resolve a remat-policy name (TransformerConfig.remat_policy).
 
+    ``also``: names ``save_flash`` saves beside its own (``remat_also_saving``).
     ``offload=True`` (cpu_checkpointing): the tagged ``layer_in`` boundary
     residual is saved to pinned host memory instead of HBM — the reference
     moves the saved input to CPU at checkpoint:493/:480; here XLA schedules
     the d2h/h2d copies asynchronously around the recompute."""
     cp = jax.checkpoint_policies
+    also = tuple(also) if name == "save_flash" else ()
     if offload:
         saved = _SAVED_NAMES.get(name)
         if saved is None:
@@ -1178,14 +1267,14 @@ def _remat_policy(name: str, offload: bool = False):
                 f"cpu_checkpointing composes with named-residual remat policies "
                 f"{sorted(_SAVED_NAMES)}, not {name!r}")
         return cp.save_and_offload_only_these_names(
-            names_which_can_be_saved=list(saved),
+            names_which_can_be_saved=list(saved + also),
             names_which_can_be_offloaded=["layer_in"],
             offload_src="device",
             offload_dst="pinned_host",
         )
     # xent_lse: the fused loss kernel's residual (ops/pallas/fused_xent.py) —
     # saved so a remat region spanning the loss never re-runs its forward
-    flash_names = cp.save_only_these_names("flash_out", "flash_lse", "xent_lse")
+    flash_names = cp.save_only_these_names(*_SAVED_NAMES["save_flash"], *also)
     if name == "save_flash":
         return flash_names
     if name == "dots_and_flash":
@@ -1308,9 +1397,13 @@ def _ffn(cfg, lp, h):
         u = jnp.einsum("bsd,df->bsf", h, lp["wi"].astype(h.dtype))
         if cfg.use_bias:
             u = u + lp["bi"].astype(h.dtype)
+        # the pre-activation as the backward pass reads it: a checkpoint that
+        # saves FFN_NAMES recomputes no up projection (``remat_candidates``)
+        u = _named(u, FFN_NAMES[0])
         gate_m, down_m = cfg.multiplier("mlp_multipliers")
         if cfg.activation == "swiglu":  # a gated feed-forward has no biases
             gate = jnp.einsum("bsd,df->bsf", h, lp["wg"].astype(h.dtype))
+            gate = _named(gate, FFN_NAMES[1])
             u = jax.nn.silu(_times(gate, gate_m)) * u
         elif cfg.activation == "relu":
             u = jax.nn.relu(u)
@@ -1795,7 +1888,7 @@ def _remat_wrapper(cfg: TransformerConfig):
     tagged ``layer_in`` (``_boundary_tagger``). None when ``cfg.remat`` is off."""
     if not cfg.remat:
         return None
-    policy = _remat_policy(cfg.remat_policy, offload=cfg.remat_offload)
+    policy = _remat_policy(cfg.remat_policy, offload=cfg.remat_offload, also=_ALSO_SAVED.get())
     tag = _boundary_tagger(cfg)
 
     def wrap(body):
@@ -2986,6 +3079,28 @@ class Model:
 
     def logical_axes(self):
         return logical_axes(self.config)
+
+    def remat_offer(self, micro_batch):
+        """For the training engine's plan of what a checkpointed layer keeps
+        (``runtime/remat_plan.plan_saved``), in bytes on one device, whose share
+        of a micro-batch ``micro_batch`` is (shapes: ``ShapeDtypeStruct``s):
+        (names, what saving them takes, what the floor policy saves, the step's
+        temporaries: ``remat_candidates``, ``step_working_bytes``). None where
+        nothing may be added: no remat, a policy other than ``save_flash``, which
+        is an explicit choice and is taken as written, or a batch that is not
+        ``split_batch``'s. The engine traces the loss inside
+        ``remat_also_saving(names)``."""
+        c = self.config
+        if not c.remat or c.remat_policy != "save_flash" or not (
+                isinstance(micro_batch, dict) and {"tokens", "input_ids"} & set(micro_batch)):
+            return None
+        sequences, length = jax.eval_shape(split_batch, micro_batch)[0].shape[:2]
+        tokens, item = sequences * length, jnp.dtype(c.dtype).itemsize
+        floor, names, values = remat_candidates(c)
+        return (names, tokens * values * item, tokens * floor * item,
+                step_working_bytes(c, sequences, tokens))
+
+    remat_also_saving = staticmethod(remat_also_saving)
 
     def flops_per_token(self) -> float:
         """Approximate training FLOPs/token (fwd+bwd ≈ 6 * n_params matmul

@@ -634,6 +634,10 @@ class DeepSpeedEngine:
             self.curriculum_scheduler = CurriculumScheduler(self.config.curriculum_learning)
 
         self._train_step = None  # compiled lazily (shape-dependent)
+        # what the step's checkpoints keep beside save_flash's floor, by the micro-
+        # batch's shapes (_saving_what_fits); floor only after a compile failed for memory
+        self._remat_plans: dict = {}
+        self._remat_floor_only = False
         self._check_output_shardings = False
         self._grad_fn = None
         self._apply_fn = None
@@ -1228,7 +1232,74 @@ class DeepSpeedEngine:
         if not bool(np.asarray(jax.device_get(metrics["overflow"]))):
             self._onebit_applied_steps += 1
 
-    def _build_train_step(self, grads_only: bool = False):
+    def _saving_what_fits(self, micro_grad, limit=None, state=None):
+        """``micro_grad`` traced so that each checkpointed layer keeps, beside
+        its floor policy's residuals, the matmul outputs this device has room
+        for (runtime/remat_plan.py: a dense feed-forward's pre-activation),
+        which the backward pass then reads instead of running the up
+        projection a second time. Chosen as the step is traced,
+        from the micro-batch's shape, the shardings and the device's memory
+        LIMIT, never from what is in use: a step built twice is one program,
+        and so is a step built by every process of a multi-process run
+        (``utils/memory.mesh_memory_limit``).
+        What was chosen is said once a shape: a log line, the gauge
+        ``train/remat_saved_bytes`` and the train step's program-ledger row.
+
+        ``limit`` / ``state`` default to the device's ``bytes_limit`` and to
+        ``self.state`` (only its shapes and shardings are read); a caller that
+        compiles for a chip it has not got hands in both. Unchanged
+        ``micro_grad`` where the model offers nothing (``Model.remat_offer``: no
+        remat, or a policy other than ``save_flash``), where the platform gives
+        no limit (the CPU), or after the chosen program failed to compile for
+        memory."""
+        if not hasattr(self.model, "remat_offer") or self._remat_floor_only:
+            return micro_grad
+        from ..utils.memory import device_bytes_held, mesh_memory_limit
+        from .remat_plan import plan_saved
+
+        mesh = self.mesh
+        if limit is None:
+            limit = mesh_memory_limit(mesh)
+        if not limit:
+            return micro_grad
+        state = self.state if state is None else state
+        state_bytes = device_bytes_held(state)
+        # the gradients: a device's shard of the parameters, in the compute dtype
+        compute_dtype = self.config.compute_dtype
+        grad_bytes = device_bytes_held(jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, compute_dtype, sharding=x.sharding),
+            state["params"]))
+        width_over = mesh.shape.get("model", 1)  # tensor parallelism splits the ffn's width
+        # locals, not ``self``: see ``_build_train_step``
+        model, plans, tm = self.model, self._remat_plans, self.telemetry
+        on_a_device = NamedSharding(mesh, self.batch_spec).shard_shape
+        gb = lambda b: f"{b / 1e9:.2f} GB"  # noqa: E731
+
+        def planned(params, mb, *rest):
+            offer = model.remat_offer(jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(on_a_device(x.shape), x.dtype), mb))
+            if offer is None:
+                return micro_grad(params, mb, *rest)
+            names, candidate, floor, working = offer
+            key = tuple(x.shape for x in jax.tree.leaves(mb))
+            plan = plan_saved(limit, state_bytes + floor, grad_bytes + working, names,
+                              candidate // width_over)
+            if plans.setdefault(key, plan) is plan:
+                log_dist(
+                    f"remat: save_flash also keeps {list(plan.names) or 'nothing'} of "
+                    f"{list(names)} ({gb(candidate // width_over)} a device): room "
+                    f"{gb(plan.room)} under a limit of {gb(limit)} beside {gb(state_bytes)} of "
+                    f"state, {gb(floor)} of save_flash's residuals and {gb(grad_bytes + working)}"
+                    " of gradients and temporaries", ranks=[0])
+                tm.gauge("train/remat_saved_bytes").set(plan.saved_bytes)
+                tm.ledger.annotate("train/train_step", remat_saved=list(plan.names),
+                                   remat_saved_bytes=plan.saved_bytes)
+            with model.remat_also_saving(plan.names):
+                return micro_grad(params, mb, *rest)
+
+        return planned
+
+    def _build_train_step(self, grads_only: bool = False, remat_limit=None, remat_state=None):
         if self._onebit_cfg is not None:
             if self._onebit_kind == "zoadam":
                 return self._build_zoadam_train_step(("warm", True))
@@ -1246,7 +1317,9 @@ class DeepSpeedEngine:
         apply_update = self._make_apply_update()
         # stable names for a trace reader (jax.named_scope lands in each
         # operation's name-scope stat): fwd_bwd / grad_clip / optimizer
-        micro_grad = jax.named_call(self._make_micro_grad(compute_dtype), name="fwd_bwd")
+        micro_grad = self._saving_what_fits(
+            jax.named_call(self._make_micro_grad(compute_dtype), name="fwd_bwd"),
+            remat_limit, remat_state)
 
         dropout = self._dropout_enabled
         rng_seed = self._stochastics_seed
@@ -1562,7 +1635,7 @@ class DeepSpeedEngine:
                 for path, leaf in jax.tree_util.tree_flatten_with_path(self.state[sub])[0]
             ]
         with tm.span("dispatch"):  # batch placement + enqueue
-            self.state, metrics = self._train_step(self.state, batch)
+            self.state, metrics = self._dispatch_step(batch)
         step_span.set_sync(metrics["loss"])
         if donation_probe is not None:
             self._donation_checked = True
@@ -1622,6 +1695,32 @@ class DeepSpeedEngine:
             self._resilience_post_step(metrics)
             self._snapshot_dl_cursor()
         return metrics
+
+    def _dispatch_step(self, batch):
+        """The compiled step on ``self.state``. Where the program whose checkpoints
+        keep more than their floor (``_saving_what_fits``) does not compile for the
+        device's memory, say so and build the floor program: a second compile, on
+        this path only. A compile that fails has consumed no donated buffer."""
+        try:
+            return self._train_step(self.state, batch)
+        except jax.errors.JaxRuntimeError as e:
+            kept = sorted({n for p in self._remat_plans.values() for n in p.names})
+            gone = any(x.is_deleted() for x in jax.tree.leaves(self.state)
+                       if isinstance(x, jax.Array))
+            if "RESOURCE_EXHAUSTED" not in str(e) or not kept or gone:
+                raise
+            logger.warning(
+                "remat: the step that also keeps %s did not fit the device (%s); building "
+                "the save_flash floor program instead. The plan's count of this step's "
+                "temporaries (models/transformer.step_working_bytes) was too low.",
+                kept, str(e)[:300])
+            self._remat_floor_only = True
+            self._remat_plans.clear()
+            self.telemetry.gauge("train/remat_saved_bytes").set(0)
+            self.telemetry.ledger.annotate("train/train_step", remat_saved=[],
+                                           remat_saved_bytes=0, remat_refit=True)
+            self._train_step = self._build_train_step()
+            return self._train_step(self.state, batch)
 
     # ------------------------------------------------------------------
     # Resilience hooks (resilience/; docs/resilience.md)

@@ -212,6 +212,7 @@ class ProgramLedger:
         # table's per-generation entry
         self._ici_gbps = float(ici_gbps) or None
         self._pipeline: Optional[dict] = None  # set by the pipeline engine
+        self._notes: dict[str, dict] = {}  # name prefix -> a builder's facts (annotate)
 
     @property
     def platform(self) -> dict:
@@ -249,6 +250,15 @@ class ProgramLedger:
                 num_stages, micro_batches),
         }
 
+    def annotate(self, prefix: str, **facts) -> None:
+        """Facts a builder knows about its programs (what the train step's
+        checkpoints keep: ``remat_saved``), carried on the row of every program
+        whose name starts with ``prefix``, captured already or later."""
+        self._notes.setdefault(prefix, {}).update(facts)
+        for name, row in self.entries.items():
+            if name.startswith(prefix):
+                row.update(facts)
+
     # -- capture (watchdog compile-detection path) -----------------------
 
     def capture(self, name: str, fn, args, kwargs, compile_s: float) -> None:
@@ -270,6 +280,8 @@ class ProgramLedger:
                     "name": name,
                     "compiles": 1,
                     "compile_s": float(compile_s),
+                    **{k: v for prefix, facts in self._notes.items()
+                       if name.startswith(prefix) for k, v in facts.items()},
                 }
                 self._pending[name] = (fn, specs, kw_specs)
             else:

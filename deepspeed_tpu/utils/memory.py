@@ -33,6 +33,43 @@ def device_memory_stats(device=None) -> dict:
     return stats or {}
 
 
+def mesh_memory_limit(mesh):
+    """``bytes_limit`` of one device of ``mesh``, THE SAME NUMBER ON EVERY
+    PROCESS of a multi-process run, or None where there is none to read. For
+    whoever chooses a program by it (``runtime/engine._saving_what_fits``): hosts
+    that traced different programs would hang in their collectives.
+
+    Read from the first device of the mesh that THIS process is attached to
+    (another process's device has no ``memory_stats()`` here); every process
+    reads its own, and the numbers agree because the mesh holds one kind of
+    device, which is checked: a mesh of mixed kinds gives None on every process.
+    None too on a platform without memory figures (the CPU) and for a mesh that
+    is described and not attached (a compile for a chip this host has not got:
+    the caller hands the limit in)."""
+    import jax
+
+    devices = list(mesh.devices.flat)
+    attached = set(jax.local_devices())
+    mine = [d for d in devices if d in attached]
+    if not mine or len({d.device_kind for d in devices}) != 1:
+        return None
+    return device_memory_stats(mine[0]).get("bytes_limit")
+
+
+def device_bytes_held(tree) -> int:
+    """Bytes ONE device holds of ``tree``'s arrays (or of ``ShapeDtypeStruct``s
+    that carry a sharding), from the shardings: exact. Leaves in pinned host
+    memory are not the device's."""
+    import math
+
+    import jax
+
+    return sum(
+        math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+        for x in jax.tree.leaves(tree)
+        if getattr(x, "sharding", None) is not None and x.sharding.memory_kind != "pinned_host")
+
+
 def see_memory_usage(message: str, force: bool = False) -> dict:
     """Log current + peak device memory and host RSS; returns the numbers.
 
