@@ -945,10 +945,11 @@ class SlotWorker:
         ``(first_token, bad)`` pair. ``uid`` only labels the span."""
         tm = self.telemetry
         # ``attn``: the form the bucket's program was traced with (its local
-        # cache is the bucket long: ``_build_prefill``), and the grid its window
-        # layers took through the flash kernel
+        # cache is the bucket long: ``_build_prefill``), and the grids its
+        # whole-context and its window layers took through the flash kernel
         attrs = dict(uid=uid, slot=slot, bucket=bucket, true_len=true_len,
                      attn=tfm.cache_block_form(self.cfg, bucket),
+                     **tfm.causal_grid_form(self.cfg, bucket),
                      **tfm.window_grid_form(self.cfg, bucket),
                      **self._block_attrs(bucket, true_len),
                      **self._ring_attrs(np.arange(true_len)))

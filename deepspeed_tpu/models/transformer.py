@@ -139,7 +139,7 @@ class TransformerConfig:
     activation: str = "gelu"
     embed_ln: bool = False  # LayerNorm after embedding (BLOOM)
     attn_impl: str = "xla"  # xla | flash | ring | sparse
-    flash_block_q: int = 0  # 0 = auto (ops/pallas/flash_attention._auto_block)
+    flash_block_q: int = 0  # 0 = auto (ops/pallas/flash_attention._auto_block / _key_block)
     flash_block_k: int = 0
     # attn_impl="sparse": block-sparse attention config (reference
     # ops/sparse_attention/sparsity_config.py). {"mode": "fixed"|"bigbird"|
@@ -2594,6 +2594,29 @@ def window_grid_form(cfg: TransformerConfig, bucket: int) -> dict:
     return {"window_grid": grid, "window_blocks_pct": round(pct, 2)}
 
 
+def causal_tiles_fact(cfg: TransformerConfig, rows: int, block_q=None, block_k=None) -> dict:
+    """``causal_tiles_pct``: the score elements the causal flash kernels compute
+    over ``rows`` rows of the model's heads as a % of those at or under the
+    diagonal (100: the triangle alone), a constant of the trace from the shapes:
+    ``flash_attention.causal_tiles_pct``, the rule the kernel's steps read their
+    own schedule by."""
+    from ..ops.pallas.flash_attention import causal_tiles_pct
+
+    return {"causal_tiles_pct": round(causal_tiles_pct(
+        rows, max(cfg.head_dim, cfg.value_head_dim), jnp.dtype(cfg.dtype).itemsize,
+        block_q, block_k), 2)}
+
+
+def causal_grid_form(cfg: TransformerConfig, bucket: int) -> dict:
+    """What a prefill span says of the flash forward grid its whole-context
+    layers took (nothing where none goes through the kernel):
+    ``causal_tiles_fact`` at the bucket's rows."""
+    whole = cfg.num_layers - len(cfg.conv_layers) - len(cfg.window_layers)
+    if not whole or cache_attention_form(cfg.num_heads, 1, bucket, bucket) != "flash":
+        return {}
+    return causal_tiles_fact(cfg, bucket)
+
+
 def expert_bank_form(cfg: TransformerConfig, moe, mesh=None):
     """``"in_place"`` or ``"sliced"`` (None for a model without dropless
     routing): where a program that only runs the forward pass through the cache
@@ -3101,6 +3124,18 @@ class Model:
                 step_working_bytes(c, sequences, tokens))
 
     remat_also_saving = staticmethod(remat_also_saving)
+
+    def flash_schedule(self, micro_batch) -> dict:
+        """For the train step's program-ledger row: ``causal_tiles_fact`` at the
+        sequence length of this micro-batch and the configured outer blocks (the
+        backward kernels cut their steps by the forward's rule). Nothing where
+        training does not attend through those kernels."""
+        c = self.config
+        if c.attn_impl != "flash" or not c.causal or not (
+                isinstance(micro_batch, dict) and {"tokens", "input_ids"} & set(micro_batch)):
+            return {}
+        length = jax.eval_shape(split_batch, micro_batch)[0].shape[1]
+        return causal_tiles_fact(c, length, c.flash_block_q or None, c.flash_block_k or None)
 
     def flops_per_token(self) -> float:
         """Approximate training FLOPs/token (fwd+bwd ≈ 6 * n_params matmul

@@ -24,10 +24,13 @@ when the stream finishes). This keeps per-program VMEM at a few hundred KB
 at any sequence length — whole-sequence BlockSpecs would blow the ~16 MB
 VMEM budget at 8-16k tokens.
 
-Causal masking skips the compute (not the grid step) of fully-masked blocks
-via ``pl.when`` — the block analogue of the reference's triangular softmax
-kernels. On the CPU platform the kernels run in Pallas interpreter mode so
-tests exercise the same code.
+Causal masking keeps the grid and its coarse blocks and cuts the work INSIDE a
+step (the block analogue of the reference's triangular softmax kernels): a step
+runs the key sub-tiles of its block that hold a key at or under its last row,
+and a step wholly above the diagonal runs nothing and fetches nothing (its
+index map stays on the last block its row needed). On
+the CPU platform the kernels run in Pallas interpreter mode so tests exercise
+the same code.
 
 Layout: public API takes [B, S, H, D] (the model family's layout) and maps
 over fused batch×head programs internally.
@@ -49,14 +52,26 @@ from . import interpret_default
 LANES = 128  # TPU lane width; LSE/delta are stored lane-broadcast
 NEG_INF = -1e30
 
-# Block-size policy. Grid-step overhead dominates tiny blocks on TPU: at
-# [B=64,H=12,S=1024,Dh=64] the 128x128 grid is 49k steps of ~4 MFLOP each and
-# the kernel measures 4.1 TFLOPS; 512/1024 blocks cut it to 1.5k steps and
-# 16 TFLOPS fwd / 32 f+b (see experiments/perf_probe2.py). Blocks are capped
-# so VMEM stays bounded at long sequence (the streamed operand still rides the
-# innermost grid dim).
+# Block-size policy. A grid step costs about a microsecond whatever it computes
+# (PERF.md section 6, PR 51 has the table: at [128 heads, 2048 rows, 128] the
+# three kernels take 7.13 ms at 512 x 1024 blocks run whole, 5.88 with a step's
+# work cut to the keys its rows see, 5.05 at 512 x 2048 and 7.37 at 512 x 512),
+# so the OUTER blocks are as coarse as VMEM lets them be, and a causal step
+# cuts its work to what the diagonal leaves of its block INSIDE the step. The
+# streamed operand still rides the innermost grid dim, so VMEM stays bounded
+# at any sequence length.
 MAX_BLOCK_Q = 512
-MAX_BLOCK_K = 1024
+MAX_BLOCK_K = 2048
+# What one key-side block (a k or a v block: each is double-buffered, and dK/dV
+# hold a float32 accumulator apiece beside them) may take of the 16 MiB of VMEM
+# the chip's compiler gives a kernel: 2048 keys of 128-wide float32 heads or
+# 256-wide bfloat16 ones compile, 2048 of 256-wide float32 are refused by 4 MiB
+# (tests/test_chip_compile.py).
+KEY_BLOCK_BYTES = 2 ** 20
+# The width of the key sub-tiles a causal step's block is cut in: a step runs
+# only the sub-tiles that hold a key at or under its last row (``_keys_seen``,
+# ``_step_cases``).
+SUB_K = 512
 
 
 def _auto_block(s: int, cap: int) -> int:
@@ -68,12 +83,23 @@ def _auto_block(s: int, cap: int) -> int:
     return min(b, s)
 
 
+def _key_block(s: int, width: int, itemsize: int) -> int:
+    """The key block over ``s`` keys (a multiple of 128) of heads ``width``
+    wide: ``_auto_block`` under ``MAX_BLOCK_K``, halved while one block would
+    pass ``KEY_BLOCK_BYTES``."""
+    cap = MAX_BLOCK_K
+    while cap > 128 and cap * width * itemsize > KEY_BLOCK_BYTES:
+        cap //= 2
+    return _auto_block(s, cap)
+
+
 # Under a window that is a constant of the trace the forward runs over a band of
 # key blocks (``_band_forward``), and the triangle's blocks are the wrong size for
 # it: a 1024-wide key block is 8 x a window of 128. The key block is the smallest
 # multiple of 128 that holds the window; the query block was timed on the chip
 # (PERF.md §6, PR 40: the kernel alone at [64 heads, rows, 128], window 128).
 BAND_BLOCK_Q = 256
+BAND_MAX_BLOCK_K = 1024  # the band's own cap: a wider window keeps the whole grid (``band_plan``)
 # What a banded step may hold of the 16 MiB of VMEM the chip's compiler gives a
 # kernel (``_band_step_bytes``, an upper estimate fitted to what the compiler
 # reported for 150 shapes: tests/test_chip_compile.py compiles a sweep).
@@ -103,7 +129,7 @@ def static_window(window, sq: int, sk: int, causal: bool):
 def _band_blocks(s: int, band: int) -> tuple[int, int]:
     """(block_q, block_k) of the banded forward over ``s`` rows (a multiple of
     128) where the caller names none."""
-    bk = min(-(-band // 128) * 128, MAX_BLOCK_K)
+    bk = min(-(-band // 128) * 128, BAND_MAX_BLOCK_K)
     while s % bk:
         bk -= 128
     return _auto_block(s, BAND_BLOCK_Q), bk
@@ -184,6 +210,76 @@ def window_grid(rows: int, window, bh: int, d: int, dv: int, itemsize: int,
     return "band", 100.0 * sum(_band_steps(num_q, bq, bk, band)) / under
 
 
+# ---------------------------------------------------------------------------
+# The causal schedule inside a grid step (PR 51)
+# ---------------------------------------------------------------------------
+
+def _sub_tile(block_k: int) -> int:
+    """Width of the key sub-tiles a causal step's ``block_k`` keys are cut in."""
+    return block_k if block_k <= SUB_K else math.gcd(block_k, SUB_K)
+
+
+def _clip(x, n):
+    """``x`` held to 0 .. n, for a Python number or a traced one."""
+    return min(max(x, 0), n) if isinstance(x, int) else jnp.clip(x, 0, n)
+
+
+def _keys_seen(r0, rows, k0, sub, n):
+    """How many of the ``n`` key sub-tiles ``sub`` wide from key ``k0`` on hold a
+    key at or under the last of the query rows ``r0 .. r0 + rows - 1``: from
+    there on every key lies after the last row and is not computed."""
+    return _clip((r0 + rows - k0 + sub - 1) // sub, n)
+
+
+def _step_cases(block_q: int, block_k: int, causal: bool):
+    """What a step of a ``block_q`` x ``block_k`` grid can be asked to compute,
+    each case a piece of straight-line code under its own ``pl.when`` on static
+    slices of the key block (a loop of a traced trip count over the sub-tiles
+    lost more between them than the diagonal saves: PERF.md section 6, PR 51):
+    -> [(sub-tiles seen | None, keys computed)], the keys from the block's
+    first on, all under the causal mask (the compare and select cost nothing
+    that a chip run could find; sparing the sub-tiles under the diagonal them
+    took a second product and cost more). Read from the shapes alone: block origins differ by multiples of gcd(block_q, block_k),
+    and a grid of these blocks meets no other case. Without a diagonal a step
+    is its whole block."""
+    if not causal:
+        return [(None, block_k)]
+    sub = _sub_tile(block_k)
+    step = math.gcd(block_q, block_k)
+    seen = {_keys_seen(ahead, block_q, 0, sub, block_k // sub)  # ahead: r0 - k0
+            for ahead in range(-((block_q - 1) // step) * step, block_k + step, step)}
+    return [(n, n * sub) for n in sorted(seen - {0})]
+
+
+def _for_step_case(qi, kj, block_q, block_k, causal, body):
+    """Run ``body(keys)`` for the one case of ``_step_cases`` that query block
+    ``qi`` meets in key block ``kj`` (none: the block lies above the diagonal)."""
+    sub = _sub_tile(block_k)
+    seen = _keys_seen(qi * block_q, block_q, kj * block_k, sub, block_k // sub)
+    for case, keys in _step_cases(block_q, block_k, causal):
+        if case is None:
+            body(keys)
+        else:
+            pl.when(seen == case)(functools.partial(body, keys))
+
+
+@functools.lru_cache(maxsize=None)  # a serving worker asks on every prefill call
+def causal_tiles_pct(rows: int, width: int, itemsize: int, block_q: int | None = None,
+                     block_k: int | None = None) -> float:
+    """The score elements the causal forward (``flash_fwd``) computes over
+    ``rows`` rows of self-attention at heads ``width`` wide (the wider of q/k
+    and v) as a % of those at or under the diagonal, from the shapes alone, as
+    the kernel's steps read their own schedule (100: the triangle and nothing
+    else; the whole square would read 200)."""
+    rows += (-rows) % 128
+    block_q = min(block_q, rows) if block_q else _auto_block(rows, MAX_BLOCK_Q)
+    block_k = min(block_k, rows) if block_k else _key_block(rows, width, itemsize)
+    sub = _sub_tile(block_k)
+    tiles = sum(_keys_seen(r0, block_q, k0, sub, block_k // sub)
+                for r0 in range(0, rows, block_q) for k0 in range(0, rows, block_k))
+    return 100.0 * tiles * block_q * sub / (rows * (rows + 1) / 2)
+
+
 def _vmem_spec(shape, index_map):
     return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
@@ -217,8 +313,9 @@ def _lanes(col, lanes=LANES):
 # In-kernel scores (shared by forward + both backward kernels)
 # ---------------------------------------------------------------------------
 
-def _block_scores(q, k_blk, qi, kj, *, sm_scale, causal, slope_ref, w_ref):
-    """[Bq, Bk] fp32 scores with alibi / local-window / causal fused.
+def _block_scores(q, k_blk, r0, k0, *, sm_scale, causal, slope_ref, w_ref):
+    """[rows, keys] fp32 scores of the query rows from ``r0`` on against the
+    keys from ``k0`` on, with alibi / local-window / causal fused.
 
     ``slope_ref`` (or None): [1, 1, LANES] block of the per-program alibi slope
     (one lane-broadcast row per fused batch×head program, read as a vector:
@@ -229,25 +326,20 @@ def _block_scores(q, k_blk, qi, kj, *, sm_scale, causal, slope_ref, w_ref):
     None): [1, LANES] runtime local-attention window; w <= 0 means global
     (lets the scanned GPT-Neo layers alternate locality with one compiled
     kernel)."""
-    block_q, block_k = q.shape[0], k_blk.shape[0]
+    rows, keys = q.shape[0], k_blk.shape[0]
     s = sm_scale * jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [Bq, Bk] fp32 accumulator
-    need_pos = causal or slope_ref is not None or w_ref is not None
-    if need_pos:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
+    )  # [rows, keys] fp32 accumulator
+    if causal or slope_ref is not None or w_ref is not None:
+        dist = (r0 - k0) + (jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
+                            - jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1))  # q_pos - k_pos
     if slope_ref is not None:
-        s = s + _widen(slope_ref[0], block_k) * (k_pos - q_pos).astype(jnp.float32)
+        s = s - _widen(slope_ref[0], keys) * dist.astype(jnp.float32)
     if w_ref is not None:
         w = w_ref[0, 0]  # fp32 runtime window; w <= 0 means global
-        s = jnp.where((w <= 0) | ((q_pos - k_pos).astype(jnp.float32) < w), s, NEG_INF)
+        s = jnp.where((w <= 0) | (dist.astype(jnp.float32) < w), s, NEG_INF)
     if causal:
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        s = jnp.where(dist >= 0, s, NEG_INF)
     return s
 
 
@@ -291,15 +383,15 @@ def _fwd_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _compute():
-        q = q_ref[0]          # [Bq, D] native dtype — MXU runs at full rate in bf16
-        k_blk = k_ref[0]      # [Bk, D]
-        v_blk = v_ref[0]
-        s = _block_scores(q, k_blk, qi, kj, sm_scale=sm_scale, causal=causal,
-                          slope_ref=slope_ref, w_ref=w_ref)
+    def _compute(keys):
+        q = q_ref[0]            # [Bq, D] native dtype — MXU runs at full rate in bf16
+        k_blk = k_ref[0, :keys, :]  # the block's keys at or under the last row
+        v_blk = v_ref[0, :keys, :]
+        s = _block_scores(q, k_blk, qi * block_q, kj * block_k, sm_scale=sm_scale,
+                          causal=causal, slope_ref=slope_ref, w_ref=w_ref)
         m_prev = m_scr[...]                     # [Bq, LANES] lane-broadcast
         m_new = jnp.maximum(m_prev, _lanes(jnp.max(s, axis=1)))
-        p = jnp.exp(s - _widen(m_new, block_k))
+        p = jnp.exp(s - _widen(m_new, keys))
         alpha = jnp.exp(m_prev - m_new)         # [Bq, LANES]
         m_scr[...] = m_new
         l_scr[...] = l_scr[...] * alpha + _lanes(jnp.sum(p, axis=1))
@@ -308,11 +400,8 @@ def _fwd_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        # skip blocks strictly above the diagonal: kj*Bk > qi*Bq + Bq - 1
-        pl.when(kj * block_k <= qi * block_q + block_q - 1)(_compute)
-    else:
-        _compute()
+    # a block strictly above the diagonal meets no case
+    _for_step_case(qi, kj, block_q, block_k, causal, _compute)
 
     @pl.when(kj == num_k - 1)
     def _finalize():
@@ -431,6 +520,26 @@ def _band_forward(q, k, v, slopes_bh, sm_scale, block_q, block_k, band, interpre
     return out[0], out[1] if with_lse else None
 
 
+def _streamed_keys(causal, block_q, block_k):
+    """Index map of the key-side operand streamed past query block ``qi`` (grid
+    ``(bh, qi, kj)``): a causal step past the diagonal's block computes nothing,
+    and stays on that block so that nothing is fetched for it either (an
+    unchanged block index starts no copy)."""
+    if not causal:
+        return lambda bh, qi, kj: (bh, kj, 0)
+    return lambda bh, qi, kj: (bh, jnp.minimum(kj, _diag(qi, block_q, block_k)), 0)
+
+
+def _streamed_rows(causal, block_q, block_k, num_q):
+    """The same for the query-side operands streamed past key block ``kj`` (grid
+    ``(bh, kj, qi)``): the steps before the first query block with a row at or
+    under the key block's first key stay on that one."""
+    if not causal:
+        return lambda bh, kj, qi: (bh, qi, 0)
+    return lambda bh, kj, qi: (
+        bh, jnp.minimum(jnp.maximum(qi, kj * block_k // block_q), num_q - 1), 0)
+
+
 def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
                    block_k, interpret, band=0, with_lse=True):
     """-> (out, lse). ``band`` (``static_window``): ``_band_forward``, which
@@ -446,10 +555,11 @@ def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
     base = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, num_k=num_k,
     )
+    kv_block = _streamed_keys(causal, block_q, block_k)
     in_specs = [
         _vmem_spec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
-        _vmem_spec((1, block_k, D), lambda bh, qi, kj: (bh, kj, 0)),
-        _vmem_spec((1, block_k, Dv), lambda bh, qi, kj: (bh, kj, 0)),
+        _vmem_spec((1, block_k, D), kv_block),
+        _vmem_spec((1, block_k, Dv), kv_block),
     ]
     operands = [q, k, v]
     if slopes_bh is not None:
@@ -502,19 +612,19 @@ def _bwd_dkdv_kernel(
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def _compute():
-        k_blk = k_ref[0]      # [Bk, D]
-        v_blk = v_ref[0]
+    def _compute(keys):
+        k_blk = k_ref[0, :keys, :]  # the block's keys at or under the last row
+        v_blk = v_ref[0, :keys, :]
         q_blk = q_ref[0]      # [Bq, D]
         do_blk = do_ref[0]
         lse = lse_ref[0]      # [Bq, LANES]
         delta = delta_ref[0]  # [Bq, LANES]
 
-        s = _block_scores(q_blk, k_blk, qi, kj, sm_scale=sm_scale, causal=causal,
-                          slope_ref=slope_ref, w_ref=w_ref)
-        p = jnp.exp(s - _widen(lse, block_k))  # [Bq, Bk]
+        s = _block_scores(q_blk, k_blk, qi * block_q, kj * block_k, sm_scale=sm_scale,
+                          causal=causal, slope_ref=slope_ref, w_ref=w_ref)
+        p = jnp.exp(s - _widen(lse, keys))  # [Bq, keys]
         # dV += P^T dO
-        dv_scr[...] += jax.lax.dot_general(
+        dv_scr[:keys, :] += jax.lax.dot_general(
             p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -522,18 +632,16 @@ def _bwd_dkdv_kernel(
         dp = jax.lax.dot_general(
             do_blk, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        ds = p * (dp - _widen(delta, block_k))
+        ds = p * (dp - _widen(delta, keys))
         # dK += dS^T Q · scale
-        dk_scr[...] += sm_scale * jax.lax.dot_general(
+        dk_scr[:keys, :] += sm_scale * jax.lax.dot_general(
             ds.astype(q_blk.dtype), q_blk, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        # q-blocks entirely above the diagonal contribute nothing to this k-block
-        pl.when(qi * block_q + block_q - 1 >= kj * block_k)(_compute)
-    else:
-        _compute()
+    # q-blocks entirely above the diagonal contribute nothing to this k-block,
+    # and the others only to its keys at or under their last row
+    _for_step_case(qi, kj, block_q, block_k, causal, _compute)
 
     @pl.when(qi == num_q - 1)
     def _finalize():
@@ -554,29 +662,26 @@ def _bwd_dq_kernel(
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _compute():
+    def _compute(keys):
         q_blk = q_ref[0]
         do_blk = do_ref[0]
         lse = lse_ref[0]      # [Bq, LANES]
         delta = delta_ref[0]  # [Bq, LANES]
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        s = _block_scores(q_blk, k_blk, qi, kj, sm_scale=sm_scale, causal=causal,
-                          slope_ref=slope_ref, w_ref=w_ref)
-        p = jnp.exp(s - _widen(lse, block_k))
+        k_blk = k_ref[0, :keys, :]
+        v_blk = v_ref[0, :keys, :]
+        s = _block_scores(q_blk, k_blk, qi * block_q, kj * block_k, sm_scale=sm_scale,
+                          causal=causal, slope_ref=slope_ref, w_ref=w_ref)
+        p = jnp.exp(s - _widen(lse, keys))
         dp = jax.lax.dot_general(
             do_blk, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        ds = p * (dp - _widen(delta, block_k))
+        ds = p * (dp - _widen(delta, keys))
         dq_scr[...] += sm_scale * jax.lax.dot_general(
             ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        pl.when(kj * block_k <= qi * block_q + block_q - 1)(_compute)
-    else:
-        _compute()
+    _for_step_case(qi, kj, block_q, block_k, causal, _compute)
 
     @pl.when(kj == num_k - 1)
     def _finalize():
@@ -599,6 +704,8 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
 
     cp = _compiler_params(3)
+    kv_block = _streamed_keys(causal, block_q, block_k)
+    q_block = _streamed_rows(causal, block_q, block_k, num_q)
 
     has_slopes = slopes_bh is not None
     has_window = w_arr is not None
@@ -619,12 +726,12 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
         kern_dkdv,
         grid=(BH, num_k, num_q),
         in_specs=[
-            _vmem_spec((1, block_q, D), lambda bh, kj, qi: (bh, qi, 0)),
+            _vmem_spec((1, block_q, D), q_block),
             _vmem_spec((1, block_k, D), lambda bh, kj, qi: (bh, kj, 0)),
             _vmem_spec((1, block_k, D), lambda bh, kj, qi: (bh, kj, 0)),
-            _vmem_spec((1, block_q, D), lambda bh, kj, qi: (bh, qi, 0)),
-            _vmem_spec((1, block_q, LANES), lambda bh, kj, qi: (bh, qi, 0)),
-            _vmem_spec((1, block_q, LANES), lambda bh, kj, qi: (bh, qi, 0)),
+            _vmem_spec((1, block_q, D), q_block),
+            _vmem_spec((1, block_q, LANES), q_block),
+            _vmem_spec((1, block_q, LANES), q_block),
         ] + extra_specs,
         out_specs=[
             _vmem_spec((1, block_k, D), lambda bh, kj, qi: (bh, kj, 0)),
@@ -650,8 +757,8 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
         grid=(BH, num_q, num_k),
         in_specs=[
             _vmem_spec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
-            _vmem_spec((1, block_k, D), lambda bh, qi, kj: (bh, kj, 0)),
-            _vmem_spec((1, block_k, D), lambda bh, qi, kj: (bh, kj, 0)),
+            _vmem_spec((1, block_k, D), kv_block),
+            _vmem_spec((1, block_k, D), kv_block),
             _vmem_spec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
             _vmem_spec((1, block_q, LANES), lambda bh, qi, kj: (bh, qi, 0)),
             _vmem_spec((1, block_q, LANES), lambda bh, qi, kj: (bh, qi, 0)),
@@ -700,7 +807,7 @@ def _flash_bhsd_bwd(sm_scale, causal, block_q, block_k, interpret, band, res, g)
     # take the triangle's blocks, not the band's
     if band:
         block_q = _auto_block(g.shape[1], MAX_BLOCK_Q)
-        block_k = _auto_block(g.shape[1], MAX_BLOCK_K)
+        block_k = _key_block(g.shape[1], g.shape[2], res[0].dtype.itemsize)
     return _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret)
 
 
@@ -790,7 +897,8 @@ def flash_attention(
     else:
         band = 0
         block_q = min(block_q, Sq_p) if block_q else _auto_block(Sq_p, MAX_BLOCK_Q)
-        block_k = min(block_k, Sk_p) if block_k else _auto_block(Sk_p, MAX_BLOCK_K)
+        block_k = (min(block_k, Sk_p) if block_k
+                   else _key_block(Sk_p, max(D, v.shape[3]), q.dtype.itemsize))
     if Sq_p % block_q or Sk_p % block_k:
         raise ValueError(
             f"sequence lengths ({Sq_p}, {Sk_p}) must be divisible by blocks ({block_q}, {block_k})"

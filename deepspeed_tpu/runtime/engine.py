@@ -1143,6 +1143,9 @@ class DeepSpeedEngine:
         PipelineEngine swaps in the executed-1F1B gradient program. ``rng`` is
         the per-micro-step dropout key (None when dropout is off)."""
         model = self.model
+        # what the model's kernels do at this micro-batch's shapes is a constant
+        # of the trace: it goes on the step's program-ledger row as it is traced
+        facts, ledger = getattr(model, "flash_schedule", None), self.telemetry.ledger
 
         dropout = self._dropout_enabled
 
@@ -1159,6 +1162,8 @@ class DeepSpeedEngine:
         vg = jax.value_and_grad(loss_fn, has_aux=True)
 
         def micro_grad(params, mb, loss_scale, rng=None, step=None):
+            if facts is not None:
+                ledger.annotate("train/train_step", **facts(mb))
             (_, loss), grads = vg(params, mb, loss_scale, rng, step)
             return loss, grads
 

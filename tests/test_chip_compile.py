@@ -149,6 +149,56 @@ def test_kernel_compiles_for_v5e(build, v5e, no_persistent_cache):
     assert _footprint(compiled) < HBM_BYTES
 
 
+ALL_FLASH = ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")
+
+
+@pytest.mark.parametrize("bh,rows,widths,dtype,alibi,kernels,block_k", [
+    (128, 2048, (128, 128), jnp.bfloat16, False, ALL_FLASH, 2048),
+    (32, 8192, (192, 128), jnp.bfloat16, False, ("flash_fwd",), 2048),
+    (16, 2048, (128, 128), jnp.bfloat16, True, ("flash_fwd",), 2048),
+    (16, 2048, (128, 128), jnp.bfloat16, True, ALL_FLASH, 2048),
+    (8, 4096, (128, 128), jnp.float32, True, ALL_FLASH, 2048),
+    (8, 4096, (256, 256), jnp.bfloat16, False, ALL_FLASH, 2048),
+    (8, 4096, (256, 256), jnp.float32, False, ALL_FLASH, 1024),
+    (8, 4096, (64, 64), jnp.bfloat16, False, ALL_FLASH, 2048),
+], ids=["train-128x2048x128", "latent-32x8192x192-128", "alibi-16x2048x128", "alibi-fwd-bwd",
+        "f32-128-alibi", "bf16-256", "f32-256-halved", "bf16-64"])
+def test_causal_schedule_compiles_for_v5e(bh, rows, widths, dtype, alibi, kernels, block_k, v5e,
+                                          no_persistent_cache):
+    """The causal kernels with their work cut inside the step (PR 51: a case a
+    count of key sub-tiles, each on a static slice of the key block, index maps
+    that stay on the last block a row needs) at the cells' shapes: the train
+    cell's three kernels at [128 heads x 2048 x 128], kanana's forward at q/k
+    heads of 192 beside value heads of 128 over 8,192 rows, BLOOM's with alibi;
+    and at the widths and dtypes that decide the key block (``_key_block``: 2,048
+    keys where a block holds ``KEY_BLOCK_BYTES`` or less; 256-wide float32
+    heads at 2,048 are refused by 4 MiB and take 1,024). Each compiles for the
+    described chip inside the 16 MiB of VMEM its compiler gives a kernel (it
+    refuses more)."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    d, dv = widths
+    qk = jax.ShapeDtypeStruct((bh, rows, d), dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((bh, rows, dv), dtype, sharding=one_chip)
+    slopes = jnp.full((bh, 1, fa.LANES), 0.25, jnp.float32) if alibi else None
+    blocks = fa._auto_block(rows, fa.MAX_BLOCK_Q), fa._key_block(rows, d, jnp.dtype(dtype).itemsize)
+    assert blocks == (512, block_k) and fa._sub_tile(blocks[1]) == fa.SUB_K
+
+    def attend(q, k, v):
+        return fa._flash_bhsd(q, k, v, slopes, None, d ** -0.5, True, *blocks, False, 0)
+
+    if len(kernels) == 1:
+        fn = attend
+    else:
+        fn = jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
+                      argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(qk, qk, v).compile().as_text()
+    found = set(re.findall(r'^\s*%?[a-z_]*?(flash_[a-z]+(?:_[a-z]+)?)_*[\d.]* = .*'
+                           r'custom_call_target="tpu_custom_call"', text, re.M))
+    assert found == set(kernels), found
+
+
 @pytest.mark.parametrize("window", [100, 128, 512, 1024, 2048, 4096])
 @pytest.mark.parametrize("widths,dtype", [((64, 64), jnp.bfloat16), ((128, 128), jnp.bfloat16),
                                           ((192, 128), jnp.bfloat16), ((256, 256), jnp.bfloat16),
@@ -170,7 +220,7 @@ def test_a_static_window_compiles_as_a_band_or_keeps_the_whole_grid(widths, dtyp
     v = jax.ShapeDtypeStruct((heads, rows, dv), dtype, sharding=one_chip)
     plan = fa.band_plan(rows, window, heads, d, dv, jnp.dtype(dtype).itemsize)
     w_arr = jnp.full((1, fa.LANES), float(window), jnp.float32)
-    blocks = plan[:2] if plan else (fa.MAX_BLOCK_Q, fa.MAX_BLOCK_K)
+    blocks = plan[:2] if plan else (fa.MAX_BLOCK_Q, fa._key_block(rows, d, jnp.dtype(dtype).itemsize))
     compiled = jax.jit(lambda q, k, v: fa._flash_forward(
         q, k, v, None, w_arr, d ** -0.5, True, *blocks, False,
         band=window if plan else 0)).lower(qk, qk, v).compile()

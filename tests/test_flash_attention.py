@@ -215,7 +215,8 @@ def test_static_window_with_alibi_matches_the_traced_window(window):
     np.testing.assert_allclose(np.asarray(band), np.asarray(whole), rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("window,rows", [(100, 256), (128, 512), (256, 512), (1, 256)])
+@pytest.mark.parametrize("window,rows", [(100, 256), (128, 512), (256, 512), (1, 256), (128, 2048),
+                                         (600, 2048)])
 def test_gradients_through_a_static_window_match_the_dense_reference(window, rows):
     """Forward only: under ``jax.grad`` the banded forward's residuals go to the
     backward kernels, which run their whole grid under the same window."""
@@ -287,7 +288,7 @@ def test_lowered_forward_grid(rows, window, blocks, grid):
         assert form == "band" and 0 < pct < 100
     else:
         assert name == "flash_fwd"
-        assert (block_q, block_k) == (fa.MAX_BLOCK_Q, fa.MAX_BLOCK_K)
+        assert (block_q, block_k) == (fa.MAX_BLOCK_Q, fa.MAX_BLOCK_K) == (512, 2048)
         assert grid_dims == (8, rows // block_q, rows // block_k)
         # a traced window, and a static one the band cannot take, ride as an operand
         assert operands == 3 + (window == "traced" or not causal)
@@ -328,3 +329,277 @@ def test_sharded_wrapper_keeps_a_static_window_static(window):
         assert name == "flash_fwd_band" and len(grid) == 2 and operands % 2 == 1
     ref = flash_attention(q, k, v, window=128.0)
     np.testing.assert_allclose(np.asarray(fn(*args)), np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The causal schedule inside a grid step (PR 51): a step runs the key sub-tiles
+# of its block that hold a key at or under its last row, and a step that runs
+# nothing fetches nothing
+# ---------------------------------------------------------------------------
+
+def _dense(q, k, v, causal=True, slopes=None, window=None):
+    """[B, S, H, D] attention in float32 with every score written out: causal,
+    alibi (slope_h * (k_pos - q_pos)) and a window (a query sees the keys
+    q - window + 1 .. q) as masks and biases on the [S, S] matrix."""
+    S, Sk = q.shape[1], k.shape[1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") / np.sqrt(q.shape[-1])
+    dist = jnp.arange(S)[:, None] - jnp.arange(Sk)[None, :]  # q_pos - k_pos
+    if slopes is not None:
+        s = s - slopes[None, :, None, None] * dist[None, None]
+    seen = jnp.ones((S, Sk), bool)
+    if causal:
+        seen &= dist >= 0
+    if window is not None:
+        seen &= dist < window
+    s = jnp.where(seen[None, None], s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v, precision="highest")
+
+
+# rows, (block_q, block_k) handed to the call (None: its own), and what the case is for
+SCHEDULE_CASES = {
+    "key-block-4x-query-block": (2048, (None, None)),    # 512 x 2048: the train cell's grid
+    "key-block-2x-query-block": (2048, (512, 1024)),     # PR 50's blocks: skipped steps
+    "two-key-blocks-of-2048": (4096, (None, None)),
+    "key-block-1x-query-block": (1536, (None, None)),    # 512 x 512: a sub-tile is the block
+    "one-block": (512, (None, None)),                    # a grid of one block
+    "one-key-block": (1024, (None, None)),               # 512 x 1024 in a (2, 1) grid
+    "padded-to-2048": (2000, (None, None)),              # rows not a multiple of the block
+    "padded-to-1152": (1100, (None, None)),              # 128 x 128 blocks after padding
+    "query-block-2x-sub-tile": (2048, (1024, 1024)),     # two row sub-tiles a step
+    "odd-block": (1536, (768, 768)),                     # sub-tiles of gcd(768, 512) = 256
+    "small-blocks": (512, (128, 256)),
+}
+
+
+def _schedule_call(rows, blocks, **kw):
+    bq, bk = blocks
+    return lambda q, k, v: flash_attention(q, k, v, block_q=bq, block_k=bk, **kw)
+
+
+@pytest.mark.parametrize("case", SCHEDULE_CASES)
+def test_causal_schedule_forward_matches_the_dense_reference(case):
+    rows, blocks = SCHEDULE_CASES[case]
+    q, k, v = _qkv(B=1, S=rows, H=2, D=32, seed=rows)
+    out = _schedule_call(rows, blocks)(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_dense(q, k, v)), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("case", SCHEDULE_CASES)
+def test_causal_schedule_gradients_match_the_dense_reference(case):
+    rows, blocks = SCHEDULE_CASES[case]
+    q, k, v = _qkv(B=1, S=rows, H=1, D=32, seed=rows + 1)
+    tgt = jax.random.normal(jax.random.PRNGKey(rows), q.shape)
+    attend = _schedule_call(rows, blocks)
+    gf = jax.grad(lambda *a: jnp.sum(attend(*a) * tgt), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(_dense(*a) * tgt), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("block_k", [None, 1024])
+@pytest.mark.parametrize("form", ["alibi", "traced-window", "traced-window-global",
+                                  "alibi+traced-window", "static-window", "not-causal",
+                                  "not-causal-window"])
+def test_causal_schedule_under_alibi_and_windows(form, block_k):
+    """What a step cut to its keys has to keep: alibi's distances, counted from
+    the block's own origin; a TRACED window (only the operand says where it
+    lies; w <= 0 is global); a static window's backward, which runs the whole
+    grid under the window; ``causal=False`` has no diagonal and runs its block
+    whole. Forward and gradients at 2,048 rows, at the call's own blocks (512 x
+    2048: steps of one to four sub-tiles) and at 512 x 1024 (skipped steps, whose
+    index maps stay behind), against the dense reference."""
+    S, H = 2048, 2
+    q, k, v = _qkv(B=1, S=S, H=H, D=32, seed=11)
+    tgt = jax.random.normal(jax.random.PRNGKey(12), q.shape)
+    slopes = jnp.asarray([0.25, 0.0625], jnp.float32) if "alibi" in form else None
+    causal = not form.startswith("not-causal")
+    window = {"traced-window": 300.0, "alibi+traced-window": 300.0, "traced-window-global": -1.0,
+              "static-window": 300, "not-causal-window": 300.0}.get(form)
+    traced = window is not None and form != "static-window"
+    dense_window = window if window and window > 0 else None
+
+    def flash(q, k, v, w):
+        return flash_attention(q, k, v, causal=causal, alibi_slopes=slopes,
+                               window=w if traced else window, block_k=block_k)
+
+    def loss(fn):
+        return lambda q, k, v, w: jnp.sum(fn(q, k, v, w) * tgt)
+
+    ref = lambda q, k, v, w: _dense(q, k, v, causal, slopes, dense_window)
+    w = jnp.float32(window if traced else 0.0)
+    out, gf = jax.value_and_grad(loss(flash), argnums=(0, 1, 2))(q, k, v, w)
+    want, gr = jax.value_and_grad(loss(ref), argnums=(0, 1, 2))(q, k, v, w)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v, w)), np.asarray(ref(q, k, v, w)),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(float(out), float(want), rtol=1e-4)
+    for a, b, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("rows", [1024, 2048])
+def test_causal_schedule_with_narrower_value_heads(rows):
+    """Latent attention's shape: value heads narrower than the q/k heads, forward
+    only (the backward kernels take one head size)."""
+    ks = jax.random.split(jax.random.PRNGKey(rows), 3)
+    q, k = (jax.random.normal(kk, (1, rows, 2, 48)) * 0.5 for kk in ks[:2])
+    v = jax.random.normal(ks[2], (1, rows, 2, 32)) * 0.5
+    np.testing.assert_allclose(np.asarray(flash_attention(q, k, v)), np.asarray(_dense(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.fixture
+def counted_tiles(monkeypatch):
+    """Every block of scores the kernels RUN (interpret mode: the kernel's jaxpr
+    is evaluated, so a callback in a case's body fires once an execution):
+    ``[(rows, keys), ...]``."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    ran, scores = [], fa._block_scores
+
+    def counting(q, k_blk, *a, **kw):
+        jax.debug.callback(lambda: ran.append((q.shape[0], k_blk.shape[0])))
+        return scores(q, k_blk, *a, **kw)
+
+    monkeypatch.setattr(fa, "_block_scores", counting)
+    return ran
+
+
+@pytest.mark.parametrize("rows,pct,parent_pct", [
+    (1024, 150.0, 200.0), (2048, 125.0, 150.0), (4096, 112.5, 125.0), (8192, 106.25, 112.5),
+    (1536, 133.33, 133.33)])
+def test_causal_tiles_pct_is_what_an_instrumented_run_counts(rows, pct, parent_pct, counted_tiles):
+    """``causal_tiles_pct`` (the pure function the prefill span and the train
+    step's ledger row quote) against the score elements an instrumented
+    interpret run of the forward computes, as a % of those at or under the
+    diagonal; ``parent_pct``: what whole blocks (the schedule before PR 51) ran
+    there."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    q, k, v = _qkv(B=1, S=rows, H=1, D=8, seed=rows)
+    jax.block_until_ready(flash_attention(q, k, v))
+    jax.effects_barrier()
+    computed = sum(r * c for r, c in counted_tiles)
+    tiles_pct = fa.causal_tiles_pct(rows, 8, 4)
+    assert tiles_pct == pytest.approx(100 * computed / (rows * (rows + 1) / 2))
+    assert tiles_pct == pytest.approx(pct, rel=2e-3)
+    blocks = fa._auto_block(rows, 512), fa._auto_block(rows, 1024)  # PR 50's, run whole
+    whole = sum(fa._diag(qi, *blocks) + 1 for qi in range(rows // blocks[0])) * blocks[0] * blocks[1]
+    assert 100 * whole / (rows * (rows + 1) / 2) == pytest.approx(parent_pct, rel=2e-3)
+    assert tiles_pct <= 100 * whole / (rows * (rows + 1) / 2)
+
+
+@pytest.mark.parametrize("width,itemsize,block_k", [(128, 2, 2048), (256, 2, 2048), (128, 4, 2048),
+                                                    (192, 4, 1024), (256, 4, 1024), (64, 2, 2048)])
+def test_key_block_is_as_coarse_as_its_bytes_allow(width, itemsize, block_k):
+    """The key block is 2,048 keys where one block of the widest head holds
+    ``KEY_BLOCK_BYTES`` or less (what the chip's compiler took:
+    tests/test_chip_compile.py), halved otherwise; the sub-tile count of the
+    causal schedule does not depend on it."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    assert fa._key_block(8192, width, itemsize) == block_k
+    assert fa._key_block(1536, width, itemsize) == 512 and fa._key_block(1024, width, itemsize) == 1024
+    assert fa.causal_tiles_pct(8192, width, itemsize) == pytest.approx(106.25, rel=2e-3)
+
+
+@pytest.mark.parametrize("window", [None, 700.0], ids=["no-window", "traced-window"])
+def test_backward_kernels_run_the_forward_s_tiles(window, counted_tiles):
+    """dK/dV and dQ cut their steps by the same rule: at 2,048 rows (one key
+    block) each runs the forward's 10 sub-tiles' worth of scores (12 before
+    PR 51) in four steps of one to four sub-tiles; a traced window changes no
+    step."""
+    q, k, v = _qkv(B=1, S=2048, H=1, D=8, seed=2)
+    w = None if window is None else jnp.float32(window)
+    jax.block_until_ready(jax.grad(lambda q, k, v: jnp.sum(flash_attention(q, k, v, window=w)),
+                                   argnums=(0, 1, 2))(q, k, v))
+    jax.effects_barrier()
+    assert sorted(counted_tiles) == sorted(3 * [(512, 512), (512, 1024), (512, 1536), (512, 2048)])
+
+
+@pytest.mark.parametrize("blocks,cases", [
+    ((512, 1024), [(1, 512), (2, 1024)]), ((512, 512), [(1, 512)]),
+    ((1024, 1024), [(2, 1024)]), ((768, 768), [(3, 768)]), ((128, 256), [(1, 256)]),
+    ((256, 2048), [(1, 512), (2, 1024), (3, 1536), (4, 2048)]),
+    ((1024, 2048), [(2, 1024), (4, 2048)]),
+], ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else None)
+def test_step_cases_are_every_case_a_grid_meets_and_no_other(blocks, cases):
+    """``_step_cases``, the pieces of straight-line code a kernel holds, from
+    the block shapes alone, against every (query block, key block) pair of a
+    long grid counted by hand: each step that computes meets exactly one case,
+    the block's keys up to the end of the sub-tile that holds the last key at or
+    under its last row; without a diagonal one case, the whole block."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    bq, bk = blocks
+    assert fa._step_cases(bq, bk, True) == cases
+    sub = fa._sub_tile(bk)
+    met = set()
+    for qi in range(8 * bk // bq):
+        for kj in range(8):
+            seen = fa._keys_seen(qi * bq, bq, kj * bk, sub, bk // sub)
+            r_last, k0 = qi * bq + bq - 1, kj * bk
+            if seen:
+                met.add((seen, seen * sub))
+                # every key of the block at or under the last row, and no sub-tile beyond them
+                assert k0 + seen * sub - 1 >= min(r_last, k0 + bk - 1) and k0 + (seen - 1) * sub <= r_last
+            else:
+                assert k0 > r_last
+    assert met == set(cases)
+    assert fa._step_cases(bq, bk, False) == [(None, bk)]
+
+
+@pytest.mark.parametrize("blocks,rows", [((512, 1024), 2048), ((512, 512), 1536), ((1024, 512), 2048),
+                                         ((128, 256), 512)])
+def test_a_step_that_computes_nothing_fetches_nothing(blocks, rows):
+    """The streamed operand's index map stays on the last block its row needs
+    (forward, dQ: the diagonal's key block; dK/dV: the first query block with a
+    row at or under the key block's first key), so a step strictly above the
+    diagonal starts no copy; every block a step COMPUTES on is still the block
+    of its own index."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    bq, bk = blocks
+    num_q, num_k = rows // bq, rows // bk
+    keys, qs = fa._streamed_keys(True, bq, bk), fa._streamed_rows(True, bq, bk, num_q)
+    skipped = 0
+    for qi in range(num_q):
+        for kj in range(num_k):
+            computes = kj * bk <= qi * bq + bq - 1
+            got_k, got_q = int(keys(0, qi, kj)[1]), int(qs(0, kj, qi)[1])
+            if computes:
+                assert (got_k, got_q) == (kj, qi)
+            else:
+                skipped += 1
+                assert got_k == fa._diag(qi, bq, bk) < kj and 0 <= got_q < num_q
+                assert got_q * bq + bq - 1 >= kj * bk > (got_q - 1) * bq + bq - 1
+    assert skipped == sum(kj * bk > qi * bq + bq - 1 for qi in range(num_q) for kj in range(num_k))
+    assert fa._streamed_keys(False, bq, bk)(0, 0, num_k - 1) == (0, num_k - 1, 0)
+    assert fa._streamed_rows(False, bq, bk, num_q)(0, num_k - 1, 0) == (0, 0, 0)
+
+
+@pytest.mark.parametrize("attn,blocks,pct", [("flash", (0, 0), 199.22), ("flash", (128, 128), 149.42),
+                                             ("xla", (0, 0), None)])
+def test_train_step_ledger_row_says_what_the_causal_kernels_compute(attn, blocks, pct):
+    """The schedule is a constant of the trace: as the train step is traced the
+    engine annotates its program-ledger row with ``causal_tiles_pct`` at the
+    micro-batch's sequence length and the configured OUTER blocks (256 rows: one
+    block computes the square, 128 x 128 blocks three of four); a model that does
+    not attend through the kernels says nothing."""
+    import deepspeed_tpu
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    cfg = TransformerConfig(vocab_size=128, max_seq_len=256, num_layers=1, num_heads=2,
+                            hidden_size=32, dtype=jnp.float32, attn_impl=attn,
+                            flash_block_q=blocks[0], flash_block_k=blocks[1])
+    engine, _, _, _ = deepspeed_tpu.initialize(model=Model(cfg), config={
+        "train_batch_size": 8, "train_micro_batch_size_per_gpu": 1,
+        "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})
+    toks = np.random.default_rng(0).integers(0, 128, size=(8, 257)).astype(np.int32)
+    engine.train_batch({"tokens": toks})
+    row = next(r for r in engine.telemetry_snapshot()["program_ledger"]
+               if r["name"].startswith("train/train_step"))
+    assert row.get("causal_tiles_pct") == pct
+    if pct:
+        assert pct == round(fa.causal_tiles_pct(256, 16, 4, blocks[0] or None, blocks[1] or None), 2)

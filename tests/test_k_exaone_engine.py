@@ -166,6 +166,9 @@ def test_the_engine_serves_a_flash_window_bucket_and_says_which_grid(program,
     blocks_pct = fa.window_grid(LONG, WINDOW, cfg.num_heads, cfg.head_dim, cfg.head_dim,
                                 jnp.dtype(cfg.dtype).itemsize)[1]
     assert long["window_blocks_pct"] == round(blocks_pct, 2) < 100
+    # its whole-context layers take the causal grid, and say what it computes of the triangle
+    tiles_pct = fa.causal_tiles_pct(LONG, cfg.head_dim, jnp.dtype(cfg.dtype).itemsize)
+    assert long["causal_tiles_pct"] == round(tiles_pct, 2) and "causal_tiles_pct" not in short
     assert short["attn"] == "dense+window" and "window_grid" not in short
 
 

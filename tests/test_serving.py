@@ -652,6 +652,7 @@ def test_prefill_span_says_which_attention_its_program_runs(kernel_from_256_rows
     short one's does not, and both serve the model's own greedy tokens."""
     import time
 
+    from deepspeed_tpu.ops.pallas.flash_attention import causal_tiles_pct
     from deepspeed_tpu.telemetry import tracing
 
     tfm, rows = kernel_from_256_rows, _FLASH_ROWS
@@ -667,6 +668,9 @@ def test_prefill_span_says_which_attention_its_program_runs(kernel_from_256_rows
     spans = {sp.attrs["uid"]: sp.attrs for sp in tracing.spans(t0) if sp.name == "prefill"}
     assert (spans[0]["bucket"], spans[0]["attn"]) == (rows, "flash")
     assert spans[1]["bucket"] < rows and spans[1]["attn"] == "dense"
+    # and what the kernel's causal schedule computes of the triangle at the bucket's rows
+    assert spans[0]["causal_tiles_pct"] == round(causal_tiles_pct(rows, cfg.head_dim, 4), 2) >= 100
+    assert "causal_tiles_pct" not in spans[1]
     for uid, prompt in ((0, long_p), (1, short_p)):
         assert res[uid].status == "ok"
         logits = tfm.apply(cfg, eng.params, prompt[None])  # training's forward: xla attention
