@@ -351,17 +351,17 @@ class SlotWorker:
         )()
         # what ONE decode step must read and write of per-sequence state, a live
         # row: the leaves of every layer that keeps any (the layout's own count:
-        # every layer of a model with a mixer, the conv layers of one with layers
-        # by operator), once each way (0 for a model without)
+        # every layer of a model with a mixer, the conv or delta layers of one with
+        # layers by operator), once each way (0 for a model without)
         kept = tfm.cache_layers(self.cfg)
         self.state_layers = kept[tfm.STATE]
         self.state_bytes_per_slot = self.state_layers * tfm.cache_state_bytes(self.cfg)
         # how many layers keep a ring of ``local_attn_window`` positions a slot (0: none)
         self.window_layers = kept[tfm.RING]
         # what the programs ran of each operator (a model with ``layer_operators`` only)
-        self.operator_attrs = ({"conv_layers": len(self.cfg.conv_layers),
-                                "attn_layers": self.cfg.num_layers - len(self.cfg.conv_layers)}
-                               if self.cfg.conv_layers else {})
+        self.operator_attrs = {
+            f"{op}_layers": len(self.cfg.layers_of(op))
+            for op in sorted(set(self.cfg.layer_operators or ()), reverse=True)}
         # where the programs read a routed layer's expert banks from ("in_place" /
         # "sliced"; None for a model without dropless routing): the rule they trace by
         self.expert_bank = tfm.expert_bank_form(self.cfg, self.params.get("moe"), self.mesh)
@@ -725,9 +725,10 @@ class SlotWorker:
         for a model without): ``state_rows``, the active rows whose state the step
         advanced, and ``state_bytes``, the per-sequence bytes it had to read and
         write for them (2 x rows x the layers that keep state x a layer's leaves:
-        a mixer's state + convolution tail, a short convolution's tail); and for a
-        model with layers by operator how many of each the program ran
-        (``conv_layers``, ``attn_layers``)."""
+        a mixer's state + convolution tail, a short convolution's tail, a delta
+        rule's matrix + filter tail); and for a model with layers by operator how
+        many of each the program ran (``conv_layers`` or ``delta_layers``,
+        ``attn_layers``)."""
         if not self.state_bytes_per_slot:
             return {}
         return {"state_rows": n_active,
@@ -750,8 +751,10 @@ class SlotWorker:
         """What a prefill or chunk span says of the block its program was traced
         with. A state-space mixer's scan: the chunks it ran (those of the
         bucket's padding among them) and ``state_rows``, the live rows that moved
-        the state. A model with layers by operator: ``conv_layers`` / ``attn_layers``
-        it ran, ``state_rows`` and ``state_bytes``, the tail it wrote. A routed
+        the state. A model with layers by operator: ``conv_layers`` (or
+        ``delta_layers``) / ``attn_layers`` it ran, ``state_rows`` and ``state_bytes``,
+        the state it wrote for the slot, and with delta layers ``scan_chunks``, the
+        chunks of ``DELTA_CHUNK`` rows the rule's block form ran. A routed
         model's ``expert_bank``: where the program reads
         layer l of the three banks from (``expert_bank_form``), and
         ``expert_gemm``: what multiplies its rows through the experts
@@ -766,8 +769,10 @@ class SlotWorker:
             attrs.update(state_rows=int(live), **self.operator_attrs)
             if self.cfg.ssm_state_size:
                 attrs.update(scan_chunks=-(-int(rows) // self.cfg.ssm_chunk_size))
-            else:  # the tail written for the slot: one layer's leaves a layer that keeps them
+            else:  # the state written for the slot: one layer's leaves a layer that keeps them
                 attrs.update(state_bytes=self.state_bytes_per_slot)
+                if self.cfg.delta_layers:
+                    attrs.update(scan_chunks=-(-int(rows) // tfm.DELTA_CHUNK))
         return attrs
 
     def _sampler_rows(self, sp, temperature, top_k, top_p):
@@ -1017,8 +1022,8 @@ class SlotWorker:
         if self.state_bytes_per_slot:
             raise NotImplementedError(
                 f"{what} with per-sequence state in the cache (a state-space mixer's recurrent "
-                "state, a short convolution's tail): the wire form carries windows of per-token "
-                "K/V and no per-sequence state")
+                "state, a short convolution's tail, a delta rule's matrix): the wire form carries "
+                "windows of per-token K/V and no per-sequence state")
         if self.window_layers:
             raise NotImplementedError(
                 f"{what} with window layers (local_attn_layers): the wire form carries windows "

@@ -127,8 +127,19 @@ class TransformerConfig:
     # every layer attends. The two operators' leaves differ, so the parameters
     # lie in stacks BY OPERATOR (``init``) and the cache keeps K/V for the
     # attention layers alone and the filter's tail for the others (``cache_layout``)
+    # or "delta", a gated delta rule in its place (Qwen3-Next's; ``_gated_delta`` has
+    # the equations): ``delta_key_heads`` key heads and ``delta_value_heads`` value
+    # heads (a multiple of them) of ``delta_head_dim``, a filter of ``conv_kernel``
+    # taps over its q | k | v, and a float32 MATRIX [head width, head width] a value
+    # head that a sequence keeps between steps beside the filter's tail
     layer_operators: Optional[tuple] = None
     conv_kernel: int = 0
+    delta_key_heads: int = 0
+    delta_value_heads: int = 0
+    delta_head_dim: int = 0
+    # An output gate on attention (Qwen3-Next's): the query projection is twice as
+    # wide, a head's second half gates the head's attention output through a sigmoid
+    attn_output_gate: bool = False
     layernorm_epsilon: float = 1e-5
     tie_embeddings: bool = True
     use_bias: bool = True
@@ -212,6 +223,9 @@ class TransformerConfig:
     # dropless only: one gated expert of this width that every token goes
     # through beside its routed ones (0: none)
     moe_shared_size: int = 0
+    # dropless only: the shared expert's output is multiplied by sigmoid(x w_g), one
+    # learned [hidden_size] vector a layer (Qwen3-Next's ``shared_expert_gate``)
+    moe_shared_gate: bool = False
     # dropless only: this many leading layers have a dense gated feed-forward
     # of width ``dense_intermediate_size`` instead of the routed block
     # (``intermediate_size`` stays the width of one expert)
@@ -276,7 +290,7 @@ class TransformerConfig:
     def layer_kinds(self):
         """Per layer (window, rotary, operator): the positions a query sees behind
         it (0: the whole context), whether its q and k are rotated, and what stands
-        in the attention sublayer's place (``OPERATORS``: "attn" itself, or "conv").
+        in the attention sublayer's place (``OPERATORS``: "attn" itself, "conv" or "delta").
         None where every layer is of one kind and the loop need not tell them apart."""
         if (self.local_attn_layers is None and self.rotary_layers is None
                 and self.layer_operators is None):
@@ -287,10 +301,40 @@ class TransformerConfig:
         rotary = [bool(on) for on in (self.rotary_layers or (1,) * L)]
         return tuple(zip(windows, rotary, self.layer_operators or ("attn",) * L))
 
+    def layers_of(self, operator: str) -> tuple:
+        """The layers (model indices) whose operator is ``operator``."""
+        return tuple(l for l, op in enumerate(self.layer_operators or ()) if op == operator)
+
     @property
     def conv_layers(self) -> tuple:
         """The layers (model indices) whose operator is the gated short convolution."""
-        return tuple(l for l, op in enumerate(self.layer_operators or ()) if op == "conv")
+        return self.layers_of("conv")
+
+    @property
+    def delta_layers(self) -> tuple:
+        """The layers (model indices) whose operator is the gated delta rule."""
+        return self.layers_of("delta")
+
+    @property
+    def stateful_layers(self) -> tuple:
+        """The layers whose operator is not attention: each keeps per-sequence state
+        (``cache_layout``) and nothing by position."""
+        return tuple(l for l, op in enumerate(self.layer_operators or ()) if op != "attn")
+
+    @property
+    def delta_key_dim(self) -> int:
+        """The delta rule's q (and k) channels: key heads x head width."""
+        return self.delta_key_heads * self.delta_head_dim
+
+    @property
+    def delta_value_dim(self) -> int:
+        """The delta rule's v (and z) channels: value heads x head width."""
+        return self.delta_value_heads * self.delta_head_dim
+
+    @property
+    def delta_conv_dim(self) -> int:
+        """The channels the delta rule's filter runs over: q | k | v."""
+        return 2 * self.delta_key_dim + self.delta_value_dim
 
     @property
     def window_layers(self) -> tuple:
@@ -531,26 +575,54 @@ def _refuse_uncoded_kinds_and_share(cfg: "TransformerConfig") -> None:
 
 # What may stand in a layer's attention sublayer (``layer_operators``); also the keys
 # of the operators' stacks under ``params["layers"]``.
-OPERATORS = ("attn", "conv")
+OPERATORS = ("attn", "conv", "delta")
 
 
 def _refuse_uncoded_operators(cfg: "TransformerConfig") -> None:
-    """``_refuse_uncoded`` for ``layer_operators``: what the stacks by operator,
-    the layer loop's static kinds and the short convolution have no code for, by name."""
+    """``_refuse_uncoded`` for ``layer_operators``, the attention gate and the shared
+    expert's gate: what the stacks by operator, the layer loop's static kinds, the
+    short convolution and the delta rule have no code for, each refusal naming the
+    operator it refuses."""
     ops, L = cfg.layer_operators, cfg.num_layers
+    delta_sizes = (cfg.delta_key_heads, cfg.delta_value_heads, cfg.delta_head_dim)
+    if cfg.moe_shared_gate and not (cfg.moe_routing == "dropless" and cfg.moe_shared_size):
+        raise ValueError("moe_shared_gate without a shared expert (moe_routing='dropless', "
+                         "moe_shared_size > 0): nothing to gate")
+    if cfg.attn_output_gate:
+        no_code = {"latent attention (kv_lora_rank > 0)": cfg.kv_lora_rank > 0,
+                   "use_bias (the gate's half of the projection is drawn with none)": cfg.use_bias}
+        for what, refused in no_code.items():
+            if refused:
+                raise NotImplementedError(f"attn_output_gate with {what} has no code")
     if ops is None:
-        if cfg.conv_kernel:
-            raise ValueError("conv_kernel without layer_operators: no layer to filter")
+        if cfg.conv_kernel or any(delta_sizes):
+            raise ValueError("conv_kernel / delta_key_heads / delta_value_heads / delta_head_dim "
+                             "without layer_operators: no layer to filter")
         return
     if len(ops) != L or any(op not in OPERATORS for op in ops):
         raise ValueError(f"layer_operators is one of {OPERATORS} a layer ({L} layers), got {ops!r}")
-    if len(set(ops)) < 2:
-        raise ValueError("layer_operators states layers of BOTH operators (every layer "
-                         "attending is layer_operators=None; a model with no attention layer "
-                         f"has no per-token cache to size), got {ops!r}")
+    others = sorted(set(ops) - {"attn"})
+    if "attn" not in ops or not others:
+        raise ValueError("layer_operators states layers of BOTH operators, attention and one "
+                         "other (every layer attending is layer_operators=None; a model with no "
+                         f"attention layer has no per-token cache to size), got {ops!r}")
+    if len(others) > 1:
+        raise NotImplementedError(
+            f"layer_operators with both {others[0]!r} and {others[1]!r} layers has no code: the "
+            "cache keeps ONE operator's per-sequence leaves beside the attention layers' K/V")
+    op = others[0]
     if cfg.conv_kernel < 2:
-        raise ValueError(f"layer_operators with a 'conv' layer states conv_kernel >= 2 taps "
+        raise ValueError(f"layer_operators with a {op!r} layer states conv_kernel >= 2 taps "
                          f"(got {cfg.conv_kernel})")
+    if op == "delta":
+        kh, vh, width = delta_sizes
+        if min(delta_sizes) < 1 or vh % kh:
+            raise ValueError("layer_operators with a 'delta' layer states its sizes: "
+                             "delta_key_heads, delta_value_heads (a multiple of them) and "
+                             f"delta_head_dim, all > 0 (got {delta_sizes})")
+    elif any(delta_sizes):
+        raise ValueError("delta_key_heads / delta_value_heads / delta_head_dim without a 'delta' "
+                         "layer: no rule to size")
     no_code = {
         "latent attention (kv_lora_rank > 0)": cfg.kv_lora_rank > 0,
         "the state-space mixer (ssm_state_size > 0: a mixer beside every layer's attention)":
@@ -570,7 +642,7 @@ def _refuse_uncoded_operators(cfg: "TransformerConfig") -> None:
     }
     for what, refused in no_code.items():
         if refused:
-            raise NotImplementedError(f"layer_operators (a 'conv' layer) with {what} has no code")
+            raise NotImplementedError(f"layer_operators (a {op!r} layer) with {what} has no code")
 
 
 def refuse_in_pipeline(cfg: "TransformerConfig") -> None:
@@ -623,7 +695,7 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
     dropless = _dropless(cfg)
     mult = cfg.multiplier
     attn_in = mult("attention_in_multiplier")
-    La = L - len(cfg.conv_layers)  # the layers that attend: as long as the attention stacks are
+    La = L - len(cfg.stateful_layers)  # the layers that attend: as long as the attention stacks are
 
     def stack(key, shape, fan_in, n=L, scale=1.0):
         ks = jax.random.split(key, n)
@@ -632,7 +704,8 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
     layers = {
         "ln1_scale": jnp.ones((L, d)),
         "ln2_scale": jnp.ones((L, d)),
-        "wq": stack(keys[0], (d, H, Dh), d, La, scale=attn_in),
+        # with an output gate a head's projection is query | gate, each Dh wide
+        "wq": stack(keys[0], (d, H, Dh * (1 + cfg.attn_output_gate)), d, La, scale=attn_in),
         # a head width that is not hidden_size // num_heads: the fan-in is the heads'
         "wo": stack(keys[3], (H, Dv, d), d if cfg.kv_lora_rank else H * Dv, La,
                     scale=mult("attention_out_multiplier")),
@@ -672,8 +745,10 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
         )
         if not dropless:
             layers.update({"bi": jnp.zeros((L, f)), "bo_mlp": jnp.zeros((L, d))})
-    if cfg.layer_operators is not None:
-        layers = _stacks_by_operator(layers, _init_conv(cfg, more[7], stack))
+    if cfg.delta_layers:
+        layers = _stacks_by_operator(layers, "delta", _init_delta(cfg, more[7], stack))
+    elif cfg.layer_operators is not None:
+        layers = _stacks_by_operator(layers, "conv", _init_conv(cfg, more[7], stack))
     stated = cfg.multipliers or {}
     params = {
         # with a stated embedding multiplier the residual stream enters at the size of
@@ -703,7 +778,7 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
         lead, fd = cfg.moe_first_dense, cfg.dense_ffn_size
         params["moe"] = init_dropless(keys[9], L - lead, cfg.num_experts, d, f,
                                       shared=cfg.moe_shared_size, select_bias=cfg.moe_select_bias,
-                                      held=cfg.experts_held[1])
+                                      held=cfg.experts_held[1], shared_gate=cfg.moe_shared_gate)
         if lead:
             params["dense_ffn"] = {"wg": stack(keys[10], (d, fd), d, lead),
                                    "wi": stack(keys[11], (d, fd), d, lead),
@@ -749,15 +824,16 @@ def _init_mtp(cfg: TransformerConfig, key) -> dict:
 _ATTENTION_LEAVES = ("wq", "wk", "wv", "wo", "q_norm_scale", "k_norm_scale")
 
 
-def _stacks_by_operator(layers: dict, conv: dict) -> dict:
+def _stacks_by_operator(layers: dict, operator: str, own: dict) -> dict:
     """``init``'s (or ``logical_axes``') one dict of a layer's leaves -> the same
     with the STACKS BY OPERATOR: the norms and a dense feed-forward, which every
     layer has, stay [L, ...] at the top; the attention sublayer's leaves go under
-    ``"attn"`` ([attention layers, ...]) and the short convolution's under
-    ``"conv"`` ([conv layers, ...]), each stack as long as its layers are many."""
+    ``"attn"`` ([attention layers, ...]) and the other operator's (``own``: the short
+    convolution's or the delta rule's) under its name (``"conv"`` / ``"delta"``),
+    each stack as long as its layers are many."""
     shared = {k: v for k, v in layers.items() if k not in _ATTENTION_LEAVES}
     return {**shared, "attn": {k: layers[k] for k in _ATTENTION_LEAVES if k in layers},
-            "conv": conv}
+            operator: own}
 
 
 def _init_conv(cfg: TransformerConfig, key, stack) -> dict:
@@ -772,6 +848,38 @@ def _init_conv(cfg: TransformerConfig, key, stack) -> dict:
     return {"conv_in": stack(k_in, (d, 3 * d), d, n),
             "conv_w": jax.random.uniform(k_w, (n, K, d), minval=-bound, maxval=bound),
             "conv_out": stack(k_out, (d, d), d, n)}
+
+
+def _init_delta(cfg: TransformerConfig, key, stack) -> dict:
+    """The gated delta rule's leaves, [delta layers]-stacked: ``delta_in`` maps the
+    hidden state to q | k | v | z (key, key, value, value channels, in that order,
+    heads side by side within each), ``delta_ba`` to b | a (one of each a value
+    head), ``delta_conv`` the filter's taps [taps, q | k | v channels] (uniform
+    within 1 / sqrt(taps), as a depthwise ``Conv1d`` draws them), ``delta_a_log`` =
+    log U(0, 16) as the published initialiser has it (the draw's floor keeps the log
+    finite) and ``delta_dt_bias`` the inverse softplus of a log-uniform step in
+    [0.001, 0.1] (Gated DeltaNet's own initialiser and ``_init_mixer``'s: heads that
+    forget in a few tokens and heads that remember thousands), the gated norm's
+    [head width] scale, ``delta_out`` the projection. The published model class
+    fills ``dt_bias`` with 1 instead: with A in (0, 16) that is exp(g) < 0.01 a token
+    for four heads in five, whose output is then sign(q_t . k_t) x the normed v_t, a
+    value no 16-bit residual stream holds to a float32 reference (on the chip at
+    the published widths: max logit error 0.34 and a routing slack of 1.4 standard
+    deviations from heads whose q . k rounds across 0; PERF.md section 6, PR 52), and
+    whose state no step ever reads."""
+    d, K, n = cfg.hidden_size, cfg.conv_kernel, len(cfg.delta_layers)
+    Hv, Kd, Vd = cfg.delta_value_heads, cfg.delta_key_dim, cfg.delta_value_dim
+    k_in, k_ba, k_w, k_a, k_dt, k_out = jax.random.split(key, 6)
+    bound = 1.0 / math.sqrt(K)
+    dt = jnp.exp(jax.random.uniform(k_dt, (n, Hv), minval=math.log(1e-3), maxval=math.log(1e-1)))
+    return {"delta_in": stack(k_in, (d, 2 * Kd + 2 * Vd), d, n),
+            "delta_ba": stack(k_ba, (d, 2 * Hv), d, n),
+            "delta_conv": jax.random.uniform(k_w, (n, K, cfg.delta_conv_dim),
+                                             minval=-bound, maxval=bound),
+            "delta_a_log": jnp.log(jax.random.uniform(k_a, (n, Hv), minval=1e-3, maxval=16.0)),
+            "delta_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
+            "delta_norm_scale": jnp.ones((n, cfg.delta_head_dim)),
+            "delta_out": stack(k_out, (Vd, d), Vd, n)}
 
 
 def _init_mixer(cfg: TransformerConfig, keys, stack) -> dict:
@@ -844,8 +952,14 @@ def logical_axes(cfg: TransformerConfig) -> Params:
         )
         if not dropless:
             layers.update({"bi": ("layers", "mlp"), "bo_mlp": ("layers", "embed")})
-    if cfg.layer_operators is not None:  # B | C | z lie side by side in one axis: not split
-        layers = _stacks_by_operator(layers, {
+    if cfg.delta_layers:  # q | k | v | z lie side by side in one axis: not split
+        layers = _stacks_by_operator(layers, "delta", {
+            "delta_in": ("layers", "embed", None), "delta_ba": ("layers", "embed", None),
+            "delta_conv": ("layers", None, None), "delta_a_log": ("layers", None),
+            "delta_dt_bias": ("layers", None), "delta_norm_scale": ("layers", None),
+            "delta_out": ("layers", None, "embed")})
+    elif cfg.layer_operators is not None:  # B | C | z lie side by side in one axis: not split
+        layers = _stacks_by_operator(layers, "conv", {
             "conv_in": ("layers", "embed", None), "conv_w": ("layers", None, None),
             "conv_out": ("layers", None, "embed")})
     axes = {
@@ -867,7 +981,8 @@ def logical_axes(cfg: TransformerConfig) -> Params:
         from ..moe.dropless import dropless_logical_axes
 
         axes["moe"] = dropless_logical_axes(shared=bool(cfg.moe_shared_size),
-                                            select_bias=cfg.moe_select_bias)
+                                            select_bias=cfg.moe_select_bias,
+                                            shared_gate=cfg.moe_shared_gate)
         if cfg.moe_first_dense:
             axes["dense_ffn"] = {"wg": ("layers", "embed", "mlp"), "wi": ("layers", "embed", "mlp"),
                                  "wo_mlp": ("layers", "mlp", "embed")}
@@ -902,13 +1017,16 @@ def logical_axes(cfg: TransformerConfig) -> Params:
 # mixer the two projections and the convolution (its tail is cached in the
 # compute dtype) are cast; ``ssm_dt_bias``, ``ssm_a_log``, ``ssm_d`` and the gated
 # norm's scale enter float32 arithmetic and are read in float32. The gated short
-# convolution's two projections and its taps are cast likewise.
+# convolution's two projections and its taps are cast likewise, and the gated delta
+# rule's three projections and its taps (``delta_a_log``, ``delta_dt_bias`` and its
+# norm's scale are read in float32); the shared expert's gate vector (``w_gate``) too.
 _READ_IN_COMPUTE_DTYPE = frozenset({
     "wte", "wpe", "lm_head", "eh_proj",
     "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo", "wkv_a", "wkv_b",
     "wi", "wo_mlp", "bi", "bo_mlp", "wg",
     "ssm_in", "ssm_out", "ssm_conv", "ssm_conv_bias",
     "conv_in", "conv_out", "conv_w",
+    "delta_in", "delta_ba", "delta_conv", "delta_out", "w_gate",
 })
 
 
@@ -1214,7 +1332,7 @@ def remat_candidates(cfg: TransformerConfig) -> tuple:
     the relayout's backward, fused into the recomputed products before, ran as
     passes of their own (PERF.md section 6, PR 50)."""
     L, lead = cfg.num_layers, cfg.moe_first_dense
-    flash = cfg.num_layers - len(cfg.conv_layers) if cfg.attn_impl == "flash" else 0
+    flash = cfg.num_layers - len(cfg.stateful_layers) if cfg.attn_impl == "flash" else 0
     floor = L * cfg.hidden_size + flash * cfg.num_heads * (-(-cfg.value_head_dim // 128) * 128)
     routed = (L - lead) // cfg.moe_every if cfg.moe_every > 0 else 0
     gated = cfg.activation == "swiglu"
@@ -1430,11 +1548,17 @@ def _qkv_proj(cfg: TransformerConfig, lp, h, positions, rotary=True):
     block's ``attend`` expands or absorbs them (``_latent_expand`` /
     ``_latent_attention``). ``rotary`` (``rotary_layers``): whether THIS layer
     rotates, a Python bool where the loop knows the layer's kind and a traced
-    one where it scans layers of both (the rotated pair is then selected)."""
+    one where it scans layers of both (the rotated pair is then selected). With
+    ``attn_output_gate`` a fourth value comes last: the second half of every
+    head's query projection [B, T, H, Dh], which ``_attn_out_proj`` gates the
+    heads' output by."""
     with jax.named_scope("attn"):
         h = _act_q(cfg, h)
         h = _times(h, cfg.multiplier("attention_in_multiplier"))
         q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(h.dtype))
+        gate = ()
+        if cfg.attn_output_gate:  # query | gate, a head at a time
+            q, gate = q[..., :cfg.head_dim], (q[..., cfg.head_dim:],)
         if cfg.kv_lora_rank:
             R, Dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
             rope = partial(rotary_embed, positions=positions, rotary_dims=Dr,
@@ -1462,11 +1586,15 @@ def _qkv_proj(cfg: TransformerConfig, lp, h, positions, rotary=True):
                       for x in (q, k)]
             q, k = turned if rotary is True else [jnp.where(rotary, t, x)
                                                   for t, x in zip(turned, (q, k))]
-        return q, k, v
+        return (q, k, v, *gate)
 
 
-def _attn_out_proj(cfg: TransformerConfig, lp, attn_out):
+def _attn_out_proj(cfg: TransformerConfig, lp, attn_out, gate=None):
+    """The heads' output [B, T, H, Dv] through ``wo``; ``gate`` (``_qkv_proj``'s, with
+    ``attn_output_gate``): each head's output times sigmoid(its gate) first."""
     with jax.named_scope("attn"):
+        if gate is not None:
+            attn_out = attn_out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(attn_out.dtype)
         attn_out = _act_q(cfg, attn_out)
         out = jnp.einsum("bshk,hkd->bsd", attn_out, lp["wo"].astype(attn_out.dtype))
         if cfg.use_bias:
@@ -1618,6 +1746,199 @@ def _index_in_kind(cfg: TransformerConfig) -> tuple:
         out.append(seen.get(kind, 0))
         seen[kind] = out[-1] + 1
     return tuple(out)
+
+
+# Rows a chunk of the gated delta rule's block form (``_delta_chunks``): the
+# published code's (``torch_chunk_gated_delta_rule``), half a lane tile, so that
+# the [chunk, chunk] matrices a head stay small beside the [head width, head width] state.
+DELTA_CHUNK = 64
+
+
+def _unit_lower_inverse(A):
+    """(I + A)^-1 of strictly lower-triangular A [..., Q, Q] (Q a power of two),
+    float32, with no loop over a chunk's rows: A is nilpotent (A^Q = 0), so the
+    series sum_n (-A)^n ends and factors as (I - A)(I + A^2)(I + A^4) ... (I +
+    A^(Q/2)), exactly: log2(Q) - 1 squarings and as many products, each a batch of
+    [Q, Q] float32 matmuls at full precision (the inverse multiplies every value
+    row of the chunk: an error in it is every later row's)."""
+    Q = A.shape[-1]
+    mm = partial(jnp.matmul, precision=lax.Precision.HIGHEST)
+    inv, power, n = jnp.eye(Q, dtype=A.dtype) - A, A, 1
+    while 2 * n < Q:
+        power, n = mm(power, power), 2 * n
+        inv = inv + mm(inv, power)
+    return inv
+
+
+def _delta_chunks(q, k, v, g, beta, S0):
+    """The gated delta rule S_t = exp(g_t) S_{t-1} + k_t (x) beta_t (v_t - (exp(g_t)
+    S_{t-1})^T k_t), o_t = S_t^T q_t over many rows, in its chunked form (Gated
+    DeltaNet, arXiv:2412.06464; ``torch_chunk_gated_delta_rule``): q, k [B, T, Hk, D]
+    (normalised; q scaled), v [B, T, H, D], g (<= 0) and beta [B, T, H] float32 (both
+    0 on a row that must not move the state), S0 [B, H, D, D] float32 (key dimension
+    first) -> (o [B, T, H, D] float32, S_T). Value head h reads key head h // (H //
+    Hk); q and k are never repeated to the value heads. T is padded to whole chunks
+    with g = beta = 0. With c_i the running sum of g inside a chunk of Q rows:
+
+        A = strictly-lower((beta k) k^T * exp(c_i - c_j));  Tm = (I + A)^-1
+        U = Tm (beta v);  W = Tm (beta k * exp(c))
+        chunk after chunk:  v' = U - W S
+                            o  = (q * exp(c)) S + lower(q k^T * exp(c_i - c_j)) v'
+                            S <- exp(c_last) S + (k * exp(c_last - c))^T v'
+
+    Everything above the last three lines is computed for all chunks at once; the
+    ``lax.scan`` over chunks carries ONE state and runs four batched matmuls a chunk.
+    Nothing rows x rows is formed: the largest temporaries are [chunks, heads, Q, Q]
+    and [rows, heads, D]. The decays, ``Tm`` and the states are float32; the
+    contractions take their operands in q's dtype and accumulate in float32."""
+    f32, dt = jnp.float32, q.dtype
+    B_, T, H, D = v.shape
+    Hk, Q = q.shape[2], DELTA_CHUNK
+    r = H // Hk
+    pad = (-T) % Q
+    if pad:
+        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                            for a in (q, k, v, g, beta))
+    nc = (T + pad) // Q
+    qc, kc = q.reshape(B_, nc, Q, Hk, D), k.reshape(B_, nc, Q, Hk, D)
+    vc = v.reshape(B_, nc, Q, Hk, r, D)
+    bc = beta.reshape(B_, nc, Q, Hk, r)
+    g_t, b_t = (jnp.moveaxis(x.reshape(B_, nc, Q, Hk, r), 2, -1) for x in (g, beta))
+    c_t = jnp.cumsum(g_t, axis=-1)  # [B, nc, Hk, r, Q]: falling from 0 inside each chunk
+    to_end = jnp.flip(jnp.cumsum(jnp.flip(g_t, -1), axis=-1), -1) - g_t  # c_last - c_i, summed
+    # from the END: a head that forgets fast has |c| in the hundreds, and a difference of two
+    # such sums is good to 1e-4 where exp() of it is multiplied into a neighbouring row
+    at = jnp.arange(Q)
+    lower = at[:, None] >= at[None, :]
+    between = (at[None, None, :] > at[None, :, None]) & (at[None, None, :] <= at[:, None, None])
+    # c_i - c_j as the sum of the g between them (j < m <= i), each pair's own sum
+    gaps = jnp.einsum("bcgrm,ijm->bcgrij", g_t, between.astype(f32),
+                      precision=lax.Precision.HIGHEST)
+    decay = jnp.exp(jnp.where(lower, gaps, -jnp.inf))
+    kk = jnp.einsum("bcigd,bcjgd->bcgij", kc, kc, preferred_element_type=f32)
+    qk = jnp.einsum("bcigd,bcjgd->bcgij", qc, kc, preferred_element_type=f32)
+    A = jnp.where(at[:, None] > at[None, :], kk[:, :, :, None] * decay * b_t[..., :, None], 0.0)
+    Tm = _unit_lower_inverse(A).astype(dt)  # [B, nc, Hk, r, Q, Q]
+    # what the scan over chunks reads, chunk first and heads before rows ([nc, B, Hk, r, Q,
+    # ...]): every contraction in its body is then a plain batched matmul over (B, Hk, r)
+    rows = lambda x, scale: (jnp.moveaxis(x, 2, -2)[:, :, :, None]  # a key head's rows, for
+                             * scale[..., None]).astype(dt)         # each of its value heads
+    chunks_first = lambda x: jnp.moveaxis(x, 1, 0)
+    U = jnp.einsum("bcgrij,bcjgrd->cbgrid", Tm, (vc * bc[..., None]).astype(dt),
+                   preferred_element_type=f32)
+    W = jnp.einsum("bcgrij,bcgrjd->cbgrid", Tm, rows(kc, b_t * jnp.exp(c_t)),
+                   preferred_element_type=f32).astype(dt)
+    intra = chunks_first((qk[:, :, :, None] * decay).astype(dt))  # [nc, B, Hk, r, Q, Q]
+    q_in = chunks_first(rows(qc, jnp.exp(c_t)))  # [nc, B, Hk, r, Q, D]
+    k_out = chunks_first(rows(kc, jnp.exp(to_end)))
+    whole = chunks_first(jnp.exp(c_t[..., -1]))  # [nc, B, Hk, r]: a chunk's whole decay
+
+    def chunk(S, xs):
+        U_c, W_c, intra_c, q_c, k_c, whole_c = xs
+        held = S.astype(dt)  # the state ENTERING the chunk is what it reads
+        moved = U_c - jnp.einsum("bgrik,bgrkv->bgriv", W_c, held, preferred_element_type=f32)
+        o = (jnp.einsum("bgrik,bgrkv->bgriv", q_c, held, preferred_element_type=f32)
+             + jnp.einsum("bgrij,bgrjv->bgriv", intra_c, moved.astype(dt),
+                          preferred_element_type=f32))
+        S = whole_c[..., None, None] * S + jnp.einsum(
+            "bgrjk,bgrjv->bgrkv", k_c, moved.astype(dt), preferred_element_type=f32)
+        return S, o
+
+    S_T, o = lax.scan(chunk, S0.astype(f32).reshape(B_, Hk, r, D, D),
+                      (U, W, intra, q_in, k_out, whole))
+    o = o.transpose(1, 0, 4, 2, 3, 5).reshape(B_, nc * Q, H, D)[:, :T]  # [nc, B, Hk, r, Q, D] ->
+    return o, S_T.reshape(B_, H, D, D)
+
+
+def _delta_step(q, k, v, g, beta, S0):
+    """``_delta_chunks`` for ONE row a sequence (a decode step): the recurrence
+    itself, elementwise in float32 (q, k [B, 1, Hk, D] float32, the rest as there).
+    With a = exp(g) and S the state read, d = beta (v - a S^T k), S' = a S + k (x) d
+    and o = S'^T q = a S^T q + (k . q) d: S is read for its two products with k and q
+    and once more for the update, and written once; no contraction rounds it."""
+    f32 = jnp.float32
+    B_, _, H, D = v.shape
+    Hk = q.shape[2]
+    r = H // Hk
+    S = S0.astype(f32).reshape(B_, Hk, r, D, D)
+    a, b = (x[:, 0].reshape(B_, Hk, r, 1) for x in (jnp.exp(g), beta))
+    qf, kf = (x[:, 0].astype(f32)[:, :, None, :, None] for x in (q, k))  # [B, Hk, 1, D, 1]
+    Sk, Sq = jnp.sum(S * kf, axis=-2), jnp.sum(S * qf, axis=-2)  # [B, Hk, r, D]: S^T k, S^T q
+    d = b * (v[:, 0].astype(f32).reshape(B_, Hk, r, D) - a * Sk)
+    o = a * Sq + jnp.sum(qf * kf, axis=-2) * d
+    S = a[..., None] * S + kf * d[..., None, :]
+    return o.reshape(B_, 1, H, D), S.reshape(B_, H, D, D)
+
+
+def _gated_delta(cfg: TransformerConfig, lp, h, state, l, live):
+    """The gated delta rule of one layer (Qwen3-Next's ``Qwen3NextGatedDeltaNet``, in
+    the attention sublayer's place) on the normed h [B, T, d] -> (its output [B, T,
+    d], state). With Hk key heads and Hv value heads of D (``delta_key_heads``,
+    ``delta_value_heads``, ``delta_head_dim``):
+
+        [q | k | v | z] = h W_in      Hk D, Hk D, Hv D, Hv D channels, in that order
+        [b | a]         = h W_ba      Hv + Hv
+        [q | k | v]     = silu(filter([q | k | v]))   depthwise, causal, no bias
+        q, k            -> Hk heads, each x * rsqrt(sum x^2 + 1e-6); q * D^-1/2
+        beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)      float32, a value head
+        S_t = exp(g_t) S_{t-1};  d_t = beta_t (v_t - S_t^T k_t);  S_t += k_t (x) d_t;  o_t = S_t^T q_t
+        out = (w * rmsnorm_D(o) * silu(z)) W_out                       the norm BEFORE the gate
+
+    Value head i reads key head i // (Hv / Hk). ``state`` is None (``apply``: the
+    sequence starts from nothing and keeps nothing) or the cache tree, whose
+    ``STATE`` leaves hold per delta layer and row the matrix S [Hv, D, D] in float32
+    (key dimension first) and ``conv``, the last ``conv_kernel - 1`` rows of the
+    filter's input q | k | v: layer ``l`` (the model's index; the leaves are indexed
+    among the delta layers) is read and written back in place. ``live`` [B, T] bool
+    or None (all): the rows that are a sequence's own. On any other row g = 0 and
+    beta = 0, so the state passes through exactly, and the tail kept is that of the
+    last LIVE rows (``_filter_tail``). T = 1 takes the recurrence itself
+    (``_delta_step``), more rows the chunked form from the state given
+    (``_delta_chunks``)."""
+    f32 = jnp.float32
+    Hk, Hv, D, K = cfg.delta_key_heads, cfg.delta_value_heads, cfg.delta_head_dim, cfg.conv_kernel
+    Kd, conv_dim = cfg.delta_key_dim, cfg.delta_conv_dim
+    B_, T, _ = h.shape
+    with jax.named_scope("delta"):
+        h = _act_q(cfg, h)
+        proj = jnp.einsum("bsd,dz->bsz", h, lp["delta_in"].astype(h.dtype))
+        ba = jnp.einsum("bsd,dz->bsz", h, lp["delta_ba"].astype(h.dtype),
+                        preferred_element_type=f32)
+        beta = jax.nn.sigmoid(ba[..., :Hv])
+        g = -jnp.exp(lp["delta_a_log"].astype(f32)) * jax.nn.softplus(
+            ba[..., Hv:] + lp["delta_dt_bias"].astype(f32))
+        if live is not None:
+            g, beta = (jnp.where(live[..., None], x, 0.0) for x in (g, beta))
+        if state is None:
+            tail, S0 = jnp.zeros((B_, K - 1, conv_dim), h.dtype), jnp.zeros((B_, Hv, D, D), f32)
+        else:
+            at = jnp.asarray(_index_in_kind(cfg), jnp.int32)[l]
+            tail, S0 = (lax.dynamic_index_in_dim(state[STATE][name], at, keepdims=False)
+                        for name in ("conv", "delta"))
+        rows, conv = _causal_filter(tail, proj[..., :conv_dim], lp["delta_conv"])
+        qkv = jax.nn.silu(conv)
+        unit = lambda x: x * lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+        q = unit(qkv[..., :Kd].reshape(B_, T, Hk, D)) * D ** -0.5
+        k = unit(qkv[..., Kd:2 * Kd].reshape(B_, T, Hk, D))
+        v = qkv[..., 2 * Kd:].reshape(B_, T, Hv, D)
+        if T == 1:
+            o, S = _delta_step(q, k, v, g, beta, S0)
+        else:
+            o, S = _delta_chunks(*(x.astype(h.dtype) for x in (q, k, v)), g, beta, S0)
+        o = o * lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.layernorm_epsilon)
+        z = proj[..., conv_dim:].astype(f32).reshape(B_, T, Hv, D)
+        gated = (o * lp["delta_norm_scale"].astype(f32) * jax.nn.silu(z)).astype(h.dtype)
+        out = jnp.einsum("bsi,id->bsd", gated.reshape(B_, T, Hv * D),
+                         lp["delta_out"].astype(h.dtype))
+        if state is None:
+            return out, state
+        held = state[STATE]
+        held = {"conv": lax.dynamic_update_slice(
+                    held["conv"], _filter_tail(rows, K, live)[None].astype(held["conv"].dtype),
+                    (at, 0, 0, 0)),
+                "delta": lax.dynamic_update_slice(
+                    held["delta"], S[None].astype(held["delta"].dtype), (at, 0, 0, 0, 0))}
+        return out, {**state, STATE: held}
 
 
 def _ssm_mixer(cfg: TransformerConfig, lp, h, state, l, live):
@@ -1802,8 +2123,9 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
       layers have other leaves); it is handed on to ``attend``. None: a model of
       one kind, or one whose layers of every kind run in ONE scan (``apply`` with
       window / rotary flags), read here at the traced ``l``. A layer whose operator is
-      "conv" runs ``_short_conv`` in the attention sublayer's place: no q / k / v,
-      no rotary, no ``attend``; its state rides in ``state`` as a mixer's does.
+      "conv" runs ``_short_conv`` in the attention sublayer's place and one whose
+      operator is "delta" ``_gated_delta``: no q / k / v of attention's, no rotary,
+      no ``attend``; its state rides in ``state`` as a mixer's does.
 
     Norm kind and placement and the residual form are what ``cfg`` says, for a
     dense layer and a routed one alike (``norm_style="post"`` is the BERT
@@ -1830,13 +2152,14 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
     rotary = True
     if cfg.rotary_layers is not None:
         rotary = kind[1] if kind is not None else jnp.asarray(cfg.rotary_layers, bool)[l]
-    if kind is not None and kind[2] == "conv":
-        conv_out, state = _short_conv(cfg, lp, h, state, l, live)
-        attn_out = branch(conv_out, cfg.attn_dropout, k_attn)
+    if kind is not None and kind[2] != "attn":
+        operator = _short_conv if kind[2] == "conv" else _gated_delta
+        op_out, state = operator(cfg, lp, h, state, l, live)
+        attn_out = branch(op_out, cfg.attn_dropout, k_attn)
     else:
-        q, k, v = _qkv_proj(cfg, lp, h, positions, rotary)
+        q, k, v, *out_gate = _qkv_proj(cfg, lp, h, positions, rotary)
         attn, state = attend(q, k, v, state, l, lp, **({} if kind is None else {"kind": kind}))
-        attn_out = branch(_attn_out_proj(cfg, lp, attn), cfg.attn_dropout, k_attn)
+        attn_out = branch(_attn_out_proj(cfg, lp, attn, *out_gate), cfg.attn_dropout, k_attn)
     if cfg.ssm_state_size:
         mixed, state = _ssm_mixer(cfg, lp, h, state, l, live)
         attn_out = attn_out + mixed
@@ -2298,8 +2621,10 @@ def cache_layout(cfg: TransformerConfig) -> dict:
     ``ssm_conv_kernel - 1`` rows of its input, in EVERY layer; or, for a model
     with ``layer_operators``, the gated short convolution's tail alone, the last
     ``conv_kernel - 1`` rows of its filter's input [conv_kernel - 1, hidden_size],
-    in the CONV layers alone (``cache_layers`` says how many keep it). Absent for
-    a model without."""
+    in the CONV layers alone (``cache_layers`` says how many keep it); or the gated
+    delta rule's float32 matrix [value heads, head width, head width] and its
+    filter's tail [conv_kernel - 1, q | k | v channels], in the DELTA layers alone.
+    Absent for a model without."""
     if cfg.kv_lora_rank:
         return {"k": (1, cfg.qk_rope_head_dim), "v": (1, cfg.kv_lora_rank)}
     layout = {"k": (cfg.kv_heads, cfg.head_dim), "v": (cfg.kv_heads, cfg.value_head_dim)}
@@ -2313,6 +2638,11 @@ def cache_layout(cfg: TransformerConfig) -> dict:
             "conv": ((cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim), None)}
     elif cfg.conv_layers:
         layout[STATE] = {"conv": ((cfg.conv_kernel - 1, cfg.hidden_size), None)}
+    elif cfg.delta_layers:
+        width = cfg.delta_head_dim
+        layout[STATE] = {
+            "delta": ((cfg.delta_value_heads, width, width), jnp.float32),
+            "conv": ((cfg.conv_kernel - 1, cfg.delta_conv_dim), None)}
     return layout
 
 
@@ -2391,11 +2721,11 @@ def cache_layers(cfg: TransformerConfig) -> dict:
     """How many layers keep each kind of ``cache_layout``'s leaves, as the leading
     axis of those leaves has it: ``"tokens"`` the whole-context attention layers,
     ``RING`` the window layers, ``STATE`` the layers with per-sequence state (every
-    layer of a model with a state-space mixer; the conv layers of one with
+    layer of a model with a state-space mixer; the conv or delta layers of one with
     ``layer_operators``; 0 for any other)."""
-    n_window, n_conv = len(cfg.window_layers), len(cfg.conv_layers)
-    return {"tokens": cfg.num_layers - n_window - n_conv, RING: n_window,
-            STATE: n_conv or (cfg.num_layers if cfg.ssm_state_size else 0)}
+    n_window, n_op = len(cfg.window_layers), len(cfg.stateful_layers)
+    return {"tokens": cfg.num_layers - n_window - n_op, RING: n_window,
+            STATE: n_op or (cfg.num_layers if cfg.ssm_state_size else 0)}
 
 
 def token_leaves(tree: dict) -> dict:
@@ -2611,7 +2941,7 @@ def causal_grid_form(cfg: TransformerConfig, bucket: int) -> dict:
     """What a prefill span says of the flash forward grid its whole-context
     layers took (nothing where none goes through the kernel):
     ``causal_tiles_fact`` at the bucket's rows."""
-    whole = cfg.num_layers - len(cfg.conv_layers) - len(cfg.window_layers)
+    whole = cfg.num_layers - len(cfg.stateful_layers) - len(cfg.window_layers)
     if not whole or cache_attention_form(cfg.num_heads, 1, bucket, bucket) != "flash":
         return {}
     return causal_tiles_fact(cfg, bucket)
@@ -2737,7 +3067,7 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
                 f"local_attn_window = {cfg.local_attn_window} positions, and the block would "
                 "overwrite entries its own first queries still see")
     in_kind = None
-    if windowed or cfg.conv_layers:  # not every layer keeps per-token K/V
+    if windowed or cfg.stateful_layers:  # not every layer keeps per-token K/V
         in_kind = jnp.asarray(_index_in_kind(cfg), jnp.int32)
 
     # Single-token decode steps route through the Pallas length-aware kernel
@@ -3148,5 +3478,5 @@ class Model:
             c.num_layers * (4 * c.hidden_size * c.hidden_size + 2 * c.hidden_size * c.ffn_size)
             + c.vocab_size * c.hidden_size
         )
-        attn = (c.num_layers - len(c.conv_layers)) * 2 * c.max_seq_len * c.hidden_size  # qk+av
+        attn = (c.num_layers - len(c.stateful_layers)) * 2 * c.max_seq_len * c.hidden_size  # qk+av
         return 6.0 * (n_params + attn)

@@ -33,7 +33,8 @@ one has no capacity and no ``[T, E, C]`` tensor at any skew:
 
 * the shared expert (``moe_shared_size``): one more gated MLP that every row
   goes through, a plain matmul beside the routed sum and not a group of the
-  grouped GEMM (its rows are all of them: nothing to sort).
+  grouped GEMM (its rows are all of them: nothing to sort); with
+  ``moe_shared_gate`` its output is multiplied by sigmoid(x . w_gate), a row.
 
 Experts are ``down(silu(gate(x)) * up(x))``, stacked ``[layers, E, ...]`` under
 the ``expert`` logical axis so a mesh shards them like the GShard bank.
@@ -122,13 +123,14 @@ BATCHED_ROWS = 64
 
 
 def init_dropless(rng, n_layers: int, num_experts: int, d_model: int, d_ff: int,
-                  shared: int = 0, select_bias: bool = False, held: int | None = None):
+                  shared: int = 0, select_bias: bool = False, held: int | None = None,
+                  shared_gate: bool = False):
     """Router and gated expert bank of ``n_layers`` routed layers; every stack
     is drawn whole, in one call (no per-layer list to restack). ``shared``: the
     width of the shared expert (0: none); ``select_bias``: the router's held
     selection bias, drawn non-zero (``SELECT_BIAS_STD``); ``held``: how many of
     the ``num_experts`` the router chooses among this program holds a bank of
-    (None: all)."""
+    (None: all); ``shared_gate``: the shared expert's gate vector ``w_gate``."""
     keys = jax.random.split(rng, 4)  # the gate and the bank draw as they always have
     keys = list(keys) + list(jax.random.split(jax.random.fold_in(rng, 1), 4))
 
@@ -150,10 +152,13 @@ def init_dropless(rng, n_layers: int, num_experts: int, d_model: int, d_ff: int,
         out["shared"] = {"wg": draw(keys[5], (n_layers, d_model, shared), d_model),
                          "wi": draw(keys[6], (n_layers, d_model, shared), d_model),
                          "wo": draw(keys[7], (n_layers, shared, d_model), shared)}
+        if shared_gate:
+            out["shared"]["w_gate"] = draw(jax.random.fold_in(rng, 2), (n_layers, d_model), d_model)
     return out
 
 
-def dropless_logical_axes(shared: bool = False, select_bias: bool = False):
+def dropless_logical_axes(shared: bool = False, select_bias: bool = False,
+                          shared_gate: bool = False):
     ex = experts_logical_axes()
     ex["wg"] = ex["wi"]
     axes = {"gate": (None, "embed", None), "experts": {k: (None,) + v for k, v in ex.items()}}
@@ -162,6 +167,8 @@ def dropless_logical_axes(shared: bool = False, select_bias: bool = False):
     if shared:
         axes["shared"] = {"wg": (None, "embed", "mlp"), "wi": (None, "embed", "mlp"),
                           "wo": (None, "mlp", "embed")}
+        if shared_gate:
+            axes["shared"]["w_gate"] = (None, "embed")
     return axes
 
 
@@ -349,9 +356,14 @@ def experts_dense(bank, x, weights, experts, layer=None, first: int = 0):
 
 
 def shared_expert(w, x):
-    """The gated MLP every row of x [T, M] goes through."""
+    """The gated MLP every row of x [T, M] goes through; with a gate vector
+    (``w_gate`` [M]: ``moe_shared_gate``) its output times sigmoid(x . w_gate), a row."""
     w = {name: leaf.astype(x.dtype) for name, leaf in w.items()}
-    return _gated(x @ w["wg"], x @ w["wi"]) @ w["wo"]
+    out = _gated(x @ w["wg"], x @ w["wi"]) @ w["wo"]
+    if "w_gate" not in w:
+        return out
+    logit = jnp.einsum("tm,m->t", x, w["w_gate"], preferred_element_type=jnp.float32)
+    return out * jax.nn.sigmoid(logit)[:, None].astype(x.dtype)
 
 
 def moe_ffn_dropless(cfg, moe_p, h, layer=None):

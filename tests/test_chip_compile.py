@@ -735,6 +735,87 @@ def test_operators_decode_program_keeps_kv_and_conv_state_in_place(v5e, no_persi
     assert ma.temp_size_in_bytes < layer_bytes // 4, (ma.temp_size_in_bytes, layer_bytes)
 
 
+def _qwen3_next_worker(n, Smax, v5e):
+    """Qwen3-Next-80B-A3B's eight layers D D D A D D D A at their published widths and
+    the cell's share (``chipbench/configs/qwen3-next-80b-a3b-L8.json``'s ``program``: a
+    gated delta rule of 16 key / 32 value heads of 128 and 4 taps in six layers, gated
+    attention at 16 / 2 heads of 256 in two, a 512-wide router top-10 with 64 experts of
+    512 held, the sliced vocabulary)."""
+    import json
+    from deepspeed_tpu.models import transformer as tfm
+
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__), "chipbench", "configs",
+                           "qwen3-next-80b-a3b-L8.json")) as f:
+        program = json.load(f)["program"]
+    cfg = tfm.TransformerConfig(dtype=jnp.bfloat16, **{**program, "max_seq_len": Smax})
+    return (cfg, *_bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0])))
+
+
+_DELTA_STACKS = (r"bf16\[6,2048,12288\]", r"bf16\[6,4096,2048\]", r"bf16\[8,64,2048,512\]",
+                 r"bf16\[8,64,512,2048\]", r"bf16\[2,2048,16,512\]")  # delta_in / _out, the banks, wq
+
+
+def test_delta_decode_program_keeps_the_matrix_state_in_place(v5e, no_persistent_cache, as_tpu):
+    """``SlotWorker``'s decode step of the block with attention and gated-delta-rule
+    layers at the cell's own size (eight layers, 64 slots x 8,192): the cache tree is
+    K/V of the TWO attention layers (a token's 2 heads of 256 side by side as one row
+    of 512), and of the SIX delta layers a float32 matrix [32, 128, 128] a slot (0.81
+    GB) and three rows of the filter's input, all the layer loop's carry, donated in and
+    aliased out. No stack is copied whole (the state, K/V, the delta layers' two large
+    projections, the expert banks, ``wq``); a layer's state is read where it lies (a
+    dynamic slice INSIDE the fusions that take its two products and its update) and
+    written back by an in-place update; the temporaries are the step's own
+    activations and the attention layers' float32 scores (74 MB: under two thirds of
+    ONE layer's state, 134 MB), and the program's footprint is what it is handed."""
+    n, Smax = 64, 8192
+    cfg, worker, params, cache, sds = _qwen3_next_worker(n, Smax, v5e)
+    assert jax.tree.map(lambda x: (x.shape, str(x.dtype)), cache) == {
+        "k": ((2, n, Smax, 1, 512), "bfloat16"), "v": ((2, n, Smax, 1, 512), "bfloat16"),
+        "state": {"delta": ((6, n, 32, 128, 128), "float32"), "conv": ((6, n, 3, 8192), "bfloat16")}}
+    compiled = _compile_decode(worker, params, cache, n, sds)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # decode_attn "xla": grouped heads, the rows form
+    for whole in (rf"f32\[6,{n},32,128,128\]", rf"bf16\[2,{n},{Smax},1,512\]") + _DELTA_STACKS:
+        copies = re.findall(rf"^\s*%?[\w.-]+ = {whole}\S* copy\(", text, re.M)
+        assert not copies, f"the decode step copies a whole stack: {copies}"
+    # one layer's state as a value of its own (sliced out, or an update not in place)
+    layer_state = re.findall(rf"^\s*(?:ROOT )?%?[\w.-]+ = f32\[1,{n},32,128,128\]\S* "
+                             r"(?:copy|dynamic-slice|fusion)\(", text, re.M)
+    in_fusion = re.findall(rf"^\s*%?dynamic[_-]slice[\w.-]* = f32\[1,{n},32,128,128\]", text, re.M)
+    assert in_fusion and len(layer_state) <= len(in_fusion), (layer_state, in_fusion)
+    ma = compiled.memory_analysis()
+    state_bytes, kv_bytes = 6 * n * 32 * 128 * 128 * 4, 4 * n * Smax * 512 * 2
+    assert ma.alias_size_in_bytes >= state_bytes + kv_bytes  # donated in, aliased out
+    assert ma.temp_size_in_bytes < state_bytes // 6 * 2 // 3, ma.temp_size_in_bytes  # 74 MB
+    assert _footprint(compiled) < 0.5 * HBM_BYTES
+
+
+def test_delta_prefill_loops_over_chunks_and_over_no_row(v5e, no_persistent_cache, as_tpu):
+    """The cell's longest prefill (8,192 rows) compiled for the chip: the two attention
+    layers go through the flash forward kernel at a 256-wide head, the held experts'
+    pairs through the grouped-matmul kernel, and each delta layer's block form is ONE
+    loop that carries a [16, 2, 128, 128] float32 state over 128 chunks: the three of
+    the scanned period are the only loops that carry it, nothing rows x rows is made,
+    no stack is copied whole, and the program fits in about half the chip."""
+    n, Smax = 64, 8192
+    cfg, worker, params, cache, sds = _qwen3_next_worker(n, Smax, v5e)
+    compiled = _compile_prefill(worker, params, cache, Smax, sds)
+    text = compiled.as_text()
+    kernels = set(re.findall(r'^\s*%?([a-z_-]+?)[\d.]* = .*custom_call_target="tpu_custom_call"',
+                             text, re.M))
+    assert kernels == {"flash_fwd", "ragged-dot-gmm"}, kernels
+    loops = re.findall(r"^\s*%?while[\w.-]* = \((.*?)\) while\(", text, re.M)
+    chunk_loops = [carry for carry in loops if "f32[1,16,2,128,128]" in carry]
+    assert len(chunk_loops) == 3 and len(loops) <= 12, (len(chunk_loops), len(loops))
+    assert all("[128,1,16,2,64,128]" in carry for carry in chunk_loops)  # 128 chunks of 64 rows
+    assert not re.search(rf"\[(?:1,)?(?:16|32),{Smax},{Smax}\]", text)
+    for whole in _DELTA_STACKS:
+        copies = re.findall(rf"^\s*%?[\w.-]+ = {whole}\S* copy\(", text, re.M)
+        assert not copies, f"the prefill copies a whole stack: {copies}"
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+    assert _footprint(compiled) < 0.6 * HBM_BYTES
+
+
 def test_latent_prefill_attends_through_the_flash_kernel(v5e, no_persistent_cache, as_tpu):
     """The 1024-row prefill of the same block at 32 heads: its dense scores would
     be 128 MiB, so the expanded form goes through the flash forward kernel at q/k

@@ -512,6 +512,17 @@ _AGREE_CASES["mixer_beside_grouped_attention-gated-rms"] = dict(
                  "ssm_out_multiplier": 0.2, "mlp_multipliers": [0.4, 0.1],
                  "lm_head_multiplier": 0.125})
 
+# the third operator: a gated delta rule in three layers of four (2 key / 4 value heads of 8, a
+# 4-tap filter, a float32 matrix a value head in the cache tree) under gated attention with
+# rotary on half a head, a softmax router and a GATED shared expert
+_AGREE_CASES["sequential-delta_in_3_of_4_gated_attention_gated_shared-rms"] = dict(
+    _AGREE_FFN["dropless_top2"], norm_kind="rms", use_bias=False, num_kv_heads=2, qk_head_dim=16,
+    rotary_pct=0.5, qk_norm="head", attn_output_gate=True, tie_embeddings=False,
+    moe_norm_topk_prob=True, moe_shared_size=24, moe_shared_gate=True,
+    layer_operators=["delta", "delta", "delta", "attn"], conv_kernel=4, delta_key_heads=2,
+    delta_value_heads=4, delta_head_dim=8)
+
+
 
 def _agree_model(fields):
     from deepspeed_tpu.models import transformer as tfm
