@@ -95,8 +95,10 @@ import math
 import os
 import time
 from collections import deque
+from collections.abc import Sequence
 from dataclasses import dataclass, field
 from functools import partial
+from itertools import islice
 from typing import Optional
 
 import jax
@@ -214,9 +216,39 @@ class _Slot:
     remaining: int = 0
     eos: int = -1  # -1 = never matches
     result: Optional[RequestResult] = None
-    tokens: list = field(default_factory=list)
+    # Python ints, converted where they are written, and append-only while
+    # the request lives: live_progress() hands out windows on this list
+    tokens: list[int] = field(default_factory=list)
     prefix_entry: object = None  # acquired PrefixEntry released on finish
     request: Optional[Request] = None  # kept for quarantine requeue/deadline
+
+
+class _TokensSoFar(Sequence):
+    """The first ``n`` tokens of a slot's list, ``n`` fixed when the window
+    is made. Never changes under its holder: the list only grows by
+    ``append`` while its request lives, and a released slot gets a NEW
+    ``_Slot`` — the old list is dropped, never cleared."""
+
+    __slots__ = ("_tokens", "_n")
+
+    def __init__(self, tokens: list[int]):
+        self._tokens = tokens
+        self._n = len(tokens)
+
+    def __len__(self):
+        return self._n
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return self._tokens[:self._n][i]
+        if i < 0:
+            i += self._n
+        if not 0 <= i < self._n:
+            raise IndexError(i)
+        return self._tokens[i]
+
+    def __iter__(self):
+        return islice(self._tokens, self._n)
 
 
 @dataclass
@@ -1787,7 +1819,7 @@ class ServingEngine:
         st.uid = uid
         st.remaining = req.max_new_tokens - 1
         st.eos = req.eos_token if req.eos_token is not None else -1
-        st.tokens = [imp["first"]]
+        st.tokens = [imp["first"]]  # an int since kv_import_begin
         st.request = req
         st.result = RequestResult(
             uid=uid, tokens=np.zeros((0,), np.int32),
@@ -1856,12 +1888,19 @@ class ServingEngine:
             return np.zeros((0,), np.int32)
         return None
 
-    def live_progress(self) -> dict[int, list[int]]:
+    def live_progress(self) -> dict[int, Sequence[int]]:
         """``{uid: tokens-so-far}`` for every ACTIVE (decoding) slot — the
         per-step progress block a worker process piggybacks on its step
         reply so a remote gateway's streams advance with ZERO extra round
-        trips (rpc.ReplicaClient caches it like load/idle)."""
-        return {st.uid: list(map(int, st.tokens))
+        trips (rpc.ReplicaClient caches it like load/idle).
+
+        Contract: each value is a READ-ONLY window (``_TokensSoFar``) on the
+        slot's list of Python ``int``s, as long as the list was at the call —
+        a later ``step()`` neither grows nor changes it; ``list(v)`` gives a
+        list of one's own (``json`` encodes nothing else). Cost: one small
+        object a live slot; no per-token work, not even a copy (on the chip's
+        host the copies read 0.33 ms a step at 128 slots x ~300 tokens)."""
+        return {st.uid: _TokensSoFar(st.tokens)
                 for slot, st in enumerate(self._slots)
                 if self._active[slot] and st.uid >= 0}
 
@@ -2294,7 +2333,7 @@ class ServingEngine:
         st.uid = req.uid
         st.remaining = req.max_new_tokens - 1
         st.eos = req.eos_token if req.eos_token is not None else -1
-        st.tokens = [first]
+        st.tokens = [int(first)]
         st.prefix_entry = entry
         st.request = req
         st.result = RequestResult(

@@ -244,7 +244,7 @@ class WorkerHost:
             # token list per step is O(tokens^2) wire over a generation,
             # and a fleet with no streaming front door must not pay it
             reply["progress"] = {
-                str(u): t for u, t in self.engine.live_progress().items()}
+                str(u): list(t) for u, t in self.engine.live_progress().items()}
         return reply
 
     def live_requests(self) -> list:
