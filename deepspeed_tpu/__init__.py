@@ -17,6 +17,7 @@ from .runtime.config import DeepSpeedConfig
 from .runtime.engine import DeepSpeedEngine
 from .runtime import activation_checkpointing as checkpointing  # noqa: F401
 from .runtime import zero  # noqa: F401
+from .telemetry import tracing
 from .utils.logging import log_dist, logger
 from . import comm
 
@@ -56,9 +57,13 @@ def initialize(
         cfg = getattr(args, "deepspeed_config", None)
     assert model is not None, "deepspeed_tpu.initialize: model is required"
     assert cfg is not None, "deepspeed_tpu.initialize: config is required"
-    engine = DeepSpeedEngine(
-        model=model, config=cfg, mesh=mesh, rng=rng, params=model_parameters, **kwargs
-    )
+    # the kept span ``startup/build`` (telemetry/tracing.py): the engine's
+    # ``mesh`` and ``state`` phases, and every program traced, compiled or
+    # loaded on the way, end under it
+    with tracing.span(tracing.STARTUP, keep=True, role="train"):
+        engine = DeepSpeedEngine(
+            model=model, config=cfg, mesh=mesh, rng=rng, params=model_parameters, **kwargs
+        )
     dataloader = None
     if training_data is not None:
         io_kw = {"collate_fn": collate_fn} if collate_fn is not None else {}

@@ -44,6 +44,7 @@ from ..comm.mesh import MeshConfig, build_mesh
 from ..models import transformer as tfm
 from ..models.transformer import Model, TransformerConfig
 from ..parallel import sharding as shd
+from ..telemetry import tracing
 from ..utils.logging import log_dist
 
 
@@ -92,31 +93,37 @@ class InferenceEngine:
         if model.config.dtype != dtype:
             model = Model(model.config.replace(dtype=dtype), loss_fn=model._loss)
 
+        # the build's phases are kept spans (telemetry/tracing.py): under
+        # ``startup/build`` where ``build_serving_engine`` opened it
         self.model = model
         self.cfg: TransformerConfig = model.config
-        self.mesh = mesh or build_mesh(MeshConfig(data=-1, model=tp_size))
-        model.set_mesh(self.mesh)
+        with tracing.span("mesh", keep=True):
+            self.mesh = mesh or build_mesh(MeshConfig(data=-1, model=tp_size))
+            model.set_mesh(self.mesh)
         self.dtype = dtype
         self.max_out_tokens = config.get("max_out_tokens", self.cfg.max_seq_len)
 
         # --- parameters onto the mesh (TP slicing = sharding specs) --------
-        axes_tree = model.logical_axes()
-        shapes = jax.eval_shape(lambda r: model.init(r), jax.random.PRNGKey(0))
-        shape_tree = jax.tree.map(lambda s: s.shape, shapes)
-        self.param_specs = shd.make_param_specs(
-            axes_tree, shape_tree, shd.DEFAULT_TP_RULES, self.mesh
-        )
-        shardings = shd.tree_shardings(self.mesh, self.param_specs)
+        with tracing.span("shapes", keep=True):
+            axes_tree = model.logical_axes()
+            shapes = jax.eval_shape(lambda r: model.init(r), jax.random.PRNGKey(0))
+            shape_tree = jax.tree.map(lambda s: s.shape, shapes)
+            self.param_specs = shd.make_param_specs(
+                axes_tree, shape_tree, shd.DEFAULT_TP_RULES, self.mesh
+            )
+            shardings = shd.tree_shardings(self.mesh, self.param_specs)
         # One rule for what the engine holds, however the weights arrive
         # (tfm.hold_for_compute: the reference's module.half(), but for the
         # leaves the forward pass reads in float32). No program casts a weight.
         if params is None:
             # drawn and rounded in one program: the float32 tree is never resident
-            params = jax.jit(lambda r: tfm.hold_for_compute(self.cfg, model.init(r)),
-                             out_shardings=shardings)(jax.random.PRNGKey(0))
+            with tracing.span("draw", keep=True):
+                params = jax.jit(lambda r: tfm.hold_for_compute(self.cfg, model.init(r)),
+                                 out_shardings=shardings)(jax.random.PRNGKey(0))
         else:
-            params = jax.device_put(
-                tfm.hold_for_compute(self.cfg, jax.tree.map(np.asarray, params)), shardings)
+            with tracing.span("load", keep=True):
+                params = jax.device_put(
+                    tfm.hold_for_compute(self.cfg, jax.tree.map(np.asarray, params)), shardings)
         self.params = params
 
         # --- weight-only int8/int4 quantization (reference: MoQ injection +
@@ -132,9 +139,10 @@ class InferenceEngine:
                     "supported yet; use tp_size=1"
                 )
             self.cfg = self.cfg.replace(weight_bits=bits, weight_group_size=group_size)
-            self.params = jax.jit(
-                partial(tfm.quantize_weights, self.cfg, bits=bits, group_size=group_size)
-            )(self.params)
+            with tracing.span("quantize", keep=True):
+                self.params = jax.jit(
+                    partial(tfm.quantize_weights, self.cfg, bits=bits, group_size=group_size)
+                )(self.params)
             self.model = Model(self.cfg, loss_fn=self.model._loss)
             self.model.set_mesh(self.mesh)
             log_dist(f"weight-only quantization: int{bits}, group {group_size}", ranks=[0])

@@ -376,11 +376,33 @@ class SlotWorker:
         # it, so a program's first call and its later ones hand it the same
         # kind of operand: one compile
         self._rng = jax.device_put(jax.random.PRNGKey(seed), self._key_sharding())
-        self._cache = jax.jit(
-            partial(tfm.init_cache, self.cfg, self.n_slots, self.Smax,
-                    dtype=self.cfg.dtype),
-            out_shardings=self._cache_shardings,
-        )()
+        # a kept span (telemetry/tracing.py): the allocation programs' traces and
+        # compiles end under it, and it under the build's ``startup/build``
+        with telemetry.span("cache", keep=True) as sp:
+            self._cache = jax.jit(
+                partial(tfm.init_cache, self.cfg, self.n_slots, self.Smax,
+                        dtype=self.cfg.dtype),
+                out_shardings=self._cache_shardings,
+            )()
+            # prefix pool: the slot cache's sibling — same [L, slots, len, H, Dh]
+            # layout, holding cached prompt prefixes instead of live sequences
+            self.pmax = 0
+            self._pool = None
+            if prefix_cfg.enabled:
+                self.pmax = int(prefix_cfg.max_prefix_len) or self.Smax
+                if self.pmax > self.Smax:
+                    raise ValueError(
+                        f"prefix_cache.max_prefix_len ({self.pmax}) exceeds the "
+                        f"slot cache length {self.Smax}")
+                pool_spec = kv_prefix_pool_spec(self.mesh, prefix_cfg.n_slots, cache_heads)
+                self._pool_sharding = NamedSharding(self.mesh, pool_spec)
+                self._pool_shardings = shardings(self._pool_sharding)
+                self._pool = jax.jit(
+                    partial(tfm.init_cache, self.cfg, prefix_cfg.n_slots, self.pmax,
+                            dtype=self.cfg.dtype),
+                    out_shardings=self._pool_shardings,
+                )()
+            sp.set_sync((self._cache, self._pool))
         # what ONE decode step must read and write of per-sequence state, a live
         # row: the leaves of every layer that keeps any (the layout's own count:
         # every layer of a model with a mixer, the conv or delta layers of one with
@@ -397,25 +419,6 @@ class SlotWorker:
         # where the programs read a routed layer's expert banks from ("in_place" /
         # "sliced"; None for a model without dropless routing): the rule they trace by
         self.expert_bank = tfm.expert_bank_form(self.cfg, self.params.get("moe"), self.mesh)
-
-        # prefix pool: the slot cache's sibling — same [L, slots, len, H, Dh]
-        # layout, holding cached prompt prefixes instead of live sequences
-        self.pmax = 0
-        self._pool = None
-        if prefix_cfg.enabled:
-            self.pmax = int(prefix_cfg.max_prefix_len) or self.Smax
-            if self.pmax > self.Smax:
-                raise ValueError(
-                    f"prefix_cache.max_prefix_len ({self.pmax}) exceeds the "
-                    f"slot cache length {self.Smax}")
-            pool_spec = kv_prefix_pool_spec(self.mesh, prefix_cfg.n_slots, cache_heads)
-            self._pool_sharding = NamedSharding(self.mesh, pool_spec)
-            self._pool_shardings = shardings(self._pool_sharding)
-            self._pool = jax.jit(
-                partial(tfm.init_cache, self.cfg, prefix_cfg.n_slots, self.pmax,
-                        dtype=self.cfg.dtype),
-                out_shardings=self._pool_shardings,
-            )()
 
         self._decode = None  # jitted lazily (params pytree shapes needed)
         self._prefills: dict[int, object] = {}  # bucket len -> jitted prefill
@@ -886,6 +889,8 @@ class SlotWorker:
                         self._cache, *out = prog(self.params, self._cache, *head, *sampler)
             compiled = bool(prog.last_call_compiled)
             self.step_compiled |= compiled
+            if compiled:  # a first call outlives the ring: its trace, compile or load, and first run
+                sp.keep = True
             chosen = None  # a routed model's choices: a device array no fetch waits for
             if self.cfg.moe_routing == "dropless":
                 *out, chosen = out

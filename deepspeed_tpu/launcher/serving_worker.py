@@ -52,13 +52,16 @@ and bounded-backoff respawn pacing from ``resilience/retry.py``.
 
 from __future__ import annotations
 
+import time
+
+T_PROCESS_START = time.perf_counter()  # the ``ready`` line's ``startup_s`` counts from here
+
 import json
 import os
 import signal
 import subprocess
 import sys
 import tempfile
-import time
 from collections import Counter
 from typing import Optional
 
@@ -71,6 +74,7 @@ from ..resilience.heartbeat import HeartbeatJudge
 from ..resilience.preemption import PreemptionGuard
 from ..resilience.retry import RetryPolicy, backoff_delay
 from ..runtime.config import RouterTransportConfig
+from ..telemetry import tracing
 from ..utils.durability import write_durable_bytes
 from ..utils.jax_env import require_chip_free, use_compile_cache
 from ..utils.logging import logger
@@ -84,21 +88,27 @@ def build_serving_engine(spec: dict, replica_id: int | str = 0,
     Params are initialized from ``PRNGKey(0)`` inside ``InferenceEngine``,
     so every process building the same spec holds identical weights.
     ``role`` (the ``--role`` flag) overrides any ``serving.role`` in the
-    spec — disaggregated pools share ONE spec and differ only by flag."""
+    spec — disaggregated pools share ONE spec and differ only by flag.
+
+    The whole build is the kept span ``startup/build``: the engine's phases
+    (``mesh``, ``shapes``, ``draw``, ``cache``) and every program traced,
+    compiled or loaded on the way end under it (``tracing.startup_table``)."""
     import jax.numpy as jnp
 
     from ..inference import InferenceEngine
     from ..inference.serving import ServingEngine
     from ..models.transformer import Model, TransformerConfig
 
-    model_spec = dict(spec.get("model", {}))
-    dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[
-        str(model_spec.pop("dtype", "float32"))]
-    cfg = TransformerConfig(dtype=dtype, **model_spec)
-    engine = InferenceEngine(
-        model=Model(cfg), config={"dtype": spec.get("engine_dtype", "fp32")})
-    return ServingEngine(engine, config=dict(spec.get("serving", {})),
-                         replica_id=replica_id, role=role)
+    with tracing.span(tracing.STARTUP, keep=True, replica_id=replica_id,
+                      role=role or "both"):
+        model_spec = dict(spec.get("model", {}))
+        dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[
+            str(model_spec.pop("dtype", "float32"))]
+        cfg = TransformerConfig(dtype=dtype, **model_spec)
+        engine = InferenceEngine(
+            model=Model(cfg), config={"dtype": spec.get("engine_dtype", "fp32")})
+        return ServingEngine(engine, config=dict(spec.get("serving", {})),
+                             replica_id=replica_id, role=role)
 
 
 class WorkerHost:
@@ -434,8 +444,13 @@ def main(argv=None) -> int:
     server = RpcServer(args.socket, host.handlers())
     # the RESOLVED address (a tcp://host:0 bind reports its real port):
     # the supervisor reads this line to learn where to connect
+    # how long ready took, and the build's phases (seconds by path): what an
+    # autoscaler's scale-up lag was made of
+    phases = tracing.startup_table()["phases"]
     print(json.dumps({"event": "ready", "pid": os.getpid(),
-                      "replica_id": rid, "socket": server.address}),
+                      "replica_id": rid, "socket": server.address,
+                      "startup_s": round(time.perf_counter() - T_PROCESS_START, 3),
+                      "phases": {k: round(v, 3) for k, v in phases.items()}}),
           flush=True)
     try:
         server.serve_forever(should_stop=guard.pending, on_tick=host.tick)

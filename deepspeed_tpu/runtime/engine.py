@@ -36,6 +36,7 @@ from .. import comm as dist
 from ..comm.mesh import MeshConfig, build_mesh, data_parallel_size
 from ..parallel import sharding as shd
 from ..ops.optimizers import get_optimizer
+from ..telemetry import tracing
 from ..utils.donation import donated_jit
 from ..utils.logging import log_dist, logger
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
@@ -108,14 +109,17 @@ class DeepSpeedEngine:
         else:
             raw = config.raw
 
-        self.mesh = mesh or build_mesh(
-            MeshConfig(
-                **{
-                    k: raw.get("mesh", {}).get(k, -1 if k == "data" else 1)
-                    for k in ("pipe", "data", "fsdp", "context", "model")
-                }
+        # the build's phases are kept spans (telemetry/tracing.py): under
+        # ``startup/build`` where ``deepspeed_tpu.initialize`` opened it
+        with tracing.span("mesh", keep=True):
+            self.mesh = mesh or build_mesh(
+                MeshConfig(
+                    **{
+                        k: raw.get("mesh", {}).get(k, -1 if k == "data" else 1)
+                        for k in ("pipe", "data", "fsdp", "context", "model")
+                    }
+                )
             )
-        )
         dp_world = data_parallel_size(self.mesh)
         self.config = (
             config
@@ -448,170 +452,189 @@ class DeepSpeedEngine:
         self.client_lr = base_lr
 
         # ---- state init (sharded at materialization — replaces zero.Init) ---
-        rng = rng if rng is not None else jax.random.PRNGKey(0)
-        param_shardings = shd.tree_shardings(self.mesh, self.param_specs)
-        if self._param_memory_kind:
-            # the parameter tier's source of truth lives in pinned host
-            # memory; init computes on device and spills leaf-by-leaf
-            param_shardings = jax.tree.map(
-                lambda s: s.with_memory_kind(self._param_memory_kind),
-                param_shardings,
-                is_leaf=lambda x: isinstance(x, NamedSharding),
-            )
-        if params is None:
-            init_fn = jax.jit(model.init, out_shardings=param_shardings)
-            params = init_fn(rng)
-        else:
-            params = jax.device_put(params, param_shardings)
+        # a kept span (telemetry/tracing.py): every program below (the draw, the
+        # optimizer's state, the host tiers' master copy and working copy) is a
+        # child by the name the code has for it, its trace and compile under it.
+        # On the engine's own tracer: its JSONL sink, and ``device_sync_spans``
+        # makes each child end when its tree is on the device
+        with self.telemetry.span("state", keep=True):
+            rng = rng if rng is not None else jax.random.PRNGKey(0)
+            param_shardings = shd.tree_shardings(self.mesh, self.param_specs)
+            if self._param_memory_kind:
+                # the parameter tier's source of truth lives in pinned host
+                # memory; init computes on device and spills leaf-by-leaf
+                param_shardings = jax.tree.map(
+                    lambda s: s.with_memory_kind(self._param_memory_kind),
+                    param_shardings,
+                    is_leaf=lambda x: isinstance(x, NamedSharding),
+                )
+            if params is None:
+                init_fn = jax.jit(model.init, out_shardings=param_shardings)
+                with self.telemetry.span("init_fn") as sp:
+                    params = init_fn(rng)
+                    sp.set_sync(params)
+            else:
+                params = jax.device_put(params, param_shardings)
 
-        # Optimizer state lives on the ZeRO shards: mirror opt specs per leaf.
-        if self._onebit_cfg is not None:
-            dp = data_parallel_size(self.mesh)
-            is_spec = lambda x: x is None or isinstance(x, tuple)
-            rep = jax.tree.map(lambda _: PartitionSpec(), axes_tree, is_leaf=is_spec)
-            stacked = jax.tree.map(
-                lambda _: PartitionSpec(("data", "fsdp")), axes_tree, is_leaf=is_spec
-            )
-            if self._onebit_kind == "adam":
-                from ..ops.onebit import init_state as onebit_init
+            # Optimizer state lives on the ZeRO shards: mirror opt specs per leaf.
+            if self._onebit_cfg is not None:
+                dp = data_parallel_size(self.mesh)
+                is_spec = lambda x: x is None or isinstance(x, tuple)
+                rep = jax.tree.map(lambda _: PartitionSpec(), axes_tree, is_leaf=is_spec)
+                stacked = jax.tree.map(
+                    lambda _: PartitionSpec(("data", "fsdp")), axes_tree, is_leaf=is_spec
+                )
+                if self._onebit_kind == "adam":
+                    from ..ops.onebit import init_state as onebit_init
 
-                self.opt_specs = {"m": rep, "v": rep, "error": stacked}
-            elif self._onebit_kind == "lamb":
-                from ..ops.onebit_lamb import init_state as _lamb_init
+                    self.opt_specs = {"m": rep, "v": rep, "error": stacked}
+                elif self._onebit_kind == "lamb":
+                    from ..ops.onebit_lamb import init_state as _lamb_init
 
-                onebit_init = partial(_lamb_init, cfg=self._onebit_cfg)
-                self.opt_specs = {
-                    "m": rep, "v": rep, "v_fresh": rep,
-                    "error": {"flat": PartitionSpec(("data", "fsdp"))},
-                    "scaling_coeff": rep, "lamb_coeff_freeze": rep,
-                    "last_factor": rep,
-                }
-                if self._onebit_cfg.comm_backend == "two_phase":
-                    # reference backend parity: per-rank server-chunk error
-                    self.opt_specs["server_error"] = {
-                        "flat": PartitionSpec(("data", "fsdp"))
+                    onebit_init = partial(_lamb_init, cfg=self._onebit_cfg)
+                    self.opt_specs = {
+                        "m": rep, "v": rep, "v_fresh": rep,
+                        "error": {"flat": PartitionSpec(("data", "fsdp"))},
+                        "scaling_coeff": rep, "lamb_coeff_freeze": rep,
+                        "last_factor": rep,
                     }
-            else:  # zoadam: per-rank momentum / delta accumulator / residual
-                from ..ops.zoadam import init_state as onebit_init
+                    if self._onebit_cfg.comm_backend == "two_phase":
+                        # reference backend parity: per-rank server-chunk error
+                        self.opt_specs["server_error"] = {
+                            "flat": PartitionSpec(("data", "fsdp"))
+                        }
+                else:  # zoadam: per-rank momentum / delta accumulator / residual
+                    from ..ops.zoadam import init_state as onebit_init
 
-                self.opt_specs = {
-                    "m": stacked, "v": rep, "u": stacked, "error": stacked,
-                    "lrs": PartitionSpec(),
-                }
-            opt_shardings = shd.tree_shardings(self.mesh, self.opt_specs)
-            self._onebit_opt_shardings = opt_shardings
-            opt_state = jax.jit(
-                partial(onebit_init, dp=dp), out_shardings=opt_shardings
-            )(params)
-        elif self._nvme_offload:
-            # states live on NVMe (nvme_optimizer); nothing on device
-            self.opt_specs = {}
-            opt_shardings = {}
-            opt_state = {}
-        else:
-            opt_state_shape = jax.eval_shape(self.opt_init, shapes)
-            self.opt_specs = self._mirror_opt_specs(opt_state_shape)
-            opt_shardings = self._to_host_shardings(shd.tree_shardings(self.mesh, self.opt_specs))
-            opt_state = jax.jit(self.opt_init, out_shardings=opt_shardings)(params)
+                    self.opt_specs = {
+                        "m": stacked, "v": rep, "u": stacked, "error": stacked,
+                        "lrs": PartitionSpec(),
+                    }
+                opt_shardings = shd.tree_shardings(self.mesh, self.opt_specs)
+                self._onebit_opt_shardings = opt_shardings
+                with self.telemetry.span("onebit_init") as sp:
+                    opt_state = jax.jit(
+                        partial(onebit_init, dp=dp), out_shardings=opt_shardings
+                    )(params)
+                    sp.set_sync(opt_state)
+            elif self._nvme_offload:
+                # states live on NVMe (nvme_optimizer); nothing on device
+                self.opt_specs = {}
+                opt_shardings = {}
+                opt_state = {}
+            else:
+                opt_state_shape = jax.eval_shape(self.opt_init, shapes)
+                self.opt_specs = self._mirror_opt_specs(opt_state_shape)
+                opt_shardings = self._to_host_shardings(shd.tree_shardings(self.mesh, self.opt_specs))
+                with self.telemetry.span("opt_init") as sp:
+                    opt_state = jax.jit(self.opt_init, out_shardings=opt_shardings)(params)
+                    sp.set_sync(opt_state)
 
-        fp16 = self.config.fp16
-        self.fp16_enabled = fp16.enabled
-        scale0 = fp16.loss_scale if fp16.loss_scale > 0 else float(2**fp16.initial_scale_power)
-        # the scalars are placed on the mesh like every other leaf: the step
-        # hands them back replicated over it, and an input whose sharding
-        # differs from the previous call's retraces and recompiles the step
-        rep = dist.replicated(self.mesh)
-        self.state = {
-            "step": jax.device_put(jnp.zeros((), jnp.int32), rep),
-            "params": params,
-            "opt": opt_state,
-            "loss_scale": jax.device_put(
-                jnp.asarray(scale0 if fp16.enabled else 1.0, jnp.float32), rep),
-            "good_steps": jax.device_put(jnp.zeros((), jnp.int32), rep),
-            "skipped": jax.device_put(jnp.zeros((), jnp.int32), rep),
-            "hysteresis": jax.device_put(jnp.asarray(fp16.hysteresis, jnp.int32), rep),
-        }
-        self._state_shardings = {
-            "step": rep,
-            "params": param_shardings,
-            "opt": opt_shardings,
-            "loss_scale": rep,
-            "good_steps": rep,
-            "skipped": rep,
-            "hysteresis": rep,
-        }
-        if self.offload_optimizer_enabled:
-            # master fp32 weights move to host alongside the moments; the
-            # device keeps only the compute-dtype (bf16/fp16) working copy.
-            master_shardings = self._to_host_shardings(
-                shd.tree_shardings(self.mesh, self.opt_specs_for_params)
-            )
-            cdt = self.config.compute_dtype
-            master = jax.jit(lambda p: p, out_shardings=master_shardings)(self.state["params"])
-            params16 = jax.jit(
-                lambda p: jax.tree.map(
-                    lambda x: x.astype(cdt) if x.dtype == jnp.float32 else x, p
-                ),
-                out_shardings=param_shardings,
-            )(self.state["params"])
-            self.state["params"] = params16
-            self.state["master"] = master
-            self._state_shardings["master"] = master_shardings
-        elif self._nvme_offload:
-            # build the NVMe-tiered optimizer from the fp32 init, then keep
-            # only the compute-dtype working copy on device
-            from .zero.nvme_optimizer import NvmeTieredOptimizer
+            fp16 = self.config.fp16
+            self.fp16_enabled = fp16.enabled
+            scale0 = fp16.loss_scale if fp16.loss_scale > 0 else float(2**fp16.initial_scale_power)
+            # the scalars are placed on the mesh like every other leaf: the step
+            # hands them back replicated over it, and an input whose sharding
+            # differs from the previous call's retraces and recompiles the step
+            rep = dist.replicated(self.mesh)
+            self.state = {
+                "step": jax.device_put(jnp.zeros((), jnp.int32), rep),
+                "params": params,
+                "opt": opt_state,
+                "loss_scale": jax.device_put(
+                    jnp.asarray(scale0 if fp16.enabled else 1.0, jnp.float32), rep),
+                "good_steps": jax.device_put(jnp.zeros((), jnp.int32), rep),
+                "skipped": jax.device_put(jnp.zeros((), jnp.int32), rep),
+                "hysteresis": jax.device_put(jnp.asarray(fp16.hysteresis, jnp.int32), rep),
+            }
+            self._state_shardings = {
+                "step": rep,
+                "params": param_shardings,
+                "opt": opt_shardings,
+                "loss_scale": rep,
+                "good_steps": rep,
+                "skipped": rep,
+                "hysteresis": rep,
+            }
+            if self.offload_optimizer_enabled:
+                # master fp32 weights move to host alongside the moments; the
+                # device keeps only the compute-dtype (bf16/fp16) working copy.
+                master_shardings = self._to_host_shardings(
+                    shd.tree_shardings(self.mesh, self.opt_specs_for_params)
+                )
+                cdt = self.config.compute_dtype
+                with self.telemetry.span("master") as sp:
+                    master = jax.jit(lambda p: p, out_shardings=master_shardings)(
+                        self.state["params"])
+                    sp.set_sync(master)
+                with self.telemetry.span("params16") as sp:
+                    params16 = jax.jit(
+                        lambda p: jax.tree.map(
+                            lambda x: x.astype(cdt) if x.dtype == jnp.float32 else x, p
+                        ),
+                        out_shardings=param_shardings,
+                    )(self.state["params"])
+                    sp.set_sync(params16)
+                self.state["params"] = params16
+                self.state["master"] = master
+                self._state_shardings["master"] = master_shardings
+            elif self._nvme_offload:
+                # build the NVMe-tiered optimizer from the fp32 init, then keep
+                # only the compute-dtype working copy on device
+                from .zero.nvme_optimizer import NvmeTieredOptimizer
 
-            if opt_type not in ("adam", "adamw", "fusedadam", "cpuadam"):
-                raise NotImplementedError(
-                    f"nvme offload supports Adam(W) (the reference swaps Adam "
-                    f"states too), not {opt_type!r}")
-            aio = self.config.aio
-            self._nvme_treedef = jax.tree_util.tree_structure(self.state["params"])
-            self._nvme_keys = []
-            params_host = {}
-            for path, leaf in jax.tree_util.tree_flatten_with_path(self.state["params"])[0]:
-                key = "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
-                self._nvme_keys.append(key)
-                params_host[key] = np.asarray(jax.device_get(leaf))
-            opt_kwargs = dict(opt_cfg.params)
-            if "betas" in opt_kwargs:
-                opt_kwargs["betas"] = tuple(opt_kwargs["betas"])
-            # same decay semantics as the on-device path, which derives the
-            # mode from the optimizer NAME and ignores any adam_w_mode key
-            # (ops/optimizers.py get_optimizer pops it): 'adam' = L2 in the
-            # gradient, 'adamw' = decoupled decay
-            name_mode = opt_type == "adamw"
-            if opt_kwargs.get("adam_w_mode", name_mode) != name_mode:
-                logger.warning(
-                    "optimizer.params.adam_w_mode=%s contradicts type %r and is "
-                    "ignored (decay mode follows the optimizer name on every "
-                    "path); use type 'adamw' for decoupled decay",
-                    opt_kwargs["adam_w_mode"], opt_cfg.type)
-            opt_kwargs["adam_w_mode"] = name_mode
-            self.nvme_opt = NvmeTieredOptimizer(
-                params_host,
-                swap_dir=off_opt.nvme_path,
-                sub_group_bytes=int(self.config.zero_optimization.sub_group_size),
-                n_threads=aio.thread_count or 4,
-                **{k: v for k, v in opt_kwargs.items()
-                   if k in ("lr", "betas", "eps", "weight_decay", "adam_w_mode")},
-            )
-            cdt = self.config.compute_dtype
-            params16 = jax.jit(
-                lambda p: jax.tree.map(
-                    lambda x: x.astype(cdt) if x.dtype == jnp.float32 else x, p
-                ),
-                out_shardings=param_shardings,
-            )(self.state["params"])
-            self.state["params"] = params16
-            # per-step param uploader, compiled ONCE (a fresh lambda per step
-            # would miss the jit cache and recompile every step)
-            self._nvme_upload = jax.jit(lambda p: p, out_shardings=param_shardings)
-            logger.info(
-                "NVMe-tiered optimizer: %.2f GB of states in %s across %d groups",
-                self.nvme_opt.state_bytes() / 1e9, off_opt.nvme_path,
-                self.nvme_opt.num_groups)
+                if opt_type not in ("adam", "adamw", "fusedadam", "cpuadam"):
+                    raise NotImplementedError(
+                        f"nvme offload supports Adam(W) (the reference swaps Adam "
+                        f"states too), not {opt_type!r}")
+                aio = self.config.aio
+                self._nvme_treedef = jax.tree_util.tree_structure(self.state["params"])
+                self._nvme_keys = []
+                params_host = {}
+                for path, leaf in jax.tree_util.tree_flatten_with_path(self.state["params"])[0]:
+                    key = "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
+                    self._nvme_keys.append(key)
+                    params_host[key] = np.asarray(jax.device_get(leaf))
+                opt_kwargs = dict(opt_cfg.params)
+                if "betas" in opt_kwargs:
+                    opt_kwargs["betas"] = tuple(opt_kwargs["betas"])
+                # same decay semantics as the on-device path, which derives the
+                # mode from the optimizer NAME and ignores any adam_w_mode key
+                # (ops/optimizers.py get_optimizer pops it): 'adam' = L2 in the
+                # gradient, 'adamw' = decoupled decay
+                name_mode = opt_type == "adamw"
+                if opt_kwargs.get("adam_w_mode", name_mode) != name_mode:
+                    logger.warning(
+                        "optimizer.params.adam_w_mode=%s contradicts type %r and is "
+                        "ignored (decay mode follows the optimizer name on every "
+                        "path); use type 'adamw' for decoupled decay",
+                        opt_kwargs["adam_w_mode"], opt_cfg.type)
+                opt_kwargs["adam_w_mode"] = name_mode
+                self.nvme_opt = NvmeTieredOptimizer(
+                    params_host,
+                    swap_dir=off_opt.nvme_path,
+                    sub_group_bytes=int(self.config.zero_optimization.sub_group_size),
+                    n_threads=aio.thread_count or 4,
+                    **{k: v for k, v in opt_kwargs.items()
+                       if k in ("lr", "betas", "eps", "weight_decay", "adam_w_mode")},
+                )
+                cdt = self.config.compute_dtype
+                with self.telemetry.span("params16") as sp:
+                    params16 = jax.jit(
+                        lambda p: jax.tree.map(
+                            lambda x: x.astype(cdt) if x.dtype == jnp.float32 else x, p
+                        ),
+                        out_shardings=param_shardings,
+                    )(self.state["params"])
+                    sp.set_sync(params16)
+                self.state["params"] = params16
+                # per-step param uploader, compiled ONCE (a fresh lambda per step
+                # would miss the jit cache and recompile every step)
+                self._nvme_upload = jax.jit(lambda p: p, out_shardings=param_shardings)
+                logger.info(
+                    "NVMe-tiered optimizer: %.2f GB of states in %s across %d groups",
+                    self.nvme_opt.state_bytes() / 1e9, off_opt.nvme_path,
+                    self.nvme_opt.num_groups)
 
         # MoQ / quantize-aware training (reference: runtime/quantize.py +
         # compression/scheduler.py): step-scheduled fake-quant of the weights.
@@ -1642,6 +1665,8 @@ class DeepSpeedEngine:
         with tm.span("dispatch"):  # batch placement + enqueue
             self.state, metrics = self._dispatch_step(batch)
         step_span.set_sync(metrics["loss"])
+        if getattr(self._train_step, "last_call_compiled", False):
+            step_span.keep = True  # a step that compiled outlives the ring, like the build
         if donation_probe is not None:
             self._donation_checked = True
             if self.config.debug.nan_check:

@@ -36,7 +36,7 @@ from .registry import Counter, Gauge, Histogram, MetricsRegistry, get_registry
 from .request_trace import RequestTracer, request_timeline, to_perfetto
 from .slo import SLOTracker, classify_terminal
 from .timeseries import TimeSeriesStore
-from .tracing import Span, SpanTracer
+from .tracing import Span, SpanTracer, startup_table
 from .watchdog import RecompileError, RecompileWatchdog, abstract_signature
 
 __all__ = [
@@ -99,8 +99,10 @@ class Telemetry:
             self.sink.emit(event)
 
     def snapshot(self, **extra) -> dict:
-        """Registry snapshot + recompile table + program ledger + step
-        anatomy (+ caller extras), the one call that reports everything. The
+        """Registry snapshot + recompile table + ``startup`` (what coming up
+        was made of: ``tracing.startup_table``, the process's kept spans) +
+        program ledger + step anatomy (+ caller extras), the one call that
+        reports everything. The
         ledger table and anatomy are computed FIRST so the MFU/intensity and
         ``<prefix>/comm/*`` gauges they publish land in the same metrics
         snapshot."""
@@ -114,6 +116,7 @@ class Telemetry:
                 out["comm_reconcile"] = rec
         out["metrics"] = self.registry.snapshot()
         out["recompile_table"] = self.watchdog.compile_table()
+        out["startup"] = startup_table()
         out.update(extra)
         return out
 

@@ -17,10 +17,16 @@ this call compiled. Each compilation is recorded with
     style, long pytrees elided),
   * the compile wall time (the compiling call's wall time minus nothing —
     it includes the first execution, which on TPU is noise next to the
-    compile itself),
+    compile itself) and what it was made of: ``trace_s``, ``lower_s``,
+    ``backend_s`` (the thread's ``xla/trace`` / ``xla/lower`` / ``xla/compile``
+    spans inside the call, from ``tracing.xla_totals()`` read before and after
+    it; ``compile_s`` less the three is the first execution and this proxy) and
+    ``cache``, what the persistent cache did for the call's compiles:
+    ``written`` if it wrote any, else ``hit`` if it loaded any, else
+    ``not_kept`` (None: the call reached no backend compile),
   * registry counters ``compile/<name>`` and histogram ``compile/wall_s``,
   * a JSONL event ``{"type": "compile", "name", "signature", "compile_s",
-    "n_for_name"}``.
+    "trace_s", "lower_s", "backend_s", "cache", "n_for_name"}``.
 
 A path declared ``stable=True`` may compile ONCE; the second compilation
 triggers the watchdog's ``mode``: ``"warn"`` logs loudly, ``"raise"`` throws
@@ -39,6 +45,7 @@ from typing import Optional
 
 from ..utils.logging import logger
 from .registry import MetricsRegistry, get_registry
+from .tracing import XLA_TOTALS, xla_totals
 
 _MAX_SIG_LEAVES = 8
 
@@ -107,16 +114,22 @@ class RecompileWatchdog:
     # -- bookkeeping ----------------------------------------------------
 
     def _record(self, name: str, signature: str, compile_s: float,
-                key: tuple | None = None) -> dict:
+                key: tuple | None = None, xla: tuple = (0.0, 0.0, 0.0, 0, 0, 0)) -> dict:
+        """``xla``: what ``tracing.xla_totals()`` grew by over the call."""
         entry = self._watched[name]
         entry["compiles"] += 1
         if key is not None:
             entry["sigs"].add(key)
+        grew = dict(zip(XLA_TOTALS, xla))
         ev = {
             "type": "compile",
             "name": name,
             "signature": signature,
             "compile_s": compile_s,
+            "trace_s": grew["trace_s"],
+            "lower_s": grew["lower_s"],
+            "backend_s": grew["backend_s"],
+            "cache": next((v for v in ("written", "hit", "not_kept") if grew[v]), None),
             "n_for_name": entry["compiles"],
         }
         self.events.append(ev)
@@ -208,6 +221,7 @@ class RecompileWatchdog:
                         + ("" if first else " (already-refused signature)"))
             if cache_size is not None:
                 before = cache_size()
+            xla0 = xla_totals()  # one thread-local read: the listener's sums so far
             t0 = time.perf_counter()
             out = fn(*args, **kwargs)
             dt = time.perf_counter() - t0
@@ -223,7 +237,8 @@ class RecompileWatchdog:
             if compiled:
                 ev = self._record(
                     name, abstract_signature(args, kwargs), dt,
-                    key=abstract_key(args, kwargs))
+                    key=abstract_key(args, kwargs),
+                    xla=tuple(b - a for a, b in zip(xla0, xla_totals())))
                 if self.ledger is not None:
                     # cost-model capture (telemetry/program_ledger.py):
                     # stores shape/dtype/sharding specs only — donated
@@ -251,7 +266,8 @@ class RecompileWatchdog:
 
     def compile_table(self) -> list[dict]:
         """Per-path summary: [{name, stable, compiles, refusals,
-        total_compile_s, signatures}] sorted by total compile time.
+        total_compile_s, trace_s, lower_s, backend_s, cache, signatures}]
+        sorted by total compile time (``cache``: each compile's verdict).
         ``refusals`` counts pre-execution raise-mode rejections — calls that
         never reached XLA, kept out of the compile accounting."""
         rows = {}
@@ -262,6 +278,10 @@ class RecompileWatchdog:
                 "compiles": entry["compiles"],
                 "refusals": entry["refusals"],
                 "total_compile_s": 0.0,
+                "trace_s": 0.0,
+                "lower_s": 0.0,
+                "backend_s": 0.0,
+                "cache": [],
                 "signatures": [],
             }
         for ev in self.events:
@@ -269,5 +289,8 @@ class RecompileWatchdog:
                 continue
             row = rows[ev["name"]]
             row["total_compile_s"] += ev["compile_s"]
+            for part in ("trace_s", "lower_s", "backend_s"):
+                row[part] += ev[part]
+            row["cache"].append(ev["cache"])
             row["signatures"].append(ev["signature"])
         return sorted(rows.values(), key=lambda r: -r["total_compile_s"])

@@ -118,6 +118,10 @@ def test_worker_process_roundtrip_and_sigterm_drain(tiny_serving_engine):
         assert sup.proc(0).wait(timeout=60) == 0
         events = {e.get("event") for e in _events(sup._logs[0])}
         assert {"ready", "drained"} <= events
+        # how long ready took and what the build was made of (kept spans, seconds by path)
+        ready = next(e for e in _events(sup._logs[0]) if e.get("event") == "ready")
+        assert ready["startup_s"] >= ready["phases"]["startup/build"] > 0
+        assert {"startup/build/draw", "startup/build/cache"} <= set(ready["phases"])
         drained = next(e for e in _events(sup._logs[0])
                        if e.get("event") == "drained")
         # the in-flight request reached a terminal state before exit
