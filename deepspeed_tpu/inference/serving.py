@@ -789,7 +789,9 @@ class SlotWorker:
         the state. A model with layers by operator: ``conv_layers`` (or
         ``delta_layers``) / ``attn_layers`` it ran, ``state_rows`` and ``state_bytes``,
         the state it wrote for the slot, and with delta layers ``scan_chunks``, the
-        chunks of ``DELTA_CHUNK`` rows the rule's block form ran. A routed
+        chunks of ``DELTA_CHUNK`` rows the rule's block form ran, and ``delta_block``,
+        what ran them (``transformer.delta_block_form``, the rule the program was
+        traced by: ``"kernel"`` or ``"xla"``). A routed
         model's ``expert_bank``: where the program reads
         layer l of the three banks from (``expert_bank_form``), and
         ``expert_gemm``: what multiplies its rows through the experts
@@ -807,7 +809,9 @@ class SlotWorker:
             else:  # the state written for the slot: one layer's leaves a layer that keeps them
                 attrs.update(state_bytes=self.state_bytes_per_slot)
                 if self.cfg.delta_layers:
-                    attrs.update(scan_chunks=-(-int(rows) // tfm.DELTA_CHUNK))
+                    attrs.update(scan_chunks=-(-int(rows) // tfm.DELTA_CHUNK),
+                                 delta_block=tfm.delta_block_form(self.cfg, int(rows), self._cache,
+                                                                  self.mesh))
         return attrs
 
     def _sampler_rows(self, sp, temperature, top_k, top_p):

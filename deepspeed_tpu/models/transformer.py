@@ -1770,7 +1770,30 @@ def _unit_lower_inverse(A):
     return inv
 
 
-def _delta_chunks(q, k, v, g, beta, S0):
+def delta_block_form(cfg: TransformerConfig, rows: int, state, mesh=None) -> str:
+    """What runs the gated delta rule's block form over a call of ``rows`` rows:
+    ``"kernel"`` (``ops/pallas/delta_rule.py``: a chunk's [chunk, chunk] matrices and
+    the state it is swept through stay in VMEM) or ``"xla"`` (``_delta_chunks``' own
+    form, the definition the kernel is tested against). The kernel is taken where
+    the code can see it may: on the ``tpu`` platform (on the CPU every delta test
+    would pay the Pallas interpreter), on one device (the partitioner cannot split
+    a Mosaic kernel), by a program that carries a state (``state`` is not None: the
+    serving programs, which only run forward; the kernel has no backward pass, so
+    ``apply`` and the loss keep the XLA form), over a block (one row is
+    ``_delta_step``'s) of heads it tiles (``delta_head_dim`` whole lane tiles).
+    ``_gated_delta`` traces by this and ``SlotWorker`` labels its ``prefill`` and
+    ``chunk`` spans by it (``delta_block``)."""
+    from ..ops.pallas.delta_rule import tiles
+
+    mesh = mesh if mesh is not None else _ACTIVE_MESH[0]
+    one_device = mesh is None or mesh.size == 1
+    if (state is not None and rows > 1 and one_device and tiles(cfg.delta_head_dim)
+            and jax.default_backend() == "tpu"):
+        return "kernel"
+    return "xla"
+
+
+def _delta_chunks(q, k, v, g, beta, S0, form: str = "xla"):
     """The gated delta rule S_t = exp(g_t) S_{t-1} + k_t (x) beta_t (v_t - (exp(g_t)
     S_{t-1})^T k_t), o_t = S_t^T q_t over many rows, in its chunked form (Gated
     DeltaNet, arXiv:2412.06464; ``torch_chunk_gated_delta_rule``): q, k [B, T, Hk, D]
@@ -1790,7 +1813,10 @@ def _delta_chunks(q, k, v, g, beta, S0):
     ``lax.scan`` over chunks carries ONE state and runs four batched matmuls a chunk.
     Nothing rows x rows is formed: the largest temporaries are [chunks, heads, Q, Q]
     and [rows, heads, D]. The decays, ``Tm`` and the states are float32; the
-    contractions take their operands in q's dtype and accumulate in float32."""
+    contractions take their operands in q's dtype and accumulate in float32.
+    ``form`` (``delta_block_form``): ``"kernel"`` hands the padded rows to
+    ``ops/pallas/delta_rule.py``, which computes the same to the same roundings with
+    every chunk's temporaries in VMEM; what follows the padding here is the definition."""
     f32, dt = jnp.float32, q.dtype
     B_, T, H, D = v.shape
     Hk, Q = q.shape[2], DELTA_CHUNK
@@ -1799,6 +1825,11 @@ def _delta_chunks(q, k, v, g, beta, S0):
     if pad:
         q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
                             for a in (q, k, v, g, beta))
+    if form == "kernel":
+        from ..ops.pallas.delta_rule import delta_chunks
+
+        o, S_T = delta_chunks(q, k, v, g, beta, S0, Q)
+        return o[:, :T], S_T
     nc = (T + pad) // Q
     qc, kc = q.reshape(B_, nc, Q, Hk, D), k.reshape(B_, nc, Q, Hk, D)
     vc = v.reshape(B_, nc, Q, Hk, r, D)
@@ -1924,7 +1955,8 @@ def _gated_delta(cfg: TransformerConfig, lp, h, state, l, live):
         if T == 1:
             o, S = _delta_step(q, k, v, g, beta, S0)
         else:
-            o, S = _delta_chunks(*(x.astype(h.dtype) for x in (q, k, v)), g, beta, S0)
+            o, S = _delta_chunks(*(x.astype(h.dtype) for x in (q, k, v)), g, beta, S0,
+                                 delta_block_form(cfg, T, state))
         o = o * lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.layernorm_epsilon)
         z = proj[..., conv_dim:].astype(f32).reshape(B_, T, Hv, D)
         gated = (o * lp["delta_norm_scale"].astype(f32) * jax.nn.silu(z)).astype(h.dtype)

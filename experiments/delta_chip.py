@@ -7,7 +7,13 @@ float8 (e4m3), the nearest precision below the configuration's.
 
     chiprun --timeout 2400 -- python3 experiments/delta_chip.py [--seeds 3] [--top 45]
 
-``--tiny`` rehearses the control flow on the CPU with the configuration's delta twin."""
+``--block`` (PR 55) times the rule's block form ALONE at the cell's widths (16 key / 32 value
+heads of 128; 8,192 and 4,096 rows of one sequence, bfloat16, a drawn state): the XLA form
+beside the Pallas kernel (``transformer._delta_chunks(form=...)``), operands passed in, and how
+far the kernel's o and S_T lie from the XLA form's (~1 min on the chip).
+
+``--tiny`` rehearses the control flow on the CPU with the configuration's delta twin (the
+block mode at 256 and 128 rows of 2 / 4 heads, the kernel through the interpreter)."""
 import argparse
 import json
 import os
@@ -19,8 +25,65 @@ from unittest import mock
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+def block_operands(rows, key_heads, value_heads, width=128, seed=0):
+    """q, k (unit rows; q scaled), v in bfloat16, g (decays a token from ~1 to ~e^-3: heads that
+    remember thousands of rows and heads that forget in one), beta, a drawn float32 state."""
+    import jax
+    import jax.numpy as jnp
+
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda x: x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+    q = unit(jax.random.normal(ks[0], (1, rows, key_heads, width))) * width ** -0.5
+    k = unit(jax.random.normal(ks[1], (1, rows, key_heads, width)))
+    v = jax.random.normal(ks[2], (1, rows, value_heads, width))
+    rate = jnp.exp(jnp.linspace(-9.0, 1.1, value_heads))  # a head's own: log-uniform steps
+    g = -rate * jax.random.uniform(ks[3], (1, rows, value_heads), minval=0.5, maxval=1.5)
+    beta = jax.random.uniform(ks[4], (1, rows, value_heads))
+    S0 = 0.1 * jax.random.normal(ks[5], (1, value_heads, width, width))
+    return tuple(x.astype(jnp.bfloat16) for x in (q, k, v)) + (g, beta, S0)
+
+
+def time_block_forms(forms, shapes, heads, repeats):
+    """Each form of ``forms`` (name -> f(q, k, v, g, beta, S0) -> (o, S_T)) jitted and timed at
+    each row count of ``shapes``: ms a call by the host's clock round ``repeats`` calls in
+    flight (the device runs them back to back), and the distance from the FIRST form."""
+    import jax
+    import jax.numpy as jnp
+
+    for rows in shapes:
+        operands = block_operands(rows, *heads)
+        want = None
+        for name, form in forms.items():
+            fn = jax.jit(form)
+            t0 = time.perf_counter()
+            got = jax.block_until_ready(fn(*operands))
+            first = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            for _ in range(repeats):
+                out = fn(*operands)
+            jax.block_until_ready(out)
+            ms = 1e3 * (time.perf_counter() - t0) / repeats
+            want = want or got
+            far = [float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))) for a, b in zip(got, want)]
+            print(json.dumps({"event": "block", "rows": rows, "form": name, "ms": round(ms, 3),
+                              "first_call_s": round(first, 2), "o_rel": far[0], "S_rel": far[1]}),
+                  flush=True)
+
+
+def block_mode(tiny):
+    from functools import partial
+
+    from deepspeed_tpu.models import transformer as tfm
+
+    forms = {form: partial(tfm._delta_chunks, form=form) for form in ("xla", "kernel")}
+    if tiny:
+        return time_block_forms(forms, (256, 128), (2, 4), 2)
+    return time_block_forms(forms, (8192, 4096), (16, 32), 20)
+
+
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--block", action="store_true")
     ap.add_argument("--seeds", type=int, default=3)
     ap.add_argument("--top", type=int, default=45)
     ap.add_argument("--tiny", action="store_true")
@@ -29,6 +92,8 @@ def main():
     args = ap.parse_args()
     if args.tiny:
         os.environ["JAX_PLATFORMS"] = "cpu"
+    if args.block:
+        return block_mode(args.tiny)
     import glob
 
     import jax

@@ -790,29 +790,39 @@ def test_delta_decode_program_keeps_the_matrix_state_in_place(v5e, no_persistent
     assert _footprint(compiled) < 0.5 * HBM_BYTES
 
 
-def test_delta_prefill_loops_over_chunks_and_over_no_row(v5e, no_persistent_cache, as_tpu):
-    """The cell's longest prefill (8,192 rows) compiled for the chip: the two attention
-    layers go through the flash forward kernel at a 256-wide head, the held experts'
-    pairs through the grouped-matmul kernel, and each delta layer's block form is ONE
-    loop that carries a [16, 2, 128, 128] float32 state over 128 chunks: the three of
-    the scanned period are the only loops that carry it, nothing rows x rows is made,
-    no stack is copied whole, and the program fits in about half the chip."""
+@pytest.mark.parametrize("rows", [8192, 4096])
+def test_delta_prefill_loops_over_chunks_and_over_no_row(rows, v5e, no_persistent_cache, as_tpu):
+    """The cell's two prefill buckets (8,192 and 4,096 rows) compiled for the chip: the
+    two attention layers go through the flash forward kernel at a 256-wide head, the
+    held experts' pairs through the grouped-matmul kernel, and each delta layer's block
+    form through ``ops/pallas/delta_rule.py`` (PR 55), which sweeps the [16, 2, 128, 128]
+    float32 state through the chunks INSIDE the kernel: no loop of the program carries
+    it (the XLA form's three chunk scans of the scanned period are gone), nothing
+    [chunks, 16, 2, 64, 64] reaches HBM (the decays, ``A``, its inverse: 38 operations of
+    0.6 to 1 ms a layer at 8,192 rows before), nothing rows x rows is made, no stack is
+    copied whole, and the program fits in about half the chip."""
+    from deepspeed_tpu.ops.pallas.delta_rule import KERNEL_NAME
+
     n, Smax = 64, 8192
     cfg, worker, params, cache, sds = _qwen3_next_worker(n, Smax, v5e)
-    compiled = _compile_prefill(worker, params, cache, Smax, sds)
+    compiled = _compile_prefill(worker, params, cache, rows, sds)
     text = compiled.as_text()
     kernels = set(re.findall(r'^\s*%?([a-z_-]+?)[\d.]* = .*custom_call_target="tpu_custom_call"',
                              text, re.M))
-    assert kernels == {"flash_fwd", "ragged-dot-gmm"}, kernels
+    assert kernels == {"flash_fwd", "ragged-dot-gmm", KERNEL_NAME}, kernels
+    calls = re.findall(rf'^\s*%?{KERNEL_NAME}[\d.]* = \((\S+), (\S+)\) custom-call\(', text, re.M)
+    assert len(calls) == 3  # one a delta layer of the scanned period
+    assert all(o.startswith(f"f32[1,{rows},4096]") and S.startswith("f32[1,16,2,128,128]")
+               for o, S in calls), calls
     loops = re.findall(r"^\s*%?while[\w.-]* = \((.*?)\) while\(", text, re.M)
-    chunk_loops = [carry for carry in loops if "f32[1,16,2,128,128]" in carry]
-    assert len(chunk_loops) == 3 and len(loops) <= 12, (len(chunk_loops), len(loops))
-    assert all("[128,1,16,2,64,128]" in carry for carry in chunk_loops)  # 128 chunks of 64 rows
-    assert not re.search(rf"\[(?:1,)?(?:16|32),{Smax},{Smax}\]", text)
+    assert not [carry for carry in loops if "f32[1,16,2,128,128]" in carry] and len(loops) <= 12
+    assert not re.search(rf"\[(?:1,)?{rows // 64},(?:1,)?16,2,64,(?:64|128)\]", text)  # a chunk's matrices, the scan's operands
+    assert not re.search(rf"\[(?:1,)?(?:16|32),{rows},{rows}\]", text)
     for whole in _DELTA_STACKS:
         copies = re.findall(rf"^\s*%?[\w.-]+ = {whole}\S* copy\(", text, re.M)
         assert not copies, f"the prefill copies a whole stack: {copies}"
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+    # 1.02 GB at 8,192 rows and 0.51 at 4,096 (2.2 GB with the XLA form's chunk operands)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9 * rows / Smax
     assert _footprint(compiled) < 0.6 * HBM_BYTES
 
 

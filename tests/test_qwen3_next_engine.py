@@ -155,6 +155,7 @@ def test_spans_and_pools_say_what_was_kept_and_read(served):
     assert sorted(by_len) == [1, 2, 9, 70, 130]
     assert all(a["state_rows"] == n and a["state_bytes"] == per_slot and
                a["scan_chunks"] == -(-a["bucket"] // 64) and "expert_rows_held" in a
+               and a["delta_block"] == "xla"  # the CPU, 16-wide heads: not the kernel's (PR 55)
                for n, a in by_len.items())
     assert by_len[130]["scan_chunks"] == 4 and by_len[9]["scan_chunks"] == 1
     with pytest.raises(NotImplementedError, match="kv_export"):
