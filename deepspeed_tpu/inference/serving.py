@@ -296,7 +296,16 @@ def _forward(cfg, params, toks, cache, pos, live, **kw):
     Then the experts chosen themselves, int32 [routed layers, B, T, k], which
     the load was counted from: they stay on the device unless
     ``SlotWorker.routing_log`` asks for them. The model is handed ``live`` too:
-    a state-space mixer's state may move on those rows alone."""
+    a state-space mixer's state may move on those rows alone. A model with an exit
+    gate (``exit_gate``: several passes over its layers) adds ONE: float32
+    [layer_passes], the ``live`` rows' mean exit distribution, reduced here on the
+    device; it comes back in the same fetch and ``SlotWorker._note_exit`` puts it
+    on the span."""
+    if cfg.exit_gate:
+        logits, cache, p = tfm.apply_with_cache(cfg, params, toks, cache, pos, live=live,
+                                                return_exit=True, **kw)
+        on = live.astype(jnp.float32)[..., None]
+        return logits, cache, (jnp.sum(p * on, axis=(0, 1)) / jnp.maximum(jnp.sum(on), 1.0),)
     if cfg.moe_routing != "dropless":
         return (*tfm.apply_with_cache(cfg, params, toks, cache, pos, live=live, **kw), ())
     logits, cache, chosen = tfm.apply_with_cache(
@@ -416,6 +425,14 @@ class SlotWorker:
         self.operator_attrs = {
             f"{op}_layers": len(self.cfg.layers_of(op))
             for op in sorted(set(self.cfg.layer_operators or ()), reverse=True)}
+        # a model whose layer stack runs several times (``layer_passes``): what its
+        # spans say of it (nothing for any other). ``cache_layers``: the K/V layers a
+        # token keeps, one a (pass, layer)
+        self.pass_attrs = {}
+        if self.cfg.layer_passes > 1:
+            self.pass_attrs = {"layer_passes": self.cfg.layer_passes,
+                               "cache_layers": kept["tokens"]}
+            telemetry.gauge("serving/cache_layers").set(kept["tokens"])
         # where the programs read a routed layer's expert banks from ("in_place" /
         # "sliced"; None for a model without dropless routing): the rule they trace by
         self.expert_bank = tfm.expert_bank_form(self.cfg, self.params.get("moe"), self.mesh)
@@ -677,10 +694,11 @@ class SlotWorker:
 
     def _outs(self, n: int) -> tuple:
         """``out_shardings`` of a program that returns the slot cache, ``n``
-        host-bound values and, for a routed model, the expert load and the
-        experts chosen (``_forward``)."""
-        routed = self.cfg.moe_routing == "dropless"
-        return (self._cache_shardings,) + (None,) * (n + 2 * routed)
+        host-bound values and ``_forward``'s extra outputs: for a routed model the
+        expert load and the experts chosen, for one with an exit gate the mean exit
+        distribution."""
+        extra = 1 if self.cfg.exit_gate else 2 * (self.cfg.moe_routing == "dropless")
+        return (self._cache_shardings,) + (None,) * (n + extra)
 
     def _key_sharding(self):
         """Where the carried key lives: replicated over the devices of the cache.
@@ -754,6 +772,19 @@ class SlotWorker:
         self.telemetry.gauge("serving/expert_load_max_over_mean").set(
             summary["expert_load_max_over_mean"])
         self.telemetry.gauge("serving/experts_touched").set(summary["experts_touched"])
+
+    def _note_exit(self, sp, mean_p) -> None:
+        """A call of a model with an exit gate: its live rows' mean exit distribution
+        ``mean_p`` [layer_passes] (the fetched tail of the program's outputs) on the
+        call's span as ``exit_pass_mean``, the mean pass at which a row would stop
+        (sum of r x p_r, r from 1), and ``exit_cdf``, the share that would have stopped
+        after each pass but the last; the first on the gauge of its name too. The gate
+        decides nothing: every row ran every pass."""
+        p = np.asarray(mean_p, np.float64)
+        mean = float(np.sum(p * np.arange(1, len(p) + 1)))
+        sp.annotate(exit_pass_mean=round(mean, 4),
+                    exit_cdf=[round(float(c), 4) for c in np.cumsum(p)[:-1]])
+        self.telemetry.gauge("serving/exit_pass_mean").set(mean)
 
     def _state_attrs(self, n_active: int) -> dict:
         """What a decode span says of the per-sequence state (nothing
@@ -878,7 +909,7 @@ class SlotWorker:
         ``d2h``, the separate arrays fetched (the experts a ``routing_log`` asks
         for are not among them). Returns ``(span, the n_out fetched arrays or
         None)``; what follows them in the fetch is a routed model's load, noted
-        with ``rows``."""
+        with ``rows``, or an exit gate's mean distribution (``_note_exit``)."""
         tm = self.telemetry
         with tm.span(name, **attrs) as sp:
             with tm.span("dispatch"):
@@ -909,7 +940,10 @@ class SlotWorker:
                     jax.block_until_ready(out)
                 with tm.span("copy"):
                     out = tuple(np.asarray(x) for x in jax.device_get(out))
-            self._note_load(sp, out[n_out:], chosen, **rows)
+            if self.cfg.exit_gate:
+                self._note_exit(sp, out[n_out])
+            else:
+                self._note_load(sp, out[n_out:], chosen, **rows)
         return sp, out[:n_out]
 
     def decode(self, last_tok, pos, wpos, active, temp, top_k, top_p):
@@ -930,7 +964,7 @@ class SlotWorker:
         live_pos = np.asarray(pos)[np.asarray(active, bool)]
         attrs = dict(n_active=n_active, cached_tokens=int(np.sum(live_pos + 1)),
                      attn=tfm.cache_step_form(self.cfg), **self._state_attrs(n_active),
-                     **self._ring_attrs(live_pos))
+                     **self._ring_attrs(live_pos), **self.pass_attrs)
         # host arrays straight into the jitted call (pjit batches the uploads;
         # ``h2d`` counts them: seven here), the carried key between them as the
         # one device operand; dtypes are pinned by the engine's per-slot state
@@ -958,7 +992,8 @@ class SlotWorker:
         attrs = dict(n_active=int(np.count_nonzero(active)), depth=depth,
                      cached_tokens=int(np.sum(
                          (np.asarray(pos) + depth + 1)[np.asarray(active, bool)])),
-                     attn=tfm.cache_step_form(self.cfg), **({"warm": True} if warm else {}))
+                     attn=tfm.cache_step_form(self.cfg), **({"warm": True} if warm else {}),
+                     **self.pass_attrs)
         # host arrays go straight into the jitted call: pjit's C++ argument
         # path uploads them in one batch, and the greedy family's trimmed
         # signature (no rng/temp/top_k/top_p — dead operands there) skips the
@@ -998,7 +1033,7 @@ class SlotWorker:
                      **tfm.causal_grid_form(self.cfg, bucket),
                      **tfm.window_grid_form(self.cfg, bucket),
                      **self._block_attrs(bucket, true_len),
-                     **self._ring_attrs(np.arange(true_len)))
+                     **self._ring_attrs(np.arange(true_len)), **self.pass_attrs)
         sp, (tok, bad) = self._run(
             "prefill", attrs, lambda: self._prefill_prog(bucket),
             lambda sp: ((np.asarray(padded, np.int32), np.int32(slot), np.int32(true_len)),
@@ -1021,7 +1056,8 @@ class SlotWorker:
         tm = self.telemetry
         attrs = dict(uid=uid, slot=slot, width=width, live=live, fetch=fetch,
                      cached_tokens=int(start) + int(live),
-                     attn=tfm.cache_step_form(self.cfg), **self._block_attrs(width, live))
+                     attn=tfm.cache_step_form(self.cfg), **self._block_attrs(width, live),
+                     **self.pass_attrs)
         sp, out = self._run(
             "chunk", attrs, lambda: self._chunk_prog(width),
             lambda sp: ((np.asarray(toks, np.int32), np.int32(slot), np.int32(start),
@@ -1549,6 +1585,8 @@ class ServingEngine:
             f"{self.worker.hbm_pools()['slot_kv_cache'] / 1e6:.1f} MB at "
             f"{tfm.cache_bytes_per_token(self.cfg)} B a token a layer "
             f"({tfm.cache_step_form(self.cfg)})"
+            + (f" in {self.worker.pass_attrs['cache_layers']} layers, one a (pass, layer) of "
+               f"{self.cfg.layer_passes} passes" if self.worker.pass_attrs else "")
             + (f" in {tfm.cache_layers(self.cfg)['tokens']} layers, per-sequence state "
                f"{self.worker.hbm_pools()['slot_state'] / 1e6:.1f} MB at "
                f"{self.worker.state_bytes_per_slot} B a slot over {self.worker.state_layers} "

@@ -54,6 +54,16 @@ class TransformerConfig:
     vocab_size: int = 50257
     max_seq_len: int = 1024
     num_layers: int = 12
+    # The layer stack runs this many times over the SAME weights (a looped /
+    # universal transformer: Ouro's ``total_ut_steps``), the final norm after EVERY
+    # pass and its output the next pass's input. Through the cache every (pass,
+    # layer) keeps K/V of its own, pass-major (``cache_layout``)
+    layer_passes: int = 1
+    # An exit gate (``layer_passes`` > 1): one [hidden_size] -> 1 linear map with a
+    # bias, read on every pass's normed output; its sigmoids give a distribution over
+    # the pass at which a token would stop (``exit_distribution``), handed out with
+    # ``return_exit``. It decides nothing: every row runs every pass
+    exit_gate: bool = False
     num_heads: int = 12
     hidden_size: int = 768
     intermediate_size: Optional[int] = None  # default 4*hidden
@@ -62,7 +72,10 @@ class TransformerConfig:
     rotary_interleaved: bool = False  # GPT-J rotate-every-two convention
     parallel_residual: bool = False  # GPT-NeoX style
     causal: bool = True  # False = bidirectional (BERT-style encoders)
-    norm_style: str = "pre"  # pre (GPT) | post (BERT) layernorm placement
+    # pre (GPT) | post (BERT) layernorm placement | sandwich: a norm BEFORE and a second
+    # norm AFTER each sublayer, inside the residual branch (x += norm2(f(norm1(x))):
+    # Ouro's), two more scale leaves a layer (``ln1_post`` / ``ln2_post``)
+    norm_style: str = "pre"
     norm_kind: str = "layer"  # layer (scale + bias) | rms (RMSNorm: scale only)
     # RMSNorm with a learned scale on the queries and the keys, before the rotary.
     # True: ONE norm over the whole projection, heads and head width together
@@ -431,6 +444,7 @@ def _refuse_uncoded(cfg: "TransformerConfig") -> None:
             "hidden_size // num_heads")
     _refuse_uncoded_heads_and_mixer(cfg)
     _refuse_uncoded_kinds_and_share(cfg)
+    _refuse_uncoded_passes(cfg)
     if cfg.moe_score_fn not in ("softmax", "sigmoid"):
         raise ValueError(f"moe_score_fn is 'softmax' or 'sigmoid', not {cfg.moe_score_fn!r}")
     forms = {"moe_score_fn": cfg.moe_score_fn != "softmax", "moe_select_bias": cfg.moe_select_bias,
@@ -645,8 +659,52 @@ def _refuse_uncoded_operators(cfg: "TransformerConfig") -> None:
             raise NotImplementedError(f"layer_operators (a {op!r} layer) with {what} has no code")
 
 
+def _refuse_uncoded_passes(cfg: "TransformerConfig") -> None:
+    """``_refuse_uncoded`` for the sandwich norms, the passes over the layer stack and
+    the exit gate: what the outer loop, the cache's (pass, layer) index and the two
+    branch norms have no code for (or no test against a reference), by name."""
+    if cfg.norm_style not in ("pre", "post", "sandwich"):
+        raise ValueError(f"norm_style is 'pre', 'post' or 'sandwich', not {cfg.norm_style!r}")
+    if cfg.layer_passes < 1:
+        raise ValueError(f"layer_passes is the times the stack runs, >= 1 (got {cfg.layer_passes})")
+    if cfg.exit_gate and cfg.layer_passes == 1:
+        raise ValueError("exit_gate without layer_passes > 1: no pass to stop before")
+    for feature, on, no_code in (
+        ("norm_style='sandwich'", cfg.norm_style == "sandwich", {
+            "parallel_residual (the second norm is on ONE sublayer's branch)":
+                cfg.parallel_residual,
+            "the state-space mixer (ssm_state_size > 0: two operators share the branch)":
+                cfg.ssm_state_size > 0,
+            "layer_operators": cfg.layer_operators is not None,
+            "mtp_layers": cfg.mtp_layers != 0,
+        }),
+        ("layer_passes > 1", cfg.layer_passes > 1, {
+            "layer kinds (local_attn_layers / rotary_layers / layer_operators: the blocks read "
+            "a layer's kind at the index the loop hands them, which counts the passes)":
+                cfg.layer_kinds is not None,
+            "the state-space mixer (ssm_state_size > 0: its state is a layer's, not a pass's)":
+                cfg.ssm_state_size > 0,
+            "latent attention (kv_lora_rank > 0)": cfg.kv_lora_rank > 0,
+            "a routed feed-forward (moe_every > 0: the experts chosen are stacked by layer)":
+                cfg.moe_every > 0,
+            "mtp_layers (the module reads the stream BEFORE the final norm)": cfg.mtp_layers != 0,
+            "dropout or progressive layer drop (one key a layer, not a pass)":
+                bool(cfg.hidden_dropout or cfg.attn_dropout or cfg.pld_enabled),
+            "param_offload (a streamed slice's host-pinned cotangents would meet over the "
+            "passes)": cfg.param_offload,
+        }),
+    ):
+        for what, refused in no_code.items():
+            if on and refused:
+                raise NotImplementedError(f"{feature} with {what} has no code")
+
+
 def refuse_in_pipeline(cfg: "TransformerConfig") -> None:
     """What the pipeline schedules (pipe/) have not carried yet, by name."""
+    if cfg.layer_passes > 1:
+        raise NotImplementedError(
+            "layer_passes > 1 under a pipeline schedule has no code: a stage would be visited "
+            "once a pass, and the schedules send a micro-batch through the stages once")
     if cfg.layer_operators is not None:
         raise NotImplementedError(
             "layer_operators under a pipeline schedule has no code: the stages slice ONE "
@@ -721,6 +779,11 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
             "wv": stack(keys[2], (d, Hkv, Dh), d, La, scale=attn_in)})
     if cfg.norm_kind != "rms":
         layers.update({"ln1_bias": jnp.zeros((L, d)), "ln2_bias": jnp.zeros((L, d))})
+    if cfg.norm_style == "sandwich":  # the second norm of each sublayer, on its branch
+        for name in ("ln1_post", "ln2_post"):
+            layers[name + "_scale"] = jnp.ones((L, d))
+            if cfg.norm_kind != "rms":
+                layers[name + "_bias"] = jnp.zeros((L, d))
     if cfg.qk_norm == "head":  # one [head_dim] scale, every head's
         layers.update({"q_norm_scale": jnp.ones((La, Dh)), "k_norm_scale": jnp.ones((La, Dh))})
     elif cfg.qk_norm:
@@ -790,6 +853,9 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> Params:
         params["moe"] = init_moe_params(keys[9], n_moe, cfg.num_experts, d, f)
     if cfg.mtp_layers:
         params["mtp"] = _init_mtp(cfg, more[6])
+    if cfg.exit_gate:  # drawn as any [d, 1] matrix; float32 and replicated wherever it is held
+        params["exit_gate"] = {"w": _dense_init(jax.random.fold_in(rng, 17), (d, 1), d),
+                               "b": jnp.zeros((1,))}
     return params
 
 
@@ -927,6 +993,11 @@ def logical_axes(cfg: TransformerConfig) -> Params:
                        "wv": ("layers", "embed", "heads", "kv")})
     if cfg.norm_kind != "rms":
         layers.update({"ln1_bias": ("layers", "embed"), "ln2_bias": ("layers", "embed")})
+    if cfg.norm_style == "sandwich":
+        for name in ("ln1_post", "ln2_post"):
+            layers[name + "_scale"] = ("layers", "embed")
+            if cfg.norm_kind != "rms":
+                layers[name + "_bias"] = ("layers", "embed")
     if cfg.qk_norm == "head":
         layers.update({"q_norm_scale": ("layers", "kv"), "k_norm_scale": ("layers", "kv")})
     elif cfg.qk_norm:
@@ -995,6 +1066,8 @@ def logical_axes(cfg: TransformerConfig) -> Params:
         axes["mtp"] = {"enorm_scale": ("embed",), "hnorm_scale": ("embed",),
                        "eh_proj": (None, "embed"), "layers": block["layers"],
                        "lnf_scale": ("embed",), **({"moe": block["moe"]} if "moe" in block else {})}
+    if cfg.exit_gate:
+        axes["exit_gate"] = {"w": (None, None), "b": (None,)}
     return axes
 
 
@@ -1337,7 +1410,8 @@ def remat_candidates(cfg: TransformerConfig) -> tuple:
     routed = (L - lead) // cfg.moe_every if cfg.moe_every > 0 else 0
     gated = cfg.activation == "swiglu"
     ffn = 2 * lead * cfg.dense_ffn_size + (L - lead - routed) * cfg.ffn_size * (1 + gated)
-    return floor, FFN_NAMES[:1 + gated], ffn
+    # a checkpoint is one (pass, layer)'s: what the layers keep, ``layer_passes`` times
+    return cfg.layer_passes * floor, FFN_NAMES[:1 + gated], cfg.layer_passes * ffn
 
 
 def step_working_bytes(cfg: TransformerConfig, sequences: int, tokens: int) -> int:
@@ -1355,7 +1429,11 @@ def step_working_bytes(cfg: TransformerConfig, sequences: int, tokens: int) -> i
       ``sequences`` row x ``loss_chunk_size`` positions x the vocabulary; all
       positions where the loss is not chunked), or one layer's backward pass,
       which holds about two rows as wide as the feed-forward and ten as wide
-      as the residual for each of the device's ``tokens``."""
+      as the residual for each of the device's ``tokens``.
+
+    ``layer_passes`` changes none of these: a pass gathers the same two layers and
+    its backward pass is one layer's; what the passes multiply is what the
+    checkpoints keep (``remat_candidates``)."""
     item = jnp.dtype(cfg.dtype).itemsize
     d, f = cfg.hidden_size, max(cfg.ffn_size, cfg.dense_ffn_size)
     layer_weights = d * (cfg.num_heads + 2 * cfg.kv_heads) * cfg.head_dim + d * d + (
@@ -2134,7 +2212,9 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
     """THE transformer layer: every caller's layer is this function.
 
     carry = (x [B, T, d] in the compute dtype, state); ``lp`` one layer's leaves;
-    ``l`` its index in the stacks it came from. What differs between callers
+    ``l`` its index in the stacks it came from (with ``layer_passes`` > 1: pass x
+    layers + that index, the (pass, layer)'s place in the CACHE's stacks, which is
+    all such a model reads ``l`` for: ``_layer_loop``). What differs between callers
     comes in as arguments and nothing else does:
 
     - ``attend(q, k, v, state, l, lp) -> (attention output, state)``: how
@@ -2161,7 +2241,9 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
 
     Norm kind and placement and the residual form are what ``cfg`` says, for a
     dense layer and a routed one alike (``norm_style="post"`` is the BERT
-    layout, sublayer -> residual add -> norm, and is sequential). Three residual
+    layout, sublayer -> residual add -> norm, and is sequential; ``"sandwich"`` is
+    the pre-norm layout with a second norm on each sublayer's OUTPUT, before the
+    residual add: x + norm(f(norm(x))), sequential too). Three residual
     forms: sequential (x + attn, then + ffn of the new x), parallel (x + attn +
     ffn, both of the same x: GPT-NeoX), and, with a state-space mixer, the
     attention and the mixer in parallel on the SAME normed input followed by a
@@ -2180,6 +2262,9 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
         return out if gate is None else gate * out
 
     pre = cfg.norm_style != "post"
+    # the sandwich's second norm, on a sublayer's branch; any other placement has none
+    post = (lambda y, name: norm(cfg, y, lp, name)) if cfg.norm_style == "sandwich" \
+        else (lambda y, name: y)
     h = norm(cfg, x, lp, "ln1") if pre else x
     rotary = True
     if cfg.rotary_layers is not None:
@@ -2191,7 +2276,8 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
     else:
         q, k, v, *out_gate = _qkv_proj(cfg, lp, h, positions, rotary)
         attn, state = attend(q, k, v, state, l, lp, **({} if kind is None else {"kind": kind}))
-        attn_out = branch(_attn_out_proj(cfg, lp, attn, *out_gate), cfg.attn_dropout, k_attn)
+        attn_out = branch(post(_attn_out_proj(cfg, lp, attn, *out_gate), "ln1_post"),
+                          cfg.attn_dropout, k_attn)
     if cfg.ssm_state_size:
         mixed, state = _ssm_mixer(cfg, lp, h, state, l, live)
         attn_out = attn_out + mixed
@@ -2203,7 +2289,7 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
         if not pre:
             x = norm(cfg, x, lp, "ln1")
         f, aux, experts = ffn(lp, norm(cfg, x, lp, "ln2") if pre else x)
-        x = x + branch(f, cfg.hidden_dropout, k_hidden)
+        x = x + branch(post(f, "ln2_post"), cfg.hidden_dropout, k_hidden)
         if not pre:
             x = norm(cfg, x, lp, "ln2")
     return (x, state), (aux, experts)
@@ -2257,10 +2343,12 @@ def _remat_wrapper(cfg: TransformerConfig):
 
 
 def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, attend,
-                per_layer=None, wrap=None, lead=None, live=None, forward_only: bool = False):
+                per_layer=None, wrap=None, lead=None, live=None, forward_only: bool = False,
+                after_pass=None):
     """THE layer loop: ``_block`` over the stacked ``layers`` [L, ...] (the
     whole model's, or one pipeline stage's slice), ``moe`` the routed layers'
-    stacks or None -> (x, state, summed aux loss, experts chosen or None).
+    stacks or None -> (x, state, summed aux loss, experts chosen or None, what
+    ``after_pass`` handed out a pass or None).
 
     Owns the stack loaders, the layer index, ``scan_unroll`` and the period. It
     first says, as Python values, WHAT every layer IS: its kind and its
@@ -2312,7 +2400,19 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
     ``expert_bank_form`` says "in_place" the three expert banks of dropless
     routing are read the same way, through the grouped GEMM's own group index
     (``moe/dropless.py``). And a one-token block is a decode step to the routed
-    feed-forward (``_moe_ffn``)."""
+    feed-forward (``_moe_ffn``).
+
+    The PASSES (``cfg.layer_passes`` > 1): everything above is then the body of ONE
+    outer ``lax.scan`` over the pass r, which carries (x, state); the stacks are
+    its constants (compile time flat in the passes as in the depth; under a backward
+    pass their cotangents add up over the passes). ``after_pass(x) -> (x, y)`` (the
+    caller's: the final norm, and the exit gate's reading where asked for) runs
+    behind every pass, the last one too, and the y's come back stacked by pass. The
+    blocks of pass r are handed r x L + l where those of a model of one pass are
+    handed l: the (pass, layer)'s place in the cache's stacks (``cache_layout``),
+    where ``attend`` writes and reads, while the block's weights are layer l's
+    slice as in any pass. ``forward_only``'s rule holds under it: no stack is
+    sliced but from its beginning, none is copied a pass."""
     by_op = {op: layers[op] for op in OPERATORS if op in layers}  # none: ONE stack of layers
     layers_xs, load_layer = _make_stack_loader(
         cfg, {k: v for k, v in layers.items() if k not in by_op} if by_op else layers)
@@ -2322,7 +2422,8 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
     n_lead = 0 if lead is None else jax.tree.leaves(lead)[0].shape[0]
     decode = forward_only and x.shape[1] == 1
     # every stack the blocks read, by the name of what uses it (``uses``)
-    stacks = {"layers": (layers_xs, jnp.arange(L, dtype=jnp.int32), per_layer or {})}
+    index = jnp.arange(L, dtype=jnp.int32)
+    stacks = {"layers": (layers_xs, index, per_layer or {})}
     load_lead = load_moe = banks = None
     if lead is not None:
         stacks["lead"], load_lead = _make_stack_loader(cfg, lead)
@@ -2423,25 +2524,42 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
 
     join = lambda ys: ys[0] if len(ys) == 1 else jax.tree.map(lambda *a: jnp.concatenate(a), *ys)
     lo, hi = n_lead, L - tail
-    carry, ys = stretch((x, state), stacks, 0, 0, lo, wrap)
-    if period == 1:  # a period of one layer is the layer: one run over the stacks as they are
-        carry, got = run(carry, stacks, 0, lo, hi, wrap)
-        ys += got
-    elif G:
-        def group(carry, held):
-            carry, ys = stretch(carry, held, lo, lo, lo + period)
-            return carry, (join(ys) if ys else None)
 
-        carry, got = lax.scan(wrap(group), carry, share(stacks, 0, lo, lo + period, G),
-                              unroll=unroll)
-        if got is not None:
-            ys.append(jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), got))
-    carry, got = stretch(carry, stacks, 0, hi, L, wrap)
-    ys += got
-    if not ys:
-        return (*carry, jnp.zeros((), jnp.float32), None)
-    aux, chosen = join(ys)
-    return (*carry, jnp.sum(aux), chosen)
+    def once(carry, stacks):
+        """Every layer once, ``stacks`` as above -> (carry, summed aux loss, experts chosen)."""
+        carry, ys = stretch(carry, stacks, 0, 0, lo, wrap)
+        if period == 1:  # a period of one layer is the layer: one run over the stacks as they are
+            carry, got = run(carry, stacks, 0, lo, hi, wrap)
+            ys += got
+        elif G:
+            def group(carry, held):
+                carry, ys = stretch(carry, held, lo, lo, lo + period)
+                return carry, (join(ys) if ys else None)
+
+            carry, got = lax.scan(wrap(group), carry, share(stacks, 0, lo, lo + period, G),
+                                  unroll=unroll)
+            if got is not None:
+                ys.append(jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), got))
+        carry, got = stretch(carry, stacks, 0, hi, L, wrap)
+        ys += got
+        if not ys:
+            return carry, jnp.zeros((), jnp.float32), None
+        aux, chosen = join(ys)
+        return carry, jnp.sum(aux), chosen
+
+    if cfg.layer_passes == 1:
+        carry, aux, chosen = once((x, state), stacks)
+        return (*carry, aux, chosen, None)
+
+    def one_pass(carry, r):
+        with jax.named_scope("pass"):
+            carry, aux, _ = once(carry, {**stacks, "layers": (layers_xs, index + r * L, {})})
+            x, handed = after_pass(carry[0])
+        return (x, carry[1]), (aux, handed)
+
+    carry, (aux, handed) = lax.scan(one_pass, (x, state),
+                                    jnp.arange(cfg.layer_passes, dtype=jnp.int32))
+    return (*carry, jnp.sum(aux), None, handed)
 
 
 def _head_matrix(params: Params, load=lambda t: t):
@@ -2454,11 +2572,49 @@ def _final_norm(cfg: TransformerConfig, params: Params, x):
     return norm(cfg, x, params, "lnf") if cfg.final_ln else x
 
 
-def _lm_head(cfg: TransformerConfig, params: Params, x):
-    """THE output head: final norm (if ``cfg.final_ln``), tied or untied
-    projection, ``lm_head_bias`` -> float32 logits. The losses read
-    ``_final_norm`` and ``_head_matrix`` through ``lm_loss_from_hidden``."""
-    x = _final_norm(cfg, params, x)
+def _after_pass(cfg: TransformerConfig, params: Params, return_exit: bool):
+    """What ``apply`` / ``apply_with_cache`` hand ``_layer_loop`` of a model with
+    ``layer_passes`` > 1 (None for any other): x -> (the final norm of it, which the
+    next pass starts from and the head reads, so that a caller that handed one in does
+    not norm the last pass's output a second time; the exit gate's
+    lambda = sigmoid(h . w_g + b_g) on that normed h, float32 [B, T], where
+    ``return_exit`` asks for it: without the flag the gate is not in the program)."""
+    if return_exit and not cfg.exit_gate:
+        raise ValueError("return_exit: the configuration states no exit_gate")
+    if cfg.layer_passes == 1:
+        return None
+
+    def after(x):
+        h = _final_norm(cfg, params, x)
+        if not return_exit:
+            return h, None
+        gate = params["exit_gate"]
+        with jax.named_scope("exit_gate"):
+            score = jnp.einsum("btd,d->bt", h.astype(jnp.float32), gate["w"][:, 0].astype(
+                jnp.float32), precision=lax.Precision.HIGHEST) + gate["b"].astype(jnp.float32)
+        return h, jax.nn.sigmoid(score)
+
+    return after
+
+
+def exit_distribution(gates):
+    """The exit gate's lambdas [passes, B, T] -> p [B, T, passes] float32, the
+    distribution over the pass at which a token would stop: p_r = lambda_r x
+    prod_{s<r} (1 - lambda_s) before the last pass, which takes the rest (its own
+    lambda is read and decides nothing). Sums to 1 over the passes."""
+    stay = jnp.cumprod(1.0 - gates[:-1], axis=0)  # the share still running BEHIND pass r
+    before = jnp.concatenate([jnp.ones_like(gates[:1]), stay[:-1]])  # ... BEFORE pass r
+    p = jnp.concatenate([gates[:-1] * before, stay[-1:]])
+    return jnp.moveaxis(p, 0, -1)
+
+
+def _lm_head(cfg: TransformerConfig, params: Params, x, normed: bool = False):
+    """THE output head: final norm (if ``cfg.final_ln``; not where ``normed`` says
+    the layer loop applied it already), tied or untied projection, ``lm_head_bias`` ->
+    float32 logits. The losses read ``_final_norm`` and ``_head_matrix`` through
+    ``lm_loss_from_hidden``."""
+    if not normed:
+        x = _final_norm(cfg, params, x)
     with jax.named_scope("lm_head"):
         logits = jnp.einsum("bsd,dv->bsv", x, _head_matrix(params).astype(x.dtype))
         logits = logits.astype(jnp.float32)
@@ -2504,6 +2660,7 @@ def apply(
     _top_streamed: bool = False,
     return_routing: bool = False,
     mtp_tokens=None,
+    return_exit: bool = False,
 ) -> jnp.ndarray:
     """tokens [B, S] int32 -> logits [B, S, vocab] (fp32), or the final hidden
     states [B, S, d] when ``return_hidden`` (used by the chunked LM loss).
@@ -2514,6 +2671,9 @@ def apply(
     AFTER the one at i) the multi-token-prediction module's logits for t_{i+2}
     come last of all, [B, S, vocab] (``mtp_logits``), and the experts its block
     chose are the last row of the routing.
+    With ``return_exit`` (``exit_gate`` only) the exit distribution comes last of
+    all: float32 [B, S, layer_passes] (``exit_distribution``); the logits are the
+    last pass's either way.
     ``rng`` enables dropout / progressive layer drop (training); ``step``
     drives the PLD theta schedule. ``_top_streamed``: the caller already
     streamed the top-level leaves (param_offload) — a shared leaf (tied wte)
@@ -2537,19 +2697,24 @@ def apply(
             theta_t = cfg.pld_theta + (1.0 - cfg.pld_theta) * jnp.exp(-cfg.pld_gamma * t)
             depth_frac = jnp.arange(L, dtype=jnp.float32) / max(1, L)
             per_layer["pld_keep"] = 1.0 - depth_frac * (1.0 - theta_t)  # [L]
-    x, _, aux_total, chosen = _layer_loop(
+    after_pass = _after_pass(cfg, params, return_exit)
+    x, _, aux_total, chosen, gates = _layer_loop(
         cfg, params["layers"], params.get("moe"), x, None, positions=positions,
         attend=_stateless_attention(cfg, S), per_layer=per_layer, wrap=_remat_wrapper(cfg),
-        lead=params.get("dense_ffn"))
+        lead=params.get("dense_ffn"), after_pass=after_pass)
     mtp = ()
     if mtp_tokens is not None:
         mtp, module_chosen = mtp_logits(cfg, params, x, mtp_tokens, positions)
         mtp = (mtp,)
         if chosen is not None and module_chosen is not None:  # the module's choices last
             chosen = jnp.concatenate([chosen, module_chosen])
-    x = _final_norm(cfg, params, x) if return_hidden else _lm_head(cfg, params, x)
+    normed = after_pass is not None  # the loop normed every pass's output, the last one's too
+    if return_hidden:
+        x = x if normed else _final_norm(cfg, params, x)
+    else:
+        x = _lm_head(cfg, params, x, normed)
     out = ((x,) + ((aux_total,) if with_aux else ()) + ((chosen,) if return_routing else ())
-           + mtp)
+           + mtp + ((exit_distribution(gates),) if return_exit else ()))
     return out if len(out) > 1 else x
 
 
@@ -2570,9 +2735,9 @@ def mtp_logits(cfg: TransformerConfig, params: Params, hidden, next_tokens, posi
                               rms_norm(hidden, p["hnorm_scale"], eps)], axis=-1)
     x = jnp.einsum("bsk,kd->bsd", joined, p["eh_proj"].astype(joined.dtype))
     block = _mtp_block_cfg(cfg)
-    x, _, _, chosen = _layer_loop(block, p["layers"], p.get("moe"), x, None,
-                                  positions=positions,
-                                  attend=_stateless_attention(block, x.shape[1]))
+    x, _, _, chosen, _ = _layer_loop(block, p["layers"], p.get("moe"), x, None,
+                                     positions=positions,
+                                     attend=_stateless_attention(block, x.shape[1]))
     return _lm_head(cfg, {**params, "lnf_scale": p["lnf_scale"]}, x), chosen
 
 
@@ -2632,6 +2797,13 @@ def cache_layout(cfg: TransformerConfig) -> dict:
     value and the rest of its key; the 'one head' is every head's. With layers of
     several kinds (``layer_kinds``) L counts the WHOLE-context ATTENTION layers
     alone (``cache_layers``): a window layer keeps a ring, a conv layer no token.
+    With ``layer_passes`` > 1 a pass attends to the keys and values that the SAME
+    pass wrote at earlier positions (they are functions of the pass's own input), so
+    every (pass, layer) keeps K/V of its own and the leading axis is ``layer_passes``
+    x L, PASS-MAJOR: pass r of layer l lies at r x L + l (ONE rule: ``_layer_loop``
+    hands the blocks that index and ``attend`` writes and reads there; the weights
+    have L layers, the cache more). Every helper below moves the leading axis as it
+    finds it.
 
     RINGS, under ``RING``, ``{leaf: (positions, heads, width)}``: what a WINDOW
     layer keeps, [L_window, B, R, heads, width]: the last R = ``local_attn_window``
@@ -2751,12 +2923,13 @@ def cache_rows_step(cfg: TransformerConfig, T: int = 1) -> bool:
 
 def cache_layers(cfg: TransformerConfig) -> dict:
     """How many layers keep each kind of ``cache_layout``'s leaves, as the leading
-    axis of those leaves has it: ``"tokens"`` the whole-context attention layers,
+    axis of those leaves has it: ``"tokens"`` the whole-context attention layers
+    (x ``layer_passes``: a (pass, layer) keeps its own K/V, ``cache_layout``),
     ``RING`` the window layers, ``STATE`` the layers with per-sequence state (every
     layer of a model with a state-space mixer; the conv or delta layers of one with
     ``layer_operators``; 0 for any other)."""
     n_window, n_op = len(cfg.window_layers), len(cfg.stateful_layers)
-    return {"tokens": cfg.num_layers - n_window - n_op, RING: n_window,
+    return {"tokens": cfg.layer_passes * (cfg.num_layers - n_window - n_op), RING: n_window,
             STATE: n_op or (cfg.num_layers if cfg.ssm_state_size else 0)}
 
 
@@ -3219,7 +3392,7 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
 def apply_with_cache(
     cfg: TransformerConfig, params: Params, tokens, cache, pos,
     last_only: bool = False, last_index=None, write_pos=None,
-    return_routing: bool = False, live=None,
+    return_routing: bool = False, live=None, return_exit: bool = False,
 ):
     """tokens [B, T] entering at absolute position ``pos`` -> (logits, updated
     cache). Serves prefill (T=prompt) and decode (T=1). With ``last_only``
@@ -3248,7 +3421,9 @@ def apply_with_cache(
     wants a layer's slice). With ``return_routing`` (dropless routing only) a third
     value comes back: the experts chosen for each of the tokens given, in every
     routed layer, int32 [layers, B, T, k] — padded and idle rows are routed like
-    any other, so a caller that counts load masks them itself.
+    any other, so a caller that counts load masks them itself. With ``return_exit``
+    (``exit_gate`` only) the exit distribution of each of the tokens given comes
+    last: float32 [B, T, layer_passes], padded and idle rows' too.
 
     ``live`` [B, T] bool (None: every row): the rows that are a sequence's own,
     leading each row of the batch — not a bucket's padding, not an idle slot's
@@ -3277,18 +3452,20 @@ def apply_with_cache(
     params = _stream_top_level(cfg, params)
     positions, attend = _cache_attention(cfg, B, T, cache_len(cache), pos, write_pos, live)
     x, _ = embed(cfg, params, tokens, positions)
-    x, cache, _, chosen = _layer_loop(
+    after_pass = _after_pass(cfg, params, return_exit)
+    x, cache, _, chosen, gates = _layer_loop(
         cfg, params["layers"], params.get("moe"), x, dict(cache),
         positions=positions, attend=attend, lead=params.get("dense_ffn"), live=live,
-        forward_only=True)
+        forward_only=True, after_pass=after_pass)
     if last_index is not None:
         # bucketed prefill: the live last token sits at ``last_index``
         # (prompt_len - 1), not at T-1 — project only that position
         x = lax.dynamic_slice_in_dim(x, jnp.asarray(last_index, jnp.int32), 1, axis=1)
     elif last_only:
         x = x[:, -1:]
-    logits = _lm_head(cfg, params, x)
-    return (logits, cache, chosen) if return_routing else (logits, cache)
+    logits = _lm_head(cfg, params, x, normed=after_pass is not None)
+    return ((logits, cache) + ((chosen,) if return_routing else ())
+            + ((exit_distribution(gates),) if return_exit else ()))
 
 
 # ---------------------------------------------------------------------------
@@ -3505,10 +3682,15 @@ class Model:
         ThroughputTimer TFLOPS estimate utils/timer.py:135)."""
         c = self.config
         # a conv layer's operator (d x 3d in, d x d out) is as many parameters as
-        # four d x d attention projections, and attends to nothing
+        # four d x d attention projections, and attends to nothing. A gated
+        # feed-forward has three matrices; a token multiplies through a layer's
+        # matrices once a PASS and through the head once
+        ffn_matrices = 3 if c.activation == "swiglu" else 2
         n_params = (
-            c.num_layers * (4 * c.hidden_size * c.hidden_size + 2 * c.hidden_size * c.ffn_size)
+            c.layer_passes * c.num_layers * (4 * c.hidden_size * c.hidden_size
+                                             + ffn_matrices * c.hidden_size * c.ffn_size)
             + c.vocab_size * c.hidden_size
         )
-        attn = (c.num_layers - len(c.stateful_layers)) * 2 * c.max_seq_len * c.hidden_size  # qk+av
+        attn = (c.layer_passes * (c.num_layers - len(c.stateful_layers))
+                * 2 * c.max_seq_len * c.hidden_size)  # qk+av
         return 6.0 * (n_params + attn)

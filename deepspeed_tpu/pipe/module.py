@@ -294,7 +294,7 @@ class PipelinedTransformer(Model):
             # the aux (load-balancing) losses of a stage's routed layers (zero for a dense
             # model) stream back through pipeline_apply's validity-gated side channel
             layers, moe = stage_params
-            h, _, aux, _ = tfm._layer_loop(
+            h, _, aux, _, _ = tfm._layer_loop(
                 cfg, layers, moe, h, None, positions=positions, attend=attend, wrap=wrap)
             return h, aux
 

@@ -3,7 +3,8 @@
     PYTHONPATH=<tree> python3 experiments/jaxpr_text.py <tree> > <tree>.json
 
 prints, for the twins WITH their operators of the four state / kind / latent
-configurations (falcon-h1, lfm2, K-EXAONE, kanana), a hash and the length of the jaxpr
+configurations (falcon-h1, lfm2, K-EXAONE, kanana) and, since PR 56, of the four
+others (bloom, OLMoE, qwen3-next, pythia), a hash and the length of the jaxpr
 TEXT of a decode step at per-row positions under a live-row mask, of two padded prefill
 buckets and of the gradient of the loss. Two trees that print the same lines trace the
 same programs, jaxpr text for jaxpr text. Nothing is compiled or run."""
@@ -20,7 +21,10 @@ import jax.numpy as jnp  # noqa: E402
 from deepspeed_tpu.models import transformer as tfm  # noqa: E402
 
 TWINS = {"falcon-h1-34b-L4": "rehearse_recurrent_program", "lfm2-24b-a2b-L9": "rehearse_conv_program",
-         "k-exaone-236b-a23b-L5": "rehearse_kinds_program", "kanana-2-30b-a3b-L7": "rehearse_program"}
+         "k-exaone-236b-a23b-L5": "rehearse_kinds_program", "kanana-2-30b-a3b-L7": "rehearse_program",
+         # PR 56: the four others, so that every configuration older than the tree is here
+         "bloom-1b7": "rehearse_program", "olmoe-1b-7b-L4": "rehearse_program",
+         "qwen3-next-80b-a3b-L8": "rehearse_delta_program", "pythia-1.4b": "rehearse_program"}
 SLOTS, SMAX, BUCKETS = 4, 256, (64, 256)
 
 

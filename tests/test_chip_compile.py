@@ -826,6 +826,95 @@ def test_delta_prefill_loops_over_chunks_and_over_no_row(rows, v5e, no_persisten
     assert _footprint(compiled) < 0.6 * HBM_BYTES
 
 
+def _ouro_worker(n, Smax, v5e, **fields):
+    """Ouro-2.6B's twelve layers at their published widths and the cell's passes
+    (``chipbench/configs/ouro-2.6b-L12.json``'s ``program``: 16 heads of 128, a gated
+    feed-forward of 5632, sandwich norms, the exit gate, the whole 49,152-row head, the
+    stack run four times)."""
+    import json
+    from deepspeed_tpu.models import transformer as tfm
+
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__), "chipbench", "configs",
+                           "ouro-2.6b-L12.json")) as f:
+        program = json.load(f)["program"]
+    cfg = tfm.TransformerConfig(dtype=jnp.bfloat16, **{**program, "max_seq_len": Smax, **fields})
+    return (cfg, *_bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0])))
+
+
+# a layer stack of Ouro's that no program may copy: wo, the three feed-forward matrices
+_OURO_STACKS = (r"bf16\[12,16,128,2048\]", r"bf16\[12,2048,5632\]", r"bf16\[12,5632,2048\]")
+_OURO_QKV = r"bf16\[12,2048,16,128\]"
+
+
+def _whole_copies(text, shape):
+    return re.findall(rf"^\s*%?[\w.-]+ = {shape}\S* copy\((\S+?)\)", text, re.M)
+
+
+def test_passes_decode_program_loops_over_the_passes_and_keeps_the_cache_in_place(
+        v5e, no_persistent_cache, as_tpu):
+    """``SlotWorker``'s decode step of the looped block at the cell's own size (twelve
+    layers run four times, 24 slots x 1,024): the cache tree is K/V of 48 (pass, layer)s,
+    [48, 24, 1024, 16, 128] twice (9.66 GB), the carry of BOTH loops, donated in and
+    aliased out, never copied. The passes are a LOOP: the program's text is that of the
+    one-pass program within a fifth (it would be fourfold unrolled), the Pallas decode
+    kernel stands in it ONCE (12 x 4 = 48 calls a step), inside two nested loops. No
+    feed-forward or output-projection stack is copied. The compiler does re-lay the
+    q / k / v stacks head-major (the layout its projection wants; the one-pass program
+    re-lays a layer's slice of them inside the loop, every layer): with the stacks
+    constants of TWO loops it hoists that out of both, ONCE a call (3 x 100 MB, from
+    the entry's own parameters), which is a quarter of what the slices would cost
+    over four passes; nothing is copied a pass. The footprint is what the cell's
+    ``why`` says: under three quarters of the chip."""
+    n, Smax = 24, 1024
+    texts = {}
+    for passes in (1, 4):
+        cfg, worker, params, cache, sds = _ouro_worker(n, Smax, v5e, layer_passes=passes,
+                                                       exit_gate=passes > 1)
+        compiled = _compile_decode(worker, params, cache, n, sds)
+        texts[passes] = compiled.as_text()
+    text = texts[4]
+    assert jax.tree.map(lambda x: x.shape, cache) == {"k": (48, n, Smax, 16, 128),
+                                                      "v": (48, n, Smax, 16, 128)}
+    assert len(text) < 1.2 * len(texts[1]), (len(text), len(texts[1]))
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 1
+    loops = re.findall(r"^\s*%?while[\w.-]* = ", text, re.M)
+    assert len(loops) == len(re.findall(r"^\s*%?while[\w.-]* = ", texts[1], re.M)) + 1
+    assert not _whole_copies(text, rf"bf16\[48,{n},{Smax},16,128\]")
+    for stack in _OURO_STACKS:
+        assert not _whole_copies(text, stack), stack
+    relaid = _whole_copies(text, _OURO_QKV)  # in the entry computation: outside both loops
+    assert len(relaid) <= 3 and relaid == _whole_copies(text[text.index("\nENTRY "):], _OURO_QKV)
+    ma = compiled.memory_analysis()
+    kv_bytes = 2 * 48 * n * Smax * 16 * 128 * 2
+    assert ma.alias_size_in_bytes >= kv_bytes  # donated in, aliased out
+    assert ma.temp_size_in_bytes < 0.35e9, ma.temp_size_in_bytes  # 0.20 GB: the re-laid stacks
+    assert _footprint(compiled) < 0.75 * HBM_BYTES  # 11.5 GB
+
+
+def test_passes_prefill_attends_densely_in_every_pass_and_copies_no_stack(
+        v5e, no_persistent_cache, as_tpu):
+    """The cell's longest prefill bucket (512 rows) compiled for the chip: dense
+    attention (16 x 512 x 512 float32 scores a layer: no kernel), the block attending to
+    itself in every pass, its local cache [48, 1, 512, 16, 128] written whole into the
+    slot (re-laid ONCE a prefill on its way there: twice 100 MB, k and v), the slot cache
+    never copied, no feed-forward or output-projection stack copied, the q / k / v stacks
+    re-laid once a call as in the decode program; temporaries 0.5 GB."""
+    n, Smax, rows = 24, 1024, 512
+    cfg, worker, params, cache, sds = _ouro_worker(n, Smax, v5e)
+    compiled = _compile_prefill(worker, params, cache, rows, sds)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert not _whole_copies(text, rf"bf16\[48,{n},{Smax},16,128\]")
+    assert len(_whole_copies(text, rf"bf16\[48,1,{rows},16,128\]")) <= 2
+    for stack in _OURO_STACKS:
+        assert not _whole_copies(text, stack), stack
+    relaid = _whole_copies(text, _OURO_QKV)  # in the entry computation: outside both loops
+    assert len(relaid) <= 3 and relaid == _whole_copies(text[text.index("\nENTRY "):], _OURO_QKV)
+    assert not re.search(rf"\[(?:1,)?16,{rows},{Smax}\]", text)  # scores against the SLOT's length
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
+    assert _footprint(compiled) < 0.78 * HBM_BYTES  # 11.8 GB
+
+
 def test_latent_prefill_attends_through_the_flash_kernel(v5e, no_persistent_cache, as_tpu):
     """The 1024-row prefill of the same block at 32 heads: its dense scores would
     be 128 MiB, so the expanded form goes through the flash forward kernel at q/k

@@ -25,7 +25,11 @@ FLOAT32_LEAVES = {"ln1_scale", "ln1_bias", "ln2_scale", "ln2_bias", "lnf_scale",
                   "emb_ln_scale", "emb_ln_bias", "q_norm_scale", "k_norm_scale",
                   "lm_head_bias", "gate",
                   # latent attention's norm over the latent; a router's selection bias (PR 31)
-                  "kv_norm_scale", "bias"}
+                  "kv_norm_scale", "bias",
+                  # the sandwich's branch norms (PR 56)
+                  "ln1_post_scale", "ln2_post_scale"}
+# ... and whole groups, by the key they hang under: the exit gate's [d, 1] map and its bias (PR 56)
+FLOAT32_GROUPS = {"exit_gate"}
 PROMPT, STEPS = 24, 3
 
 
@@ -81,7 +85,8 @@ def test_held_leaves_are_in_the_dtype_the_forward_pass_reads(config):
     held = tfm.hold_for_compute(cfg, params)
     assert jax.tree.structure(held) == jax.tree.structure(params)
     for path, leaf in jax.tree_util.tree_leaves_with_path(held):
-        want = "float32" if path[-1].key in FLOAT32_LEAVES else "bfloat16"
+        in_float32 = path[-1].key in FLOAT32_LEAVES or path[0].key in FLOAT32_GROUPS
+        want = "float32" if in_float32 else "bfloat16"
         assert leaf.dtype.name == want, jax.tree_util.keystr(path)
     assert {"bfloat16", "float32"} == set(_dtypes(held).values())
 

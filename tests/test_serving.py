@@ -522,6 +522,16 @@ _AGREE_CASES["sequential-delta_in_3_of_4_gated_attention_gated_shared-rms"] = di
     layer_operators=["delta", "delta", "delta", "attn"], conv_kernel=4, delta_key_heads=2,
     delta_value_heads=4, delta_head_dim=8)
 
+# a second norm on each sublayer's branch (PR 56), alone and with the stack run three times
+# over the same weights: the cache keeps K/V per (pass, layer), 12 cache layers for 4 of weights
+_AGREE_CASES["sequential-dense-sandwich_rms"] = dict(
+    norm_style="sandwich", norm_kind="rms", use_bias=False, activation="swiglu",
+    tie_embeddings=False)
+_AGREE_CASES["sequential-dense-sandwich_layer_norms_with_biases"] = dict(norm_style="sandwich")
+_AGREE_CASES["sequential-dense-sandwich_rms-3_passes-exit_gate"] = dict(
+    _AGREE_CASES["sequential-dense-sandwich_rms"], layer_passes=3, exit_gate=True)
+_AGREE_CASES["parallel-dense-pre_layer-2_passes"] = dict(parallel_residual=True, layer_passes=2)
+
 
 
 def _agree_model(fields):
