@@ -49,10 +49,34 @@ SPANS = ("serve.step", "serve.decode", "serve.prefill", "stamp", "generator", "i
 # the least a traced run's host window may be, as a share of the window: the
 # host-clock per-layer metrics and the run's attempted / failed are read there
 HOST_WINDOW_MIN_SHARE = 0.2
+# a cell's traced length leaves that share at this many times the stop rate its file states:
+# operations a second rise with steps a second, so a program twice as fast stops twice as long
+# (the farthest a decode program stands from its floor is a factor of 2.3; PERF.md section 6, PR 57)
+STOP_RATE_HEADROOM = 2.0
 
 
 class HostWindowTooShort(RuntimeError):
     """stop_trace ate the window a traced run reads its host-clock metrics from."""
+
+
+def trace_block(trace: dict, profiler: dict) -> dict:
+    """A cell's ``trace`` block as a run takes it, with what the cell's ``profiler``
+    block states: ``stop_rate`` (seconds of ``stop_trace`` a profiled second, as read
+    on the chip) and, where it has one, ``trace_seconds`` in place of ``trace.seconds``
+    (a block that a test outside the benchmark holds to its letter stays as it is)."""
+    out = {**trace, "stop_rate": profiler.get("stop_rate")}
+    if "trace_seconds" in profiler:
+        out["seconds"] = profiler["trace_seconds"]
+    return out
+
+
+def host_window_left(trace: dict, lead_in_s: float, window_s: float, before_s: float,
+                     stop_rate: float) -> float:
+    """Seconds of host window a traced run keeps where ``stop_trace`` takes
+    ``stop_rate`` seconds for each second profiled: the profiler starts ``before_s``
+    ahead of the window (or with the loop, in a shorter lead-in)."""
+    profiled = min(lead_in_s, before_s) + trace["seconds"]
+    return window_s - trace["seconds"] - stop_rate * profiled - trace["settle_s"]
 
 
 def _bucket(srv, n: int) -> int:
@@ -215,15 +239,18 @@ def _loop(run, srv, Request, traffic, samples) -> dict:
     # With --trace 1 the profiler starts run.TRACE_START_BEFORE_S before the
     # window, inside the lead-in, which no metric reads (in the loop's first
     # iteration where the lead-in is no longer than that): stop_trace stalls the
-    # loop in proportion to what was profiled (0.7-2.2 s a profiled second, by
-    # the cell), so it pays for that second and the traced ones, not for the
-    # lead-in. The traced window is the first trace.seconds of the measured
-    # window, and the host-clock per-layer metrics are read from what arrives
-    # once stop_trace and the backlog it caused are over.
-    trace = run.sized("trace")
+    # loop in proportion to what was profiled (0.7-3.6 s a profiled second, by
+    # the cell: its file states the rate, ``profiler.stop_rate``), so it pays
+    # for that second and the traced ones, not for the lead-in. The traced
+    # window is the first trace.seconds of the measured window, and the
+    # host-clock per-layer metrics are read from what arrives once stop_trace
+    # and the backlog it caused are over.
+    trace = trace_block(run.sized("trace"), run.sized("profiler"))
     tracing = "waiting" if run.trace else None
     resume_at = lo
-    profiler = {"start_at": None, "start_s": None, "stop_s": None}  # loop clock, seconds
+    # loop clock, seconds; stop_rate: stop_s a profiled second, beside the cell file's
+    profiler = {"start_at": None, "start_s": None, "stop_s": None, "stop_rate": None,
+                "stop_rate_stated": trace["stop_rate"]}
 
     stamps: dict[int, list] = {}
     steps, first_seen, submitted = [], {}, {}
@@ -261,6 +288,7 @@ def _loop(run, srv, Request, traffic, samples) -> dict:
             tracing, traced[1] = None, now
             stopped = clock()
             profiler["stop_s"] = stopped - now
+            profiler["stop_rate"] = profiler["stop_s"] / (now - profiler["start_at"])
             resume_at = stopped + trace["settle_s"]
         if not closed and srv.n_active == 0 and srv.n_prefilling == 0 and nxt < total:
             wait = reqs[nxt]["arrival_time"] - now  # serve()'s own sleep rule
@@ -308,10 +336,12 @@ def _loop(run, srv, Request, traffic, samples) -> dict:
         if hi - resume_at < HOST_WINDOW_MIN_SHARE * (hi - lo) or not arrivals:
             stop_s = profiler["stop_s"]  # None: the loop ended before the traced window did
             raise HostWindowTooShort(
-                f"stop_trace took trace_stop_s={stop_s and round(stop_s, 1)} s and left the "
+                f"stop_trace took trace_stop_s={stop_s and round(stop_s, 1)} s (rate "
+                f"{profiler['stop_rate'] and round(profiler['stop_rate'], 2)} a profiled second, "
+                f"the cell file states {trace['stop_rate']}) and left the "
                 f"host window [resume_at={resume_at:.1f}, hi={hi:.1f}) with {arrivals} "
                 f"arrival(s): under {HOST_WINDOW_MIN_SHARE:.0%} of the window's "
-                f"{hi - lo:.1f} s, or empty. Shorten the cell's trace.seconds")
+                f"{hi - lo:.1f} s, or empty. Shorten the cell's traced seconds")
 
     records = []
     for r in reqs:

@@ -2,31 +2,24 @@
 recurrent state per sequence beside grouped-query K/V: the least time the chip
 needs to move what one decode step must (``ssm_cost.decode_min_bytes``: the
 matmul weights once, the state of the rows the step advanced read AND written,
-the live K/V at ``num_kv_heads`` heads) over the median ``.../decode`` span. The
-state's bytes and the live tokens are the medians of the spans' own
-``state_bytes`` and ``cached_tokens``; a program whose spans lack either (one
-without the mixer) gives nothing."""
-import numpy as np
-
+the live K/V at ``num_kv_heads`` heads) over the device's own time a run of the
+decode program (``decode_floor.py``). The state's bytes and the live tokens are
+the medians of the traced calls' own ``state_bytes`` and ``cached_tokens``; a
+program whose spans lack either (one without the mixer) gives nothing."""
 from .. import ssm_cost
-from . import span_ring as R
+from . import decode_floor as F
 
 NAME, UNIT, LAYER = "ssm_decode_hbm_floor_pct", "%", "serving device programs"
+NEEDS = ("cached_tokens", "state_bytes")
 
 
 def read(ctx):
-    if not ctx["serve"] or "ssm_state_size" not in ctx["program"]:
+    if "ssm_state_size" not in ctx["program"]:
         return None
-    calls = [call for call, _, _ in R.calls(R.started_in(R.serve_window(ctx)), "decode")]
-    calls = [c for c in calls if "state_bytes" in c.attrs and "cached_tokens" in c.attrs]
+    calls = F.calls(ctx, NEEDS)
     if not calls:
         return None
-    state = float(np.median([c.attrs["state_bytes"] for c in calls]))
-    cached = float(np.median([c.attrs["cached_tokens"] for c in calls]))
+    cached, state = (F.median(calls, key) for key in NEEDS)
     need = ssm_cost.decode_min_bytes(ctx["program"], cached, state)
-    floor_ms = 1e3 * need / ctx["peak"]["hbm_bytes_per_s"]
-    step_ms = R.median_ms(calls)
-    ctx["run"].note(event="roofline", program="decode", floor_ms=floor_ms, step_ms=step_ms,
-                    bytes=need, state_bytes=state, cached_tokens=cached,
-                    state_rows=float(np.median([c.attrs.get("state_rows", 0) for c in calls])))
-    return 100.0 * floor_ms / step_ms
+    return F.share(ctx, calls, need, live_tokens=cached, state_bytes=state,
+                   state_rows=F.median(calls, "state_rows"))

@@ -130,7 +130,7 @@ class Run:
         return jax.profiler.TraceAnnotation(name)
 
     # A serving driver starts the profiler this long before its window, not
-    # before its loop: stop_trace stalls the loop for 0.7-2.2 s a PROFILED second
+    # before its loop: stop_trace stalls the loop for 0.7-3.6 s a PROFILED second
     # (PERF.md section 6, PR 44), so a profile of the whole lead-in left a traced
     # run no window to read its host-clock metrics from. The start-up itself
     # takes 0.04-0.05 s on the chip: a second leaves some 30 to 100 steady steps

@@ -12,8 +12,10 @@ program on two devices' planes) against the reduction pinned when it was
 recorded; ``flops.py`` and the references' counts against hand counts for
 both configurations; that each traffic generator gives identical requests for
 one seed and different ones for another; where a traced serving run starts and
-stops the profiler and the host window that leaves (``test_trace_window.py``,
-on a virtual clock); that ``BENCHMARK.json``, the
+stops the profiler, the host window that leaves and every cell file against its
+own stop rate (``test_trace_window.py``, on a virtual clock), and the readers that
+are shares of the device's time on a hand-made trace (``test_device_shares.py``,
+through the same table); that ``BENCHMARK.json``, the
 directories and the readers agree (every metric has its reader, every cell its
 files, and ``run.py`` names none of them); and the references
 (``check_references``, run as part of ``check_files``): every configuration

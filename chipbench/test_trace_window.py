@@ -8,7 +8,10 @@ them through ``python3 -m chipbench.selftest``. The stub server takes 20 ms of
 the virtual clock a step; the stub profiler takes ``start_s`` to start and
 ``stop_rate`` seconds to stop for every second it profiled, which is how
 ``jax.profiler.stop_trace`` costs on the chip (README.md, "The timeline of a
-traced serving run").
+traced serving run"). The rate is each cell's own: its file states it
+(``profiler.stop_rate``), and the cell's traced length must leave the least host
+window at ``serve.STOP_RATE_HEADROOM`` x that rate, so that a program twice as fast
+still prints a result.
 """
 
 from __future__ import annotations
@@ -20,15 +23,15 @@ import os
 from types import SimpleNamespace
 from unittest import mock
 
+from . import test_device_shares
 from .drivers import serve
 from .run import Run
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BEFORE = Run.TRACE_START_BEFORE_S
 STEP_S = 0.02
-# over the slowest stop_trace read on the chip, seconds a profiled second: bloom-1b7.serve-doc's
-# 9.05 s for 4.05 is 2.23 (PERF.md section 6, PR 44; the six cells read 0.7-2.2, by their operations a second)
-WORST_STOP_RATE = 2.5
+with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as _f:
+    WINDOW_S = float(json.load(_f)["run_seconds"])  # 51: the window ``drive`` runs too
 
 
 class Clock:
@@ -47,10 +50,13 @@ class Clock:
 class StubRun:
     TRACE_START_BEFORE_S = BEFORE
 
-    def __init__(self, clock, *, trace, seconds, settle_s=5.0, start_s=0.05, stop_rate=1.2):
+    def __init__(self, clock, *, trace, seconds, settle_s=5.0, start_s=0.05, stop_rate=1.2,
+                 trace_seconds=None):
         self.clock, self.trace = clock, trace
         self.blocks = {"trace": {"seconds": seconds, "settle_s": settle_s},
-                       "traffic": {"grace_s": 5.0}}
+                       "profiler": {"stop_rate": stop_rate}, "traffic": {"grace_s": 5.0}}
+        if trace_seconds is not None:
+            self.blocks["profiler"]["trace_seconds"] = trace_seconds
         self.start_s, self.stop_rate = start_s, stop_rate
         self.events = []  # (name, clock at entry, clock at exit)
 
@@ -125,7 +131,7 @@ def drive(*, lo, seconds, trace=True, steps_per_request=25, loop="closed", **pro
     every request arrives at 0); returns what ``_loop`` returned and the stub
     profiler's calls as (entry, exit) on the loop's clock."""
     clock = Clock()
-    hi = lo + 51.0
+    hi = lo + WINDOW_S
     run = StubRun(clock, trace=trace, seconds=seconds, **profiler)
     requests = [{"uid": i, "prompt": [0], "max_new_tokens": steps_per_request,
                  "temperature": 0.0, "top_p": 1.0, "arrival_time": 0.0} for i in range(4000)]
@@ -178,6 +184,9 @@ def case_host_window_runs_from_the_stop_and_the_settle_to_hi():
     profiled = at["trace_stop"][0] - at["trace_start"][0]
     assert profiled <= BEFORE + 6.0 + 3 * STEP_S  # not the 36 s a start before the clock profiles
     assert abs(out["profiler"]["stop_s"] - 1.5 * profiled) < 1e-9
+    # the realised rate beside the one the cell file states (the stub's are the same number)
+    assert abs(out["profiler"]["stop_rate"] - 1.5) < 1e-9
+    assert out["profiler"]["stop_rate_stated"] == 1.5
     # ISSUE 44's arithmetic: 81 - (36 + 1.5 x (BEFORE + 6) + 5), 23 s at its BEFORE of 5
     assert hi - resume_at >= 40.0 - 1.5 * (BEFORE + 6.0) - 0.2 >= 23.0, resume_at
     assert sum(resume_at <= r["arrival"] < hi for r in out["records"]) > 100
@@ -215,30 +224,97 @@ def case_loop_that_ends_before_the_window_raises():
 def case_untraced_run_touches_no_profiler():
     out, at = drive(lo=30.0, seconds=6.0, trace=False)
     assert at == {} and out["window"] == (30.0, 81.0) and out["traced"] == (None, None)
-    assert out["profiler"] == {"start_at": None, "start_s": None, "stop_s": None}
+    assert out["profiler"] == {"start_at": None, "start_s": None, "stop_s": None,
+                               "stop_rate": None, "stop_rate_stated": 1.2}
 
 
-def case_every_cell_file_leaves_a_host_window():
-    """``trace.seconds`` is bounded by the host window it leaves: at the slowest
-    stop_trace read in any cell, every serving cell keeps the least share."""
-    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
-        window = float(json.load(f)["run_seconds"])
-    seen = 0
+def _cell_files() -> dict:
+    """name -> cell of every serving cell file (the train driver profiles after its window)."""
+    cells = {}
     for path in sorted(glob.glob(os.path.join(HERE, "workloads", "*.json"))):
         with open(path) as f:
             cell = json.load(f)
-        if "lead_in_s" not in cell.get("traffic", {}):
-            continue  # the train driver profiles after its window
-        seen += 1
-        lead, trace = float(cell["traffic"]["lead_in_s"]), cell["trace"]
-        profiled = min(lead, BEFORE) + trace["seconds"]
-        left = window - trace["seconds"] - WORST_STOP_RATE * profiled - trace["settle_s"]
-        assert left >= serve.HOST_WINDOW_MIN_SHARE * window, (cell["name"], left)
-    assert seen >= 6
+        if "lead_in_s" in cell.get("traffic", {}):
+            cells[cell["name"]] = cell
+    return cells
+
+
+def _hold_to_its_own_rate(cell: dict) -> None:
+    """The rule of ``trace.seconds``: at ``STOP_RATE_HEADROOM`` x the stop rate the cell's
+    file states (a program twice as fast runs twice the operations a second, and
+    ``stop_trace`` costs by the operation), the traced run keeps the least host window."""
+    trace = serve.trace_block(cell["trace"], cell.get("profiler", {}))
+    assert trace["stop_rate"], f"{cell['name']}: the cell file states no profiler.stop_rate"
+    left = serve.host_window_left(trace, float(cell["traffic"]["lead_in_s"]), WINDOW_S, BEFORE,
+                                  serve.STOP_RATE_HEADROOM * trace["stop_rate"])
+    assert left >= serve.HOST_WINDOW_MIN_SHARE * WINDOW_S, (
+        f"{cell['name']}: {trace['seconds']} s traced at {serve.STOP_RATE_HEADROOM} x "
+        f"{trace['stop_rate']} leaves {left:.1f} s of host window, under "
+        f"{serve.HOST_WINDOW_MIN_SHARE * WINDOW_S:.1f}")
+
+
+def _fails_by_name(cell: dict, *words) -> None:
+    try:
+        _hold_to_its_own_rate(cell)
+    except AssertionError as e:
+        assert cell["name"] in str(e) and all(w in str(e) for w in words), e
+        return
+    raise AssertionError(f"{cell['name']} passed the rule it should fail")
+
+
+def case_every_cell_file_leaves_a_host_window():
+    """``trace.seconds`` is bounded by the host window it leaves at twice the cell's OWN
+    stop rate, which every serving cell file states."""
+    cells = _cell_files()
+    for cell in cells.values():
+        _hold_to_its_own_rate(cell)
+    assert len(cells) >= 8
+
+
+def case_six_seconds_at_ouros_rate_fails_by_name():
+    """The parent's file of the newest cell, with the rate read in it (3.6): 51 - 6 - 2 x 3.6
+    x 7 - 5 is under nought. Today's program kept 14.6 s; one 17% faster kept under 10.2."""
+    parent = {**_cell_files()["ouro-2.6b-L12.serve-reason"], "profiler": {"stop_rate": 3.6}}
+    assert parent["trace"]["seconds"] == 6.0
+    _fails_by_name(parent, "6.0 s traced at 2.0 x 3.6", "of host window")
+    _hold_to_its_own_rate({**parent, "profiler": {"stop_rate": 3.6, "trace_seconds": 3.0}})
+    _fails_by_name({**parent, "profiler": {"stop_rate": 3.6, "trace_seconds": 4.0}}, "4.0 s traced")
+
+
+def case_cell_file_without_a_stop_rate_fails_by_name():
+    for cell in _cell_files().values():
+        _fails_by_name({k: v for k, v in cell.items() if k != "profiler"}, "profiler.stop_rate")
+        _fails_by_name({**cell, "profiler": {"trace_seconds": 1.0}}, "profiler.stop_rate")
+
+
+def case_program_twice_as_fast_keeps_its_host_window():
+    """What the rule buys, on the virtual clock: every shipped cell's traced length under a
+    ``stop_trace`` at TWICE the cell's stated rate prints a result; the parent's 6 s at
+    twice Ouro's 3.6 raises."""
+    for cell in _cell_files().values():
+        trace = serve.trace_block(cell["trace"], cell["profiler"])
+        lead = float(cell["traffic"]["lead_in_s"])
+        out, _ = drive(lo=lead, seconds=trace["seconds"], settle_s=trace["settle_s"],
+                       stop_rate=serve.STOP_RATE_HEADROOM * trace["stop_rate"])
+        resume_at, hi = out["window"]
+        assert hi - resume_at >= serve.HOST_WINDOW_MIN_SHARE * WINDOW_S, cell["name"]
+    assert "trace_stop_s=" in _raises(lo=25.0, seconds=6.0, stop_rate=2 * 3.6)
+
+
+def case_trace_seconds_of_the_profiler_block_stands_for_the_trace_blocks():
+    """``profiler.trace_seconds`` replaces ``trace.seconds`` in the loop itself."""
+    out, _ = drive(lo=25.0, seconds=6.0, stop_rate=3.6, trace_seconds=3.0)
+    t0, t1 = out["traced"]
+    assert 25.0 <= t0 < 25.0 + 2 * STEP_S and 28.0 <= t1 < 28.0 + 2 * STEP_S, (t0, t1)
+    # 51 - 3 - 3.6 x 4 - 5: the 28.6 s ISSUE 57 reckons for today's program
+    assert 28.4 < out["window"][1] - out["window"][0] < 28.7, out["window"]
 
 
 CASES = {name[len("case_"):]: fn for name, fn in sorted(globals().items())
          if name.startswith("case_")}
+# the cases of the readers that are shares of the device's time ride along: the repository's
+# tests and selftest.py run THIS table, and a benchmark PR adds no file under tests/
+CASES.update({f"device_shares.{name}": fn for name, fn in test_device_shares.CASES.items()})
 
 
 def pytest_generate_tests(metafunc):
