@@ -433,6 +433,9 @@ class SlotWorker:
             self.pass_attrs = {"layer_passes": self.cfg.layer_passes,
                                "cache_layers": kept["tokens"]}
             telemetry.gauge("serving/cache_layers").set(kept["tokens"])
+        # the cached positions a block of the Pallas decode kernel's walk holds (None:
+        # a step does not attend through it): what ``kv_rows_fetched`` counts in
+        self.kv_block = tfm.decode_kernel_block(self.cfg, self.Smax, tfm.cache_dtype(self._cache))
         # where the programs read a routed layer's expert banks from ("in_place" /
         # "sliced"; None for a model without dropless routing): the rule they trace by
         self.expert_bank = tfm.expert_bank_form(self.cfg, self.params.get("moe"), self.mesh)
@@ -959,12 +962,16 @@ class SlotWorker:
         # ``sampler``: the form its sampler takes on these rows;
         # ``state_rows`` / ``state_bytes`` (a state-space mixer only): the active
         # rows whose per-sequence state the step advanced, and the bytes of it
-        # the step had to read AND write
+        # the step had to read AND write; ``kv_rows_fetched`` (a step through the
+        # Pallas decode kernel only): the positions of ONE cache layer the
+        # kernel's walk fetched for the live rows, whole blocks of ``kv_block``
         n_active = int(np.count_nonzero(active))
         live_pos = np.asarray(pos)[np.asarray(active, bool)]
         attrs = dict(n_active=n_active, cached_tokens=int(np.sum(live_pos + 1)),
                      attn=tfm.cache_step_form(self.cfg), **self._state_attrs(n_active),
                      **self._ring_attrs(live_pos), **self.pass_attrs)
+        if self.kv_block:
+            attrs["kv_rows_fetched"] = tfm.kv_rows_fetched(live_pos, self.kv_block)
         # host arrays straight into the jitted call (pjit batches the uploads;
         # ``h2d`` counts them: seven here), the carried key between them as the
         # one device operand; dtypes are pinned by the engine's per-slot state

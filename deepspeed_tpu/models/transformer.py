@@ -3101,6 +3101,27 @@ def cache_step_form(cfg: TransformerConfig) -> str:
     return "dense" if not n_window else "ring" if n_window == cfg.num_layers else "dense+ring"
 
 
+def decode_kernel_block(cfg: TransformerConfig, smax: int, kv_dtype=None):
+    """Cached positions a block of the Pallas decode kernel's walk holds, over a
+    cache ``smax`` long held in ``kv_dtype`` (the model's own where None): the
+    kernel's one rule, ``ops/pallas/decode_attention.block_rows``. None where a
+    one-token step does not attend through the kernel (``decode_attn: "xla"``;
+    alibi, whose bias stays unfused)."""
+    if cfg.decode_attn != "kernel" or cfg.pos_emb == "alibi":
+        return None
+    from ..ops.pallas.decode_attention import block_rows
+
+    return block_rows(smax, cfg.kv_heads * cfg.head_dim
+                      * jnp.dtype(kv_dtype or cfg.dtype).itemsize)
+
+
+def kv_rows_fetched(live_pos, block: int) -> int:
+    """Cache positions of ONE layer the decode kernel's walk fetches for rows whose
+    newest positions are ``live_pos`` (a host array): whole blocks of ``block``
+    positions (``decode_kernel_block``), ``pos // block + 1`` a row."""
+    return int(np.sum(np.asarray(live_pos) // block + 1)) * block
+
+
 def cache_block_form(cfg: TransformerConfig, bucket: int) -> str:
     """How a prefill block ``bucket`` long that fills its own cache attends, for
     the span's ``attn``: ``cache_attention_form``'s ``"flash"`` / ``"dense"``, with
@@ -3188,10 +3209,10 @@ def expert_bank_form(cfg: TransformerConfig, moe, mesh=None):
 
 
 def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, write_pos=None,
-                     live=None):
+                     live=None, kv_dtype=None):
     """-> (positions [B, T], the block's ``attend``) for T new tokens entering a
-    stacked [L, B, Smax, H, Dh] cache tree at ``pos`` (scalar, or [B] with
-    ``write_pos``: see ``apply_with_cache``).
+    stacked [L, B, Smax, H, Dh] cache tree (held in ``kv_dtype``; the model's own
+    where None) at ``pos`` (scalar, or [B] with ``write_pos``: see ``apply_with_cache``).
 
     ``attend`` writes the new rows into layer ``l`` of the stacks and attends to
     that layer where it lies. Latent attention has its two forms here, chosen
@@ -3279,9 +3300,14 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
     # (ops/pallas/decode_attention.py — the reference's softmax_context,
     # pt_binding.cpp:1237): it reads only cache blocks up to ``pos`` instead
     # of the dense O(Smax) recompute. Alibi keeps the XLA path (bias unfused).
-    use_decode_kernel = T == 1 and cfg.decode_attn == "kernel" and cfg.pos_emb != "alibi"
+    # Its work list (the live blocks in row order) is the same for every cache
+    # layer of a step, so it is built here, once, and not in the layer loop.
+    kernel_block = decode_kernel_block(cfg, Smax, kv_dtype) if T == 1 else None
+    use_decode_kernel = kernel_block is not None
     if use_decode_kernel:
-        from ..ops.pallas.decode_attention import decode_attention
+        from ..ops.pallas.decode_attention import decode_attention, decode_walk
+
+        walk = decode_walk(pos, B, Smax, kernel_block)
 
     if vector_pos:
         rows = jnp.arange(B)[:, None]
@@ -3365,7 +3391,7 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
         k_stack, v_stack = write(stacks["k"], l, as_row(k)), write(stacks["v"], l, as_row(v))
         stacks = {**stacks, "k": k_stack, "v": v_stack}  # rings and a mixer's state ride along
         if use_decode_kernel:
-            attn = decode_attention(q[:, 0], k_stack, v_stack, pos, layer=l)[:, None]
+            attn = decode_attention(q[:, 0], k_stack, v_stack, pos, layer=l, walk=walk)[:, None]
             return attn, stacks
         if fills_cache:
             k_l, v_l = k.astype(k_stack.dtype), v.astype(v_stack.dtype)
@@ -3450,7 +3476,8 @@ def apply_with_cache(
             "`live` (the rows that are the sequence's own), or it runs on over the padding; so "
             "does a window layer's ring, or it keeps the padding's rows")
     params = _stream_top_level(cfg, params)
-    positions, attend = _cache_attention(cfg, B, T, cache_len(cache), pos, write_pos, live)
+    positions, attend = _cache_attention(cfg, B, T, cache_len(cache), pos, write_pos, live,
+                                         cache_dtype(cache))
     x, _ = embed(cfg, params, tokens, positions)
     after_pass = _after_pass(cfg, params, return_exit)
     x, cache, _, chosen, gates = _layer_loop(

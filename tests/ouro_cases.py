@@ -68,8 +68,8 @@ PLANTED = {  # one line of the program wrong: (function, the line, what stands i
         "x = jnp.where(r == cfg.layer_passes - 1, x, carry[0])"),
     "a branch norm dropped": ("_block", 'post(f, "ln2_post")', "f"),
     "a decode step reads the pass before": (
-        "_cache_attention", "decode_attention(q[:, 0], k_stack, v_stack, pos, layer=l)",
-        "decode_attention(q[:, 0], k_stack, v_stack, pos, "
+        "_cache_attention", "decode_attention(q[:, 0], k_stack, v_stack, pos, layer=l, walk=walk)",
+        "decode_attention(q[:, 0], k_stack, v_stack, pos, walk=walk, "
         "layer=jnp.where(l >= cfg.num_layers, l - cfg.num_layers, l))"),
     # a fault of PRECISION, for the chip (float32 compute makes it no fault at all): the
     # norm between the passes in the compute dtype

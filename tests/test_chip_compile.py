@@ -152,6 +152,40 @@ def test_kernel_compiles_for_v5e(build, v5e, no_persistent_cache):
 ALL_FLASH = ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")
 
 
+@pytest.mark.parametrize("cell,stack,alibi", [
+    ("ouro-2.6b-L12.serve-reason", (48, 24, 1024, 16, 128), False),
+    ("olmoe-1b-7b-L4.serve-doc", (4, 16, 2048, 16, 128), False),
+    # no cell's program: the model routes alibi round the kernel (ROADMAP S2(e)); the kernel takes it
+    ("bloom-1b7.serve-doc, alibi in the kernel", (24, 8, 2048, 16, 128), True)])
+def test_decode_kernel_compiles_at_the_cells_shapes(cell, stack, alibi, v5e, no_persistent_cache):
+    """The decode kernel over a traced layer of the two cells' whole cache stacks, the
+    work list handed in as a model hands it: a block by the rule (128 positions: 1 MiB
+    of K and V), its two operands double-buffered well under the kernel's 16 MiB of
+    VMEM (past it the compiler refuses, which interpret mode cannot show), the grid's
+    length the list's own (a dynamic bound), and no copy of a stack beside the call."""
+    from deepspeed_tpu.models.transformer import alibi_slopes
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+
+    L, n, Smax, H, Dh = stack
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=SingleDeviceSharding(v5e[0]))
+    block = da.block_rows(Smax, H * Dh * 2)
+    assert block == 128 and 2 * 2 * block * H * Dh * 2 < 16 * 2 ** 20 / 4
+
+    def step(q, k, v, pos, layer):
+        walk = da.decode_walk(pos, n, Smax, block)
+        return da.decode_attention(q, k, v, pos, layer=layer, walk=walk, interpret=False,
+                                   alibi_slopes=alibi_slopes(H) if alibi else None)
+
+    cache = sds(stack, jnp.bfloat16)
+    compiled = jax.jit(step).lower(sds((n, H, Dh), jnp.bfloat16), cache, cache,
+                                   sds((n,), jnp.int32), sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 1
+    assert not _whole_copies(text, re.escape(f"bf16[{L},{n},{Smax},{H},{Dh}]"))
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 @pytest.mark.parametrize("bh,rows,widths,dtype,alibi,kernels,block_k", [
     (128, 2048, (128, 128), jnp.bfloat16, False, ALL_FLASH, 2048),
     (32, 8192, (192, 128), jnp.bfloat16, False, ("flash_fwd",), 2048),
@@ -253,11 +287,35 @@ def _bare_slot_worker(cfg, n, Smax, one_chip):
     return worker, params, cache, sds
 
 
-def _compile_decode(worker, params, cache, n, sds):
+def _decode_operands(params, cache, n, sds):
     vec = lambda dtype: sds((n,), dtype)
-    return worker._build_decode().lower(
-        params, cache, vec(jnp.int32), vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
-        sds((2,), jnp.uint32), vec(jnp.float32), vec(jnp.int32), vec(jnp.float32)).compile()
+    return (params, cache, vec(jnp.int32), vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+            sds((2,), jnp.uint32), vec(jnp.float32), vec(jnp.int32), vec(jnp.float32))
+
+
+def _compile_decode(worker, params, cache, n, sds):
+    return worker._build_decode().lower(*_decode_operands(params, cache, n, sds)).compile()
+
+
+def _loop_depths(jaxpr, primitive, depth=0):
+    """How deep in loops (``scan`` / ``while``) each ``primitive`` equation of a jaxpr lies."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            found.append(depth)
+        inner = depth + (eqn.primitive.name in ("scan", "while"))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _loop_depths(sub, primitive, inner)
+    return found
+
+
+def _walk_built_once(worker, params, cache, n, sds, loops):
+    """The decode kernel's work list (a ``cumsum`` over the rows' blocks; the sampler's
+    nucleus has the program's other one) is built outside the layer loop(s), once a
+    step, and the kernel stands ``loops`` loops deep."""
+    jaxpr = worker._build_decode().trace(*_decode_operands(params, cache, n, sds)).jaxpr.jaxpr
+    assert set(_loop_depths(jaxpr, "cumsum")) == {0}
+    assert _loop_depths(jaxpr, "pallas_call") == [loops]
 
 
 def _compile_prefill(worker, params, cache, bucket, sds):
@@ -292,10 +350,11 @@ def _family_cfg(family, L, Smax, H=16, Dh=128):
 # the decode program keeps the slot cache in place (tier 1)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("family", ["bloom_dense_alibi", "pythia_pallas_kernel"])
+@pytest.mark.parametrize("family", ["bloom_dense_alibi", "pythia_pallas_kernel", "olmoe_dropless"])
 def test_decode_program_keeps_the_slot_cache_in_place(family, v5e, no_persistent_cache, as_tpu):
     """``SlotWorker``'s decode step at the widths of the benchmark's two dense
-    configurations (4 layers, 8 slots x 512, vocabulary 1024): the stacked
+    configurations and of its first routed one, the other cell whose steps run the
+    Pallas decode kernel (4 layers, 8 slots x 512, vocabulary 1024): the stacked
     cache is the layer loop's carry, donated in and aliased out. Put it back
     into the scan's xs/ys and the compiler slices a layer out and restacks it
     in every iteration and copies the whole cache twice to reconcile the
@@ -306,7 +365,9 @@ def test_decode_program_keeps_the_slot_cache_in_place(family, v5e, no_persistent
     compiled = _compile_decode(worker, params, cache, n, sds)
 
     text = compiled.as_text()
-    assert ("tpu_custom_call" in text) == (family == "pythia_pallas_kernel")
+    assert ("tpu_custom_call" in text) == (family != "bloom_dense_alibi")
+    if family != "bloom_dense_alibi":
+        _walk_built_once(worker, params, cache, n, sds, loops=1)
     whole = re.escape(f"bf16[{L},{n},{Smax},{H},{Dh}]")
     copies = re.findall(rf"^\s*%?[\w.-]+ = {whole}\S* copy\(", text, re.M)
     assert not copies, f"the decode step copies the whole slot cache: {copies}"
@@ -877,6 +938,7 @@ def test_passes_decode_program_loops_over_the_passes_and_keeps_the_cache_in_plac
                                                       "v": (48, n, Smax, 16, 128)}
     assert len(text) < 1.2 * len(texts[1]), (len(text), len(texts[1]))
     assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 1
+    _walk_built_once(worker, params, cache, n, sds, loops=2)  # once a step, not 48 times
     loops = re.findall(r"^\s*%?while[\w.-]* = ", text, re.M)
     assert len(loops) == len(re.findall(r"^\s*%?while[\w.-]* = ", texts[1], re.M)) + 1
     assert not _whole_copies(text, rf"bf16\[48,{n},{Smax},16,128\]")
