@@ -439,6 +439,9 @@ class SlotWorker:
         # where the programs read a routed layer's expert banks from ("in_place" /
         # "sliced"; None for a model without dropless routing): the rule they trace by
         self.expert_bank = tfm.expert_bank_form(self.cfg, self.params.get("moe"), self.mesh)
+        # the routed layers a row goes through (a model with dropless routing only)
+        self.routed_layers = (jax.tree.leaves(self.params["moe"]["experts"])[0].shape[0]
+                              if self.expert_bank else 0)
 
         self._decode = None  # jitted lazily (params pytree shapes needed)
         self._prefills: dict[int, object] = {}  # bucket len -> jitted prefill
@@ -467,7 +470,8 @@ class SlotWorker:
         # a routed model's programs return the experts they chose beside the
         # load counted from them; set this to a list and every fetched call
         # appends {"span", its rows (decode / verify: pos, active; prefill:
-        # uid, slot, true_len; chunk: uid, slot, start, live), "chosen"}. The
+        # uid, slot, true_len; chunk: uid, slot, start, live), "chosen"} (a chunk
+        # left asynchronous too: its choices are then waited for). The
         # programs are the same either way: observation, not a path
         self.routing_log: list | None = None
 
@@ -935,6 +939,11 @@ class SlotWorker:
             sp.annotate(compiled=compiled, h2d=len(head) + len(sampler),
                         d2h=len(out) if fetch else 0)
             if not fetch:
+                if self.routing_log is not None and chosen is not None:
+                    # an unfetched call's choices, for the check alone: the one wait an
+                    # asynchronous call is otherwise spared, and only while the log is on
+                    self.routing_log.append({"span": sp.name, **rows,
+                                             "chosen": np.asarray(chosen)})
                 return sp, None
             with tm.span("fetch"):
                 with tm.span("wait"):
@@ -1061,10 +1070,22 @@ class SlotWorker:
         no latency datum); the FINAL chunk fetches and returns
         ``(first_token, bad)``. ``uid`` only labels the span."""
         tm = self.telemetry
-        attrs = dict(uid=uid, slot=slot, width=width, live=live, fetch=fetch,
-                     cached_tokens=int(start) + int(live),
-                     attn=tfm.cache_step_form(self.cfg), **self._block_attrs(width, live),
+        # ``whole_keys``: the keys ONE whole-context layer's live queries required
+        # (the query at position p sees p + 1), ``ring_tokens`` ONE window layer's
+        # (min(p + 1, window)); ``attn_chunk``: how the whole-context layers read the
+        # slot's cache (``cache_chunk_form``: over the live key blocks, or all of
+        # ``Smax`` densely); ``expert_rows_held``: the (row, expert) pairs the routed
+        # layers multiplied, the padding's among them, known before the call where
+        # every expert is held (a held share's count comes back with a FETCHED call)
+        seen = np.arange(int(start), int(start) + int(live), dtype=np.int64)
+        attrs = dict(uid=uid, slot=slot, start=int(start), width=width, live=live, fetch=fetch,
+                     cached_tokens=int(start) + int(live), whole_keys=int(np.sum(seen + 1)),
+                     attn=tfm.cache_step_form(self.cfg),
+                     attn_chunk=tfm.cache_chunk_form(self.cfg, 1, width, self.Smax),
+                     **self._block_attrs(width, live), **self._ring_attrs(seen),
                      **self.pass_attrs)
+        if self.expert_bank and not self.cfg.moe_experts_held:
+            attrs["expert_rows_held"] = int(width) * self.cfg.moe_top_k * self.routed_layers
         sp, out = self._run(
             "chunk", attrs, lambda: self._chunk_prog(width),
             lambda sp: ((np.asarray(toks, np.int32), np.int32(slot), np.int32(start),
@@ -1403,10 +1424,10 @@ class ServingEngine:
         if windowed:
             # each moves the cache by POSITION, and a ring was overwritten past its
             # window: a prefix's ring is not the ring after the prefix unless it is
-            # stored with it, a chunk entering past position 0 overwrites entries its
-            # first queries still see, a rejected draft cannot be rolled back out of it
-            for what, on in (("prefix_cache", pc.enabled), ("chunked_prefill", cp.enabled),
-                             ("speculation", sp.enabled)):
+            # stored with it, a rejected draft cannot be rolled back out of it. (A
+            # chunk entering past position 0 has code: it attends over [ring ; chunk]
+            # before the ring is written, ``transformer._cache_attention``.)
+            for what, on in (("prefix_cache", pc.enabled), ("speculation", sp.enabled)):
                 if on:
                     raise NotImplementedError(
                         f"{what} with window layers (local_attn_layers) has no code: a window "

@@ -97,14 +97,16 @@ def build_serving_engine(spec: dict, replica_id: int | str = 0,
 
     from ..inference import InferenceEngine
     from ..inference.serving import ServingEngine
-    from ..models.transformer import Model, TransformerConfig
+    from ..models.transformer import Model, TransformerConfig, rotary_kinds_fact
 
     with tracing.span(tracing.STARTUP, keep=True, replica_id=replica_id,
-                      role=role or "both"):
+                      role=role or "both") as built:
         model_spec = dict(spec.get("model", {}))
         dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[
             str(model_spec.pop("dtype", "float32"))]
         cfg = TransformerConfig(dtype=dtype, **model_spec)
+        if cfg.pos_emb == "rotary":  # how each layer kind turns its q and k
+            built.annotate(rotary_kinds=rotary_kinds_fact(cfg))
         engine = InferenceEngine(
             model=Model(cfg), config={"dtype": spec.get("engine_dtype", "fp32")})
         return ServingEngine(engine, config=dict(spec.get("serving", {})),

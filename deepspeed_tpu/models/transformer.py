@@ -126,12 +126,28 @@ class TransformerConfig:
     # GPT-Neo's alternating local layers and EXAONE's three sliding layers in
     # four are this one thing. ``rotary_layers`` (``pos_emb="rotary"`` only):
     # per-layer 0/1 flags, 0 = no rotary on that layer's q and k (EXAONE's full
-    # layers carry no position at all); None = every layer. Through the cache a
-    # window layer keeps a RING of ``local_attn_window`` positions a sequence and
-    # a whole layer ``Smax`` (``cache_layout``)
+    # layers carry no position at all); None = every layer. HOW a rotated layer
+    # turns is its kind's to state (``rotary_by_kind`` below; ``rotary_base`` where
+    # it states nothing). Through the cache a window layer keeps a RING of
+    # ``local_attn_window`` positions a sequence and a whole layer ``Smax``
+    # (``cache_layout``); a prompt may enter both in chunks (``_cache_attention``)
     local_attn_window: int = 0
     local_attn_layers: Optional[tuple] = None
     rotary_layers: Optional[tuple] = None
+    # A layer kind's ROTARY, as data (``rotary_spec``): ``{"window": spec, "whole":
+    # spec}``, the rotary of the layers that attend inside the window and of those
+    # that attend over the whole context (a kind left out turns by ``rotary_base``).
+    # A spec is plain, ``{"base": b}``: inv_freq_i = b^(-2i/d); or YaRN's blend (Peng
+    # et al. 2023, as ``transformers`` computes it), ``{"type": "yarn", "base",
+    # "factor", "original_max_position_embeddings", "beta_fast" (32), "beta_slow" (1),
+    # "attention_factor" (0.1 ln(factor) + 1), "truncate" (True)}``: the dimensions
+    # that turn more than ``beta_fast`` times over the original context keep their
+    # frequency, those that turn less than ``beta_slow`` times are divided by
+    # ``factor``, a linear ramp between, and cos and sin are multiplied by
+    # ``attention_factor`` (``rotary_table`` has the equations). Mellum2's full layers
+    # state YaRN and its window layers a plain base. ``rotary_layers``' 0 still means
+    # no rotary at all. None: every rotated layer turns by ``rotary_base``, as ever
+    rotary_by_kind: Optional[dict] = None
     # A layer's OPERATOR, the third part of its kind: per layer "attn" (the
     # attention sublayer above) or "conv", a gated short convolution in its place
     # (LFM2's; ``_short_conv`` has the equations): no q / k / v, no rotary, no
@@ -297,6 +313,10 @@ class TransformerConfig:
                 object.__setattr__(self, name, tuple(int(v) for v in getattr(self, name)))
         if isinstance(self.layer_operators, list):
             object.__setattr__(self, "layer_operators", tuple(self.layer_operators))
+        if self.rotary_by_kind is not None:
+            object.__setattr__(self, "rotary_by_kind", _Stated(
+                {kind: _Stated(spec) if isinstance(spec, dict) else spec
+                 for kind, spec in self.rotary_by_kind.items()}))
         _refuse_uncoded(self)
 
     @property
@@ -353,6 +373,13 @@ class TransformerConfig:
     def window_layers(self) -> tuple:
         """The layers (model indices) that attend inside a sliding window."""
         return tuple(l for l, on in enumerate(self.local_attn_layers or ()) if on)
+
+    def rotary_spec(self, window) -> dict:
+        """The rotary a layer of one KIND states (``rotary_by_kind``): ``window``
+        truthy for a layer that attends inside the sliding window, falsy for one
+        over the whole context. A kind that states none turns by ``rotary_base``."""
+        stated = (self.rotary_by_kind or {}).get("window" if window else "whole")
+        return stated if stated is not None else {"base": self.rotary_base}
 
     @property
     def experts_held(self) -> tuple:
@@ -542,9 +569,9 @@ def _refuse_uncoded_kinds_and_share(cfg: "TransformerConfig") -> None:
     """``_refuse_uncoded`` for the layer kinds, the per-head q/k norm, the held
     share of the experts and the multi-token-prediction module. (Kinds with
     latent attention or the state-space mixer are refused beside those; a window
-    layer through the CACHE with alibi, a block entering a ring past position 0
-    and a padded block without ``live`` are refused where the cache path is
-    traced, ``_cache_attention``: ``apply`` has all of them.)"""
+    layer through the CACHE with alibi, a verify block into a ring and a padded
+    block without ``live`` are refused where the cache path is traced,
+    ``_cache_attention``: ``apply`` has all of them.)"""
     if cfg.qk_norm not in (False, True, "head"):
         raise ValueError(f"qk_norm is False, True (one norm over the whole projection) or "
                          f"'head' (one of width head_dim on every head), not {cfg.qk_norm!r}")
@@ -558,6 +585,7 @@ def _refuse_uncoded_kinds_and_share(cfg: "TransformerConfig") -> None:
         raise ValueError("local_attn_layers without a local_attn_window > 0: no window to slide")
     if cfg.rotary_layers is not None and cfg.pos_emb != "rotary":
         raise ValueError(f"rotary_layers with pos_emb={cfg.pos_emb!r}: no rotary to switch off")
+    _refuse_uncoded_rotary_kinds(cfg)
     if cfg.moe_experts_held is not None:
         first, count = (tuple(cfg.moe_experts_held) + (0, 0))[:2]
         if cfg.moe_routing != "dropless":
@@ -585,6 +613,53 @@ def _refuse_uncoded_kinds_and_share(cfg: "TransformerConfig") -> None:
         for what, refused in no_code.items():
             if refused:
                 raise NotImplementedError(f"mtp_layers with {what} has no code")
+
+
+# A YaRN spec's keys (``rotary_by_kind``): those it must state, and those with a default.
+_YARN_STATED = ("base", "factor", "original_max_position_embeddings")
+_YARN_KEYS = _YARN_STATED + ("type", "beta_fast", "beta_slow", "attention_factor", "truncate")
+
+
+def _refuse_uncoded_rotary_kinds(cfg: "TransformerConfig") -> None:
+    """``_refuse_uncoded`` for ``rotary_by_kind``: a spec that is neither plain nor
+    YaRN, or misses a key, and the combinations no code turns by a kind's table."""
+    if cfg.rotary_by_kind is None:
+        return
+    if cfg.pos_emb != "rotary":
+        raise ValueError(f"rotary_by_kind with pos_emb={cfg.pos_emb!r}: no rotary to state")
+    for kind, spec in cfg.rotary_by_kind.items():
+        if kind not in ("window", "whole"):
+            raise ValueError(f"rotary_by_kind states the kinds 'window' and 'whole', not {kind!r}")
+        if not isinstance(spec, dict) or float(spec.get("base", 0)) <= 0:
+            raise ValueError(f"rotary_by_kind[{kind!r}] is a spec with a base > 0, got {spec!r}")
+        form = spec.get("type", "plain")
+        if form == "plain":
+            known = ("type", "base")
+        elif form == "yarn":
+            known = _YARN_KEYS
+            missing = [k for k in _YARN_STATED if k not in spec]
+            if missing or float(spec["factor"]) <= 0:
+                raise ValueError(f"rotary_by_kind[{kind!r}]: a YaRN spec states "
+                                 f"{', '.join(_YARN_STATED)} (factor > 0), got {dict(spec)!r}")
+        else:
+            raise NotImplementedError(
+                f"rotary_by_kind[{kind!r}]: a rotary of type {form!r} has no code ('plain' and "
+                "'yarn' have; linear, dynamic, longrope and llama3 scaling have none)")
+        unknown = sorted(set(spec) - set(known))
+        if unknown:
+            raise NotImplementedError(
+                f"rotary_by_kind[{kind!r}]: the key(s) {', '.join(unknown)} of a {form} rotary "
+                "have no code (mscale / mscale_all_dim among them: state attention_factor)")
+    if "window" in cfg.rotary_by_kind and not cfg.window_layers:
+        raise ValueError("rotary_by_kind states a 'window' kind and no layer attends inside a "
+                         "window (local_attn_layers)")
+    no_code = {"latent attention (kv_lora_rank > 0: its rotary part is one shared key's)":
+                   cfg.kv_lora_rank > 0,
+               "rotary_pct < 1": cfg.rotary_pct != 1.0,
+               "rotary_interleaved": cfg.rotary_interleaved}
+    for what, refused in no_code.items():
+        if refused:
+            raise NotImplementedError(f"rotary_by_kind with {what} has no code")
 
 
 # What may stand in a layer's attention sublayer (``layer_operators``); also the keys
@@ -709,11 +784,11 @@ def refuse_in_pipeline(cfg: "TransformerConfig") -> None:
         raise NotImplementedError(
             "layer_operators under a pipeline schedule has no code: the stages slice ONE "
             "stack of identical layers, and these lie in stacks by operator")
-    if cfg.rotary_layers is not None or cfg.mtp_layers:
+    if cfg.rotary_layers is not None or cfg.rotary_by_kind is not None or cfg.mtp_layers:
         raise NotImplementedError(
-            "rotary_layers / mtp_layers under a pipeline schedule have no code: a stage sees "
-            "its own layer indices, not the model's, and no stage owns the module behind the "
-            "last layer")
+            "rotary_layers / rotary_by_kind / mtp_layers under a pipeline schedule have no "
+            "code: a stage sees its own layer indices, not the model's, and no stage owns the "
+            "module behind the last layer")
     if cfg.ssm_state_size > 0:
         raise NotImplementedError(
             "the state-space mixer (ssm_state_size > 0) under a pipeline schedule has no "
@@ -864,7 +939,7 @@ def _mtp_block_cfg(cfg: TransformerConfig) -> TransformerConfig:
     model's own, of the whole-context kind with no rotary, its feed-forward the
     model's last layer's (routed where the model routes)."""
     return cfg.replace(num_layers=1, mtp_layers=0, moe_first_dense=0,
-                       dense_intermediate_size=None, local_attn_layers=None,
+                       dense_intermediate_size=None, local_attn_layers=None, rotary_by_kind=None,
                        rotary_layers=(0,) if cfg.pos_emb == "rotary" else None)
 
 
@@ -1148,19 +1223,100 @@ def norm(cfg: "TransformerConfig", x, p, name: str):
     return layer_norm(x, p[name + "_scale"], p[name + "_bias"], cfg.layernorm_epsilon)
 
 
-def rotary_embed(x, positions, rotary_dims, interleaved: bool = False, base: float = 10000.0):
+def yarn_ramp(spec: dict, rotary_dims: int) -> np.ndarray:
+    """YaRN's blend r_i [rotary_dims // 2] float32 for a spec of ``rotary_by_kind``: 0
+    where dimension pair i keeps its frequency, 1 where it is divided by ``factor``.
+    With d(n) = rotary_dims ln(original_max_position_embeddings / (2 pi n)) / (2 ln
+    base), the pair that turns n times over the original context: low = d(beta_fast),
+    high = d(beta_slow), floored and ceiled where ``truncate`` (the published default),
+    clipped to 0 .. rotary_dims - 1; r_i = clip((i - low) / (high - low), 0, 1)."""
+    base, span = float(spec["base"]), float(spec["original_max_position_embeddings"])
+    turns = lambda n: rotary_dims * math.log(span / (n * 2 * math.pi)) / (2 * math.log(base))
+    low, high = turns(float(spec.get("beta_fast", 32))), turns(float(spec.get("beta_slow", 1)))
+    if spec.get("truncate", True):
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, rotary_dims - 1)
+    if low == high:
+        high += 0.001  # the published guard against a ramp of no width
+    i = np.arange(rotary_dims // 2, dtype=np.float32)
+    return np.clip((i - np.float32(low)) / np.float32(high - low), 0, 1).astype(np.float32)
+
+
+def yarn_attention_factor(spec: dict) -> float:
+    """What a YaRN spec multiplies cos and sin by: ``attention_factor`` as stated, or
+    0.1 ln(factor) + 1 (1 for a factor <= 1)."""
+    stated = spec.get("attention_factor")
+    if stated is not None:
+        return float(stated)
+    factor = float(spec["factor"])
+    return 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rotary_table(spec: dict, rotary_dims: int):
+    """A rotary spec (``TransformerConfig.rotary_spec``) -> (inv_freq [rotary_dims //
+    2] float32, the constant cos and sin are multiplied by): THE place a frequency is
+    computed. Plain: inv_freq_i = base^(-2i / rotary_dims), and 1. YaRN: (1 - r_i) x
+    that + r_i x that / ``factor`` (``yarn_ramp``), and ``yarn_attention_factor``."""
+    half = rotary_dims // 2
+    plain = jnp.exp(-math.log(float(spec["base"])) * jnp.arange(0, half, dtype=jnp.float32) / half)
+    if spec.get("type", "plain") == "plain":
+        return plain, 1.0
+    r = jnp.asarray(yarn_ramp(spec, rotary_dims))
+    return plain / float(spec["factor"]) * r + plain * (1 - r), yarn_attention_factor(spec)
+
+
+def rotary_tables(cfg: "TransformerConfig"):
+    """The tables of a model whose layer kinds state their rotary (``rotary_by_kind``),
+    built ONCE a forward pass (``_layer_loop``): (inv_freq [2, half], factor [2]), row 0
+    the whole-context kind's and row 1 the window kind's; a block takes its kind's row,
+    at a Python index where the loop knows the kind and at a traced one where it scans
+    layers of both. None for a model that states one rotary for all: ``rotary_embed``
+    then computes ``rotary_base``'s frequencies where it always has."""
+    if cfg.rotary_by_kind is None:
+        return None
+    rd = int(cfg.head_dim * cfg.rotary_pct)
+    freqs, factors = zip(*(rotary_table(cfg.rotary_spec(window), rd) for window in (False, True)))
+    return jnp.stack(freqs), jnp.asarray(factors, jnp.float32)
+
+
+def rotary_kinds_fact(cfg: "TransformerConfig"):
+    """What the engine's build span says of the program's rotary: None for a model
+    without, ``"plain(<base>)"`` where every rotated layer turns by ``rotary_base``, and
+    with ``rotary_by_kind`` each kind's own, ``"whole=yarn(<base>, x<factor>, <original
+    context>) window=plain(<base>)"``."""
+    if cfg.pos_emb != "rotary":
+        return None
+
+    def fact(spec):
+        if spec.get("type", "plain") == "plain":
+            return f"plain({float(spec['base']):g})"
+        return (f"yarn({float(spec['base']):g}, x{float(spec['factor']):g}, "
+                f"{int(spec['original_max_position_embeddings'])})")
+
+    if cfg.rotary_by_kind is None:
+        return fact({"base": cfg.rotary_base})
+    kinds = [("whole", False)] + ([("window", True)] if cfg.window_layers else [])
+    return " ".join(f"{name}={fact(cfg.rotary_spec(window))}" for name, window in kinds)
+
+
+def rotary_embed(x, positions, rotary_dims, interleaved: bool = False, base: float = 10000.0,
+                 table=None):
     """Apply rotary position embedding to the first ``rotary_dims`` of x
     [B, S, H, Dh] (reference inference kernel: apply_rotary_pos_emb,
     csrc/transformer/inference/csrc/pt_binding.cpp:1268). ``interleaved``
     selects GPT-J's rotate-every-two pairing ((x0,x1),(x2,x3),...) instead of
-    the NeoX half-split ((x0,x_half),...)."""
+    the NeoX half-split ((x0,x_half),...). ``table`` (a layer kind's row of
+    ``rotary_tables``): (inv_freq [rotary_dims // 2], the factor on cos and sin)
+    in the place of ``base``'s plain frequencies."""
     rd = rotary_dims
     x_rot, x_pass = x[..., :rd], x[..., rd:]
     half = rd // 2
-    freqs = jnp.exp(-math.log(base) * jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs, factor = rotary_table({"base": base}, rd) if table is None else table
     angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]  # [B,S,half]
-    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
+    # YaRN's attention factor (1 for a plain kind) multiplies in float32
+    scaled = (lambda t: t) if table is None else (lambda t: t * factor)
+    cos = scaled(jnp.cos(angles)[:, :, None, :]).astype(x.dtype)
+    sin = scaled(jnp.sin(angles)[:, :, None, :]).astype(x.dtype)
     if interleaved:
         x1, x2 = x_rot[..., 0::2], x_rot[..., 1::2]
         r1 = x1 * cos - x2 * sin
@@ -1619,7 +1775,7 @@ def _dense_ffn(cfg, lp, h):
     return _ffn(cfg, lp, h), jnp.zeros((), jnp.float32), None
 
 
-def _qkv_proj(cfg: TransformerConfig, lp, h, positions, rotary=True):
+def _qkv_proj(cfg: TransformerConfig, lp, h, positions, rotary=True, table=None):
     """LN'd hidden states -> rotary-embedded q, k, v [B, T, H, Dh]. Latent
     attention gives what it caches in the place of k and v: the rotary key
     every head shares [B, T, 1, Dr] and the normed latent [B, T, 1, R]; the
@@ -1629,7 +1785,8 @@ def _qkv_proj(cfg: TransformerConfig, lp, h, positions, rotary=True):
     one where it scans layers of both (the rotated pair is then selected). With
     ``attn_output_gate`` a fourth value comes last: the second half of every
     head's query projection [B, T, H, Dh], which ``_attn_out_proj`` gates the
-    heads' output by."""
+    heads' output by. ``table`` (``rotary_by_kind``): this layer's kind's row of
+    ``rotary_tables``, which ``rotary_embed`` turns by in ``rotary_base``'s place."""
     with jax.named_scope("attn"):
         h = _act_q(cfg, h)
         h = _times(h, cfg.multiplier("attention_in_multiplier"))
@@ -1660,8 +1817,8 @@ def _qkv_proj(cfg: TransformerConfig, lp, h, positions, rotary=True):
             k = rms_norm(k, lp["k_norm_scale"], cfg.layernorm_epsilon, axes=(-2, -1))
         if cfg.pos_emb == "rotary" and rotary is not False:
             rd = int(cfg.head_dim * cfg.rotary_pct)
-            turned = [rotary_embed(x, positions, rd, cfg.rotary_interleaved, cfg.rotary_base)
-                      for x in (q, k)]
+            turned = [rotary_embed(x, positions, rd, cfg.rotary_interleaved, cfg.rotary_base,
+                                   table) for x in (q, k)]
             q, k = turned if rotary is True else [jnp.where(rotary, t, x)
                                                   for t, x in zip(turned, (q, k))]
         return (q, k, v, *gate)
@@ -2208,7 +2365,7 @@ NEG_BIAS = -1e30
 
 
 def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
-           rng=None, pld_keep=None, live=None, kind=None):
+           rng=None, pld_keep=None, live=None, kind=None, tables=None):
     """THE transformer layer: every caller's layer is this function.
 
     carry = (x [B, T, d] in the compute dtype, state); ``lp`` one layer's leaves;
@@ -2238,6 +2395,9 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
       "conv" runs ``_short_conv`` in the attention sublayer's place and one whose
       operator is "delta" ``_gated_delta``: no q / k / v of attention's, no rotary,
       no ``attend``; its state rides in ``state`` as a mixer's does.
+    - ``tables``: ``rotary_tables(cfg)`` where the layer kinds state their rotary
+      (``rotary_by_kind``; built here where a caller hands none): the block turns q
+      and k by its kind's row, the window kind's or the whole-context kind's.
 
     Norm kind and placement and the residual form are what ``cfg`` says, for a
     dense layer and a routed one alike (``norm_style="post"`` is the BERT
@@ -2269,12 +2429,18 @@ def _block(cfg: TransformerConfig, carry, lp, l, *, positions, attend, ffn,
     rotary = True
     if cfg.rotary_layers is not None:
         rotary = kind[1] if kind is not None else jnp.asarray(cfg.rotary_layers, bool)[l]
+    table = None
+    if cfg.rotary_by_kind is not None:
+        tables = tables if tables is not None else rotary_tables(cfg)
+        windowed = int(bool(kind[0])) if kind is not None else jnp.asarray(
+            cfg.local_attn_layers or (0,) * cfg.num_layers, jnp.int32)[l]
+        table = (tables[0][windowed], tables[1][windowed])
     if kind is not None and kind[2] != "attn":
         operator = _short_conv if kind[2] == "conv" else _gated_delta
         op_out, state = operator(cfg, lp, h, state, l, live)
         attn_out = branch(op_out, cfg.attn_dropout, k_attn)
     else:
-        q, k, v, *out_gate = _qkv_proj(cfg, lp, h, positions, rotary)
+        q, k, v, *out_gate = _qkv_proj(cfg, lp, h, positions, rotary, table)
         attn, state = attend(q, k, v, state, l, lp, **({} if kind is None else {"kind": kind}))
         attn_out = branch(post(_attn_out_proj(cfg, lp, attn, *out_gate), "ln1_post"),
                           cfg.attn_dropout, k_attn)
@@ -2462,7 +2628,8 @@ def _layer_loop(cfg: TransformerConfig, layers, moe, x, state, *, positions, att
             period = cfg.remat_group
     G, tail = divmod(n, period) if period else (0, 0)
     unroll = max(1, cfg.scan_unroll)
-    block = partial(_block, cfg, positions=positions, attend=attend, live=live)
+    block = partial(_block, cfg, positions=positions, attend=attend, live=live,
+                    tables=rotary_tables(cfg))
     unwrapped = lambda body: body  # noqa: E731
     wrap = wrap or unwrapped
 
@@ -2810,12 +2977,16 @@ def cache_layout(cfg: TransformerConfig) -> dict:
     positions of a sequence, position p at index p mod R, whatever ``Smax`` is.
     R is the window itself: a step at position p reads p - R + 1 ... p, exactly
     the R entries the ring holds once p is written, so no entry is read that a
-    mask must hide for being too old, and R = 128 is one lane tile. (A block of
-    T > 1 tokens entering at a position past 0 would need R >= window + T - 1 to
-    still hold what its first query sees: chunked prefill and verification are
-    refused for it, ``_cache_attention``.) A ring cannot be sliced at an old
-    position nor rolled back: the helpers move it WHOLE, as they do state.
-    Absent for a model without window layers.
+    mask must hide for being too old, and R = 128 is one lane tile. A block of
+    T > 1 tokens entering at a position past 0 (a CHUNK of a prompt) would need R
+    >= window + T - 1 to still hold what its first query sees once it is written;
+    the ring stays R = window all the same, because the block attends over [ring ;
+    block] BEFORE the write and the ring then takes its last R LIVE rows
+    (``_cache_attention``): rings of window + chunk would cost a slot chunk x
+    window layers more positions (Mellum2: 5.50 GB where 4.70 are held) and every
+    decode step a mask over them. A ring cannot be sliced at an old position nor
+    rolled back (a verify block is refused for it): the helpers move it WHOLE, as
+    they do state. Absent for a model without window layers.
 
     Per-SEQUENCE leaves, under ``STATE``, ``{leaf: (shape, dtype or None for the
     cache's)}``: state with no position axis, [L, B, *shape] in the cache, that
@@ -2832,10 +3003,11 @@ def cache_layout(cfg: TransformerConfig) -> dict:
     if cfg.kv_lora_rank:
         return {"k": (1, cfg.qk_rope_head_dim), "v": (1, cfg.kv_lora_rank)}
     layout = {"k": (cfg.kv_heads, cfg.head_dim), "v": (cfg.kv_heads, cfg.value_head_dim)}
+    rings = {name: (cfg.local_attn_window,) + tail for name, tail in layout.items()}
     if cache_heads_merged(cfg):
         layout = {name: (1, heads * width) for name, (heads, width) in layout.items()}
     if cfg.window_layers:
-        layout[RING] = {name: (cfg.local_attn_window,) + tail for name, tail in layout.items()}
+        layout[RING] = rings  # a ring keeps its heads: its step reads R positions, not Smax
     if cfg.ssm_state_size:
         layout[STATE] = {
             "ssm": ((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size), jnp.float32),
@@ -2889,11 +3061,14 @@ def cache_heads_merged(cfg: TransformerConfig) -> bool:
     its cache attends to itself and never reads it, and a block too long for the rows
     form (a chunk) views the layer as heads where it reads it. Plain XLA attention
     alone: the Pallas decode kernel takes [L, B, Smax, H, Dh] stacks; a latent is one
-    'head' already; a window layer's ring keeps its heads, and so does the
-    whole-context layer beside it (K-EXAONE: ONE such layer, which the compiler reads
-    in place with the re-layout inside the contraction's own fusion; no K/V slice or
-    copy stands in its program). Multi-head attention at the lanes' width (BLOOM,
-    OLMoE, Pythia) has nothing to gain. A row is one 'head' and replicates over a
+    'head' already; a window layer's ring keeps its heads whatever the whole-context
+    layers do, and so does ONE whole-context layer beside rings (K-EXAONE: the compiler
+    reads it in place with the re-layout inside the contraction's own fusion; no K/V
+    slice or copy stands in its program). SEVERAL whole-context layers beside rings are
+    a stack again and grouped heads merge there as anywhere (Mellum2, [2, 32, 32768, 4,
+    128]: as heads each layer's K and V left the stack as a 268 MB copy every step,
+    12.2 of a 31.5 ms step, PERF.md §6 PR 59). Multi-head attention at the lanes' width
+    (BLOOM, OLMoE, Pythia) has nothing to gain. A row is one 'head' and replicates over a
     mesh's tensor axis (``kv_slot_cache_spec``): grouped heads that the process's
     active mesh (``Model.set_mesh``, as ``expert_bank_form`` reads it) would shard
     over that axis stay heads, each shard holding its own; narrow heads merge there
@@ -2905,7 +3080,9 @@ def cache_heads_merged(cfg: TransformerConfig) -> bool:
         from ..parallel.sharding import batch_and_head_axes
 
         grouped = batch_and_head_axes(_ACTIVE_MESH[0], 1, cfg.kv_heads)[1] is None
-    return (not cfg.kv_lora_rank and cfg.decode_attn == "xla" and not cfg.window_layers
+    if cfg.window_layers:  # beside rings: grouped heads of a STACK of whole-context layers
+        narrow, grouped = False, grouped and cache_layers(cfg)["tokens"] > 1
+    return (not cfg.kv_lora_rank and cfg.decode_attn == "xla"
             and (narrow or grouped) and together % LANES == 0
             and cfg.value_head_dim == cfg.head_dim)
 
@@ -3088,6 +3265,68 @@ def cache_attention_form(num_heads: int, B: int, T: int, Smax: int, lock_step: b
     return "flash" if fills_cache and 4 * B * num_heads * T * Smax > DENSE_SCORE_BYTES else "dense"
 
 
+# Cached positions a step of ``_blocks_attention``'s walk holds: 512 keys of 4 K/V heads
+# against 2,048 rows of 32 query heads are 134 MB of float32 scores, the most a step makes.
+CHUNK_KEY_BLOCK = 512
+
+
+def cache_chunk_form(cfg: TransformerConfig, B: int, T: int, Smax: int) -> str:
+    """``"blocks"`` or ``"dense"``: how a block of ``T`` > 1 tokens a row that READS a
+    whole-context layer's cache ``Smax`` long (a chunk entering at its own position, a
+    verify block) attends there, from what the code can see. Densely over all ``Smax``
+    positions under the causal mask while those scores fit ``DENSE_SCORE_BYTES``;
+    beyond, over the key blocks up to the newest position and no further
+    (``_blocks_attention``): a 2,048-row chunk in a slot of 32,768 would make 8.6 GB of
+    scores a layer where its queries see ``start + i`` keys. ONE walk, in XLA, on every
+    platform and mesh: a Pallas kernel of the same walk tied with it in the chunk
+    program it was written for (Mellum2's, 2,048 rows over 32,768: 41.3 / 42.1 / 49.0 /
+    60.0 ms with the kernel at prefixes of 0 / 4,096 / 14,336 / 28,672 against 39.3 /
+    41.9 / 50.4 / 61.8, PERF.md §6 PR 59) and went; a ``perf_opt`` has to earn it.
+    Latent attention (its absorbed form), alibi (its bias is [T, Smax]), a block short
+    enough for the rows form and a cache no key block divides stay dense.
+    ``_cache_attention`` traces by this and ``SlotWorker.chunk`` labels its span by it
+    (``attn_chunk``)."""
+    over = 4 * B * cfg.num_heads * T * Smax > DENSE_SCORE_BYTES
+    plain = not cfg.kv_lora_rank and cfg.pos_emb != "alibi" and not cache_rows_step(cfg, T)
+    return "blocks" if T > 1 and over and plain and Smax % CHUNK_KEY_BLOCK == 0 else "dense"
+
+
+def _blocks_attention(q, k_l, v_l, positions):
+    """Attention of a block q [B, T, H, D] at ``positions`` [B, T] over ONE layer of a
+    cache k_l / v_l [B, Smax, Hkv, D] that already holds the block's own keys: the
+    softmax taken online over key blocks of ``CHUNK_KEY_BLOCK`` positions, from the
+    first to the one that holds the newest position and no further (a loop of a traced
+    trip count), each under the causal mask of the absolute positions; a group of
+    query heads against its one K/V head where it lies. The work follows the keys the
+    block's queries see, not ``Smax``."""
+    B, T, H, D = q.shape
+    Hkv, block = k_l.shape[2], CHUNK_KEY_BLOCK
+    qg = q.reshape(B, T, Hkv, H // Hkv, D)
+    scale = 1.0 / math.sqrt(D)
+    newest = jnp.minimum(jnp.max(positions), k_l.shape[1] - 1)
+
+    def step(j, carry):
+        m, norm, acc = carry
+        k_b, v_b = (lax.dynamic_slice_in_dim(c, j * block, block, axis=1) for c in (k_l, v_l))
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_b).astype(jnp.float32) * scale
+        seen = (j * block + jnp.arange(block))[None, None, :] <= positions[:, :, None]
+        s = jnp.where(seen[:, None, None], s, NEG_BIAS)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        fade = jnp.exp(m - m_new)
+        acc = acc * fade[..., None] + jnp.einsum(
+            "bhgqk,bkhd->bhgqd", p.astype(q.dtype), v_b).astype(jnp.float32)
+        return m_new, norm * fade + jnp.sum(p, axis=-1), acc
+
+    lead = (B, Hkv, H // Hkv, T)
+    m, norm, acc = lax.fori_loop(
+        0, newest // block + 1, step,
+        (jnp.full(lead, NEG_BIAS, jnp.float32), jnp.zeros(lead, jnp.float32),
+         jnp.zeros(lead + (v_l.shape[-1],), jnp.float32)))
+    out = (acc / norm[..., None]).astype(q.dtype)  # block 0 holds key 0: every row saw a key
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, T, H, v_l.shape[-1])
+
+
 def cache_step_form(cfg: TransformerConfig) -> str:
     """``"latent"``, ``"dense"``, ``"dense+ring"`` or ``"ring"``: how a step that
     READS the slot cache (decode, verify, chunk) attends, for the spans' ``attn``:
@@ -3243,8 +3482,20 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
     ring's R entries, each masked by the absolute position it holds (entry r at a
     query position p holds p - ((p - r) mod R); negative: never written by this
     sequence, whatever an earlier one left there). Nothing ``Smax`` long is made
-    for a window layer. A block of several tokens entering past position 0 would
-    overwrite ring entries its own first queries still see: refused by name."""
+    for a window layer. A block of several tokens entering past position 0 (a
+    chunk: ``pos`` traced) would overwrite ring entries its own first queries still
+    see, so it attends over [ring ; block] BEFORE the write: the window's positions
+    before the block, out of the ring in position order, and the block's own keys
+    are one run of consecutive positions, R + T long, on which causal attention
+    under the window's mask is what the model requires (the same two forms, by the
+    same rule on R + T rows); the ring then takes the block's last R LIVE rows. A
+    block written apart from where it attends (``write_pos``: a verify block, whose
+    rejected tail would have to be rolled back) is refused by name.
+
+    A block that READS a whole-context layer's cache (a chunk, a verify block)
+    attends densely over ``Smax`` while those scores are small and over the key
+    blocks up to its newest position beyond (``cache_chunk_form``,
+    ``_blocks_attention``)."""
     starts = isinstance(pos, (int, np.integer)) and int(pos) == 0
     pos = jnp.asarray(pos, jnp.int32)
     vector_pos = pos.ndim >= 1
@@ -3265,7 +3516,12 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
     fills_cache = lock_step and T == Smax
     use_flash = cache_attention_form(cfg.num_heads, B, T, Smax, lock_step) == "flash"
     windowed = set(cfg.window_layers)  # a window layer's block attends to itself: [T, T] scores
-    window_flash = bool(windowed) and cache_attention_form(cfg.num_heads, B, T, T) == "flash"
+    # ... or, entering past position 0, to the ring's positions before it and itself
+    ring_rows = T if starts or T == 1 else cfg.local_attn_window + T
+    window_flash = bool(windowed) and cache_attention_form(
+        cfg.num_heads, B, ring_rows, ring_rows) == "flash"
+    # a block that reads a whole-context layer's cache: over the live key blocks, or densely
+    chunk_form = "dense" if fills_cache else cache_chunk_form(cfg, B, T, Smax)
     if use_flash or window_flash:
         from ..ops.pallas.flash_attention import flash_attention_sharded
 
@@ -3286,12 +3542,12 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
             raise NotImplementedError(
                 "window layers (local_attn_layers) through the cache with pos_emb='alibi' have "
                 "no code: the ring's bias from the positions its entries hold is not written")
-        if T > 1 and not starts:
+        if T > 1 and write_pos is not None:
             raise NotImplementedError(
-                f"a block of {T} tokens entering a window layer's ring past position 0 (chunked "
-                "prefill, speculative verification) has no code: the ring keeps "
-                f"local_attn_window = {cfg.local_attn_window} positions, and the block would "
-                "overwrite entries its own first queries still see")
+                f"a block of {T} tokens written apart from where it attends (write_pos: "
+                "speculative verification) into a window layer's ring has no code: the ring "
+                f"keeps local_attn_window = {cfg.local_attn_window} positions, and a rejected "
+                "tail cannot be rolled back out of it")
     in_kind = None
     if windowed or cfg.stateful_layers:  # not every layer keeps per-token K/V
         in_kind = jnp.asarray(_index_in_kind(cfg), jnp.int32)
@@ -3358,8 +3614,55 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
             mask = jnp.where(seen, 0.0, NEG_BIAS).astype(jnp.float32)[:, None, None, :]
             attn = xla_attention(q, k_l, v_l, bias=mask, causal=False)
             return attn, {**stacks, RING: ring}
-        # a block that starts its sequences: attends to itself under the window's mask
         k_l, v_l = k.astype(ring["k"].dtype), v.astype(ring["v"].dtype)
+        # a block's live rows a sequence, counted where each form wants them (a block that
+        # starts its sequences behind its attention, as its program has always been traced)
+        live_rows = lambda: (jnp.full((B,), T, jnp.int32) if live is None  # noqa: E731
+                             else jnp.sum(live.astype(jnp.int32), axis=1))
+        if not starts:
+            # A block entering at its own position (a chunk): attention over [ring ;
+            # block] BEFORE the write. Per row, the R positions before ``start`` (those
+            # of them that exist: from position 0) out of the ring in position order,
+            # then the block's own keys, are ONE run of consecutive positions from
+            # ``base`` on, R + T long; the queries lie in it at ``start - base``, behind
+            # rows of zeros. Plain causal self-attention under the window's mask over
+            # that run is what the model requires (what lies behind the block is hidden
+            # by causality, as a bucket's padding is), so it takes the forms a block that
+            # starts its sequences takes, by the same rule on its R + T rows.
+            start = positions[:, 0]
+            base = jnp.maximum(start - R, 0)
+            at = start - base  # [B], 0 .. R
+            order = ((base[:, None] + slots[None, :]) % R)[:, :, None, None]
+            place = jax.vmap(lambda run, new, i: lax.dynamic_update_slice(run, new, (i, 0, 0)))
+            ring_l = {name: lax.dynamic_index_in_dim(ring[name], l, keepdims=False)
+                      for name in ("k", "v")}
+            # what the ring holds BEHIND the block: the last R LIVE positions, entry r the
+            # newest position p = r (mod R) at or under the block's last live one, the
+            # block's row p - start where that is in the block, what it held (that same
+            # p) where not. Attention reads the ring as it was: the block's first queries
+            # still see entries its last rows overwrite
+            p = held(start + live_rows() - 1)
+            src = jnp.clip(p - start[:, None], 0, T - 1)[:, :, None, None]
+            fresh = (p >= start[:, None])[:, :, None, None]
+            written = {name: jnp.where(fresh, jnp.take_along_axis(new, src, axis=1), ring_l[name])
+                       for name, new in (("k", k_l), ("v", v_l))}
+            k_run, v_run = (place(jnp.concatenate(
+                [jnp.take_along_axis(ring_l[name], order, axis=1), jnp.zeros_like(new)], axis=1),
+                new, at) for name, new in (("k", k_l), ("v", v_l)))
+            q_run = place(jnp.zeros((B, R + T) + q.shape[2:], q.dtype), q, at)
+            if window_flash:
+                attn = flash_attention_sharded(
+                    q_run, *repeat_groups(q_run, k_run, v_run), mesh=_ACTIVE_MESH[0],
+                    causal=True, window=float(window))
+            else:
+                dist = jnp.arange(R + T)[:, None] - jnp.arange(R + T)[None, :]
+                inside = jnp.where(dist < window, 0.0, NEG_BIAS).astype(jnp.float32)
+                attn = xla_attention(q_run, k_run, v_run, bias=inside[None, None])
+            attn = jax.vmap(lambda a, i: lax.dynamic_slice_in_dim(a, i, T, axis=0))(attn, at)
+            ring = {name: lax.dynamic_update_slice(ring[name], written[name][None],
+                                                   (l, 0, 0, 0, 0)) for name in ("k", "v")}
+            return attn, {**stacks, RING: ring}
+        # a block that starts its sequences: attends to itself under the window's mask
         if window_flash:
             attn = flash_attention_sharded(q, *repeat_groups(q, k_l, v_l), mesh=_ACTIVE_MESH[0],
                                            causal=True, window=float(window))
@@ -3367,9 +3670,7 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
             dist = steps[:, None] - steps[None, :]
             inside = jnp.where(dist < window, 0.0, NEG_BIAS).astype(jnp.float32)
             attn = xla_attention(q, k_l, v_l, bias=inside[None, None])
-        n = (jnp.full((B,), T, jnp.int32) if live is None
-             else jnp.sum(live.astype(jnp.int32), axis=1))
-        src = jnp.clip(held(n - 1), 0, T - 1)[:, :, None, None]  # the last R LIVE rows
+        src = jnp.clip(held(live_rows() - 1), 0, T - 1)[:, :, None, None]  # the last R LIVE rows
         ring = {name: lax.dynamic_update_slice(
             ring[name], jnp.take_along_axis(new, src, axis=1)[None], (l, 0, 0, 0, 0))
             for name, new in (("k", k_l), ("v", v_l))}
@@ -3405,6 +3706,8 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
             if rows_step:
                 return _rows_attention(q, k_l[:, :, 0], v_l[:, :, 0], pos, bias), stacks
             k_l, v_l = as_heads(k_l), as_heads(v_l)
+            if chunk_form == "blocks":
+                return _blocks_attention(q, k_l, v_l, positions), stacks
         if use_flash:
             attn = flash_attention_sharded(q, *repeat_groups(q, k_l, v_l), mesh=_ACTIVE_MESH[0],
                                            causal=True, alibi_slopes=slopes)

@@ -731,6 +731,82 @@ def test_kinds_prefill_takes_the_banded_forward_in_its_window_layers(v5e, no_per
     assert _footprint(compiled) < HBM_BYTES
 
 
+def _mellum_worker(n, Smax, v5e):
+    """Mellum2-12B-A2.5B's eight layers S S S G S S S G at their published widths
+    (``chipbench/configs/mellum2-12b-a2.5b-L8.json``'s ``program``: 32 / 4 heads of 128, a
+    window of 1,024, a rotary per layer kind, 64 experts top-8, the WHOLE vocabulary:
+    the head's logits are part of what a chunk makes)."""
+    import json
+    from deepspeed_tpu.models import transformer as tfm
+
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__), "chipbench", "configs",
+                           "mellum2-12b-a2.5b-L8.json")) as f:
+        program = json.load(f)["program"]
+    cfg = tfm.TransformerConfig(dtype=jnp.bfloat16, **{**program, "max_seq_len": Smax})
+    return (cfg, *_bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0])))
+
+
+def _compile_chunk(worker, params, cache, width, sds):
+    one = lambda dtype: sds((1,), dtype)
+    return worker._build_chunk(width).lower(
+        params, cache, sds((1, width), jnp.int32), sds((), jnp.int32), sds((), jnp.int32),
+        sds((), jnp.int32), sds((2,), jnp.uint32), one(jnp.float32), one(jnp.int32),
+        one(jnp.float32)).compile()
+
+
+@pytest.mark.parametrize("width", [2048, 256])
+def test_chunk_into_rings_attends_through_kernels_and_fits(width, v5e, no_persistent_cache,
+                                                           as_tpu):
+    """The cell's chunk programs (a whole chunk of 2,048 rows; the shortest tail, 256)
+    compiled for the chip at the cell's own size, 32 slots x 32,768 beside 7.6 GB of
+    weights: the two whole-context layers walk the slot's cache a key block at a time
+    (``_blocks_attention``: nothing [rows, Smax] of scores is made, 8.6 GB a layer if it
+    were; a step's are [rows, 512]), the six window layers attend over [ring ; chunk]
+    through the banded flash forward (its run is window + width rows), and the program
+    fits the chip beside the cache it is handed."""
+    n, Smax = 32, 32768
+    cfg, worker, params, cache, sds = _mellum_worker(n, Smax, v5e)
+    assert jax.tree.map(lambda x: x.shape, cache) == {  # rows beside rings of heads
+        "k": (2, n, Smax, 1, 512), "v": (2, n, Smax, 1, 512),
+        "ring": {"k": (6, n, 1024, 4, 128), "v": (6, n, 1024, 4, 128)}}
+    compiled = _compile_chunk(worker, params, cache, width, sds)
+    text = compiled.as_text()
+    calls = re.findall(r'^\s*%?(flash_fwd[a-z_]*)[\d.]* = .*?custom-call\(', text, re.M)
+    assert sorted(set(calls)) == ["flash_fwd_band"], calls
+    assert not re.search(rf"f32\[(?:1,)?{cfg.num_heads},{width},{Smax}\]", text)
+    assert re.search(rf"f32\[1,4,8,{width},512\]", text)  # the walk's scores, a key block's
+    assert _footprint(compiled) < HBM_BYTES, _footprint(compiled) / 1e9
+    ma = compiled.memory_analysis()
+    print({"width": width, "temp_gb": ma.temp_size_in_bytes / 1e9,
+           "argument_gb": ma.argument_size_in_bytes / 1e9, "alias_gb": ma.alias_size_in_bytes / 1e9,
+           "footprint_gb": _footprint(compiled) / 1e9})
+
+
+def test_mellum_decode_program_fits_beside_32_slots_of_32768(v5e, no_persistent_cache, as_tpu):
+    """The cell's decode step (32 rows over two whole-context layers 32,768 long and six
+    rings of 1,024, ``decode_attn: xla``) compiled for the chip: both cache kinds are
+    donated in and aliased out, neither stack is copied whole, the step fits, and the
+    two whole-context layers are contracted where they lie in their stack of ROWS
+    (``cache_heads_merged``): as heads, [2, 32, 32768, 4, 128], each layer's K and V left
+    the stack as a 268 MB copy every step (1.07 GB of temporaries, 12.2 of a 31.5 ms
+    step on the chip, PERF.md section 6 PR 59); the temporaries left are a layer's
+    float32 scores [32, 32, 32768] and their exponentials."""
+    n, Smax = 32, 32768
+    cfg, worker, params, cache, sds = _mellum_worker(n, Smax, v5e)
+    compiled = _compile_decode(worker, params, cache, n, sds)
+    text = compiled.as_text()
+    for whole in (rf"bf16\[2,{n},{Smax},1,512\]", rf"bf16\[6,{n},1024,4,128\]"):
+        copies = re.findall(rf"^\s*%?[\w.-]+ = {whole}\S* copy\(", text, re.M)
+        assert not copies, f"the decode step copies a whole cache stack: {copies}"
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 2 * n * Smax * 512 * 2, ma.temp_size_in_bytes / 1e9  # < a layer's K
+    cache_bytes = 2 * (2 * n * Smax + 6 * n * 1024) * 4 * 128 * 2
+    assert ma.alias_size_in_bytes >= cache_bytes
+    assert _footprint(compiled) < HBM_BYTES, _footprint(compiled) / 1e9
+    print({"decode_temp_gb": ma.temp_size_in_bytes / 1e9,
+           "footprint_gb": _footprint(compiled) / 1e9})
+
+
 def _lfm2_worker(n, Smax, v5e):
     """LFM2-24B-A2B's nine layers C A C C C A C C C at their published widths
     (``chipbench/configs/lfm2-24b-a2b-L9.json``'s ``program``: a gated short

@@ -28,7 +28,6 @@ def _spec(program, **serving):
 
 @pytest.mark.parametrize("what,block", [
     ("prefix_cache", {"prefix_cache": {"enabled": True, "n_slots": 2}}),
-    ("chunked_prefill", {"chunked_prefill": {"enabled": True, "chunk_size": 16}}),
     ("speculation", {"speculation": {"enabled": True}}),
     ("role", {"role": "prefill"}),
 ])

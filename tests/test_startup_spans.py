@@ -247,7 +247,9 @@ def test_build_serving_engine_leaves_startup_build_with_its_phases():
     srv = build_serving_engine(SPEC, replica_id="b-1", role="both")
     spans = tracing.spans(since)
     (build,) = [sp for sp in spans if sp.path == "startup/build"]
-    assert build.attrs == {"role": "both"} and build.replica_id == "b-1" and build.parent is None
+    # ``rotary_kinds`` (PR 59): how each layer kind of the program turns its q and k
+    assert build.attrs == {"role": "both", "rotary_kinds": "plain(10000)"}
+    assert build.replica_id == "b-1" and build.parent is None
     phases = [sp for sp in spans if sp.parent == build.id]
     # the engine's own, whatever tracer opened each (``cache`` is the serving telemetry's)
     assert [sp.name for sp in phases] == ["mesh", "shapes", "draw", "cache"]
