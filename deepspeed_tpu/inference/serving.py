@@ -62,7 +62,12 @@ programs over sharded state:
                              (a future-dated queue head never blocks later
                              traffic), slot eviction on EOS / max-tokens,
                              request→response bookkeeping, and a wall-clock
-                             ``serve`` driver.
+                             ``serve`` driver. It runs ONE decode step ahead of
+                             the host's copy of the tokens
+                             (``ServingEngine._step``): step k + 1 is enqueued
+                             before step k is fetched, the tokens between them
+                             staying on the device, so the host's side of a
+                             step runs under the device's.
 
   * degradation          — production traffic includes requests that must be
                              refused or abandoned (docs/resilience.md):
@@ -284,6 +289,20 @@ class _Handoff:
     entry: object = None  # acquired PrefixEntry, released on handoff_release
 
 
+@dataclass
+class _Flight:
+    """The decode step that is enqueued and not fetched yet, as the scheduler
+    planned it: the rows it ran and who held each. Its tokens are emitted against
+    THESE rows, not the slots' state at the fetch: by then a slot may have been
+    freed (cancelled, evicted, quarantined, ended by an EOS the step before) or
+    freed AND refilled, and such a row ran for nothing."""
+
+    active: np.ndarray  # [n_slots] bool: the rows the step ran
+    # [n_slots]: each slot's count of occupants at the enqueue. A uid would not do:
+    # a quarantined request is requeued under its uid and may come back to its slot
+    epoch: np.ndarray
+
+
 def _forward(cfg, params, toks, cache, pos, live, **kw):
     """``apply_with_cache`` for a serving program -> (logits, cache, extra
     outputs). A dense model has no extra output and its program is what it
@@ -327,7 +346,9 @@ class SlotWorker:
     shedding, quarantine — pure host state transitions. This worker owns
     the DEVICE: the slot KV cache, the prefix pool, the sampler PRNG, and
     the small inventory of long-lived compiled programs that touch them.
-    Every public method here is exactly one host→device dispatch; nothing
+    Every public method here is exactly one host→device dispatch (``decode``
+    a second, small one on a step that takes tokens from the host: the merge;
+    ``collect`` none, it fetches); nothing
     in this class knows about requests, arrival times, or health.
 
     The boundary is what makes fleet serving possible as pure host code:
@@ -385,6 +406,17 @@ class SlotWorker:
         # it, so a program's first call and its later ones hand it the same
         # kind of operand: one compile
         self._rng = jax.device_put(jax.random.PRNGKey(seed), self._key_sharding())
+        # the tokens the last decode step sampled stay on the device as well: they
+        # are the NEXT step's token operand, which the scheduler enqueues before it
+        # has fetched them (``decode``). Placed like the key, and the decode program
+        # takes and returns them there: the same kind of operand from its first
+        # call on, one compile. Rows that were idle in that step hold 0
+        self._toks = jax.device_put(np.zeros((self.n_slots,), np.int32), self._key_sharding())
+        # the decode step that is enqueued and not fetched (``decode`` / ``collect``):
+        # its outputs on the device, a routed model's choices, the rows it ran, when
+        # it was handed over and whether that call compiled
+        self._pending = None
+        self._t_fetched = 0.0  # when the last decode step's results reached the host
         # a kept span (telemetry/tracing.py): the allocation programs' traces and
         # compiles end under it, and it under the build's ``startup/build``
         with telemetry.span("cache", keep=True) as sp:
@@ -411,7 +443,15 @@ class SlotWorker:
                             dtype=self.cfg.dtype),
                     out_shardings=self._pool_shardings,
                 )()
-            sp.set_sync((self._cache, self._pool))
+            # the one program beside the four that draw: the host's tokens into the
+            # carried ones, row by row (``decode``). Built and run once here, so no
+            # serving call compiles it
+            wd = telemetry.watchdog
+            self._merge = wd.watch(self._build_merge(), wd.unique_name("serving/token_merge"),
+                                   stable=True)
+            self._toks = self._merge(np.ones((self.n_slots,), np.bool_),
+                                     np.zeros((self.n_slots,), np.int32), self._toks)
+            sp.set_sync((self._cache, self._pool, self._toks))
         # what ONE decode step must read and write of per-sequence state, a live
         # row: the leaves of every layer that keeps any (the layout's own count:
         # every layer of a model with a mixer, the conv or delta layers of one with
@@ -461,6 +501,7 @@ class SlotWorker:
         self._kv_exports: dict[int, object] = {}
         self._kv_imports: dict[int, object] = {}
         self._decode_steps = 0
+        self._decode_steps_ahead = 0  # those enqueued while the step before was unfetched
         # True if ANY dispatch since the scheduler last reset it paid a
         # compilation — the Router's step-latency heartbeat exempts such
         # steps (a cold replica's first step compiles for tens of seconds
@@ -502,7 +543,21 @@ class SlotWorker:
         # all serving programs donate the slot KV cache / prefix pool —
         # XLA-created device buffers, never CPU zero-copy host memory, so
         # donation stays on every backend (utils/donation.py is the gate)
-        return self._keyed_jit(decode, 4, 2)
+        return self._keyed_jit(decode, 4, 2, carried_tokens=True)
+
+    def _build_merge(self):
+        """``where(mask, host, carried)`` over the slots: the token operand of a decode
+        step in which some rows' token is the HOST's (a prefill's first token, an
+        imported or requeued request's, a row that fell idle: 0) and the others' is
+        the one the last step sampled, which only the device has yet. It merges
+        OUTSIDE the decode program, whose operand list stays what it was, and runs
+        only on a step that has such a row. Pinned like the carried key, in and out."""
+        rep = self._key_sharding()
+
+        def token_merge(mask, host, carried):
+            return jnp.where(mask, host, carried)
+
+        return jax.jit(token_merge, in_shardings=(rep, rep, rep), out_shardings=rep)
 
     def _build_verify(self, depth: int, greedy_only: bool = False):
         cfg = self.cfg
@@ -717,18 +772,25 @@ class SlotWorker:
             return SingleDeviceSharding(*on.device_set)
         return NamedSharding(on.mesh, PartitionSpec())
 
-    def _keyed_jit(self, fun, n_head: int, n: int):
+    def _keyed_jit(self, fun, n_head: int, n: int, carried_tokens: bool = False):
         """The jit of a program that draws: ``fun(params, cache, *n_head operands,
         rng, temp, top_k, top_p)`` splits the worker's key ``rng`` and hands the
         carried half back behind the cache, ahead of its ``n`` host-bound values.
         The key's sharding is pinned coming in and going out, as the cache's is
         going out: the key one call returns is the next call's operand, whichever
         program that is, and a key typed by hand (a lowering for the compiler's
-        memory account) lowers to the same module as a served call's."""
+        memory account) lowers to the same module as a served call's.
+        ``carried_tokens`` (the decode program): its first operand, the tokens, and
+        its first host-bound value, the tokens it sampled, are pinned the same way:
+        one step's output is the next step's operand (``_toks``), and a token vector
+        typed by hand lowers to the same module too."""
         cache, *rest = self._outs(n)
         key = self._key_sharding()
+        head = (None,) * n_head
+        if carried_tokens:
+            head, rest = (key, *head[1:]), (key, *rest[1:])
         return donated_jit(fun, donate_argnums=(1,),
-                           in_shardings=(None,) * (2 + n_head) + (key, None, None, None),
+                           in_shardings=(None, None, *head, key, None, None, None),
                            out_shardings=(cache, key, *rest))
 
     # -- dispatches ------------------------------------------------------
@@ -865,9 +927,10 @@ class SlotWorker:
 
     def _run(self, name: str, attrs: dict, program, operands, n_out: int, *,
              key: bool = True, fetch: bool = True, **rows):
-        """The scaffold of the four worker calls (``decode``, ``verify``,
-        ``prefill``, ``chunk``), and the ONE place their spans are opened. A
-        call makes ONE trip into the runtime, the call of its own program:
+        """The scaffold of three worker calls (``verify``, ``prefill``, ``chunk``):
+        ``_dispatch`` then ``_fetch_results`` of the same program's run under one span
+        ``name``. (``decode`` is made of the same two halves, of two different
+        steps.) A call makes ONE trip into the runtime, the call of its own program:
         nothing eager (no program of its own, no upload of its own) runs before
         it. A call is one span ``name`` with two children and, under those, the
         four parts a call's cost beyond the device's work is made of:
@@ -878,6 +941,7 @@ class SlotWorker:
             dispatch/operands ``operands(sp)`` -> (the program's operands behind
                               params and cache, its sampler rows): host
                               conversions only, every one a numpy array or scalar
+                              (or an array the device holds already)
             dispatch/enqueue  the watched program's call: the proxy's bookkeeping,
                               pjit's argument path over the parameter tree, the
                               batched upload of the host operands, the enqueue
@@ -912,58 +976,104 @@ class SlotWorker:
         On the call's span: ``compiled`` (a call that compiled is no latency
         datum, and the scheduler's heartbeat exempts its step), ``h2d``, the
         host arrays handed to the program (every operand behind params and
-        cache but the carried key, a device operand and no upload) and
+        cache that the device does not hold already: not the carried key, not a
+        decode step's carried tokens) and
         ``d2h``, the separate arrays fetched (the experts a ``routing_log`` asks
         for are not among them). Returns ``(span, the n_out fetched arrays or
         None)``; what follows them in the fetch is a routed model's load, noted
         with ``rows``, or an exit gate's mean distribution (``_note_exit``)."""
-        tm = self.telemetry
-        with tm.span(name, **attrs) as sp:
-            with tm.span("dispatch"):
-                with tm.span("operands"):
-                    head, sampler = operands(sp)
-                prog = program()
-                with tm.span("enqueue"):
-                    if key:
-                        self._cache, self._rng, *out = prog(
-                            self.params, self._cache, *head, self._rng, *sampler)
-                    else:
-                        self._cache, *out = prog(self.params, self._cache, *head, *sampler)
-            compiled = bool(prog.last_call_compiled)
-            self.step_compiled |= compiled
-            if compiled:  # a first call outlives the ring: its trace, compile or load, and first run
-                sp.keep = True
-            chosen = None  # a routed model's choices: a device array no fetch waits for
-            if self.cfg.moe_routing == "dropless":
-                *out, chosen = out
-            sp.annotate(compiled=compiled, h2d=len(head) + len(sampler),
-                        d2h=len(out) if fetch else 0)
+        with self.telemetry.span(name, **attrs) as sp:
+            out, chosen = self._dispatch(sp, program, operands, key=key)
             if not fetch:
+                sp.annotate(d2h=0)
                 if self.routing_log is not None and chosen is not None:
                     # an unfetched call's choices, for the check alone: the one wait an
                     # asynchronous call is otherwise spared, and only while the log is on
                     self.routing_log.append({"span": sp.name, **rows,
                                              "chosen": np.asarray(chosen)})
                 return sp, None
-            with tm.span("fetch"):
-                with tm.span("wait"):
-                    for x in out:
-                        x.copy_to_host_async()
-                    jax.block_until_ready(out)
-                with tm.span("copy"):
-                    out = tuple(np.asarray(x) for x in jax.device_get(out))
-            if self.cfg.exit_gate:
-                self._note_exit(sp, out[n_out])
-            else:
-                self._note_load(sp, out[n_out:], chosen, **rows)
-        return sp, out[:n_out]
+            return sp, self._fetch_results(sp, out, chosen, n_out, rows)
 
-    def decode(self, last_tok, pos, wpos, active, temp, top_k, top_p):
-        """Advance EVERY slot one token — THE compile-stable path: a second
-        compilation means an operand's shape/dtype/sharding drifted and
-        every admission would pay a retrace (the watchdog warns or raises
-        per config). Returns host ``(next_token, bad_sentinel)`` [n_slots]
-        arrays."""
+    def _dispatch(self, sp, program, operands, *, key: bool = True, first=None):
+        """The first half of a call under its span ``sp``: the ``dispatch`` span and
+        its parts, the program's call (``first``: what a call runs inside
+        ``dispatch`` ahead of them, a decode step's token merge). Returns ``(the
+        outputs behind the cache and the key, on the device, a routed model's choices
+        or None)`` and notes ``compiled`` and ``h2d`` on ``sp``."""
+        tm = self.telemetry
+        with tm.span("dispatch"):
+            if first is not None:
+                first()
+            with tm.span("operands"):
+                head, sampler = operands(sp)
+            prog = program()
+            with tm.span("enqueue"):
+                if key:
+                    self._cache, self._rng, *out = prog(
+                        self.params, self._cache, *head, self._rng, *sampler)
+                else:
+                    self._cache, *out = prog(self.params, self._cache, *head, *sampler)
+        compiled = bool(prog.last_call_compiled)
+        self.step_compiled |= compiled
+        if compiled:  # a first call outlives the ring: its trace, compile or load, and first run
+            sp.keep = True
+        chosen = None  # a routed model's choices: a device array no fetch waits for
+        if self.cfg.moe_routing == "dropless":
+            *out, chosen = out
+        sp.annotate(compiled=compiled,
+                    h2d=sum(isinstance(x, (np.ndarray, np.generic)) for x in (*head, *sampler)))
+        return out, chosen
+
+    def _fetch_results(self, sp, out, chosen, n_out: int, rows: dict):
+        """The second half of a call under the span ``sp``: the ``fetch`` span and its
+        two parts over ``out``, a dispatch's host-bound outputs. Notes ``d2h`` and what
+        follows the ``n_out`` results (a routed model's load with ``rows``, the rows of
+        the run that PRODUCED it; an exit gate's distribution) on ``sp`` and returns
+        the results as numpy."""
+        tm = self.telemetry
+        with tm.span("fetch"):
+            with tm.span("wait"):
+                for x in out:
+                    x.copy_to_host_async()
+                jax.block_until_ready(out)
+            with tm.span("copy"):
+                out = tuple(np.asarray(x) for x in jax.device_get(out))
+        sp.annotate(d2h=len(out))
+        if self.cfg.exit_gate:
+            self._note_exit(sp, out[n_out])
+        else:
+            self._note_load(sp, out[n_out:], chosen, **rows)
+        return out[:n_out]
+
+    def decode(self, toks, from_host, pos, wpos, active, temp, top_k, top_p, *,
+               rows_discarded: int = 0):
+        """ENQUEUE one decode step over every slot, then FETCH the step enqueued
+        before it, if one is unfetched: the host's side of a step (operands,
+        enqueue, copy back, and whatever the scheduler does between two calls) runs
+        while the device works on a step that was queued before it began. THE
+        compile-stable path: a second compilation means an operand's
+        shape/dtype/sharding drifted and every admission would pay a retrace (the
+        watchdog warns or raises per config).
+
+        The token operand stays on the device: ``_toks``, the tokens the last
+        enqueued step sampled (0 in its idle rows). ``from_host`` [n_slots] bool
+        marks the rows whose token the HOST knows instead, ``toks`` [n_slots]: there
+        ``_build_merge``'s program puts them in first (span ``dispatch/merge``); a
+        step with no such row takes ``_toks`` as the last step returned it.
+
+        One span ``decode`` a call, as for the other calls, but of TWO device steps:
+        its ``dispatch`` (``operands``, ``merge`` where it ran, ``enqueue``) is the
+        step handed over, its ``fetch`` (``wait``, ``copy``) the step before. What the
+        span says of the rows (``n_active``, ``cached_tokens``, ...) is the enqueued
+        step's; a routed model's load and an exit gate's distribution come with the
+        fetch and are the fetched step's, as are the ``pos`` / ``active`` rows a
+        ``routing_log`` entry pairs the choices with. ``ahead``: a step was unfetched
+        when this one was enqueued (the device had work queued throughout);
+        ``rows_discarded`` (where not 0): the rows of the fetched step that the
+        scheduler drops, their request having ended since they were enqueued. With
+        nothing unfetched the span has no ``fetch`` and the call returns None;
+        otherwise host ``(next_token, bad_sentinel)`` [n_slots] of the FETCHED
+        step."""
         tm = self.telemetry
         # ``cached_tokens``: the cache positions the step attends to, summed
         # over its live rows (row at ``pos`` reads [0, pos]); ``attn``: the form
@@ -976,25 +1086,78 @@ class SlotWorker:
         # kernel's walk fetched for the live rows, whole blocks of ``kv_block``
         n_active = int(np.count_nonzero(active))
         live_pos = np.asarray(pos)[np.asarray(active, bool)]
+        prev = self._pending
         attrs = dict(n_active=n_active, cached_tokens=int(np.sum(live_pos + 1)),
                      attn=tfm.cache_step_form(self.cfg), **self._state_attrs(n_active),
-                     **self._ring_attrs(live_pos), **self.pass_attrs)
+                     **self._ring_attrs(live_pos), **self.pass_attrs, ahead=prev is not None)
         if self.kv_block:
             attrs["kv_rows_fetched"] = tfm.kv_rows_fetched(live_pos, self.kv_block)
-        # host arrays straight into the jitted call (pjit batches the uploads;
-        # ``h2d`` counts them: seven here), the carried key between them as the
-        # one device operand; dtypes are pinned by the engine's per-slot state
-        # arrays. The operand list is pinned too (``_run``)
-        sp, (nxt, bad) = self._run(
-            "decode", attrs, self._decode_prog,
-            lambda sp: ((last_tok, pos, np.asarray(wpos, np.int32), active),
-                        self._sampler_rows(sp, temp, top_k, top_p)),
-            2, pos=np.array(pos), active=np.array(active, bool))
+
+        def operands(sp):
+            # host arrays straight into the jitted call (pjit batches the uploads;
+            # ``h2d`` counts them: six here, and the merge's two where it ran), the
+            # carried tokens and the carried key the two device operands; dtypes are
+            # pinned by the engine's per-slot state arrays. The operand list is
+            # pinned too (``_run``)
+            return ((self._toks, pos, np.asarray(wpos, np.int32), active),
+                    self._sampler_rows(sp, temp, top_k, top_p))
+
+        def merge():
+            with tm.span("merge"):
+                self._toks = self._merge(np.asarray(from_host, np.bool_),
+                                         np.asarray(toks, np.int32), self._toks)
+
+        with tm.span("decode", **attrs) as sp:
+            merged = bool(np.any(from_host))
+            t_enqueue = time.perf_counter()
+            out, chosen = self._dispatch(sp, self._decode_prog, operands,
+                                         first=merge if merged else None)
+            self._toks = out[0]
+            self._pending = (out, chosen,  # ``span``: the log's word for the step, whoever fetches
+                             dict(span="decode", pos=np.array(pos), active=np.array(active, bool)),
+                             t_enqueue, sp.attrs["compiled"])
+            if merged:
+                sp.annotate(h2d=sp.attrs["h2d"] + 2, merged=True)
+            fetched = self._fetch_pending(sp, prev, rows_discarded)
         self._decode_steps += 1
-        if not sp.attrs["compiled"]:
-            tm.histogram("serving/decode_step_sec").observe(sp.dur_s)
         tm.counter("serving/decode_steps").inc()
-        return nxt, bad
+        if prev is not None:
+            self._decode_steps_ahead += 1
+            tm.counter("serving/decode_steps_ahead").inc()
+        return fetched
+
+    def collect(self, *, rows_discarded: int = 0):
+        """Fetch the decode step that is enqueued and unfetched, and enqueue nothing:
+        the scheduler's call where the next step needs this one's tokens on the host
+        first (a drafter, an armed fault injector), where no slot continues, and
+        before ``drain()`` / ``serve()`` return. Its span is ``collect``, with a
+        ``fetch`` and nothing else (so a ``decode`` span is a device step, one each).
+        Returns host ``(next_token, bad_sentinel)``, or None where nothing is
+        unfetched (no span then)."""
+        prev, self._pending = self._pending, None
+        if prev is None:
+            return None
+        with self.telemetry.span("collect") as sp:
+            return self._fetch_pending(sp, prev, rows_discarded)
+
+    def _fetch_pending(self, sp, prev, rows_discarded: int = 0):
+        """``_fetch_results`` of ``prev``, a ``_pending`` decode step (None: nothing, and
+        ``d2h`` 0), under ``sp``; its time feeds ``serving/decode_step_sec``: from
+        when the device could begin it (its enqueue, or the fetch of the step before
+        where that came later) until its results were on the host."""
+        if prev is None:
+            sp.annotate(d2h=0)
+            return None
+        if rows_discarded:
+            sp.annotate(rows_discarded=rows_discarded)
+        out, chosen, rows, t_enqueue, compiled = prev
+        fetched = self._fetch_results(sp, out, chosen, 2, rows)
+        done = time.perf_counter()
+        if not compiled:
+            self.telemetry.histogram("serving/decode_step_sec").observe(
+                done - max(t_enqueue, self._t_fetched))
+        self._t_fetched = done
+        return fetched
 
     def verify(self, depth: int, toks, pos, wpos, active, temp, top_k, top_p,
                greedy_only: bool = False, warm: bool = False):
@@ -1214,6 +1377,7 @@ class SlotWorker:
             "decode": int(self._decode._cache_size()) if self._decode is not None else 0,
             "prefill": {b: int(f._cache_size()) for b, f in sorted(self._prefills.items())},
             "decode_steps": self._decode_steps,
+            "decode_steps_ahead": self._decode_steps_ahead,
         }
         if self._chunk_progs:
             out["chunk_prefill"] = {w: int(f._cache_size())
@@ -1238,6 +1402,7 @@ class SlotWorker:
                                 for w, f in sorted(self._kv_imports.items())}
         if self._poison is not None:
             out["fill_slot"] = int(self._poison._cache_size())
+        out["token_merge"] = int(self._merge._cache_size())
         return out
 
 
@@ -1556,6 +1721,12 @@ class ServingEngine:
         self._uid_tenant: dict[int, str] = {}
         self._slot_faults = np.zeros((n,), np.int32)
         self._quarantined_slots: set[int] = set()
+        # the decode step the device has and the host has not fetched (``_step``:
+        # the scheduler runs ONE step ahead), and how many requests have left each
+        # slot (``_release_slot``): what tells a row of that step whose request is
+        # still there from one that ran for nothing
+        self._flight: Optional[_Flight] = None
+        self._slot_epoch = np.zeros((n,), np.int64)
         # uids exempt from queue-bound accounting: a Router's failover /
         # drain requeues were already accepted once — like quarantine
         # replays, they are neither shed nor allowed to displace arrivals
@@ -1972,7 +2143,8 @@ class ServingEngine:
         Contract: each value is a READ-ONLY window (``_TokensSoFar``) on the
         slot's list of Python ``int``s, as long as the list was at the call —
         a later ``step()`` neither grows nor changes it; ``list(v)`` gives a
-        list of one's own (``json`` encodes nothing else). Cost: one small
+        list of one's own (``json`` encodes nothing else). A token is here one
+        ``step()`` after the one that enqueued its decode step (``_step``). Cost: one small
         object a live slot; no per-token work, not even a copy (on the chip's
         host the copies read 0.33 ms a step at 128 slots x ~300 tokens)."""
         return {st.uid: _TokensSoFar(st.tokens)
@@ -2029,7 +2201,7 @@ class ServingEngine:
     @property
     def idle(self) -> bool:
         return (not self._queue and not self._prefilling
-                and not self._active.any()
+                and not self._active.any() and self._flight is None
                 and not self._handoffs and not self._imports)
 
     @property
@@ -2536,6 +2708,7 @@ class ServingEngine:
             self._pfx.release(st.prefix_entry)
         self._slots[slot] = _Slot()
         self._active[slot] = False
+        self._slot_epoch[slot] += 1  # a row still in flight for it now runs for nothing
         # pos 0 is the freed slot's ATTENTION position only (cheapest for the
         # length-aware decode kernel); its decode WRITE goes to wpos=Smax and
         # is dropped by the scatter — never park the write in range (step())
@@ -2735,13 +2908,80 @@ class ServingEngine:
             tm.counter("resilience/failed_requests").inc()
             self._synth_result(req, "failed_nan", slot=slot)
 
-    def _step_decode(self, wpos):
-        """Advance every active slot ONE token through the decode program —
-        the legacy (and speculation-off) device step."""
-        nxt, bad = self.worker.decode(
-            self._last_tok, self._pos, wpos, self._active,
-            self._temp, self._top_k, self._top_p)
-        self._emit(self._emit_decoded, nxt, bad)
+    def _advance_flight(self, ahead: bool) -> None:
+        """One decode step handed to the device and the one in flight (if any)
+        fetched and emitted; ``ahead``: in that order, else the step in flight is
+        only collected and nothing is enqueued (the caller does that once the host
+        holds every token).
+
+        What the next step needs is known WITHOUT the fetch: the rows of the step in
+        flight whose request is still there (``kept``) move on by one position, those
+        among them that this exhausts (``remaining``) end by length and are left
+        out, and a slot activated since joins with the token the host has (a
+        prefill's first, an import's). Only the tokens of the kept rows are the
+        device's alone: they stay there (``SlotWorker._toks``) and ``from_host``
+        marks every other row whose token changes, an ended row's to 0, so the
+        program's operands are to the bit what they would be had the host fetched
+        first. Not known: an EOS, a ``bad`` sentinel. A row enqueued for a request
+        that one of those (or a cancel, an eviction) has ended since is dropped
+        when its step is emitted; its K/V write is harmless, since whatever enters
+        the slot later is enqueued behind it."""
+        fl = self._flight
+        kept = fl.active & (fl.epoch == self._slot_epoch)
+        dropped = int(np.count_nonzero(fl.active & ~kept))
+        fetched = None
+        if ahead:
+            last = np.fromiter((st.remaining <= 1 for st in self._slots), np.bool_, self.n_slots)
+            active = self._active & ~(kept & last)
+            if active.any():
+                fetched = self._enqueue_decode(
+                    active, self._pos + kept, ~(kept & active) & (fl.active | active), dropped)
+        if fetched is None:
+            fetched = self.worker.collect(rows_discarded=dropped)
+            self._flight = None
+        if dropped:
+            self.telemetry.counter("serving/decode_rows_discarded").inc(dropped)
+            for slot in map(int, np.flatnonzero(fl.active & ~kept & fetched[1])):
+                # a dropped row's sentinel: nobody is there to quarantine, but the
+                # position it wrote may hold NaN K/V; scrub while the slot is empty
+                # (an occupant's own sentinel catches it otherwise)
+                if slot in self._free:
+                    self.worker.fill_slot(slot, 0.0)
+        self._emit(self._emit_decoded, kept, *fetched)
+
+    def _enqueue_decode(self, active, pos, from_host, rows_discarded: int = 0):
+        """``worker.decode`` over the rows ``active`` at ``pos``: enqueues the step,
+        makes it the one in flight, and returns what the call fetched of the step
+        before (None: nothing was unfetched; ``rows_discarded``: how many of that
+        step's rows the scheduler will drop, for the call's span). Every operand is
+        an array of this call's own: the slots' state arrays change under the
+        device's feet otherwise, the call being asynchronous. Rows outside ``active`` read as a
+        freed slot's do (token 0, greedy), whatever their slot still holds."""
+        self._note_device_step(int(np.count_nonzero(active)))
+        # inactive slots WRITE at position Smax — the cache scatter's
+        # mode="drop" discards their garbage KV entirely. Writing at 0 (the
+        # pre-chunked-prefill scheme) corrupted PREFILLING slots — a slot
+        # mid-admission already holds its prefix KV at position 0, and
+        # decode steps run interleaved with its remaining chunks. Their
+        # ATTENTION position is 0, so the length-aware decode kernel never
+        # streams the full cache for them.
+        def rows(state, idle):
+            return np.where(active, state, idle).astype(state.dtype, copy=False)
+
+        pos = rows(np.asarray(pos, np.int32), 0)
+        fetched = self.worker.decode(
+            rows(self._last_tok, 0), from_host, pos, rows(pos, self.Smax), active,
+            rows(self._temp, 0), rows(self._top_k, 0), rows(self._top_p, 1),
+            rows_discarded=rows_discarded)
+        self._flight = _Flight(active=active, epoch=self._slot_epoch.copy())
+        return fetched
+
+    def _note_device_step(self, n_active: int) -> None:
+        """The gauges of a device step (decode or verify) over ``n_active`` rows."""
+        tm = self.telemetry
+        tm.gauge("serving/active_slots").set(n_active)
+        tm.histogram("serving/queue_depth_hist").observe(len(self._queue))
+        tm.histogram("serving/slot_occupancy").observe(n_active / self.n_slots)
 
     def _emit(self, bookkeeping, *fetched) -> None:
         """The host's work after a device step's fetch, under one span:
@@ -2751,12 +2991,12 @@ class ServingEngine:
             sp.annotate(tokens=bookkeeping(*fetched),
                         finished=len(self._terminal_uids) - done0)
 
-    def _emit_decoded(self, nxt, bad) -> int:
+    def _emit_decoded(self, rows, nxt, bad) -> int:
+        """The tokens of a fetched decode step onto the requests of ``rows``
+        [n_slots] bool: the rows it ran whose request is still in its slot."""
         tm = self.telemetry
         emitted = 0
-        for slot in range(self.n_slots):
-            if not self._active[slot]:
-                continue
+        for slot in map(int, np.flatnonzero(rows)):
             st = self._slots[slot]
             if bad[slot]:
                 # non-finite logits: the slot's KV/state is poisoned. The
@@ -2884,11 +3124,15 @@ class ServingEngine:
 
     def step(self, now: float | None = None, *,
              enforce_deadlines: bool = True) -> list[int]:
-        """One scheduler iteration: sweep deadlines and shed queue overflow,
+        """One scheduler iteration: enqueue the next decode step behind the one
+        in flight and fetch and emit that one (``_step``: the scheduler runs ONE
+        step ahead), sweep deadlines and shed queue overflow,
         admit arrived requests, advance at most ``chunks_per_step`` admission
         chunks (round-robin over prefilling slots — active slots never stall
-        behind a long prompt), then advance every active slot by one token
-        (one device call). Returns the uids that reached a TERMINAL state
+        behind a long prompt), and with nothing in flight enqueue a decode step
+        (one device call, not waited for: its tokens are appended, and show in
+        ``live_progress()``, in the NEXT iteration). Returns the uids that reached
+        a TERMINAL state
         since the last step() returned — finished ok, expired, shed,
         deadline-evicted, cancelled, or failed — so a caller driving the
         scheduler directly never waits forever on a degraded request.
@@ -2903,8 +3147,29 @@ class ServingEngine:
             return self._step(now, enforce_deadlines)
 
     def _step(self, now: float, enforce_deadlines: bool) -> list[int]:
+        """The scheduler runs ONE decode step ahead of the host's copy of the
+        tokens. The order of an iteration:
+
+          1. a step is in flight (k) and nothing needs its tokens first: enqueue
+             step k + 1 for the slots that continue (``_advance_flight``);
+          2. fetch step k and emit it against the rows that produced it;
+          3. sweep, admit (a one-shot prefill stays a synchronous call, queued
+             behind step k + 1), advance admission chunks;
+          4. nothing is in flight now (the first step, or 1 did not run): enqueue a
+             step, and return without waiting for it.
+
+        So the host's side of a step, and whatever its caller does between two
+        iterations, runs while the device works on a step queued before it began; a
+        token reaches the host (``live_progress()``, the returned uids) one
+        iteration after its step was enqueued. Depth is 0 or 1 by what the step
+        holds, no switch: 1 does not run, and the step in flight is collected
+        first, where the next step cannot be planned without its tokens on the
+        host: with a drafter (it proposes from them, and ``verify`` takes the host's
+        tokens) and with a fault injector armed (it picks its victim by them)."""
         tm = self.telemetry
         self.worker.step_compiled = False  # fresh heartbeat window
+        if self._flight is not None:
+            self._advance_flight(ahead=self._drafter is None and self._inj is None)
         with tm.span("sweep"):
             self._maybe_sample_rings(now)
             if self._incidents is not None and self._incidents.pending \
@@ -2929,17 +3194,21 @@ class ServingEngine:
                     slots = sorted(self._prefilling)
                     self._advance_prefill(slots[self._rr % len(slots)])
                     self._rr += 1
+        if self._flight is None and self._active.any():
+            self._step_from_host()
         if not self._active.any():
             # the occupancy gauge must read 0 once the engine idles — the
             # bench's slot-leak check watches exactly this
             tm.gauge("serving/active_slots").set(0)
-            finished = self._terminal_uids
-            self._terminal_uids = []
-            return finished
-        n_active = int(self._active.sum())
-        tm.gauge("serving/active_slots").set(n_active)
-        tm.histogram("serving/queue_depth_hist").observe(len(self._queue))
-        tm.histogram("serving/slot_occupancy").observe(n_active / self.n_slots)
+        finished = self._terminal_uids
+        self._terminal_uids = []
+        return finished
+
+    def _step_from_host(self) -> None:
+        """A device step with nothing in flight: the host holds every active slot's
+        token. A ``verify`` where a drafter proposed (synchronous: it is fetched and
+        emitted here), else a decode step, enqueued and left in flight."""
+        tm = self.telemetry
         if self._inj is not None:
             # decode-phase fault injection: NaN-poison the chosen request's
             # slot KV BEFORE the decode dispatch, so THIS decode genuinely
@@ -2950,32 +3219,22 @@ class ServingEngine:
                         st.uid, "decode", len(st.tokens) - 1):
                     self.worker.fill_slot(slot, float("nan"))
                     tm.counter("resilience/injected_faults").inc()
-        # inactive slots WRITE at position Smax — the cache scatter's
-        # mode="drop" discards their garbage KV entirely. Writing at 0 (the
-        # pre-chunked-prefill scheme) corrupted PREFILLING slots — a slot
-        # mid-admission already holds its prefix KV at position 0, and
-        # decode steps run interleaved with its remaining chunks. Their
-        # ATTENTION position stays self._pos (0 when idle), so the
-        # length-aware decode kernel never streams the full cache for them.
-        wpos = np.where(self._active, self._pos, np.int32(self.Smax))
         drafts: dict[int, np.ndarray] = {}
         if self._drafter is not None:
             with tm.span("draft") as sp:
                 drafts = self._draft()
                 sp.annotate(slots=len(drafts))
         if drafts:
-            self._step_verify(drafts, wpos)
+            self._note_device_step(int(self._active.sum()))
+            # idle rows write at Smax (dropped) and attend at 0: ``_enqueue_decode``
+            self._step_verify(drafts, np.where(self._active, self._pos, np.int32(self.Smax)))
         else:
             # no slot drafted this step (speculation off, or the histories
             # have no n-gram match yet): the plain ONE-token decode program
             # — the non-speculative path stays exercised, and a spec-enabled
             # engine pays ZERO verify overhead on draft-less steps
-            self._step_decode(wpos)
-        if not self._active.any():
-            tm.gauge("serving/active_slots").set(0)
-        finished = self._terminal_uids
-        self._terminal_uids = []
-        return finished
+            self._enqueue_decode(self._active.copy(), self._pos,
+                                 np.ones((self.n_slots,), np.bool_))
 
     def _draft(self) -> dict[int, np.ndarray]:
         """Each active slot's proposal for this step, where it has one."""
@@ -3017,12 +3276,14 @@ class ServingEngine:
         return drafts
 
     def drain(self) -> dict[int, RequestResult]:
-        """Run steps until queue and slots are empty (ignoring arrival
+        """Run steps until queue and slots are empty and no decode step is
+        unfetched (ignoring arrival
         times, deadlines AND the queue bound — drain's ``now=inf`` clock
         would otherwise expire every deadline-bearing request and shed
         every future-dated one as a simultaneous arrival); return all
         results so far."""
-        while self._queue or self._prefilling or self._active.any():
+        while (self._queue or self._prefilling or self._active.any()
+               or self._flight is not None):  # nothing stays unfetched
             self.step(now=float("inf"), enforce_deadlines=False)
         if self._incidents is not None and self._incidents.pending:
             # drain's now=inf never ticks the recorder (non-finite clock);
@@ -3057,6 +3318,10 @@ class ServingEngine:
                 if wait > 0:
                     time.sleep(min(wait, 0.05))
             self.step()
+        # nothing stays unfetched: a step still in flight is other requests' (they
+        # keep their tokens, and stay in flight if they outlive this call)
+        if self._flight is not None:
+            self._advance_flight(ahead=False)
         return {u: self._results[u] for u in target}
 
     # -- observability --------------------------------------------------

@@ -75,8 +75,12 @@ def test_spans_and_pools_say_what_was_read(served):
     decodes = [sp for sp in spans if sp.name == "decode"]
     assert {sp.attrs["attn"] for sp in prefills} == {"dense+window"}
     assert {sp.attrs["attn"] for sp in decodes} == {"dense+ring"}
-    for sp in prefills + decodes:
-        assert sp.attrs["window_layers"] == 5 and sp.attrs["experts_held"] == 4
+    assert all(sp.attrs["window_layers"] == 5 for sp in prefills + decodes)
+    # a decode call fetches the step BEFORE it (PR 60): what comes with a fetch is on all but a burst's first
+    fetched = [sp for sp in decodes if sp.attrs["d2h"]]
+    assert len(fetched) > len(decodes) / 2
+    for sp in prefills + fetched:
+        assert sp.attrs["experts_held"] == 4
         assert 0 < sp.attrs["experts_touched"] <= 4 and sp.attrs["expert_rows_held"] > 0
     by_len = {sp.attrs["true_len"]: sp for sp in prefills}
     for p in prompts:  # a prefill's queries each read min(position + 1, window) of a ring

@@ -362,7 +362,10 @@ def test_serving_engine_serves_and_its_spans_say_what_was_read(cfg, program):
     assert all(sp.attrs["cached_tokens"] >= 40 * sp.attrs["n_active"] for sp in spans["decode"])
     # the 150-token prompt's chunks: each reads what lay before it and its own live rows
     assert sorted(sp.attrs["cached_tokens"] for sp in spans["chunk"])[-1] == 150
-    for sp in spans["prefill"] + spans["decode"]:  # the routed attributes, for this router too
+    # a decode call fetches the step BEFORE it (PR 60): what comes with a fetch is on all but a burst's first
+    fetched = [sp for sp in spans["decode"] if sp.attrs["d2h"]]
+    assert len(fetched) > len(spans["decode"]) / 2
+    for sp in spans["prefill"] + fetched:  # the routed attributes, for this router too
         assert sp.attrs["expert_load_max_over_mean"] >= 1.0
         assert 0 < sp.attrs["experts_touched"] <= cfg.num_experts
 

@@ -138,7 +138,12 @@ def test_spans_and_pools_say_what_was_kept_and_read(served):
     assert len(prefills) == 5 and decodes
     for sp in prefills + decodes:
         assert sp.attrs["conv_layers"] == 5 and sp.attrs["attn_layers"] == 2
-        assert sp.attrs["attn"] == "dense" and 0 < sp.attrs["experts_touched"] <= 16
+        assert sp.attrs["attn"] == "dense"
+    # a decode call fetches the step BEFORE it (PR 60): what comes with a fetch is on all but a burst's first
+    fetched = [sp for sp in decodes if sp.attrs["d2h"]]
+    assert len(fetched) > len(decodes) / 2
+    for sp in prefills + fetched:
+        assert 0 < sp.attrs["experts_touched"] <= 16
         assert sp.attrs["expert_load_max_over_mean"] >= 1
     for sp in decodes:
         assert sp.attrs["state_rows"] == sp.attrs["n_active"]

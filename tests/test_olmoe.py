@@ -426,7 +426,11 @@ def test_serving_engine_serves_and_its_spans_carry_the_load(cfg, program):
     assert srv.compile_counts()["decode"] == 1
     calls = [sp for sp in tracing.spans(t0) if sp.name in ("prefill", "decode")]
     assert {sp.name for sp in calls} == {"prefill", "decode"}
+    # a decode call fetches the step BEFORE it (PR 60): what comes with a fetch is on all but a burst's first
+    assert [sp.attrs["d2h"] for sp in calls if sp.name == "decode"] == [0, 3, 3, 3]
     for sp in calls:
+        if not sp.attrs["d2h"]:
+            continue
         assert 1.0 <= sp.attrs["expert_load_max_over_mean"] <= cfg.num_experts
         assert 0 < sp.attrs["experts_touched"] <= cfg.num_experts
     gauges = srv.telemetry.registry.snapshot()["gauges"]

@@ -145,8 +145,12 @@ def test_spans_gauges_and_pools_say_the_passes(served, program):
     prefills = [sp for sp in spans if sp.name == "prefill"]
     decodes = [sp for sp in spans if sp.name == "decode"]
     assert len(prefills) == 5 and decodes
+    # a decode call fetches the step BEFORE it (PR 60): what comes with a fetch is on all but a burst's first
+    assert sum(not sp.attrs["d2h"] for sp in decodes) < len(decodes) / 2
     for sp in prefills + decodes:
         assert sp.attrs["layer_passes"] == 3 and sp.attrs["cache_layers"] == 6
+        if sp.name == "decode" and not sp.attrs["d2h"]:
+            continue
         assert 1.0 <= sp.attrs["exit_pass_mean"] <= 3.0 and sp.attrs["d2h"] == 3
         cdf = sp.attrs["exit_cdf"]
         assert len(cdf) == 2 and 0 <= cdf[0] <= cdf[1] <= 1

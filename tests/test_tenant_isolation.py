@@ -205,10 +205,10 @@ def test_tenant_mix_adds_zero_programs_and_keeps_parity(engine):
         srv.submit(Request(uid=10 + i, prompt=p, max_new_tokens=8,
                            tenant=tenants[i]))
     res = srv.drain()
-    # the tenant axis is host-only: not one new program (decode_steps is
-    # a step counter, not a program count — it keeps ticking)
+    # the tenant axis is host-only: not one new program (decode_steps and
+    # decode_steps_ahead are step counters, not program counts — they keep ticking)
     def _programs(cc):
-        return {k: v for k, v in cc.items() if k != "decode_steps"}
+        return {k: v for k, v in cc.items() if not k.startswith("decode_steps")}
     assert _programs(srv.compile_counts()) == _programs(warm)
     for i, p in enumerate(prompts):
         ref = engine.generate(p[None], max_new_tokens=8)[0]

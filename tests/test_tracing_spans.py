@@ -184,9 +184,13 @@ PARENTS = {
     "sweep": {"serve/step"}, "admit": {"serve/step"}, "chunks": {"serve/step"},
     "draft": {"serve/step"}, "emit": {"serve/step"},
     "decode": {"serve/step"}, "verify": {"serve/step"},
+    # the fetch of a decode step that no later step was enqueued behind (PR 60: a ``decode``
+    # span is the DISPATCH of one step and the fetch of the step before)
+    "collect": {"serve/step"},
     "prefill": {"admit"}, "chunk": {"chunks", "admit"},
-    "dispatch": set(WORKER_CALLS), "fetch": set(WORKER_CALLS),
+    "dispatch": set(WORKER_CALLS), "fetch": {*WORKER_CALLS, "collect"},
     "operands": {"dispatch"}, "enqueue": {"dispatch"},
+    "merge": {"dispatch"},  # a decode step's host tokens into the carried ones
     "wait": {"fetch"}, "copy": {"fetch"},
     # a program's first call traces, lowers and compiles (or loads) inside enqueue;
     # the prefix pool's copies are called from admit and emit, under no span of their own
@@ -238,12 +242,19 @@ def test_serving_spans(tiny_serving_engine, mode):
         assert sp.t0 <= sp.t1
         if up is not None:
             assert sp.path == f"{up.path}/{sp.name}" and up.t0 <= sp.t0 and sp.t1 <= up.t1
+    fetches = lambda sp: any(c.name == "fetch" for c in kids.get(sp.id, []))
     for st in steps:
-        names = [c.name for c in kids.get(st.id, [])]
-        assert names[:2] == ["sweep", "admit"] and names.count("sweep") == 1
+        below = kids.get(st.id, [])
+        names = [c.name for c in below]
+        # a step in flight is dealt with first (the next enqueued and it fetched, or it
+        # collected) and emitted; then the sweep and the admissions; a step is enqueued last
+        at = names.index("sweep")
+        assert names[at:at + 2] == ["sweep", "admit"] and names.count("sweep") == 1
+        assert names[:at] in ([], ["decode", "emit"], ["collect", "emit"])
         assert names.count("decode") + names.count("verify") <= 1
-        # host bookkeeping follows every device step, and only a device step
-        assert names.count("emit") == names.count("decode") + names.count("verify")
+        # host bookkeeping follows every fetch of a device step, and only that
+        assert names.count("emit") == sum(fetches(c) for c in below)
+        assert all(b.name == "emit" for a, b in zip(below, below[1:]) if fetches(a))
 
     # a call that compiled is kept with its xla/* spans (they outlive the ring);
     # no steady span is
@@ -261,8 +272,13 @@ def test_serving_spans(tiny_serving_engine, mode):
     for sp in calls:
         under = [c.name for c in kids.get(sp.id, [])]
         want = ["dispatch"] if sp.attrs.get("fetch") is False else ["dispatch", "fetch"]
+        if sp.name == "decode" and sp.attrs["d2h"] == 0:  # nothing was unfetched behind it
+            want = ["dispatch"]
         assert under == want, (sp.path, under)
         assert isinstance(sp.attrs["compiled"], bool)
+    # every decode step is fetched once: by the decode call after it, or collected
+    collects = [sp for sp in spans if sp.name == "collect"]
+    assert all([c.name for c in kids[sp.id]] == ["fetch"] for sp in collects)
 
     # the latency histograms hold exactly the calls that did not compile
     def timed(kind):
@@ -273,10 +289,20 @@ def test_serving_spans(tiny_serving_engine, mode):
                        ("verify", "serving/verify_step_sec"),
                        ("chunk", "serving/chunk_prefill_sec")):
         assert hist.get(name, {"count": 0})["count"] == len(timed(kind)), name
-        if timed(kind):
+        if timed(kind) and kind != "decode":
             np.testing.assert_allclose(hist[name]["sum"], sum(sp.dur_s for sp in timed(kind)))
     decodes = [sp for sp in calls if sp.name == "decode"]
     assert len(decodes) == counters.get("serving/decode_steps", 0)
+    # a decode step's datum runs from when the device could begin it (its enqueue, or the
+    # fetch before it) to its own fetch, in a LATER span: no more than from its span's start
+    # to the end of the span that fetched it
+    fetched_by = sorted([sp for sp in decodes if sp.attrs["d2h"]] + collects, key=lambda sp: sp.t0)
+    assert len(fetched_by) == len(decodes)
+    if timed("decode"):
+        assert 0 < hist["serving/decode_step_sec"]["sum"] <= sum(
+            b.t1 - a.t0 for a, b in zip(decodes, fetched_by) if not a.attrs["compiled"])
+    assert counters.get("serving/decode_steps_ahead", 0) == sum(sp.attrs["ahead"] for sp in decodes)
+    assert (mode == "speculation") == (not any(sp.attrs["ahead"] for sp in decodes))
     assert all(1 <= sp.attrs["n_active"] <= 2 for sp in decodes)
     emitted = sum(sp.attrs["tokens"] for sp in spans if sp.name == "emit")
     finished = sum(sp.attrs["finished"] for sp in spans if sp.name == "emit")
@@ -311,8 +337,8 @@ def test_serving_spans(tiny_serving_engine, mode):
 
     if mode == "plain":
         assert {sp.name for sp in spans} - {sp.name for sp in xla} == {
-            "serve/step", "sweep", "admit", "prefill", "decode", "dispatch", "operands",
-            "enqueue", "fetch", "wait", "copy", "emit"}
+            "serve/step", "sweep", "admit", "prefill", "decode", "dispatch", "merge", "operands",
+            "enqueue", "fetch", "wait", "copy", "emit", "collect"}
         assert len(prefills) == len(reqs) and not chunks
     if mode == "chunked":
         assert not prefills and {by_id[sp.parent].name for sp in chunks} == {"chunks"}
@@ -398,9 +424,11 @@ def test_worker_call_outside_step_has_a_short_path(tiny_serving_engine):
 
 # call kind -> (serving mode that makes it, request keywords, host operands, device operands,
 # arrays fetched): what the call hands its program behind params and cache, by where it lives
-# when handed over (the one device operand is the carried key: no upload, so not in ``h2d``)
+# when handed over (the one device operand is the carried key: no upload, so not in ``h2d``;
+# a decode step's tokens are carried too since PR 60, and where the host has some of them a
+# merge program takes two uploads of its own first: ``merged``, ``h2d`` 8)
 ANATOMY = {
-    "decode": ("plain", {}, 7, 1, 2),
+    "decode": ("plain", {}, 6, 2, 2),
     "prefill": ("plain", {}, 6, 1, 2),  # prompt, slot, true_len ride the program's own upload
     "chunk": ("chunked", {}, 7, 1, 2),
     "verify": ("speculation", {}, 4, 0, 1),  # greedy: no key, no sampler rows, ONE packed array
@@ -447,9 +475,14 @@ def test_worker_call_has_four_parts_in_order(tiny_serving_engine, kind):
     for call in calls:
         dispatch, *rest = kids[call.id]
         parts = kids[dispatch.id]
+        if call.attrs.get("merged"):  # a decode step's host tokens go in first
+            assert parts[0].name == "merge" and parts[0].path == f"{dispatch.path}/merge"
+            assert dispatch.t0 <= parts[0].t0 and parts[0].t1 <= parts[1].t0
+            parts = parts[1:]
         assert [sp.name for sp in parts] == ["operands", "enqueue"], call.path
         groups = [(dispatch, parts)]
-        if call.attrs.get("fetch") is False:
+        if call.attrs.get("fetch") is False or (name == "decode" and not rest):
+            # a chunk left asynchronous; a decode step with no step unfetched before it
             assert not rest and call.attrs["d2h"] == 0
         else:
             (fetch,) = rest
@@ -461,21 +494,28 @@ def test_worker_call_has_four_parts_in_order(tiny_serving_engine, kind):
             assert all(a.t1 <= b.t0 for a, b in zip(below, below[1:]))
             assert all(sp.path == f"{up.path}/{sp.name}" for sp in below)
             lookup = below[1].t0 - below[0].t1 if up is dispatch else 0.0
-            uncovered.append(up.dur_s - sum(sp.dur_s for sp in below) - lookup)
+            merge = below[0].t0 - up.t0 if up is dispatch and call.attrs.get("merged") else 0.0
+            uncovered.append(up.dur_s - sum(sp.dur_s for sp in below) - lookup - merge)
     # what no part covers is a few Python statements: microseconds, on any machine
     assert np.median(uncovered) < 2e-4, np.median(uncovered)
     assert not any(sp.name == "key" for sp in spans)
     if kind == "chunk":
         assert any(c.attrs["fetch"] is False for c in calls)
+    if kind == "decode":  # its fetch is the step BEFORE: all but the first have one
+        assert [bool(c.attrs["d2h"]) for c in calls] == [False] + [True] * (len(calls) - 1)
+        assert any(c.attrs.get("merged") for c in calls) and not all(
+            c.attrs.get("merged") for c in calls)
 
 
 @pytest.mark.parametrize("kind", list(ANATOMY))
 def test_h2d_and_d2h_are_what_the_call_hands_over_and_fetches(tiny_serving_engine, monkeypatch,
                                                               kind):
-    """``h2d`` = the host arrays given to the program (the carried key is the
-    one device operand, and no upload); ``d2h`` = the arrays ``device_get`` is
-    handed: counted here from outside, at the program's call and at
-    ``jax.device_get``."""
+    """``h2d`` = the host arrays given to the program (the carried key is a device
+    operand, and no upload, as are a decode step's carried tokens; the two uploads of
+    a decode step's token merge count with the step); ``d2h`` = the arrays
+    ``device_get`` is handed: counted here from outside, at the program's call and
+    at ``jax.device_get``. A decode call fetches the step BEFORE it (none: 0), and
+    the last step's fetch is a ``collect`` span's."""
     import jax
 
     from deepspeed_tpu.inference import ServingEngine
@@ -508,12 +548,15 @@ def test_h2d_and_d2h_are_what_the_call_hands_over_and_fetches(tiny_serving_engin
     for ops in mine:
         assert sum(isinstance(x, jax.Array) for x in ops) == n_device
         assert sum(isinstance(x, (np.ndarray, np.generic)) for x in ops) == n_host
-    assert {sp.attrs["h2d"] for sp in calls} == {n_host}
-    fetching = [sp for sp in calls if sp.attrs.get("fetch") is not False]
+    assert {sp.attrs["h2d"] - 2 * bool(sp.attrs.get("merged")) for sp in calls} == {n_host}
+    fetching = [sp for sp in calls if sp.attrs.get("fetch") is not False and sp.attrs["d2h"]]
     assert {sp.attrs["d2h"] for sp in fetching} == {n_fetched}
+    if name == "decode":
+        assert [sp for sp in calls if not sp.attrs["d2h"]] == calls[:1]
     # every fetch of the run is some call's: none is made beside the scaffold
+    others += [sp for sp in spans if sp.name == "collect"]
     assert sorted(fetched) == sorted(sp.attrs["d2h"] for sp in calls + others
-                                     if sp.attrs.get("fetch") is not False)
+                                     if sp.attrs.get("fetch") is not False and sp.attrs["d2h"])
 
 
 @pytest.mark.parametrize("kind", list(ANATOMY))
@@ -522,7 +565,8 @@ def test_worker_call_goes_into_the_runtime_once(tiny_serving_engine, monkeypatch
     ``jnp`` conversion, no upload of its own. The call's one trip into the
     runtime is its own program's. Checked on warm programs (a first call
     traces, and the traced body splits the key), with the eager entry points
-    made to raise while ``_run`` is on the stack."""
+    made to raise while ``_run`` (a decode step: ``decode``, whose token merge is
+    a program of its own and no eager operation) is on the stack."""
     import jax
     import jax.numpy as jnp
 
@@ -544,10 +588,11 @@ def test_worker_call_goes_into_the_runtime_once(tiny_serving_engine, monkeypatch
 
     warm = served(0)  # every program of the run compiles here
     inside, eager = [], []
-    run = SlotWorker._run
+    entry = "decode" if name == "decode" else "_run"
+    run = getattr(SlotWorker, entry)
 
     def watched(self, *args, **kwargs):
-        inside.append(args[0])
+        inside.append(name)
         try:
             return run(self, *args, **kwargs)
         finally:
@@ -561,7 +606,7 @@ def test_worker_call_goes_into_the_runtime_once(tiny_serving_engine, monkeypatch
             return fn(*args, **kwargs)
         return guarded
 
-    monkeypatch.setattr(SlotWorker, "_run", watched)
+    monkeypatch.setattr(SlotWorker, entry, watched)
     for owner, attr in ((jax.random, "split"), (jax, "device_put"), (jnp, "asarray"),
                         (jnp, "array"), (type(jnp.int32), "__call__")):
         monkeypatch.setattr(owner, attr, refuse(f"{owner.__name__}.{attr}", getattr(owner, attr)))
@@ -578,7 +623,8 @@ def test_worker_call_goes_into_the_runtime_once(tiny_serving_engine, monkeypatch
 
 def test_a_routed_model_fetches_one_array_more():
     """The expert load comes back in the fetch that brings the tokens: ``d2h``
-    3 where a dense model's call has 2; ``h2d`` is the dense model's."""
+    3 where a dense model's call has 2; ``h2d`` is the dense model's. A decode call's
+    fetch, and so the load on its span, is the step's BEFORE it."""
     from chipbench.references import program_of
     from deepspeed_tpu.inference.serving import Request
     from deepspeed_tpu.launcher.serving_worker import build_serving_engine
@@ -593,8 +639,9 @@ def test_a_routed_model_fetches_one_array_more():
     since = time.perf_counter()
     assert all(r.ok for r in srv.serve(reqs).values())
     calls = {sp.name: sp for sp in tracing.spans(since) if sp.name in ("prefill", "decode")}
+    assert not calls["decode"].attrs.get("merged")  # the last one: no slot changed hands
     assert {k: (sp.attrs["h2d"], sp.attrs["d2h"]) for k, sp in calls.items()} == {
-        "prefill": (6, 3), "decode": (7, 3)}
+        "prefill": (6, 3), "decode": (6, 3)}
     assert "expert_load_max_over_mean" in calls["decode"].attrs
 
 
@@ -651,15 +698,15 @@ def _eager_split_form(worker, monkeypatch):
     chain = [jax.random.PRNGKey(STREAM_SEED)]
     monkeypatch.setattr(jax.random, "split", lambda key, num=2: (
         jnp.stack([key, key]) if num == 2 else split(key, num)))
-    run = worker._run
+    dispatch = worker._dispatch  # every call's first half, a decode step's too
 
     def eager(*args, key=True, **kwargs):
         if key:
             chain[0], k = split(chain[0])
             worker._rng = jax.device_put(k, worker._key_sharding())
-        return run(*args, key=key, **kwargs)
+        return dispatch(*args, key=key, **kwargs)
 
-    monkeypatch.setattr(worker, "_run", eager)
+    monkeypatch.setattr(worker, "_dispatch", eager)
     return chain
 
 
