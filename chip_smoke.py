@@ -22,7 +22,7 @@ run with a non-zero exit:
            (interpret=False, ``tpu_custom_call`` in the lowered text) at the
            shapes the next two phases use, against ``xla_attention`` in
            float32.
-  train    ``deepspeed_tpu.initialize`` with bench.py's 125M configuration,
+  train    ``deepspeed_tpu.initialize`` with a GPT-2 125M configuration,
            a few ``engine.train_batch`` calls on one seeded batch: finite
            falling loss, no overflow, one compilation of the step.
   serve    ``launcher.serving_worker.build_serving_engine`` + ``ServingEngine
@@ -212,7 +212,7 @@ def phase_kernels(args, sz) -> None:
 # ---------------------------------------------------------------------------
 
 def _train_model(sz):
-    """bench.py's tuned 125M configuration (its ``main()``), at ``sz``."""
+    """The GPT-2 125M configuration, at ``sz``."""
     import jax.numpy as jnp
 
     from deepspeed_tpu.models.transformer import Model, TransformerConfig

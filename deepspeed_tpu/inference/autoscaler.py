@@ -45,7 +45,7 @@ Every decision is a typed event in a bounded ring (``describe()``,
 carried in ``Router.telemetry_snapshot()`` and rendered by the report
 CLI) plus ``router/autoscale/*`` counters and gauges.
 
-The drill that proves the loop end-to-end is ``bench.py --surge``: an
+The drill that proves the loop end-to-end is ``drills.py --surge``: an
 open-loop bursty trace with heavy-tail prompt lengths and a mid-trace
 worker SIGKILL — the fleet grows to target, recovers the corpse, serves
 every accepted request to a terminal state with greedy parity on the

@@ -53,7 +53,7 @@ one ``Router``:
     keepalive comments an idle stream emits exactly so detection is
     bounded) failing or overrunning ``gateway.write_timeout_s``; the
     gateway cancels the uid, which frees its slot and prefix refs
-    (occupancy returns to 0 — the ``bench.py --gateway-chaos`` proof).
+    (occupancy returns to 0 — the ``drills.py --gateway-chaos`` proof).
   * ``GET /healthz`` — 200 while serving (healthy-replica count, open
     streams, brownout flag), 503 once draining or with no healthy
     replica: the load-balancer-facing signal to stop sending traffic.

@@ -34,7 +34,7 @@ Process lifecycle:
   * SIGKILL — nothing runs; the Router sees ``RpcConnectionLost`` on its
     next call (DEAD verdict, exactly-once failover from router-side
     request state) and the ``WorkerSupervisor`` respawns a fresh process
-    after its bounded backoff. This is the ``bench.py --chaos-serving``
+    after its bounded backoff. This is the ``drills.py --chaos-serving``
     drill's fault.
 
 Replay-safe step contract: terminal uids (and their encoded results)

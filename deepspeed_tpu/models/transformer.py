@@ -3467,7 +3467,7 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
     stacks are its state, so they stay the layer loop's CARRY: with the cache
     donated the loop's input and output are one buffer; as the scan's xs/ys they
     would be sliced out and restacked layer by layer and copied whole
-    (tests/test_chip_compile.py guards it).
+    (tests/test_chip_compile_serving.py guards it).
 
     Layers of several kinds (``cfg.layer_kinds``; the loop hands ``attend`` the
     layer's ``kind`` as a Python value). A whole-context layer is the above, at

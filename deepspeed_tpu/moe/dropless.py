@@ -115,7 +115,7 @@ SELECT_BIAS_STD = 0.02
 # the chip at 128 rows inside a serving program (a decode step of 128 slots, a
 # 128-row prefill) it took the second and copied BOTH whole [L, E, M, F] stacks into
 # that layout in every call (2 x 3 GB at LFM2's widths: neither program fit; on the
-# chip, PR 42, and ``tests/test_chip_compile.py``). With e a batch dimension each
+# chip, PR 42, and ``tests/test_chip_compile_caches.py``). With e a batch dimension each
 # expert's [M, F] is read where it lies, whatever the rows. Every decode step the
 # benchmark had before (16 to 32 rows) keeps the form it was timed in; of its
 # prefill programs one takes this form (a held share's 512-row bucket).

@@ -66,7 +66,7 @@ MAX_BLOCK_K = 2048
 # hold a float32 accumulator apiece beside them) may take of the 16 MiB of VMEM
 # the chip's compiler gives a kernel: 2048 keys of 128-wide float32 heads or
 # 256-wide bfloat16 ones compile, 2048 of 256-wide float32 are refused by 4 MiB
-# (tests/test_chip_compile.py).
+# (tests/test_chip_compile_kernels.py).
 KEY_BLOCK_BYTES = 2 ** 20
 # The width of the key sub-tiles a causal step's block is cut in: a step runs
 # only the sub-tiles that hold a key at or under its last row (``_keys_seen``,
@@ -102,7 +102,7 @@ BAND_BLOCK_Q = 256
 BAND_MAX_BLOCK_K = 1024  # the band's own cap: a wider window keeps the whole grid (``band_plan``)
 # What a banded step may hold of the 16 MiB of VMEM the chip's compiler gives a
 # kernel (``_band_step_bytes``, an upper estimate fitted to what the compiler
-# reported for 150 shapes: tests/test_chip_compile.py compiles a sweep).
+# reported for 150 shapes: tests/test_chip_compile_kernels.py compiles a sweep).
 BAND_VMEM_BYTES = 14 * 2 ** 20
 # Heads of a banded step written out inline in one iteration of its loop over
 # them: 1 / 2 / 4 / 8 of 8 read 4.06 / 3.17 / 2.91 / 2.89 ms at 16,384 rows

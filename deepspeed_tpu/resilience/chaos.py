@@ -2,7 +2,7 @@
 search, and delta-debugging shrink (docs/resilience.md "Chaos
 conductor").
 
-The seven bench.py drills each exercise the fault combinations their
+The seven drills.py drills each exercise the fault combinations their
 author imagined. This module imagines them for us: a ``FaultSchedule``
 is an ordered list of ``(site, key, at)`` entries over the full
 ``FaultInjector.SITES`` registry, generated from a seed + workload
@@ -14,7 +14,7 @@ run), or caller-built real ``ServingEngine``/process fleets via the
 (``resilience/invariants.py``). ``search()`` runs N seeded schedules
 and, on violation, ``shrink_schedule()`` delta-debugs the schedule to a
 minimal reproducer written as a rename-durable ``chaos-repro-NNN.json``
-that ``bench.py --chaos-replay`` re-executes bit-identically.
+that ``drills.py --chaos-replay`` re-executes bit-identically.
 
 Determinism is the whole design:
 

@@ -112,7 +112,7 @@ class ControlPlaneCrash(ResilienceError):
     Router raises this at the armed step, modelling the gateway+router
     process dying mid-traffic. Recovery tests abandon the raising Router and
     rebuild one over the SAME replicas and journal — the in-process spelling
-    of the ``bench.py --router-chaos`` SIGKILL."""
+    of the ``drills.py --router-chaos`` SIGKILL."""
 
 
 class RpcError(ResilienceError):

@@ -69,7 +69,7 @@ Fault sites (see docs/resilience.md for where each is wired):
                       so recovery tests can abandon the Router mid-traffic
                       and rebuild one over the same replicas + request
                       journal — the deterministic in-process spelling of
-                      the ``bench.py --router-chaos`` gateway+router
+                      the ``drills.py --router-chaos`` gateway+router
                       SIGKILL (inference/router.py consumes this).
 
 Two selection modes compose:

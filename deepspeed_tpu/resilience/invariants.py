@@ -1,6 +1,6 @@
 """Invariant oracles: the resilience contract as a reusable library.
 
-Seven bench.py drills (--fault-rate/--chaos/--chaos-serving/--surge/
+Seven drills.py drills (--fault-rate/--chaos/--chaos-serving/--surge/
 --gateway-chaos/--router-chaos/--tenant-chaos) grew the same assertions
 independently: every accepted request reaches a terminal state, recovered
 output is bitwise-identical to an unfaulted run, slots drain to zero,

@@ -10,7 +10,7 @@ worker cannot hot-spin the supervisor).
 Jitter is *deterministic* — a crc32 hash of ``(seed, attempt)``, the same
 construction the fault injector uses — so a retried run under CI fault
 injection replays the exact same delays and the chaos drill
-(``bench.py --chaos``) stays reproducible. Real fleets get decorrelation by
+(``drills.py --chaos``) stays reproducible. Real fleets get decorrelation by
 seeding with the worker rank / restart generation.
 
 Stdlib-only: importable from the agent and CLI without jax.

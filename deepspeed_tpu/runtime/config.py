@@ -194,7 +194,8 @@ class MonitorBackendConfig:
 @dataclass
 class CollectiveLedgerConfig:
     """Collective X-ray sub-block (``telemetry.ledger.collectives``;
-    ``telemetry/collective_ledger.py``, docs/PERF.md "Collective X-ray"):
+    ``telemetry/collective_ledger.py``, docs/observability.md "Collective
+    X-ray"):
 
     - ``enabled``: parse each resolved program's post-optimization HLO for
       collective ops (payload bytes, mesh-axis attribution, static
@@ -219,7 +220,7 @@ class CollectiveLedgerConfig:
 @dataclass
 class LedgerConfig:
     """Program-ledger sub-block (``telemetry.ledger``;
-    ``telemetry/program_ledger.py``, docs/PERF.md):
+    ``telemetry/program_ledger.py``, docs/observability.md):
 
     - ``enabled``: capture the XLA cost model (flops, bytes accessed, HBM
       footprint) of every watchdog-wrapped program and derive MFU/roofline
@@ -480,7 +481,7 @@ class FaultInjectionConfig:
     Two selection modes compose: the deterministic lists fire exactly once
     per listed key (a rewound step / requeued request is not re-faulted —
     transient-fault model), and ``rate`` adds an independent seeded draw per
-    opportunity (for randomized smoke runs, e.g. ``bench.py --fault-rate``).
+    opportunity (for randomized smoke runs, e.g. ``drills.py --fault-rate``).
 
     - ``nan_grad_steps``: 1-based global steps whose gradients go non-finite.
     - ``io_error_writes``: 1-based indices of guarded checkpoint file writes
@@ -518,7 +519,7 @@ class FaultInjectionConfig:
       "dies" — ``Router.step`` raises a typed ``ControlPlaneCrash`` so
       in-process recovery tests can abandon the Router mid-traffic and
       rebuild one over the same replicas + journal (the deterministic
-      spelling of the ``bench.py --router-chaos`` SIGKILL;
+      spelling of the ``drills.py --router-chaos`` SIGKILL;
       ``inference/router.py`` consumes this).
     - ``rate`` in [0, 1] with optional ``sites`` allowlist
       (``nan_grads`` | ``io_error`` | ``io_flaky`` | ``garbage_logits`` |
@@ -674,7 +675,7 @@ class RetryConfig:
 @dataclass
 class ChaosConfig:
     """``resilience.chaos`` block (consumed by ``resilience/chaos.py`` and
-    the ``bench.py --chaos-search`` drill; docs/resilience.md "Chaos
+    the ``drills.py --chaos-search`` drill; docs/resilience.md "Chaos
     conductor").
 
     - ``n_schedules``: schedules per search run (each a pure function of

@@ -166,11 +166,12 @@ class DeepSpeedEngine:
         )
         # program-ledger join rules: the train step's cost model reads its
         # measured wall time from the step-time histogram and publishes the
-        # engine's headline train/mfu gauge (docs/PERF.md)
+        # engine's headline train/mfu gauge (docs/observability.md)
         self.telemetry.ledger.bind(
             "train/train_step", wall_hist="train/step_time_sec", gauge="train")
         # the collective X-ray maps HLO replica groups back to axis names
-        # through the engine's own mesh (docs/PERF.md "Collective X-ray")
+        # through the engine's own mesh (docs/observability.md "Collective
+        # X-ray")
         self.telemetry.ledger.set_mesh_shape(dict(self.mesh.shape))
         # wall-clock timers mirror into the same registry (utils/timer.py —
         # the standalone pre-spine path is deprecated)

@@ -14,7 +14,7 @@ CommsLogger / nvtx / flops-profiler islands, unified):
     second compilation.
   * ``ProgramLedger`` — XLA cost model (flops/bytes/HBM) per watched
     program, joined with the wall-time histograms into MFU + roofline rows
-    (telemetry/program_ledger.py; docs/PERF.md).
+    (telemetry/program_ledger.py; docs/observability.md).
   * ``RequestTracer`` — bounded per-request lifecycle timeline with a
     Perfetto export (telemetry/request_trace.py).
   * exporters — JSONL event log, Prometheus text, MonitorMaster bridge.

@@ -35,10 +35,9 @@ reading the COMPILED program, not the python that traced it:
 Known limits (by design): the byte model is per-compiled-program — a
 collective inside a ``while``/scan body is counted once, not per trip
 (the *measured* wall time in the anatomy absorbs the repetition); the
-wire-time model is the standard ring-algorithm factor per op (docs/PERF.md
-"Collective X-ray"), an estimate, not a measurement. Methodology and ICI
-peak provenance live in docs/PERF.md; metric catalog in
-docs/observability.md.
+wire-time model is the standard ring-algorithm factor per op, an
+estimate, not a measurement. Methodology, ICI peak provenance and the
+metric catalog live in docs/observability.md ("Collective X-ray").
 """
 
 from __future__ import annotations
@@ -293,8 +292,9 @@ def infer_axes(groups: list[list[int]],
     return fallback
 
 
-# wire-time algorithm factors (ring algorithms; docs/PERF.md "Collective
-# X-ray"): payload_bytes * factor / ici_bw models the per-chip link time
+# wire-time algorithm factors (ring algorithms; docs/observability.md
+# "Collective X-ray"): payload_bytes * factor / ici_bw models the per-chip
+# link time
 def _wire_factor(op: str, group_size: int) -> float:
     n = max(2, group_size)
     if op == "all-reduce":

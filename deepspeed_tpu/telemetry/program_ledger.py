@@ -1,6 +1,6 @@
 """XLA program ledger: cost-model + HBM accounting for every compiled program.
 
-bench.py emits one aggregate TFLOPS number and the telemetry spine records
+A benchmark emits aggregate rates and the telemetry spine records
 wall-time histograms — neither says *where* step time and HBM go, or how far
 a program sits from the hardware roof. The reference ships this layer as its
 flops profiler + wall-clock breakdown (deepspeed/profiling/flops_profiler/);
@@ -39,8 +39,8 @@ yields the derived metrics the ROADMAP's perf push needs:
 to named pools (params, opt state, slot KV cache, prefix pool) next to the
 runtime's bytes-in-use/limit watermarks, with a configurable warn threshold.
 
-Peak-table provenance and the roofline method are documented in
-docs/PERF.md; the metric catalog lives in docs/observability.md.
+Peak-table provenance, the roofline method and the metric catalog live in
+docs/observability.md ("Program ledger").
 """
 
 from __future__ import annotations
@@ -52,8 +52,8 @@ from ..utils.logging import logger
 # ---------------------------------------------------------------------------
 # per-platform peaks (dense bf16 TFLOPS per chip, HBM GB/s per chip,
 # aggregate one-way ICI GB/s per chip — the Gbps figures in the Google
-# Cloud TPU system-architecture docs divided by 8; see docs/PERF.md for the
-# provenance table). A generation missing here degrades to the labeled
+# Cloud TPU system-architecture docs divided by 8; see docs/observability.md
+# for the provenance table). A generation missing here degrades to the labeled
 # "unrated" entry — rows stay attributable, never wrong.
 # ---------------------------------------------------------------------------
 
@@ -70,8 +70,7 @@ PEAKS: dict[str, dict] = {
                 "peak_ici_gbps": 600.0},
     "tpu_v6e": {"label": "TPU v6e", "peak_tflops": 918.0, "peak_hbm_gbps": 1640.0,
                 "peak_ici_gbps": 448.0},
-    # CPU fallback: rows are LABELED but never rated against a TPU peak —
-    # the same comparable-verdict discipline bench.py applies to its rows
+    # CPU fallback: rows are LABELED but never rated against a TPU peak
     "cpu": {"label": "cpu (unrated)", "peak_tflops": None, "peak_hbm_gbps": None,
             "peak_ici_gbps": None},
     "unknown": {"label": "unrated", "peak_tflops": None, "peak_hbm_gbps": None,

@@ -236,7 +236,7 @@ def summarize(events: list[dict], top: int = 10) -> str:
 
     # -- program roofline -----------------------------------------------
     # the ledger's static cost model joined with measured wall times
-    # (telemetry/program_ledger.py; docs/PERF.md): where step time and
+    # (telemetry/program_ledger.py; docs/observability.md): where step time and
     # headroom actually are, per compiled program
     lrows = ledger_rows(snap)
     if lrows:

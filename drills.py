@@ -1,50 +1,16 @@
-"""Benchmark: GPT-2 125M-class causal-LM training throughput on one chip.
+"""Correctness drills: ten CPU-pinned soaks of the resilience and serving
+contracts. Each prints ONE JSON line and exits 0 when its contract held;
+none of them measures speed (the benchmark is ``BENCHMARK.json`` +
+``chipbench/``). ``python drills.py`` alone lists them.
 
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", ...}.
-
-Baseline anchor: the reference's single-device headline is BERT-large at
-64 TFLOPS/GPU on V100 (BASELINE.md row 1). We report achieved model TFLOPS
-per chip on a decoder-only 125M model (seq 1024, bf16) and vs_baseline =
-achieved_TFLOPS / 64.0.
-
-Robustness (VERDICT r01 weak #1, r04 weak #1): backend init can fail
-transiently, and a full-config child can time out for CODE reasons while
-the backend is fine (or vice versa). The parent therefore stays off jax and
-runs every stage in a fresh child (JAX caches backend-init failures per
-process, and the chip belongs to one process at a time):
-
-  1. PRE-FLIGHTS the backend: a child that only jits a tiny matmul, on a
-     short deadline. Failure here = backend down (code can't hang a
-     256x256 matmul) — retried with the bounded-backoff schedule of
-     resilience/retry.py (DSTPU_BENCH_PREFLIGHT_ATTEMPTS attempts, default
-     4, delays 15s -> 30s -> 60s ... capped at 120s, deterministic jitter).
-     DSTPU_BENCH_FORCE_PREFLIGHT_FAIL=1 forces every attempt to fail (CI
-     drill for the fallback path).
-  1b. Every emitted JSON row is STAMPED with ``platform`` and a
-     ``comparable`` flag — False whenever the row ran on a fallback
-     backend (CPU), so trajectory tooling can exclude non-TPU rows instead
-     of silently flatlining on them.
-  2. Runs the FULL config (the autotuned r5 winner).
-  3. On full-config timeout WITH a passing pre-flight, runs the KNOWN-GOOD
-     reduced config (save_flash @ micro 32 — the r2/r3 proven-compiling
-     geometry) so a perf regression in the tuned path still yields a real
-     TPU number.
-  4. Falls back to CPU only when the pre-flight itself says the backend is
-     gone, and records WHY in the JSON line (diagnosis + per-stage errors).
-
-This ladder and its CPU row are ROADMAP S0/D1's to replace; ``chip_smoke.py``
-is the no-fallback proof that the tree runs on the chip. Compile time is
-recorded separately from step time (compile_s) so a compile-time regression
-is visible instead of masquerading as a hang.
-
-Fault-injection smoke (``python bench.py --fault-rate 0.05``, CI tier):
+Fault-injection smoke (``python drills.py --fault-rate 0.05``, CI tier):
 runs a CPU serving workload with seeded rate-mode NaN-logit injection and
 ASSERTS the resilience contract — every request reaches a terminal status,
 ``resilience/recovered`` is non-zero (at least one quarantined request's
 clean replay finished), and no slot leaks (occupancy gauge back to 0, every
 non-quarantined slot back in the free pool). Prints one JSON line.
 
-Surge drill (``python bench.py --surge [n_requests] [--surge-seed N]``, CI
+Surge drill (``python drills.py --surge [n_requests] [--surge-seed N]``, CI
 tier): the self-healing elastic fleet end-to-end — real worker processes
 behind the Router + the ledger-driven Autoscaler, an open-loop bursty
 trace with heavy-tail prompt lengths and mixed priorities, and a
@@ -56,7 +22,7 @@ parity on the completed set, brownout engaged while saturated at max, and
 no worker compiled a second decode program. Prints one JSON line with
 scale/respawn/brownout/shed counts and p99 TTFT.
 
-Gateway chaos drill (``python bench.py --gateway-chaos [--gateway-seed N]``,
+Gateway chaos drill (``python drills.py --gateway-chaos [--gateway-seed N]``,
 CI tier): the HTTP/SSE front door end-to-end — real worker processes over
 the TCP transport behind a real ``launcher/http_gateway`` server, open-loop
 HTTP clients with heavy-tail prompts, mid-stream client disconnects
@@ -67,7 +33,7 @@ loss, disconnect→cancel frees slots (occupancy and prefix refs back to
 single-engine run, all upgrade waves complete, watchdog raise everywhere.
 Prints one JSON line.
 
-Chaos soak drill (``python bench.py --chaos [steps] [--chaos-seed N]``, CI
+Chaos soak drill (``python drills.py --chaos [steps] [--chaos-seed N]``, CI
 tier): a supervisor loop trains a tiny model to a target step count under
 seeded random preemptions (each takes a just-in-time ``preempt``-tag
 checkpoint and kills the generation), one NaN step, and a transient
@@ -85,185 +51,6 @@ import os
 import subprocess
 import sys
 import time
-
-_CHILD_ENV = "_DSTPU_BENCH_CHILD"
-_MODE_ENV = "_DSTPU_BENCH_MODE"  # preflight | full | fallback (+JAX_PLATFORMS=cpu)
-
-
-def _preflight():
-    """Tiny-jit backend probe: prints one JSON line and exits. Anything that
-    hangs here is the backend, not model code."""
-    import jax
-
-    t0 = time.perf_counter()
-    import jax.numpy as jnp
-
-    x = jnp.ones((256, 256), jnp.bfloat16)
-    y = jax.jit(lambda a: a @ a)(x)
-    import numpy as np
-
-    np.asarray(jax.device_get(y[0, 0]))
-    print(json.dumps({
-        "metric": "preflight",
-        "platform": jax.devices()[0].platform,
-        "elapsed_s": round(time.perf_counter() - t0, 2),
-        "n_chips": len(jax.devices()),
-    }), flush=True)
-    sys.exit(0)
-
-
-def main():
-    import jax
-
-    from deepspeed_tpu.utils.jax_env import use_compile_cache
-
-    use_compile_cache()
-
-    if os.environ.get(_MODE_ENV) == "preflight":
-        _preflight()
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    import deepspeed_tpu
-    from deepspeed_tpu.models.transformer import Model, TransformerConfig
-
-    platform = jax.devices()[0].platform
-    on_tpu = platform == "tpu"
-    fallback = os.environ.get(_MODE_ENV) == "fallback"
-
-    # GPT-2 small (125M): 12L, 768h, 12 heads, vocab 50257, seq 1024.
-    if on_tpu:
-        # batch 64 fits in 16 GB HBM thanks to layer remat + chunked LM loss
-        L, H, D, V, S, B = 12, 12, 768, 50304, 1024, 64
-    else:  # CPU smoke fallback so the script always emits a line
-        L, H, D, V, S, B = 2, 4, 128, 1024, 128, 4
-
-    cfg = TransformerConfig(
-        vocab_size=V,
-        max_seq_len=S,
-        num_layers=L,
-        num_heads=H,
-        hidden_size=D,
-        pos_emb="learned",
-        dtype=jnp.bfloat16,
-        remat=on_tpu,  # activation checkpointing over the layer scan
-        # r5 isolated sweep (experiments/autotune_r5_log/autotune_r5.json, 18
-        # trials, builder-attested on the old stack): dots_and_flash @ micro
-        # 16 with the loss chunked at 256 beats the r3 winner (micro 32,
-        # chunk 512) 104.7k vs 99.2k tok/s
-        # — the smaller live-logit slab lets the no-matmul-recompute policy
-        # keep more of the batch resident. fallback mode: the r2-proven
-        # save_flash geometry — compiles smaller and survives even if the
-        # tuned path regresses.
-        remat_policy=("save_flash" if (fallback or not on_tpu) else "dots_and_flash"),
-        attn_impl="flash" if on_tpu else "xla",
-        # experiments/perf_probe5.py: 1024x1024 beats the auto 512/1024 cap
-        # by ~1.6% at these shapes (the whole 1k sequence in one k-block)
-        flash_block_q=1024 if on_tpu else 0,
-        flash_block_k=1024 if on_tpu else 0,
-        # fallback keeps the default chunk 512 — exactly the r2-proven
-        # geometry, not an untested save_flash+chunk256 combination
-        loss_chunk_size=256 if (on_tpu and not fallback) else 512,
-    )
-    model = Model(cfg)
-    micro = (B // 2 if fallback else B // 4) if on_tpu else B
-    ds_cfg = {
-        "train_batch_size": B,
-        "train_micro_batch_size_per_gpu": micro,
-        "gradient_accumulation_steps": B // micro,
-        "optimizer": {"type": "AdamW", "params": {"lr": 6e-4, "weight_decay": 0.1}},
-        "zero_optimization": {"stage": 1},
-        "bf16": {"enabled": True},
-        "gradient_clipping": 1.0,
-        "steps_per_print": 1000000,
-        "mesh": {"data": -1},
-    }
-    engine, _, _, _ = deepspeed_tpu.initialize(model=model, config=ds_cfg)
-    tokens = np.random.default_rng(0).integers(0, V, size=(B, S + 1)).astype(np.int32)
-    batch = {"tokens": tokens}
-
-    def sync(m):
-        jax.block_until_ready(m["loss"])
-
-    # warmup (compile + 3 steady-state steps); compile time reported apart
-    # from step time so a compile regression is diagnosable (VERDICT r04 #1)
-    t_c0 = time.perf_counter()
-    sync(engine.train_batch(batch))
-    compile_s = time.perf_counter() - t_c0
-    m = None
-    for _ in range(3 if on_tpu else 1):
-        m = engine.train_batch(batch)
-    sync(m)
-
-    steps = 20 if on_tpu else 3
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        m = engine.train_batch(batch)
-    sync(m)
-    dt = time.perf_counter() - t0
-
-    tokens_per_step = B * S
-    tok_s = steps * tokens_per_step / dt
-    n_chips = len(jax.devices())
-    tok_s_chip = tok_s / n_chips
-
-    # 6*N FLOPs/token (fwd+bwd) + attention term (12*S*D per layer per token:
-    # QK^T + AV, 2*S*D MACs each fwd, x3 for fwd+bwd — same convention as
-    # models/transformer.py flops_per_token)
-    n_params = L * (4 * D * D + 8 * D * D) + V * D + S * D
-    attn_flops = L * 12 * S * D
-    flops_per_token = 6 * n_params + attn_flops
-    tflops = tok_s_chip * flops_per_token / 1e12
-
-    out = {
-        "metric": "gpt2-125M bf16 train throughput (achieved TFLOPS/chip)",
-        "value": round(tflops, 2),
-        "unit": "TFLOPS/chip",
-        "vs_baseline": round(tflops / 64.0, 3),
-        "tokens_per_sec_per_chip": round(tok_s_chip, 1),
-        "platform": platform,
-        "n_chips": n_chips,
-        "compile_s": round(compile_s, 1),
-        "config": "fallback_save_flash_micro32" if fallback else "tuned_r5_dots_and_flash_micro16_chunk256",
-    }
-    # program-ledger stamp (telemetry/program_ledger.py): XLA's own cost
-    # model for the compiled train step + the derived MFU and roofline
-    # verdict, so each BENCH row carries WHY, not just how fast. Outside
-    # the timed region; on a CPU fallback the row stays labeled
-    # "unrated:cpu" — never rated against a TPU peak (mfu null).
-    try:
-        snap = engine.telemetry_snapshot()
-        rows = snap.get("program_ledger", [])
-        out["program_ledger"] = [
-            {k: row.get(k) for k in
-             ("name", "flops", "bytes_accessed", "arith_intensity",
-              "compile_s", "wall_p50_s", "achieved_tflops", "roofline")}
-            for row in rows[:4]]
-        step_row = next((r for r in rows
-                         if r["name"].startswith("train/train_step")), None)
-        if step_row is not None:
-            out["mfu"] = step_row.get("mfu")
-            out["roofline"] = step_row.get("roofline")
-        # collective X-ray stamp (telemetry/collective_ledger.py): the
-        # train step's comm-by-axis split, exposed-comm estimate and the
-        # STATIC overlap verdict from the compiled HLO — on CPU fallback
-        # the times stay labeled nulls (comm_rated false), never fabricated
-        anat = next((r for r in snap.get("step_anatomy", [])
-                     if r.get("name", "").startswith("train/train_step")),
-                    None)
-        if anat is not None:
-            out["step_anatomy"] = {
-                k: anat.get(k) for k in
-                ("name", "comm_bytes_by_axis", "comm_time_by_axis",
-                 "comm_time_s", "exposed_comm_estimate_s",
-                 "overlap_verdict", "comm_rated")}
-        hbm = snap.get("hbm", {})
-        if hbm.get("pools"):
-            out["hbm_pools_bytes"] = hbm["pools"]
-    except Exception as e:  # noqa: BLE001 — the throughput row must emit
-        out["program_ledger_error"] = f"{type(e).__name__}: {e}"
-    print(json.dumps(out), flush=True)
 
 
 def _cpu_drill_env():
@@ -344,8 +131,8 @@ def _fault_smoke(rate: float) -> int:
         "metric": "serving fault-injection smoke (recovered requests)",
         "value": int(recovered),
         "unit": "requests",
-        # CPU-pinned correctness smoke: never a trajectory datapoint
-        **_drill_stamp(),
+        # CPU-pinned correctness smoke: never a perf datapoint
+        "platform": "cpu",
         "fault_rate": rate,
         "n_requests": len(reqs),
         "statuses": dict(statuses),
@@ -486,8 +273,8 @@ def _chaos(steps: int, seed: int) -> int:
         "value": int(tallies["preemptions"] + tallies["ckpt_retries"]
                      + tallies["nan_skipped_steps"]),
         "unit": "faults",
-        # CPU-pinned correctness soak: never a trajectory datapoint
-        **_drill_stamp(),
+        # CPU-pinned correctness soak: never a perf datapoint
+        "platform": "cpu",
         "target_steps": steps,
         "survivor_steps": survivor_steps,
         "generations": generations,
@@ -504,7 +291,7 @@ def _chaos(steps: int, seed: int) -> int:
 
 
 def _chaos_serving(seed: int) -> int:
-    """Cross-process serving chaos drill (``bench.py --chaos-serving``):
+    """Cross-process serving chaos drill (``drills.py --chaos-serving``):
     3 REAL worker processes behind the Router's RPC transport; one is
     SIGKILL'd mid-prefill and one mid-decode. Asserts the fleet contract
     across genuine OS process boundaries: every accepted request reaches a
@@ -695,8 +482,8 @@ def _chaos_serving(seed: int) -> int:
             "metric": "serving kill-9 chaos drill (failed-over requests recovered)",
             "value": int(stats["failovers_recovered"]),
             "unit": "requests",
-            # CPU-pinned correctness soak: never a trajectory datapoint
-            **_drill_stamp(),
+            # CPU-pinned correctness soak: never a perf datapoint
+            "platform": "cpu",
             "workers": 3,
             "kills": {"mid_prefill_rid": victim_prefill,
                       "mid_decode_rid": victim_decode},
@@ -715,7 +502,7 @@ def _chaos_serving(seed: int) -> int:
 
 
 def _disagg_drill(seed: int) -> int:
-    """Disaggregated prefill/decode drill (``bench.py --disagg``): the
+    """Disaggregated prefill/decode drill (``drills.py --disagg``): the
     role-split fleet's headline proof, three phases —
 
       1. IN-PROCESS parity matrix: a 2-prefill + 2-decode fleet vs the
@@ -735,9 +522,8 @@ def _disagg_drill(seed: int) -> int:
          corpse are all asserted.
 
     Emits one JSON row with handoff p50/p99, per-pool replica counts and
-    scale decisions, and the tokens/sec ratio — flat ``disagg_*`` keys the
-    trajectory tooling delta-tracks (non-gating). CPU-pinned correctness
-    soak, never a perf datapoint."""
+    scale decisions, and the tokens/sec ratio as flat ``disagg_*`` keys.
+    CPU-pinned correctness soak, never a perf datapoint."""
     _cpu_drill_env()
     import signal
 
@@ -913,7 +699,7 @@ def _disagg_drill(seed: int) -> int:
                       "(handoffs under mid-transfer kill)",
             "value": int(stats["disagg"]["handoffs"]),
             "unit": "handoffs",
-            **_drill_stamp(),
+            "platform": "cpu",
             "workers": {"prefill": 2, "decode": 1},
             "kill": {"victim_rid": victim_rid, "site": "kv_export_window#2"},
             "n_requests": len(ref),
@@ -935,7 +721,7 @@ def _disagg_drill(seed: int) -> int:
 
 
 def _surge(n_requests: int, seed: int) -> int:
-    """Trace-driven surge/failure drill (``bench.py --surge [n]``): the
+    """Trace-driven surge/failure drill (``drills.py --surge [n]``): the
     self-healing elastic fleet end-to-end. One REAL worker process behind
     the Router + a ledger-driven Autoscaler over the WorkerSupervisor; an
     open-loop trace (two bursts, heavy-tail prompt lengths, mixed
@@ -947,7 +733,7 @@ def _surge(n_requests: int, seed: int) -> int:
     unfaulted single-engine run's, brownout engaged while saturated at
     max, and no worker compiled a second decode program (watchdog RAISE
     everywhere). Emits one JSON row with scale/respawn/brownout/shed
-    counts and p99 TTFT. CPU-pinned correctness soak, never a trajectory
+    counts and p99 TTFT. CPU-pinned correctness soak, never a perf
     datapoint."""
     _cpu_drill_env()
     import signal
@@ -1134,8 +920,8 @@ def _surge(n_requests: int, seed: int) -> int:
                          + asc_c.get("scale_downs", 0)
                          + asc_c.get("respawns", 0)),
             "unit": "events",
-            # CPU-pinned correctness soak: never a trajectory datapoint
-            **_drill_stamp(),
+            # CPU-pinned correctness soak: never a perf datapoint
+            "platform": "cpu",
             "n_requests": len(prompts),
             "accepted": len(submitted),
             "rejected_at_submit": dict(
@@ -1155,7 +941,7 @@ def _surge(n_requests: int, seed: int) -> int:
 
 
 def _gateway_chaos(seed: int) -> int:
-    """Front-door chaos drill (``bench.py --gateway-chaos``): REAL worker
+    """Front-door chaos drill (``drills.py --gateway-chaos``): REAL worker
     processes (TCP transport) behind a REAL HTTP/SSE gateway, driven by
     open-loop HTTP clients with heavy-tail prompts. Mid-trace: several
     clients DISCONNECT mid-stream, one worker is SIGKILL'd (recovered via
@@ -1173,7 +959,7 @@ def _gateway_chaos(seed: int) -> int:
     shows the dead verdict and the failover storm, ``bin/dstpu_autopsy``
     must exit 0 on it, and the measured ring-sampling overhead must stay
     under 1% of decode step wall (the docs/observability.md claim).
-    CPU-pinned correctness soak, never a trajectory datapoint."""
+    CPU-pinned correctness soak, never a perf datapoint."""
     _cpu_drill_env()
     import glob
     import shutil
@@ -1570,8 +1356,8 @@ def _gateway_chaos(seed: int) -> int:
             "value": int(len(cancelled) + state["respawns"]
                          + len(upgraded)),
             "unit": "events",
-            # CPU-pinned correctness soak: never a trajectory datapoint
-            **_drill_stamp(),
+            # CPU-pinned correctness soak: never a perf datapoint
+            "platform": "cpu",
             "workers": 3,
             "transport": "tcp",
             "n_requests": n_req,
@@ -1688,7 +1474,7 @@ def _router_chaos_child(cfg_path: str) -> int:
 
 
 def _router_chaos(seed: int) -> int:
-    """Control-plane chaos drill (``bench.py --router-chaos``): 3 REAL TCP
+    """Control-plane chaos drill (``drills.py --router-chaos``): 3 REAL TCP
     worker processes under live HTTP/SSE traffic; the gateway+router
     process is SIGKILL'd mid-prefill and mid-stream, then RESTARTED
     against the same request journal and worker workdir. The restarted
@@ -1701,7 +1487,7 @@ def _router_chaos(seed: int) -> int:
     token stream, bitwise greedy parity vs an unfaulted single-engine run
     on EVERY completion, journal replay idempotence, slot/prefix-ref
     occupancy back to 0, and watchdog RAISE held on every worker.
-    CPU-pinned correctness soak, never a trajectory datapoint."""
+    CPU-pinned correctness soak, never a perf datapoint."""
     _cpu_drill_env()
     import signal
     import socket as socket_mod
@@ -2024,8 +1810,8 @@ def _router_chaos(seed: int) -> int:
                          + rec.get("router/recovery/redispatched", 0)
                          + len(resumed)),
             "unit": "requests",
-            # CPU-pinned correctness soak: never a trajectory datapoint
-            **_drill_stamp(),
+            # CPU-pinned correctness soak: never a perf datapoint
+            "platform": "cpu",
             "workers": 3,
             "transport": "tcp",
             "n_requests": n_req,
@@ -2061,7 +1847,7 @@ def _router_chaos(seed: int) -> int:
 
 
 def _tenant_chaos(seed: int) -> int:
-    """Multi-tenant isolation drill (``bench.py --tenant-chaos``): a REAL
+    """Multi-tenant isolation drill (``drills.py --tenant-chaos``): a REAL
     2-worker TCP fleet behind the authenticated HTTP gateway, serving a
     conformant VICTIM tenant (weight 4), a 10x-concurrency AGGRESSOR
     tenant (weight 1, per-tenant quota), and an invalid-token ATTACKER.
@@ -2078,7 +1864,7 @@ def _tenant_chaos(seed: int) -> int:
     uid), per-tenant accounting rebuilt after the SIGKILL, no raw bearer
     token in the journal or child logs, and the decode program count flat
     (the tenant axis never becomes a traced operand). CPU-pinned
-    correctness soak, never a trajectory datapoint."""
+    correctness soak, never a perf datapoint."""
     _cpu_drill_env()
     import hashlib
     import signal
@@ -2532,8 +2318,8 @@ def _tenant_chaos(seed: int) -> int:
             "metric": "tenant isolation drill (victim SLO held under attack)",
             "value": int(agg_stats["s429"] + len(attacker["codes"])),
             "unit": "contained_requests",
-            # CPU-pinned correctness soak: never a trajectory datapoint
-            **_drill_stamp(),
+            # CPU-pinned correctness soak: never a perf datapoint
+            "platform": "cpu",
             "workers": 2,
             "transport": "tcp",
             "tenants": 2,
@@ -2576,7 +2362,7 @@ def _tenant_chaos(seed: int) -> int:
 
 
 def _chaos_search(n_schedules: int, seed: int) -> int:
-    """Seeded fault-space search (``bench.py --chaos-search``): run
+    """Seeded fault-space search (``drills.py --chaos-search``): run
     ``n_schedules`` generated ``FaultSchedule``s against the shared
     invariant suite over the host-only fake fleet
     (``resilience/chaos.py``). Every violation is delta-debugged to a
@@ -2602,8 +2388,8 @@ def _chaos_search(n_schedules: int, seed: int) -> int:
         "metric": "chaos fault-space search (green schedules)",
         "value": int(row["schedules_run"]) - len(row["violations"]),
         "unit": "schedules",
-        # CPU-pinned correctness search: never a trajectory datapoint
-        **_drill_stamp(),
+        # CPU-pinned correctness search: never a perf datapoint
+        "platform": "cpu",
         "schedules_run": row["schedules_run"],
         "sites_covered": row["sites_covered"],
         "site_fired": site_fired,
@@ -2615,7 +2401,7 @@ def _chaos_search(n_schedules: int, seed: int) -> int:
 
 
 def _chaos_replay(path: str) -> int:
-    """Replay one ``chaos-repro-NNN.json`` (``bench.py --chaos-replay``)
+    """Replay one ``chaos-repro-NNN.json`` (``drills.py --chaos-replay``)
     and verify bit-identical reproduction: the re-run must produce the
     SAME outcome digest and trip the SAME invariant set the artifact
     recorded. Also accepts a bare schedule JSON (replays without the
@@ -2632,8 +2418,8 @@ def _chaos_replay(path: str) -> int:
         "metric": "chaos repro replay (bit-identical)",
         "value": int(ok),
         "unit": "bool",
-        # CPU-pinned correctness replay: never a trajectory datapoint
-        **_drill_stamp(),
+        # CPU-pinned correctness replay: never a perf datapoint
+        "platform": "cpu",
         "repro": os.path.basename(path),
         "digest": rep["digest"],
         "digest_match": rep["digest_match"],
@@ -2644,220 +2430,68 @@ def _chaos_replay(path: str) -> int:
     return 0 if ok else 1
 
 
-def _drill_stamp():
-    """The constant provenance block every CPU-pinned correctness drill
-    stamps into its row: the ``_stamp_row`` platform/comparable/perf-xray
-    contract (labeled, never rated) — one definition so a drill can't
-    drift from the trajectory tooling's expectations."""
-    return {
-        "platform": "cpu",
-        "comparable": False,
-        "mfu": None,
-        "roofline": "unrated:cpu",
-        "step_anatomy": None,
-        "spec_acceptance_rate": None,
-        "spec_tokens_per_sec_per_request_ratio": None,
-        # multi-tenant isolation stamps (--tenant-chaos): labeled nulls on
-        # every non-tenant drill row, real values where the drill measured
-        "tenant_victim_ttft_p99_ratio": None,
-        "tenant_victim_sheds": None,
-        "tenant_aggressor_429s": None,
-    }
+# One entry a drill: flag, function, operand, seed flag. An operand is
+# (usage text, type, default, least value, why that is the least); a default
+# of None makes it required, and a drill with no operand refuses one. Where
+# two flags are given the earlier entry runs (``--chaos-replay --chaos-search``
+# is a replay that lacks its FILE).
+_DRILLS = (
+    ("--router-chaos", _router_chaos, None, "--router-seed"),
+    ("--tenant-chaos", _tenant_chaos, None, "--tenant-seed"),
+    ("--fault-rate", _fault_smoke,
+     ("<float in (0, 1]>", float, None, None, None), None),
+    ("--surge", _surge,
+     ("[n_requests >= 12]", int, 30, 12,
+      "n_requests must be >= 12 (room for two bursts + the high-priority "
+      "stragglers)"), "--surge-seed"),
+    ("--gateway-chaos", _gateway_chaos, None, "--gateway-seed"),
+    ("--disagg", _disagg_drill, None, "--disagg-seed"),
+    ("--chaos-replay", _chaos_replay,
+     ("<chaos-repro.json>", str, None, None, None), None),
+    ("--chaos-search", _chaos_search,
+     ("[n_schedules >= 1]", int, 64, 1, "n_schedules must be >= 1"),
+     "--chaos-search-seed"),
+    ("--chaos-serving", _chaos_serving, None, "--chaos-seed"),
+    ("--chaos", _chaos,
+     ("[steps >= 6]", int, 12, 6,
+      "steps must be >= 6 (room for 2 preempts + 1 NaN)"), "--chaos-seed"),
+)
 
 
-def _stamp_row(obj, stage):
-    """Backend provenance on EVERY bench row: ``platform`` plus a
-    ``comparable`` verdict — False when the row ran on a fallback backend
-    (CPU), so the BENCH trajectory tooling can exclude it instead of
-    silently flatlining on it (the r04/r05 regression). Rows that never ran
-    anywhere (total failure) stamp platform "none". The same discipline
-    extends to the perf-xray fields: every row carries ``mfu``,
-    ``roofline`` AND ``step_anatomy`` keys — null / "unrated:<platform>"
-    unless the child computed real ones from the program ledger /
-    collective X-ray, so a fallback row is labeled, never rated against a
-    TPU peak (and never carries fabricated comm numbers)."""
-    obj["bench_stage"] = stage
-    platform = obj.get("platform") or "none"
-    obj["platform"] = platform
-    obj["comparable"] = platform not in ("none", "cpu")
-    obj.setdefault("mfu", None)
-    obj.setdefault("roofline", f"unrated:{platform}")
-    obj.setdefault("step_anatomy", None)
-    # speculative-decoding stamps (benchmarks/serving_throughput.py): rows
-    # whose run never measured a spec cell carry the keys as labeled nulls
-    obj.setdefault("spec_acceptance_rate", None)
-    obj.setdefault("spec_tokens_per_sec_per_request_ratio", None)
-    return obj
+def _usage(flag, operand, seed_flag):
+    words = (flag, operand and operand[0], seed_flag and f"[{seed_flag} <int>]")
+    return " ".join(w for w in words if w)
 
 
-def _preflight_probe(run_child, attempts, pf_timeout, diag, sleep=None):
-    """Backend preflight with bounded-backoff retries. A backend that is
-    not up yet is a retriable condition (resilience/retry.py backoff: 15s
-    base doubling to a 120s cap, deterministic jitter) — r04/r05 flatlined
-    to CPU rows because two fixed attempts gave it ~30s total to come up.
-    Returns (backend_up, errors); errors holds one entry per failed
-    attempt for the collapsed stderr line."""
-    from deepspeed_tpu.resilience.retry import RetryPolicy, backoff_delay
-
-    if sleep is None:
-        # resolved at call time (not a def-time default) so tests that
-        # monkeypatch time.sleep actually intercept the backoff
-        sleep = time.sleep
-    policy = RetryPolicy(max_attempts=max(1, attempts),
-                         base_delay_s=15.0, max_delay_s=120.0, jitter=0.25)
-    force_fail = os.environ.get("DSTPU_BENCH_FORCE_PREFLIGHT_FAIL") == "1"
-    errs = []
-    for attempt in range(1, policy.max_attempts + 1):
-        if attempt > 1:
-            sleep(backoff_delay(attempt - 1, policy, seed=0))
-        diag["preflight_attempts"] = attempt
-        if force_fail:
-            line, err = None, "forced (DSTPU_BENCH_FORCE_PREFLIGHT_FAIL=1)"
-        else:
-            line, err = run_child({_MODE_ENV: "preflight"}, timeout=pf_timeout)
-        if line:
-            diag["preflight"] = json.loads(line)
-            platform = diag["preflight"].get("platform")
-            if platform != "cpu":
-                return True, errs
-            # a missing TPU can manifest as a SILENT cpu fallback (jax init
-            # falls through instead of raising) — that is the same retriable
-            # condition as a timeout, not a verdict; a later fresh child can
-            # find the TPU once the backend is up. Costs the bounded backoff
-            # (~2 min total) on genuinely CPU-only boxes, which the explicit
-            # non-comparable fallback row then documents.
-            errs.append(f"came up on {platform}")
-        else:
-            errs.append(err)
-    return False, errs
-
-
-def _extract_json_line(text):
-    for line in reversed(text.splitlines()):
-        line = line.strip()
-        if line.startswith("{"):
-            try:
-                obj = json.loads(line)
-                if isinstance(obj, dict) and "metric" in obj:
-                    return line
-            except ValueError:
-                continue
-    return None
-
-
-def _run_child(extra_env, timeout):
-    from deepspeed_tpu.utils.jax_env import require_chip_free
-
-    env = dict(os.environ)
-    env[_CHILD_ENV] = "1"
-    env.update(extra_env)
-    require_chip_free("bench.py parent", env)
+def _drill_args(flag, operand, seed_flag):
+    """One entry's arguments off ``sys.argv``. Anything malformed prints the
+    drill's usage line and exits 2 before jax is imported: never a
+    traceback, never a started drill."""
+    argv, args = sys.argv, []
     try:
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__)],
-            env=env, capture_output=True, text=True, timeout=timeout,
-        )
-    except subprocess.TimeoutExpired as e:
-        # salvage a JSON line if the child printed one then hung at exit
-        out = e.stdout.decode() if isinstance(e.stdout, bytes) else (e.stdout or "")
-        line = _extract_json_line(out)
-        if line:
-            return line, None
-        return None, "timeout"
-    line = _extract_json_line(proc.stdout)
-    if proc.returncode == 0 and line:
-        return line, None
-    tail = (proc.stderr or proc.stdout or "").strip().splitlines()[-5:]
-    return None, f"rc={proc.returncode}: " + " | ".join(tail)
-
-
-def _parent():
-    diag = {"preflight": None, "attempts": [], "preflight_attempts": 0}
-
-    def emit(line, stage):
-        obj = _stamp_row(json.loads(line), stage)
-        if diag["preflight"]:
-            obj["preflight_s"] = diag["preflight"].get("elapsed_s")
-        obj["preflight_attempts"] = diag["preflight_attempts"]
-        print(json.dumps(obj), flush=True)
-        return 0
-
-    def _collapse(attempts):
-        """['preflight: timeout', 'preflight: timeout'] -> one entry with a
-        count — the r05 log tail was N identical lines saying nothing new."""
-        out = []
-        for a in attempts:
-            if out and out[-1][0] == a:
-                out[-1][1] += 1
-            else:
-                out.append([a, 1])
-        return [(a if n == 1 else f"{a} (x{n})") for a, n in out]
-
-    def note(stage, err):
-        diag["attempts"].append(f"{stage}: {err}")
-        print(f"[bench] {stage} failed: {err}", file=sys.stderr, flush=True)
-
-    timeouts = tuple(
-        int(t) for t in os.environ.get(
-            "DSTPU_BENCH_TIMEOUTS", "180,900,900,600").split(",")
-    )
-    pf_timeout, full_timeout, retry_timeout, fb_timeout = (tuple(timeouts) + (600,) * 4)[:4]
-
-    # 1. backend pre-flight: tiny jit on a short deadline, retried with
-    # bounded backoff (a dead backend is retriable — see _preflight_probe).
-    # Failed attempts are collected and printed as ONE collapsed stderr line
-    # after the loop (repeating "[bench] preflight failed: timeout" per
-    # attempt added nothing — BENCH_r05's tail was the same line twice).
-    pf_attempts = int(os.environ.get("DSTPU_BENCH_PREFLIGHT_ATTEMPTS", "4"))
-    backend_up, pf_errs = _preflight_probe(
-        _run_child, pf_attempts, pf_timeout, diag)
-    for err in pf_errs:
-        diag["attempts"].append(f"preflight: {err}")
-    if pf_errs:
-        msgs = _collapse(pf_errs)
-        print(f"[bench] preflight failed ({len(pf_errs)} attempt"
-              f"{'s' if len(pf_errs) > 1 else ''}): " + "; ".join(msgs),
-              file=sys.stderr, flush=True)
-
-    if backend_up:
-        # 2. full tuned config (+1 retry — transient backend drops happen)
-        for attempt, t in enumerate((full_timeout, retry_timeout)):
-            if attempt:
-                time.sleep(15)
-            line, err = _run_child({_MODE_ENV: "full"}, timeout=t)
-            if line:
-                return emit(line, "full")
-            note("full", err)
-        # 3. known-good reduced config: tuned path regressed, prove the
-        #    dense path still performs rather than punting to CPU
-        line, err = _run_child({_MODE_ENV: "fallback"}, timeout=fb_timeout)
-        if line:
-            return emit(line, "fallback_known_good")
-        note("fallback", err)
-
-    # 4. CPU fallback so a number is always recorded — explicitly stamped
-    # non-comparable (platform cpu) with the diagnosis: a retried-but-dead
-    # backend yields a visible fallback row, never a silent CPU datapoint
-    line, err = _run_child({"JAX_PLATFORMS": "cpu"}, timeout=900)
-    if line:
-        obj = _stamp_row(json.loads(line), "cpu_fallback")
-        obj["diagnosis"] = (
-            "tpu backend down (preflight failed)" if not backend_up
-            else "tpu bench failed despite live backend — code regression?")
-        obj["errors"] = "; ".join(_collapse(diag["attempts"]))[-500:]
-        obj["preflight_attempts"] = diag["preflight_attempts"]
-        print(json.dumps(obj), flush=True)
-        return 0
-    note("cpu", err)
-    print(json.dumps(_stamp_row({
-        "metric": "gpt2-125M bf16 train throughput (achieved TFLOPS/chip)",
-        "value": 0.0,
-        "unit": "TFLOPS/chip",
-        "vs_baseline": 0.0,
-        "error": "; ".join(_collapse(diag["attempts"]))[-500:],
-        "preflight_attempts": diag["preflight_attempts"],
-    }, "none")), flush=True)
-    return 0
+        word = argv[argv.index(flag) + 1:][:1]
+        # "--"-prefixed means the next FLAG; a bare "-3" is a (bad) operand
+        # and must hit the usage check, not be ignored
+        given = bool(word) and not word[0].startswith("--")
+        if operand is None:
+            if given:
+                raise ValueError(f"unexpected operand {word[0]!r} (the drill "
+                                 f"takes only {seed_flag})")
+        else:
+            _, kind, default, least, why = operand
+            if not given and default is None:
+                raise ValueError("missing operand")
+            args.append(kind(word[0]) if given else default)
+            if least is not None and args[0] < least:
+                raise ValueError(why)
+        if seed_flag is not None:
+            args.append(int(argv[argv.index(seed_flag) + 1])
+                        if seed_flag in argv else 0)
+    except (IndexError, ValueError) as e:
+        print(f"usage: drills.py {_usage(flag, operand, seed_flag)} ({e})",
+              file=sys.stderr)
+        sys.exit(2)
+    return args
 
 
 if __name__ == "__main__":
@@ -2866,170 +2500,10 @@ if __name__ == "__main__":
         # launches (and SIGKILLs); not a user-facing drill entry
         sys.exit(_router_chaos_child(
             sys.argv[sys.argv.index("--router-chaos-child") + 1]))
-    if "--router-chaos" in sys.argv:
-        # usage-error exit 2 on malformed values (same contract as
-        # --chaos/--chaos-serving/--surge/--gateway-chaos)
-        try:
-            idx = sys.argv.index("--router-chaos")
-            if idx + 1 < len(sys.argv) and not sys.argv[idx + 1].startswith("--"):
-                raise ValueError(
-                    f"unexpected operand {sys.argv[idx + 1]!r} (the drill "
-                    "takes only --router-seed)")
-            rc_seed = 0
-            if "--router-seed" in sys.argv:
-                rc_seed = int(sys.argv[sys.argv.index("--router-seed") + 1])
-        except (IndexError, ValueError) as e:
-            print(f"usage: bench.py --router-chaos [--router-seed <int>] "
-                  f"({e})", file=sys.stderr)
-            sys.exit(2)
-        sys.exit(_router_chaos(rc_seed))
-    if "--tenant-chaos" in sys.argv:
-        # usage-error exit 2 on malformed values (same contract as
-        # --chaos/--chaos-serving/--surge/--router-chaos)
-        try:
-            idx = sys.argv.index("--tenant-chaos")
-            if idx + 1 < len(sys.argv) and not sys.argv[idx + 1].startswith("--"):
-                raise ValueError(
-                    f"unexpected operand {sys.argv[idx + 1]!r} (the drill "
-                    "takes only --tenant-seed)")
-            tc_seed = 0
-            if "--tenant-seed" in sys.argv:
-                tc_seed = int(sys.argv[sys.argv.index("--tenant-seed") + 1])
-        except (IndexError, ValueError) as e:
-            print(f"usage: bench.py --tenant-chaos [--tenant-seed <int>] "
-                  f"({e})", file=sys.stderr)
-            sys.exit(2)
-        sys.exit(_tenant_chaos(tc_seed))
-    if "--fault-rate" in sys.argv:
-        try:
-            rate = float(sys.argv[sys.argv.index("--fault-rate") + 1])
-        except (IndexError, ValueError):
-            print("usage: bench.py --fault-rate <float in (0, 1]>",
-                  file=sys.stderr)
-            sys.exit(2)
-        sys.exit(_fault_smoke(rate))
-    if "--surge" in sys.argv:
-        # usage-error exit 2 on malformed values (same contract as
-        # --chaos/--chaos-serving): --surge [n_requests >= 12] [--surge-seed N]
-        try:
-            idx = sys.argv.index("--surge")
-            n_requests = 30
-            if idx + 1 < len(sys.argv) and not sys.argv[idx + 1].startswith("--"):
-                # "--"-prefixed means the next FLAG; a bare "-3" is a (bad)
-                # operand and must hit the usage check, not be ignored
-                n_requests = int(sys.argv[idx + 1])
-            surge_seed = 0
-            if "--surge-seed" in sys.argv:
-                surge_seed = int(sys.argv[sys.argv.index("--surge-seed") + 1])
-            if n_requests < 12:
-                raise ValueError(
-                    "n_requests must be >= 12 (room for two bursts + the "
-                    "high-priority stragglers)")
-        except (IndexError, ValueError) as e:
-            print(f"usage: bench.py --surge [n_requests >= 12] "
-                  f"[--surge-seed <int>] ({e})", file=sys.stderr)
-            sys.exit(2)
-        sys.exit(_surge(n_requests, surge_seed))
-    if "--gateway-chaos" in sys.argv:
-        # usage-error exit 2 on malformed values (same contract as
-        # --chaos/--chaos-serving/--surge)
-        try:
-            idx = sys.argv.index("--gateway-chaos")
-            if idx + 1 < len(sys.argv) and not sys.argv[idx + 1].startswith("--"):
-                raise ValueError(
-                    f"unexpected operand {sys.argv[idx + 1]!r} (the drill "
-                    "takes only --gateway-seed)")
-            gw_seed = 0
-            if "--gateway-seed" in sys.argv:
-                gw_seed = int(sys.argv[sys.argv.index("--gateway-seed") + 1])
-        except (IndexError, ValueError) as e:
-            print(f"usage: bench.py --gateway-chaos [--gateway-seed <int>] "
-                  f"({e})", file=sys.stderr)
-            sys.exit(2)
-        sys.exit(_gateway_chaos(gw_seed))
-    if "--disagg" in sys.argv:
-        # usage-error exit 2 on malformed values (same contract as
-        # --chaos/--chaos-serving/--surge/--gateway-chaos)
-        try:
-            idx = sys.argv.index("--disagg")
-            if idx + 1 < len(sys.argv) and not sys.argv[idx + 1].startswith("--"):
-                raise ValueError(
-                    f"unexpected operand {sys.argv[idx + 1]!r} (the drill "
-                    "takes only --disagg-seed)")
-            dg_seed = 0
-            if "--disagg-seed" in sys.argv:
-                dg_seed = int(sys.argv[sys.argv.index("--disagg-seed") + 1])
-        except (IndexError, ValueError) as e:
-            print(f"usage: bench.py --disagg [--disagg-seed <int>] ({e})",
-                  file=sys.stderr)
-            sys.exit(2)
-        sys.exit(_disagg_drill(dg_seed))
-    if "--chaos-replay" in sys.argv:
-        # usage-error exit 2 on malformed values (same contract as
-        # --chaos/--chaos-serving/--chaos-search)
-        try:
-            idx = sys.argv.index("--chaos-replay")
-            if idx + 1 >= len(sys.argv) or sys.argv[idx + 1].startswith("--"):
-                raise ValueError("missing FILE operand")
-            repro_path = sys.argv[idx + 1]
-        except (IndexError, ValueError) as e:
-            print(f"usage: bench.py --chaos-replay <chaos-repro.json> ({e})",
-                  file=sys.stderr)
-            sys.exit(2)
-        sys.exit(_chaos_replay(repro_path))
-    if "--chaos-search" in sys.argv:
-        # usage-error exit 2 on malformed values (same contract as
-        # --chaos/--chaos-serving/--surge)
-        try:
-            idx = sys.argv.index("--chaos-search")
-            cs_n = 64
-            if idx + 1 < len(sys.argv) and not sys.argv[idx + 1].startswith("--"):
-                # "--"-prefixed means the next FLAG; a bare "-3" is a (bad)
-                # operand and must hit the usage check, not be ignored
-                cs_n = int(sys.argv[idx + 1])
-            cs_seed = 0
-            if "--chaos-search-seed" in sys.argv:
-                cs_seed = int(
-                    sys.argv[sys.argv.index("--chaos-search-seed") + 1])
-            if cs_n < 1:
-                raise ValueError("n_schedules must be >= 1")
-        except (IndexError, ValueError) as e:
-            print(f"usage: bench.py --chaos-search [n_schedules >= 1] "
-                  f"[--chaos-search-seed <int>] ({e})", file=sys.stderr)
-            sys.exit(2)
-        sys.exit(_chaos_search(cs_n, cs_seed))
-    if "--chaos-serving" in sys.argv:
-        # usage-error exit 2 on malformed values (same contract as --chaos)
-        try:
-            chaos_seed = 0
-            if "--chaos-seed" in sys.argv:
-                chaos_seed = int(sys.argv[sys.argv.index("--chaos-seed") + 1])
-        except (IndexError, ValueError) as e:
-            print(f"usage: bench.py --chaos-serving [--chaos-seed <int>] ({e})",
-                  file=sys.stderr)
-            sys.exit(2)
-        sys.exit(_chaos_serving(chaos_seed))
-    if "--chaos" in sys.argv:
-        # usage-error exit 2 on malformed values (same contract as
-        # --fault-rate): --chaos [steps >= 6] [--chaos-seed <int>]
-        try:
-            idx = sys.argv.index("--chaos")
-            steps = 12
-            if idx + 1 < len(sys.argv) and not sys.argv[idx + 1].startswith("--"):
-                # "--"-prefixed means the next FLAG; a bare "-3" is a (bad)
-                # steps value and must hit the usage check, not be ignored
-                steps = int(sys.argv[idx + 1])
-            chaos_seed = 0
-            if "--chaos-seed" in sys.argv:
-                chaos_seed = int(sys.argv[sys.argv.index("--chaos-seed") + 1])
-            if steps < 6:
-                raise ValueError("steps must be >= 6 (room for 2 preempts + 1 NaN)")
-        except (IndexError, ValueError) as e:
-            print(f"usage: bench.py --chaos [steps >= 6] [--chaos-seed <int>] ({e})",
-                  file=sys.stderr)
-            sys.exit(2)
-        sys.exit(_chaos(steps, chaos_seed))
-    if os.environ.get(_CHILD_ENV) == "1":
-        main()
-    else:
-        sys.exit(_parent())
+    for flag, drill, operand, seed_flag in _DRILLS:
+        if flag in sys.argv:
+            sys.exit(drill(*_drill_args(flag, operand, seed_flag)))
+    print("usage: drills.py <drill>, one of", file=sys.stderr)
+    for flag, _, operand, seed_flag in _DRILLS:
+        print(f"  drills.py {_usage(flag, operand, seed_flag)}", file=sys.stderr)
+    sys.exit(2)

@@ -209,24 +209,9 @@ def pytest_sessionfinish(session, exitstatus):
     except Exception as e:  # noqa: BLE001 — advisory only, never fails a run
         print(f"[conftest] dstpu-audit verdict skipped: {e}")
 
-    # One-line BENCH-trajectory verdict beside the budget and lint lines:
-    # the r04/r05 flatline went unnoticed for two rounds — a full run now
-    # states the comparable-row regression verdict every session. Warn-only.
-    traj = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                        "bin", "bench_trajectory")
-    repo = os.path.dirname(os.path.dirname(__file__))
-    try:
-        proc = subprocess.run([sys.executable, traj, "--dir", repo],
-                              capture_output=True, text=True, timeout=30)
-        out = (proc.stdout.strip().splitlines()
-               + proc.stderr.strip().splitlines()) or ["no output"]
-        print(f"-- {out[-1]} (bin/bench_trajectory, warn-only) --")
-    except Exception as e:  # noqa: BLE001 — advisory only, never fails a run
-        print(f"[conftest] bench-trajectory verdict skipped: {e}")
-
     # One-line fault-site coverage verdict beside the others: every
     # FaultInjector site must keep at least one exercising tier-1 test or
-    # bench drill (docs/resilience.md "Chaos conductor"). The failing gate
+    # drill (docs/resilience.md "Chaos conductor"). The failing gate
     # is tests/test_chaos.py; this line keeps the registry/coverage state
     # visible on runs that deselect it. Warn-only by construction.
     cov = os.path.join(os.path.dirname(os.path.dirname(__file__)),

@@ -14,7 +14,7 @@ work, milliseconds each). Exactly ONE test builds real engines — on the
 session ``tiny_serving_engine`` shapes (n_slots 2, prompts [5, 11, 23],
 max_new 8: the test_serving parity set), so it adds no new XLA programs.
 The process-mode end of the loop (WorkerSupervisor spawn/respawn/retire)
-is proven by ``bench.py --surge``.
+is proven by ``drills.py --surge``.
 """
 
 import numpy as np

@@ -352,13 +352,13 @@ def test_chaos_runner_real_engine_schedule_green(tiny_serving_engine):
     assert all(r.status == "ok" for r in out.results.values())
 
 
-@pytest.mark.slow  # subprocess bench.py boot; the warm sibling is
+@pytest.mark.slow  # subprocess drills.py boot; the warm sibling is
 # test_faulted_runs_recover_green_across_seeds, which runs the same search
 # machinery in-process on the fake fleet every tier-1 pass
 def test_chaos_search_soak_subprocess(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"),
+        [sys.executable, os.path.join(REPO, "drills.py"),
          "--chaos-search", "8", "--chaos-search-seed", "1"],
         capture_output=True, text=True, timeout=300, cwd=str(tmp_path),
         env=env)

@@ -91,7 +91,7 @@ def test_compile_cache_rule(monkeypatch, tmp_path):
 
 
 @pytest.mark.parametrize("path", [
-    "chip_smoke.py", "bench.py", "tests/conftest.py",
+    "chip_smoke.py", "drills.py", "tests/conftest.py",
     "deepspeed_tpu/autotuning/trial_runner.py",
     "deepspeed_tpu/launcher/serving_worker.py",
 ])
@@ -109,9 +109,9 @@ def test_entry_points_take_the_cache_dir_from_the_helper(path):
     ("tpu", {"JAX_PLATFORMS": "cpu"}, False),    # the child is pinned off the chip
 ])
 def test_one_process_per_chip_guard(monkeypatch, held, child_env, raises):
-    """Every place that starts device children (bench.py's parent, the trial
-    scheduler, WorkerSupervisor, the elastic agent, probe_backend) asks this
-    first: a parent that holds the chip fails loudly instead of spawning."""
+    """Every place that starts device children (the trial scheduler,
+    WorkerSupervisor, the elastic agent, probe_backend) asks this first: a
+    parent that holds the chip fails loudly instead of spawning."""
     from deepspeed_tpu.utils import jax_env
 
     assert jax_env.holds_accelerator() is None  # this process runs on the CPU

@@ -1,6 +1,5 @@
 """Collective X-ray: HLO collective parsing, mesh-axis mapping, the ICI
-comm-time model, step-anatomy math, comm reconcile, and the
-bench-trajectory gate.
+comm-time model, step-anatomy math, and comm reconcile.
 
 Contracts under test:
 
@@ -19,18 +18,13 @@ Contracts under test:
     lazy resolution with bit-exact compile-count equality pre/post
     snapshot under watchdog raise — the X-ray adds zero XLA programs;
   * ``CommsLogger.summary()`` per-axis totals and ``reconcile()`` verdicts
-    (ok / unlogged-in-host / unseen-in-hlo);
-  * ``bin/bench_trajectory`` exit contract on synthetic rows AND on the
-    repo's real BENCH_r01..r05 record (r04/r05 named as excluded).
+    (ok / unlogged-in-host / unseen-in-hlo).
 
 Speed: everything here is host-side string/dict work except ONE tiny
 shard_map psum program (first run compiles it into tests/.xla_cache;
 warm runs load it).
 """
 
-import importlib.util
-import json
-import os
 import textwrap
 
 import numpy as np
@@ -40,8 +34,6 @@ from deepspeed_tpu.telemetry import Telemetry
 from deepspeed_tpu.telemetry.collective_ledger import (
     infer_axes, parse_hlo_collectives, pipeline_bubble_fraction,
     step_anatomy, summarize_collectives)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
@@ -426,15 +418,6 @@ def test_reconcile_canonicalizes_trivial_axes(clean_comms_logger):
     assert rows["data+fsdp"]["verdict"] == "ok"
 
 
-def test_trajectory_help_exits_zero(capsys):
-    # --help is SUCCESS under the 0/1/2 contract, not a usage error
-    traj = _load_trajectory()
-    assert traj.main(["--help"]) == 0
-    assert "regression" in capsys.readouterr().out.lower()
-    assert traj.main(["--no-such-flag"]) == 2
-    capsys.readouterr()
-
-
 def test_reconcile_warning_renders_in_report(clean_comms_logger):
     from deepspeed_tpu.telemetry.report import summarize
 
@@ -486,153 +469,3 @@ def test_disabled_collectives_skip_hlo_capture(mesh8):
     assert tm.ledger.collectives.programs == {}
     rows = {r["name"]: r for r in snap["step_anatomy"]}
     assert rows["test/psum-off"]["overlap_verdict"] == "none"
-
-
-# ---------------------------------------------------------------------------
-# bin/bench_trajectory
-# ---------------------------------------------------------------------------
-
-def _load_trajectory():
-    from importlib.machinery import SourceFileLoader
-
-    path = os.path.join(REPO, "bin", "bench_trajectory")
-    loader = SourceFileLoader("bench_trajectory", path)
-    spec = importlib.util.spec_from_loader("bench_trajectory", loader)
-    mod = importlib.util.module_from_spec(spec)
-    loader.exec_module(mod)
-    return mod
-
-
-def _write_rows(d, rows):
-    for i, parsed in enumerate(rows, 1):
-        obj = {"n": i}
-        if parsed is not None:
-            obj["parsed"] = parsed
-        with open(os.path.join(d, f"BENCH_r{i:02d}.json"), "w") as f:
-            json.dump(obj, f)
-
-
-def test_trajectory_on_the_real_repo_rows(tmp_path, capsys):
-    """Acceptance: the shipped BENCH/MULTICHIP record (r02..r04 of BENCH,
-    r02..r05 of MULTICHIP), with a synthetic failed run before it and a
-    synthetic CPU-fallback row after it, exits 0 and names the failed run,
-    r04 and the fallback as excluded non-comparable rows."""
-    import glob
-    import shutil
-
-    traj = _load_trajectory()
-    d = str(tmp_path)
-    for pat in ("BENCH_r*.json", "MULTICHIP_r*.json"):
-        for path in glob.glob(os.path.join(REPO, pat)):
-            shutil.copy(path, d)
-    with open(os.path.join(d, "BENCH_r01.json"), "w") as f:
-        json.dump({"n": 1, "rc": 1, "parsed": None}, f)  # crashed at init
-    with open(os.path.join(d, "BENCH_r05.json"), "w") as f:
-        json.dump({"n": 5, "rc": 0, "parsed": {
-            "value": 0.02, "tokens_per_sec_per_chip": 6458.3,
-            "platform": "cpu", "bench_stage": "cpu_fallback"}}, f)
-    assert traj.main(["--dir", d]) == 0
-    out = capsys.readouterr().out
-    assert "r04" in out and "r05" in out
-    assert out.count("EXCLUDED") >= 3  # r01 (failed run) + r04 + r05
-    assert "excluded: r01, r04, r05" in out
-    assert "multichip" in out
-
-
-def test_trajectory_regression_flags(tmp_path, capsys):
-    traj = _load_trajectory()
-    d = str(tmp_path)
-    _write_rows(d, [
-        {"platform": "tpu", "comparable": True,
-         "tokens_per_sec_per_chip": 100.0, "value": 10.0},
-        {"platform": "tpu", "comparable": True,
-         "tokens_per_sec_per_chip": 90.0, "value": 9.0},  # -10% tok/s
-    ])
-    assert traj.main(["--dir", d]) == 1
-    err = capsys.readouterr().err
-    assert "REGRESSION" in err and "tok/s" in err
-
-
-def test_trajectory_bridges_cpu_fallback_gap(tmp_path, capsys):
-    """A non-comparable row between two comparable ones is shown, excluded,
-    and the delta bridges OVER it (the r03 -> r04/r05 lesson)."""
-    traj = _load_trajectory()
-    d = str(tmp_path)
-    _write_rows(d, [
-        {"platform": "tpu", "comparable": True,
-         "tokens_per_sec_per_chip": 100.0},
-        {"platform": "cpu", "comparable": False,
-         "tokens_per_sec_per_chip": 5.0},  # dead-backend fallback
-        {"platform": "tpu", "comparable": True,
-         "tokens_per_sec_per_chip": 99.0},  # -1% vs r01: under threshold
-    ])
-    assert traj.main(["--dir", d]) == 0
-    out = capsys.readouterr().out
-    assert "r02  EXCLUDED" in out
-    assert "vs r01" in out  # r03 diffed against r01, not the cpu row
-
-
-def test_trajectory_mfu_drop_flags_and_stampless_rows_bridge(tmp_path, capsys):
-    traj = _load_trajectory()
-    d = str(tmp_path)
-    _write_rows(d, [
-        # pre-PR6 row without a `comparable` stamp: platform derives it
-        {"platform": "tpu", "tokens_per_sec_per_chip": 100.0, "mfu": 0.5},
-        {"platform": "tpu", "comparable": True,
-         "tokens_per_sec_per_chip": 101.0, "mfu": 0.4},  # -20% mfu
-    ])
-    assert traj.main(["--dir", d]) == 1
-    assert "mfu" in capsys.readouterr().err
-
-
-def test_trajectory_usage_errors(tmp_path, capsys):
-    traj = _load_trajectory()
-    assert traj.main(["--dir", str(tmp_path / "nope")]) == 2
-    empty = tmp_path / "empty"
-    empty.mkdir()
-    assert traj.main(["--dir", str(empty)]) == 2
-    assert traj.main(["--dir", str(tmp_path), "--threshold", "7"]) == 2
-    capsys.readouterr()
-
-
-def test_trajectory_format_json_emits_per_metric_delta_table(tmp_path,
-                                                             capsys):
-    """PR 15: ``--format json`` carries the per-metric delta table the
-    text report only printed inline, so the audit/lint/trajectory trio is
-    uniformly machine-readable. ``--json`` stays as an alias."""
-    traj = _load_trajectory()
-    d = str(tmp_path)
-    _write_rows(d, [
-        {"platform": "tpu", "comparable": True,
-         "tokens_per_sec_per_chip": 100.0, "mfu": 0.5},
-        {"platform": "tpu", "comparable": True,
-         "tokens_per_sec_per_chip": 110.0, "mfu": 0.4},  # -20% mfu
-    ])
-    assert traj.main(["--dir", d, "--format", "json"]) == 1
-    doc = json.loads(capsys.readouterr().out)  # stdout is PURE json
-    assert doc["tool"] == "bench_trajectory"
-    by_metric = {x["metric"]: x for x in doc["deltas"]}
-    tok = by_metric["tok/s/chip"]
-    assert tok["from"] == "r01" and tok["to"] == "r02"
-    assert tok["prev"] == 100.0 and tok["value"] == 110.0
-    assert abs(tok["delta_rel"] - 0.1) < 1e-9 and not tok["regressed"]
-    mfu = by_metric["mfu"]
-    assert mfu["regressed"] and mfu["gates"]
-    assert doc["threshold"] == pytest.approx(0.05)
-    assert doc["regressions"] and "mfu" in doc["regressions"][0]
-
-
-def test_trajectory_json_mode(tmp_path, capsys):
-    traj = _load_trajectory()
-    d = str(tmp_path)
-    _write_rows(d, [
-        {"platform": "tpu", "comparable": True,
-         "tokens_per_sec_per_chip": 100.0},
-        {"platform": "cpu", "comparable": False},
-    ])
-    assert traj.main(["--dir", d, "--json"]) == 0
-    out = capsys.readouterr().out
-    doc = json.loads(out)  # OK verdict goes to stderr in json mode
-    assert [r["comparable"] for r in doc["rows"]] == [True, False]
-    assert doc["excluded"] == ["r02"]
-    assert doc["regressions"] == []

@@ -495,7 +495,7 @@ def test_causal_tiles_pct_is_what_an_instrumented_run_counts(rows, pct, parent_p
 def test_key_block_is_as_coarse_as_its_bytes_allow(width, itemsize, block_k):
     """The key block is 2,048 keys where one block of the widest head holds
     ``KEY_BLOCK_BYTES`` or less (what the chip's compiler took:
-    tests/test_chip_compile.py), halved otherwise; the sub-tile count of the
+    tests/test_chip_compile_kernels.py), halved otherwise; the sub-tile count of the
     causal schedule does not depend on it."""
     from deepspeed_tpu.ops.pallas import flash_attention as fa
 

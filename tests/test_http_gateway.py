@@ -17,7 +17,7 @@ each, no device work). The upgrade state machine runs over host-only
 builds real engines — on the session ``tiny_serving_engine`` shapes
 (n_slots 2, the [5, 11, 23]/max_new-8 parity set test_serving cached), so
 it adds no new XLA programs. The multi-process TCP gateway drill is
-``bench.py --gateway-chaos``; its in-tree sibling here is the slow-tier
+``drills.py --gateway-chaos``; its in-tree sibling here is the slow-tier
 ``test_gateway_over_worker_process`` (warm sibling: the real-engine
 integration below).
 """
@@ -1173,7 +1173,7 @@ def test_gateway_real_engine_stream_parity_disconnect_and_upgrade(
 
 
 @pytest.mark.slow  # warm sibling: the real-engine integration above; the
-#                    full TCP drill is bench.py --gateway-chaos
+#                    full TCP drill is drills.py --gateway-chaos
 def test_gateway_over_worker_process(tmp_path):
     """ONE worker process behind the gateway over the real RPC transport:
     the step-piggybacked progress cache streams tokens with parity, and a

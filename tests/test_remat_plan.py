@@ -99,7 +99,7 @@ def test_candidates_follow_the_models_shapes(over, names, values):
 def test_a_head_narrower_than_the_lanes_takes_a_whole_tile_in_the_floor():
     """``flash_out`` lies [heads, rows, head width] on the device in 128-lane
     tiles: GPT-2 125M's 64-wide heads take a 128-wide head's room (the twin of
-    tests/test_chip_compile.py showed it: twice the values)."""
+    tests/test_chip_compile_whole.py showed it: twice the values)."""
     cfg = TransformerConfig(**{**PYTHIA, "hidden_size": 768, "num_heads": 12, "num_layers": 12,
                                "intermediate_size": None})
     assert tfm.remat_candidates(cfg) == (12 * 768 + 12 * 12 * 128, FFN[:1], 12 * 4 * 768)

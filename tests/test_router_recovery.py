@@ -3,7 +3,7 @@ recovery): the brain dies, the fleet doesn't.
 
 The contract under test (docs/serving.md "Crash-safe control plane"): a
 Router with a request journal can be ABANDONED mid-traffic (the in-process
-spelling of the ``bench.py --router-chaos`` SIGKILL — the deterministic
+spelling of the ``drills.py --router-chaos`` SIGKILL — the deterministic
 ``router_crash`` fault site provides the typed raise) and a NEW Router
 built over the same replicas + journal recovers with zero accepted-request
 loss: journaled terminals replay, in-flight requests still held by

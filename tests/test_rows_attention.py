@@ -5,7 +5,7 @@ sliced out of the stack, through ``_cache_attention``'s own ``attend`` (the
 write into the stack, the read, the form chosen from the shapes), and a
 grouped-query model's greedy tokens through the serving engine against
 ``apply``'s. What the form buys is a property of the program compiled for the
-chip: ``tests/test_chip_compile.py`` holds that.
+chip: ``tests/test_chip_compile_caches.py`` holds that.
 """
 
 import jax

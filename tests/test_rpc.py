@@ -12,7 +12,7 @@ Speed discipline: everything here is host-only or reuses the session
 — the test_serving parity set); remote replicas are REAL ServingEngines
 hosted by an ``RpcServer`` in a background thread, so no new XLA programs
 and no process boots. Real worker processes are covered by
-tests/test_serving_worker.py and the ``bench.py --chaos-serving`` drill.
+tests/test_serving_worker.py and the ``drills.py --chaos-serving`` drill.
 """
 
 import os

@@ -7,7 +7,7 @@ config) and inherits ``tests/.xla_cache`` + the pytest RNG flags through
 the environment, so its programs are cache loads, never new shapes — but
 they do pay interpreter+jax boot per process, so the warm tier keeps
 exactly ONE spawn; the respawn/failover drill with a second process is
-slow-tier (the real kill-9 parity drill is ``bench.py --chaos-serving``).
+slow-tier (the real kill-9 parity drill is ``drills.py --chaos-serving``).
 """
 
 import json
@@ -237,7 +237,7 @@ def test_respawn_budget_heals_after_sustained_health(tmp_path):
 
 @pytest.mark.slow  # second+third process boots (~15s/family); the warm
 # siblings above keep spawn/drain/heartbeat coverage on BOTH families
-# (unix roundtrip + tcp roundtrip), and bench.py --chaos-serving /
+# (unix roundtrip + tcp roundtrip), and drills.py --chaos-serving /
 # --surge are the full kill-9 parity drills
 @pytest.mark.parametrize("family", ["unix", "tcp"])
 def test_supervisor_kill9_respawn_and_router_reattach(tiny_serving_engine,

@@ -18,7 +18,7 @@ driven through real ``ServingEngine``/``Router`` instances over the
 session ``tiny_serving_engine`` shapes (n_slots 2, the [5, 11, 23]/
 max_new-8 parity set — no new programs); the gateway tests ride a
 host-only fake router like test_http_gateway. The multi-process drill is
-``bench.py --tenant-chaos``.
+``drills.py --tenant-chaos``.
 """
 
 import hashlib
