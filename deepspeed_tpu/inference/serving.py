@@ -882,6 +882,16 @@ class SlotWorker:
         return {"window_layers": self.window_layers,
                 "ring_tokens": int(np.sum(np.minimum(p + 1, self.cfg.local_attn_window)))}
 
+    def _expert_gemm(self, rows: int) -> dict:
+        """``expert_gemm`` of a routed model's call of ``rows`` tokens (a decode
+        step's: every slot; a verify step's: every slot's block): what multiplies
+        them through the experts, by the rule the program was traced by
+        (``dropless.expert_gemm_form``). Nothing for a model with no routed layer."""
+        if not self.expert_bank:
+            return {}
+        return {"expert_gemm": expert_gemm_form(self.cfg, self.params["moe"]["experts"], int(rows),
+                                                self.expert_bank == "in_place")}
+
     def _block_attrs(self, rows: int, live: int) -> dict:
         """What a prefill or chunk span says of the block its program was traced
         with. A state-space mixer's scan: the chunks it ran (those of the
@@ -899,9 +909,7 @@ class SlotWorker:
         Nothing for a model with neither."""
         attrs = {}
         if self.expert_bank:
-            attrs = {"expert_bank": self.expert_bank,
-                     "expert_gemm": expert_gemm_form(self.cfg, self.params["moe"]["experts"],
-                                                     rows, self.expert_bank == "in_place")}
+            attrs = {"expert_bank": self.expert_bank, **self._expert_gemm(rows)}
         if self.state_bytes_per_slot:
             attrs.update(state_rows=int(live), **self.operator_attrs)
             if self.cfg.ssm_state_size:
@@ -1089,7 +1097,8 @@ class SlotWorker:
         prev = self._pending
         attrs = dict(n_active=n_active, cached_tokens=int(np.sum(live_pos + 1)),
                      attn=tfm.cache_step_form(self.cfg), **self._state_attrs(n_active),
-                     **self._ring_attrs(live_pos), **self.pass_attrs, ahead=prev is not None)
+                     **self._ring_attrs(live_pos), **self.pass_attrs,
+                     **self._expert_gemm(len(active)), ahead=prev is not None)
         if self.kv_block:
             attrs["kv_rows_fetched"] = tfm.kv_rows_fetched(live_pos, self.kv_block)
 
@@ -1172,7 +1181,7 @@ class SlotWorker:
                      cached_tokens=int(np.sum(
                          (np.asarray(pos) + depth + 1)[np.asarray(active, bool)])),
                      attn=tfm.cache_step_form(self.cfg), **({"warm": True} if warm else {}),
-                     **self.pass_attrs)
+                     **self.pass_attrs, **self._expert_gemm(len(active) * (depth + 1)))
         # host arrays go straight into the jitted call: pjit's C++ argument
         # path uploads them in one batch, and the greedy family's trimmed
         # signature (no rng/temp/top_k/top_p — dead operands there) skips the

@@ -25,11 +25,16 @@ one has no capacity and no ``[T, E, C]`` tensor at any skew:
   says which; PERF.md §6, PR 46 has the chip's timings of both at every
   cell's shapes: 1.28 -> 0.6 ms a projection at OLMoE's).
 * ``experts_dense``: every expert on every row, each expert's weights read
-  once; for the few rows of a decode step, where the sorted form reads the
-  same bytes and pays a sort, two gathers and near-empty tiles on top
-  (PERF.md §6, PR 27 has the chip's timings of both, taken against the
-  compiler's 512-row tile; PR 46's table has the sorted form's grouped matmuls
-  alone at 256 and 512 rows a call under both kernels).
+  once; for the few rows of a decode step where they touch about every expert,
+  so that the sorted form reads the same bytes and pays a sort, two gathers
+  and near-empty tiles on top (PERF.md §6, PR 27 has the chip's timings of
+  both, taken against the compiler's 512-row tile; PR 46's table has the
+  sorted form's grouped matmuls alone at 256 and 512 rows a call under both
+  kernels). Where a step's rows leave a share of a whole bank untouched (24
+  rows x 6 choices of 128 experts touch two thirds), a serving program on the
+  chip takes the sorted form through the kernel instead, the pairs padded to
+  its row tile: an expert no row chose is no visit and its matrices are never
+  read (``expert_gemm_form``, ``sorted_ahead``; PERF.md §6, PR 62).
 
 * the shared expert (``moe_shared_size``): one more gated MLP that every row
   goes through, a plain matmul beside the routed sum and not a group of the
@@ -88,15 +93,39 @@ from .experts import experts_logical_axes
 # then, and 1.9 / 2.0, 2.0 / 3.6, 3.4 / 3.7, 6.1 / 4.3, 13.2 / 5.2 held in
 # bfloat16: up to 512 the dense form won either way. Those sorted timings were
 # of ``lax.ragged_dot``'s 512-row tile, the bank sliced out a layer at a time.
-# Which form each row count takes today: up to 512 rows the dense form, on any
-# platform; over 512 the sorted form, whose grouped matmuls are the Pallas
-# kernel's in a serving program on the chip and ``ragged_dot``'s elsewhere
-# (``expert_gemm_form``). Under that kernel the three grouped matmuls of a
-# 256- or 512-row call are about half what they were (PERF.md §6, PR 46: the
-# table's last four rows), so the sorted form may now overtake the dense one
-# below 512; the constant stays until the two are timed as whole blocks on
-# held stacks (ROADMAP S2(i)).
+# Which form each row count takes today (``expert_gemm_form``): over 512 rows
+# the sorted form, whose grouped matmuls are the Pallas kernel's in a serving
+# program on the chip and ``ragged_dot``'s elsewhere; up to 512 the dense form,
+# on any platform, EXCEPT where a serving program on the chip holds a whole
+# bank of which the call's rows leave enough untouched (``sorted_ahead``, PR
+# 62): there the sorted form through the kernel, which reads the banks the rows
+# chose and no other. The 256- to 512-row end is still this constant's: under
+# the kernel the three grouped matmuls of such a call are about half what they
+# were (PERF.md §6, PR 46: the table's last four rows), so the sorted form may
+# overtake the dense one there too, where every expert is touched and the
+# question is the MXU's, not the bytes'; not timed as whole blocks (ROADMAP
+# S2(i)), and ``chipbench/kinds_cost.py::SORTED_FORM_ROWS`` mirrors the constant.
 DENSE_ROWS = 512
+
+# The few-rows end, from the chip's timings of one layer's block whole (the
+# route excluded) on held stacks at the four whole-bank decode shapes of the
+# benchmark (PERF.md §6, PR 62; ms, dense / sorted through the kernel at a
+# 128-row tile and three bank blocks, and the experts touched): kanana 24 rows x
+# 6 of 128: 1.72 / 1.15, 87 touched; OLMoE 16 x 8 of 64: 1.27 / 1.01, 57;
+# Mellum2 32 x 8 of 64: 1.17 / 1.08, 62; LFM2 128 x 4 of 64: 1.76 / 1.69, 64.
+# At every shape the sorted block took what its touched banks take at 711 to
+# 714 GB/s, the sort, the count, the gathers and the combine (0.03 ms at 144 to
+# 256 pairs, 0.07 at 512) included; the dense form streams all of them at 637
+# to 701 GB/s alone and about 5% faster inside a decode program (kanana's cell:
+# 1.63 ms a layer). So the forms are compared by their bytes.
+# ``SORTED_FIXED_BYTES``: what the sorted form's fixed work is worth in bytes
+# streamed (0.045 ms: the 0.03 to 0.07 above and the first block's exposed wait
+# of each of the three kernel calls). ``SORTED_AHEAD``: the sorted form is taken
+# where its bytes are at most this share of the dense form's; where no bank is
+# skipped the two are within the 4 to 8% that the block alone and the block in
+# its program differ by, and the form stays the one every cell was timed in.
+SORTED_FIXED_BYTES = 32 * 2 ** 20
+SORTED_AHEAD = 0.95
 
 # The standard deviation the selection bias is DRAWN with. The published model
 # starts it at zero and moves it by the experts' load, never by the loss; at
@@ -209,32 +238,53 @@ def _gated(gate, up):
     return jax.nn.silu(gate) * up
 
 
+def sorted_ahead(rows: int, top_k: int, experts: int, bank_bytes: int) -> bool:
+    """Whether the sorted form through the kernel is ahead of the dense one at
+    ``rows`` rows of a decode or verify step (up to ``DENSE_ROWS``), by what each
+    must stream: the dense form every expert's three matrices (``bank_bytes``, a
+    layer's), the sorted form those of the experts an even router's ``rows`` x
+    ``top_k`` choices touch, u = 1 - (1 - k / E) ** rows of them (a trained or a
+    seeded router is more uneven and touches fewer), and ``SORTED_FIXED_BYTES``
+    on top."""
+    touched = 1.0 - (1.0 - top_k / experts) ** rows
+    return touched * bank_bytes + SORTED_FIXED_BYTES <= SORTED_AHEAD * bank_bytes
+
+
 def expert_gemm_form(cfg, bank, rows: int, in_place: bool) -> str:
     """What multiplies a call of ``rows`` tokens through the routed experts:
-    ``"dense"`` (``experts_dense``: up to ``DENSE_ROWS``), ``"ragged_dot"`` (the
-    sorted forms over the compiler's grouped GEMM) or ``"gmm<tm>"`` (the sorted
-    forms over ``ops/pallas/grouped_gemm.py`` at a row tile of ``tm``). The
-    kernel is taken where the code can see it may: on the ``tpu`` platform (on
-    the CPU every routed test would pay the Pallas interpreter), by a program
-    that only runs forward (``in_place``: the held stacks, which
-    ``transformer.expert_bank_form`` grants to serving programs alone; the kernel
-    has no backward pass), at shapes it tiles (``gmm_tiling``, from the rows of a
-    call and the bank's widths). ``moe_ffn_dropless`` traces by this and
-    ``SlotWorker`` labels its ``prefill`` and ``chunk`` spans by it
-    (``expert_gemm``)."""
-    if rows <= DENSE_ROWS:
-        return "dense"
+    ``"dense"`` (``experts_dense``), ``"ragged_dot"`` (the sorted forms over the
+    compiler's grouped GEMM) or ``"gmm<tm>"`` (the sorted forms over
+    ``ops/pallas/grouped_gemm.py`` at a row tile of ``tm``). The kernel is taken
+    where the code can see it may: on the ``tpu`` platform (on the CPU every
+    routed test would pay the Pallas interpreter), by a program that only runs
+    forward (``in_place``: the held stacks, which ``transformer.expert_bank_form``
+    grants to serving programs alone; the kernel has no backward pass), at
+    shapes it tiles (``gmm_tiling``, from the rows of a call and the bank's
+    widths). Over ``DENSE_ROWS`` rows the form is a sorted one. Up to them it is
+    the dense one, but for a whole bank that may go through the kernel and whose
+    rows leave enough of its experts untouched (``sorted_ahead``, from the rows,
+    ``moe_top_k`` and the bank's shape): the kernel reads no matrix of an
+    expert no row chose, the dense form reads them all. A held share keeps the
+    dense form at few rows (its sorted form is a loop of trips).
+    ``moe_ffn_dropless`` traces by this and ``SlotWorker`` labels its spans by
+    it (``expert_gemm``)."""
+    few = rows <= DENSE_ROWS
     if in_place and jax.default_backend() == "tpu":
-        from ..ops.pallas.grouped_gemm import gmm_tiling
+        from ..ops.pallas.grouped_gemm import gmm_tiling, whole_tiles
 
         count, M, F = bank["wi"].shape[-3:]
+        whole = count == cfg.num_experts
         m = rows * cfg.moe_top_k
-        if count < cfg.num_experts:
+        if not whole:
             m = held_chunk_rows(m, count, cfg.num_experts)
+        elif few:  # ``experts_sorted`` pads a step's pairs
+            m = whole_tiles(m)
         tiles = gmm_tiling(m, M, F), gmm_tiling(m, F, M)
-        if all(tiles):
+        bank_bytes = 3 * count * M * F * jnp.dtype(bank["wi"].dtype).itemsize
+        if all(tiles) and (not few or (whole and sorted_ahead(rows, cfg.moe_top_k, count,
+                                                              bank_bytes))):
             return f"gmm{tiles[0][0]}"
-    return "ragged_dot"
+    return "dense" if few else "ragged_dot"
 
 
 def _grouped_dot(xs, w, sizes, kernel: bool):
@@ -261,12 +311,18 @@ def experts_sorted(bank, x, weights, experts, layer=None, kernel: bool = False):
         flat = layer * bank["wi"].shape[1] + flat
         bank = {name: leaf.reshape((-1,) + leaf.shape[2:]) for name, leaf in bank.items()}
     sizes = jnp.zeros((bank["wi"].shape[0],), jnp.int32).at[flat].add(1)
-    xs = x[order // k]
+    pad = 0
+    if kernel:  # whole row tiles (a decode step's pairs: 144 -> 256)
+        from ..ops.pallas.grouped_gemm import whole_tiles
+
+        pad = whole_tiles(T * k) - T * k
+    # the rows past the pairs are no group's: no visit stores them, no token gathers them
+    xs = x[(jnp.concatenate([order, jnp.zeros((pad,), order.dtype)]) if pad else order) // k]
     w = {name: leaf.astype(x.dtype) for name, leaf in bank.items()}
     dot = partial(_grouped_dot, sizes=sizes, kernel=kernel)
     h = _gated(dot(xs, w["wg"]), dot(xs, w["wi"]))
     ys = dot(h, w["wo"])  # [T * k, M], still sorted
-    ys = ys[jnp.argsort(order)].reshape(T, k, M)
+    ys = ys[jnp.argsort(order)].reshape(T, k, M)  # pair p's row: none past the pairs
     return jnp.einsum("tkm,tk->tm", ys.astype(jnp.float32), weights).astype(x.dtype)
 
 

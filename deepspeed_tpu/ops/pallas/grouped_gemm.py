@@ -48,10 +48,20 @@ from jax.experimental.pallas import tpu as pltpu
 from . import interpret_default
 
 LANES = 128  # the MXU's width, and the least a block's last dimension may be
-# What the two buffers of a step's bank block may take of VMEM (the chip has
-# 128 MiB; the compiler gives a kernel 16 MiB unless told otherwise, and is told
+# The row tile, at every number of rows: the MXU's width (``gmm_tiling`` has the
+# prefill shapes' table). At the 144 to 512 pairs of a decode step, where a group
+# is one visit of a row or two, tiles of 16, 32 and 64 rows read within 0.5% of
+# it at every shape (PERF.md §6, PR 62): a visit then lasts as long as its
+# block's copy, not as its rows' multiplying.
+ROW_TILE = LANES
+# What ONE of the kernel's blocks of the bank may take of VMEM (the chip has 128
+# MiB; the compiler gives a kernel 16 MiB unless told otherwise, and is told
 # here what the blocks add up to: ``_vmem_limit``).
-BANK_BLOCK_BYTES = 24 * 2 ** 20
+BANK_BLOCK_BYTES = 12 * 2 ** 20
+# The rows of a call up to which the kernel keeps THREE blocks of the bank and
+# not two (``bank_blocks``): 16 row tiles, over the routed layers' 64 to 128
+# experts a row or two a group.
+FEW_ROWS = 2048
 # The instruction's name in the compiled program and the operation's in a device
 # trace: the grouped matmul of the routed feed-forward under whichever kernel, so
 # what finds ``lax.ragged_dot``'s seconds there (``ragged-dot``) finds these.
@@ -61,11 +71,11 @@ KERNEL_NAME = "ragged-dot-gmm"
 def visits(group_sizes, m: int, tm: int):
     """The grid's visits axis, from the groups' sizes: -> (offsets [G + 1], the
     row each group starts at; group [m / tm + G - 1] and tile [same], what visit
-    i multiplies; following [G], the next group after g that visits a tile (G:
-    none) and nth [G], g's place among those that do; how many visits there
-    are). A group visits the tiles from the one its first row lies in to the one
-    its last row lies in; an empty group visits none. Visits past the count
-    repeat the last one and are not run."""
+    i multiplies; following [G + 1], the next group after g that visits a tile
+    (G: none, and none follows it) and nth [G], g's place among those that do;
+    how many visits there are). A group visits the tiles from the one its first
+    row lies in to the one its last row lies in; an empty group visits none.
+    Visits past the count repeat the last one and are not run."""
     G = group_sizes.shape[0]
     ends = jnp.cumsum(group_sizes)
     starts = ends - group_sizes
@@ -79,7 +89,7 @@ def visits(group_sizes, m: int, tm: int):
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends.astype(jnp.int32)])
     ids = jnp.arange(G, dtype=jnp.int32)
     later = lax.cummin(jnp.where(n_tiles > 0, ids, G), reverse=True)  # first visiting group >= g
-    following = jnp.concatenate([later[1:], jnp.full((1,), G, jnp.int32)])
+    following = jnp.concatenate([later[1:], jnp.full((2,), G, jnp.int32)])  # and none follows none
     nth = jnp.cumsum(n_tiles > 0, dtype=jnp.int32) - 1
     return (offsets, group, tile.astype(jnp.int32), following, nth), upto[-1].astype(jnp.int32)
 
@@ -88,30 +98,40 @@ def _gmm_kernel(offsets, group, tile, following, nth, lhs, rhs, out, bank, arriv
                 tm: int, tn: int):
     """One visit: ``lhs`` [tm, K] and ``out`` [tm, tn] are the tile's blocks, brought
     and taken by the grid's own pipeline; ``rhs`` is the whole bank where it lies
-    in HBM, and ``bank`` [2, K, tn] the two column blocks of it this kernel keeps:
-    the visiting group's, and the following group's, whose copy starts at the
-    group's first visit and is waited for at its own, so it has all of a group's
-    visits to arrive in (the grid's pipeline would start it at the group's LAST
-    visit: a 4 MB block against one 128-row visit's 2.7 us of multiplying)."""
+    in HBM, and ``bank`` [buffers, K, tn] the column blocks of it this kernel keeps:
+    the visiting group's and those of the ``buffers - 1`` groups that visit after
+    it, the last of whose copies starts at the group's first visit and is waited
+    for at its own, so it has all of the visits in between to arrive in (the
+    grid's pipeline would start it at the group's LAST visit: a 4 MB block
+    against one 128-row visit's 2.7 us of multiplying)."""
     n, i = pl.program_id(0), pl.program_id(1)
     g = group[i]
-    slot = nth[g] % 2
+    buffers, G = bank.shape[0], rhs.shape[0]
+    slot = nth[g] % buffers
 
     def block(of, into):
         return pltpu.make_async_copy(rhs.at[of, :, pl.ds(n * tn, tn)], bank.at[into],
                                      arrived.at[into])
 
+    def start(of, into):
+        @pl.when(of < G)
+        def _():
+            block(of, into).start()
+
     @pl.when(i == 0)
     def _():
-        block(g, slot).start()
+        of = g  # the first group that visits: nth 0
+        for into in range(buffers - 1):
+            start(of, into)
+            of = following[of]
 
     @pl.when((i == 0) | (group[jnp.maximum(i - 1, 0)] != g))
     def _():
         block(g, slot).wait()
-
-        @pl.when(following[g] < rhs.shape[0])
-        def _():
-            block(following[g], 1 - slot).start()
+        of = g
+        for _ in range(buffers - 1):
+            of = following[of]
+        start(of, (nth[g] + buffers - 1) % buffers)  # the block of the group before g: done with
 
     product = jnp.dot(lhs[...], bank[slot], preferred_element_type=jnp.float32)
     rows = tile[i] * tm + lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
@@ -119,16 +139,16 @@ def _gmm_kernel(offsets, group, tile, following, nth, lhs, rhs, out, bank, arriv
     out[...] = jnp.where(mine, product.astype(out.dtype), out[...])
 
 
-def _vmem_limit(tm, K, tn, itemsize):
-    """The bank's two blocks, the rows' and the output's twice (the grid's
+def _vmem_limit(tm, K, tn, itemsize, buffers):
+    """The bank's blocks, the rows' and the output's twice (the grid's
     pipeline double-buffers them), the float32 product of a visit, and a
     quarter over for what the compiler adds."""
-    held = 2 * (K * tn + tm * K + tm * tn) * itemsize + tm * tn * 4
+    held = (buffers * K * tn + 2 * (tm * K + tm * tn)) * itemsize + tm * tn * 4
     return max(16 * 2 ** 20, 5 * held // 4)
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
-def _gmm(lhs, rhs, group_sizes, tm, tn, interpret):
+@functools.partial(jax.jit, static_argnames=("tm", "tn", "buffers", "interpret"))
+def _gmm(lhs, rhs, group_sizes, tm, tn, buffers, interpret):
     m, K = lhs.shape
     G, _, N = rhs.shape
     scalars, count = visits(group_sizes, m, tm)
@@ -142,17 +162,39 @@ def _gmm(lhs, rhs, group_sizes, tm, tn, interpret):
             in_specs=[pl.BlockSpec((tm, K), lambda n, i, offsets, group, tile, *_: (tile[i], 0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((tm, tn), lambda n, i, offsets, group, tile, *_: (tile[i], n)),
-            scratch_shapes=[pltpu.VMEM((2, K, tn), rhs.dtype), pltpu.SemaphoreType.DMA((2,))],
+            scratch_shapes=[pltpu.VMEM((buffers, K, tn), rhs.dtype),
+                            pltpu.SemaphoreType.DMA((buffers,))],
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(tm, K, tn, itemsize)),
+            vmem_limit_bytes=_vmem_limit(tm, K, tn, itemsize, buffers)),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * K * N, transcendentals=0,
             bytes_accessed=(m * K * (N // tn) + K * N * min(G, m) + m * N) * itemsize),
         interpret=interpret,
         name=KERNEL_NAME,
     )(*scalars, lhs, rhs)
+
+
+def bank_blocks(m: int) -> int:
+    """How many blocks of the bank the kernel keeps over ``m`` rows: the visiting
+    group's and the next one's, or the next two's. With two, a group's copy
+    starts when the group before it begins: early enough where a group is several
+    visits (a prefill's 64 to 384 rows a group), but where a group is ONE visit (a
+    decode step's pairs: 24 rows x 6 choices touch 87 experts, a row or two each)
+    one copy is in flight at a time, with a gap at every wait. On the chip
+    (PERF.md §6, PR 62), one layer's routed block at the four decode shapes, two
+    blocks / three (ms): 1.26 / 1.15, 1.08 / 1.01, 1.16 / 1.08, 1.78 / 1.69; one
+    projection at 2,048 to 8,192 rows 4 to 8% faster with three at 32 to 128 rows
+    a group, at 16,384 rows over OLMoE's 64 experts (256 a group) 5 to 7% SLOWER,
+    at kanana's 49,152 over 128 within 0.5%. No program the benchmark ran before
+    PR 62 hands the kernel ``FEW_ROWS`` rows or fewer: they keep two."""
+    return 3 if m <= FEW_ROWS else 2
+
+
+def whole_tiles(m: int) -> int:
+    """``m`` rows up to whole row tiles: what a caller that may pad hands over."""
+    return -(-m // ROW_TILE) * ROW_TILE
 
 
 def gmm_tiling(m: int, K: int, N: int, itemsize: int = 2):
@@ -162,14 +204,15 @@ def gmm_tiling(m: int, K: int, N: int, itemsize: int = 2):
     of the four routed cells, groups of 64 to 384 rows in the mean). Rows: the
     MXU's width; 256 read 6 to 30% over it and 512 45 to 105% at every shape,
     64 within 6% under it at eight and 10% over at one. Columns: all of them
-    where two blocks of the bank fit ``BANK_BLOCK_BYTES`` (the rows are then
-    read once), else halved until they do; at 512 every shape read 8 to 27%
-    more."""
-    tm = LANES
+    where a block of the bank fits ``BANK_BLOCK_BYTES`` (the rows are then
+    read once), else halved until it does; at 512 every shape read 8 to 27%
+    more. How many blocks of the bank the kernel keeps is ``bank_blocks``'s,
+    from the rows too."""
+    tm = ROW_TILE
     if m % tm:
         return None
     tn = N
-    while 2 * K * tn * itemsize > BANK_BLOCK_BYTES and tn % (2 * LANES) == 0:
+    while K * tn * itemsize > BANK_BLOCK_BYTES and tn % (2 * LANES) == 0:
         tn //= 2
     return tm, tn
 
@@ -183,4 +226,5 @@ def grouped_matmul(lhs, rhs, group_sizes, tiling, interpret: bool | None = None)
         raise ValueError(f"tiling {tiling} does not divide {lhs.shape} x {rhs.shape}")
     if interpret is None:
         interpret = interpret_default()
-    return _gmm(lhs, rhs, group_sizes.astype(jnp.int32), tm, tn, interpret)
+    return _gmm(lhs, rhs, group_sizes.astype(jnp.int32), tm, tn, bank_blocks(lhs.shape[0]),
+                interpret)

@@ -115,13 +115,15 @@ def _loop_depths(jaxpr, primitive, depth=0):
     return found
 
 
-def _walk_built_once(worker, params, cache, n, sds, loops):
+def _walk_built_once(worker, params, cache, n, sds, loops, grouped=0):
     """The decode kernel's work list (a ``cumsum`` over the rows' blocks; the sampler's
     nucleus has the program's other one) is built outside the layer loop(s), once a
-    step, and the kernel stands ``loops`` loops deep."""
+    step, and the kernel stands ``loops`` loops deep. ``grouped``: the grouped-matmul
+    kernel calls beside it in the layer loop (three where a routed step's few rows take
+    the sorted form, PR 62), whose visits are counted (``cumsum``) per layer, by nature."""
     jaxpr = worker._build_decode().trace(*_decode_operands(params, cache, n, sds)).jaxpr.jaxpr
-    assert set(_loop_depths(jaxpr, "cumsum")) == {0}
-    assert _loop_depths(jaxpr, "pallas_call") == [loops]
+    assert set(_loop_depths(jaxpr, "cumsum")) == ({0, loops} if grouped else {0})
+    assert _loop_depths(jaxpr, "pallas_call") == [loops] * (1 + grouped)
 
 
 def _compile_prefill(worker, params, cache, bucket, sds):

@@ -62,7 +62,10 @@ def test_decode_program_keeps_the_slot_cache_in_place(family, v5e, no_persistent
     text = compiled.as_text()
     assert ("tpu_custom_call" in text) == (family != "bloom_dense_alibi")
     if family != "bloom_dense_alibi":
-        _walk_built_once(worker, params, cache, n, sds, loops=1)
+        # OLMoE's 8 rows x 8 choices of 64 leave a third of a layer's experts untouched: its
+        # step's routed layers take the grouped-matmul kernel (PR 62), three calls a layer
+        _walk_built_once(worker, params, cache, n, sds, loops=1,
+                         grouped=3 if family == "olmoe_dropless" else 0)
     whole = re.escape(f"bf16[{L},{n},{Smax},{H},{Dh}]")
     copies = re.findall(rf"^\s*%?[\w.-]+ = {whole}\S* copy\(", text, re.M)
     assert not copies, f"the decode step copies the whole slot cache: {copies}"
@@ -333,11 +336,17 @@ def test_dropless_expert_block_reads_the_held_stacks_in_place(v5e, no_persistent
      2048, (2560, "gmm128")),
     ("lfm2", dict(hidden_size=2048, intermediate_size=1536, num_experts=64, moe_top_k=4,
                   moe_score_fn="sigmoid"), 1024, (4096, "gmm128")),
+    # a decode step's rows (PR 62): the pairs padded to whole row tiles (144 -> 256, 128 -> 128)
+    ("kanana-decode", dict(hidden_size=2048, intermediate_size=768, num_experts=128, moe_top_k=6,
+                           moe_score_fn="sigmoid", moe_shared_size=1536), 24, (256, "gmm128")),
+    ("olmoe-decode", dict(hidden_size=2048, intermediate_size=1024, num_experts=64, moe_top_k=8),
+     16, (128, "gmm128")),
 ], ids=lambda v: v if isinstance(v, str) else None)
 def test_dropless_expert_block_takes_the_grouped_matmul_kernel(cell, widths, rows, tile, v5e,
                                                                no_persistent_cache, as_tpu):
     """The routed block of the four routed cells (their widths, experts and
-    choices; two layers of held stacks; a prefill bucket of each) in one scan,
+    choices; two layers of held stacks; a prefill bucket of each, and of the two
+    whose decode step leaves banks untouched the step's rows, PR 62) in one scan,
     under ``as_tpu``: the sorted forms go through ``ops/pallas/grouped_gemm.py`` at
     the tile the rule picks (PR 46), which the chip's compiler takes (its VMEM,
     its alignment: what the interpreter cannot show). The loop body holds three
@@ -363,7 +372,8 @@ def test_dropless_expert_block_takes_the_grouped_matmul_kernel(cell, widths, row
                          "wo": sds((L, S, M), jnp.bfloat16)}
     pairs, form = tile
     assert expert_gemm_form(cfg, moe["experts"], rows, True) == form
-    assert expert_gemm_form(cfg, moe["experts"], rows, False) == "ragged_dot"
+    assert expert_gemm_form(cfg, moe["experts"], rows, False) == ("ragged_dot" if rows > 512
+                                                                  else "dense")
 
     def in_place(moe, h):
         def body(h, xs):

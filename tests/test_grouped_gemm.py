@@ -107,7 +107,32 @@ def test_the_rule_picks_the_tile_from_the_shapes(m, K, N, want):
     if want:
         tm, tn = want
         assert m % tm == 0 and N % tn == 0
-        assert 2 * K * tn * 2 <= grouped_gemm.BANK_BLOCK_BYTES
+        assert K * tn * 2 <= grouped_gemm.BANK_BLOCK_BYTES
+
+
+@pytest.mark.parametrize("buffers", [2, 3, 4])
+def test_kernel_agrees_at_every_number_of_bank_blocks(buffers, monkeypatch):
+    """The kernel keeps the visiting group's block of the bank and those of the
+    groups that visit next (``bank_blocks``: two, or three at few rows); with one visit a group, as
+    a decode step's pairs lie (a row or two an expert, most experts of a layer,
+    the other layers' groups empty), every visit waits for its own block and
+    starts the one ``buffers - 1`` groups on, into the block of the group before."""
+    monkeypatch.setattr(grouped_gemm, "bank_blocks", lambda m: buffers)
+    rng = np.random.default_rng(buffers)
+    sizes = [0] * 24 + [int(n) for n in rng.integers(0, 4, 24)]  # the second layer of two
+    sizes[30] += 150 - sum(sizes)  # ... and one group that crosses the tile's edge
+    lhs, rhs, group_sizes = _operands(256, sizes, seed=2)
+    out = grouped_matmul(lhs, rhs, group_sizes, (128, 256), interpret=True)
+    _close(out, lax.ragged_dot(lhs, rhs, group_sizes), 150)
+
+
+@pytest.mark.parametrize("m,blocks", [(128, 3), (256, 3), (2048, 3), (2560, 2), (4096, 2),
+                                      (16384, 2), (49152, 2)])
+def test_three_blocks_of_the_bank_at_few_rows_alone(m, blocks):
+    """A decode step's padded pairs (128 to 512 rows) keep three blocks; every
+    call the prefill and chunk programs make (2,560 rows, K-EXAONE's held chunk,
+    and up) keeps the two it was timed with."""
+    assert grouped_gemm.bank_blocks(m) == blocks
 
 
 def test_a_tiling_that_does_not_divide_is_refused():
@@ -159,3 +184,82 @@ def test_routed_block_is_the_same_with_the_kernel_and_without(config, kernel_on_
     assert np.isfinite(with_kernel).all()
     np.testing.assert_allclose(with_kernel, without, rtol=2e-2,
                                atol=2e-2 * float(np.abs(without).max()))
+
+
+# configuration: (its cell, what a decode step of the cell's slots takes on the chip in place)
+DECODE_FORMS = {
+    "olmoe-1b-7b-L4": ("serve-doc", "gmm128"),  # 16 rows x 8 of 64: 88% touched by an even router
+    "kanana-2-30b-a3b-L7": ("serve-longdoc", "gmm128"),  # 24 x 6 of 128: 68%
+    "mellum2-12b-a2.5b-L8": ("serve-repoctx", "dense"),  # 32 x 8 of 64: 99%
+    "lfm2-24b-a2b-L9": ("serve-longgen", "dense"),  # 128 x 4 of 64: every expert
+    "k-exaone-236b-a23b-L5": ("serve-mixedlen", "dense"),  # a held share: 16 of 128
+    "qwen3-next-80b-a3b-L8": ("serve-longdoc", "dense"),  # a held share: 64 of 512
+}
+
+
+@pytest.mark.parametrize("config", sorted(DECODE_FORMS))
+def test_the_rule_at_few_rows_is_from_rows_choices_and_the_bank(config, monkeypatch):
+    """``expert_gemm_form`` at the six routed configurations' published widths and
+    their cells' slots (a decode step's rows): the sorted form through the kernel
+    where a whole bank is read in place on the ``tpu`` platform and the rows leave
+    enough of it untouched; the dense form on the CPU, out of a scanned slice, for
+    a held share at any few rows, and at 512 rows; over 512 what it was."""
+    cell, on_chip = DECODE_FORMS[config]
+    with open(os.path.join(CONFIGS, config + ".json")) as f:
+        program = json.load(f)["program"]
+    with open(os.path.join(os.path.dirname(CONFIGS), "workloads", f"{config}.{cell}.json")) as f:
+        rows = json.load(f)["deployment"]["n_slots"]
+    cfg = tfm.TransformerConfig(dtype=jnp.bfloat16, **{**program, "vocab_size": 1024})
+    count = cfg.experts_held[1]
+    bank = {"wi": jax.ShapeDtypeStruct((2, count, cfg.hidden_size, cfg.intermediate_size),
+                                       jnp.bfloat16)}
+    form = lambda rows, in_place: dropless.expert_gemm_form(cfg, bank, rows, in_place)
+    assert {form(n, in_place) for n in (1, rows, 512) for in_place in (True, False)} == {"dense"}
+    assert form(1024, True) == form(1024, False) == "ragged_dot"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert form(rows, True) == on_chip
+    assert form(rows, False) == form(512, True) == form(512, False) == "dense"
+    assert (form(1024, True), form(1024, False)) == ("gmm128", "ragged_dot")
+    if count < cfg.num_experts:  # a held share: no few rows take the kernel
+        assert {form(n, True) for n in (1, 2, 4, 8, 16, 64, 256)} == {"dense"}
+    else:  # fewer rows touch fewer experts; enough rows touch them all
+        assert form(4, True) == "gmm128" and form(256, True) == "dense"
+
+
+@pytest.mark.parametrize("rows,top_k,experts,mb,ahead", [
+    (24, 6, 128, 1208, True), (48, 6, 128, 1208, True), (64, 6, 128, 1208, False),  # kanana
+    (16, 8, 64, 805, True), (32, 8, 64, 805, False),  # OLMoE
+    (32, 8, 64, 793, False), (128, 4, 64, 1208, False),  # Mellum2, LFM2
+    (16, 8, 256, 11274, True), (64, 8, 256, 11274, True),  # DeepSeek-V3's layer at a small batch
+    (4, 2, 16, 1, False),  # a rehearsal's bank: the fixed work is all there is
+])
+def test_sorted_ahead_by_the_bytes_each_form_streams(rows, top_k, experts, mb, ahead):
+    assert dropless.sorted_ahead(rows, top_k, experts, mb * 10 ** 6) == ahead
+
+
+@pytest.mark.parametrize("config,rows", [("kanana-2-30b-a3b-L7", 24), ("olmoe-1b-7b-L4", 16)])
+def test_decode_rows_through_the_kernel_equal_the_dense_form(config, rows, kernel_on_cpu,
+                                                             monkeypatch):
+    """A decode step's rows of the two rehearsal twins whose cells take the
+    kernel at few rows (the rule steered to say so at the twins' widths, where the
+    fixed work is all there is): the pairs padded to the row tile (96 and 64 ->
+    128), the held stacks read in place, against ``experts_dense`` out of the
+    same stacks: the same experts chosen, the same output to bfloat16 rounding."""
+    cfg = _rehearsal(config)
+    moe = tfm.hold_for_compute(cfg, tfm.Model(cfg).init(jax.random.PRNGKey(5)))["moe"]
+    layer = jnp.int32(jax.tree.leaves(moe["experts"])[0].shape[0] - 1)
+    moe_l = {name: (leaf if name == "experts" else jax.tree.map(lambda a: a[layer], leaf))
+             for name, leaf in moe.items()}
+    h = jax.random.normal(jax.random.PRNGKey(6), (rows, 1, cfg.hidden_size), jnp.bfloat16)
+    assert dropless.expert_gemm_form(cfg, moe["experts"], rows, True) == "dense"
+    monkeypatch.setattr(dropless, "sorted_ahead", lambda *a: True)
+    assert dropless.expert_gemm_form(cfg, moe["experts"], rows, True) == "gmm128"
+    assert dropless.expert_gemm_form(cfg, moe["experts"], rows, False) == "dense"
+    sorted_, _, chosen = dropless.moe_ffn_dropless(cfg, moe_l, h, layer)
+    monkeypatch.undo()
+    assert dropless.expert_gemm_form(cfg, moe["experts"], rows, True) == "dense"
+    dense, _, chosen_dense = dropless.moe_ffn_dropless(cfg, moe_l, h, layer)
+    np.testing.assert_array_equal(np.asarray(chosen), np.asarray(chosen_dense))
+    sorted_, dense = (np.asarray(a, np.float32) for a in (sorted_, dense))
+    assert np.isfinite(sorted_).all()
+    np.testing.assert_allclose(sorted_, dense, rtol=2e-2, atol=2e-2 * float(np.abs(dense).max()))

@@ -368,6 +368,10 @@ def test_serving_engine_serves_and_its_spans_say_what_was_read(cfg, program):
     for sp in spans["prefill"] + fetched:  # the routed attributes, for this router too
         assert sp.attrs["expert_load_max_over_mean"] >= 1.0
         assert 0 < sp.attrs["experts_touched"] <= cfg.num_experts
+    # every routed call states the form its rows went through the experts in (PR 62: decode too);
+    # on the CPU no call takes the kernel, and these few rows take every expert
+    for sp in spans["prefill"] + spans["decode"] + spans["chunk"]:
+        assert sp.attrs["expert_gemm"] == "dense"
 
 
 def test_a_plain_model_spans_say_dense():

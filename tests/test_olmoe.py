@@ -429,6 +429,8 @@ def test_serving_engine_serves_and_its_spans_carry_the_load(cfg, program):
     # a decode call fetches the step BEFORE it (PR 60): what comes with a fetch is on all but a burst's first
     assert [sp.attrs["d2h"] for sp in calls if sp.name == "decode"] == [0, 3, 3, 3]
     for sp in calls:
+        # the form the call's rows went through the experts in (PR 62: on decode spans too)
+        assert sp.attrs["expert_gemm"] == "dense"
         if not sp.attrs["d2h"]:
             continue
         assert 1.0 <= sp.attrs["expert_load_max_over_mean"] <= cfg.num_experts
