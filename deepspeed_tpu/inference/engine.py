@@ -243,6 +243,11 @@ class InferenceEngine:
         whole loop (prefill + scan'd decode with the Pallas decode-attention
         kernel) is one compiled program per (B, prompt_len, max_new_tokens)
         bucket."""
+        if self.cfg.attn_block_length > 1:
+            raise NotImplementedError(
+                "generate() decodes one token a step under the causal mask; a model that "
+                f"generates by diffusion over blocks (attn_block_length = "
+                f"{self.cfg.attn_block_length}) is served by ServingEngine (its block step)")
         prompt = jnp.asarray(prompt_tokens, jnp.int32)
         B, S = prompt.shape
         budget = min(self.cfg.max_seq_len, self.max_out_tokens)

@@ -174,6 +174,32 @@ def sample_logits_vector(logits, rng, temperature, top_k, top_p):
          lambda: drawn_from(_filter_logits_vector(logits, t, k, p))))
 
 
+def sample_with_confidence(logits, rng, temperature, top_k, top_p):
+    """``sample_logits_vector`` and, per row, the probability of the token it chose
+    under the softmax over the row's whole vocabulary (the raw logits: no temperature,
+    no filter): logits [R, V] -> (token ids [R] int32, confidence [R] float32). What a
+    pass of generation by diffusion over blocks ranks a block's masked rows by."""
+    tokens = sample_logits_vector(logits, rng, temperature, top_k, top_p)
+    logits = logits.astype(jnp.float32)
+    picked = jnp.take_along_axis(logits, tokens[:, None], axis=-1)[:, 0]
+    return tokens, jnp.exp(picked - jax.nn.logsumexp(logits, axis=-1))
+
+
+def reveal_rows(confidence, masked, count, threshold):
+    """Which of a block's masked rows a denoising pass reveals: confidence [n, B]
+    float32 (read where ``masked``), masked [n, B] bool, count [n] int32, threshold [n]
+    float32 -> [n, B] bool. The ``count`` masked rows of largest confidence a slot (the
+    earlier position at a tie; all of them where fewer are masked), and every masked
+    row whose confidence is over ``threshold`` (``inf``: none). Every operand is an
+    array: a slot's pass, its schedule and its strategy are data, never the program."""
+    c = jnp.where(masked, confidence, -jnp.inf)
+    at = jnp.arange(c.shape[1])
+    ahead = (c[:, None, :] > c[:, :, None]) | (
+        (c[:, None, :] == c[:, :, None]) & (at[None, None, :] < at[None, :, None]))
+    rank = jnp.sum(ahead, axis=-1)  # [n, B]: how many rows of the slot go before this one
+    return masked & ((rank < count[:, None]) | (c > threshold[:, None]))
+
+
 def verify_logits_vector(logits, draft, rng, temperature, top_k, top_p):
     """Speculative verify over a whole draft block: logits [B, D+1, V]
     (position j's logits predict the token AFTER draft token j), draft

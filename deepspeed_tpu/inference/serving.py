@@ -69,6 +69,16 @@ programs over sharded state:
                              staying on the device, so the host's side of a
                              step runs under the device's.
 
+  * block steps           — a model that generates by diffusion over blocks
+                             (``attn_block_length`` B > 1) has no decode step: ONE
+                             ``block_step`` program runs a pass over every slot's open
+                             block of B positions (denoise, commit and open are all
+                             operands), the blocks stay on the device between steps,
+                             the scheduler plans a step ahead of the fetch under the
+                             static schedule, and a request receives its tokens a
+                             block at a time (docs/serving.md "Generation by diffusion
+                             over blocks").
+
   * degradation          — production traffic includes requests that must be
                              refused or abandoned (docs/resilience.md):
                              per-request deadlines (queued past deadline →
@@ -115,8 +125,9 @@ from ..models import transformer as tfm
 from ..moe.dropless import expert_gemm_form, expert_load, load_summary
 from ..parallel.sharding import kv_prefix_pool_spec, kv_slot_cache_spec
 from ..resilience import FaultInjector, RequestRejected
-from ..runtime.config import (ChunkedPrefillConfig, FaultInjectionConfig,
-                              IncidentConfig, LedgerConfig, PrefixCacheConfig,
+from ..runtime.config import (BlockGenerationConfig, ChunkedPrefillConfig,
+                              FaultInjectionConfig, IncidentConfig, LedgerConfig,
+                              PrefixCacheConfig,
                               RequestTraceConfig, SLOConfig,
                               SpeculationConfig, TenantConfig,
                               TimeSeriesConfig)
@@ -128,8 +139,8 @@ from ..utils.donation import donated_jit
 from ..utils.logging import log_dist
 from .engine import InferenceEngine
 from .prefix_cache import PrefixIndex
-from .sampling import (SAMPLER_FORMS, sample_logits_vector, sampler_form,
-                       verify_logits_vector)
+from .sampling import (SAMPLER_FORMS, reveal_rows, sample_logits_vector,
+                       sample_with_confidence, sampler_form, verify_logits_vector)
 from .speculation import make_drafter
 
 
@@ -301,6 +312,13 @@ class _Flight:
     # [n_slots]: each slot's count of occupants at the enqueue. A uid would not do:
     # a quarantined request is requeued under its uid and may come back to its slot
     epoch: np.ndarray
+    # a BLOCK step's rows (generation by diffusion over blocks; None for a decode step),
+    # all [n_slots]: which rows ran a denoising pass (the others a commit), how many
+    # positions of the slot's block were masked at its entry, and the first position of
+    # the block that is the request's to receive (behind its prompt's last tokens)
+    denoise: Optional[np.ndarray] = None
+    masked: Optional[np.ndarray] = None
+    gen_from: Optional[np.ndarray] = None
 
 
 def _forward(cfg, params, toks, cache, pos, live, **kw):
@@ -412,9 +430,19 @@ class SlotWorker:
         # takes and returns them there: the same kind of operand from its first
         # call on, one compile. Rows that were idle in that step hold 0
         self._toks = jax.device_put(np.zeros((self.n_slots,), np.int32), self._key_sharding())
-        # the decode step that is enqueued and not fetched (``decode`` / ``collect``):
-        # its outputs on the device, a routed model's choices, the rows it ran, when
-        # it was handed over and whether that call compiled
+        # a model that generates by diffusion over blocks (``attn_block_length`` B > 1)
+        # carries every slot's OPEN BLOCK there instead: its B tokens and which of them are
+        # still masked, [n_slots, B] each, the block step's first two operands and first
+        # two host-bound values (``block_step``), pinned like the tokens above
+        self.block_len = int(self.cfg.attn_block_length)
+        self._btoks = self._bmask = None
+        if self.block_len > 1:
+            open_block = (self.n_slots, self.block_len)
+            self._btoks = jax.device_put(np.zeros(open_block, np.int32), self._key_sharding())
+            self._bmask = jax.device_put(np.zeros(open_block, np.bool_), self._key_sharding())
+        # the decode (or block) step that is enqueued and not fetched (``decode`` /
+        # ``block_step`` / ``collect``): its outputs on the device, a routed model's
+        # choices, the rows it ran, when it was handed over and whether that call compiled
         self._pending = None
         self._t_fetched = 0.0  # when the last decode step's results reached the host
         # a kept span (telemetry/tracing.py): the allocation programs' traces and
@@ -484,6 +512,7 @@ class SlotWorker:
                               if self.expert_bank else 0)
 
         self._decode = None  # jitted lazily (params pytree shapes needed)
+        self._block = None  # jitted block step (``attn_block_length`` > 1), lazily too
         self._prefills: dict[int, object] = {}  # bucket len -> jitted prefill
         self._chunk_progs: dict[int, object] = {}  # chunk width -> jitted chunk
         # (spec depth, greedy_only) -> jitted verify: two program families
@@ -502,6 +531,8 @@ class SlotWorker:
         self._kv_imports: dict[int, object] = {}
         self._decode_steps = 0
         self._decode_steps_ahead = 0  # those enqueued while the step before was unfetched
+        self._block_steps = 0
+        self._block_steps_ahead = 0
         # True if ANY dispatch since the scheduler last reset it paid a
         # compilation — the Router's step-latency heartbeat exempts such
         # steps (a cold replica's first step compiles for tens of seconds
@@ -515,6 +546,11 @@ class SlotWorker:
         # left asynchronous too: its choices are then waited for). The
         # programs are the same either way: observation, not a path
         self.routing_log: list | None = None
+        # the same for a block step's outcome: set this to a list and every FETCHED
+        # block step appends the rows it ran (``pos``, ``active``, ``opened``, ``count``)
+        # and what it left (``toks``, ``mask`` [n_slots, B], ``bad``), with every row's
+        # confidence as the program computed it (``conf`` [n_slots, B])
+        self.block_log: list | None = None
 
     # -- compiled programs ----------------------------------------------
 
@@ -558,6 +594,55 @@ class SlotWorker:
             return jnp.where(mask, host, carried)
 
         return jax.jit(token_merge, in_shardings=(rep, rep, rep), out_shardings=rep)
+
+    def _build_block_step(self):
+        """ONE program for every phase of generation by diffusion over blocks: a pass
+        over every slot's open block of B = ``attn_block_length`` positions, [n_slots, B]
+        rows. Nothing of a request, a phase, a schedule or a strategy is baked in beyond
+        B: all of it is the operands'.
+
+        ``toks`` / ``mask`` [n_slots, B]: the open blocks as the last step left them, on
+        the device. ``opened`` [n_slots]: the rows whose block OPENS with this step; they
+        take ``new_toks`` / ``new_mask`` from the host (a prompt's last tokens in place
+        and the rest masked; B masks behind a committed block). A masked position's input
+        is the mask token's embedding. The block is written at ``wpos .. wpos + B - 1``
+        BEFORE it attends (idle and prefilling rows at ``Smax``: dropped) and attends at
+        ``pos .. pos + B - 1`` under the mask that is causal between blocks, so every row
+        sees the whole block and everything before it. Per masked row the token
+        (``sample_with_confidence``: arg-max or a draw, by the slot's sampler rows) and
+        its probability under the softmax over the vocabulary; ``reveal_rows`` keeps the
+        ``count`` most confident of a slot's masked rows and those over ``threshold``.
+        A pass whose block holds no mask reveals nothing, and the K/V it wrote is the
+        block's final K/V: the COMMIT is this same program on such a row. Behind the
+        sentinel the program hands back every row's confidence [n_slots, B] float32, which
+        stays on the device unless ``block_log`` asks for it (as the experts chosen do
+        for ``routing_log``): what a check compares of THIS program's own arithmetic."""
+        cfg, B = self.cfg, self.block_len
+        mask_id = jnp.int32(cfg.mask_token_id)
+
+        def block_step(params, cache, toks, mask, opened, new_toks, new_mask, pos, wpos,
+                       active, count, threshold, rng, temp, top_k, top_p):
+            toks = jnp.where(opened[:, None], new_toks, toks)
+            mask = jnp.where(opened[:, None], new_mask, mask)
+            on = jnp.broadcast_to(active[:, None], toks.shape)
+            logits, cache, load = _forward(
+                cfg, params, jnp.where(mask, mask_id, toks), cache, pos, on, write_pos=wpos)
+            # the sentinel spans the block: a NaN in any of its rows poisons the pass
+            bad = jnp.any(~jnp.isfinite(logits), axis=(1, 2))
+            rng, k = jax.random.split(rng)
+            rows = lambda a: jnp.repeat(a, B)  # a slot's sampler state on each of its rows
+            x0, conf = sample_with_confidence(
+                logits.reshape(-1, logits.shape[-1]), k, rows(temp), rows(top_k), rows(top_p))
+            reveal = on & reveal_rows(conf.reshape(toks.shape), mask, count, threshold)
+            toks = jnp.where(on, jnp.where(reveal, x0.reshape(toks.shape), toks), 0)
+            return (cache, rng, toks, mask & ~reveal & on, bad, conf.reshape(toks.shape), *load)
+
+        cache, *rest = self._outs(4)
+        key = self._key_sharding()
+        return donated_jit(block_step, donate_argnums=(1,),
+                           in_shardings=(None, None, key, key) + (None,) * 8
+                           + (key, None, None, None),
+                           out_shardings=(cache, key, key, key, *rest[2:]))
 
     def _build_verify(self, depth: int, greedy_only: bool = False):
         cfg = self.cfg
@@ -801,6 +886,13 @@ class SlotWorker:
             self._decode = wd.watch(
                 self._build_decode(), wd.unique_name("serving/decode"), stable=True)
         return self._decode
+
+    def _block_prog(self):
+        if self._block is None:
+            wd = self.telemetry.watchdog
+            self._block = wd.watch(
+                self._build_block_step(), wd.unique_name("serving/block_step"), stable=True)
+        return self._block
 
     def _verify_prog(self, depth: int, greedy_only: bool):
         key = (depth, greedy_only)
@@ -1160,12 +1252,79 @@ class SlotWorker:
         if rows_discarded:
             sp.annotate(rows_discarded=rows_discarded)
         out, chosen, rows, t_enqueue, compiled = prev
-        fetched = self._fetch_results(sp, out, chosen, 2, rows)
+        blocks = self.block_len > 1  # a worker's steps are of one kind: (toks, mask, bad) against (token, bad)
+        if blocks:  # the rows' confidences: no fetch waits for them
+            *out, conf = out
+        fetched = self._fetch_results(sp, out, chosen, 3 if blocks else 2, rows)
         done = time.perf_counter()
         if not compiled:
-            self.telemetry.histogram("serving/decode_step_sec").observe(
+            self.telemetry.histogram(
+                "serving/block_step_sec" if blocks else "serving/decode_step_sec").observe(
                 done - max(t_enqueue, self._t_fetched))
         self._t_fetched = done
+        if blocks and self.block_log is not None:
+            self.block_log.append({**rows, "toks": fetched[0], "mask": fetched[1],
+                                   "bad": fetched[2], "conf": np.asarray(conf)})
+        return fetched
+
+    def block_step(self, opened, new_toks, new_mask, pos, wpos, active, count, threshold,
+                   temp, top_k, top_p, *, masked_rows: int, commits: int,
+                   rows_discarded: int = 0):
+        """ENQUEUE one block step over every slot (``_build_block_step`` has the
+        operands), then FETCH the step enqueued before it, if one is unfetched: ``decode``'s
+        two halves, of two device steps, for a model that generates by diffusion over
+        blocks. The open blocks stay on the device between steps (``_btoks`` /
+        ``_bmask``); the host hands over only the blocks that open.
+
+        One span ``block_step`` a call with ``dispatch`` / ``fetch`` children as the other
+        programs have. Of the ENQUEUED step: ``rows`` (n_slots x B), ``slots_active``,
+        ``masked_rows`` (masked positions at its entry), ``revealed`` (what ``count``
+        reveals; the dynamic strategy may reveal more, and ``serving/tokens_revealed``
+        counts what the fetch shows), ``commits`` (the active slots whose pass is a
+        commit), ``live_keys`` (the keys ONE layer's block rows are required to read: a
+        slot at ``pos`` reads pos + B from each of its B rows), ``expert_rows_held``,
+        ``expert_gemm``, ``ahead``. A routed model's load comes with the fetch and is the
+        FETCHED step's. Returns host ``(toks, mask, bad)`` of the fetched step ([n_slots,
+        B] twice and [n_slots]), or None where nothing was unfetched."""
+        tm = self.telemetry
+        B, n = self.block_len, self.n_slots
+        on = np.asarray(active, bool)
+        n_active = int(np.count_nonzero(on))
+        prev = self._pending
+        attrs = dict(rows=n * B, slots_active=n_active, masked_rows=int(masked_rows),
+                     revealed=int(np.sum(np.asarray(count)[on])), commits=int(commits),
+                     live_keys=int(np.sum((np.asarray(pos, np.int64)[on] + B) * B)),
+                     attn=tfm.cache_step_form(self.cfg), **self._expert_gemm(n * B),
+                     ahead=prev is not None)
+        if self.expert_bank:
+            attrs.update(expert_bank=self.expert_bank,
+                         expert_rows_held=n * B * self.cfg.moe_top_k * self.routed_layers)
+
+        def operands(sp):
+            # the carried blocks and the carried key are the device's; the rest are this
+            # call's own host arrays (``h2d`` counts them)
+            return ((self._btoks, self._bmask, np.asarray(opened, np.bool_),
+                     np.asarray(new_toks, np.int32), np.asarray(new_mask, np.bool_),
+                     np.asarray(pos, np.int32), np.asarray(wpos, np.int32), on,
+                     np.asarray(count, np.int32), np.asarray(threshold, np.float32)),
+                    self._sampler_rows(sp, temp, top_k, top_p))
+
+        with tm.span("block_step", **attrs) as sp:
+            t_enqueue = time.perf_counter()
+            out, chosen = self._dispatch(sp, self._block_prog, operands)
+            self._btoks, self._bmask = out[0], out[1]
+            out.append(out.pop(3))  # (toks, mask, bad, the load, conf): ``_fetch_pending``
+            self._pending = (out, chosen,
+                             dict(span="block_step", pos=np.array(pos), active=on.copy(),
+                                  opened=np.array(opened, bool), count=np.array(count)),
+                             t_enqueue, sp.attrs["compiled"])
+            fetched = self._fetch_pending(sp, prev, rows_discarded)
+        self._block_steps += 1
+        tm.counter("serving/block_steps").inc()
+        tm.counter("serving/block_commits").inc(int(commits))
+        if prev is not None:
+            self._block_steps_ahead += 1
+            tm.counter("serving/block_steps_ahead").inc()
         return fetched
 
     def verify(self, depth: int, toks, pos, wpos, active, temp, top_k, top_p,
@@ -1388,6 +1547,9 @@ class SlotWorker:
             "decode_steps": self._decode_steps,
             "decode_steps_ahead": self._decode_steps_ahead,
         }
+        if self.block_len > 1:
+            out.update(block_step=int(self._block._cache_size()) if self._block is not None else 0,
+                       block_steps=self._block_steps, block_steps_ahead=self._block_steps_ahead)
         if self._chunk_progs:
             out["chunk_prefill"] = {w: int(f._cache_size())
                                     for w, f in sorted(self._chunk_progs.items())}
@@ -1567,6 +1729,8 @@ class ServingEngine:
             "serving/chunk_prefill[", wall_hist="serving/chunk_prefill_sec")
         self.telemetry.ledger.bind(
             "serving/verify[", wall_hist="serving/verify_step_sec")
+        self.telemetry.ledger.bind(
+            "serving/block_step", wall_hist="serving/block_step_sec")
         # collective X-ray axis mapping reads the inference mesh (a 1-device
         # mesh simply yields no collectives — anatomy rows stay labeled)
         self.telemetry.ledger.set_mesh_shape(dict(engine.mesh.shape))
@@ -1607,6 +1771,31 @@ class ServingEngine:
                         f"{what} with window layers (local_attn_layers) has no code: a window "
                         "layer keeps a ring of its last local_attn_window positions, which "
                         "cannot be cut or rolled back at an old position; serve with it off")
+        bg = config.get("block_generation", {})
+        if isinstance(bg, dict):
+            bg = BlockGenerationConfig(**bg)
+        self.block_cfg: BlockGenerationConfig = bg
+        # generation by diffusion over blocks (docs/serving.md): B positions a slot a
+        # device step, T denoising passes and a commit a block
+        self.block_len = int(engine.cfg.attn_block_length)
+        self.block_passes = int(bg.denoising_steps) or self.block_len
+        self._block_static = bg.strategy == "low_confidence_static"
+        if self.block_len > 1:
+            # each assumes that a step yields one token a row, at the causal mask: a
+            # prefix's K/V ends where a block may not, a chunk's rows see their block's
+            # later rows only once those are written, a draft is a causal continuation,
+            # and a handoff ships a first token that a block model's prefill does not make
+            for what, on in (("prefix_cache", pc.enabled), ("chunked_prefill", cp.enabled),
+                             ("speculation", sp.enabled),
+                             (f"serving role {self.role!r}", self.role != "both")):
+                if on:
+                    raise NotImplementedError(
+                        f"{what} with generation by diffusion over blocks (attn_block_length "
+                        f"= {self.block_len}) has no code: serve with it off")
+            if not 1 <= self.block_passes <= self.block_len:
+                raise ValueError(
+                    f"block_generation.denoising_steps is 1 .. the block length "
+                    f"{self.block_len} (0: the block length), got {bg.denoising_steps}")
         # the drafter is constructed eagerly so a bad draft_source fails at
         # engine build, not on the first decode step (draft_model needs the
         # model's vocab size to build its host-resident scorer)
@@ -1681,6 +1870,10 @@ class ServingEngine:
                 f"{engine.cfg.max_seq_len} and max_out_tokens "
                 f"{engine.max_out_tokens})")
         self.min_bucket = int(min_prefill_bucket)
+        if self.block_len > 1 and (self.budget % self.block_len or self.min_bucket % self.block_len):
+            raise ValueError(
+                f"max_seq_len ({self.budget}) and min_prefill_bucket ({self.min_bucket}) are "
+                f"multiples of the block length {self.block_len}: a slot holds whole blocks")
 
         # the compiled-program driver: device state + program inventory
         # (this scheduler is pure host code from here on)
@@ -1710,6 +1903,18 @@ class ServingEngine:
         self._temp = np.zeros((n,), np.float32)
         self._top_k = np.zeros((n,), np.int32)
         self._top_p = np.ones((n,), np.float32)
+        # generation by diffusion over blocks: each slot's open block AS PLANNED, i.e.
+        # behind every step enqueued (``_pos`` is the block's first position). What the
+        # static schedule lets the host know without a fetch (``_enqueue_block_step``)
+        B = self.block_len
+        self._bleft = np.zeros((n,), np.int32)  # positions still masked
+        self._bpass = np.zeros((n,), np.int32)  # denoising passes the block has had
+        self._bopen = np.zeros((n,), np.bool_)  # the next step opens the block (host's tokens)
+        self._bnew_toks = np.zeros((n, B), np.int32)
+        self._bnew_mask = np.zeros((n, B), np.bool_)
+        self._bgen_from = np.zeros((n,), np.int32)  # the block's first position to emit
+        self._bbudget = np.zeros((n,), np.int64)  # tokens of the request not planned yet
+        self._bdone = np.zeros((n,), np.bool_)  # its last step is enqueued
 
         self._queue: deque[Request] = deque()
         self._prefilling: dict[int, _Prefill] = {}  # slot -> admission state
@@ -1788,6 +1993,9 @@ class ServingEngine:
             feat.append(f"chunked_prefill[{cp.chunk_size}]")
         if sp.enabled:
             feat.append(f"speculation[depth {sp.depth}, {sp.draft_source}]")
+        if self.block_len > 1:
+            feat.append(f"block_generation[{self.block_len} positions, {self.block_passes} "
+                        f"passes + commit, {bg.strategy}]")
         log_dist(
             f"serving engine: {n} slots x {self.Smax} tokens, cache "
             f"{self.worker.hbm_pools()['slot_kv_cache'] / 1e6:.1f} MB at "
@@ -2503,13 +2711,18 @@ class ServingEngine:
 
     def _prefill_one_shot(self, req: Request, slot: int, prompt: np.ndarray,
                           t_adm: float, entry):
-        S = prompt.shape[0]
-        bucket = self._bucket_len(S)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :S] = prompt
-        first, bad = self.worker.prefill(
-            bucket, padded, slot, S, req.temperature, req.top_k, req.top_p,
-            uid=req.uid)
+        # a block model prefills the prompt's WHOLE blocks; its last S mod B tokens open
+        # the first generated block (``_open_first_block``), and the token the program
+        # samples at the last prefilled row is nobody's
+        S = prompt.shape[0] - prompt.shape[0] % self.block_len
+        first, bad = 0, False
+        if S:  # (a prompt shorter than a block has nothing to prefill)
+            bucket = self._bucket_len(S)
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :S] = prompt[:S]
+            first, bad = self.worker.prefill(
+                bucket, padded, slot, S, req.temperature, req.top_k, req.top_p,
+                uid=req.uid)
         t_first = time.perf_counter() - self._epoch
         self._activate(slot, req, prompt, first, t_adm, t_first, entry, bad=bad)
 
@@ -2564,6 +2777,9 @@ class ServingEngine:
             self._quarantine(slot, req, "prefill")
             self._release_slot(slot)
             return
+        if self.block_len > 1:
+            self._open_first_block(slot, req, prompt, t_adm)
+            return
         S = prompt.shape[0]
         eos = req.eos_token if req.eos_token is not None else -1
         if self.role == "prefill" and first != eos and req.max_new_tokens > 1:
@@ -2613,6 +2829,32 @@ class ServingEngine:
             self._insert_prefix(slot, prompt)
         if first == st.eos or st.remaining <= 0:
             self._finish(slot)
+
+    def _open_first_block(self, slot: int, req: Request, prompt: np.ndarray, t_adm: float):
+        """``_activate`` for a model that generates by diffusion over blocks: the
+        prompt's whole blocks are resident, and the block that holds position S opens
+        with the prompt's last S mod B tokens in place and the rest masked. Nothing is
+        emitted yet: the request receives its tokens a block at a time, each when its
+        last masked position is revealed (``_emit_blocks``), and its first-token time is
+        that of its first block."""
+        B, S = self.block_len, prompt.shape[0]
+        r = S % B
+        st = self._slots[slot]
+        st.uid, st.remaining, st.tokens, st.request = req.uid, req.max_new_tokens, [], req
+        st.eos = req.eos_token if req.eos_token is not None else -1
+        st.result = RequestResult(
+            uid=req.uid, tokens=np.zeros((0,), np.int32), prompt_len=S,
+            arrival_time=req.arrival_time, admitted_time=t_adm, slot=slot)
+        self._active[slot] = True
+        self._pos[slot] = S - r
+        self._temp[slot], self._top_k[slot], self._top_p[slot] = (
+            req.temperature, req.top_k, req.top_p)
+        self._bnew_toks[slot] = 0
+        self._bnew_toks[slot, :r] = prompt[S - r:]
+        self._bnew_mask[slot] = np.arange(B) >= r
+        self._bopen[slot], self._bdone[slot] = True, False
+        self._bleft[slot], self._bpass[slot], self._bgen_from[slot] = B - r, 0, r
+        self._bbudget[slot] = req.max_new_tokens
 
     def _insert_prefix(self, slot: int, prompt: np.ndarray):
         """Offer the freshly prefilled prompt to the prefix cache; a created
@@ -2726,6 +2968,8 @@ class ServingEngine:
         self._temp[slot] = 0.0
         self._top_k[slot] = 0
         self._top_p[slot] = 1.0
+        self._bleft[slot] = self._bpass[slot] = self._bgen_from[slot] = self._bbudget[slot] = 0
+        self._bopen[slot] = self._bdone[slot] = False
         if slot in self._quarantined_slots:
             self.telemetry.gauge("resilience/quarantined_slots").set(
                 len(self._quarantined_slots))
@@ -2918,45 +3162,71 @@ class ServingEngine:
             self._synth_result(req, "failed_nan", slot=slot)
 
     def _advance_flight(self, ahead: bool) -> None:
-        """One decode step handed to the device and the one in flight (if any)
-        fetched and emitted; ``ahead``: in that order, else the step in flight is
-        only collected and nothing is enqueued (the caller does that once the host
-        holds every token).
+        """One step handed to the device and the one in flight (if any) fetched and
+        emitted; ``ahead``: in that order, else the step in flight is only collected and
+        nothing is enqueued (the caller does that once the host holds every token). A
+        decode step, or for a model that generates by diffusion over blocks a block step
+        (``_plan_decode_ahead`` / ``_plan_block_ahead`` say what each knows without the
+        fetch; a block step runs ahead under the static schedule alone).
 
-        What the next step needs is known WITHOUT the fetch: the rows of the step in
-        flight whose request is still there (``kept``) move on by one position, those
-        among them that this exhausts (``remaining``) end by length and are left
-        out, and a slot activated since joins with the token the host has (a
-        prefill's first, an import's). Only the tokens of the kept rows are the
-        device's alone: they stay there (``SlotWorker._toks``) and ``from_host``
-        marks every other row whose token changes, an ended row's to 0, so the
-        program's operands are to the bit what they would be had the host fetched
-        first. Not known: an EOS, a ``bad`` sentinel. A row enqueued for a request
-        that one of those (or a cancel, an eviction) has ended since is dropped
-        when its step is emitted; its K/V write is harmless, since whatever enters
-        the slot later is enqueued behind it."""
+        Not known ahead: an EOS, a ``bad`` sentinel. A row enqueued for a request that one
+        of those (or a cancel, an eviction) has ended since is dropped when its step is
+        emitted; its K/V write is harmless, since whatever enters the slot later is
+        enqueued behind it."""
+        blocks = self.block_len > 1
         fl = self._flight
         kept = fl.active & (fl.epoch == self._slot_epoch)
         dropped = int(np.count_nonzero(fl.active & ~kept))
         fetched = None
-        if ahead:
-            last = np.fromiter((st.remaining <= 1 for st in self._slots), np.bool_, self.n_slots)
-            active = self._active & ~(kept & last)
-            if active.any():
-                fetched = self._enqueue_decode(
-                    active, self._pos + kept, ~(kept & active) & (fl.active | active), dropped)
+        if ahead and (self._block_static or not blocks):
+            fetched = (self._plan_block_ahead if blocks else self._plan_decode_ahead)(
+                fl, kept, dropped)
         if fetched is None:
             fetched = self.worker.collect(rows_discarded=dropped)
             self._flight = None
         if dropped:
-            self.telemetry.counter("serving/decode_rows_discarded").inc(dropped)
-            for slot in map(int, np.flatnonzero(fl.active & ~kept & fetched[1])):
+            self.telemetry.counter("serving/block_rows_discarded" if blocks
+                                   else "serving/decode_rows_discarded").inc(
+                dropped * self.block_len)
+            for slot in map(int, np.flatnonzero(fl.active & ~kept & fetched[-1])):
                 # a dropped row's sentinel: nobody is there to quarantine, but the
                 # position it wrote may hold NaN K/V; scrub while the slot is empty
                 # (an occupant's own sentinel catches it otherwise)
                 if slot in self._free:
                     self.worker.fill_slot(slot, 0.0)
-        self._emit(self._emit_decoded, kept, *fetched)
+        if blocks:
+            self._emit(self._emit_blocks, fl, kept, *fetched)
+        else:
+            self._emit(self._emit_decoded, kept, *fetched)
+
+    def _plan_decode_ahead(self, fl: _Flight, kept, dropped: int):
+        """The next decode step, enqueued behind the one in flight (None: no slot
+        continues). What it needs is known WITHOUT the fetch: the rows of the step in
+        flight whose request is still there (``kept``) move on by one position, those
+        among them that this exhausts (``remaining``) end by length and are left out, and
+        a slot activated since joins with the token the host has (a prefill's first, an
+        import's). Only the tokens of the kept rows are the device's alone: they stay
+        there (``SlotWorker._toks``) and ``from_host`` marks every other row whose token
+        changes, an ended row's to 0, so the program's operands are to the bit what they
+        would be had the host fetched first."""
+        last = np.fromiter((st.remaining <= 1 for st in self._slots), np.bool_, self.n_slots)
+        active = self._active & ~(kept & last)
+        if not active.any():
+            return None
+        return self._enqueue_decode(
+            active, self._pos + kept, ~(kept & active) & (fl.active | active), dropped)
+
+    def _plan_block_ahead(self, fl: _Flight, kept, dropped: int):
+        """The next block step, enqueued behind the one in flight (None: no slot
+        continues). Under the static schedule a pass reveals ``count`` of a slot's masked
+        positions, so the host knows how many are left behind every step it has enqueued
+        (``_bleft``), which pass comes next (denoise while any is left, then the commit,
+        then the next block opens B positions on), which pass finishes a block, and which
+        finished block uses up the request's ``max_new_tokens`` (``_bdone``: that slot's
+        last step; it takes no commit, nothing will read its K/V). The blocks themselves
+        stay on the device (``SlotWorker._btoks`` / ``_bmask``)."""
+        active = self._active & ~self._bdone
+        return self._enqueue_block_step(active, dropped) if active.any() else None
 
     def _enqueue_decode(self, active, pos, from_host, rows_discarded: int = 0):
         """``worker.decode`` over the rows ``active`` at ``pos``: enqueues the step,
@@ -2984,6 +3254,90 @@ class ServingEngine:
             rows_discarded=rows_discarded)
         self._flight = _Flight(active=active, epoch=self._slot_epoch.copy())
         return fetched
+
+    def _enqueue_block_step(self, active, rows_discarded: int = 0):
+        """``worker.block_step`` over the slots ``active``, each at the pass its plan says:
+        enqueues the step, makes it the one in flight, moves the plan on, and returns what
+        the call fetched of the step before (None: nothing was unfetched). A slot with
+        masked positions left takes a DENOISING pass that reveals ``B // T`` of them (all
+        that are left on its T-th; under the dynamic strategy those over the threshold
+        besides, so what is left is read from the fetch, ``_emit_blocks``); a slot with none
+        takes the COMMIT, behind which its next block opens B positions on, all masked.
+        Every operand is an array of this call's own; rows outside ``active`` read as a
+        freed slot's do."""
+        B, T = self.block_len, self.block_passes
+        self._note_device_step(int(np.count_nonzero(active)))
+        left = np.where(active, self._bleft, 0)
+        denoise, commit = active & (left > 0), active & (left == 0)
+        count = np.where(self._bpass >= T - 1, left, np.minimum(B // T, left)).astype(np.int32)
+        count = np.where(denoise, count, 0)
+        threshold = np.full((self.n_slots,), np.inf, np.float32)
+        if not self._block_static:
+            threshold[denoise] = self.block_cfg.threshold
+        pos = np.where(active, self._pos, 0).astype(np.int32)
+        state = lambda rows, idle: np.where(active, rows, idle).astype(rows.dtype)  # noqa: E731
+        fetched = self.worker.block_step(
+            self._bopen & active, self._bnew_toks.copy(), self._bnew_mask.copy(), pos,
+            np.where(active, pos, self.Smax), active, count, threshold,
+            state(self._temp, 0), state(self._top_k, 0), state(self._top_p, 1),
+            masked_rows=int(left.sum()), commits=int(np.count_nonzero(commit)),
+            rows_discarded=rows_discarded)
+        self._flight = _Flight(active=active, epoch=self._slot_epoch.copy(), denoise=denoise,
+                               masked=left, gen_from=self._bgen_from.copy())
+        # the plan behind this step
+        self._bopen[active] = False
+        self._bpass[denoise] += 1
+        if self._block_static:
+            self._bleft[denoise] -= count[denoise]
+            finishes = denoise & (self._bleft == 0)
+            taken = np.minimum(B - self._bgen_from, self._bbudget)
+            self._bbudget[finishes] -= taken[finishes]
+            self._bdone |= finishes & (self._bbudget == 0)
+        self._pos[commit] += B
+        self._bopen[commit] = True
+        self._bnew_toks[commit], self._bnew_mask[commit] = 0, True
+        self._bleft[commit], self._bpass[commit], self._bgen_from[commit] = B, 0, 0
+        return fetched
+
+    def _emit_blocks(self, fl: _Flight, rows, toks, mask, bad) -> int:
+        """A fetched block step onto the requests of ``rows`` [n_slots] bool (the rows it
+        ran whose request is still in its slot): a denoising pass that left no masked
+        position FINISHED its block, and the request receives the block's positions from
+        ``gen_from`` on, up to its ``max_new_tokens`` (the rest of its last block is
+        thrown away) or an EOS."""
+        tm = self.telemetry
+        left = mask.sum(axis=1)
+        tm.counter("serving/tokens_revealed").inc(int(np.sum((fl.masked - left)[rows & fl.denoise])))
+        emitted = 0
+        for slot in map(int, np.flatnonzero(rows)):
+            st = self._slots[slot]
+            if bad[slot]:
+                tm.counter("resilience/nan_logit_faults").inc()
+                self._quarantine(slot, st.request, "block_step")
+                self._release_slot(slot)
+                continue
+            if not fl.denoise[slot]:
+                continue
+            if not self._block_static:  # the plan reads what the pass left
+                self._bleft[slot] = left[slot]
+            if left[slot]:
+                continue
+            if not st.tokens:
+                st.result.first_token_time = time.perf_counter() - self._epoch
+                if self.tracer is not None:
+                    self.tracer.record(st.uid, "first_token", t=st.result.first_token_time,
+                                       slot=slot)
+            finished = False
+            for tok in map(int, toks[slot, fl.gen_from[slot]:]):
+                st.tokens.append(tok)
+                st.remaining -= 1
+                emitted += 1
+                if tok == st.eos or st.remaining <= 0:
+                    finished = True
+                    break
+            if finished:
+                self._finish(slot)
+        return emitted
 
     def _note_device_step(self, n_active: int) -> None:
         """The gauges of a device step (decode or verify) over ``n_active`` rows."""
@@ -3228,6 +3582,11 @@ class ServingEngine:
                         st.uid, "decode", len(st.tokens) - 1):
                     self.worker.fill_slot(slot, float("nan"))
                     tm.counter("resilience/injected_faults").inc()
+        if self.block_len > 1:
+            active = self._active & ~self._bdone
+            if active.any():
+                self._enqueue_block_step(active)
+            return
         drafts: dict[int, np.ndarray] = {}
         if self._drafter is not None:
             with tm.span("draft") as sp:

@@ -134,6 +134,17 @@ class TransformerConfig:
     local_attn_window: int = 0
     local_attn_layers: Optional[tuple] = None
     rotary_layers: Optional[tuple] = None
+    # Generation by diffusion over blocks (SDAR): the mask is causal BETWEEN blocks of
+    # ``attn_block_length`` positions, aligned from position 0, and every position of a
+    # block sees its whole block: key j is visible to query i iff j // B <= i // B
+    # (``block_visible``). 1 is the causal mask, and every program is what it was.
+    # ``mask_token_id``: the id whose embedding stands at a position of a block that is
+    # not revealed yet (which positions those are is the caller's to know: the id may
+    # occur in a prompt). A block is denoised through the cache by ``apply_with_cache``
+    # with T = ``attn_block_length`` rows a sequence at a per-row ``pos``; the serving
+    # engine's block step drives it (inference/serving.py)
+    attn_block_length: int = 1
+    mask_token_id: int = -1
     # A layer kind's ROTARY, as data (``rotary_spec``): ``{"window": spec, "whole":
     # spec}``, the rotary of the layers that attend inside the window and of those
     # that attend over the whole context (a kind left out turns by ``rotary_base``).
@@ -472,6 +483,7 @@ def _refuse_uncoded(cfg: "TransformerConfig") -> None:
     _refuse_uncoded_heads_and_mixer(cfg)
     _refuse_uncoded_kinds_and_share(cfg)
     _refuse_uncoded_passes(cfg)
+    _refuse_uncoded_blocks(cfg)
     if cfg.moe_score_fn not in ("softmax", "sigmoid"):
         raise ValueError(f"moe_score_fn is 'softmax' or 'sigmoid', not {cfg.moe_score_fn!r}")
     forms = {"moe_score_fn": cfg.moe_score_fn != "softmax", "moe_select_bias": cfg.moe_select_bias,
@@ -774,8 +786,49 @@ def _refuse_uncoded_passes(cfg: "TransformerConfig") -> None:
                 raise NotImplementedError(f"{feature} with {what} has no code")
 
 
+def _refuse_uncoded_blocks(cfg: "TransformerConfig") -> None:
+    """``_refuse_uncoded`` for the block-causal mask (``attn_block_length`` > 1): what
+    no code attends under it, by name. (Its training objective is refused where the
+    loss is asked for, ``causal_lm_loss``; what the serving engine has not built for it
+    where the engine is built.)"""
+    B = cfg.attn_block_length
+    if B < 1 or B & (B - 1) or B > 128:
+        raise ValueError(
+            "attn_block_length is a power of two from 1 (the causal mask) to 128: the flash "
+            f"kernel's tiles start at multiples of 128, which a block must not straddle (got {B})")
+    if B == 1:
+        return
+    if not 0 <= cfg.mask_token_id < cfg.vocab_size:
+        raise ValueError(
+            f"attn_block_length={B} states its mask_token_id, an id of the vocabulary "
+            f"(got {cfg.mask_token_id} of {cfg.vocab_size})")
+    no_code = {
+        "causal=False (the mask is causal between blocks)": not cfg.causal,
+        "window layers, rotary_layers or layer_operators (a ring keeps positions, not "
+        "blocks; a conv's or a delta rule's state has no block to reopen)":
+            cfg.layer_kinds is not None,
+        "the state-space mixer (ssm_state_size > 0: a state moved on by a pass cannot be "
+        "moved back for the next pass over the same block)": cfg.ssm_state_size > 0,
+        "latent attention (kv_lora_rank > 0)": cfg.kv_lora_rank > 0,
+        "layer_passes > 1": cfg.layer_passes > 1,
+        "mtp_layers": cfg.mtp_layers != 0,
+        "pos_emb='alibi' (its bias is written for the causal mask)": cfg.pos_emb == "alibi",
+        f"attn_impl={cfg.attn_impl!r} (the XLA form and the flash kernel take the mask)":
+            cfg.attn_impl not in ("xla", "flash"),
+        "decode_attn='kernel' (the Pallas decode kernel walks the keys at or under ONE "
+        "query's position; state decode_attn='xla')": cfg.decode_attn == "kernel",
+    }
+    for what, refused in no_code.items():
+        if refused:
+            raise NotImplementedError(f"attn_block_length > 1 with {what} has no code")
+
+
 def refuse_in_pipeline(cfg: "TransformerConfig") -> None:
     """What the pipeline schedules (pipe/) have not carried yet, by name."""
+    if cfg.attn_block_length > 1:
+        raise NotImplementedError(
+            "attn_block_length > 1 under a pipeline schedule has no code: the stages attend "
+            "under the causal mask")
     if cfg.layer_passes > 1:
         raise NotImplementedError(
             "layer_passes > 1 under a pipeline schedule has no code: a stage would be visited "
@@ -1340,13 +1393,22 @@ def alibi_slopes(num_heads: int) -> jnp.ndarray:
     return base
 
 
-def xla_attention(q, k, v, *, causal_offset=0, bias=None, causal=True, dtype=jnp.float32):
+def block_visible(q_pos, block: int):
+    """The last key position a query at ``q_pos`` sees under the mask that is causal
+    between blocks of ``block`` positions (``attn_block_length``): the last position of
+    its own block. The query's own position where ``block`` is 1 (nothing is traced)."""
+    return q_pos if block == 1 else q_pos - q_pos % block + (block - 1)
+
+
+def xla_attention(q, k, v, *, causal_offset=0, bias=None, causal=True, dtype=jnp.float32,
+                  block: int = 1):
     """Plain einsum attention [B,S,H,Dh] — the baseline the Pallas flash
     kernel is validated against (mirrors tests vs vendored BERT in the
     reference's test_cuda_forward.py strategy). ``causal=False`` gives the
     bidirectional encoder form (BERT). ``causal_offset`` may be a scalar or a
     per-row [B] vector — continuous batching decodes every cache slot at its
-    own absolute position. v's heads may be another width than q's and k's.
+    own absolute position. ``block`` > 1: causal between blocks of that many
+    positions (``block_visible``). v's heads may be another width than q's and k's.
     k and v may have FEWER heads than q (grouped-query attention): query head i
     attends key / value head i // (H // Hkv), the group contracted against its
     one K/V head where it lies, never against repeated copies. That contraction's
@@ -1358,31 +1420,32 @@ def xla_attention(q, k, v, *, causal_offset=0, bias=None, causal=True, dtype=jnp
     Hkv = k.shape[2]
     if Hkv == H:
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / math.sqrt(Dh)
-        probs = _masked_softmax(scores, causal_offset, bias, causal).astype(q.dtype)
+        probs = _masked_softmax(scores, causal_offset, bias, causal, block).astype(q.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
     qg = q.reshape(B, Sq, Hkv, H // Hkv, Dh)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32) / math.sqrt(Dh)
     scores = scores.reshape(B, H, Sq, k.shape[1])  # the mask and a bias are per query head
-    probs = _masked_softmax(scores, causal_offset, bias, causal).astype(q.dtype)
+    probs = _masked_softmax(scores, causal_offset, bias, causal, block).astype(q.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.reshape(B, Hkv, H // Hkv, Sq, k.shape[1]), v)
     return out.reshape(B, Sq, H, v.shape[-1])
 
 
-def _masked_softmax(scores, causal_offset=0, bias=None, causal=True):
+def _masked_softmax(scores, causal_offset=0, bias=None, causal=True, block: int = 1):
     """float32 scores [B, H, Sq, Sk] -> probabilities, under ``xla_attention``'s
-    additive bias and causal mask (``causal_offset``: scalar or [B])."""
+    additive bias and causal mask (``causal_offset``: scalar or [B]; ``block``:
+    ``block_visible``)."""
     Sq, Sk = scores.shape[-2:]
     if bias is not None:
         scores = scores + bias
     if causal:
         off = jnp.asarray(causal_offset)
         if off.ndim == 0:
-            q_pos = jnp.arange(Sq)[:, None] + off
+            q_pos = block_visible(jnp.arange(Sq)[:, None] + off, block)
             k_pos = jnp.arange(Sk)[None, :]
             mask = q_pos >= k_pos  # [Sq, Sk]
             scores = jnp.where(mask[None, None], scores, jnp.finfo(jnp.float32).min)
         else:
-            q_pos = off[:, None, None] + jnp.arange(Sq)[None, :, None]
+            q_pos = block_visible(off[:, None, None] + jnp.arange(Sq)[None, :, None], block)
             k_pos = jnp.arange(Sk)[None, None, :]
             mask = q_pos >= k_pos  # [B, Sq, Sk]
             scores = jnp.where(mask[:, None], scores, jnp.finfo(jnp.float32).min)
@@ -1419,7 +1482,7 @@ def _latent_attention(cfg: "TransformerConfig", lp, q, k_pe, c, pos):
     return jnp.einsum("bthr,rhv->bthv", out_lat, w[..., Dn:])
 
 
-def _rows_attention(q, k_rows, v_rows, pos, bias=None):
+def _rows_attention(q, k_rows, v_rows, pos, bias=None, block: int = 1):
     """Grouped-query attention of a short block q [B, T, H, Dh] over a cache layer
     that holds a token's K/V heads side by side as ONE row (``cache_heads_merged``):
     k_rows [B, Smax, Hkv x Dh], v_rows [B, Smax, Hkv x Dv], valid rows
@@ -1443,7 +1506,7 @@ def _rows_attention(q, k_rows, v_rows, pos, bias=None):
     q_rows = jnp.where(own, q.transpose(0, 2, 1, 3)[:, :, :, None, :], 0)  # [B, H, T, Hkv, Dh]
     scores = jnp.einsum("bmr,bsr->bms", q_rows.reshape(B, H * T, Hkv * Dh), k_rows)
     scores = scores.astype(jnp.float32).reshape(B, H, T, Smax) / math.sqrt(Dh)
-    probs = _masked_softmax(scores, pos, bias).astype(q.dtype)
+    probs = _masked_softmax(scores, pos, bias, block=block).astype(q.dtype)
     out_rows = jnp.einsum("bms,bsr->bmr", probs.reshape(B, H * T, Smax), v_rows)
     out = jnp.where(own, out_rows.reshape(B, H, T, Hkv, -1), 0)  # one block is the head's
     return jnp.sum(out, axis=3).transpose(0, 2, 1, 3)
@@ -1686,6 +1749,7 @@ def _attention_dispatch(cfg: TransformerConfig):
             return flash_attention_sharded(
                 q, k, v, mesh=_ACTIVE_MESH[0], causal=cfg.causal, block_q=bq,
                 block_k=bk, alibi_slopes=slopes, window=window,
+                mask_block=cfg.attn_block_length,
             )
 
         # alibi and local windows are fused IN-KERNEL (computed from block
@@ -1723,7 +1787,8 @@ def _attention_dispatch(cfg: TransformerConfig):
             return sparse_flash_attention(q, k, v, layout, causal=cfg.causal)
 
         return sparse_fn
-    return lambda q, k, v, bias: xla_attention(q, k, v, bias=bias, causal=cfg.causal)
+    return lambda q, k, v, bias: xla_attention(q, k, v, bias=bias, causal=cfg.causal,
+                                               block=cfg.attn_block_length)
 
 
 def _times(x, m: float):
@@ -3231,13 +3296,15 @@ def fresh_cache_state(cache, fresh):
         lambda c: jnp.where(fresh, jnp.zeros((), c.dtype), c), cache[STATE])}
 
 
-def cached_attention(q, k_cache, v_cache, pos, *, bias=None):
+def cached_attention(q, k_cache, v_cache, pos, *, bias=None, block: int = 1):
     """Attention of q [B,T,H,Dh] against a [B,Smax,H,Dh] cache whose valid
     keys are [0, pos+T): the causal mask with offset ``pos`` covers the
     prefix, the new block's internal causality, and the padding tail.
     ``pos`` may be a scalar (lock-step batch) or a per-row [B] vector
-    (continuous batching: each slot at its own position)."""
-    return xla_attention(q, k_cache, v_cache, causal_offset=pos, bias=bias)
+    (continuous batching: each slot at its own position). ``block`` > 1: the mask is
+    causal between blocks of that many positions (``block_visible``): a block of T =
+    ``block`` rows entering at a multiple of it sees [0, pos + T) from every row."""
+    return xla_attention(q, k_cache, v_cache, causal_offset=pos, bias=bias, block=block)
 
 
 # The float32 score matrix [B, H, T, Smax] of ONE layer, in bytes, up to which
@@ -3291,15 +3358,17 @@ def cache_chunk_form(cfg: TransformerConfig, B: int, T: int, Smax: int) -> str:
     return "blocks" if T > 1 and over and plain and Smax % CHUNK_KEY_BLOCK == 0 else "dense"
 
 
-def _blocks_attention(q, k_l, v_l, positions):
+def _blocks_attention(q, k_l, v_l, positions, mask_block: int = 1):
     """Attention of a block q [B, T, H, D] at ``positions`` [B, T] over ONE layer of a
     cache k_l / v_l [B, Smax, Hkv, D] that already holds the block's own keys: the
     softmax taken online over key blocks of ``CHUNK_KEY_BLOCK`` positions, from the
     first to the one that holds the newest position and no further (a loop of a traced
     trip count), each under the causal mask of the absolute positions; a group of
     query heads against its one K/V head where it lies. The work follows the keys the
-    block's queries see, not ``Smax``."""
+    block's queries see, not ``Smax``. ``mask_block`` > 1: each query sees to the end of
+    its own block of that many positions (``block_visible``), and the walk goes as far."""
     B, T, H, D = q.shape
+    positions = block_visible(positions, mask_block)
     Hkv, block = k_l.shape[2], CHUNK_KEY_BLOCK
     qg = q.reshape(B, T, Hkv, H // Hkv, D)
     scale = 1.0 / math.sqrt(D)
@@ -3495,7 +3564,13 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
     A block that READS a whole-context layer's cache (a chunk, a verify block)
     attends densely over ``Smax`` while those scores are small and over the key
     blocks up to its newest position beyond (``cache_chunk_form``,
-    ``_blocks_attention``)."""
+    ``_blocks_attention``).
+
+    ``attn_block_length`` > 1 (generation by diffusion over blocks): every form
+    above takes the mask that is causal between blocks (``block_visible``) from the
+    ABSOLUTE positions, which stay what turns the rotary: row i of a block entering
+    at ``pos`` embeds and turns at ``pos + i`` and sees every key up to its block's
+    last position, the block's own rows among them, written before it attends."""
     starts = isinstance(pos, (int, np.integer)) and int(pos) == 0
     pos = jnp.asarray(pos, jnp.int32)
     vector_pos = pos.ndim >= 1
@@ -3704,15 +3779,17 @@ def _cache_attention(cfg: TransformerConfig, B: int, T: int, Smax: int, pos, wri
             if cfg.kv_lora_rank:
                 return _latent_attention(cfg, lp, q, k_l, v_l, pos), stacks
             if rows_step:
-                return _rows_attention(q, k_l[:, :, 0], v_l[:, :, 0], pos, bias), stacks
+                return _rows_attention(q, k_l[:, :, 0], v_l[:, :, 0], pos, bias,
+                                       cfg.attn_block_length), stacks
             k_l, v_l = as_heads(k_l), as_heads(v_l)
             if chunk_form == "blocks":
-                return _blocks_attention(q, k_l, v_l, positions), stacks
+                return _blocks_attention(q, k_l, v_l, positions, cfg.attn_block_length), stacks
         if use_flash:
             attn = flash_attention_sharded(q, *repeat_groups(q, k_l, v_l), mesh=_ACTIVE_MESH[0],
-                                           causal=True, alibi_slopes=slopes)
+                                           causal=True, alibi_slopes=slopes,
+                                           mask_block=cfg.attn_block_length)
         else:
-            attn = cached_attention(q, k_l, v_l, pos, bias=bias)
+            attn = cached_attention(q, k_l, v_l, pos, bias=bias, block=cfg.attn_block_length)
         return attn, stacks
 
     return positions, attend
@@ -3921,6 +3998,12 @@ def causal_lm_loss(
     so the [B, S, vocab] logits tensor is never materialized — on a 16 GB
     v5e this is what lets 125M-class models train at batch 64+.
     """
+    if cfg.attn_block_length > 1:
+        raise NotImplementedError(
+            "causal_lm_loss with attn_block_length > 1 has no code: a model that generates "
+            "by diffusion over blocks is trained to denoise masked blocks under a noise "
+            "schedule, which its configuration does not give; next-token cross-entropy "
+            "under the block mask would let a position see its own label")
     inputs, labels = split_batch(batch)
     # stream top-level leaves ONCE for both the embedding and the (tied)
     # head use — see apply()'s _top_streamed note

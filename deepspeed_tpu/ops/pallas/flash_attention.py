@@ -313,7 +313,7 @@ def _lanes(col, lanes=LANES):
 # In-kernel scores (shared by forward + both backward kernels)
 # ---------------------------------------------------------------------------
 
-def _block_scores(q, k_blk, r0, k0, *, sm_scale, causal, slope_ref, w_ref):
+def _block_scores(q, k_blk, r0, k0, *, sm_scale, causal, slope_ref, w_ref, mask_block=1):
     """[rows, keys] fp32 scores of the query rows from ``r0`` on against the
     keys from ``k0`` on, with alibi / local-window / causal fused.
 
@@ -325,7 +325,10 @@ def _block_scores(q, k_blk, r0, k0, *, sm_scale, causal, slope_ref, w_ref):
     softmax_context_* the same way, pt_binding.cpp:1231-1283). ``w_ref`` (or
     None): [1, LANES] runtime local-attention window; w <= 0 means global
     (lets the scanned GPT-Neo layers alternate locality with one compiled
-    kernel)."""
+    kernel). ``mask_block`` > 1 (with ``causal``): causal BETWEEN blocks of that many
+    positions, a query seeing its own block whole (key j iff j // B <= i // B); ``r0``
+    is a multiple of it (a tile starts at a multiple of 128), so a row's place in its
+    block is its place in the tile's."""
     rows, keys = q.shape[0], k_blk.shape[0]
     s = sm_scale * jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -338,7 +341,11 @@ def _block_scores(q, k_blk, r0, k0, *, sm_scale, causal, slope_ref, w_ref):
     if w_ref is not None:
         w = w_ref[0, 0]  # fp32 runtime window; w <= 0 means global
         s = jnp.where((w <= 0) | (dist.astype(jnp.float32) < w), s, NEG_INF)
-    if causal:
+    if causal and mask_block > 1:  # the keys behind a query that its own block still holds
+        behind = (mask_block - 1) - (jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
+                                     & (mask_block - 1))
+        s = jnp.where(dist + behind >= 0, s, NEG_INF)
+    elif causal:
         s = jnp.where(dist >= 0, s, NEG_INF)
     return s
 
@@ -370,7 +377,7 @@ def _wrap_extras(base, n_in, has_slopes, has_window):
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, sm_scale, causal, num_k, slope_ref=None, w_ref=None,
+    *, sm_scale, causal, num_k, slope_ref=None, w_ref=None, mask_block=1,
 ):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -388,7 +395,8 @@ def _fwd_kernel(
         k_blk = k_ref[0, :keys, :]  # the block's keys at or under the last row
         v_blk = v_ref[0, :keys, :]
         s = _block_scores(q, k_blk, qi * block_q, kj * block_k, sm_scale=sm_scale,
-                          causal=causal, slope_ref=slope_ref, w_ref=w_ref)
+                          causal=causal, slope_ref=slope_ref, w_ref=w_ref,
+                          mask_block=mask_block)
         m_prev = m_scr[...]                     # [Bq, LANES] lane-broadcast
         m_new = jnp.maximum(m_prev, _lanes(jnp.max(s, axis=1)))
         p = jnp.exp(s - _widen(m_new, keys))
@@ -400,7 +408,8 @@ def _fwd_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    # a block strictly above the diagonal meets no case
+    # a block strictly above the diagonal meets no case (under ``mask_block`` too: the
+    # sub-tiles a step computes end at multiples of 128, where a block of the mask ends)
     _for_step_case(qi, kj, block_q, block_k, causal, _compute)
 
     @pl.when(kj == num_k - 1)
@@ -541,7 +550,7 @@ def _streamed_rows(causal, block_q, block_k, num_q):
 
 
 def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
-                   block_k, interpret, band=0, with_lse=True):
+                   block_k, interpret, band=0, with_lse=True, mask_block=1):
     """-> (out, lse). ``band`` (``static_window``): ``_band_forward``, which
     alone heeds ``with_lse``; ``w_arr`` is then the backward's alone."""
     if band:
@@ -553,7 +562,7 @@ def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
     num_k = Sk // block_k
     grid = (BH, Sq // block_q, num_k)
     base = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, num_k=num_k,
+        _fwd_kernel, sm_scale=sm_scale, causal=causal, num_k=num_k, mask_block=mask_block,
     )
     kv_block = _streamed_keys(causal, block_q, block_k)
     in_specs = [
@@ -779,16 +788,22 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
 # Public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash_bhsd(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q, block_k,
-                interpret, band):
+                interpret, band, mask_block=1):
     out, _ = _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal,
-                            block_q, block_k, interpret, band, with_lse=False)
+                            block_q, block_k, interpret, band, with_lse=False,
+                            mask_block=mask_block)
     return out
 
 
 def _flash_bhsd_fwd(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
-                    block_k, interpret, band):
+                    block_k, interpret, band, mask_block=1):
+    if mask_block > 1:
+        raise NotImplementedError(
+            "flash_attention backward under mask_block > 1 has no code: the backward kernels "
+            "attend under the causal mask (a model that generates by diffusion over blocks "
+            "has no training objective here)")
     out, lse = _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal,
                               block_q, block_k, interpret, band)
     # Under jax.checkpoint, out/lse are the residuals the backward kernels
@@ -801,7 +816,7 @@ def _flash_bhsd_fwd(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
     return out, (q, k, v, slopes_bh, w_arr, out, lse)
 
 
-def _flash_bhsd_bwd(sm_scale, causal, block_q, block_k, interpret, band, res, g):
+def _flash_bhsd_bwd(sm_scale, causal, block_q, block_k, interpret, band, mask_block, res, g):
     # the backward kernels keep the whole grid: a band's window reaches them as
     # the array a traced one would be (``w_arr`` among the residuals), and they
     # take the triangle's blocks, not the band's
@@ -826,6 +841,7 @@ def flash_attention(
     interpret: bool | None = None,
     alibi_slopes=None,
     window=None,
+    mask_block: int = 1,
 ):
     """Fused blockwise attention. q/k/v: [B, S, H, D] -> [B, S, H, D]. The
     forward kernel takes value heads of a width of their own (v [B, S, H, Dv]
@@ -848,6 +864,14 @@ def flash_attention(
     A general dense ``bias`` tensor is not fused; those callers use the XLA
     path (models/transformer._attention_dispatch falls back).
 
+    ``mask_block`` > 1 (causal self-attention, forward only): the mask is causal
+    between blocks of that many positions and a query sees its own block whole (key
+    j iff j // B <= i // B: generation by diffusion over blocks). A power of two up
+    to 128 that divides the rows: the grid, the blocks streamed and the sub-tiles a
+    step computes are the causal ones (all end at multiples of 128, where a block of
+    the mask ends), and only the compare inside a diagonal tile differs. 1 traces
+    what it always has.
+
     Sequence lengths need not be block-aligned when ``causal``: q/k/v are
     zero-padded up to a 128 multiple — padded key positions sit *after* every
     real query position, so the causal mask already excludes them, and padded
@@ -867,6 +891,14 @@ def flash_attention(
         # one [1, LANES] row per fused batch×head program
         slopes_bh = jnp.broadcast_to(
             jnp.tile(sl, B)[:, None, None], (B * H, 1, LANES))
+    if mask_block > 1:
+        ok = causal and Sq == Sk and window is None and alibi_slopes is None
+        if not ok or mask_block & (mask_block - 1) or mask_block > 128 or Sq % mask_block:
+            raise NotImplementedError(
+                f"flash_attention(mask_block={mask_block}) is causal self-attention with no "
+                "window and no alibi, under blocks of a power of two up to 128 that divides "
+                f"the rows (got causal={causal}, rows ({Sq}, {Sk}), window={window!r}, "
+                f"alibi={alibi_slopes is not None})")
     window, band = static_window(window, Sq, Sk, causal)
     w_arr = None
     if window is not None:
@@ -911,7 +943,7 @@ def flash_attention(
 
     out = _flash_bhsd(
         to_bhsd(q), to_bhsd(k), to_bhsd(v), slopes_bh, w_arr, sm_scale, causal,
-        block_q, block_k, interpret, band
+        block_q, block_k, interpret, band, mask_block
     )
     out = out.reshape(B, H, Sq_p, v.shape[3]).transpose(0, 2, 1, 3)
     if pad_q:

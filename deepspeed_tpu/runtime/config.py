@@ -1388,6 +1388,47 @@ class SpeculationConfig:
                 f"got {self.ngram_min_match}")
 
 
+BLOCK_STRATEGIES = ("low_confidence_static", "low_confidence_dynamic")
+
+
+@dataclass
+class BlockGenerationConfig:
+    """``serving.block_generation`` block (consumed by
+    ``inference/serving.ServingEngine`` for a model that generates by diffusion
+    over blocks, ``attn_block_length`` > 1; docs/serving.md "Generation by
+    diffusion over blocks"). The deployment's choice of quality against speed;
+    nothing of it is baked into a compiled program.
+
+    - ``denoising_steps``: T, the denoising passes a block of B positions takes
+      (1 .. B; 0 = B). A pass reveals B // T of the block's masked positions,
+      the last what is left; one more pass, the commit, writes the finished
+      block's K/V.
+    - ``strategy``: ``low_confidence_static`` (the B // T masked rows of largest
+      confidence a pass: the host knows every slot's pass without a fetch, so a
+      block step is enqueued ahead of the last one's fetch) or
+      ``low_confidence_dynamic`` (besides, every masked row whose confidence is
+      over ``threshold``: the host fetches a step before it plans the next).
+    - ``threshold``: the dynamic strategy's confidence above which a row is
+      revealed whatever its rank. The default cites no source (the family's own
+      sampler is not in this repository) and the dynamic path has no chip reading:
+      docs/serving.md.
+    """
+
+    denoising_steps: int = 0
+    strategy: str = "low_confidence_static"
+    threshold: float = 0.9
+
+    def __post_init__(self):
+        if self.strategy not in BLOCK_STRATEGIES:
+            raise DeepSpeedConfigError(
+                f"serving.block_generation.strategy must be one of {BLOCK_STRATEGIES}, "
+                f"got {self.strategy!r}")
+        if self.denoising_steps < 0:
+            raise DeepSpeedConfigError(
+                f"serving.block_generation.denoising_steps must be >= 0, "
+                f"got {self.denoising_steps}")
+
+
 @dataclass
 class RouterConfig:
     """``serving.router`` block (consumed by ``inference/router.Router``;
@@ -1486,6 +1527,7 @@ class ServingConfig:
     prefix_cache: PrefixCacheConfig = field(default_factory=PrefixCacheConfig)
     chunked_prefill: ChunkedPrefillConfig = field(default_factory=ChunkedPrefillConfig)
     speculation: SpeculationConfig = field(default_factory=SpeculationConfig)
+    block_generation: BlockGenerationConfig = field(default_factory=BlockGenerationConfig)
     fault_injection: FaultInjectionConfig = field(default_factory=FaultInjectionConfig)
     router: RouterConfig = field(default_factory=RouterConfig)
     gateway: GatewayConfig = field(default_factory=GatewayConfig)
@@ -1511,6 +1553,8 @@ class ServingConfig:
             self.chunked_prefill = _build(ChunkedPrefillConfig, self.chunked_prefill)
         if isinstance(self.speculation, dict):
             self.speculation = _build(SpeculationConfig, self.speculation)
+        if isinstance(self.block_generation, dict):
+            self.block_generation = _build(BlockGenerationConfig, self.block_generation)
         if isinstance(self.fault_injection, dict):
             self.fault_injection = _build(FaultInjectionConfig, self.fault_injection)
         if isinstance(self.router, dict):

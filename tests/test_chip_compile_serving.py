@@ -387,3 +387,45 @@ def test_dropless_expert_block_takes_the_grouped_matmul_kernel(cell, widths, row
     assert sorted(calls) == sorted([(str(pairs), str(F))] * 2 + [(str(pairs), str(M))]), calls
     assert not re.findall(RAGGED_DOT, text, re.M)
     assert not re.findall(rf"^\s*%?[\w.-]+ = bf16\[{count},(?:{M},{F}|{F},{M})\]", text, re.M)
+
+
+# ---------------------------------------------------------------------------
+# the block step keeps the slot cache in place (tier 1)
+# ---------------------------------------------------------------------------
+
+def test_block_step_keeps_the_slot_cache_as_the_layer_loops_carry(v5e, no_persistent_cache, as_tpu):
+    """``SlotWorker``'s block step (generation by diffusion over blocks) at SDAR-30B-A3B's
+    widths (32 / 4 grouped heads of 128, 128 experts of 768 top-8; 4 layers, 8 slots x 512,
+    vocabulary 1024): the stacked cache of merged rows [L, n, Smax, 1, 512] is the layer
+    loop's carry, donated in and aliased out; the block's 4 x 32 query rows contract the
+    rows where they lie (``_rows_attention``), so no layer is sliced out of the stack and
+    nothing cache-sized stands beside it (at the cell's size a copy is 2.8 GB)."""
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    L, n, Smax, B = 4, 8, 512, 4
+    cfg = TransformerConfig(
+        vocab_size=1024, max_seq_len=Smax, num_layers=L, num_heads=32, num_kv_heads=4,
+        qk_head_dim=128, hidden_size=2048, intermediate_size=768, pos_emb="rotary",
+        rotary_base=1e6, tie_embeddings=False, use_bias=False, norm_kind="rms",
+        activation="swiglu", decode_attn="xla", qk_norm="head", moe_every=1,
+        moe_routing="dropless", moe_norm_topk_prob=True, moe_aux_coeff=0.0, num_experts=128,
+        moe_top_k=8, attn_block_length=B, mask_token_id=1023, dtype=jnp.bfloat16)
+    worker, params, cache, sds = _bare_slot_worker(cfg, n, Smax, SingleDeviceSharding(v5e[0]))
+    worker.block_len, worker.n_slots = B, n
+    vec, blk = (lambda d: sds((n,), d)), (lambda d: sds((n, B), d))
+    compiled = worker._build_block_step().lower(
+        params, cache, blk(jnp.int32), blk(jnp.bool_), vec(jnp.bool_), blk(jnp.int32),
+        blk(jnp.bool_), vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_), vec(jnp.int32),
+        vec(jnp.float32), sds((2,), jnp.uint32), vec(jnp.float32), vec(jnp.int32),
+        vec(jnp.float32)).compile()
+    text = compiled.as_text()
+    assert cache["k"].shape == (L, n, Smax, 1, 512)  # a position's K/V heads as one row
+    whole = r"bf16\[" + rf"{L},{n},{Smax},(?:1,)?512" + r"\]"
+    copies = re.findall(rf"^\s*%?[\w.-]+ = {whole}\S* copy\(", text, re.M)
+    assert not copies, f"the block step copies the whole slot cache: {copies}"
+    ma = compiled.memory_analysis()
+    layer_bytes = n * Smax * 512 * 2  # one layer of K (or of V)
+    assert ma.alias_size_in_bytes >= 2 * L * layer_bytes  # K and V: donated in, aliased out
+    # the block's float32 scores [n, 32 x 4, Smax] and the sampler's rows, not a layer of K/V
+    # sliced out beside the stack for each of K and V
+    assert ma.temp_size_in_bytes < 2 * layer_bytes, (ma.temp_size_in_bytes, layer_bytes)
