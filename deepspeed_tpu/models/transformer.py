@@ -461,8 +461,9 @@ def _refuse_uncoded(cfg: "TransformerConfig") -> None:
             "layer kinds (local_attn_layers / rotary_layers)": cfg.layer_kinds is not None,
             "mtp_layers": cfg.mtp_layers != 0,
             "weight_bits": cfg.weight_bits != 0,
-            f"attn_impl={cfg.attn_impl!r} (training attends through the XLA form: the flash "
-            "backward kernels take one head size)": cfg.attn_impl != "xla",
+            f"attn_impl={cfg.attn_impl!r} (training attends through the XLA form or the flash "
+            "kernels, which take value heads of their own width)":
+                cfg.attn_impl not in ("xla", "flash"),
             "decode_attn='kernel' (the Pallas decode kernel reads per-head K/V; state "
             "decode_attn='xla')": cfg.decode_attn == "kernel",
         }
@@ -4080,14 +4081,21 @@ class Model:
     def flash_schedule(self, micro_batch) -> dict:
         """For the train step's program-ledger row: ``causal_tiles_fact`` at the
         sequence length of this micro-batch and the configured outer blocks (the
-        backward kernels cut their steps by the forward's rule). Nothing where
-        training does not attend through those kernels."""
+        backward cuts its steps by the forward's rule), and ``flash_bwd_form``:
+        whether that backward is the one kernel or the pair
+        (``flash_attention.backward_form``: ``fused`` / ``split``). Nothing
+        where training does not attend through those kernels."""
         c = self.config
         if c.attn_impl != "flash" or not c.causal or not (
                 isinstance(micro_batch, dict) and {"tokens", "input_ids"} & set(micro_batch)):
             return {}
+        from ..ops.pallas.flash_attention import backward_form
+
         length = jax.eval_shape(split_batch, micro_batch)[0].shape[1]
-        return causal_tiles_fact(c, length, c.flash_block_q or None, c.flash_block_k or None)
+        blocks = c.flash_block_q or None, c.flash_block_k or None
+        return {**causal_tiles_fact(c, length, *blocks),
+                "flash_bwd_form": backward_form(length, c.head_dim, c.value_head_dim,
+                                                jnp.dtype(c.dtype).itemsize, *blocks)}
 
     def flops_per_token(self) -> float:
         """Approximate training FLOPs/token (fwd+bwd ≈ 6 * n_params matmul

@@ -11,18 +11,24 @@ online-softmax ("flash") attention pair of kernels:
     and a [Bq, D] accumulator in VMEM; saves the per-row logsumexp for the
     backward pass.
   * backward recomputes P = exp(QK^T·scale − L) blockwise (FlashAttention-2
-    decomposition): one kernel accumulates dK/dV over q-blocks, one
-    accumulates dQ over k-blocks; the softmax Jacobian term uses
-    D_i = rowsum(dO ∘ O) computed in plain XLA.
+    decomposition) in ONE kernel where a head's dQ fits VMEM (``flash_bwd``,
+    PR 64: grid (head, key block, query block); a tile's P and dS are computed
+    once and feed dV and dK of its key block and dQ of its query block, dQ of
+    the whole head held in float32 scratch across its key blocks: 5 matmuls and
+    one exponential a tile), else in two (``backward_form``: one kernel
+    accumulates dK/dV over q-blocks, one dQ over k-blocks, each recomputing
+    the scores: 7 matmuls and two exponentials); the softmax Jacobian term
+    uses D_i = rowsum(dO ∘ O) computed in plain XLA.
 
 VMEM residency is O(block) not O(sequence): the streamed operand rides the
 *innermost grid dimension* (its BlockSpec indexes that dim), so Pallas
 double-buffers one block at a time from HBM while the online-softmax /
 gradient state lives in VMEM scratch accumulators that persist across the
 sequential innermost grid steps (output blocks are revisited, written once
-when the stream finishes). This keeps per-program VMEM at a few hundred KB
-at any sequence length — whole-sequence BlockSpecs would blow the ~16 MB
-VMEM budget at 8-16k tokens.
+when the stream finishes). This keeps per-program VMEM bounded at any
+sequence length — whole-sequence BlockSpecs would blow the VMEM budget at
+8-16k tokens, which is why the fused backward, whose dQ IS a whole-sequence
+block, is taken by a rule on the shapes and falls back to the pair.
 
 Causal masking keeps the grid and its coarse blocks and cuts the work INSIDE a
 step (the block analogue of the reference's triangular softmax kernels): a step
@@ -68,6 +74,16 @@ MAX_BLOCK_K = 2048
 # 256-wide bfloat16 ones compile, 2048 of 256-wide float32 are refused by 4 MiB
 # (tests/test_chip_compile_kernels.py).
 KEY_BLOCK_BYTES = 2 ** 20
+# The fused backward (``backward_form``): a head's dQ stays in VMEM over its key
+# blocks, so a step grows with the rows. ``FUSED_VMEM_BYTES`` is what its blocks and
+# accumulators may take by ``_fused_step_bytes`` (past it the backward is the pair of
+# kernels, whose steps do not grow), ``FUSED_VMEM_LIMIT`` what the kernel asks the
+# compiler for in place of the 16 MiB it gives by default: the compiler's own
+# temporaries beside those buffers (the tile's scores in flight, spilled registers)
+# came to 3 - 6 MiB and moved by 3 MiB with nothing but the count of heads in the
+# grid, which the second 16 MiB absorb (a v5e core has 128 MiB of VMEM).
+FUSED_VMEM_BYTES = 16 * 2 ** 20
+FUSED_VMEM_LIMIT = 32 * 2 ** 20
 # The width of the key sub-tiles a causal step's block is cut in: a step runs
 # only the sub-tiles that hold a key at or under its last row (``_keys_seen``,
 # ``_step_cases``).
@@ -91,6 +107,14 @@ def _key_block(s: int, width: int, itemsize: int) -> int:
     while cap > 128 and cap * width * itemsize > KEY_BLOCK_BYTES:
         cap //= 2
     return _auto_block(s, cap)
+
+
+def _outer_blocks(rows: int, keys: int, width: int, itemsize: int, block_q, block_k):
+    """(block_q, block_k) of the whole grid over ``rows`` x ``keys`` (multiples
+    of 128) at heads ``width`` wide (the wider of q/k and v): the caller's, held
+    to the lengths, or ``_auto_block`` / ``_key_block``'s."""
+    return (min(block_q, rows) if block_q else _auto_block(rows, MAX_BLOCK_Q),
+            min(block_k, keys) if block_k else _key_block(keys, width, itemsize))
 
 
 # Under a window that is a constant of the trace the forward runs over a band of
@@ -272,8 +296,7 @@ def causal_tiles_pct(rows: int, width: int, itemsize: int, block_q: int | None =
     the kernel's steps read their own schedule (100: the triangle and nothing
     else; the whole square would read 200)."""
     rows += (-rows) % 128
-    block_q = min(block_q, rows) if block_q else _auto_block(rows, MAX_BLOCK_Q)
-    block_k = min(block_k, rows) if block_k else _key_block(rows, width, itemsize)
+    block_q, block_k = _outer_blocks(rows, rows, width, itemsize, block_q, block_k)
     sub = _sub_tile(block_k)
     tiles = sum(_keys_seen(r0, block_q, k0, sub, block_k // sub)
                 for r0 in range(0, rows, block_q) for k0 in range(0, rows, block_k))
@@ -288,11 +311,13 @@ def _scratch(shape):
     return pltpu.VMEM(shape, jnp.float32)
 
 
-def _compiler_params(grid_len):
-    """Mark every grid dim except the innermost (the sequential stream over
-    which scratch accumulates) as parallel."""
+def _compiler_params(grid_len, sequential=1, vmem_limit_bytes=None):
+    """Mark every grid dim except the ``sequential`` innermost (the stream over
+    which scratch accumulates) as parallel; ``vmem_limit_bytes``: what the kernel
+    may take of VMEM where the compiler's 16 MiB are too few."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel",) * (grid_len - 1) + ("arbitrary",))
+        dimension_semantics=("parallel",) * (grid_len - sequential) + ("arbitrary",) * sequential,
+        vmem_limit_bytes=vmem_limit_bytes)
 
 
 def _widen(lane_tile, width):
@@ -607,14 +632,87 @@ def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
 # ---------------------------------------------------------------------------
 
 
+def _tile_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj, keys, *, sm_scale, causal,
+                slope_ref, w_ref):
+    """One tile of the backward, for the step's query block against the first
+    ``keys`` keys of its key block: S = QK^T, P = exp(S - lse), dP = dO V^T,
+    dS = P * (dP - delta) -> (q, do, k, P and dS in the operands' dtype): what
+    dV += P^T dO, dK += dS^T Q and dQ += dS K are taken from."""
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+    k_blk = k_ref[0, :keys, :]  # the block's keys at or under the last row
+    q_blk = q_ref[0]            # [Bq, D]
+    do_blk = do_ref[0]          # [Bq, Dv]
+    s = _block_scores(q_blk, k_blk, qi * block_q, kj * block_k, sm_scale=sm_scale,
+                      causal=causal, slope_ref=slope_ref, w_ref=w_ref)
+    p = jnp.exp(s - _widen(lse_ref[0], keys))  # [Bq, keys]
+    dp = jax.lax.dot_general(
+        do_blk, v_ref[0, :keys, :], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    ds = p * (dp - _widen(delta_ref[0], keys))
+    return q_blk, do_blk, k_blk, p.astype(do_blk.dtype), ds.astype(q_blk.dtype)
+
+
+def _dkdv_add(dk_scr, dv_scr, keys, q_blk, do_blk, p, ds, sm_scale):
+    """dV += P^T dO and dK += dS^T Q . scale on the first ``keys`` keys of the
+    key block's float32 accumulators."""
+    dv_scr[:keys, :] += jax.lax.dot_general(
+        p, do_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    dk_scr[:keys, :] += sm_scale * jax.lax.dot_general(
+        ds, q_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _bwd_fused_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    dq_scr, dk_scr, dv_scr, *, sm_scale, causal, num_q, num_k, slope_ref=None, w_ref=None,
+):
+    """The whole backward in one pass over the scores: grid ``(bh, kj, qi)``, the
+    query blocks the inner walk. A tile's P and dS are computed ONCE
+    (``_tile_grads``) and feed dV and dK of its key block (float32 scratch,
+    written when the key block's walk ends) and dQ of its query block: dQ of the
+    whole head stays in VMEM (``dq_scr``, [Sq, D] float32) across the head's key
+    blocks and is written once, in the output's dtype, when the last one ends."""
+    kj = pl.program_id(1)
+    qi = pl.program_id(2)
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+
+    @pl.when((kj == 0) & (qi == 0))
+    def _init_head():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    @pl.when(qi == 0)
+    def _init_keys():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def _compute(keys):
+        q_blk, do_blk, k_blk, p, ds = _tile_grads(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj, keys, sm_scale=sm_scale,
+            causal=causal, slope_ref=slope_ref, w_ref=w_ref)
+        _dkdv_add(dk_scr, dv_scr, keys, q_blk, do_blk, p, ds, sm_scale)
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_scr[rows, :] += sm_scale * jax.lax.dot_general(
+            ds, k_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    # q-blocks entirely above the diagonal contribute nothing to this k-block,
+    # and the others only to its keys at or under their last row
+    _for_step_case(qi, kj, block_q, block_k, causal, _compute)
+
+    @pl.when(qi == num_q - 1)
+    def _finalize_keys():
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+    @pl.when((kj == num_k - 1) & (qi == num_q - 1))
+    def _finalize_head():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+
 def _bwd_dkdv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_scr, dv_scr, *, sm_scale, causal, num_q, slope_ref=None, w_ref=None,
 ):
     kj = pl.program_id(1)
     qi = pl.program_id(2)
-    block_k = k_ref.shape[1]
-    block_q = q_ref.shape[1]
 
     @pl.when(qi == 0)
     def _init():
@@ -622,35 +720,12 @@ def _bwd_dkdv_kernel(
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     def _compute(keys):
-        k_blk = k_ref[0, :keys, :]  # the block's keys at or under the last row
-        v_blk = v_ref[0, :keys, :]
-        q_blk = q_ref[0]      # [Bq, D]
-        do_blk = do_ref[0]
-        lse = lse_ref[0]      # [Bq, LANES]
-        delta = delta_ref[0]  # [Bq, LANES]
+        q_blk, do_blk, _, p, ds = _tile_grads(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj, keys, sm_scale=sm_scale,
+            causal=causal, slope_ref=slope_ref, w_ref=w_ref)
+        _dkdv_add(dk_scr, dv_scr, keys, q_blk, do_blk, p, ds, sm_scale)
 
-        s = _block_scores(q_blk, k_blk, qi * block_q, kj * block_k, sm_scale=sm_scale,
-                          causal=causal, slope_ref=slope_ref, w_ref=w_ref)
-        p = jnp.exp(s - _widen(lse, keys))  # [Bq, keys]
-        # dV += P^T dO
-        dv_scr[:keys, :] += jax.lax.dot_general(
-            p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        # dS = P ∘ (dO V^T − Δ)
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - _widen(delta, keys))
-        # dK += dS^T Q · scale
-        dk_scr[:keys, :] += sm_scale * jax.lax.dot_general(
-            ds.astype(q_blk.dtype), q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    # q-blocks entirely above the diagonal contribute nothing to this k-block,
-    # and the others only to its keys at or under their last row
-    _for_step_case(qi, kj, block_q, block_k, causal, _compute)
+    _for_step_case(qi, kj, q_ref.shape[1], k_ref.shape[1], causal, _compute)
 
     @pl.when(qi == num_q - 1)
     def _finalize():
@@ -664,57 +739,70 @@ def _bwd_dq_kernel(
 ):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
-    block_q = q_ref.shape[1]
-    block_k = k_ref.shape[1]
 
     @pl.when(kj == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     def _compute(keys):
-        q_blk = q_ref[0]
-        do_blk = do_ref[0]
-        lse = lse_ref[0]      # [Bq, LANES]
-        delta = delta_ref[0]  # [Bq, LANES]
-        k_blk = k_ref[0, :keys, :]
-        v_blk = v_ref[0, :keys, :]
-        s = _block_scores(q_blk, k_blk, qi * block_q, kj * block_k, sm_scale=sm_scale,
-                          causal=causal, slope_ref=slope_ref, w_ref=w_ref)
-        p = jnp.exp(s - _widen(lse, keys))
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - _widen(delta, keys))
+        _, _, k_blk, _, ds = _tile_grads(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj, keys, sm_scale=sm_scale,
+            causal=causal, slope_ref=slope_ref, w_ref=w_ref)
         dq_scr[...] += sm_scale * jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            ds, k_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    _for_step_case(qi, kj, block_q, block_k, causal, _compute)
+    _for_step_case(qi, kj, q_ref.shape[1], k_ref.shape[1], causal, _compute)
 
     @pl.when(kj == num_k - 1)
     def _finalize():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
+def _fused_step_bytes(rows, block_q, block_k, d, dv, itemsize):
+    """VMEM a step of the fused backward holds, by the count of its own buffers:
+    the streamed blocks twice (q, dO and the float32 lse and delta rows of a
+    query block; k, v and the dK, dV it writes of a key block), the key block's
+    two float32 accumulators and the head's dQ (float32 scratch and the block
+    written out, twice), with 3 bytes a score element for the tile in flight (the
+    compiler's own temporaries came to 0.9 - 6 of them: ``FUSED_VMEM_LIMIT``
+    holds what is over); a head narrower than the 128 lanes is padded to them."""
+    both = max(d, LANES) + max(dv, LANES)
+    streamed = itemsize * (block_q + 2 * block_k) * both + 2 * 4 * LANES * block_q
+    held = 4 * block_k * both + rows * max(d, LANES) * (4 + 2 * itemsize)
+    return 2 * streamed + held + 3 * block_q * block_k
+
+
+def backward_form(rows: int, d: int, dv: int, itemsize: int, block_q: int | None = None,
+                  block_k: int | None = None, keys: int | None = None) -> str:
+    """``"fused"`` | ``"split"``: the backward the kernels take over ``rows``
+    query rows (``keys`` keys: the rows', for self-attention) of heads ``d``
+    wide (values ``dv``), from the shapes and the blocks alone (None: the
+    call's own). One kernel (``flash_bwd``) where a head's dQ fits VMEM beside a
+    step's blocks (``_fused_step_bytes`` within ``FUSED_VMEM_BYTES``: up to 4,096
+    rows of 128-wide bfloat16 heads at the call's own blocks, 2,048 of latent
+    attention's 192 / 128), else the pair that recomputes the scores
+    (``flash_bwd_dkdv`` + ``flash_bwd_dq``), whose steps do not grow with the
+    rows."""
+    rows += (-rows) % 128
+    keys = rows if keys is None else keys + (-keys) % 128
+    block_q, block_k = _outer_blocks(rows, keys, max(d, dv), itemsize, block_q, block_k)
+    fits = _fused_step_bytes(rows, block_q, block_k, d, dv, itemsize) <= FUSED_VMEM_BYTES
+    return "fused" if fits else "split"
+
+
 def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
     q, k, v, slopes_bh, w_arr, out, lse = res
-    if v.shape[2] != q.shape[2]:
-        raise NotImplementedError(
-            f"flash_attention backward: value heads of {v.shape[2]} beside q/k heads of "
-            f"{q.shape[2]} — the backward kernels take one head size; differentiate through "
-            "attn_impl='xla'")
     lse = jnp.broadcast_to(lse[..., None], lse.shape + (LANES,))  # re-tile lanes
     BH, Sq, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = v.shape[1:]  # a value head may be narrower than a q/k head (latent attention)
     num_q = Sq // block_q
     num_k = Sk // block_k
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [BH,Sq]
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
 
-    cp = _compiler_params(3)
     kv_block = _streamed_keys(causal, block_q, block_k)
     q_block = _streamed_rows(causal, block_q, block_k, num_q)
+    key_block = lambda bh, kj, qi: (bh, kj, 0)
 
     has_slopes = slopes_bh is not None
     has_window = w_arr is not None
@@ -726,61 +814,82 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
     if has_window:
         extra_specs.append(_vmem_spec((1, LANES), lambda bh, a, b: (0, 0)))
         extra_ops.append(w_arr)
+    operands = (q, k, v, g, lse, delta, *extra_ops)
+    dslopes = jnp.zeros_like(slopes_bh) if has_slopes else None
+    dw = jnp.zeros_like(w_arr) if has_window else None
 
+    # the query blocks streamed past a key block: dK/dV's walk, and the fused kernel's
+    by_key_block = [
+        _vmem_spec((1, block_q, D), q_block),
+        _vmem_spec((1, block_k, D), key_block),
+        _vmem_spec((1, block_k, Dv), key_block),
+        _vmem_spec((1, block_q, Dv), q_block),
+        _vmem_spec((1, block_q, LANES), q_block),
+        _vmem_spec((1, block_q, LANES), q_block),
+    ] + extra_specs
+    dkdv_specs = [_vmem_spec((1, block_k, D), key_block), _vmem_spec((1, block_k, Dv), key_block)]
+    dkdv_shapes = [jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
+                   jax.ShapeDtypeStruct((BH, Sk, Dv), v.dtype)]
+    dkdv_scratch = [_scratch((block_k, D)), _scratch((block_k, Dv))]
+
+    if backward_form(Sq, D, Dv, q.dtype.itemsize, block_q, block_k, Sk) == "fused":
+        base = functools.partial(_bwd_fused_kernel, sm_scale=sm_scale, causal=causal,
+                                 num_q=num_q, num_k=num_k)
+        dq, dk, dv = pl.pallas_call(
+            _wrap_extras(base, 6, has_slopes, has_window),
+            grid=(BH, num_k, num_q),
+            in_specs=by_key_block,
+            out_specs=[_vmem_spec((1, Sq, D), lambda bh, kj, qi: (bh, 0, 0))] + dkdv_specs,
+            out_shape=[jax.ShapeDtypeStruct((BH, Sq, D), q.dtype)] + dkdv_shapes,
+            scratch_shapes=[_scratch((Sq, D))] + dkdv_scratch,
+            interpret=interpret,
+            # dQ accumulates over both walks: only the heads are independent
+            compiler_params=_compiler_params(3, 2, FUSED_VMEM_LIMIT),
+            name="flash_bwd",
+        )(*operands)
+        return dq, dk, dv, dslopes, dw
+
+    # the pair holds a whole [block_q, block_k] tile as the fused kernel does, and the
+    # compiler's temporaries for dK/dV's moved past the default 16 MiB with the count of
+    # heads alone (256-wide bfloat16 heads at 2,048 keys: fits at 8 heads, 19.8 MiB at 128)
+    cp = _compiler_params(3, vmem_limit_bytes=FUSED_VMEM_LIMIT)
     base_dkdv = functools.partial(
         _bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal, num_q=num_q,
     )
-    kern_dkdv = _wrap_extras(base_dkdv, 6, has_slopes, has_window)
-    dkdv = pl.pallas_call(
-        kern_dkdv,
+    dk, dv = pl.pallas_call(
+        _wrap_extras(base_dkdv, 6, has_slopes, has_window),
         grid=(BH, num_k, num_q),
-        in_specs=[
-            _vmem_spec((1, block_q, D), q_block),
-            _vmem_spec((1, block_k, D), lambda bh, kj, qi: (bh, kj, 0)),
-            _vmem_spec((1, block_k, D), lambda bh, kj, qi: (bh, kj, 0)),
-            _vmem_spec((1, block_q, D), q_block),
-            _vmem_spec((1, block_q, LANES), q_block),
-            _vmem_spec((1, block_q, LANES), q_block),
-        ] + extra_specs,
-        out_specs=[
-            _vmem_spec((1, block_k, D), lambda bh, kj, qi: (bh, kj, 0)),
-            _vmem_spec((1, block_k, D), lambda bh, kj, qi: (bh, kj, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, Sk, D), v.dtype),
-        ],
-        scratch_shapes=[_scratch((block_k, D)), _scratch((block_k, D))],
+        in_specs=by_key_block,
+        out_specs=dkdv_specs,
+        out_shape=dkdv_shapes,
+        scratch_shapes=dkdv_scratch,
         interpret=interpret,
         compiler_params=cp,
         name="flash_bwd_dkdv",
-    )(q, k, v, g, lse, delta, *extra_ops)
-    dk, dv = dkdv
+    )(*operands)
 
     base_dq = functools.partial(
         _bwd_dq_kernel, sm_scale=sm_scale, causal=causal, num_k=num_k,
     )
-    kern_dq = _wrap_extras(base_dq, 6, has_slopes, has_window)
+    query_block = lambda bh, qi, kj: (bh, qi, 0)
     dq = pl.pallas_call(
-        kern_dq,
+        _wrap_extras(base_dq, 6, has_slopes, has_window),
         grid=(BH, num_q, num_k),
         in_specs=[
-            _vmem_spec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
+            _vmem_spec((1, block_q, D), query_block),
             _vmem_spec((1, block_k, D), kv_block),
-            _vmem_spec((1, block_k, D), kv_block),
-            _vmem_spec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
-            _vmem_spec((1, block_q, LANES), lambda bh, qi, kj: (bh, qi, 0)),
-            _vmem_spec((1, block_q, LANES), lambda bh, qi, kj: (bh, qi, 0)),
+            _vmem_spec((1, block_k, Dv), kv_block),
+            _vmem_spec((1, block_q, Dv), query_block),
+            _vmem_spec((1, block_q, LANES), query_block),
+            _vmem_spec((1, block_q, LANES), query_block),
         ] + extra_specs,
-        out_specs=_vmem_spec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
+        out_specs=_vmem_spec((1, block_q, D), query_block),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
         scratch_shapes=[_scratch((block_q, D))],
         interpret=interpret,
         compiler_params=cp,
         name="flash_bwd_dq",
-    )(q, k, v, g, lse, delta, *extra_ops)
-    dslopes = jnp.zeros_like(slopes_bh) if has_slopes else None
-    dw = jnp.zeros_like(w_arr) if has_window else None
+    )(*operands)
     return dq, dk, dv, dslopes, dw
 
 
@@ -821,8 +930,9 @@ def _flash_bhsd_bwd(sm_scale, causal, block_q, block_k, interpret, band, mask_bl
     # the array a traced one would be (``w_arr`` among the residuals), and they
     # take the triangle's blocks, not the band's
     if band:
-        block_q = _auto_block(g.shape[1], MAX_BLOCK_Q)
-        block_k = _key_block(g.shape[1], g.shape[2], res[0].dtype.itemsize)
+        q, v = res[0], res[2]
+        block_q, block_k = _outer_blocks(q.shape[1], q.shape[1], max(q.shape[2], v.shape[2]),
+                                         q.dtype.itemsize, None, None)
     return _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret)
 
 
@@ -844,10 +954,10 @@ def flash_attention(
     mask_block: int = 1,
 ):
     """Fused blockwise attention. q/k/v: [B, S, H, D] -> [B, S, H, D]. The
-    forward kernel takes value heads of a width of their own (v [B, S, H, Dv]
-    -> [B, S, H, Dv]: latent attention's 192-wide q/k beside 128-wide v); the
-    softmax scale defaults to 1/sqrt(D) of the q/k heads as given. The backward
-    kernels refuse unequal widths by name.
+    kernels take value heads of a width of their own (v [B, S, H, Dv]
+    -> [B, S, H, Dv]: latent attention's 192-wide q/k beside 128-wide v;
+    dV is then as wide as v, dQ and dK as q and k); the softmax scale defaults
+    to 1/sqrt(D) of the q/k heads as given.
 
     Structured biases are FUSED (computed from block positions in-kernel, no
     HBM bias tensor — the reference threads alibi through its inference
@@ -928,9 +1038,8 @@ def flash_attention(
         block_q, block_k = plan[:2]
     else:
         band = 0
-        block_q = min(block_q, Sq_p) if block_q else _auto_block(Sq_p, MAX_BLOCK_Q)
-        block_k = (min(block_k, Sk_p) if block_k
-                   else _key_block(Sk_p, max(D, v.shape[3]), q.dtype.itemsize))
+        block_q, block_k = _outer_blocks(Sq_p, Sk_p, max(D, v.shape[3]), q.dtype.itemsize,
+                                         block_q, block_k)
     if Sq_p % block_q or Sk_p % block_k:
         raise ValueError(
             f"sequence lengths ({Sq_p}, {Sk_p}) must be divisible by blocks ({block_q}, {block_k})"

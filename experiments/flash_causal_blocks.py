@@ -3,13 +3,16 @@ grid step can take (PR 51): what the kernels of the PARENT commit run (every blo
 touches, whole, and a fetch for every step), and this tree's kernels with and without the cut of a
 step's block to the key sub-tiles its rows see (``block@512x1024``: no cut, the parent's work
 under this tree's index maps, which fetch nothing for a step that computes nothing) at several
-OUTER blocks (``512``: the module's own). A time is
+OUTER blocks (``512``: the module's own), the backward as the ONE kernel ``flash_bwd`` where
+``backward_form`` takes it (PR 64) and, under ``/split``, as the pair that recomputes the scores. A time is
 the DEVICE's: the mean duration of the kernel's own events in a profiler trace of ``REPS`` calls,
 so no host time and no neighbouring operation is in it. ``of_triangle_pct`` is the share of the
 chip's peak the call reaches when charged the triangle's operations alone (``chipbench/flops.py``
 ``flash_cost``'s count: 2 matmuls forward, 5 backward, half the square), which is what
 ``flash_roofline_pct`` reads in the train cell. PERF.md section 6 (PR 51) has the table, and the
-two forms it refused (a loop over sub-tiles; a mask only on the sub-tiles the diagonal crosses).
+two forms it refused (a loop over sub-tiles; a mask only on the sub-tiles the diagonal crosses);
+PR 64's has both backward forms at the train cell's shape, at 1,536 and 4,096 rows, at latent
+attention's 192 / 128 heads (which the parent's backward refuses: its row says so) and under alibi.
 
     git archive <parent> | tar -x -C .chipbench_tree      # once, for the ``parent`` column
     chiprun -- python3 experiments/flash_causal_blocks.py [--tiny] [--only train]
@@ -23,6 +26,7 @@ import glob
 import importlib.util
 import json
 import os
+import re
 import sys
 import tempfile
 import time
@@ -36,24 +40,32 @@ from deepspeed_tpu.ops.pallas import flash_attention as fa
 
 PEAK_FLOPS = 197e12  # one v5e chip, bf16 (chipbench/peaks.py)
 REPS = 10
-KERNELS = ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")
+# kernel: what finds its events by name. ``flash_bwd_dkdv`` + ``flash_bwd_dq`` are the parent's
+# backward and this tree's ``split`` form, ``flash_bwd`` (PR 64) the one kernel
+KERNELS = {"flash_fwd": "flash_fwd", "flash_bwd_dkdv": "flash_bwd_dkdv", "flash_bwd_dq": "flash_bwd_dq",
+           "flash_bwd": r"flash_bwd(?!_d)"}
+BACKWARD = ("flash_bwd_dkdv", "flash_bwd_dq", "flash_bwd")
 PARENT = os.path.join(ROOT, ".chipbench_tree", "deepspeed_tpu", "ops", "pallas", "flash_attention.py")
 # name: (fused batch x heads, rows, q/k width, value width, alibi, with the backward)
 SHAPES = {
     "train": (128, 2048, 128, 128, False, True),       # pythia-1.4b.train-zero3-x4: 8 x 16 heads
     "train-1024": (128, 1024, 128, 128, False, True),
     "train-1536": (128, 1536, 128, 128, False, True),
+    "train-4096": (64, 4096, 128, 128, False, True),    # the longest rows whose dQ stays in VMEM
+    "latent-train": (64, 2048, 192, 128, False, True),  # latent attention's heads (kanana's kind)
+    "alibi-train": (64, 2048, 128, 128, True, True),    # BLOOM's kind
     "bloom": (16, 2048, 128, 128, True, False),        # bloom-1b7.serve-doc's prefill
     "kanana-8192": (32, 8192, 192, 128, False, False),  # latent attention, expanded
     "kanana-4096": (32, 4096, 192, 128, False, False),
     "k-exaone-8192": (64, 8192, 128, 128, False, False),
 }
 TINY = {"train": (2, 2048, 32, 32, False, True), "bloom": (2, 1024, 32, 32, True, False)}
-# name: (SUB_K (None: the whole block), (MAX_BLOCK_Q, MAX_BLOCK_K) (None: the module's))
-SCHEDULES = {"block@512x1024": (None, (512, 1024)), "512@512x1024": (512, (512, 1024)),
-             "512": (512, None), "512@1024x1024": (512, (1024, 1024)),
-             "512@1024x2048": (512, (1024, 2048)), "512@512x512": (512, (512, 512)),
-             "512@256x1024": (512, (256, 1024))}
+# name: (SUB_K (None: the whole block), (MAX_BLOCK_Q, MAX_BLOCK_K) (None: the module's)[, the
+# backward's form (PR 64; left out: ``backward_form``'s own)])
+SCHEDULES = {"512": (512, None), "512/split": (512, None, "split"),
+             "512@512x1024": (512, (512, 1024)), "512@512x1024/split": (512, (512, 1024), "split"),
+             "512@256x2048": (512, (256, 2048)), "512@1024x1024": (512, (1024, 1024)),
+             "512@512x512": (512, (512, 512)), "block@512x1024": (None, (512, 1024))}
 
 
 def load_parent():
@@ -69,16 +81,17 @@ def load_parent():
 class schedule:
     """The module's constants for the length of a trace: the kernels read them as they are traced."""
 
-    def __init__(self, sub_k, blocks):
-        self.new = (sub_k or 1 << 30, *(blocks or (fa.MAX_BLOCK_Q, fa.MAX_BLOCK_K)))
+    def __init__(self, sub_k, blocks, form=None):
+        budget = {None: fa.FUSED_VMEM_BYTES, "split": 0, "fused": 1 << 40}[form]
+        self.new = (sub_k or 1 << 30, *(blocks or (fa.MAX_BLOCK_Q, fa.MAX_BLOCK_K)), budget)
 
     def __enter__(self):
-        self.old = (fa.SUB_K, fa.MAX_BLOCK_Q, fa.MAX_BLOCK_K)
-        fa.SUB_K, fa.MAX_BLOCK_Q, fa.MAX_BLOCK_K = self.new
+        self.old = (fa.SUB_K, fa.MAX_BLOCK_Q, fa.MAX_BLOCK_K, fa.FUSED_VMEM_BYTES)
+        fa.SUB_K, fa.MAX_BLOCK_Q, fa.MAX_BLOCK_K, fa.FUSED_VMEM_BYTES = self.new
         fa.causal_tiles_pct.cache_clear()
 
     def __exit__(self, *exc):
-        fa.SUB_K, fa.MAX_BLOCK_Q, fa.MAX_BLOCK_K = self.old
+        fa.SUB_K, fa.MAX_BLOCK_Q, fa.MAX_BLOCK_K, fa.FUSED_VMEM_BYTES = self.old
         fa.causal_tiles_pct.cache_clear()
 
 
@@ -99,8 +112,8 @@ def kernel_ms(fn, args, interpret):
         if not interpret:
             path, = glob.glob(os.path.join(tmp, "**", "*.xplane.pb"), recursive=True)
             ops, = reduce.load(path).devices.values()
-            for kernel in KERNELS:
-                spent = [b - a for name, a, b in ops if kernel in name]
+            for kernel, named in KERNELS.items():
+                spent = [b - a for name, a, b in ops if re.search(named, name)]
                 if spent:  # one event a call: ``events`` says so
                     found[kernel] = sum(spent) / REPS * 1e3
                     found.setdefault("events", {})[kernel] = len(spent)
@@ -129,7 +142,7 @@ def main():
                              m._key_block(rows, max(d, dv), 2) if hasattr(m, "_key_block")
                              else m._auto_block(rows, m.MAX_BLOCK_K))
         triangle = bh * rows * rows / 2
-        counted = {"flash_fwd": 2 * triangle * (d + dv), "backward": 5 * 2 * triangle * d}
+        counted = {"flash_fwd": 2 * triangle * (d + dv), "backward": 2 * triangle * (3 * d + 2 * dv)}
 
         def call(module, blocks):  # arrays are operands: a closed-over one is baked into the executable
             attend = lambda q, k, v, slopes: module._flash_bhsd(
@@ -142,21 +155,21 @@ def main():
         rows_out, ref = {}, None
         variants = ([("parent", None)] if parent else []) + list(SCHEDULES.items())
         for label, sched in variants:
-            if sched is None:
-                blocks = auto(parent)
-                ms, out = kernel_ms(call(parent, blocks), (q, k, v, slopes, g), interpret)
-                tiles = None
-            else:
-                with schedule(*sched):
-                    blocks = auto()
-                    try:
-                        ms, out = kernel_ms(call(fa, blocks), (q, k, v, slopes, g), interpret)
-                    except Exception as e:  # noqa: BLE001 -- a block the chip's compiler refuses is a row
-                        print(name, label, blocks, "refused:", str(e)[-300:], flush=True)
-                        continue
+            module = parent if sched is None else fa
+            with schedule(*(sched or (fa.SUB_K, None))):
+                blocks = auto(module)
+                try:
+                    ms, out = kernel_ms(call(module, blocks), (q, k, v, slopes, g), interpret)
+                # a block the chip's compiler refuses is a row, and so is a backward the parent lacks
+                except Exception as e:  # noqa: BLE001
+                    print(name, label, blocks, "refused:", str(e)[-300:], flush=True)
+                    continue
+                tiles = form = None
+                if sched is not None:
                     tiles = fa.causal_tiles_pct(rows, max(d, dv), 2)
+                    form = fa.backward_form(rows, d, dv, 2, *blocks) if backward else None
             ref = out if ref is None else ref
-            row = {"blocks": blocks, "ms": ms, "causal_tiles_pct": tiles,
+            row = {"blocks": blocks, "ms": ms, "causal_tiles_pct": tiles, "backward_form": form,
                    "maxdiff_vs_first": max(float(jnp.max(jnp.abs(a.astype(jnp.float32)
                                                                  - b.astype(jnp.float32))))
                                            for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(ref)))}
@@ -164,7 +177,8 @@ def main():
                 share = lambda ops, t: 100 * ops / PEAK_FLOPS / (t / 1e3)
                 row["of_triangle_pct"] = {"flash_fwd": share(counted["flash_fwd"], ms["flash_fwd"])}
                 if backward:
-                    both = ms["flash_bwd_dkdv"] + ms["flash_bwd_dq"]
+                    both = sum(ms.get(kernel, 0.0) for kernel in BACKWARD)
+                    row["backward_ms"] = both
                     row["of_triangle_pct"]["backward"] = share(counted["backward"], both)
                     row["of_triangle_pct"]["all"] = share(sum(counted.values()), both + ms["flash_fwd"])
             rows_out[label] = row

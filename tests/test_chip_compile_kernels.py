@@ -84,7 +84,8 @@ def test_kernel_compiles_for_v5e(build, v5e, no_persistent_cache):
     assert _footprint(compiled) < HBM_BYTES
 
 
-ALL_FLASH = ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")
+FUSED = ("flash_fwd", "flash_bwd")  # PR 64: forward + backward are TWO Pallas calls where dQ fits VMEM
+SPLIT = ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")
 
 
 @pytest.mark.parametrize("cell,stack,alibi", [
@@ -122,16 +123,21 @@ def test_decode_kernel_compiles_at_the_cells_shapes(cell, stack, alibi, v5e, no_
 
 
 @pytest.mark.parametrize("bh,rows,widths,dtype,alibi,kernels,block_k", [
-    (128, 2048, (128, 128), jnp.bfloat16, False, ALL_FLASH, 2048),
+    (128, 2048, (128, 128), jnp.bfloat16, False, FUSED, 2048),
     (32, 8192, (192, 128), jnp.bfloat16, False, ("flash_fwd",), 2048),
+    (32, 2048, (192, 128), jnp.bfloat16, False, FUSED, 2048),
     (16, 2048, (128, 128), jnp.bfloat16, True, ("flash_fwd",), 2048),
-    (16, 2048, (128, 128), jnp.bfloat16, True, ALL_FLASH, 2048),
-    (8, 4096, (128, 128), jnp.float32, True, ALL_FLASH, 2048),
-    (8, 4096, (256, 256), jnp.bfloat16, False, ALL_FLASH, 2048),
-    (8, 4096, (256, 256), jnp.float32, False, ALL_FLASH, 1024),
-    (8, 4096, (64, 64), jnp.bfloat16, False, ALL_FLASH, 2048),
-], ids=["train-128x2048x128", "latent-32x8192x192-128", "alibi-16x2048x128", "alibi-fwd-bwd",
-        "f32-128-alibi", "bf16-256", "f32-256-halved", "bf16-64"])
+    (16, 2048, (128, 128), jnp.bfloat16, True, FUSED, 2048),
+    (8, 4096, (128, 128), jnp.bfloat16, True, FUSED, 2048),
+    (8, 4096, (192, 128), jnp.bfloat16, False, SPLIT, 2048),
+    (8, 8192, (128, 128), jnp.bfloat16, False, SPLIT, 2048),
+    (8, 4096, (128, 128), jnp.float32, True, SPLIT, 2048),
+    (8, 4096, (256, 256), jnp.bfloat16, False, SPLIT, 2048),
+    (8, 4096, (256, 256), jnp.float32, False, SPLIT, 1024),
+    (8, 4096, (64, 64), jnp.bfloat16, False, FUSED, 2048),
+], ids=["train-128x2048x128", "latent-32x8192x192-128", "latent-train-32x2048x192-128",
+        "alibi-16x2048x128", "alibi-fwd-bwd", "bf16-128-4096-alibi", "latent-4096-split",
+        "bf16-128-8192-split", "f32-128-alibi", "bf16-256", "f32-256-halved", "bf16-64"])
 def test_causal_schedule_compiles_for_v5e(bh, rows, widths, dtype, alibi, kernels, block_k, v5e,
                                           no_persistent_cache):
     """The causal kernels with their work cut inside the step (PR 51: a case a
@@ -143,7 +149,12 @@ def test_causal_schedule_compiles_for_v5e(bh, rows, widths, dtype, alibi, kernel
     keys where a block holds ``KEY_BLOCK_BYTES`` or less; 256-wide float32
     heads at 2,048 are refused by 4 MiB and take 1,024). Each compiles for the
     described chip inside the 16 MiB of VMEM its compiler gives a kernel (it
-    refuses more)."""
+    refuses more). Since PR 64 the backward is ONE kernel (``flash_bwd``, once:
+    the train step's forward + backward hold TWO Pallas calls, were three) where
+    ``backward_form`` says a head's dQ fits VMEM beside a step's blocks (the
+    train cell's shape, latent attention's 192 / 128 heads at 2,048 rows, 4,096
+    rows of 128-wide bfloat16 heads under alibi), and the pair on the other side
+    of the line, which the compiler takes at any length."""
     from deepspeed_tpu.ops.pallas import flash_attention as fa
 
     one_chip = SingleDeviceSharding(v5e[0])
@@ -153,6 +164,8 @@ def test_causal_schedule_compiles_for_v5e(bh, rows, widths, dtype, alibi, kernel
     slopes = jnp.full((bh, 1, fa.LANES), 0.25, jnp.float32) if alibi else None
     blocks = fa._auto_block(rows, fa.MAX_BLOCK_Q), fa._key_block(rows, d, jnp.dtype(dtype).itemsize)
     assert blocks == (512, block_k) and fa._sub_tile(blocks[1]) == fa.SUB_K
+    form = fa.backward_form(rows, d, dv, jnp.dtype(dtype).itemsize)
+    assert len(kernels) == 1 or kernels == {"fused": FUSED, "split": SPLIT}[form]
 
     def attend(q, k, v):
         return fa._flash_bhsd(q, k, v, slopes, None, d ** -0.5, True, *blocks, False, 0)
@@ -163,9 +176,9 @@ def test_causal_schedule_compiles_for_v5e(bh, rows, widths, dtype, alibi, kernel
         fn = jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
                       argnums=(0, 1, 2))
     text = jax.jit(fn).lower(qk, qk, v).compile().as_text()
-    found = set(re.findall(r'^\s*%?[a-z_]*?(flash_[a-z]+(?:_[a-z]+)?)_*[\d.]* = .*'
-                           r'custom_call_target="tpu_custom_call"', text, re.M))
-    assert found == set(kernels), found
+    found = re.findall(r'^\s*(?:ROOT )?%?[a-z_]*?(flash_[a-z]+(?:_[a-z]+)?)_*[\d.]* = .*'
+                       r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert sorted(found) == sorted(kernels), found  # each once
 
 
 @pytest.mark.parametrize("window", [100, 128, 512, 1024, 2048, 4096])

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.models.transformer import Model, TransformerConfig, xla_attention
-from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.flash_attention import backward_form, flash_attention
 
 
 def _qkv(B=2, S=256, H=4, D=32, dtype=jnp.float32, seed=0):
@@ -230,7 +230,11 @@ def test_gradients_through_a_static_window_match_the_dense_reference(window, row
     def loss_ref(q, k, v):
         return jnp.sum(_dense_window(to_bhsd(q), to_bhsd(k), to_bhsd(v), window)[0] * tgt)
 
-    assert _pallas_calls(jax.grad(loss_flash), q, k, v)["flash_bwd_dq"][2] == 7  # + the window
+    # float32 heads: the one kernel up to 1,024 rows, the pair at 2,048; each takes the window
+    kernels = BACKWARD_KERNELS[backward_form(rows, 32, 32, 4)]
+    assert kernels == BACKWARD_KERNELS["split" if rows == 2048 else "fused"]
+    calls = _pallas_calls(jax.grad(loss_flash), q, k, v)
+    assert all(calls[name][2] == 7 for name in kernels)
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b, name in zip(gf, gr, "qkv"):
@@ -376,6 +380,25 @@ def _schedule_call(rows, blocks, **kw):
     return lambda q, k, v: flash_attention(q, k, v, block_q=bq, block_k=bk, **kw)
 
 
+BACKWARD_KERNELS = {"fused": {"flash_bwd"}, "split": {"flash_bwd_dkdv", "flash_bwd_dq"}}
+
+
+@pytest.fixture(params=["fused", "split"])
+def form(request, monkeypatch):
+    """The backward's two forms (PR 64), each forced for a test at shapes whose
+    own rule (``backward_form``) would take the fused one: the budget of the
+    rule is the one thing that chooses."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "FUSED_VMEM_BYTES", {"fused": 1 << 40, "split": 0}[request.param])
+    return request.param
+
+
+def _backward_kernels(loss, *args):
+    return {name for name in _pallas_calls(jax.grad(loss, argnums=(0, 1, 2)), *args)
+            if name.startswith("flash_bwd")}
+
+
 @pytest.mark.parametrize("case", SCHEDULE_CASES)
 def test_causal_schedule_forward_matches_the_dense_reference(case):
     rows, blocks = SCHEDULE_CASES[case]
@@ -385,11 +408,15 @@ def test_causal_schedule_forward_matches_the_dense_reference(case):
 
 
 @pytest.mark.parametrize("case", SCHEDULE_CASES)
-def test_causal_schedule_gradients_match_the_dense_reference(case):
+def test_causal_schedule_gradients_match_the_dense_reference(case, form):
+    """Under the one kernel (dQ of the head held in VMEM over its key blocks: one
+    block, several, rows that no power of two over 512 divides, padded rows) and
+    under the pair."""
     rows, blocks = SCHEDULE_CASES[case]
     q, k, v = _qkv(B=1, S=rows, H=1, D=32, seed=rows + 1)
     tgt = jax.random.normal(jax.random.PRNGKey(rows), q.shape)
     attend = _schedule_call(rows, blocks)
+    assert _backward_kernels(lambda *a: jnp.sum(attend(*a) * tgt), q, k, v) == BACKWARD_KERNELS[form]
     gf = jax.grad(lambda *a: jnp.sum(attend(*a) * tgt), argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(lambda *a: jnp.sum(_dense(*a) * tgt), argnums=(0, 1, 2))(q, k, v)
     for a, b, name in zip(gf, gr, "qkv"):
@@ -398,12 +425,12 @@ def test_causal_schedule_gradients_match_the_dense_reference(case):
 
 
 @pytest.mark.parametrize("block_k", [None, 1024])
-@pytest.mark.parametrize("form", ["alibi", "traced-window", "traced-window-global",
+@pytest.mark.parametrize("kind", ["alibi", "traced-window", "traced-window-global",
                                   "alibi+traced-window", "static-window", "not-causal",
                                   "not-causal-window"])
-def test_causal_schedule_under_alibi_and_windows(form, block_k):
-    """What a step cut to its keys has to keep: alibi's distances, counted from
-    the block's own origin; a TRACED window (only the operand says where it
+def test_causal_schedule_under_alibi_and_windows(kind, block_k, form):
+    """What a step cut to its keys has to keep, in the one backward kernel and in
+    the pair: alibi's distances, counted from the block's own origin; a TRACED window (only the operand says where it
     lies; w <= 0 is global); a static window's backward, which runs the whole
     grid under the window; ``causal=False`` has no diagonal and runs its block
     whole. Forward and gradients at 2,048 rows, at the call's own blocks (512 x
@@ -412,11 +439,11 @@ def test_causal_schedule_under_alibi_and_windows(form, block_k):
     S, H = 2048, 2
     q, k, v = _qkv(B=1, S=S, H=H, D=32, seed=11)
     tgt = jax.random.normal(jax.random.PRNGKey(12), q.shape)
-    slopes = jnp.asarray([0.25, 0.0625], jnp.float32) if "alibi" in form else None
-    causal = not form.startswith("not-causal")
+    slopes = jnp.asarray([0.25, 0.0625], jnp.float32) if "alibi" in kind else None
+    causal = not kind.startswith("not-causal")
     window = {"traced-window": 300.0, "alibi+traced-window": 300.0, "traced-window-global": -1.0,
-              "static-window": 300, "not-causal-window": 300.0}.get(form)
-    traced = window is not None and form != "static-window"
+              "static-window": 300, "not-causal-window": 300.0}.get(kind)
+    traced = window is not None and kind != "static-window"
     dense_window = window if window and window > 0 else None
 
     def flash(q, k, v, w):
@@ -439,14 +466,22 @@ def test_causal_schedule_under_alibi_and_windows(form, block_k):
 
 
 @pytest.mark.parametrize("rows", [1024, 2048])
-def test_causal_schedule_with_narrower_value_heads(rows):
+def test_causal_schedule_with_narrower_value_heads(rows, form):
     """Latent attention's shape: value heads narrower than the q/k heads, forward
-    only (the backward kernels take one head size)."""
-    ks = jax.random.split(jax.random.PRNGKey(rows), 3)
+    and (since PR 64: dV and dO have the value heads' width, dQ and dK the q/k
+    heads') the gradients of both backward forms."""
+    ks = jax.random.split(jax.random.PRNGKey(rows), 4)
     q, k = (jax.random.normal(kk, (1, rows, 2, 48)) * 0.5 for kk in ks[:2])
     v = jax.random.normal(ks[2], (1, rows, 2, 32)) * 0.5
+    tgt = jax.random.normal(ks[3], v.shape)
     np.testing.assert_allclose(np.asarray(flash_attention(q, k, v)), np.asarray(_dense(q, k, v)),
                                rtol=2e-5, atol=2e-5)
+    gf = jax.grad(lambda *a: jnp.sum(flash_attention(*a) * tgt), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(_dense(*a) * tgt), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gr, "qkv"):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5,
+                                   err_msg=f"d{name}")
 
 
 @pytest.fixture
@@ -505,17 +540,19 @@ def test_key_block_is_as_coarse_as_its_bytes_allow(width, itemsize, block_k):
 
 
 @pytest.mark.parametrize("window", [None, 700.0], ids=["no-window", "traced-window"])
-def test_backward_kernels_run_the_forward_s_tiles(window, counted_tiles):
-    """dK/dV and dQ cut their steps by the same rule: at 2,048 rows (one key
-    block) each runs the forward's 10 sub-tiles' worth of scores (12 before
+def test_backward_kernels_run_the_forward_s_tiles(window, counted_tiles, form):
+    """The backward cuts its steps by the same rule: at 2,048 rows (one key
+    block) a kernel runs the forward's 10 sub-tiles' worth of scores (12 before
     PR 51) in four steps of one to four sub-tiles; a traced window changes no
-    step."""
+    step. The one kernel passes over the scores ONCE where dK/dV and dQ each
+    do (PR 64: the forward's tiles twice in all, not three times)."""
     q, k, v = _qkv(B=1, S=2048, H=1, D=8, seed=2)
     w = None if window is None else jnp.float32(window)
     jax.block_until_ready(jax.grad(lambda q, k, v: jnp.sum(flash_attention(q, k, v, window=w)),
                                    argnums=(0, 1, 2))(q, k, v))
     jax.effects_barrier()
-    assert sorted(counted_tiles) == sorted(3 * [(512, 512), (512, 1024), (512, 1536), (512, 2048)])
+    passes = {"fused": 2, "split": 3}[form]
+    assert sorted(counted_tiles) == sorted(passes * [(512, 512), (512, 1024), (512, 1536), (512, 2048)])
 
 
 @pytest.mark.parametrize("blocks,cases", [
@@ -579,6 +616,37 @@ def test_a_step_that_computes_nothing_fetches_nothing(blocks, rows):
     assert fa._streamed_rows(False, bq, bk, num_q)(0, num_k - 1, 0) == (0, 0, 0)
 
 
+@pytest.mark.parametrize("rows,widths,itemsize,blocks,want", [
+    (2048, (128, 128), 2, (None, None), "fused"),   # the train cell's heads: 12.5 of the 16 MiB
+    (2048, (192, 128), 2, (None, None), "fused"),   # latent attention's
+    (2048, (64, 64), 2, (None, None), "fused"),     # narrow heads are padded to the lanes
+    (1536, (128, 128), 2, (None, None), "fused"),   # three key blocks of 512
+    (2000, (128, 128), 2, (None, None), "fused"),   # padded to 2,048
+    (4096, (128, 128), 2, (None, None), "fused"),   # the longest rows at 128-wide bfloat16 heads
+    (4096, (128, 128), 2, (1024, 1024), "fused"),
+    (4096, (192, 128), 2, (None, None), "split"),
+    (6144, (128, 128), 2, (None, None), "split"),   # dQ alone: 3 MiB of float32 and 3 written out
+    (8192, (128, 128), 2, (None, None), "split"),
+    (8192, (128, 128), 2, (128, 128), "fused"),     # a caller's small blocks leave dQ the room
+    (2048, (128, 128), 4, (None, None), "split"),   # float32: the blocks are twice the bytes
+    (2048, (256, 256), 2, (None, None), "split"),
+    (1024, (256, 256), 4, (None, None), "split"),
+])
+def test_backward_form_is_read_from_the_shapes(rows, widths, itemsize, blocks, want):
+    """``backward_form``: the one kernel where a head's dQ fits VMEM beside a
+    step's blocks, the pair where it does not, from rows, widths, itemsize and
+    blocks alone (tests/test_chip_compile_kernels.py compiles both sides of the
+    line for the chip); and the backward a call TRACES is the form it names."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    d, dv = widths
+    assert fa.backward_form(rows, d, dv, itemsize, *blocks) == want
+    dtype = {2: jnp.bfloat16, 4: jnp.float32}[itemsize]
+    qk, v = jax.ShapeDtypeStruct((1, rows, 1, d), dtype), jax.ShapeDtypeStruct((1, rows, 1, dv), dtype)
+    loss = lambda q, k, v: jnp.sum(flash_attention(q, k, v, block_q=blocks[0], block_k=blocks[1]))
+    assert _backward_kernels(loss, qk, qk, v) == BACKWARD_KERNELS[want]
+
+
 @pytest.mark.parametrize("attn,blocks,pct", [("flash", (0, 0), 199.22), ("flash", (128, 128), 149.42),
                                              ("xla", (0, 0), None)])
 def test_train_step_ledger_row_says_what_the_causal_kernels_compute(attn, blocks, pct):
@@ -601,5 +669,6 @@ def test_train_step_ledger_row_says_what_the_causal_kernels_compute(attn, blocks
     row = next(r for r in engine.telemetry_snapshot()["program_ledger"]
                if r["name"].startswith("train/train_step"))
     assert row.get("causal_tiles_pct") == pct
+    assert row.get("flash_bwd_form") == ("fused" if pct else None)  # PR 64: which backward it traced
     if pct:
         assert pct == round(fa.causal_tiles_pct(256, 16, 4, blocks[0] or None, blocks[1] or None), 2)

@@ -100,7 +100,7 @@ _REFUSED = {
     "alibi": (dict(pos_emb="alibi"), "pos_emb='alibi'"),
     "qk_norm": (dict(qk_norm=True), "qk_norm"),
     "decode kernel": (dict(decode_attn="kernel"), "decode_attn='kernel'"),
-    "flash training": (dict(attn_impl="flash"), "attn_impl='flash'"),
+    "ring training": (dict(attn_impl="ring"), "attn_impl='ring'"),
     "biases": (dict(use_bias=True), "use_bias"),
     "int8 weights": (dict(weight_bits=8), "weight_bits"),
     "head sizes without a latent": (dict(kv_lora_rank=0), "without latent attention"),
@@ -124,12 +124,40 @@ def test_disaggregated_roles_refuse_the_latent_cache(program):
                               "serving": {"n_slots": 2, "max_seq_len": 128, "role": "prefill"}})
 
 
-def test_flash_backward_refuses_unequal_head_sizes():
+def test_flash_backward_takes_value_heads_of_their_own_width():
+    """Since PR 64 (it refused them by name before): dV and dO are as wide as the
+    value heads, dQ and dK as the q/k heads."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
-    q = jnp.ones((1, 128, 2, 48))
-    with pytest.raises(NotImplementedError, match="one head size"):
-        jax.grad(lambda v: jnp.sum(flash_attention(q, q, v)))(jnp.ones((1, 128, 2, 32)))
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q, k = (jax.random.normal(kk, (1, 128, 2, 48)) * 0.5 for kk in ks[:2])
+    v = jax.random.normal(ks[2], (1, 128, 2, 32)) * 0.5
+    loss = lambda fn: lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+    got = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: tfm.xla_attention(q, k, v, causal=True)),
+                    argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5)
+
+
+def test_training_through_the_flash_kernels_matches_the_xla_form(cfg, params):
+    """``attn_impl="flash"`` with latent attention (refused until PR 64: the backward
+    kernels took one head size): the expanded heads, 48-wide q/k beside 32-wide v
+    here, go through the forward kernel and the one backward kernel, and the loss
+    and every leaf's gradient are the XLA form's."""
+    tokens = _tokens(cfg, (2, 129), seed=4)
+
+    def loss_and_grads(c):
+        model = tfm.Model(c)
+        return jax.value_and_grad(lambda p: model.loss(p, {"tokens": tokens}))(params)
+
+    want, g_want = loss_and_grads(cfg)
+    got, g_got = loss_and_grads(cfg.replace(attn_impl="flash"))
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    worst = jax.tree.map(lambda a, b: float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-12)),
+                         g_got, g_want)
+    assert max(jax.tree.leaves(worst)) < 1e-4, worst
 
 
 def test_apply_matches_the_reference_and_returns_its_choices(cfg, params, program, reference):
