@@ -3464,12 +3464,13 @@ def causal_tiles_fact(cfg: TransformerConfig, rows: int, block_q=None, block_k=N
     over ``rows`` rows of the model's heads as a % of those at or under the
     diagonal (100: the triangle alone), a constant of the trace from the shapes:
     ``flash_attention.causal_tiles_pct``, the rule the kernel's steps read their
-    own schedule by."""
-    from ..ops.pallas.flash_attention import causal_tiles_pct
+    own schedule by; and ``diag_sub`` (PR 65): the edge of the sub-tiles the
+    forward cut the diagonal's tile in, 0 where it ran whole."""
+    from ..ops.pallas.flash_attention import causal_tiles_pct, diag_sub
 
-    return {"causal_tiles_pct": round(causal_tiles_pct(
-        rows, max(cfg.head_dim, cfg.value_head_dim), jnp.dtype(cfg.dtype).itemsize,
-        block_q, block_k), 2)}
+    shape = rows, max(cfg.head_dim, cfg.value_head_dim), jnp.dtype(cfg.dtype).itemsize
+    return {"causal_tiles_pct": round(causal_tiles_pct(*shape, block_q, block_k), 2),
+            "diag_sub": diag_sub(*shape, block_q, block_k)}
 
 
 def causal_grid_form(cfg: TransformerConfig, bucket: int) -> dict:
@@ -4080,22 +4081,27 @@ class Model:
 
     def flash_schedule(self, micro_batch) -> dict:
         """For the train step's program-ledger row: ``causal_tiles_fact`` at the
-        sequence length of this micro-batch and the configured outer blocks (the
-        backward cuts its steps by the forward's rule), and ``flash_bwd_form``:
-        whether that backward is the one kernel or the pair
-        (``flash_attention.backward_form``: ``fused`` / ``split``). Nothing
-        where training does not attend through those kernels."""
+        sequence length of this micro-batch and the configured outer blocks
+        (``diag_sub`` beside it: the edge the forward cut the diagonal's tile in,
+        0: whole), ``flash_bwd_form``: whether that backward is the one kernel or
+        the pair (``flash_attention.backward_form``: ``fused`` / ``split``), and
+        ``flash_bwd_diag_sub``: the edge the backward cut the diagonal's tile in
+        (it cuts under 2,048 rows too, where the forward does not:
+        ``flash_attention._diag_cut``). Nothing where training does not attend
+        through those kernels."""
         c = self.config
         if c.attn_impl != "flash" or not c.causal or not (
                 isinstance(micro_batch, dict) and {"tokens", "input_ids"} & set(micro_batch)):
             return {}
-        from ..ops.pallas.flash_attention import backward_form
+        from ..ops.pallas.flash_attention import backward_form, diag_sub
 
         length = jax.eval_shape(split_batch, micro_batch)[0].shape[1]
         blocks = c.flash_block_q or None, c.flash_block_k or None
+        item = jnp.dtype(c.dtype).itemsize
         return {**causal_tiles_fact(c, length, *blocks),
-                "flash_bwd_form": backward_form(length, c.head_dim, c.value_head_dim,
-                                                jnp.dtype(c.dtype).itemsize, *blocks)}
+                "flash_bwd_form": backward_form(length, c.head_dim, c.value_head_dim, item, *blocks),
+                "flash_bwd_diag_sub": diag_sub(length, max(c.head_dim, c.value_head_dim), item,
+                                               *blocks, backward=True)}
 
     def flops_per_token(self) -> float:
         """Approximate training FLOPs/token (fwd+bwd ≈ 6 * n_params matmul

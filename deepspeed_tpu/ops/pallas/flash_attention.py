@@ -9,7 +9,9 @@ online-softmax ("flash") attention pair of kernels:
   * forward never materializes the [S, S] score matrix: per q-block it
     streams k/v blocks, keeping a running row-max / row-sum (online softmax)
     and a [Bq, D] accumulator in VMEM; saves the per-row logsumexp for the
-    backward pass.
+    backward pass as ONE float32 a row ([BH, 1, Sq], laid along the lanes:
+    PR 65; a lane-broadcast [BH, Sq, 128] array before, 134 MB a layer at the
+    train cell's shape).
   * backward recomputes P = exp(QK^T·scale − L) blockwise (FlashAttention-2
     decomposition) in ONE kernel where a head's dQ fits VMEM (``flash_bwd``,
     PR 64: grid (head, key block, query block); a tile's P and dS are computed
@@ -17,8 +19,14 @@ online-softmax ("flash") attention pair of kernels:
     the whole head held in float32 scratch across its key blocks: 5 matmuls and
     one exponential a tile), else in two (``backward_form``: one kernel
     accumulates dK/dV over q-blocks, one dQ over k-blocks, each recomputing
-    the scores: 7 matmuls and two exponentials); the softmax Jacobian term
-    uses D_i = rowsum(dO ∘ O) computed in plain XLA.
+    the scores: 7 matmuls and two exponentials). The backward's tile is
+    TRANSPOSED (S^T = K Q^T, keys down the sublanes, rows along the lanes: PR 65),
+    so a row's logsumexp is read as it was written, dV and dK are products as
+    they lie and only dQ contracts over a transposed operand; the softmax
+    Jacobian term D_i = rowsum(dO ∘ O) is made inside the step from the dO block
+    it holds and the O block streamed beside it (``_delta_row``: XLA's own pass
+    over dO and O, handing the kernel a row, cost the train cell's 1,195 ms step
+    4.3 ms more; a value head narrower than the lanes keeps that pass).
 
 VMEM residency is O(block) not O(sequence): the streamed operand rides the
 *innermost grid dimension* (its BlockSpec indexes that dim), so Pallas
@@ -55,7 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret_default
 
-LANES = 128  # TPU lane width; LSE/delta are stored lane-broadcast
+LANES = 128  # TPU lane width; a step's running max / sum are held lane-broadcast in VMEM
 NEG_INF = -1e30
 
 # Block-size policy. A grid step costs about a microsecond whatever it computes
@@ -84,10 +92,15 @@ KEY_BLOCK_BYTES = 2 ** 20
 # grid, which the second 16 MiB absorb (a v5e core has 128 MiB of VMEM).
 FUSED_VMEM_BYTES = 16 * 2 ** 20
 FUSED_VMEM_LIMIT = 32 * 2 ** 20
-# The width of the key sub-tiles a causal step's block is cut in: a step runs
-# only the sub-tiles that hold a key at or under its last row (``_keys_seen``,
-# ``_step_cases``).
+# The width of the key sub-tiles a causal step's block is cut in where its rows
+# are not cut too (``diag_sub`` 0): a step runs only the sub-tiles that hold a
+# key at or under its last row (``_keys_seen``, ``_step_pieces``).
 SUB_K = 512
+# The edge of the sub-tiles the DIAGONAL's tile is cut in (PR 65: two MXU tiles;
+# ``diag_sub``): a step's rows go in pieces of this many, each against the keys
+# at or under ITS last row, so of a 512 x 512 tile on the diagonal the quarter
+# above it is neither multiplied nor exponentiated.
+DIAG_SUB = 256
 
 
 def _auto_block(s: int, cap: int) -> int:
@@ -255,36 +268,89 @@ def _keys_seen(r0, rows, k0, sub, n):
     return _clip((r0 + rows - k0 + sub - 1) // sub, n)
 
 
-def _step_cases(block_q: int, block_k: int, causal: bool):
-    """What a step of a ``block_q`` x ``block_k`` grid can be asked to compute,
-    each case a piece of straight-line code under its own ``pl.when`` on static
-    slices of the key block (a loop of a traced trip count over the sub-tiles
-    lost more between them than the diagonal saves: PERF.md section 6, PR 51):
-    -> [(sub-tiles seen | None, keys computed)], the keys from the block's
-    first on, all under the causal mask (the compare and select cost nothing
-    that a chip run could find; sparing the sub-tiles under the diagonal them
-    took a second product and cost more). Read from the shapes alone: block origins differ by multiples of gcd(block_q, block_k),
-    and a grid of these blocks meets no other case. Without a diagonal a step
-    is its whole block."""
+def _diag_cut(block_q: int, block_k: int, backward: bool = False) -> int:
+    """The edge of the sub-tiles a causal step of ``block_q`` rows against
+    ``block_k`` keys cuts the diagonal's tile in, 0 where the tile runs whole.
+    ``DIAG_SUB`` where the query block holds two or more of them (the halves of a
+    256-row block would fall to the MXU's own 128 rows, where a product's fixed
+    cost is what the cut saves). The backward, which is bound by its products,
+    cuts wherever it can (3 - 8% of the kernel at 1,024 to 4,096 rows). The
+    FORWARD is bound by its stores: it gains 0.6 - 4% of the kernel where the key
+    block holds four query blocks or more (2,048 rows and over) and LOST 3 - 4%
+    under that (1,024 rows: a key block of two; 1,536: of one), so it cuts only
+    there. The head's width does not enter: at 192 / 128 and at 256-wide heads
+    the cut compiles inside the same VMEM and saves as large a share of the
+    schedule (PERF.md section 6, PR 65)."""
+    if block_q < 2 * DIAG_SUB or block_q % DIAG_SUB or not (backward or block_k >= 4 * block_q):
+        return 0
+    return DIAG_SUB
+
+
+def _step_pieces(ahead: int, block_q: int, block_k: int, cut: int) -> tuple:
+    """What a causal step computes whose first row lies ``ahead`` keys past its
+    key block's first key: ((row0, key0, key1), ...), the query block's rows from
+    ``row0`` on against the key block's keys ``key0 .. key1 - 1``, every piece
+    under the causal mask (the compare and select cost nothing that a chip run
+    could find; sparing the keys under the diagonal them took a product of their
+    own and cost more: PERF.md section 6, PR 51). Nothing: the block lies above
+    the diagonal. With the rows whole (``cut`` 0) one piece, the key sub-tiles
+    ``_sub_tile`` wide that hold a key at or under the LAST row. Cut in row
+    pieces of ``cut`` (``_diag_cut``), the first piece's keys for every row, and
+    for the rows from each later piece on the ``cut`` keys more that this piece
+    sees: the stairs under the diagonal, so the sub-tiles above it are not
+    computed and the keys under it stay ONE product with all the block's rows."""
+    if not cut:
+        sub = _sub_tile(block_k)
+        keys = _keys_seen(ahead, block_q, 0, sub, block_k // sub) * sub
+        return ((0, 0, keys),) if keys else ()
+    stairs = ((row0, _clip(ahead + row0, block_k) if row0 else 0,
+               _clip(ahead + row0 + cut, block_k)) for row0 in range(0, block_q, cut))
+    return tuple(piece for piece in stairs if piece[2] > piece[1])
+
+
+def _step_cases(num_q: int, num_k: int, block_q: int, block_k: int, causal: bool, cut: int):
+    """What the steps of a ``num_q`` x ``num_k`` grid of ``block_q`` x ``block_k``
+    blocks can be asked to compute, each case a piece of straight-line code under
+    its own ``pl.when`` on static slices of the blocks (a loop of a traced trip
+    count over the sub-tiles lost more between them than the diagonal saves:
+    PERF.md section 6, PR 51): -> [(lo, hi, pieces)], the steps whose first row
+    lies ``lo .. hi`` keys past their key block's first run ``_step_pieces``'s
+    ``pieces`` (more keys the further under the diagonal, so a case is a range).
+    Only what this grid meets is listed (one key block over 2,048 rows never
+    sees a block wholly under the diagonal). Without a diagonal a step is its
+    whole block."""
     if not causal:
-        return [(None, block_k)]
-    sub = _sub_tile(block_k)
-    step = math.gcd(block_q, block_k)
-    seen = {_keys_seen(ahead, block_q, 0, sub, block_k // sub)  # ahead: r0 - k0
-            for ahead in range(-((block_q - 1) // step) * step, block_k + step, step)}
-    return [(n, n * sub) for n in sorted(seen - {0})]
+        return [(None, None, ((0, 0, block_k),))]
+    met = {}
+    for ahead in sorted({qi * block_q - kj * block_k for qi in range(num_q) for kj in range(num_k)}):
+        pieces = _step_pieces(ahead, block_q, block_k, cut)
+        if pieces:
+            met[pieces] = (met.get(pieces, (ahead,))[0], ahead)
+    return [(lo, hi, pieces) for pieces, (lo, hi) in met.items()]
 
 
-def _for_step_case(qi, kj, block_q, block_k, causal, body):
-    """Run ``body(keys)`` for the one case of ``_step_cases`` that query block
-    ``qi`` meets in key block ``kj`` (none: the block lies above the diagonal)."""
-    sub = _sub_tile(block_k)
-    seen = _keys_seen(qi * block_q, block_q, kj * block_k, sub, block_k // sub)
-    for case, keys in _step_cases(block_q, block_k, causal):
-        if case is None:
-            body(keys)
+def _for_step_case(qi, kj, block_q, block_k, cases, body):
+    """Run ``body(pieces)`` for the one case of ``cases`` (``_step_cases``) that
+    query block ``qi`` meets in key block ``kj`` (none: the block lies above the
+    diagonal)."""
+    ahead = qi * block_q - kj * block_k
+    for lo, hi, pieces in cases:
+        if lo is None:
+            body(pieces)
         else:
-            pl.when(seen == case)(functools.partial(body, keys))
+            met = ahead == lo if hi == lo else (ahead >= lo) & (ahead <= hi)
+            pl.when(met)(functools.partial(body, pieces))
+
+
+def diag_sub(rows: int, width: int, itemsize: int, block_q: int | None = None,
+             block_k: int | None = None, backward: bool = False) -> int:
+    """The edge of the sub-tiles the causal forward (``backward``: the backward)
+    cuts the diagonal's tile in over ``rows`` rows of self-attention at heads
+    ``width`` wide (the wider of q/k and v), 0 where it runs whole (``_diag_cut``
+    at the call's own blocks): what a span or a program's ledger row says of the
+    schedule beside ``causal_tiles_pct``."""
+    rows += (-rows) % 128
+    return _diag_cut(*_outer_blocks(rows, rows, width, itemsize, block_q, block_k), backward)
 
 
 @functools.lru_cache(maxsize=None)  # a serving worker asks on every prefill call
@@ -297,10 +363,11 @@ def causal_tiles_pct(rows: int, width: int, itemsize: int, block_q: int | None =
     else; the whole square would read 200)."""
     rows += (-rows) % 128
     block_q, block_k = _outer_blocks(rows, rows, width, itemsize, block_q, block_k)
-    sub = _sub_tile(block_k)
-    tiles = sum(_keys_seen(r0, block_q, k0, sub, block_k // sub)
-                for r0 in range(0, rows, block_q) for k0 in range(0, rows, block_k))
-    return 100.0 * tiles * block_q * sub / (rows * (rows + 1) / 2)
+    cut = _diag_cut(block_q, block_k)
+    tiles = sum((block_q - row0) * (key1 - key0)
+                for r0 in range(0, rows, block_q) for k0 in range(0, rows, block_k)
+                for row0, key0, key1 in _step_pieces(r0 - k0, block_q, block_k, cut))
+    return 100.0 * tiles / (rows * (rows + 1) / 2)
 
 
 def _vmem_spec(shape, index_map):
@@ -334,13 +401,23 @@ def _lanes(col, lanes=LANES):
     return jnp.broadcast_to(col[:, None], (col.shape[0], lanes))
 
 
+def _row(lane_tile):
+    """[rows, LANES] lane-broadcast tile -> [1, rows]: a row's statistic laid
+    along the lanes, as it leaves the forward and enters the backward (one
+    float32 a row; a lane-broadcast array in HBM is 128)."""
+    return lane_tile.T[0:1]
+
+
 # ---------------------------------------------------------------------------
 # In-kernel scores (shared by forward + both backward kernels)
 # ---------------------------------------------------------------------------
 
-def _block_scores(q, k_blk, r0, k0, *, sm_scale, causal, slope_ref, w_ref, mask_block=1):
+def _block_scores(q, k_blk, r0, k0, *, sm_scale, causal, slope_ref, w_ref, mask_block=1,
+                  transposed=False):
     """[rows, keys] fp32 scores of the query rows from ``r0`` on against the
-    keys from ``k0`` on, with alibi / local-window / causal fused.
+    keys from ``k0`` on, with alibi / local-window / causal fused;
+    ``transposed``: [keys, rows], K Q^T (the backward's tile: a row's logsumexp
+    and delta lie along the lanes there, ``_row``).
 
     ``slope_ref`` (or None): [1, 1, LANES] block of the per-program alibi slope
     (one lane-broadcast row per fused batch×head program, read as a vector:
@@ -354,22 +431,21 @@ def _block_scores(q, k_blk, r0, k0, *, sm_scale, causal, slope_ref, w_ref, mask_
     positions, a query seeing its own block whole (key j iff j // B <= i // B); ``r0``
     is a multiple of it (a tile starts at a multiple of 128), so a row's place in its
     block is its place in the tile's."""
-    rows, keys = q.shape[0], k_blk.shape[0]
+    lhs, rhs = (k_blk, q) if transposed else (q, k_blk)
     s = sm_scale * jax.lax.dot_general(
-        q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [rows, keys] fp32 accumulator
+        lhs, rhs, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )  # fp32 accumulator
+    along_rows, along_keys = (1, 0) if transposed else (0, 1)
     if causal or slope_ref is not None or w_ref is not None:
-        dist = (r0 - k0) + (jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
-                            - jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1))  # q_pos - k_pos
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, along_rows)
+        dist = (r0 - k0) + (row - jax.lax.broadcasted_iota(jnp.int32, s.shape, along_keys))  # q_pos - k_pos
     if slope_ref is not None:
-        s = s - _widen(slope_ref[0], keys) * dist.astype(jnp.float32)
+        s = s - _widen(slope_ref[0], s.shape[1]) * dist.astype(jnp.float32)
     if w_ref is not None:
         w = w_ref[0, 0]  # fp32 runtime window; w <= 0 means global
         s = jnp.where((w <= 0) | (dist.astype(jnp.float32) < w), s, NEG_INF)
     if causal and mask_block > 1:  # the keys behind a query that its own block still holds
-        behind = (mask_block - 1) - (jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
-                                     & (mask_block - 1))
-        s = jnp.where(dist + behind >= 0, s, NEG_INF)
+        s = jnp.where(dist + (mask_block - 1) - (row & (mask_block - 1)) >= 0, s, NEG_INF)
     elif causal:
         s = jnp.where(dist >= 0, s, NEG_INF)
     return s
@@ -401,9 +477,13 @@ def _wrap_extras(base, n_in, has_slopes, has_window):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, sm_scale, causal, num_k, slope_ref=None, w_ref=None, mask_block=1,
+    q_ref, k_ref, v_ref, o_ref, *rest,
+    sm_scale, causal, num_k, cases, with_lse, slope_ref=None, w_ref=None, mask_block=1,
 ):
+    """``rest``: the row of logsumexps where the call writes one (``with_lse``),
+    then the scratch: the running max, the running sum, the accumulator."""
+    lse_ref = rest[0] if with_lse else None
+    m_scr, l_scr, acc_scr = rest[-3:]
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     block_q = q_ref.shape[1]
@@ -415,34 +495,40 @@ def _fwd_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _compute(keys):
-        q = q_ref[0]            # [Bq, D] native dtype — MXU runs at full rate in bf16
-        k_blk = k_ref[0, :keys, :]  # the block's keys at or under the last row
-        v_blk = v_ref[0, :keys, :]
-        s = _block_scores(q, k_blk, qi * block_q, kj * block_k, sm_scale=sm_scale,
-                          causal=causal, slope_ref=slope_ref, w_ref=w_ref,
-                          mask_block=mask_block)
+    def _compute(pieces):
+        # every piece's scores first: a row's max runs over all the keys it sees
+        scores = [_block_scores(
+            q_ref[0, row0:, :],      # native dtype: the MXU runs at full rate in bf16
+            k_ref[0, key0:key1, :], qi * block_q + row0, kj * block_k + key0, sm_scale=sm_scale,
+            causal=causal, slope_ref=slope_ref, w_ref=w_ref, mask_block=mask_block)
+            for row0, key0, key1 in pieces]
         m_prev = m_scr[...]                     # [Bq, LANES] lane-broadcast
-        m_new = jnp.maximum(m_prev, _lanes(jnp.max(s, axis=1)))
-        p = jnp.exp(s - _widen(m_new, keys))
+        for (row0, _, _), s in zip(pieces, scores):
+            m_scr[row0:, :] = jnp.maximum(m_scr[row0:, :], _lanes(jnp.max(s, axis=1)))
+        m_new = m_scr[...]
         alpha = jnp.exp(m_prev - m_new)         # [Bq, LANES]
-        m_scr[...] = m_new
-        l_scr[...] = l_scr[...] * alpha + _lanes(jnp.sum(p, axis=1))
-        acc_scr[...] = acc_scr[...] * alpha[:, 0:1] + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        l_scr[...] = l_scr[...] * alpha
+        acc_scr[...] = acc_scr[...] * alpha[:, 0:1]
+        for (row0, key0, key1), s in zip(pieces, scores):
+            v_blk = v_ref[0, key0:key1, :]
+            p = jnp.exp(s - _widen(m_new[row0:], key1 - key0))
+            l_scr[row0:, :] += _lanes(jnp.sum(p, axis=1))
+            acc_scr[row0:, :] += jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
 
     # a block strictly above the diagonal meets no case (under ``mask_block`` too: the
-    # sub-tiles a step computes end at multiples of 128, where a block of the mask ends)
-    _for_step_case(qi, kj, block_q, block_k, causal, _compute)
+    # pieces a step computes end at multiples of 128, where a block of the mask ends)
+    _for_step_case(qi, kj, block_q, block_k, cases, _compute)
 
     @pl.when(kj == num_k - 1)
     def _finalize():
         l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_scr[...] / l_safe[:, 0:1]).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[...] + jnp.log(l_safe)
+        if with_lse:
+            lse_ref[0] = _row(m_scr[...] + jnp.log(l_safe))
 
 
 def _band_kernel(q_ref, *refs, sm_scale, band, views, has_slopes, with_lse, unroll):
@@ -576,11 +662,16 @@ def _streamed_rows(causal, block_q, block_k, num_q):
 
 def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
                    block_k, interpret, band=0, with_lse=True, mask_block=1):
-    """-> (out, lse). ``band`` (``static_window``): ``_band_forward``, which
-    alone heeds ``with_lse``; ``w_arr`` is then the backward's alone."""
+    """-> (out, lse [BH, 1, Sq] float32: a row's logsumexp along the lanes, or
+    None without ``with_lse``: nothing will differentiate the call, a serving
+    prefill, and the row, with the transpose that lays it, is not made).
+    ``band`` (``static_window``): ``_band_forward`` (which writes the logsumexp
+    lane-broadcast: its first lane is taken); ``w_arr`` is then the backward's
+    alone."""
     if band:
-        return _band_forward(q, k, v, slopes_bh, sm_scale, block_q, block_k, band, interpret,
-                             with_lse)
+        out, lse = _band_forward(q, k, v, slopes_bh, sm_scale, block_q, block_k, band, interpret,
+                                 with_lse)
+        return out, lse if lse is None else lse[:, None, :, 0]
     BH, Sq, D = q.shape
     Dv = v.shape[2]  # a value head may be narrower than a q/k head (latent attention)
     Sk = k.shape[1]
@@ -588,6 +679,9 @@ def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
     grid = (BH, Sq // block_q, num_k)
     base = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, num_k=num_k, mask_block=mask_block,
+        with_lse=with_lse,
+        cases=_step_cases(Sq // block_q, num_k, block_q, block_k, causal,
+                          _diag_cut(block_q, block_k)),
     )
     kv_block = _streamed_keys(causal, block_q, block_k)
     in_specs = [
@@ -603,18 +697,17 @@ def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
         in_specs.append(_vmem_spec((1, LANES), lambda bh, qi, kj: (0, 0)))
         operands.append(w_arr)
     kernel = _wrap_extras(base, 3, slopes_bh is not None, w_arr is not None)
-    out, lse = pl.pallas_call(
+    out_specs = [_vmem_spec((1, block_q, Dv), lambda bh, qi, kj: (bh, qi, 0))]
+    out_shape = [jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype)]
+    if with_lse:  # one float32 a row (``_row``)
+        out_specs.append(_vmem_spec((1, 1, block_q), lambda bh, qi, kj: (bh, 0, qi)))
+        out_shape.append(jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32))
+    out, *lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=[
-            _vmem_spec((1, block_q, Dv), lambda bh, qi, kj: (bh, qi, 0)),
-            _vmem_spec((1, block_q, LANES), lambda bh, qi, kj: (bh, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype),
-            jax.ShapeDtypeStruct((BH, Sq, LANES), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             _scratch((block_q, LANES)),   # running row-max m
             _scratch((block_q, LANES)),   # running row-sum l
@@ -624,7 +717,7 @@ def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
         interpret=interpret,
         name="flash_fwd",
     )(*operands)
-    return out, lse
+    return out, lse[0] if with_lse else None
 
 
 # ---------------------------------------------------------------------------
@@ -632,38 +725,62 @@ def _flash_forward(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
 # ---------------------------------------------------------------------------
 
 
-def _tile_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj, keys, *, sm_scale, causal,
+def _delta_row(do_ref, o_ref):
+    """The softmax Jacobian's D_i = rowsum(dO ∘ O) of the step's query block as
+    float32 rows along the lanes (``_row``): -> ``from_row``, the [1, rows -
+    row0] of it from a row on. ``o_ref`` is the O block streamed beside the dO
+    block the step holds anyway, and the row is made here, no array of it in
+    HBM; or, where ``_flash_backward`` made the row itself (a head narrower than
+    the lanes), the [1, 1, rows] block of it, read where a piece starts."""
+    if o_ref.shape[1] == 1:
+        return lambda row0: o_ref[0, :, row0:]
+    prod = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+    row = _row(_lanes(jnp.sum(prod, axis=1)))
+    return lambda row0: row[:, row0:]
+
+
+def _tile_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta, qi, kj, piece, *, sm_scale, causal,
                 slope_ref, w_ref):
-    """One tile of the backward, for the step's query block against the first
-    ``keys`` keys of its key block: S = QK^T, P = exp(S - lse), dP = dO V^T,
-    dS = P * (dP - delta) -> (q, do, k, P and dS in the operands' dtype): what
-    dV += P^T dO, dK += dS^T Q and dQ += dS K are taken from."""
+    """One piece of a step of the backward (``_step_pieces``), the tile
+    TRANSPOSED, keys down the sublanes and rows along the lanes, where a row's
+    logsumexp and delta (``lse_ref`` [1, 1, Bq], ``delta``: ``_delta_row``'s) lie as they
+    are stored: S^T = K Q^T, P^T = exp(S^T - lse), dP^T = V dO^T, dS^T = P^T *
+    (dP^T - delta) -> (q, do, k, P^T and dS^T in the operands' dtype): what
+    dV += P^T dO, dK += dS^T Q (both as they lie) and dQ += dS K are taken from."""
+    row0, key0, key1 = piece
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
-    k_blk = k_ref[0, :keys, :]  # the block's keys at or under the last row
-    q_blk = q_ref[0]            # [Bq, D]
-    do_blk = do_ref[0]          # [Bq, Dv]
-    s = _block_scores(q_blk, k_blk, qi * block_q, kj * block_k, sm_scale=sm_scale,
-                      causal=causal, slope_ref=slope_ref, w_ref=w_ref)
-    p = jnp.exp(s - _widen(lse_ref[0], keys))  # [Bq, keys]
-    dp = jax.lax.dot_general(
-        do_blk, v_ref[0, :keys, :], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    k_blk = k_ref[0, key0:key1, :]
+    q_blk = q_ref[0, row0:, :]    # [rows, D]
+    do_blk = do_ref[0, row0:, :]  # [rows, Dv]
+    st = _block_scores(q_blk, k_blk, qi * block_q + row0, kj * block_k + key0, sm_scale=sm_scale,
+                       causal=causal, slope_ref=slope_ref, w_ref=w_ref, transposed=True)
+    pt = jnp.exp(st - lse_ref[0, :, row0:])  # [keys, rows]
+    dpt = jax.lax.dot_general(
+        v_ref[0, key0:key1, :], do_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    ds = p * (dp - _widen(delta_ref[0], keys))
-    return q_blk, do_blk, k_blk, p.astype(do_blk.dtype), ds.astype(q_blk.dtype)
+    dst = pt * (dpt - delta(row0))
+    return q_blk, do_blk, k_blk, pt.astype(do_blk.dtype), dst.astype(q_blk.dtype)
 
 
-def _dkdv_add(dk_scr, dv_scr, keys, q_blk, do_blk, p, ds, sm_scale):
-    """dV += P^T dO and dK += dS^T Q . scale on the first ``keys`` keys of the
-    key block's float32 accumulators."""
-    dv_scr[:keys, :] += jax.lax.dot_general(
-        p, do_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    dk_scr[:keys, :] += sm_scale * jax.lax.dot_general(
-        ds, q_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+def _dkdv_add(dk_scr, dv_scr, piece, q_blk, do_blk, pt, dst, sm_scale):
+    """dV += P^T dO and dK += dS^T Q . scale on the piece's keys of the key
+    block's float32 accumulators."""
+    _, key0, key1 = piece
+    dv_scr[key0:key1, :] += jax.lax.dot_general(
+        pt, do_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    dk_scr[key0:key1, :] += sm_scale * jax.lax.dot_general(
+        dst, q_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _dq_of(dst, k_blk, sm_scale):
+    """dS K . scale of a piece, [rows, D] float32, from the transposed dS^T."""
+    return sm_scale * jax.lax.dot_general(
+        dst, k_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
 def _bwd_fused_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-    dq_scr, dk_scr, dv_scr, *, sm_scale, causal, num_q, num_k, slope_ref=None, w_ref=None,
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, dk_ref, dv_ref,
+    dq_scr, dk_scr, dv_scr, *, sm_scale, causal, num_q, num_k, cases, slope_ref=None, w_ref=None,
 ):
     """The whole backward in one pass over the scores: grid ``(bh, kj, qi)``, the
     query blocks the inner walk. A tile's P and dS are computed ONCE
@@ -684,18 +801,19 @@ def _bwd_fused_kernel(
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def _compute(keys):
-        q_blk, do_blk, k_blk, p, ds = _tile_grads(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj, keys, sm_scale=sm_scale,
-            causal=causal, slope_ref=slope_ref, w_ref=w_ref)
-        _dkdv_add(dk_scr, dv_scr, keys, q_blk, do_blk, p, ds, sm_scale)
-        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
-        dq_scr[rows, :] += sm_scale * jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    def _compute(pieces):
+        delta = _delta_row(do_ref, o_ref)
+        for piece in pieces:
+            q_blk, do_blk, k_blk, pt, dst = _tile_grads(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta, qi, kj, piece, sm_scale=sm_scale,
+                causal=causal, slope_ref=slope_ref, w_ref=w_ref)
+            _dkdv_add(dk_scr, dv_scr, piece, q_blk, do_blk, pt, dst, sm_scale)
+            rows = pl.ds(pl.multiple_of(qi * block_q + piece[0], LANES), block_q - piece[0])
+            dq_scr[rows, :] += _dq_of(dst, k_blk, sm_scale)
 
     # q-blocks entirely above the diagonal contribute nothing to this k-block,
-    # and the others only to its keys at or under their last row
-    _for_step_case(qi, kj, block_q, block_k, causal, _compute)
+    # and the others only to the keys at or under their rows
+    _for_step_case(qi, kj, block_q, block_k, cases, _compute)
 
     @pl.when(qi == num_q - 1)
     def _finalize_keys():
@@ -708,8 +826,8 @@ def _bwd_fused_kernel(
 
 
 def _bwd_dkdv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_scr, dv_scr, *, sm_scale, causal, num_q, slope_ref=None, w_ref=None,
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dk_ref, dv_ref,
+    dk_scr, dv_scr, *, sm_scale, causal, num_q, cases, slope_ref=None, w_ref=None,
 ):
     kj = pl.program_id(1)
     qi = pl.program_id(2)
@@ -719,13 +837,15 @@ def _bwd_dkdv_kernel(
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def _compute(keys):
-        q_blk, do_blk, _, p, ds = _tile_grads(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj, keys, sm_scale=sm_scale,
-            causal=causal, slope_ref=slope_ref, w_ref=w_ref)
-        _dkdv_add(dk_scr, dv_scr, keys, q_blk, do_blk, p, ds, sm_scale)
+    def _compute(pieces):
+        delta = _delta_row(do_ref, o_ref)
+        for piece in pieces:
+            q_blk, do_blk, _, pt, dst = _tile_grads(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta, qi, kj, piece, sm_scale=sm_scale,
+                causal=causal, slope_ref=slope_ref, w_ref=w_ref)
+            _dkdv_add(dk_scr, dv_scr, piece, q_blk, do_blk, pt, dst, sm_scale)
 
-    _for_step_case(qi, kj, q_ref.shape[1], k_ref.shape[1], causal, _compute)
+    _for_step_case(qi, kj, q_ref.shape[1], k_ref.shape[1], cases, _compute)
 
     @pl.when(qi == num_q - 1)
     def _finalize():
@@ -734,8 +854,8 @@ def _bwd_dkdv_kernel(
 
 
 def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
-    *, sm_scale, causal, num_k, slope_ref=None, w_ref=None,
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, dq_scr,
+    *, sm_scale, causal, num_k, cases, slope_ref=None, w_ref=None,
 ):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -744,14 +864,15 @@ def _bwd_dq_kernel(
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _compute(keys):
-        _, _, k_blk, _, ds = _tile_grads(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj, keys, sm_scale=sm_scale,
-            causal=causal, slope_ref=slope_ref, w_ref=w_ref)
-        dq_scr[...] += sm_scale * jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    def _compute(pieces):
+        delta = _delta_row(do_ref, o_ref)
+        for piece in pieces:
+            _, _, k_blk, _, dst = _tile_grads(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta, qi, kj, piece, sm_scale=sm_scale,
+                causal=causal, slope_ref=slope_ref, w_ref=w_ref)
+            dq_scr[piece[0]:, :] += _dq_of(dst, k_blk, sm_scale)
 
-    _for_step_case(qi, kj, q_ref.shape[1], k_ref.shape[1], causal, _compute)
+    _for_step_case(qi, kj, q_ref.shape[1], k_ref.shape[1], cases, _compute)
 
     @pl.when(kj == num_k - 1)
     def _finalize():
@@ -760,14 +881,16 @@ def _bwd_dq_kernel(
 
 def _fused_step_bytes(rows, block_q, block_k, d, dv, itemsize):
     """VMEM a step of the fused backward holds, by the count of its own buffers:
-    the streamed blocks twice (q, dO and the float32 lse and delta rows of a
-    query block; k, v and the dK, dV it writes of a key block), the key block's
-    two float32 accumulators and the head's dQ (float32 scratch and the block
-    written out, twice), with 3 bytes a score element for the tile in flight (the
-    compiler's own temporaries came to 0.9 - 6 of them: ``FUSED_VMEM_LIMIT``
-    holds what is over); a head narrower than the 128 lanes is padded to them."""
+    the streamed blocks twice (q, dO, O and the float32 row of logsumexps, eight
+    sublanes tall in VMEM, of a query block; k, v and the dK, dV it writes of a
+    key block), the key block's two float32 accumulators and the head's dQ
+    (float32 scratch and the block written out, twice), with 3 bytes a score
+    element for the tile in flight (the compiler's own temporaries came to 0.9 -
+    6 of them: ``FUSED_VMEM_LIMIT`` holds what is over); a head narrower than
+    the 128 lanes is padded to them."""
     both = max(d, LANES) + max(dv, LANES)
-    streamed = itemsize * (block_q + 2 * block_k) * both + 2 * 4 * LANES * block_q
+    streamed = (itemsize * ((block_q + 2 * block_k) * both + block_q * max(dv, LANES))
+                + 4 * 8 * block_q)
     held = 4 * block_k * both + rows * max(d, LANES) * (4 + 2 * itemsize)
     return 2 * streamed + held + 3 * block_q * block_k
 
@@ -778,7 +901,7 @@ def backward_form(rows: int, d: int, dv: int, itemsize: int, block_q: int | None
     query rows (``keys`` keys: the rows', for self-attention) of heads ``d``
     wide (values ``dv``), from the shapes and the blocks alone (None: the
     call's own). One kernel (``flash_bwd``) where a head's dQ fits VMEM beside a
-    step's blocks (``_fused_step_bytes`` within ``FUSED_VMEM_BYTES``: up to 4,096
+    step's blocks (``_fused_step_bytes`` within ``FUSED_VMEM_BYTES``: up to 6,144
     rows of 128-wide bfloat16 heads at the call's own blocks, 2,048 of latent
     attention's 192 / 128), else the pair that recomputes the scores
     (``flash_bwd_dkdv`` + ``flash_bwd_dq``), whose steps do not grow with the
@@ -791,18 +914,18 @@ def backward_form(rows: int, d: int, dv: int, itemsize: int, block_q: int | None
 
 
 def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
-    q, k, v, slopes_bh, w_arr, out, lse = res
-    lse = jnp.broadcast_to(lse[..., None], lse.shape + (LANES,))  # re-tile lanes
+    q, k, v, slopes_bh, w_arr, out, lse = res  # lse [BH, 1, Sq]: the forward's row, as it was written
     BH, Sq, D = q.shape
     Sk, Dv = v.shape[1:]  # a value head may be narrower than a q/k head (latent attention)
     num_q = Sq // block_q
     num_k = Sk // block_k
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [BH,Sq]
-    delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
+    cases = _step_cases(num_q, num_k, block_q, block_k, causal,
+                        _diag_cut(block_q, block_k, backward=True))
 
     kv_block = _streamed_keys(causal, block_q, block_k)
     q_block = _streamed_rows(causal, block_q, block_k, num_q)
     key_block = lambda bh, kj, qi: (bh, kj, 0)
+    lse_block = lambda bh, kj, qi: (bh, 0, q_block(bh, kj, qi)[1])  # the row [BH, 1, Sq] of the same block
 
     has_slopes = slopes_bh is not None
     has_window = w_arr is not None
@@ -814,7 +937,19 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
     if has_window:
         extra_specs.append(_vmem_spec((1, LANES), lambda bh, a, b: (0, 0)))
         extra_ops.append(w_arr)
-    operands = (q, k, v, g, lse, delta, *extra_ops)
+    # O rides beside dO and a step takes delta = rowsum(dO ∘ O) from the two blocks
+    # (``_delta_row``) where its heads fill whole lane tiles: XLA's own pass over dO and
+    # O is 0.27 ms a call at [128, 2048, 128] where the kernel grows by 0.03, and with it
+    # the train cell's step read 1,199.4 ms for 1,195.2 (PERF.md section 6, PR 65). A
+    # narrower head's O is kept by XLA with the rows along the lanes (the saved
+    # ``flash_out`` stack of 64-wide heads in half the bytes): an operand of the kernel
+    # would pin it to padded tiles, so there XLA's pass makes the row, as it made the
+    # array before
+    inside = Dv % LANES == 0
+    if not inside:
+        out = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, None, :]
+    o_spec = lambda rows, row: _vmem_spec((1, block_q, Dv), rows) if inside else _vmem_spec((1, 1, block_q), row)
+    operands = (q, k, v, g, out, lse, *extra_ops)
     dslopes = jnp.zeros_like(slopes_bh) if has_slopes else None
     dw = jnp.zeros_like(w_arr) if has_window else None
 
@@ -824,8 +959,8 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
         _vmem_spec((1, block_k, D), key_block),
         _vmem_spec((1, block_k, Dv), key_block),
         _vmem_spec((1, block_q, Dv), q_block),
-        _vmem_spec((1, block_q, LANES), q_block),
-        _vmem_spec((1, block_q, LANES), q_block),
+        o_spec(q_block, lse_block),
+        _vmem_spec((1, 1, block_q), lse_block),
     ] + extra_specs
     dkdv_specs = [_vmem_spec((1, block_k, D), key_block), _vmem_spec((1, block_k, Dv), key_block)]
     dkdv_shapes = [jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
@@ -834,7 +969,7 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
 
     if backward_form(Sq, D, Dv, q.dtype.itemsize, block_q, block_k, Sk) == "fused":
         base = functools.partial(_bwd_fused_kernel, sm_scale=sm_scale, causal=causal,
-                                 num_q=num_q, num_k=num_k)
+                                 num_q=num_q, num_k=num_k, cases=cases)
         dq, dk, dv = pl.pallas_call(
             _wrap_extras(base, 6, has_slopes, has_window),
             grid=(BH, num_k, num_q),
@@ -854,7 +989,7 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
     # heads alone (256-wide bfloat16 heads at 2,048 keys: fits at 8 heads, 19.8 MiB at 128)
     cp = _compiler_params(3, vmem_limit_bytes=FUSED_VMEM_LIMIT)
     base_dkdv = functools.partial(
-        _bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal, num_q=num_q,
+        _bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal, num_q=num_q, cases=cases,
     )
     dk, dv = pl.pallas_call(
         _wrap_extras(base_dkdv, 6, has_slopes, has_window),
@@ -869,9 +1004,10 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
     )(*operands)
 
     base_dq = functools.partial(
-        _bwd_dq_kernel, sm_scale=sm_scale, causal=causal, num_k=num_k,
+        _bwd_dq_kernel, sm_scale=sm_scale, causal=causal, num_k=num_k, cases=cases,
     )
     query_block = lambda bh, qi, kj: (bh, qi, 0)
+    query_row = lambda bh, qi, kj: (bh, 0, qi)
     dq = pl.pallas_call(
         _wrap_extras(base_dq, 6, has_slopes, has_window),
         grid=(BH, num_q, num_k),
@@ -880,8 +1016,8 @@ def _flash_backward(res, g, sm_scale, causal, block_q, block_k, interpret):
             _vmem_spec((1, block_k, D), kv_block),
             _vmem_spec((1, block_k, Dv), kv_block),
             _vmem_spec((1, block_q, Dv), query_block),
-            _vmem_spec((1, block_q, LANES), query_block),
-            _vmem_spec((1, block_q, LANES), query_block),
+            o_spec(query_block, query_row),
+            _vmem_spec((1, 1, block_q), query_row),
         ] + extra_specs,
         out_specs=_vmem_spec((1, block_q, D), query_block),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
@@ -918,10 +1054,10 @@ def _flash_bhsd_fwd(q, k, v, slopes_bh, w_arr, sm_scale, causal, block_q,
     # Under jax.checkpoint, out/lse are the residuals the backward kernels
     # need; naming them lets a remat policy (models/transformer.py
     # _remat_policy 'flash' names) save them so the forward kernel is NOT
-    # re-run inside the backward pass. lse is saved de-broadcast ([BH,S], not
-    # the lane-tiled [BH,S,LANES]) so the saved residual is 128x smaller.
+    # re-run inside the backward pass. lse is the kernel's own [BH, 1, Sq] row,
+    # one float32 a query, saved and handed to the backward as it was written.
     out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse[:, :, 0], "flash_lse")
+    lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, slopes_bh, w_arr, out, lse)
 
 

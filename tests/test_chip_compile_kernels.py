@@ -122,24 +122,34 @@ def test_decode_kernel_compiles_at_the_cells_shapes(cell, stack, alibi, v5e, no_
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
-@pytest.mark.parametrize("bh,rows,widths,dtype,alibi,kernels,block_k", [
-    (128, 2048, (128, 128), jnp.bfloat16, False, FUSED, 2048),
-    (32, 8192, (192, 128), jnp.bfloat16, False, ("flash_fwd",), 2048),
-    (32, 2048, (192, 128), jnp.bfloat16, False, FUSED, 2048),
-    (16, 2048, (128, 128), jnp.bfloat16, True, ("flash_fwd",), 2048),
-    (16, 2048, (128, 128), jnp.bfloat16, True, FUSED, 2048),
-    (8, 4096, (128, 128), jnp.bfloat16, True, FUSED, 2048),
-    (8, 4096, (192, 128), jnp.bfloat16, False, SPLIT, 2048),
-    (8, 8192, (128, 128), jnp.bfloat16, False, SPLIT, 2048),
-    (8, 4096, (128, 128), jnp.float32, True, SPLIT, 2048),
-    (8, 4096, (256, 256), jnp.bfloat16, False, SPLIT, 2048),
-    (8, 4096, (256, 256), jnp.float32, False, SPLIT, 1024),
-    (8, 4096, (64, 64), jnp.bfloat16, False, FUSED, 2048),
+@pytest.mark.parametrize("bh,rows,widths,dtype,alibi,kernels,block_k,mask_block", [
+    (128, 2048, (128, 128), jnp.bfloat16, False, FUSED, 2048, 1),
+    (32, 8192, (192, 128), jnp.bfloat16, False, ("flash_fwd",), 2048, 1),
+    (32, 2048, (192, 128), jnp.bfloat16, False, FUSED, 2048, 1),
+    (16, 2048, (128, 128), jnp.bfloat16, True, ("flash_fwd",), 2048, 1),
+    (16, 2048, (128, 128), jnp.bfloat16, True, FUSED, 2048, 1),
+    (8, 4096, (128, 128), jnp.bfloat16, True, FUSED, 2048, 1),
+    (8, 4096, (192, 128), jnp.bfloat16, False, SPLIT, 2048, 1),
+    (8, 8192, (128, 128), jnp.bfloat16, False, SPLIT, 2048, 1),
+    (8, 4096, (128, 128), jnp.float32, True, SPLIT, 2048, 1),
+    (8, 4096, (256, 256), jnp.bfloat16, False, SPLIT, 2048, 1),
+    (8, 4096, (256, 256), jnp.float32, False, SPLIT, 1024, 1),
+    (8, 4096, (64, 64), jnp.bfloat16, False, FUSED, 2048, 1),
+    (16, 8192, (256, 256), jnp.bfloat16, False, ("flash_fwd",), 2048, 1),
+    (128, 2048, (256, 256), jnp.bfloat16, False, SPLIT, 2048, 1),
+    (32, 6144, (128, 128), jnp.bfloat16, False, FUSED, 2048, 1),
+    (32, 2048, (128, 128), jnp.bfloat16, False, ("flash_fwd",), 2048, 4),
+    (32, 1024, (128, 128), jnp.bfloat16, False, ("flash_fwd",), 1024, 4),
+    (32, 1024, (64, 64), jnp.bfloat16, False, ("flash_fwd",), 1024, 1),
+    (128, 1536, (128, 128), jnp.bfloat16, False, FUSED, 512, 1),
 ], ids=["train-128x2048x128", "latent-32x8192x192-128", "latent-train-32x2048x192-128",
         "alibi-16x2048x128", "alibi-fwd-bwd", "bf16-128-4096-alibi", "latent-4096-split",
-        "bf16-128-8192-split", "f32-128-alibi", "bf16-256", "f32-256-halved", "bf16-64"])
-def test_causal_schedule_compiles_for_v5e(bh, rows, widths, dtype, alibi, kernels, block_k, v5e,
-                                          no_persistent_cache):
+        "bf16-128-8192-split", "f32-128-alibi", "bf16-256", "f32-256-halved", "bf16-64",
+        "qwen3-next-16x8192x256", "bf16-256-128-heads", "bf16-128-6144-fused",
+        "sdar-32x2048x128-blocks-of-4", "sdar-32x1024x128-blocks-of-4", "lfm2-32x1024x64",
+        "train-128x1536x128"])
+def test_causal_schedule_compiles_for_v5e(bh, rows, widths, dtype, alibi, kernels, block_k, mask_block,
+                                          v5e, no_persistent_cache):
     """The causal kernels with their work cut inside the step (PR 51: a case a
     count of key sub-tiles, each on a static slice of the key block, index maps
     that stay on the last block a row needs) at the cells' shapes: the train
@@ -154,7 +164,21 @@ def test_causal_schedule_compiles_for_v5e(bh, rows, widths, dtype, alibi, kernel
     ``backward_form`` says a head's dQ fits VMEM beside a step's blocks (the
     train cell's shape, latent attention's 192 / 128 heads at 2,048 rows, 4,096
     rows of 128-wide bfloat16 heads under alibi), and the pair on the other side
-    of the line, which the compiler takes at any length."""
+    of the line, which the compiler takes at any length. Since PR 65 the
+    backward cuts the diagonal's tile in 256 x 256 sub-tiles (``diag_sub``: a
+    case is two pieces, the keys the first 256 rows see against all 512 and the
+    256 more against the last 256), and so does the forward where the key block
+    holds four query blocks (2,048 rows and over); the backward's tile is
+    transposed, the logsumexp is a [BH, 1, rows] row, delta is made in the kernel,
+    and a forward that nothing differentiates writes no logsumexp: the train cell's shape,
+    kanana's 192 / 128 heads over 8,192 rows, qwen3-next's 256-wide heads over
+    8,192 rows (forward) and at 128 heads through the pair, 6,144 rows of
+    128-wide heads, which the one kernel's own count of its buffers now holds
+    (15.8 MiB; 16.5 with two lane-broadcast blocks where O's and a row are), and
+    on the forward's other side: SDAR's prefill under the mask between blocks of
+    4 positions at 2,048 rows (cut) and 1,024 (whole), LFM2's 64-wide heads at
+    1,024 rows, and 1,536 rows in 512 x 512 blocks, where the forward runs the
+    diagonal whole and the backward cuts it."""
     from deepspeed_tpu.ops.pallas import flash_attention as fa
 
     one_chip = SingleDeviceSharding(v5e[0])
@@ -164,11 +188,14 @@ def test_causal_schedule_compiles_for_v5e(bh, rows, widths, dtype, alibi, kernel
     slopes = jnp.full((bh, 1, fa.LANES), 0.25, jnp.float32) if alibi else None
     blocks = fa._auto_block(rows, fa.MAX_BLOCK_Q), fa._key_block(rows, d, jnp.dtype(dtype).itemsize)
     assert blocks == (512, block_k) and fa._sub_tile(blocks[1]) == fa.SUB_K
-    form = fa.backward_form(rows, d, dv, jnp.dtype(dtype).itemsize)
+    item = jnp.dtype(dtype).itemsize
+    assert fa.diag_sub(rows, max(d, dv), item, backward=True) == fa.DIAG_SUB == 256
+    assert fa.diag_sub(rows, max(d, dv), item) == (256 if block_k == 2048 else 0)
+    form = fa.backward_form(rows, d, dv, item)
     assert len(kernels) == 1 or kernels == {"fused": FUSED, "split": SPLIT}[form]
 
     def attend(q, k, v):
-        return fa._flash_bhsd(q, k, v, slopes, None, d ** -0.5, True, *blocks, False, 0)
+        return fa._flash_bhsd(q, k, v, slopes, None, d ** -0.5, True, *blocks, False, 0, mask_block)
 
     if len(kernels) == 1:
         fn = attend

@@ -185,7 +185,8 @@ def test_banded_forward_matches_the_whole_grid_and_a_dense_reference(rows, windo
     ref, ref_lse = _dense_window(q, k, v, window)
     for got, lse in ((out_b, lse_b), (out_w, lse_w)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
-        np.testing.assert_allclose(np.asarray(lse[:, :, 0]), np.asarray(ref_lse),
+        assert lse.shape == (2, 1, rows)  # one float32 a row, along the lanes (PR 65)
+        np.testing.assert_allclose(np.asarray(lse[:, 0]), np.asarray(ref_lse),
                                    rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(out_b), np.asarray(out_w), rtol=1e-6, atol=1e-6)
     np.testing.assert_allclose(np.asarray(lse_b), np.asarray(lse_w), rtol=1e-6, atol=1e-6)
@@ -359,7 +360,10 @@ def _dense(q, k, v, causal=True, slopes=None, window=None):
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v, precision="highest")
 
 
-# rows, (block_q, block_k) handed to the call (None: its own), and what the case is for
+# rows, (block_q, block_k) handed to the call (None: its own), and what the case is for. Since
+# PR 65 a query block of 512 rows or more cuts the diagonal's tile in 256 x 256 sub-tiles
+# (``diag_sub`` 256): in the backward always, in the forward where the key block holds four
+# query blocks; the 128- and 256-row blocks of the last cases run it whole (0)
 SCHEDULE_CASES = {
     "key-block-4x-query-block": (2048, (None, None)),    # 512 x 2048: the train cell's grid
     "key-block-2x-query-block": (2048, (512, 1024)),     # PR 50's blocks: skipped steps
@@ -422,6 +426,75 @@ def test_causal_schedule_gradients_match_the_dense_reference(case, form):
     for a, b, name in zip(gf, gr, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5,
                                    err_msg=f"d{name}")
+
+
+def _dense_lse(q, k):
+    """[B, S, H, D] -> [B * H, S]: each causal row's logsumexp in float32, what
+    the forward hands the backward (the first lane of the lane-broadcast array
+    it wrote before PR 65)."""
+    S = q.shape[1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32),
+                   precision="highest") / np.sqrt(q.shape[-1])
+    s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None], s, -jnp.inf)
+    return jax.scipy.special.logsumexp(s, axis=-1).reshape(-1, S)
+
+
+# rows, dtype, the backward form forced (None: the rule's own, named beside it), diag_sub of
+# the forward and of the backward
+CELL_CLASS_CASES = {
+    # the train cell's shape class: 2,048 rows of 128-wide heads in 512 x 2048 blocks
+    "train-cell-bfloat16": (2048, jnp.bfloat16, None, "fused", (256, 256)),
+    "train-cell-float32-one-kernel": (2048, jnp.float32, "fused", "fused", (256, 256)),
+    "past-the-limit-float32-the-pair": (2048, jnp.float32, None, "split", (256, 256)),
+    # 512 x 512 blocks: the store-bound forward runs the diagonal whole under a key block
+    # of four query blocks, the backward cuts it
+    "rows-no-power-of-two-divides": (1536, jnp.float32, None, "fused", (0, 256)),
+    "whole-diagonal": (1280, jnp.float32, None, "fused", (0, 0)),            # 256 x 256 blocks
+}
+
+
+@pytest.mark.parametrize("case", CELL_CLASS_CASES)
+def test_the_cut_diagonal_and_the_row_statistics_match_the_float32_reference(case, monkeypatch):
+    """PR 65 at the train cell's shape class and on both sides of each rule: the
+    diagonal's tile in 256 x 256 sub-tiles (``diag_sub`` 256) and whole (0, a
+    256-row query block; in the forward a key block under four query blocks too),
+    the one backward kernel and the pair past ``backward_form``'s limit, rows no
+    power-of-two block over 512 divides. The forward, the logsumexp it hands on
+    (ONE float32 a row, ``[BH, 1, Sq]``: what the first lane of the old
+    ``[BH, Sq, 128]`` array held; not written where nothing will read it, a
+    serving prefill) and all three gradients against the float32 dense reference."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    rows, dtype, forced, form, sub = CELL_CLASS_CASES[case]
+    if forced:
+        monkeypatch.setattr(fa, "FUSED_VMEM_BYTES", {"fused": 1 << 40, "split": 0}[forced])
+    q, k, v = _qkv(B=1, S=rows, H=1, D=128, dtype=dtype, seed=rows)
+    tgt = jax.random.normal(jax.random.PRNGKey(rows), q.shape, dtype)
+    wide = lambda *xs: tuple(x.astype(jnp.float32) for x in xs)
+    item = jnp.dtype(dtype).itemsize
+    assert (fa.diag_sub(rows, 128, item), fa.diag_sub(rows, 128, item, backward=True)) == sub
+    assert fa.backward_form(rows, 128, 128, item) == form
+    loss = lambda fn: lambda *a: jnp.sum((fn(*a) * tgt).astype(jnp.float32))
+    assert _backward_kernels(loss(flash_attention), q, k, v) == BACKWARD_KERNELS[form]
+    tol = dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 else dict(rtol=5e-4, atol=5e-5)
+
+    blocks = fa._outer_blocks(rows, rows, 128, item, None, None)
+    bhsd = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, rows, 128)
+    forward = lambda **kw: fa._flash_forward(bhsd(q), bhsd(k), bhsd(v), None, None, 128 ** -0.5, True,
+                                             *blocks, True, **kw)
+    out, lse = forward()
+    served, nothing = forward(with_lse=False)
+    assert nothing is None and np.array_equal(np.asarray(served), np.asarray(out))
+    assert lse.shape == (1, 1, rows) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse[:, 0]), np.asarray(_dense_lse(q, k)), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
+                               np.asarray(bhsd(_dense(*wide(q, k, v)))), **tol)
+    gf = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(_dense(*a) * tgt.astype(jnp.float32)),
+                  argnums=(0, 1, 2))(*wide(q, k, v))
+    for a, b, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a.astype(jnp.float32)), np.asarray(b),
+                                   err_msg=f"d{name}", **tol)
 
 
 @pytest.mark.parametrize("block_k", [None, 1024])
@@ -502,14 +575,17 @@ def counted_tiles(monkeypatch):
 
 
 @pytest.mark.parametrize("rows,pct,parent_pct", [
-    (1024, 150.0, 200.0), (2048, 125.0, 150.0), (4096, 112.5, 125.0), (8192, 106.25, 112.5),
+    (1024, 150.0, 200.0), (2048, 112.5, 150.0), (4096, 106.25, 125.0), (8192, 103.125, 112.5),
     (1536, 133.33, 133.33)])
 def test_causal_tiles_pct_is_what_an_instrumented_run_counts(rows, pct, parent_pct, counted_tiles):
     """``causal_tiles_pct`` (the pure function the prefill span and the train
     step's ledger row quote) against the score elements an instrumented
     interpret run of the forward computes, as a % of those at or under the
     diagonal; ``parent_pct``: what whole blocks (the schedule before PR 51) ran
-    there."""
+    there. Since PR 65 the diagonal's 512 x 512 tile is three 256 x 256 sub-tiles
+    of its four where the key block holds four query blocks: 112.5% at 2,048 rows
+    where PR 51 left 125; under that (1,024 and 1,536 rows) the forward runs it
+    whole, as PR 51 left it."""
     from deepspeed_tpu.ops.pallas import flash_attention as fa
 
     q, k, v = _qkv(B=1, S=rows, H=1, D=8, seed=rows)
@@ -530,61 +606,98 @@ def test_causal_tiles_pct_is_what_an_instrumented_run_counts(rows, pct, parent_p
 def test_key_block_is_as_coarse_as_its_bytes_allow(width, itemsize, block_k):
     """The key block is 2,048 keys where one block of the widest head holds
     ``KEY_BLOCK_BYTES`` or less (what the chip's compiler took:
-    tests/test_chip_compile_kernels.py), halved otherwise; the sub-tile count of the
-    causal schedule does not depend on it."""
+    tests/test_chip_compile_kernels.py), halved otherwise; the key sub-tiles of the
+    causal schedule do not depend on it, and the forward cuts the diagonal's tile
+    where it holds four query blocks (PR 65)."""
     from deepspeed_tpu.ops.pallas import flash_attention as fa
 
     assert fa._key_block(8192, width, itemsize) == block_k
     assert fa._key_block(1536, width, itemsize) == 512 and fa._key_block(1024, width, itemsize) == 1024
-    assert fa.causal_tiles_pct(8192, width, itemsize) == pytest.approx(106.25, rel=2e-3)
+    assert fa.causal_tiles_pct(8192, width, itemsize) == pytest.approx(
+        103.125 if block_k == 2048 else 106.25, rel=2e-3)
 
 
 @pytest.mark.parametrize("window", [None, 700.0], ids=["no-window", "traced-window"])
 def test_backward_kernels_run_the_forward_s_tiles(window, counted_tiles, form):
     """The backward cuts its steps by the same rule: at 2,048 rows (one key
-    block) a kernel runs the forward's 10 sub-tiles' worth of scores (12 before
-    PR 51) in four steps of one to four sub-tiles; a traced window changes no
-    step. The one kernel passes over the scores ONCE where dK/dV and dQ each
-    do (PR 64: the forward's tiles twice in all, not three times)."""
+    block) a kernel runs the forward's 9 sub-tiles' worth of scores (10 before
+    PR 65, 12 before PR 51) in four steps of two pieces each, all 512 rows
+    against the keys the first 256 see and the last 256 rows against the 256
+    keys more that they see; a traced window changes no step. The one kernel
+    passes over the scores ONCE where dK/dV and dQ each do (PR 64: the forward's
+    tiles twice in all, not three times)."""
     q, k, v = _qkv(B=1, S=2048, H=1, D=8, seed=2)
     w = None if window is None else jnp.float32(window)
     jax.block_until_ready(jax.grad(lambda q, k, v: jnp.sum(flash_attention(q, k, v, window=w)),
                                    argnums=(0, 1, 2))(q, k, v))
     jax.effects_barrier()
     passes = {"fused": 2, "split": 3}[form]
-    assert sorted(counted_tiles) == sorted(passes * [(512, 512), (512, 1024), (512, 1536), (512, 2048)])
+    assert sorted(counted_tiles) == sorted(passes * (
+        [(512, 256), (512, 768), (512, 1280), (512, 1792)] + 4 * [(256, 256)]))
+
+
+def STAIRS(ahead, rows=512):
+    """A diagonal step's pieces at 256-row sub-tiles, ``ahead`` keys past its key block's first."""
+    return tuple((row0, ahead + row0 if row0 else 0, ahead + row0 + 256)
+                 for row0 in range(0, rows, 256))
 
 
 @pytest.mark.parametrize("blocks,cases", [
-    ((512, 1024), [(1, 512), (2, 1024)]), ((512, 512), [(1, 512)]),
-    ((1024, 1024), [(2, 1024)]), ((768, 768), [(3, 768)]), ((128, 256), [(1, 256)]),
-    ((256, 2048), [(1, 512), (2, 1024), (3, 1536), (4, 2048)]),
-    ((1024, 2048), [(2, 1024), (4, 2048)]),
-], ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else None)
+    ((512, 1024), [(0, 0, STAIRS(0)), (512, 512, STAIRS(512)), (1024, 7680, ((0, 0, 1024),))]),
+    ((512, 512), [(0, 0, STAIRS(0)), (512, 3584, ((0, 0, 512),))]),
+    ((1024, 1024), [(0, 0, STAIRS(0, 1024)), (1024, 7168, ((0, 0, 1024),))]),
+    ((768, 768), [(0, 0, STAIRS(0, 768)), (768, 5376, ((0, 0, 768),))]),
+    ((128, 256), [(0, 1920, ((0, 0, 256),))]),
+    ((256, 2048), [(0, 256, ((0, 0, 512),)), (512, 768, ((0, 0, 1024),)),
+                   (1024, 1280, ((0, 0, 1536),)), (1536, 16128, ((0, 0, 2048),))]),
+    ((1024, 2048), [(0, 0, STAIRS(0, 1024)), (1024, 1024, STAIRS(1024, 1024)),
+                    (2048, 15360, ((0, 0, 2048),))]),
+], ids=lambda x: "x".join(map(str, x)) if isinstance(x[0], int) else None)
 def test_step_cases_are_every_case_a_grid_meets_and_no_other(blocks, cases):
     """``_step_cases``, the pieces of straight-line code a kernel holds, from
-    the block shapes alone, against every (query block, key block) pair of a
-    long grid counted by hand: each step that computes meets exactly one case,
-    the block's keys up to the end of the sub-tile that holds the last key at or
-    under its last row; without a diagonal one case, the whole block."""
+    the grid and the block shapes alone: [(lo, hi, pieces)], a case the steps
+    whose first row lies lo .. hi keys past their key block's first. Against every
+    (query block, key block) pair of a long grid counted by hand: each step that
+    computes meets exactly one case; its pieces hold every key at or under each
+    row, no 256 x 256 sub-tile wholly above the diagonal where the rows are cut
+    (``_diag_cut``: 512-row blocks and over) and no 512-key sub-tile beyond the
+    last row where they are not; a grid of ONE key block over 2,048 rows lists
+    no block wholly under the diagonal; without a diagonal one case, the whole
+    block."""
     from deepspeed_tpu.ops.pallas import flash_attention as fa
 
     bq, bk = blocks
-    assert fa._step_cases(bq, bk, True) == cases
-    sub = fa._sub_tile(bk)
+    cut = fa._diag_cut(bq, bk, backward=True)
+    assert cut == (256 if bq >= 512 else 0)
+    assert fa._diag_cut(bq, bk) == (cut if bk >= 4 * bq else 0)  # the forward's rule
+    num_q, num_k = 8 * bk // bq, 8
+    assert fa._step_cases(num_q, num_k, bq, bk, True, cut) == cases
+    edge = cut or fa._sub_tile(bk)
     met = set()
-    for qi in range(8 * bk // bq):
-        for kj in range(8):
-            seen = fa._keys_seen(qi * bq, bq, kj * bk, sub, bk // sub)
-            r_last, k0 = qi * bq + bq - 1, kj * bk
-            if seen:
-                met.add((seen, seen * sub))
-                # every key of the block at or under the last row, and no sub-tile beyond them
-                assert k0 + seen * sub - 1 >= min(r_last, k0 + bk - 1) and k0 + (seen - 1) * sub <= r_last
-            else:
-                assert k0 > r_last
-    assert met == set(cases)
-    assert fa._step_cases(bq, bk, False) == [(None, bk)]
+    for qi in range(num_q):
+        for kj in range(num_k):
+            ahead = qi * bq - kj * bk
+            mine = [pieces for lo, hi, pieces in cases if lo <= ahead <= hi]
+            if kj * bk > qi * bq + bq - 1:  # the block lies above the diagonal
+                assert not mine
+                continue
+            (pieces,) = mine
+            met.add(pieces)
+            seen = np.zeros((bq, bk), bool)
+            for row0, key0, key1 in pieces:
+                assert not seen[row0:, key0:key1].any()  # no score twice
+                seen[row0:, key0:key1] = True
+            under = np.arange(bq)[:, None] + ahead >= np.arange(bk)[None, :]
+            assert seen[under].all()
+            # what is computed beyond the triangle lies in sub-tiles the diagonal crosses
+            for r in range(0, bq, cut or bq):
+                for c in range(0, bk, edge):
+                    tile = np.s_[r:r + (cut or bq), c:c + edge]
+                    assert under[tile].any() or not seen[tile].any()
+    assert met == {pieces for _, _, pieces in cases}
+    assert fa._step_cases(num_q, num_k, bq, bk, False, cut) == [(None, None, ((0, 0, bk),))]
+    if blocks == (512, 1024):  # one key block: the case wholly under the diagonal is not traced
+        assert fa._step_cases(2, 1, bq, bk, True, cut) == cases[:2]
 
 
 @pytest.mark.parametrize("blocks,rows", [((512, 1024), 2048), ((512, 512), 1536), ((1024, 512), 2048),
@@ -625,8 +738,8 @@ def test_a_step_that_computes_nothing_fetches_nothing(blocks, rows):
     (4096, (128, 128), 2, (None, None), "fused"),   # the longest rows at 128-wide bfloat16 heads
     (4096, (128, 128), 2, (1024, 1024), "fused"),
     (4096, (192, 128), 2, (None, None), "split"),
-    (6144, (128, 128), 2, (None, None), "split"),   # dQ alone: 3 MiB of float32 and 3 written out
-    (8192, (128, 128), 2, (None, None), "split"),
+    (6144, (128, 128), 2, (None, None), "fused"),   # PR 65: the O block and a row of lse for two lane-broadcast blocks
+    (8192, (128, 128), 2, (None, None), "split"),   # dQ alone: 4 MiB of float32 and 4 written out
     (8192, (128, 128), 2, (128, 128), "fused"),     # a caller's small blocks leave dQ the room
     (2048, (128, 128), 4, (None, None), "split"),   # float32: the blocks are twice the bytes
     (2048, (256, 256), 2, (None, None), "split"),
@@ -670,5 +783,7 @@ def test_train_step_ledger_row_says_what_the_causal_kernels_compute(attn, blocks
                if r["name"].startswith("train/train_step"))
     assert row.get("causal_tiles_pct") == pct
     assert row.get("flash_bwd_form") == ("fused" if pct else None)  # PR 64: which backward it traced
+    # PR 65: 256-row blocks run the diagonal whole, in the forward and in the backward
+    assert (row.get("diag_sub"), row.get("flash_bwd_diag_sub")) == ((0, 0) if pct else (None, None))
     if pct:
         assert pct == round(fa.causal_tiles_pct(256, 16, 4, blocks[0] or None, blocks[1] or None), 2)
